@@ -18,11 +18,10 @@ small group counts, aggregate-heavy / per-group-light at 32k-100k (the
 100k-group regime is many quiet groups, not 100k firehoses — per-group
 rate at the 1M/s target is ~10 commits/s/group).
 
-Prints one JSON line per scale.  The host runtime is the subject, so the
-engine is pinned to CPU by default (pass --default-backend to benchmark
-the runtime over a real accelerator engine).
+Prints one JSON line per scale, each naming the backend the engine ran
+on: the one JAX finds.  For the CPU backend set ``JAX_PLATFORMS=cpu``.
 
-Usage: bench_runtime.py [n_groups ...] [--default-backend]
+Usage: bench_runtime.py [n_groups ...] [--tcp]
 """
 
 import json
@@ -57,12 +56,13 @@ def run(n_groups: int = 1024, rounds: int = 0, burst_n: int = 0,
         heat=None, hops=None) -> dict:
     """``pipeline``: True/False forces the durable pipeline on/off for
     every node; None uses the runtime default (RAFT_PIPELINE env if set,
-    else on only for accelerator engine backends — see RaftNode).
+    else on exactly when the engine's backend is not the CPU — see
+    RaftNode).
     ``host_workers``: striped host tier width per node (None = the
     runtime default, env RAFT_HOST_WORKERS else 1 = serial).
     ``native``: True/False pins the C++ stage_and_sync host tier on/off
     via RAFT_NATIVE_HOST for the run; None = runtime auto-selection
-    (native whenever the .so loads).
+    (native whenever the native WAL engine built).
     ``lat_sample``: pins RAFT_LAT_SAMPLE (1/N span sampling; 0 disables
     the latency plane entirely) for the run; None = env default.  When
     the plane is on, the result carries per-entry commit-path latency
@@ -228,9 +228,13 @@ def run(n_groups: int = 1024, rounds: int = 0, burst_n: int = 0,
                     "apply_ack")
                     if (s := _summ(f"lat_{name}_s")) is not None},
             }
+        import jax
+        dev = jax.devices()[0]
         return {
             "metric": f"durable-runtime commits/sec @{n_groups} groups "
-                      f"(3 nodes, WAL fsync barrier, applies, {transport})",
+                      f"(3 nodes, WAL fsync barrier, applies, {transport}, "
+                      f"engine on {dev.platform} {dev.device_kind})",
+            "platform": dev.platform,
             "value": round(commits / elapsed),
             "unit": "commits/sec",
             "vs_baseline": None,
@@ -258,12 +262,10 @@ def run(n_groups: int = 1024, rounds: int = 0, burst_n: int = 0,
 
 
 if __name__ == "__main__":
+    from rafting_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = sys.argv[1:]
-    if "--default-backend" in args:
-        args.remove("--default-backend")
-    else:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     transport = "loopback"
     if "--tcp" in args:
         # Real localhost sockets: measures the transport plane's framing,

@@ -284,11 +284,16 @@ class RaftContainer:
         import time as _time
         adm = self._admin_provider.admin
         deadline = _time.monotonic() + timeout
+        # One step is one replicated commit — several ticks — so its budget
+        # follows the tick the node is configured to keep: a fixed 5 s
+        # expired every step at a 1 s tick, and no open ever committed.
+        step_cap = max(5.0, 30 * self.config.tick_interval)
         while _time.monotonic() < deadline:
             status, lane = adm.status_of(name)
             if reached(status):
                 return lane
-            step_timeout = max(0.1, min(5.0, deadline - _time.monotonic()))
+            step_timeout = max(0.1, min(step_cap,
+                                        deadline - _time.monotonic()))
             # Probe the builder BEFORE spending a replicated next_tx: if
             # there is nothing to do locally (state not yet replicated to
             # this node), just wait — don't spam the meta log.  Permanent

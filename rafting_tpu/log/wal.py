@@ -33,6 +33,8 @@ from __future__ import annotations
 
 import ctypes
 import errno as _errno
+import hashlib
+import logging
 import os
 import struct
 import subprocess
@@ -42,6 +44,8 @@ import zlib
 from typing import Dict, Optional
 
 from ..utils import iofault
+
+log = logging.getLogger(__name__)
 
 
 class WalSyncError(IOError):
@@ -110,38 +114,57 @@ def _merge_wal_errors(excs):
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
 _SRC = os.path.join(_NATIVE_DIR, "wal.cpp")
-_SO = os.path.join(_NATIVE_DIR, "libwal.so")
 _build_lock = threading.Lock()
 _lib = None
 _build_err: Optional[str] = None
 
 
-def _build_native() -> Optional[str]:
-    """Compile the native engine if missing/stale; return error or None."""
+def _so_path() -> str:
+    """The artefact is named after the hash of the source it was built
+    from, so the loaded binary is provably the build of THIS wal.cpp —
+    file times do not survive a copied or archived tree, and a stale
+    binary can never be picked up under the current source's name."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_NATIVE_DIR, f"libwal-{digest}.so")
+
+
+def _build_native(so: str) -> Optional[str]:
+    """Compile the native engine if its artefact is missing; return the
+    error or None.  Built under a per-process name and renamed into
+    place, so concurrent builders (test workers) never load a half-
+    written file."""
+    if os.path.exists(so):
+        return None
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        if (os.path.exists(_SO)
-                and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-            return None
         r = subprocess.run(
             ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-pthread",
-             _SRC, "-o", _SO],
-            capture_output=True, text=True, timeout=120)
+             _SRC, "-o", tmp],
+            capture_output=True, text=True, timeout=300)
         if r.returncode != 0:
             return r.stderr[-2000:]
+        os.replace(tmp, so)
         return None
     except Exception as e:  # toolchain absent
         return str(e)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _load() -> Optional[ctypes.CDLL]:
     global _lib, _build_err
     with _build_lock:
-        if _lib is not None:
-            return _lib
-        _build_err = _build_native()
+        if _lib is not None or _build_err is not None:
+            return _lib     # a failed build does not mend within a process
+        so = _so_path()
+        _build_err = _build_native(so)
         if _build_err is not None:
+            log.warning("native WAL engine unavailable, serving from the "
+                        "Python engine: %s", _build_err)
             return None
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         lib.wal_open.restype = ctypes.c_void_p
         lib.wal_open.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
         lib.wal_close.argtypes = [ctypes.c_void_p]
@@ -202,41 +225,35 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.wal_gc_abort.restype = None
         lib.wal_error.argtypes = [ctypes.c_void_p]
         lib.wal_error.restype = ctypes.c_char_p
-        # Native host tier (hasattr-guarded so a stale prebuilt .so still
-        # serves the classic surface — callers probe can_stage_native).
-        if hasattr(lib, "wal_stage_and_sync"):
-            lib.wal_stage_and_sync.restype = ctypes.c_int
-            lib.wal_stage_and_sync.argtypes = (
-                [ctypes.POINTER(ctypes.c_void_p), ctypes.c_uint32,
-                 ctypes.c_uint32]
-                + [ctypes.c_void_p] * 13
-                + [ctypes.c_int, ctypes.POINTER(ctypes.c_double),
-                   ctypes.POINTER(ctypes.c_double)])
-            lib.wal_pack_ae.restype = ctypes.c_int64
-            lib.wal_pack_ae.argtypes = [
-                ctypes.POINTER(ctypes.c_void_p), ctypes.c_uint32,
-                ctypes.c_uint32, ctypes.c_uint64, ctypes.c_void_p,
-                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
-                ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8))]
-            lib.wal_buf_free.restype = None
-            lib.wal_buf_free.argtypes = [ctypes.POINTER(ctypes.c_uint8)]
-        # Injectable fault table (hasattr-guarded like the host tier: a
-        # stale prebuilt .so still serves the classic surface).
-        if hasattr(lib, "wal_fault_set"):
-            lib.wal_fault_set.restype = ctypes.c_int
-            lib.wal_fault_set.argtypes = [
-                ctypes.c_void_p, ctypes.c_int, ctypes.c_int64, ctypes.c_int64]
-            lib.wal_fault_clear.restype = None
-            lib.wal_fault_clear.argtypes = [ctypes.c_void_p]
-            lib.wal_poisoned.restype = ctypes.c_int
-            lib.wal_poisoned.argtypes = [ctypes.c_void_p]
-            lib.wal_last_errno.restype = ctypes.c_int
-            lib.wal_last_errno.argtypes = [ctypes.c_void_p]
-        # Per-stripe instrumentation export (hasattr-guarded like the host
-        # tier: a stale prebuilt .so still serves the classic surface).
-        if hasattr(lib, "wal_stats"):
-            lib.wal_stats.restype = None
-            lib.wal_stats.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
+        # Native host tier.
+        lib.wal_stage_and_sync.restype = ctypes.c_int
+        lib.wal_stage_and_sync.argtypes = (
+            [ctypes.POINTER(ctypes.c_void_p), ctypes.c_uint32,
+             ctypes.c_uint32]
+            + [ctypes.c_void_p] * 13
+            + [ctypes.c_int, ctypes.POINTER(ctypes.c_double),
+               ctypes.POINTER(ctypes.c_double)])
+        lib.wal_pack_ae.restype = ctypes.c_int64
+        lib.wal_pack_ae.argtypes = [
+            ctypes.POINTER(ctypes.c_void_p), ctypes.c_uint32,
+            ctypes.c_uint32, ctypes.c_uint64, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8))]
+        lib.wal_buf_free.restype = None
+        lib.wal_buf_free.argtypes = [ctypes.POINTER(ctypes.c_uint8)]
+        # Injectable fault table.
+        lib.wal_fault_set.restype = ctypes.c_int
+        lib.wal_fault_set.argtypes = [
+            ctypes.c_void_p, ctypes.c_int, ctypes.c_int64, ctypes.c_int64]
+        lib.wal_fault_clear.restype = None
+        lib.wal_fault_clear.argtypes = [ctypes.c_void_p]
+        lib.wal_poisoned.restype = ctypes.c_int
+        lib.wal_poisoned.argtypes = [ctypes.c_void_p]
+        lib.wal_last_errno.restype = ctypes.c_int
+        lib.wal_last_errno.argtypes = [ctypes.c_void_p]
+        # Per-stripe instrumentation export.
+        lib.wal_stats.restype = None
+        lib.wal_stats.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
         _lib = lib
         return lib
 
@@ -251,13 +268,6 @@ WAL_STAT_KEYS = ("stage_ns", "fsync_ns", "pack_ns", "bytes",
 
 def native_available() -> bool:
     return _load() is not None
-
-
-def native_host_available() -> bool:
-    """True when the loaded .so exports the native host tier entry points
-    (wal_stage_and_sync / wal_pack_ae)."""
-    lib = _load()
-    return lib is not None and hasattr(lib, "wal_stage_and_sync")
 
 
 def _shard_split(n_shards: int, g_arr, cols):
@@ -367,33 +377,31 @@ class _NativeWal:
 
     # -- injectable fault table (testkit/faultfs) ----------------------
     def set_fault(self, op: str, after: int = 0, value: int = 0) -> None:
-        if not hasattr(self._lib, "wal_fault_set"):
-            raise RuntimeError("native fault table unavailable (stale .so)")
         if value == 0 and op in ("fsync", "write"):
             value = _errno.EIO
         self._lib.wal_fault_set(self._h, _FAULT_OPS[op], int(after),
                                 int(value))
 
     def clear_faults(self) -> None:
-        if self._h and hasattr(self._lib, "wal_fault_clear"):
+        if self._h:
             self._lib.wal_fault_clear(self._h)
 
     @property
     def poisoned(self) -> bool:
-        if not self._h or not hasattr(self._lib, "wal_poisoned"):
+        if not self._h:
             return False
         return bool(self._lib.wal_poisoned(self._h))
 
     @property
     def last_errno(self) -> int:
-        if not self._h or not hasattr(self._lib, "wal_last_errno"):
+        if not self._h:
             return 0
         return int(self._lib.wal_last_errno(self._h))
 
     def stats(self) -> Dict[str, int]:
         """Cumulative per-stripe instrumentation (WAL_STAT_KEYS), read
         zero-copy from the engine's atomic counters."""
-        if not self._h or not hasattr(self._lib, "wal_stats"):
+        if not self._h:
             return dict.fromkeys(WAL_STAT_KEYS, 0)
         out = (ctypes.c_uint64 * len(WAL_STAT_KEYS))()
         self._lib.wal_stats(self._h, out)
@@ -405,9 +413,7 @@ class _NativeWal:
             raise WalNoSpace(msg, (self.shard_id,))
         raise WalSyncError(msg, (self.shard_id,))
 
-    @property
-    def can_stage_native(self) -> bool:
-        return native_host_available()
+    can_stage_native = True
 
     def stage_and_sync(self, groups, idxs, terms, ptrs, lens,
                        trunc_g, trunc_from, floor_g, floor_idx, floor_term,
@@ -1219,7 +1225,7 @@ class ShardedWal:
 
     @property
     def can_stage_native(self) -> bool:
-        return self._handles is not None and native_host_available()
+        return self._handles is not None
 
     def stage_and_sync(self, groups, idxs, terms, ptrs, lens,
                        trunc_g, trunc_from, floor_g, floor_idx, floor_term,

@@ -373,8 +373,8 @@ class RaftNode:
         # Native host tier (_host_phase_native): the per-tick stage →
         # fsync hot loop crosses into the WAL engine's C side ONCE, with
         # real OS threads per stripe-set (no GIL) — auto-selected when
-        # the .so exports it, forced on/off with RAFT_NATIVE_HOST=1/0.
-        # Byte-identical WAL layout to the Python paths, so recovery is
+        # the native WAL engine serves, forced on/off with
+        # RAFT_NATIVE_HOST=1/0.  Byte-identical WAL layout to the Python paths, so recovery is
         # interchangeable between backends.
         can_native = bool(getattr(self.store, "can_stage_native", False))
         env_native = os.environ.get("RAFT_NATIVE_HOST", "").strip().lower()
@@ -475,6 +475,7 @@ class RaftNode:
         # overload.  RAFT_ADMISSION=0 disables (admit() then always
         # passes and only the caps remain).
         self.admission = admission_from_env(seed=seed ^ node_id)
+        self._tick_started: Optional[float] = None  # previous tick's start
         self._adm_delay: Optional[float] = None  # this tick's sojourn sample
         self._adm_fold = [0, 0, 0, 0]  # counters folded into metrics
         # Cross-group transaction plane (runtime/txn.py): the driver
@@ -1360,7 +1361,15 @@ class RaftNode:
                 self._host_phase(ctx)
         self.metrics.observe("tick_latency_s",
                              time.perf_counter() - _tick_t0)
-        self._admission_tick(time.perf_counter() - _tick_t0)
+        # The admission controller's tick is the time from one tick's
+        # start to the next: a submission queues for whole tick PERIODS,
+        # however short the tick's own work is.  Fed the work time alone,
+        # a loop paced slower than its work (tick_ms=1000 on the chip)
+        # saw every one-period wait as a standing queue and shed an idle
+        # cluster's traffic.
+        prev, self._tick_started = self._tick_started, _tick_t0
+        self._admission_tick(time.perf_counter() - _tick_t0
+                             if prev is None else _tick_t0 - prev)
         # Txn plane: fold driver/resolver counters and (every
         # sweep_every ticks) resolve expired write-intents on groups
         # this node leads (runtime/txn.py — coordinator timeouts are
@@ -1799,12 +1808,6 @@ class RaftNode:
         self.metrics.gauge("groups_active", int(self.h_active.sum()))
         self.metrics.gauge(
             "groups_led", int((h_role == LEADER).sum()))
-        # Empty-payload short-circuits (machine/spi.py applies_empty
-        # opt-in): nonzero here explains a last_applied that lags the
-        # commit frontier without digging through warn-once logs.
-        skips = getattr(self.dispatcher, "empty_skips", 0)
-        if skips:
-            self.metrics.gauge("empty_apply_skips", int(skips))
 
     # ---------------------------------------------------- tick: host phase
 
@@ -1842,6 +1845,14 @@ class RaftNode:
                     self._host_phase_striped(ctx, defer_send)
                 else:
                     self._host_phase_serial(ctx, defer_send)
+                # Empty-payload short-circuits (machine/spi.py
+                # applies_empty opt-in), published behind the apply
+                # phase that counts them: nonzero here explains a
+                # last_applied that lags the commit frontier without
+                # digging through warn-once logs.
+                skips = self.dispatcher.empty_skips
+                if skips:
+                    self.metrics.gauge("empty_apply_skips", skips)
             except (WalNoSpace, WalSyncError) as e:
                 self._storage_fault(e, pre_tail)
         finally:
@@ -2498,7 +2509,14 @@ class RaftNode:
                 # the HEAD while the queue is still FIFO (pre-engage
                 # transient) and at the TAIL once LIFO kicks in, so
                 # check both ends.  Only untouched batches (taken == 0)
-                # are expirable; never entries the device accepted.
+                # are expirable; never entries the device accepted —
+                # nor, in pipelined mode, entries it may yet accept: the
+                # tick dispatched after this one already carries offers
+                # against this queue, and the device accepts by COUNT,
+                # so the queue must keep at least that many entries.
+                if adm_expire is not None:
+                    riding = self._inflight_submit - submit_n
+                    queued = self._queued_n
                 for g, q in self._submissions.items():
                     if not q:
                         continue
@@ -2507,10 +2525,12 @@ class RaftNode:
                         adm_oldest = t0
                     if adm_expire is not None:
                         while q and q[0].taken == 0 \
-                                and adm_now - q[0].t_enq > adm_expire:
+                                and adm_now - q[0].t_enq > adm_expire \
+                                and queued[g] - len(q[0].run) >= riding[g]:
                             self._expire_batch(g, q.popleft(), expired)
                         while q and q[-1].taken == 0 \
-                                and adm_now - q[-1].t_enq > adm_expire:
+                                and adm_now - q[-1].t_enq > adm_expire \
+                                and queued[g] - len(q[-1].run) >= riding[g]:
                             self._expire_batch(g, q.pop(), expired)
             if adm_oldest is not None:
                 self._adm_delay = adm_now - adm_oldest

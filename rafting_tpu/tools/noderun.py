@@ -38,17 +38,15 @@ def main() -> int:
     ap.add_argument("--group", default="root")
     ap.add_argument("--period", type=float, default=0.01,
                     help="seconds between submissions (reference: 10ms)")
-    ap.add_argument("--platform", default="cpu",
-                    help="jax platform to pin ('' = default backend)")
     ap.add_argument("--drain", type=float, default=3.0,
                     help="seconds to keep ticking after SIGTERM")
     args = ap.parse_args()
 
-    if args.platform:
-        import jax
-        jax.config.update("jax_platforms", args.platform)
-
     from rafting_tpu.api import RaftContainer, load_xml_config
+    from rafting_tpu.utils.compile_cache import enable_compile_cache
+
+    # The engine runs on the backend JAX finds (JAX_PLATFORMS selects).
+    enable_compile_cache()
 
     cfg = load_xml_config(args.config)
     container = RaftContainer(cfg).create()

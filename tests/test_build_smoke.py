@@ -71,11 +71,15 @@ def test_fresh_so_round_trip(fresh_so, tmp_path):
     lib.wal_close(h)
 
 
-def test_binding_reports_native_host():
+def test_binding_loads_the_build_of_the_committed_source():
     """The in-repo binding (which builds/loads lazily on first use) must
-    agree that the host tier is available when a toolchain exists."""
-    assert wal_mod.native_available()
-    assert wal_mod.native_host_available()
+    load when a toolchain exists, from an artefact named after the hash
+    of wal.cpp — never a binary some other source produced."""
+    import hashlib
+    assert wal_mod.native_available(), wal_mod._build_err
+    with open(wal_mod._SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert os.path.basename(wal_mod._lib._name) == f"libwal-{digest}.so"
 
 
 _SAN_FLAGS = ["-fsanitize=address,undefined", "-fno-sanitize-recover=all",

@@ -30,7 +30,7 @@ from rafting_tpu.core.types import (
     EngineConfig, HostInbox, LEADER, Messages, StepInfo, crash_restart,
     init_state,
 )
-from test_oracle_parity import run_parity
+from rafting_tpu.testkit.parity import run_parity
 
 CFG = dict(n_groups=8, n_peers=3, log_slots=16, batch=4, max_submit=4,
            election_ticks=6, heartbeat_ticks=2, rpc_timeout_ticks=5,

@@ -24,7 +24,7 @@ from rafting_tpu.testkit.fixtures import NullProvider
 from rafting_tpu.testkit.harness import LocalCluster
 from rafting_tpu.testkit.oracle import oracle_step
 
-from test_oracle_parity import (
+from rafting_tpu.testkit.parity import (
     assert_info_equal, assert_messages_equal, assert_state_equal,
 )
 

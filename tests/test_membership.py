@@ -18,7 +18,7 @@ from rafting_tpu.core.types import (
 from rafting_tpu.testkit.invariants import ClusterChecker
 from rafting_tpu.testkit import nemesis
 
-from test_oracle_parity import run_parity
+from rafting_tpu.testkit.parity import run_parity
 
 
 # ----------------------------------------------------------------- parity --
@@ -349,7 +349,7 @@ def test_rebalance_walk_parity_tick_for_tick():
     """The scripted walk with kernel <-> oracle parity asserted EVERY
     tick: the same membership schedule (learner add at a fixed tick,
     joint switch later, transfer at the end) drives both engines."""
-    from test_oracle_parity import (
+    from rafting_tpu.testkit.parity import (
         assert_info_equal, assert_messages_equal, assert_state_equal,
         route_numpy,
     )

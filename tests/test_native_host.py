@@ -29,7 +29,7 @@ from test_host_striped import (
 )
 
 pytestmark = pytest.mark.skipif(
-    not wal_mod.native_host_available(),
+    not wal_mod.native_available(),
     reason="native WAL host tier unavailable (no toolchain/.so)")
 
 CFG = EngineConfig(n_groups=8, n_peers=3, log_slots=16, batch=4,

@@ -378,6 +378,39 @@ def test_forced_shed_refusals_metrics_and_healthz(tmp_path):
         c.close()
 
 
+def test_paced_tick_loop_does_not_read_its_own_period_as_a_queue(tmp_path):
+    """A loop paced slower than its work (tick_ms above the tick's own
+    cost — the served path at 100k lanes on one chip) makes every
+    submission wait about one tick PERIOD before the device sees it.
+    The controller's target is counted in ticks, so its tick must be
+    that period: measured against the work time alone, each one-period
+    wait looked like a standing queue and an idle cluster shed its only
+    client."""
+    import time
+    c = LocalCluster(CFG, str(tmp_path), seed=9, pipeline=True)
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        pace = 0.15                # well above one tick's work
+        for _ in range(25):        # the loop is paced from its first tick
+            time.sleep(pace)
+            c.tick(1)
+        futs = []
+        for k in range(20):
+            if node.is_leader(0) and node.is_ready(0):
+                futs.append(node.submit(0, b"paced-%d" % k))
+            time.sleep(pace)
+            c.tick(1)
+        c.tick(10)
+        adm = node.admission
+        assert adm.shed == 0 and adm.expired == 0 and adm.level == 0.0, \
+            adm.snapshot()
+        assert futs and all(f.done() and f.exception() is None
+                            for f in futs)
+    finally:
+        c.close()
+
+
 def test_quarantined_stripe_fast_fails_unavailable(tmp_path):
     def store_factory(i):
         return LogStore(os.path.join(str(tmp_path), f"node{i}", "wal"),

@@ -30,6 +30,33 @@ CFG = EngineConfig(n_groups=4, n_peers=3, log_slots=32, batch=4,
 # ---------------------------------------------------------------- crash window
 
 
+def test_late_shed_keeps_offers_riding_the_dispatched_tick(tmp_path):
+    """Pipelined mode dispatches tick N+1 before tick N's host phase runs,
+    so when that phase sheds aged batches from the submit queues, some
+    queued entries are already offered to the device.  The device accepts
+    by count: expiring those entries would leave it accepting more than
+    the queue holds (the "beyond the queued depth" invariant in
+    _persist_prepare).  The shed must leave them queued."""
+    c = LocalCluster(CFG, str(tmp_path), pipeline=True, wal_shards=2)
+    try:
+        lead = c.wait_leader(0)
+        c.tick(5)
+        node = c.nodes[lead]
+        assert node.is_ready(0)
+        node.admission.expire_age = lambda: 0.0   # every batch is overage
+        futs = [node.submit(0, b"ride-%d" % k) for k in range(3)]
+        for _ in range(40):
+            c.tick(1)
+            if all(f.done() for f in futs):
+                break
+        assert all(f.done() for f in futs)
+        # All three rode the tick dispatched before the shed looked at
+        # them, so all three were accepted and must commit.
+        assert [f.exception() for f in futs] == [None] * 3
+    finally:
+        c.close()
+
+
 def test_crash_between_dispatch_and_fsync_completes_nothing(tmp_path):
     """Kill the node inside the pipeline's overlap window — tick N's scan
     accepted entries and tick N+1 may already be dispatched, but tick N's

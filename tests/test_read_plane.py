@@ -237,7 +237,7 @@ def test_bench_reads_stage_smoke(monkeypatch):
     import bench
     # warmup == measure == 12 ticks on purpose: every fused scan in the
     # stage then shares ONE (cfg, 12) compilation (tier-1 time budget).
-    res = bench.child_run(64, 12, 12, platform="cpu")
+    res = bench.child_run(64, 12, 12)
     assert res["reads"] > 0 and res["rps"] > 0
     assert res["read_mix"] == "90/10"
     # Reads bypass the log: entries appended come from the write stream

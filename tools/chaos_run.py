@@ -372,7 +372,6 @@ def main() -> int:
         assert args.recovery_ticks < args.isolate_dur, \
             "--recovery-ticks must fit inside --isolate-dur"
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from rafting_tpu.core.types import EngineConfig
     from rafting_tpu.machine.kv_machine import KVMachineProvider
     from rafting_tpu.testkit.chaos import plan_chaos, timeline_json

@@ -1,12 +1,10 @@
 #!/usr/bin/env python
 """BASELINE config 4 validation: 100k groups x 5 peers, mixed
-AppendEntries + RequestVote traffic under partition, on the real device,
+AppendEntries + RequestVote traffic under partition, on the backend JAX finds,
 with in-kernel invariant checks compiled in (EngineConfig.debug_checks).
 
-Measured r4 on TPU v5e-1 (seed 4): elect 100k x 5 in ~97s (incl. compile),
-95.7% of majority-side groups re-elect + progress within 30 partitioned
-ticks, 100% by 120; after heal, zero same-term split brain across all
-100k groups and every group progresses.  Total 289s, 87.3M commits.
+Runs on the backend JAX finds and says which.  On a TPU: not measured by
+any recorded run.
 
 Usage: python tools/validate_config4.py [n_groups]
 """

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """BASELINE config 5 validation: 100k groups with InstallSnapshot
-lagging-follower catch-up, on the real device, with in-kernel invariant
+lagging-follower catch-up, on the backend JAX finds, with in-kernel invariant
 checks compiled in.
 
 Scenario: one node is isolated while the majority keeps committing and
