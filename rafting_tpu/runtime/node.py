@@ -74,7 +74,7 @@ from ..utils.latency import (
     hops_from_env, tracer_from_env,
 )
 from ..utils.metrics import Metrics
-from ..utils.profiling import TickProfiler
+from ..utils.profiling import StageSpans
 from ..utils.tracelog import TraceLog
 
 log = logging.getLogger(__name__)
@@ -757,9 +757,11 @@ class RaftNode:
         # tracing is off.  Served over HTTP by start_observability().
         self.tracelog = TraceLog(cfg)
         self._obsrv = None
-        # Device-profiler hook (SURVEY §5): bounded capture of the tick
-        # loop; armed via profile_ticks() or RAFT_PROFILE_DIR.
-        self.profiler = TickProfiler.from_env()
+        # The phase the tick thread is in (utils/profiling.py): a
+        # tick_stage_<name>_s sample per phase per tick, and a raft.<name>
+        # span in whatever jax.profiler session is running.
+        self._stages = StageSpans(self.metrics, node_id)
+        self._tick_due: Optional[float] = None   # _run: next start due
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # Double-buffered pipeline state: the fetched-but-not-yet-host-
@@ -886,6 +888,7 @@ class RaftNode:
             except Exception:
                 log.exception("node %d: pipeline drain failed on close",
                               self.node_id)
+            self._stages.leave()
         if self._lat is not None:
             # Final harvest: retired-but-unmerged spans land in the
             # histograms before the registry goes quiet (spans still in
@@ -921,7 +924,6 @@ class RaftNode:
                 # crash-safe; recovery re-derives everything) and bail.
                 log.error("node %d: WAL GC worker failed to stop; leaking "
                           "store handle", self.node_id)
-                self.profiler.close()
                 self.dispatcher.close()
                 return
         if self._gc_phase == 2:
@@ -933,7 +935,6 @@ class RaftNode:
         elif self._gc_phase != 0:
             self.store.gc_abort()
         self._gc_phase = 0
-        self.profiler.close()
         self.dispatcher.close()
         if self._host_pool is not None:
             self._host_pool.shutdown(wait=True)
@@ -1260,16 +1261,40 @@ class RaftNode:
 
     # ------------------------------------------------------------- tick loop
 
+    # A tick counts as late when it starts more than this share of a
+    # period after it was due.  Measured (PERF.md, PR 23 call 25): a tick
+    # of 1.2 periods (0.2 late) leaves the peers' slice-per-tick streams
+    # in step, one of 1.8 (0.8 late) leaves a standing backlog of one
+    # slice per source in this node's InboxAccumulator; half a period
+    # separates the two.
+    LATE_TICK_SHARE = 0.5
+
+    def _note_tick_start(self, now: float, interval: float) -> None:
+        """Tick thread, once per loop period: how late this tick starts.
+        It was due one interval after the previous start."""
+        due, self._tick_due = self._tick_due, now + interval
+        if due is None:
+            return
+        late = max(0.0, now - due)
+        self.metrics.observe("tick_late_s", late)
+        if late > self.LATE_TICK_SHARE * interval:
+            self.metrics["ticks_late"] += 1
+
     def _run(self, interval: float) -> None:
+        st = self._stages
         while not self._stop.is_set():
             t0 = time.perf_counter()
+            self._note_tick_start(t0, interval)
             try:
                 self.tick()
             except Exception:
                 log.exception("node %d tick failed", self.node_id)
+                st.leave()
             dt = time.perf_counter() - t0
             if dt < interval:
+                st.enter("wait")
                 time.sleep(interval - dt)
+                st.leave()
 
     def set_active(self, group: int, active: bool,
                    purge: bool = False) -> None:
@@ -1304,10 +1329,6 @@ class RaftNode:
             os.replace(tmp, self._lane_gens_path)
         self.set_active(lane, True)
 
-    def profile_ticks(self, log_dir: str, n_ticks: int = 64) -> None:
-        """Capture the next ``n_ticks`` ticks to a JAX profiler trace."""
-        self.profiler.arm(log_dir, n_ticks)
-
     def tick(self) -> StepInfo:
         """Advance the node one tick and return its StepInfo.
 
@@ -1329,38 +1350,54 @@ class RaftNode:
         range into a commit.  Pipeline barriers (lifecycle changes,
         snapshot installs) drain the pending tick first; both are rare.
         """
-        _tick_t0 = time.perf_counter()
-        with self.profiler.step(self.ticks):
-            ctx = self._dispatch()
-            if self.pipeline:
-                prev, self._pending = self._pending, None
-                try:
-                    if prev is not None:
-                        self._host_phase(prev, defer_send=True)
-                finally:
-                    # The dispatched tick must never be dropped: even if
-                    # the previous host phase failed (the loop in _run
-                    # keeps ticking through exceptions), fetch and stash
-                    # it so its appends are persisted next tick —
-                    # otherwise the device state advances past entries
-                    # whose payloads the WAL never saw.
-                    self._fetch(ctx)
-                    self._pending = ctx
-                    # Eager leader sends: THIS tick's AE/heartbeat frames
-                    # leave now, ahead of this tick's own fsync (which
-                    # runs next tick).  Safe because commit counts our
-                    # self-match only up to the fsynced durable tail
-                    # (HostInbox.durable_tail); AE-responses, votes and
-                    # client futures stay strictly behind the fsync in
-                    # the deferred host phase.  Pending is stashed FIRST
-                    # so a send failure can't drop the tick.
-                    self._eager_send(ctx)
-                    self._flush_sends()
-            else:
+        st = self._stages
+        # Every instant of tick() belongs to one named phase (the stage
+        # histograms and raft.<name> profiler spans): dispatch_intake,
+        # dispatch_upload, dispatch_enqueue (_dispatch); wal, fsync, send,
+        # apply, reads, maintain (_host_phase); scan_device, scan_fetch,
+        # mirrors (_fetch); eager_send (pipelined mode); tail.  _run adds
+        # wait.  Pipelined order: dispatch, host phase, fetch, eager_send.
+        st.begin(self.ticks)
+        if self._lat is not None:
+            self._lat.tick = self.ticks
+        _tick_t0 = st.enter("dispatch_intake")
+        ctx = self._dispatch()
+        if self.pipeline:
+            prev, self._pending = self._pending, None
+            try:
+                if prev is not None:
+                    self._host_phase(prev, defer_send=True)
+            finally:
+                # The dispatched tick must never be dropped: even if
+                # the previous host phase failed (the loop in _run
+                # keeps ticking through exceptions), fetch and stash
+                # it so its appends are persisted next tick —
+                # otherwise the device state advances past entries
+                # whose payloads the WAL never saw.
                 self._fetch(ctx)
-                self._host_phase(ctx)
-        self.metrics.observe("tick_latency_s",
-                             time.perf_counter() - _tick_t0)
+                self._pending = ctx
+                # Eager leader sends: THIS tick's AE/heartbeat frames
+                # leave now, ahead of this tick's own fsync (which
+                # runs next tick).  Safe because commit counts our
+                # self-match only up to the fsynced durable tail
+                # (HostInbox.durable_tail); AE-responses, votes and
+                # client futures stay strictly behind the fsync in
+                # the deferred host phase.  Pending is stashed FIRST
+                # so a send failure can't drop the tick.
+                st.enter("eager_send")
+                self._eager_send(ctx)
+                self._flush_sends()
+        else:
+            self._fetch(ctx)
+            self._host_phase(ctx)
+        # tick_latency_s ends here, where it always has; the tail below
+        # (admission, txn, span harvest, health) is the stage after it.
+        m = self.metrics
+        m.observe("tick_latency_s", st.enter("tail") - _tick_t0)
+        m.observe("tick_stage_dispatch_s", st.total(
+            "dispatch_intake", "dispatch_upload", "dispatch_enqueue"))
+        m.observe("tick_stage_scan_wait_s",
+                  st.total("scan_device", "scan_fetch"))
         # The admission controller's tick is the time from one tick's
         # start to the next: a submission queues for whole tick PERIODS,
         # however short the tick's own work is.  Fed the work time alone,
@@ -1388,7 +1425,7 @@ class RaftNode:
         # Health scorecards last: the fold above just refreshed the hop
         # histograms this tick's peer scoring reads.
         self._health_tick()
-        self.profiler.after_tick()
+        st.leave()
         return ctx.info
 
     def _admission_tick(self, tick_s: float) -> None:
@@ -1527,6 +1564,7 @@ class RaftNode:
             # are rare catch-up/admin events; one overlap window is lost.
             prev, self._pending = self._pending, None
             self._host_phase(prev)
+            self._stages.enter("dispatch_intake")
         if changes:
             act = np.asarray(self.state.active).copy()
             purged = []
@@ -1586,6 +1624,10 @@ class RaftNode:
                     b = q.popleft()
                     self._read_queued_n[g] -= len(b.payloads)
                     self._reads_offered[g] = b
+                    if b.sink.span is not None:
+                        # The group's one offer slot is won: submitted ->
+                        # offered was the wait for it (lat_read_queue_s).
+                        b.sink.span.mark(OFFERED)
             for g, b in self._reads_offered.items():
                 if not self._inflight_read[g]:
                     read_n[g] = len(b.payloads)
@@ -1645,29 +1687,36 @@ class RaftNode:
             # clamp (_acked_tail) is fed in serial mode too.
             src = self._durable_tail_m if self._acked_tail is None \
                 else self._acked_tail
-            durable = jnp.asarray(np.minimum(
-                src, I32_SAFE_MAX).astype(np.int32))
+            durable = np.minimum(src, I32_SAFE_MAX).astype(np.int32)
+        compact_to = self._compact_grant.astype(np.int32)
+        self._compact_grant = np.zeros(G, np.int64)
+
+        # -- 2. network inbox ------------------------------------------------
+        arrays, staged_payloads = self.acc.drain()
+        self._fold_inbox_stats()
+
+        # -- 2b. uploads: every host plane crosses to the device here, after
+        # the whole intake, so that one stage holds them all ----------------
+        st = self._stages
+        st.enter("dispatch_upload")
         host = HostInbox(
             submit_n=jnp.asarray(submit_n),
             snap_done=jnp.asarray(snap_done),
             snap_idx=jnp.asarray(snap_idx),
             snap_term=jnp.asarray(snap_term),
             snap_conf=jnp.asarray(snap_conf),
-            compact_to=jnp.asarray(self._compact_grant.astype(np.int32)),
+            compact_to=jnp.asarray(compact_to),
             conf_voters=jnp.asarray(conf_voters),
             conf_learners=jnp.asarray(conf_learners),
             xfer_target=jnp.asarray(xfer_target),
             read_n=jnp.asarray(read_n),
             read_veto=jnp.asarray(read_veto),
-            durable_tail=durable,
+            durable_tail=None if durable is None else jnp.asarray(durable),
         )
-        self._compact_grant = np.zeros(G, np.int64)
-
-        # -- 2. network inbox ------------------------------------------------
-        arrays, staged_payloads = self.acc.drain()
         inbox = Messages(**{k: jnp.asarray(v) for k, v in arrays.items()})
 
         # -- 3. device step (async dispatch: no transfer, no block) ----------
+        st.enter("dispatch_enqueue")
         self.state, outbox, info = node_step(cfg, self.state, inbox, host)
 
         ctx = _TickCtx()
@@ -1684,6 +1733,25 @@ class RaftNode:
         self._inflight_read = self._inflight_read + read_n
         return ctx
 
+    def _fold_inbox_stats(self) -> None:
+        """Tick thread, right after ``acc.drain()``: fold what the drain
+        saw (transport/inbox.py InboxStats) into the registry — the wait
+        of every slice popped, the deepest queue left behind (one sample
+        a tick, so the histogram's mean over a window is a depth in
+        slices), the same per source as gauges, and the slices collapsed
+        or dropped.  Reader threads only ever touch the accumulator."""
+        stats = self.acc.take_stats()
+        m = self.metrics
+        for w in stats.waits_s:
+            m.observe("inbox_wait_s", w)
+        m.observe("inbox_backlog", max(stats.depth.values(), default=0))
+        for src, depth in stats.depth.items():
+            m.gauge(f"inbox_backlog_src{src}", depth)
+        if stats.collapsed:
+            m["inbox_collapsed"] += stats.collapsed
+        if stats.dropped:
+            m["inbox_dropped"] += stats.dropped
+
     # --------------------------------------------------------- tick: fetch
 
     def _fetch(self, ctx: _TickCtx) -> None:
@@ -1693,15 +1761,21 @@ class RaftNode:
         the wait here is whatever device time the host work did not
         cover."""
         cfg = self.cfg
-        _w0 = time.perf_counter()
-        # One transfer for everything the host needs this tick (the heat
-        # lanes ride it as a None subtree when cfg.heat is off).
-        (h_info, h_out, h_term, h_voted, h_role, h_leader, h_commit, h_base,
-         h_base_term, h_heat) = jax.device_get(
+        st = self._stages
+        # The wait is split where the work happens: scan_device is the
+        # device's remaining work on this tick's step, scan_fetch the
+        # device-to-host copy (tick_stage_scan_wait_s, observed by tick(),
+        # stays their sum).  One transfer for everything the host needs
+        # this tick (the heat lanes ride it as a None subtree when
+        # cfg.heat is off).
+        st.enter("scan_device")
+        outs = jax.block_until_ready(
             (ctx.info, ctx.outbox, ctx.term, ctx.voted, ctx.role,
              ctx.leader, ctx.commit, ctx.base, ctx.base_term, ctx.heat))
-        self.metrics.observe("tick_stage_scan_wait_s",
-                             time.perf_counter() - _w0)
+        st.enter("scan_fetch")
+        (h_info, h_out, h_term, h_voted, h_role, h_leader, h_commit, h_base,
+         h_base_term, h_heat) = jax.device_get(outs)
+        st.enter("mirrors")
         ctx.info, ctx.outbox = h_info, h_out
         ctx.term, ctx.voted, ctx.role = h_term, h_voted, h_role
         ctx.leader, ctx.commit = h_leader, h_commit
@@ -1889,7 +1963,8 @@ class RaftNode:
 
     def _host_phase_serial(self, ctx: _TickCtx, defer_send: bool) -> None:
         G = self.cfg.n_groups
-        _t0 = time.perf_counter()
+        st = self._stages
+        st.enter("wal")
         # -- 4. persistence barrier ------------------------------------------
         prep = self._persist_prepare(
             ctx.info, ctx.term, ctx.voted, ctx.leader, ctx.base,
@@ -1903,7 +1978,7 @@ class RaftNode:
         self._sweep_rejections(prep)
         self._hops_scan(ctx)
         ctx.staged_payloads = ctx.arrays = None   # drop frame pins early
-        _t1 = time.perf_counter()
+        _t1 = st.enter("fsync")
         if self._lat_tick:
             self._lat_stamp(STAGED)
         if self._hops is not None:
@@ -1911,7 +1986,7 @@ class RaftNode:
         if need_sync or self._sync_pending:
             self._barrier()     # THE durability barrier
             self._barrier_ok()
-        _t2 = time.perf_counter()
+        _t2 = st.enter("send")
         if self._lat_tick:
             self._lat_stamp(FSYNCED)
         if self._hops is not None:
@@ -1928,7 +2003,7 @@ class RaftNode:
             self._held_sections.setdefault(p, []).extend(secs)
         if not defer_send:
             self._flush_sends()
-        _t3 = time.perf_counter()
+        st.enter("apply")
         if self._lat_tick:
             self._lat_stamp(SENT)
 
@@ -1942,25 +2017,18 @@ class RaftNode:
         after = self.dispatcher.applied_frontier(G)
         self.metrics["applies"] += int((after - before).sum())
         self.metrics["commits"] = int(ctx.commit.astype(np.int64).sum())
-        _t4 = time.perf_counter()
+        st.enter("reads")
 
         # -- 6b. read plane: stamped/released bookkeeping + serving ----------
         self._harvest_reads(ctx.info)
         self._serve_reads(after)
-        _t5 = time.perf_counter()
+        st.enter("maintain")
 
         # -- 7. maintain: checkpoints, compaction, snapshot downloads --------
         self._maintain(after, ctx.base, ctx.term)
         self._snapshot_requests(ctx.info, ctx.base)
-        _t6 = time.perf_counter()
-
-        m = self.metrics
-        m.observe("tick_stage_wal_s", _t1 - _t0)
-        m.observe("tick_stage_fsync_s", _t2 - _t1)
-        m.observe("tick_stage_send_s", _t3 - _t2)
-        m.observe("tick_stage_apply_s", _t4 - _t3)
-        m.observe("tick_stage_reads_s", _t5 - _t4)
-        m.observe("tick_stage_maintain_s", _t6 - _t5)
+        # The six stages are observed at their boundaries; whichever
+        # phase follows (scan_device, tail, dispatch_intake) ends maintain.
 
     def _ensure_host_pool(self) -> ThreadPoolExecutor:
         """W-1 stripe workers; the tick thread itself is worker 0."""
@@ -1991,7 +2059,11 @@ class RaftNode:
         Membership-config ticks (leader conf appends or adopted conf
         words) return None from prepare and run the serial phase: the
         conf sidecar is one global JSON doc and conf traffic is rare."""
-        _t0 = time.perf_counter()
+        # Phases A and B interleave two stages each inside the workers:
+        # the spans are raft.wal (A) and raft.send (B), and the four
+        # histograms keep the workers' own maxima (observe=False).
+        st = self._stages
+        st.enter("wal", observe=False)
         prep = self._persist_prepare(
             ctx.info, ctx.term, ctx.voted, ctx.leader, ctx.base,
             ctx.base_term, ctx.staged_payloads, ctx.arrays, ctx.submit_n,
@@ -2060,6 +2132,7 @@ class RaftNode:
         self._hops_scan(ctx)
         ctx.staged_payloads = ctx.arrays = None
 
+        st.enter("send", observe=False)
         self.dispatcher.warm_mirror(G)
         before = self.dispatcher.applied_frontier(G)
         groups = self._worker_groups
@@ -2088,15 +2161,14 @@ class RaftNode:
         after = self.dispatcher.applied_frontier(G)
         self.metrics["applies"] += int((after - before).sum())
         self.metrics["commits"] = int(ctx.commit.astype(np.int64).sum())
-        _t4 = time.perf_counter()
+        st.enter("reads")
 
         self._harvest_reads(ctx.info)
         self._serve_reads(after)
-        _t5 = time.perf_counter()
+        st.enter("maintain")
 
         self._maintain(after, ctx.base, ctx.term)
         self._snapshot_requests(ctx.info, ctx.base)
-        _t6 = time.perf_counter()
 
         m = self.metrics
         # Stage times report the BARRIER (max-across-workers) cost — the
@@ -2106,8 +2178,6 @@ class RaftNode:
         m.observe("tick_stage_fsync_s", max(r[1] for r in res_a))
         m.observe("tick_stage_send_s", max(r[1] for r in res_b))
         m.observe("tick_stage_apply_s", max(r[2] for r in res_b))
-        m.observe("tick_stage_reads_s", _t5 - _t4)
-        m.observe("tick_stage_maintain_s", _t6 - _t5)
         for k in range(W):
             m.observe("stripe_busy_s",
                       res_a[k][0] + res_a[k][1]
@@ -2134,7 +2204,11 @@ class RaftNode:
         any native staging failure is an IOError from the store — same
         failure surface as a Python-path write error."""
         G = self.cfg.n_groups
-        _t0 = time.perf_counter()
+        # One C call stages AND fsyncs: the span is raft.wal for both,
+        # and the two histograms split it by the engine's own fsync time
+        # (observe=False).
+        st = self._stages
+        _t0 = st.enter("wal", observe=False)
         prep = self._persist_prepare(
             ctx.info, ctx.term, ctx.voted, ctx.leader, ctx.base,
             ctx.base_term, ctx.staged_payloads, ctx.arrays, ctx.submit_n,
@@ -2161,7 +2235,7 @@ class RaftNode:
         # The native call is done — the arena views the spans pinned are
         # no longer referenced from C.
         ctx.staged_payloads = ctx.arrays = None
-        _t1 = time.perf_counter()
+        _t1 = st.enter("send")
 
         held = self._stash_outbox_sections(
             ctx.outbox, deferred=ctx.deferred_ae,
@@ -2170,7 +2244,7 @@ class RaftNode:
             self._held_sections.setdefault(p, []).extend(secs)
         if not defer_send:
             self._flush_sends()
-        _t3 = time.perf_counter()
+        st.enter("apply")
         if self._lat_tick:
             self._lat_stamp(SENT)
 
@@ -2181,25 +2255,20 @@ class RaftNode:
         after = self.dispatcher.applied_frontier(G)
         self.metrics["applies"] += int((after - before).sum())
         self.metrics["commits"] = int(ctx.commit.astype(np.int64).sum())
-        _t4 = time.perf_counter()
+        st.enter("reads")
 
         self._harvest_reads(ctx.info)
         self._serve_reads(after)
-        _t5 = time.perf_counter()
+        st.enter("maintain")
 
         self._maintain(after, ctx.base, ctx.term)
         self._snapshot_requests(ctx.info, ctx.base)
-        _t6 = time.perf_counter()
 
         m = self.metrics
         # wal_s is everything up to the barrier minus the C-measured
         # fsync share: prepare + span assembly + the native stage.
         m.observe("tick_stage_wal_s", max(0.0, (_t1 - _t0) - fs_s))
         m.observe("tick_stage_fsync_s", fs_s)
-        m.observe("tick_stage_send_s", _t3 - _t1)
-        m.observe("tick_stage_apply_s", _t4 - _t3)
-        m.observe("tick_stage_reads_s", _t5 - _t4)
-        m.observe("tick_stage_maintain_s", _t6 - _t5)
 
     def _native_blob_fn(self, cols, starts, ns):
         """codec ``payload_blob_fn``: native AE blob pack (None → the

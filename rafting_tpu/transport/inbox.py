@@ -27,17 +27,37 @@ context/member/Leader.java:224-227).
 AppendEntries payload bytes ride with their frame and are staged here until
 the engine accepts the entries (StepInfo.appended_from/to), at which point
 the runtime moves them into the durable LogStore.
+
+The backlog is measured where it stands: every slice is stamped at
+``merge()``, and ``drain()`` records (under the queue lock) how long each
+slice it popped had waited, how many slices each source still holds after
+the pop, and how many were collapsed or dropped.  ``take_stats()`` hands
+the record to the draining thread — the node's tick thread, which folds it
+into its registry; reader threads never touch a registry.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
-from typing import Deque, Dict, Tuple
+from dataclasses import dataclass, field
+from typing import Deque, Dict, List, Tuple
 
 import numpy as np
 
 from .codec import KIND_FIELDS
+
+
+@dataclass
+class InboxStats:
+    """What the drains since the last ``take_stats()`` saw."""
+    waits_s: List[float] = field(default_factory=list)   # merge -> drain,
+    #                                             one per slice popped
+    depth: Dict[int, int] = field(default_factory=dict)  # src -> slices
+    #                                   left queued after the last pop
+    collapsed: int = 0        # slices merged into one by a collapse
+    dropped: int = 0          # slices refused at MAX_QUEUED_SLICES
 
 
 class InboxAccumulator:
@@ -48,9 +68,11 @@ class InboxAccumulator:
         self.cfg = cfg
         self.template = template
         self._lock = threading.Lock()
-        # src -> FIFO of (fields, payloads) slices, fields in the sparse
-        # codec.unpack_slice format: field -> (group cols, values).
+        # src -> FIFO of (fields, payloads, arrival) slices, fields in the
+        # sparse codec.unpack_slice format: field -> (group cols, values);
+        # arrival is the merge() instant on this process's perf_counter.
         self._queues: Dict[int, Deque[tuple]] = {}
+        self._stats = InboxStats()      # guarded by _lock
 
     def merge(self, src: int,
               fields: Dict[str, Tuple[np.ndarray, np.ndarray]],
@@ -62,8 +84,9 @@ class InboxAccumulator:
             if q is None:
                 q = self._queues[src] = deque()
             if len(q) >= self.MAX_QUEUED_SLICES:
+                self._stats.dropped += 1
                 return   # = network loss; sender's resend timeout recovers
-            q.append((fields, payloads))
+            q.append((fields, payloads, time.perf_counter()))
 
     def drain(self) -> Tuple[Dict[str, np.ndarray],
                              Dict[Tuple[int, int], Tuple[int, list]]]:
@@ -83,20 +106,33 @@ class InboxAccumulator:
         }
         payloads: Dict[Tuple[int, int], Tuple[int, list]] = {}
         with self._lock:
+            st = self._stats
+            now = time.perf_counter()
             for src, q in self._queues.items():
                 if not q:
+                    st.depth[src] = 0
                     continue
                 if len(q) > self.COLLAPSE_BACKLOG:
                     batch, q_new = list(q), deque()
                     self._queues[src] = q_new
+                    st.collapsed += len(batch)
                 else:
                     batch = [q.popleft()]
-                for fields, pl in batch:
+                st.depth[src] = len(self._queues[src])
+                for fields, pl, arrived in batch:
+                    st.waits_s.append(now - arrived)
                     for name, (cols, vals) in fields.items():
                         arrays[name][src, cols] = vals
                     for g, run in pl.items():
                         payloads[(src, g)] = run
         return arrays, payloads
+
+    def take_stats(self) -> InboxStats:
+        """The record of the drains since the last call; the caller owns
+        it.  Call from the draining thread, right after ``drain()``."""
+        with self._lock:
+            st, self._stats = self._stats, InboxStats()
+        return st
 
     @property
     def has_traffic(self) -> bool:

@@ -19,7 +19,12 @@ Design:
   every hot-path hook is one attribute-is-None check.
 * **Spans** stamp wall-clock marks through the commit path:
   ``submitted → offered → staged → fsynced → sent → committed →
-  applied → acked`` (writes) and ``submitted → served`` (reads).  A
+  applied → acked`` (writes) and ``submitted → offered → served``
+  (reads: ``offered`` is the promotion of the batch from the group's
+  waiting queue into its one offer slot, so ``submitted → offered`` is
+  the wait for that slot).  Every stamp also records the node's tick
+  number (``Span.n``), the axis the tick loop's stage spans
+  (utils/profiling.py StageSpans) carry: (node, tick) joins the two.  A
   span that dies before its ack — leadership loss, storage fault, lane
   close — retires with ``outcome-unknown`` (or ``refused`` for marked
   pre-log refusals) and contributes NO latency sample: a crashed span
@@ -36,8 +41,9 @@ Design:
   a 100k-group burst cannot turn the trace plane into the workload.
 
 Histograms land in the node's Metrics registry as ``lat_<pair>_s``
-per phase pair plus ``lat_e2e_s`` / ``lat_read_e2e_s`` end-to-end, so
-/metrics exposition and /latency percentiles come from one source.
+per phase pair plus ``lat_e2e_s`` / ``lat_read_e2e_s`` end-to-end (reads
+split into ``lat_read_queue_s`` + ``lat_read_confirm_s``), so /metrics
+exposition and /latency percentiles come from one source.
 """
 
 from __future__ import annotations
@@ -90,7 +96,7 @@ class Span:
     no locking — a torn read can only be observed by the harvester for
     an outcome-unknown span, which reports no latency anyway."""
 
-    __slots__ = ("seq", "kind", "k", "group", "idx", "tick", "t",
+    __slots__ = ("seq", "kind", "k", "group", "idx", "tick", "t", "n",
                  "outcome", "tr")
 
     def __init__(self, seq: int, kind: str, k: int):
@@ -103,6 +109,7 @@ class Span:
         #                           shared axis flight-recorder events
         #                           and worker-util intervals plot on
         self.t = [0.0] * 9
+        self.n = [-1] * 9         # the node's tick number at each stamp
         self.outcome: Optional[str] = None   # None=in flight, "ok",
         #                                      "unknown", "refused"
         self.tr: Optional["LatencyTracer"] = None   # set by make_span —
@@ -111,16 +118,23 @@ class Span:
     def mark(self, phase: int) -> None:
         if self.t[phase] == 0.0:
             self.t[phase] = time.perf_counter()
+            tr = self.tr
+            if tr is not None:
+                self.n[phase] = tr.tick
 
     def to_dict(self) -> dict:
         """Per-phase breakdown for /latency and save_dump meta: deltas
-        from ``submitted`` (seconds), only for stamped phases."""
+        from ``submitted`` (seconds) and the node's tick number at each
+        stamp, only for stamped phases."""
         t0 = self.t[SUBMITTED]
         phases = {PHASE_NAMES[i]: round(self.t[i] - t0, 9)
                   for i in range(1, 9) if self.t[i] > 0.0}
+        ticks = {PHASE_NAMES[i]: self.n[i]
+                 for i in range(9) if self.t[i] > 0.0}
         return {"seq": self.seq, "kind": self.kind, "group": self.group,
                 "idx": self.idx, "k": self.k, "tick": self.tick,
-                "outcome": self.outcome or "in-flight", "phases": phases}
+                "outcome": self.outcome or "in-flight", "phases": phases,
+                "ticks": ticks}
 
 
 class TxnSpan:
@@ -184,6 +198,9 @@ class LatencyTracer:
         self._rings_lock = threading.Lock()
         self._rings: List[deque] = []
         self._tls = threading.local()
+        # The node's tick number, written by the tick thread at the start
+        # of every tick and read by whichever thread stamps a span.
+        self.tick = 0
         # Tick-thread-only state.
         self.pending_commit: List[Span] = []   # offered, awaiting commit
         self.recent: deque = deque(maxlen=recent)
@@ -326,6 +343,11 @@ class LatencyTracer:
                 if sp.kind == "r":
                     if t[SERVED] > 0.0:
                         observe("lat_read_e2e_s", t[SERVED] - t[SUBMITTED])
+                        if t[OFFERED] > 0.0:
+                            observe("lat_read_queue_s",
+                                    t[OFFERED] - t[SUBMITTED])
+                            observe("lat_read_confirm_s",
+                                    t[SERVED] - t[OFFERED])
                     continue
                 for name, a, b in PHASE_PAIRS:
                     if t[a] > 0.0 and t[b] > 0.0:
@@ -383,7 +405,8 @@ class LatencyTracer:
             "phases": phases,
             "recent": [sp.to_dict() for sp in list(self.recent)],
         }
-        for key in ("lat_e2e_s", "lat_read_e2e_s"):
+        for key in ("lat_e2e_s", "lat_read_e2e_s", "lat_read_queue_s",
+                    "lat_read_confirm_s"):
             h = metrics._histograms.get(key)
             if h is not None and h.n:
                 doc[key[:-2]] = h.summary() | {"p999": h.quantile(0.999)}

@@ -2,22 +2,23 @@
 logback lines only, support/RaftConfig.java:137-141 — so the TPU build adds
 JAX profiler integration from the start).
 
-Two entry points:
-
 * :func:`device_trace` — context manager wrapping a measurement region in
   ``jax.profiler.trace`` so XLA device timelines land in TensorBoard format
-  (the benchmark uses this around its measure loop via BENCH_PROFILE_DIR).
-* :meth:`TickProfiler` — bounded capture of a live node's tick loop: each
-  tick becomes a ``StepTraceAnnotation`` so host phases and the fused device
-  step line up on one timeline.  Armed via RaftNode.profile_ticks() or the
-  RAFT_PROFILE_DIR environment variable.
+  (bench.py uses it around its measure loop via BENCH_PROFILE_DIR).
+* :class:`StageSpans` — names the phase a node's tick thread is in.  At
+  each phase boundary it observes ``tick_stage_<name>_s`` in the node's
+  registry and, while ANY ``jax.profiler`` session runs (whoever started
+  it), emits a ``raft.<name>`` span carrying ``node`` and ``tick`` on
+  ``/host:CPU`` of that session, on the device trace's clock.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
-from typing import Optional
+import time
+from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 @contextlib.contextmanager
@@ -31,74 +32,62 @@ def device_trace(log_dir: Optional[str]):
         yield
 
 
-# jax.profiler traces are PROCESS-global (start_trace raises if one is
-# already running), so at most one TickProfiler may hold a trace at a time —
-# in-process multi-node harnesses construct several RaftNodes, and with
-# RAFT_PROFILE_DIR set each would otherwise try to arm.
-_TRACE_OWNER: list = []
+class StageSpans:
+    """The phase the tick thread is in, one at a time: ``enter`` ends the
+    open phase and starts the next at the same instant, so the phases of a
+    loop period tile it with no gap and no overlap.  Spans are siblings —
+    no parent span: a trace reducer that labels a device gap with the
+    host span of greatest overlap would hand every gap to the parent.
+    Tick thread only (the registry's single writer).  With no profiler
+    session a boundary costs one flag test beside its histogram sample."""
 
+    __slots__ = ("_metrics", "_node", "tick", "spent", "_name", "_t0",
+                 "_observe", "_span")
 
-class TickProfiler:
-    """Capture N ticks of a node runtime into a profiler trace.
+    def __init__(self, metrics, node_id: int):
+        self._metrics = metrics
+        self._node = int(node_id)
+        self.tick = 0
+        # Seconds per phase since begin(): what the node sums into the
+        # composite stages (dispatch, scan_wait) and the tick's total.
+        self.spent: Dict[str, float] = {}
+        self._name: Optional[str] = None
+        self._t0 = 0.0
+        self._observe = True
+        self._span = None
 
-    Start/stop are explicit and bounded (a trace left running grows without
-    bound); each tick is annotated so per-phase host time and device time
-    correlate in the viewer.  Only the first profiler to arm in a process
-    captures — later arms are silently skipped (the trace is process-global).
-    """
+    def begin(self, tick: int) -> None:
+        """A new tick: spans from here carry its number."""
+        self.tick = tick
+        self.spent.clear()
 
-    def __init__(self):
-        self._remaining = 0
-        self._active = False
+    def enter(self, name: str, observe: bool = True) -> float:
+        """Boundary: the open phase ends, ``name`` starts.  Returns the
+        instant.  ``observe=False`` emits the span but leaves the
+        histogram to the caller (host phases that split one call's time
+        by the engine's own measurement)."""
+        now = self.leave()
+        self._name, self._t0, self._observe = name, now, observe
+        if TraceAnnotation.is_enabled():
+            self._span = TraceAnnotation("raft." + name, node=self._node,
+                                         tick=self.tick)
+            self._span.__enter__()
+        return now
 
-    def arm(self, log_dir: str, n_ticks: int = 64) -> None:
-        if self._active or not log_dir or n_ticks <= 0 or _TRACE_OWNER:
-            return
-        import jax
-        os.makedirs(log_dir, exist_ok=True)
-        try:
-            jax.profiler.start_trace(log_dir)
-        except RuntimeError:  # someone else (outside this module) is tracing
-            return
-        _TRACE_OWNER.append(self)
-        self._remaining = n_ticks
-        self._active = True
+    def total(self, *names: str) -> float:
+        """Seconds spent in these phases since begin()."""
+        return sum(self.spent.get(n, 0.0) for n in names)
 
-    @classmethod
-    def from_env(cls) -> "TickProfiler":
-        """Armed from RAFT_PROFILE_DIR / RAFT_PROFILE_TICKS if set."""
-        p = cls()
-        d = os.environ.get("RAFT_PROFILE_DIR", "")
-        if d:
-            p.arm(d, int(os.environ.get("RAFT_PROFILE_TICKS", "64")))
-        return p
-
-    def step(self, tick_no: int):
-        """Context for one tick; stops the trace after the armed budget."""
-        if not self._active:
-            return contextlib.nullcontext()
-        import jax
-        return jax.profiler.StepTraceAnnotation("raft_tick", step_num=tick_no)
-
-    def after_tick(self) -> None:
-        if not self._active:
-            return
-        self._remaining -= 1
-        if self._remaining <= 0:
-            import jax
-            jax.profiler.stop_trace()
-            self._release()
-
-    def _release(self) -> None:
-        self._active = False
-        if self in _TRACE_OWNER:
-            _TRACE_OWNER.remove(self)
-
-    def close(self) -> None:
-        if self._active:
-            import jax
-            try:
-                jax.profiler.stop_trace()
-            except RuntimeError:
-                pass
-            self._release()
+    def leave(self) -> float:
+        """End the open phase (if any) with none following."""
+        span, self._span = self._span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+        now = time.perf_counter()
+        name, self._name = self._name, None
+        if name is not None:
+            dt = now - self._t0
+            self.spent[name] = self.spent.get(name, 0.0) + dt
+            if self._observe:
+                self._metrics.observe(f"tick_stage_{name}_s", dt)
+        return now
