@@ -83,6 +83,31 @@ log = logging.getLogger(__name__)
 # consumers only read it.
 _NOOP_LENS = np.zeros(1, np.uint32)
 
+# The stages of one host phase (_host_phase), as StageSpans names them.
+HOST_STAGES = ("wal", "fsync", "send", "apply", "reads", "maintain")
+# A fetched tick settles in its own period when its host phase fits this
+# many times into what is left of the period: once for the phase itself,
+# once more for the tail, the flush and a phase slower than any seen.
+SETTLE_MARGIN = 2.0
+# The host phase is taken to cost what the costliest of this many recent
+# ones did: one slow phase is remembered for that many ticks and then
+# forgotten whatever its size, and a cost that hovers at the limit reads
+# as its upper envelope, so the order does not flip tick by tick.
+HOST_COST_MEMORY = 8
+
+
+def settles_now(now: float, due: Optional[float], cost: float) -> bool:
+    """Whether a pipelined tick, just fetched at ``now``, runs its own
+    host phase in this period (True) or stashes it for the next tick to
+    run under that tick's scan (False).  ``due`` is when the loop is to
+    start its next tick, ``cost`` what a host phase has lately taken
+    (seconds, the clock of ``now``).  A caller that ticks with no loop
+    observes no deadline (``due`` None) and keeps the order the node was
+    constructed with: pipelined stays overlapped."""
+    if due is None:
+        return False
+    return now + SETTLE_MARGIN * cost <= due
+
 
 class BatchSubmit:
     """One future for a whole batch of commands (resolves to the list of
@@ -243,10 +268,11 @@ class _TickCtx:
         # device refs (dispatch) -> host arrays (fetch)
         "info", "outbox", "term", "voted", "role", "leader", "commit",
         "base", "base_term", "heat",
-        # Eager-send bookkeeping (pipelined mode): per-peer AE columns
+        # Eager-send bookkeeping (overlapped ticks): per-peer AE columns
         # whose payloads were not staged at fetch time — the host phase
-        # packs exactly these after the barrier.  None = pipeline off
-        # (every kind packs post-fsync, the classic send).
+        # packs exactly these after the barrier.  None = nothing left
+        # eagerly (serial and settled ticks: every kind packs post-fsync,
+        # the classic send).
         "deferred_ae",
     )
 
@@ -299,14 +325,17 @@ class RaftNode:
         ``serializer``: CmdSerializer for command/result encoding across
         the leader-forward relay (api/serial.py; reference CmdSerializer,
         support/serial/CmdSerializer.java:11-24) — default JSON.
-        ``pipeline``: run the double-buffered durable pipeline (see
+        ``pipeline``: build the double-buffered durable pipeline (see
         ``tick``).  Default: env RAFT_PIPELINE if set (0/false = serial),
-        else ON exactly when the engine runs on an accelerator backend —
-        there the fused scan is the dominant tick cost and overlapping it
-        with the host phase pays; on the CPU backend the scan is a small
-        slice of a host-bound tick, so the pipeline's +1-tick message
-        latency costs more than the overlap saves (measured 0.84x at 32k
-        groups — see BENCH_PIPELINE in bench_runtime.py for the A/B).
+        else ON exactly when the engine runs on an accelerator backend.
+        A pipelined node under its own loop (``start``) then chooses the
+        order per tick from the loop's deadline: a fetched tick whose
+        host phase fits in what is left of the period runs it there (the
+        serial order, nothing deferred), and only a tick with no such
+        room is overlapped with the next scan, one tick later for every
+        message and acknowledgement it carries (``settles_now``).  A
+        caller that drives ``tick()`` itself has no deadline and gets the
+        overlapped order on every tick.
         ``wal_shards``: stripe count for the default WAL store (ignored
         when ``store`` is passed) — default from env RAFT_WAL_SHARDS,
         else 4.
@@ -762,6 +791,11 @@ class RaftNode:
         # span in whatever jax.profiler session is running.
         self._stages = StageSpans(self.metrics, node_id)
         self._tick_due: Optional[float] = None   # _run: next start due
+        # Seconds per host phase over the last HOST_COST_MEMORY ticks that
+        # ran one (the stage spans' own sum): what settles_now() weighs
+        # against the time left until _tick_due.
+        self._host_costs: deque = deque(maxlen=HOST_COST_MEMORY)
+        self._host_runs = 0                      # host phases this tick
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # Double-buffered pipeline state: the fetched-but-not-yet-host-
@@ -786,10 +820,15 @@ class RaftNode:
                                    "n_shards", 1))
         self.metrics.gauge("host_workers", self._w_eff)
         self.metrics.gauge("native_host", int(self._native_host))
-        # Eager leader sends (pipelined mode): AE frames released right
+        # Eager leader sends (overlapped ticks): AE frames released right
         # after fetch, ahead of the tick's own fsync (safe — commit only
         # counts fsynced self-matches via HostInbox.durable_tail).
         self.metrics["eager_sends"] += 0
+        # Ticks started, and those of them whose own host phase ran in
+        # their own period (serial ticks, and pipelined ticks that
+        # settles_now() found room for).
+        self.metrics["ticks"] += 0
+        self.metrics["ticks_settled"] += 0
 
     # ------------------------------------------------------------------ API
 
@@ -1338,29 +1377,54 @@ class RaftNode:
 
         Pipelined mode (the durable pipeline): this tick's fused scan is
         DISPATCHED first (JAX async dispatch — no blocking transfer), the
-        PREVIOUS tick's host phase (WAL staging, the fsync barrier,
-        outbox release, applies, read serving, maintenance) runs while
-        the device computes, and only then are this tick's results
-        fetched.  Safety holds because (a) a tick's outbox and futures
-        are released only inside its own host phase, strictly after its
-        fsync barrier — ack-after-fsync, exactly as serial — and (b) the
-        scan's commit quorum counts our own match only up to the FSYNCED
-        durable tail fed through ``HostInbox.durable_tail``, so a scan
-        racing the previous tick's fsync can never self-ack an un-fsynced
-        range into a commit.  Pipeline barriers (lifecycle changes,
-        snapshot installs) drain the pending tick first; both are rare.
+        host phase of a tick still pending from before (WAL staging, the
+        fsync barrier, outbox release, applies, read serving,
+        maintenance) runs while the device computes, and then this
+        tick's results are fetched.  What follows is chosen per tick from
+        what the loop observes (``settles_now``):
+
+        * SETTLED — the time left until the loop is due to start its next
+          tick holds this tick's host phase with room to spare: the phase
+          runs now, in serial order (fetch, persist+fsync, one flush,
+          apply, reads), nothing stays pending, and the next tick finds
+          nothing to overlap.  No fetched tick then sleeps through the
+          rest of a period waiting for the next tick to process it.
+        * OVERLAPPED — there is no such room (a node whose host work
+          fills its period), or no deadline at all (a caller that drives
+          ``tick()`` itself: ``LocalCluster``, ``chip_smoke.py``, the
+          lock-step tests): the tick is stashed in ``_pending``, its
+          AppendEntries leave eagerly, and its host phase runs under the
+          NEXT tick's scan — the double-buffered order the pipeline was
+          built for.
+
+        The tick that goes from overlapping to settling runs two host
+        phases, the pending tick's and then its own, each behind its own
+        barrier, and flushes once.  Safety holds in either order because
+        (a) a tick's acknowledgements, AE-responses, votes, served reads
+        and futures are released only inside its own host phase, strictly
+        after its fsync barrier — ack-after-fsync, exactly as serial —
+        and (b) the scan's commit quorum counts our own match only up to
+        the FSYNCED durable tail fed through ``HostInbox.durable_tail``,
+        so a scan racing the previous tick's fsync can never self-ack an
+        un-fsynced range into a commit.  Pipeline barriers (lifecycle
+        changes, snapshot installs) drain the pending tick first; both
+        are rare.
         """
         st = self._stages
         # Every instant of tick() belongs to one named phase (the stage
         # histograms and raft.<name> profiler spans): dispatch_intake,
         # dispatch_upload, dispatch_enqueue (_dispatch); wal, fsync, send,
         # apply, reads, maintain (_host_phase); scan_device, scan_fetch,
-        # mirrors (_fetch); eager_send (pipelined mode); tail.  _run adds
-        # wait.  Pipelined order: dispatch, host phase, fetch, eager_send.
+        # mirrors (_fetch); eager_send (overlapped ticks); tail.  _run adds
+        # wait.  Pipelined order: dispatch, pending host phase, fetch, then
+        # own host phase (settled) or eager_send (overlapped).
         st.begin(self.ticks)
+        self._host_runs = 0
         if self._lat is not None:
             self._lat.tick = self.ticks
         _tick_t0 = st.enter("dispatch_intake")
+        m = self.metrics
+        m["ticks"] += 1
         ctx = self._dispatch()
         if self.pipeline:
             prev, self._pending = self._pending, None
@@ -1369,31 +1433,47 @@ class RaftNode:
                     self._host_phase(prev, defer_send=True)
             finally:
                 # The dispatched tick must never be dropped: even if
-                # the previous host phase failed (the loop in _run
+                # the pending host phase failed (the loop in _run
                 # keeps ticking through exceptions), fetch and stash
                 # it so its appends are persisted next tick —
                 # otherwise the device state advances past entries
                 # whose payloads the WAL never saw.
                 self._fetch(ctx)
                 self._pending = ctx
-                # Eager leader sends: THIS tick's AE/heartbeat frames
-                # leave now, ahead of this tick's own fsync (which
-                # runs next tick).  Safe because commit counts our
-                # self-match only up to the fsynced durable tail
-                # (HostInbox.durable_tail); AE-responses, votes and
-                # client futures stay strictly behind the fsync in
-                # the deferred host phase.  Pending is stashed FIRST
-                # so a send failure can't drop the tick.
-                st.enter("eager_send")
-                self._eager_send(ctx)
-                self._flush_sends()
+            settled = settles_now(
+                time.perf_counter(), self._tick_due,
+                max(self._host_costs, default=0.0))
         else:
             self._fetch(ctx)
+            settled = True
+        if settled:
+            # The serial order from here, for a serial node and for a
+            # pipelined one with room in this period.  Everything of
+            # this tick is packed behind its own barrier (deferred_ae
+            # stays None: no eager pack), and the host phase's one
+            # flush carries whatever a pending tick's phase above held
+            # back with it — one slice per peer per tick, as the peers'
+            # inbox accumulators drain them.
+            self._pending = None
             self._host_phase(ctx)
+            m["ticks_settled"] += 1
+        else:
+            # Eager leader sends: THIS tick's AE/heartbeat frames
+            # leave now, ahead of this tick's own fsync (which
+            # runs next tick).  Safe because commit counts our
+            # self-match only up to the fsynced durable tail
+            # (HostInbox.durable_tail); AE-responses, votes and
+            # client futures stay strictly behind the fsync in
+            # the deferred host phase.
+            st.enter("eager_send")
+            self._eager_send(ctx)
+            self._flush_sends()
         # tick_latency_s ends here, where it always has; the tail below
         # (admission, txn, span harvest, health) is the stage after it.
-        m = self.metrics
         m.observe("tick_latency_s", st.enter("tail") - _tick_t0)
+        if self._host_runs:
+            self._host_costs.append(
+                st.total(*HOST_STAGES) / self._host_runs)
         m.observe("tick_stage_dispatch_s", st.total(
             "dispatch_intake", "dispatch_upload", "dispatch_enqueue"))
         m.observe("tick_stage_scan_wait_s",
@@ -1756,10 +1836,10 @@ class RaftNode:
 
     def _fetch(self, ctx: _TickCtx) -> None:
         """Pull the dispatched scan's results to the host (the pipeline's
-        only blocking point) and refresh the per-tick mirrors.  In
-        pipelined mode this runs AFTER the previous tick's host phase, so
-        the wait here is whatever device time the host work did not
-        cover."""
+        only blocking point) and refresh the per-tick mirrors.  After an
+        overlapped tick this runs AFTER that tick's host phase, so the
+        wait here is whatever device time the host work did not cover;
+        after a settled tick it is the whole step."""
         cfg = self.cfg
         st = self._stages
         # The wait is split where the work happens: scan_device is the
@@ -1889,14 +1969,16 @@ class RaftNode:
         """One fetched tick's host work: WAL staging, THE fsync barrier,
         outbox release, applies + future completion, read serving,
         maintenance.  Everything that acknowledges the tick runs here,
-        strictly after its barrier — in pipelined mode this whole phase
-        overlaps the next tick's device scan.
+        strictly after its barrier — the phase of an overlapped tick
+        runs under the next tick's device scan, that of a settled tick
+        right behind its own fetch.
 
         ``defer_send``: pack the outbox but HOLD the per-peer sections in
-        ``_held_sections`` instead of flushing frames — the pipelined
+        ``_held_sections`` instead of flushing frames — a pipelined
         tick() flushes exactly once per wall tick, after the eager AE
-        pack, so each peer receives ONE combined slice per tick (the
-        inbox accumulator drains one slice per source per tick).
+        pack (overlapped) or inside its own host phase (settled), so
+        each peer receives ONE combined slice per tick (the inbox
+        accumulator drains one slice per source per tick).
 
         With ``host_workers > 1`` the phase fans out across the striped
         worker pool (``_host_phase_striped``); membership-config ticks
@@ -1911,6 +1993,7 @@ class RaftNode:
         policy knows exactly which per-group tails a failed barrier
         left unconfirmed."""
         pre_tail = self._durable_tail_m.copy()
+        self._host_runs += 1
         try:
             try:
                 if self._native_host and not self._poisoned_stripes:
@@ -2579,7 +2662,7 @@ class RaftNode:
                 # transient) and at the TAIL once LIFO kicks in, so
                 # check both ends.  Only untouched batches (taken == 0)
                 # are expirable; never entries the device accepted —
-                # nor, in pipelined mode, entries it may yet accept: the
+                # nor, in an overlapped tick, entries it may yet accept: the
                 # tick dispatched after this one already carries offers
                 # against this queue, and the device accepts by COUNT,
                 # so the queue must keep at least that many entries.
@@ -3440,7 +3523,7 @@ class RaftNode:
         return held
 
     def _eager_send(self, ctx: _TickCtx) -> None:
-        """Pipelined mode: pack THIS tick's AE sections right after
+        """Overlapped ticks: pack THIS tick's AE sections right after
         fetch, ahead of the tick's own fsync (which runs inside next
         tick's host phase).  Safe for AE only: the commit rule counts
         our own match at min(log.last, durable_tail) (core/step.py), so
@@ -3481,11 +3564,13 @@ class RaftNode:
 
     def _flush_sends(self) -> None:
         """Assemble every peer's held sections into ONE MSGS frame and
-        release it.  The single per-tick flush point: in pipelined mode
-        a peer's frame combines the previous tick's post-fsync sections
-        with this tick's eager AE sections (eager last — for a lane
-        duplicated across sections, unpack's scatter is last-wins, so
-        the newer AE stands).
+        release it.  The single per-tick flush point: in an overlapped
+        tick a peer's frame combines the previous tick's post-fsync
+        sections with this tick's eager AE sections; in the tick that
+        goes from overlapping to settling, the pending tick's post-fsync
+        sections with this tick's own (the newer last either way — for a
+        lane duplicated across sections, unpack's scatter is last-wins,
+        so the newer message stands).
 
         Hop-tracing sideband: pending HOPS requests/echoes piggyback on
         the same send_slice blob (FrameReader parses concatenated

@@ -145,6 +145,41 @@ def test_host_tier_metrics_on_exposition(tmp_path, monkeypatch):
         c.close()
 
 
+@pytest.mark.parametrize("pipeline,due,share", [
+    (False, None, 1.0), (True, None, 0.0), (True, float("inf"), 1.0),
+], ids=["serial", "overlapped", "settled"])
+def test_ticks_settled_on_exposition(tmp_path, pipeline, due, share):
+    """ISSUE 25 satellite: how often the tick loop settles a tick in its
+    own period is on /metrics from boot — `ticks_settled` beside `ticks`,
+    both counters — and reads 1 for a serial node, 0 for a pipelined node
+    with no deadline, 1 for a pipelined node whose deadline has room."""
+    from rafting_tpu.core.types import EngineConfig
+    from rafting_tpu.testkit.harness import LocalCluster
+
+    cfg = EngineConfig(n_groups=4, n_peers=3, log_slots=16, batch=4,
+                       max_submit=4, election_ticks=6, heartbeat_ticks=2,
+                       rpc_timeout_ticks=5)
+    c = LocalCluster(cfg, str(tmp_path), pipeline=pipeline)
+    try:
+        node = c.nodes[0]
+        boot = node.metrics.render_prometheus()
+        validate_exposition(boot)
+        assert "raft_ticks_total 0" in boot
+        assert "raft_ticks_settled_total 0" in boot
+        for n in c.nodes.values():
+            n._tick_due = due
+        c.tick(10)
+        text = node.metrics.render_prometheus()
+        validate_exposition(text)
+        assert "raft_ticks_total 10" in text
+        assert f"raft_ticks_settled_total {int(10 * share)}" in text
+        assert node.metrics["ticks"] == node.ticks == 10
+        doc = node.metrics.to_dict()["counters"]
+        assert doc["ticks_settled"] == share * doc["ticks"]
+    finally:
+        c.close()
+
+
 def test_membership_counters_on_metrics(tmp_path):
     """ISSUE 7 satellite: the membership-change and leadership-transfer
     counters render on /metrics from boot (zeros included), move with a

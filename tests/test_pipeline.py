@@ -18,6 +18,7 @@ import pytest
 from rafting_tpu.core.types import EngineConfig, LEADER
 from rafting_tpu.log.store import LogStore, restore_raft_state
 from rafting_tpu.log.wal import native_available
+from rafting_tpu.runtime.node import SETTLE_MARGIN, settles_now
 from rafting_tpu.snapshot.policy import MaintainAgreement
 from rafting_tpu.testkit.fixtures import NullProvider
 from rafting_tpu.testkit.harness import LocalCluster
@@ -25,6 +26,38 @@ from rafting_tpu.testkit.harness import LocalCluster
 CFG = EngineConfig(n_groups=4, n_peers=3, log_slots=32, batch=4,
                    max_submit=4, election_ticks=10, heartbeat_ticks=3,
                    rpc_timeout_ticks=8)
+
+# A loop deadline as a manually ticked node sees it (`_run` keeps
+# `_tick_due`; nobody moves it between manual ticks): one that always has
+# room for the host phase, one that never has, and none at all.
+ROOM, NO_ROOM, NO_DEADLINE = float("inf"), float("-inf"), None
+
+
+def _give_deadline(c: LocalCluster, due) -> None:
+    for node in c.nodes.values():
+        node._tick_due = due
+
+
+def _spy_sends(c: LocalCluster):
+    """Count `transport.send_slice` calls per (source, peer); the caller
+    clears the Counter between rounds."""
+    from collections import Counter
+    sends = Counter()
+    for i, node in c.nodes.items():
+        def spy(peer, blob, _i=i, _orig=node.transport.send_slice):
+            sends[(_i, peer)] += 1
+            return _orig(peer, blob)
+        node.transport.send_slice = spy
+    return sends
+
+
+def _spy_events(node, events: list) -> None:
+    """Log a node's completed barriers and the frames it hands out."""
+    barrier_ok, sends = node._barrier_ok, node.transport.send_slice
+    node._barrier_ok = lambda: (events.append(("barrier",)),
+                                barrier_ok())[1]
+    node.transport.send_slice = lambda p, blob: (
+        events.append(("send", p)), sends(p, blob))[1]
 
 
 # ---------------------------------------------------------------- crash window
@@ -57,38 +90,72 @@ def test_late_shed_keeps_offers_riding_the_dispatched_tick(tmp_path):
         c.close()
 
 
-def test_crash_between_dispatch_and_fsync_completes_nothing(tmp_path):
-    """Kill the node inside the pipeline's overlap window — tick N's scan
-    accepted entries and tick N+1 may already be dispatched, but tick N's
-    host phase (WAL staging + fsync) has NOT run.  The crash image must
-    recover to the pre-accept durable tail, and no submit future may have
-    completed for the un-fsynced range."""
+@pytest.mark.parametrize("due", [NO_DEADLINE, ROOM],
+                         ids=["overlapped", "settled"])
+def test_crash_between_dispatch_and_fsync_completes_nothing(tmp_path, due):
+    """Kill the node between a tick's dispatch and that tick's fsync —
+    the scan accepted entries, the host phase (WAL staging + fsync) has
+    NOT run.  In an overlapped tick that window lasts until the next
+    tick (which may already be dispatched); in a settled tick it is the
+    instant between fetch and the tick's own host phase.  The crash image
+    must recover to the pre-accept durable tail, no submit future may
+    have completed for the un-fsynced range, and nothing completes, and
+    no frame leaves inside the host phase, before its barrier."""
     c = LocalCluster(CFG, str(tmp_path), pipeline=True, wal_shards=2)
     try:
         lead = c.wait_leader(0)
         c.tick(5)
+        _give_deadline(c, due)
         node = c.nodes[lead]
         tail_before = int(node._durable_tail_m[0])
+        img = str(tmp_path / "crash-img")
 
         fut = node.submit_batch(0, [b"crash-%d" % k for k in range(3)])
-        # One lockstep round: the leader's scan accepts the batch, but in
-        # pipelined mode its host phase runs only NEXT tick — this is
-        # exactly the crash window.
+        events, seen = [], {}
+        fut.add_done_callback(lambda f: events.append(("done",)))
+        _spy_events(node, events)
+        host_phase = node._host_phase
+
+        def crash_window(ctx, defer_send=False):
+            acc = int(np.asarray(ctx.info.submit_acc)[0])
+            if acc and not seen:
+                # Fetched, not yet staged or fsynced: the crash window.
+                events.append(("accepted",))
+                seen.update(
+                    acc=acc, done=fut.done(),
+                    start=int(np.asarray(ctx.info.submit_start)[0]),
+                    tail=int(node._durable_tail_m[0]))
+                shutil.copytree(os.path.join(node.data_dir, "wal"), img)
+            return host_phase(ctx, defer_send)
+        node._host_phase = crash_window
+
+        # One lockstep round: the leader's scan accepts the batch.  An
+        # overlapped tick runs its host phase only NEXT tick; a settled
+        # one has run it by now, behind its own barrier.
         c.tick(1)
-        pend = node._pending
-        assert pend is not None, "pipelined node must hold a pending tick"
-        acc = int(np.asarray(pend.info.submit_acc)[0])
-        assert acc == 3, f"device should have accepted the batch, got {acc}"
-        start = int(np.asarray(pend.info.submit_start)[0])
+        if due is NO_DEADLINE:
+            pend = node._pending
+            assert pend is not None, "overlapped node must hold a pending tick"
+            assert int(np.asarray(pend.info.submit_acc)[0]) == 3
+            assert not seen, "host phase ran in the tick that accepted"
+            assert not fut.done(), \
+                "submit future completed before the range was fsynced"
+            assert int(node._durable_tail_m[0]) == tail_before
+            c.tick(1)
+        else:
+            assert node._pending is None, "a settled tick leaves none pending"
+        assert seen["acc"] == 3, f"device should have accepted the batch: {seen}"
+        start, acc = seen["start"], seen["acc"]
 
-        # The un-fsynced range must not be acknowledged in any way.
-        assert not fut.done(), \
-            "submit future completed before the range was fsynced"
-        assert int(node._durable_tail_m[0]) == tail_before
-
-        # Crash disk image: copy the WAL dir as it is at this instant.
-        img = str(tmp_path / "crash-img")
-        shutil.copytree(os.path.join(node.data_dir, "wal"), img)
+        # The un-fsynced range was not acknowledged in any way.
+        assert not seen["done"]
+        assert seen["tail"] == tail_before
+        # Behind the window, this tick's own barrier came first: no frame
+        # left between the accept and the barrier, and the future (which
+        # needs a quorum anyway) resolves only after it.
+        after = events[events.index(("accepted",)) + 1:]
+        assert after and after[0] == ("barrier",), events
+        assert int(node._durable_tail_m[0]) >= start + acc - 1
 
         # Recovery from the image: the durable tail excludes the whole
         # accepted-but-never-fsynced range.
@@ -109,7 +176,7 @@ def test_crash_between_dispatch_and_fsync_completes_nothing(tmp_path):
             if fut.done():
                 break
         assert fut.done() and len(fut.result(timeout=1)) == 3
-        assert int(node._durable_tail_m[0]) >= start + acc - 1
+        assert events.index(("done",)) > events.index(("barrier",))
     finally:
         c.close()
 
@@ -136,6 +203,221 @@ def test_close_drains_pending_tick(tmp_path):
             assert store.tail(0) >= end
         finally:
             store.close()
+    finally:
+        c.close()
+
+
+# ------------------------------------------------- settle or overlap, per tick
+
+
+@pytest.mark.parametrize("now,due,cost,settles", [
+    (10.0, None, 0.0, False),          # no deadline: as constructed
+    (10.0, None, 0.001, False),
+    (10.0, 10.5, 0.0, True),           # nothing measured yet, not late
+    (10.03, 10.5, 0.007, True),        # the cell: 7 ms of 470 left
+    (10.2, 11.0, 0.1, True),           # tick_ms 1000, 100 ms host phase
+    (10.04, 10.1, 0.05, False),        # fits once, not with the margin
+    (10.04, 10.1, 0.03, True),         # fits with the margin exactly
+    (10.0, 10.5, 0.6, False),          # a host phase longer than a period
+    (10.6, 10.5, 0.0, False),          # already past the next start
+    (10.5, 10.5, 0.0, True),
+], ids=["no-deadline", "no-deadline-cost", "first-tick", "cell",
+        "one-second-tick", "fits-once", "fits-margin", "over-period",
+        "late", "on-the-dot"])
+def test_settles_now_on_made_up_readings(now, due, cost, settles):
+    """The decision is a function of what the loop observes and nothing
+    else: no clock is read, no node is built."""
+    assert settles_now(now, due, cost) is settles
+    if due is not None:
+        # Monotone in the room: what settles with less room settles with
+        # more, and the margin is the whole of the rule.
+        assert settles_now(now, due + 1.0, cost) or not settles
+        assert settles == (now + SETTLE_MARGIN * cost <= due)
+
+
+CFG_HB1 = EngineConfig(n_groups=4, n_peers=3, log_slots=32, batch=4,
+                       max_submit=4, election_ticks=10, heartbeat_ticks=1,
+                       rpc_timeout_ticks=8)
+
+
+def _rounds_until(c, fut, limit=40) -> int:
+    for r in range(1, limit + 1):
+        c.tick(1)
+        if fut.done():
+            return r
+    raise AssertionError(f"not done in {limit} rounds")
+
+
+def test_deadline_with_room_commits_in_three_ticks_and_reads_in_one(
+        tmp_path, monkeypatch):
+    """A pipelined cluster whose loops have room: a write is acknowledged
+    within 3 ticks of its offer and a lease read is served by the tick
+    that stamps it; with no deadline the same cluster takes the extra
+    tick at every hop, as it always has."""
+    from rafting_tpu.utils.latency import OFFERED, SERVED
+    monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
+    c = LocalCluster(CFG_HB1, str(tmp_path), provider_factory=NullProvider,
+                     seed=3, pipeline=True)
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        c.tick(6)
+        overlapped = _rounds_until(c, node.submit(0, b"slow"))
+        c.tick(4)
+
+        _give_deadline(c, ROOM)
+        c.tick(3)           # the pending ticks settle; leases stay fresh
+        assert all(n._pending is None for n in c.nodes.values())
+        settled0 = node.metrics["ticks_settled"]
+        ticks0 = node.metrics["ticks"]
+        settled = _rounds_until(c, node.submit(0, b"fast"))
+        assert settled <= 3, f"a settled write took {settled} ticks"
+        assert settled < overlapped, (settled, overlapped)
+
+        rd = node.read(0, b"q")
+        assert _rounds_until(c, rd) == 1
+        c.tick(2)           # retired spans are harvested at a tick's tail
+        sp = max((sp for sp in node._lat.recent if sp.kind == "r"),
+                 key=lambda sp: sp.seq)
+        assert sp.outcome == "ok"
+        assert sp.n[SERVED] == sp.n[OFFERED], \
+            "the read was not served by the tick that stamped it"
+        assert node.metrics["ticks_settled"] - settled0 \
+            == node.metrics["ticks"] - ticks0 > 0
+    finally:
+        c.close()
+
+
+def _trace_rounds(root, due, rounds=14):
+    """Drive one fixed script and record, per round, everything a peer or
+    a client could tell a tick order by."""
+    c = LocalCluster(CFG, root, provider_factory=NullProvider, seed=3,
+                     pipeline=True)
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        _give_deadline(c, due)
+        sends = _spy_sends(c)
+        futs = [node.submit_batch(0, [b"t%d" % k]) for k in range(3)]
+        trace = []
+        for _ in range(rounds):
+            sends.clear()
+            c.tick(1)
+            trace.append((
+                tuple(f.done() for f in futs),
+                tuple(n._pending is not None for n in c.nodes.values()),
+                tuple(int(n._durable_tail_m[0]) for n in c.nodes.values()),
+                tuple(int(n.h_commit[0]) for n in c.nodes.values()),
+                tuple(int(n.metrics["eager_sends"])
+                      for n in c.nodes.values()),
+                tuple(sorted(sends.items()))))
+        assert all(f.done() for f in futs)
+        return trace, [int(n.metrics["ticks_settled"])
+                       for n in c.nodes.values()]
+    finally:
+        c.close()
+
+
+def test_deadline_without_room_is_tick_for_tick_the_overlapped_order(
+        tmp_path):
+    """The fallback: a node whose period has no room for its host phase
+    (here: a deadline that has always passed) overlaps exactly as a node
+    with no deadline does — same pending ticks, same eager sends, same
+    frames, same durable tails and acknowledgements, round for round."""
+    as_today = _trace_rounds(str(tmp_path / "none"), NO_DEADLINE)
+    no_room = _trace_rounds(str(tmp_path / "late"), NO_ROOM)
+    assert no_room == as_today
+    trace, settled = no_room
+    assert settled == [0, 0, 0]
+    assert all(all(pending) for _, pending, *_ in trace)
+
+
+@pytest.mark.parametrize("mode", ["settled", "overlapped", "transition"])
+def test_one_slice_per_peer_per_tick(tmp_path, mode):
+    """The peers' inbox accumulators drain one slice per source per tick:
+    whatever order a tick takes, a node hands each peer at most one."""
+    c = LocalCluster(CFG, str(tmp_path), provider_factory=NullProvider,
+                     seed=3, pipeline=True)
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        _give_deadline(c, ROOM if mode == "settled" else NO_DEADLINE)
+        c.tick(2)
+        sends = _spy_sends(c)
+        total, two_phases = 0, 0
+        for r in range(12):
+            if mode == "transition" and r % 4 == 2:
+                # Every node goes from overlapping to settling this
+                # round (two host phases in one tick), and back later.
+                assert all(n._pending is not None
+                           for n in c.nodes.values())
+                _give_deadline(c, ROOM)
+            elif mode == "transition" and r % 4 == 3:
+                _give_deadline(c, NO_DEADLINE)
+            node.submit_batch(0, [b"s%d" % r])
+            sends.clear()
+            c.tick(1)
+            assert max(sends.values(), default=0) <= 1, (r, dict(sends))
+            total += sum(sends.values())
+            two_phases += sum(n._host_runs == 2 for n in c.nodes.values())
+        assert total >= 12
+        assert two_phases == (9 if mode == "transition" else 0)
+    finally:
+        c.close()
+
+
+def test_transition_tick_runs_pending_host_phase_before_its_own(tmp_path):
+    """Going from overlapping to settling, one tick runs two host phases:
+    the pending tick's, then its own, each behind its own barrier, and
+    the one flush follows both."""
+    c = LocalCluster(CFG, str(tmp_path), provider_factory=NullProvider,
+                     seed=3, pipeline=True)
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        # Writes accepted by two consecutive ticks, so that both host
+        # phases of the transition tick have a barrier of their own.
+        f1 = node.submit_batch(0, [b"n-1"])
+        c.tick(1)
+        pend = node._pending
+        assert pend is not None
+        assert int(np.asarray(pend.info.submit_acc)[0]) == 1
+        f2 = node.submit_batch(0, [b"n"])
+
+        events = []
+        _spy_events(node, events)
+        host_phase = node._host_phase
+        node._host_phase = lambda ctx, defer_send=False: (
+            events.append(("host", ctx, defer_send)),
+            host_phase(ctx, defer_send))[1]
+        node._tick_due = ROOM
+        settled0 = node.metrics["ticks_settled"]
+        node.tick()
+
+        kinds = [e[0] for e in events]
+        hosts = [e for e in events if e[0] == "host"]
+        assert [h[1] for h in hosts][0] is pend, "N-1 must run first"
+        assert len(hosts) == 2 and hosts[1][1] is not pend
+        assert int(np.asarray(hosts[1][1].info.submit_acc)[0]) == 1
+        assert (hosts[0][2], hosts[1][2]) == (True, False), \
+            "N-1 holds its frames for N's one flush"
+        # host(N-1) barrier host(N) barrier send...: nothing leaves before
+        # the second barrier, and each peer gets one slice.
+        assert kinds[:4] == ["host", "barrier", "host", "barrier"], kinds
+        assert set(kinds[4:]) == {"send"}
+        peers = [e[1] for e in events if e[0] == "send"]
+        assert sorted(peers) == sorted(set(peers)) and peers
+        assert node._pending is None
+        assert node.metrics["ticks_settled"] == settled0 + 1
+        for _ in range(20):
+            if f1.done() and f2.done():
+                break
+            c.tick(1)
+        assert f1.result(timeout=1) and f2.result(timeout=1)
     finally:
         c.close()
 

@@ -139,6 +139,34 @@ def test_no_profiler_session_allocates_no_annotation(monkeypatch):
     assert st.leave() >= end and len(st.spent) == 2     # nothing open
 
 
+def test_host_cost_is_the_stage_spans_own_sum(tmp_path):
+    """What the loop weighs against its deadline (runtime/node.py
+    settles_now) is read from the stage spans, per host phase: the six
+    host stages of a tick over the host phases it ran, the costliest of
+    the last HOST_COST_MEMORY ticks."""
+    from rafting_tpu.runtime.node import HOST_COST_MEMORY, HOST_STAGES
+    c = LocalCluster(EngineConfig(n_groups=4, n_peers=3), str(tmp_path),
+                     seed=1, pipeline=True)
+    try:
+        node = c.nodes[0]
+        node.tick()
+        assert not node._host_costs           # fetched, no host phase yet
+        node.tick()
+        assert len(node._host_costs) == node._host_runs == 1
+        assert node._host_costs[-1] == pytest.approx(
+            node._stages.total(*HOST_STAGES))
+        node._tick_due = float("inf")         # room: this tick runs two
+        node.tick()
+        assert node._host_runs == 2
+        assert node._host_costs[-1] == pytest.approx(
+            node._stages.total(*HOST_STAGES) / 2)
+        for _ in range(HOST_COST_MEMORY + 3):
+            node.tick()
+        assert len(node._host_costs) == HOST_COST_MEMORY
+    finally:
+        c.close()
+
+
 def test_late_ticks_from_a_fake_clock(tmp_path):
     """tick_late_s = start - due, the due instant being the previous
     start plus the interval; ticks_late counts starts more than half a
