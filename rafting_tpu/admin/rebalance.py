@@ -40,7 +40,7 @@ class Rebalancer:
                  max_rounds: int = 4000, catch_up_slack: int = 2):
         """``nodes``: node_id -> RaftNode (or anything exposing h_role,
         membership(), change_membership(), transfer_leadership(),
-        catch_up_gap()).  ``step()`` advances the cluster one round
+        catch_up_gaps()).  ``step()`` advances the cluster one round
         between polls (default: 5 ms wall sleep for free-running nodes).
         ``catch_up_slack``: a learner counts as caught up when its
         replication gap (last - match on the leader) is at most this
@@ -114,9 +114,9 @@ class Rebalancer:
                 if nid is None:
                     return False
                 node = self.nodes[nid]
-                return all(node.catch_up_gap(group, p)
-                           <= self.catch_up_slack
-                           for p in range(64) if (newcomers >> p) & 1)
+                gaps = node.catch_up_gaps()[group]
+                return all(gaps[p] <= self.catch_up_slack
+                           for p in range(len(gaps)) if (newcomers >> p) & 1)
             self._wait(caught_up, f"group {group}: learner catch-up")
         # Stage 3: promote + demote in ONE joint walk (the kernel appends
         # C_old,new, commits it under both quorums, auto-appends C_new).
@@ -160,8 +160,7 @@ class Rebalancer:
                           if (voters >> p) & 1 and p != node_id]
             if not candidates:
                 continue
-            target = min(candidates,
-                         key=lambda p: node.catch_up_gap(g, p))
+            target = min(candidates, key=node.catch_up_gaps()[g].__getitem__)
             fut = node.transfer_leadership(g, target)
             try:
                 self._wait_future(fut, f"group {g}: leadership transfer")
@@ -208,7 +207,7 @@ class Rebalancer:
             pool = healthy or candidates
             if not pool:
                 continue
-            target = min(pool, key=lambda p: node.catch_up_gap(g, p))
+            target = min(pool, key=node.catch_up_gaps()[g].__getitem__)
             fut = node.transfer_leadership(g, target)
             try:
                 self._wait_future(fut, f"group {g}: leadership transfer")

@@ -51,6 +51,14 @@ class RaftConfig:
     election_mul: float = 3.0
     broadcast_mul: float = 0.5
     pre_vote: bool = True
+    # Set by whoever runs several nodes of one cluster on ONE host (three
+    # containers in a process: the benchmark's coord-1g-3v): node i of P
+    # then ticks a share i/P of a period after node 0 on the host's
+    # monotonic clock, so that no two tick threads queue for one
+    # interpreter (RaftNode._next_start).  Off, a node's starts are one
+    # period apart wherever its boot put them: members on hosts of their
+    # own have clocks of their own and nothing to stagger against.
+    tick_stagger: bool = False
     # engine shapes
     n_groups: int = 16
     log_slots: int = 64
@@ -177,7 +185,7 @@ def load_xml_config(path: str) -> RaftConfig:
             <remote>raft://127.0.0.1:6003</remote>
           </cluster>
           <timing tick="100" heartbeat="1" election="3" broadcast="0.5"
-                  pre-vote="true"/>
+                  pre-vote="true" tick-stagger="false"/>
           <engine groups="16" log-slots="64" batch="8" max-submit="8"/>
           <snapshot state-change-threshold="64" dirty-log-tolerance="16"
                     snap-min-interval="20" compact-min-interval="10"
@@ -211,6 +219,7 @@ def load_xml_config(path: str) -> RaftConfig:
         election_mul=attr("timing", "election", 3.0, float),
         broadcast_mul=attr("timing", "broadcast", 0.5, float),
         pre_vote=attr("timing", "pre-vote", True, boolean),
+        tick_stagger=attr("timing", "tick-stagger", False, boolean),
         n_groups=attr("engine", "groups", 16, int),
         log_slots=attr("engine", "log-slots", 64, int),
         batch=attr("engine", "batch", 8, int),

@@ -154,7 +154,8 @@ class RaftContainer:
             self._node = self.factory.build_node(
                 self.config, initial_active=self.registry.open_lanes())
         if start_loop:
-            self._node.start(self.config.tick_interval)
+            self._node.start(self.config.tick_interval,
+                             stagger=self.config.tick_stagger)
         else:
             self._node.transport.start()
         atexit.register(self.destroy)

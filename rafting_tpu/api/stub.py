@@ -167,9 +167,11 @@ class RaftStub:
         return self._forwarded(payload, timeout, read=True)
 
     def read_batch(self, queries) -> Future:
-        """Many linearizable queries under ONE ReadIndex barrier (one
-        future resolving to the list of results in order) — the batch
-        amortization the read plane exists for.  Leader-local only: a
+        """Many linearizable queries as ONE call: one future resolving to
+        the list of results in order, atomically.  (Barriers are shared
+        anyway: every read of the group that waits when its offer slot
+        comes free rides one, ``read`` calls included.)  Leader-local
+        only: a
         non-leader stub's batch fails NotLeader (forward the individual
         reads or redirect the batch by hint).  No timeout parameter on
         purpose: the batch is never forwarded, so there is no retry chase

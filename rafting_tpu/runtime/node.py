@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import json
 import logging
+import math
 import os
 import threading
 import time
@@ -235,15 +236,10 @@ class _SubBatch:
 
 
 class _ReadBatch:
-    """One queued linearizable read batch: query payloads + promise sink.
-
-    Read batches move through four host stages mirroring the device FIFO
-    (core/types.py rq_* lanes): WAITING (client-enqueued) -> OFFERED
-    (this tick's HostInbox.read_n) -> PENDING (device stamped it with a
-    ReadIndex; awaiting the quorum barrier) -> RELEASED (barrier
-    confirmed; served once ``applied >= read_index``).  Unlike
-    submissions, a read batch is atomic — the device stamps it whole or
-    not at all — so there is no ``taken`` cursor."""
+    """One client call's linearizable reads (``read`` / ``read_batch``):
+    query payloads + promise sink.  WAITING from enqueue until its
+    group's offer slot is won; from there it is one part of a
+    :class:`_ReadOffer`."""
 
     __slots__ = ("payloads", "sink", "t_enq")
 
@@ -251,6 +247,25 @@ class _ReadBatch:
         self.payloads = payloads
         self.sink = sink
         self.t_enq = t_enq
+
+
+class _ReadOffer:
+    """What one ReadIndex barrier carries: every batch of a group that was
+    WAITING when the group's offer slot was won, in arrival order.
+
+    An offer moves through the host stages that mirror the device FIFO
+    (core/types.py rq_* lanes): OFFERED (this tick's HostInbox.read_n, one
+    count for all parts) -> PENDING (the device stamped it with a
+    ReadIndex; awaiting the quorum barrier) -> RELEASED (barrier
+    confirmed; served once ``applied >= read_index``).  The device stamps
+    an offer whole or not at all, so there is no ``taken`` cursor; each
+    part keeps its own sink, span and result list."""
+
+    __slots__ = ("parts", "n")
+
+    def __init__(self, parts: List[_ReadBatch]):
+        self.parts = parts
+        self.n = sum(len(b.payloads) for b in parts)
 
 
 class _TickCtx:
@@ -427,6 +442,7 @@ class RaftNode:
             payload_window_fn=self.store.payloads_window,
             payload_runs_fn=getattr(self.store, "payload_runs", None))
         self.maintain = maintain or MaintainAgreement(cfg.n_groups)
+        self.maintain.watch_ring(cfg.log_slots, cfg.max_submit)
         self.template = messages_template(cfg)
         self.acc = InboxAccumulator(cfg, self.template)
         self.transport = transport_factory(self, self.acc.merge,
@@ -515,18 +531,20 @@ class RaftNode:
 
         # Linearizable read plane (ReadIndex + lease, core/step.py phase
         # 8b): the host-side FIFO mirror of the device's rq_* lanes.  A
-        # batch is WAITING until the group's offer slot frees, OFFERED for
-        # exactly the ticks its HostInbox.read_n is up, PENDING once the
-        # device stamps it (StepInfo.read_acc/read_index), RELEASED once
-        # the quorum barrier confirms (read_rel, FIFO order), and served —
+        # batch is WAITING until the group's offer slot frees; then every
+        # waiting batch of the group becomes one offer (_ReadOffer),
+        # OFFERED for exactly the ticks its HostInbox.read_n is up,
+        # PENDING once the device stamps it (StepInfo.read_acc/
+        # read_index), RELEASED once the quorum barrier confirms
+        # (read_rel, FIFO order), and served —
         # the machine queried at ``applied >= read_index`` — on the tick
         # thread.  Reads never enter the log, so EVERY read failure is a
         # marked retry-safe refusal (api/anomaly.py as_refusal).
         self._read_lock = threading.Lock()
         self._reads_waiting: Dict[int, deque] = {}
-        self._reads_offered: Dict[int, _ReadBatch] = {}
-        self._reads_pending: Dict[int, deque] = {}   # (read_index, batch)
-        self._reads_released: Dict[int, deque] = {}  # (read_index, batch)
+        self._reads_offered: Dict[int, _ReadOffer] = {}
+        self._reads_pending: Dict[int, deque] = {}   # (read_index, offer)
+        self._reads_released: Dict[int, deque] = {}  # (read_index, offer)
         self._read_queued_n = np.zeros(G, np.int32)
         # Columnar serve gate: per group, the smallest read_index any
         # released batch still waits on (int64 sentinel = no batch).
@@ -791,6 +809,7 @@ class RaftNode:
         # span in whatever jax.profiler session is running.
         self._stages = StageSpans(self.metrics, node_id)
         self._tick_due: Optional[float] = None   # _run: next start due
+        self._tick_stagger = False               # start(): share a host
         # Seconds per host phase over the last HOST_COST_MEMORY ticks that
         # ran one (the stage spans' own sum): what settles_now() weighs
         # against the time left until _tick_due.
@@ -829,14 +848,27 @@ class RaftNode:
         # settles_now() found room for).
         self.metrics["ticks"] += 0
         self.metrics["ticks_settled"] += 0
+        # Read plane: offers the device stamped (one ReadIndex barrier
+        # each) and the queries that rode a barrier another call opened.
+        self.metrics["read_barriers"] += 0
+        self.metrics["reads_coalesced"] += 0
+        # Maintenance a full log ring asked for ahead of the cadence
+        # (snapshot/policy.py): checkpoints serialized, grants issued.
+        self.metrics["ckpt_by_pressure"] += 0
+        self.metrics["compactions_by_pressure"] += 0
 
     # ------------------------------------------------------------------ API
 
-    def start(self, tick_interval: float = 0.02) -> None:
+    def start(self, tick_interval: float = 0.02,
+              stagger: bool = False) -> None:
         """Run the tick loop in a background thread (the node's
         'event loop'; interval plays the reference's tick,
-        support/RaftConfig.java:171-185)."""
+        support/RaftConfig.java:171-185).  ``stagger``
+        (``RaftConfig.tick_stagger``): this node shares its host with the
+        cluster's other nodes, and ticks on its own share of a grid
+        (``_next_start``)."""
         self._tick_interval = tick_interval
+        self._tick_stagger = stagger
         self.transport.start()
         self._thread = threading.Thread(
             target=self._run, args=(tick_interval,),
@@ -1210,9 +1242,13 @@ class RaftNode:
                    tenant: Optional[str] = None) -> Future:
         """Offer many linearizable queries as ONE read batch with one
         future resolving to the list of results in order.  The whole batch
-        shares one ReadIndex barrier — the amortization the read plane
-        exists for.  Same refusal taxonomy as :meth:`submit_batch`, but
-        every refusal/abort is retry-safe (see :meth:`read`)."""
+        shares one ReadIndex barrier — and shares it with every other
+        batch of the group that waits when the group's offer slot is won
+        (``_dispatch``), single ``read`` calls included: the amortization
+        the read plane exists for needs no caller to batch by hand; this
+        method is for the caller that wants one result list.  Same refusal
+        taxonomy as :meth:`submit_batch`, but every refusal/abort is
+        retry-safe (see :meth:`read`)."""
         sink = BatchSubmit(len(payloads), single=_single)
         fut = sink.future
         err = self._refusal(group)
@@ -1308,10 +1344,35 @@ class RaftNode:
     # separates the two.
     LATE_TICK_SHARE = 0.5
 
-    def _note_tick_start(self, now: float, interval: float) -> None:
-        """Tick thread, once per loop period: how late this tick starts.
-        It was due one interval after the previous start."""
-        due, self._tick_due = self._tick_due, now + interval
+    def _next_start(self, now: float, interval: float) -> float:
+        """When the tick after the one starting at ``now`` is due.  One
+        period on, unless the nodes of this cluster were said to share a
+        host (``start(stagger=True)``: three containers in one process,
+        as the benchmark's coordination service runs them).  Then it is
+        the first instant of this node's grid more than half a period
+        away: the grid is ``k * interval`` on the host's monotonic
+        clock, node i of P a share i/P of a period after node 0, so no
+        two of them ever tick together.  Left to where their boots
+        happened to put them, ticks that overlap stay overlapped (each
+        start is one period after the last) and every stage of every
+        tick takes two to three times as long, the tick threads queueing
+        for one interpreter: 75-95 ms a tick against 26-31 at 16 lanes
+        (PERF.md, PR 26), and a whole process was in one state or the
+        other.  On the grid a tick that overran its period starts the
+        next at once and is back on the grid one tick later."""
+        if not self._tick_stagger:
+            return now + interval
+        P = self.cfg.n_peers
+        phase = interval * (self.node_id % P) / P
+        return (math.floor((now + 0.5 * interval - phase) / interval)
+                + 1) * interval + phase
+
+    def _note_tick_start(self, now: float, interval: float,
+                         due_next: float) -> None:
+        """Tick thread, once per loop period: how late this tick starts
+        against when it was due, and when the loop is to start the next
+        one (``due_next``)."""
+        due, self._tick_due = self._tick_due, due_next
         if due is None:
             return
         late = max(0.0, now - due)
@@ -1323,16 +1384,17 @@ class RaftNode:
         st = self._stages
         while not self._stop.is_set():
             t0 = time.perf_counter()
-            self._note_tick_start(t0, interval)
+            due = self._next_start(t0, interval)
+            self._note_tick_start(t0, interval, due)
             try:
                 self.tick()
             except Exception:
                 log.exception("node %d tick failed", self.node_id)
                 st.leave()
-            dt = time.perf_counter() - t0
-            if dt < interval:
+            left = due - time.perf_counter()
+            if left > 0:
                 st.enter("wait")
-                time.sleep(interval - dt)
+                time.sleep(left)
                 st.leave()
 
     def set_active(self, group: int, active: bool,
@@ -1561,7 +1623,7 @@ class RaftNode:
                  backpressure=self._io_backpressure,
                  admission_level=adm.level if adm.enabled else 0.0)
         # Contact feed from the device qc lanes (max over groups -> [P]
-        # last-heard ticks), at an admin cadence like catch_up_gap.
+        # last-heard ticks), at an admin cadence like catch_up_gaps.
         if self.state.qc is not None and self.ticks % 16 == 0:
             heard = np.asarray(jax.device_get(self.state.qc.heard))
             h.note_contact(heard.max(axis=0))
@@ -1582,6 +1644,7 @@ class RaftNode:
         from ..core.types import conf_new_of, conf_voters_of
 
         moved = 0
+        gaps = None     # one fetch a round, at the first group that moves
         for g in led:
             g = int(g)
             if moved >= self._evac_groups_per_round:
@@ -1597,7 +1660,9 @@ class RaftNode:
                     and p not in bad]
             if not cand:
                 continue   # nowhere healthy to go — stay and serve
-            target = min(cand, key=lambda p: self.catch_up_gap(g, p))
+            if gaps is None:
+                gaps = self.catch_up_gaps()
+            target = min(cand, key=lambda p: gaps[g, p])
             fut = self.transfer_leadership(g, target)
             if fut.done() and fut.exception() is not None:
                 continue   # refused (raced a role change) — not an evac
@@ -1691,26 +1756,36 @@ class RaftNode:
             submit_n = np.minimum(
                 np.maximum(self._queued_n - self._inflight_submit, 0),
                 cfg.max_submit).astype(np.int32)
-        # Read plane: promote one waiting batch per group into the offer
-        # slot; an unstamped offer (no free device slot / not leader yet)
-        # simply stays offered and is re-offered next tick.  An offer
+        # Read plane: when a group's offer slot is free, promote ALL of
+        # its waiting batches into it as one offer — one read_n, one
+        # stamp, one barrier for every read the group holds.  What keeps
+        # that linearizable: only batches WAITING at this instant are
+        # merged, so every merged read was invoked before the step that
+        # stamps its ReadIndex is even dispatched; a read that arrives
+        # while an offer exists (offered, or riding the pending tick)
+        # waits for the next slot and never joins a stamped offer.  An
+        # unstamped offer (no free device slot / not leader yet) simply
+        # stays offered, as it is, and is re-offered next tick.  An offer
         # riding the pending tick is masked out until that tick's harvest
-        # (a batch must reach the device exactly once per stamp attempt).
+        # (an offer must reach the device exactly once per stamp attempt).
         read_n = np.zeros(G, np.int32)
         with self._read_lock:
             for g, q in self._reads_waiting.items():
                 if q and g not in self._reads_offered \
                         and not self._inflight_read[g]:
-                    b = q.popleft()
-                    self._read_queued_n[g] -= len(b.payloads)
-                    self._reads_offered[g] = b
-                    if b.sink.span is not None:
-                        # The group's one offer slot is won: submitted ->
-                        # offered was the wait for it (lat_read_queue_s).
-                        b.sink.span.mark(OFFERED)
-            for g, b in self._reads_offered.items():
+                    offer = _ReadOffer(list(q))
+                    q.clear()
+                    self._read_queued_n[g] -= offer.n
+                    self._reads_offered[g] = offer
+                    for b in offer.parts:
+                        if b.sink.span is not None:
+                            # The group's offer slot is won: submitted ->
+                            # offered was the wait for it
+                            # (lat_read_queue_s).
+                            b.sink.span.mark(OFFERED)
+            for g, offer in self._reads_offered.items():
                 if not self._inflight_read[g]:
-                    read_n[g] = len(b.payloads)
+                    read_n[g] = offer.n
         # Wall-clock pause detection (HostInbox.read_veto contract): a gap
         # beyond read_fresh_ticks tick intervals invalidates stored lease
         # evidence AND whatever acks queued in the inbox across the pause.
@@ -3022,9 +3097,9 @@ class RaftNode:
 
     def _harvest_reads(self, info: StepInfo) -> None:
         """Tick thread: mirror the device read FIFO's transitions reported
-        in StepInfo — offered batches the device STAMPED move to pending
-        with their ReadIndex; pending batches whose barrier RELEASED move
-        to released (FIFO, exactly read_rel of them); device-side ABORTS
+        in StepInfo — offers the device STAMPED move to pending with
+        their ReadIndex; pending offers whose barrier RELEASED move to
+        released (FIFO, exactly read_rel of them); device-side ABORTS
         (leadership/term change dropped the whole FIFO) fail every
         un-served batch as a retry-safe refusal."""
         read_acc = np.asarray(info.read_acc)
@@ -3036,15 +3111,19 @@ class RaftNode:
         with self._read_lock:
             for g in np.nonzero(read_acc > 0)[0].tolist():
                 b = self._reads_offered.pop(g, None)
-                # The device stamps exactly the offered batch, whole (its
-                # intake reads HostInbox.read_n built from this mirror) —
-                # a mismatch means the FIFOs desynchronized, the read
+                # The device stamps exactly the offer, whole (its intake
+                # reads HostInbox.read_n built from this mirror) — a
+                # mismatch means the FIFOs desynchronized, the read
                 # analog of the submit queue-depth invariant.
-                assert b is not None and int(read_acc[g]) == len(b.payloads), \
+                assert b is not None and int(read_acc[g]) == b.n, \
                     (f"g={g}: device stamped {int(read_acc[g])} reads "
-                     "beyond the offered batch")
+                     "beyond the offer")
                 self._reads_pending.setdefault(g, deque()).append(
                     (int(read_idx[g]), b))
+                m = self.metrics
+                m["read_barriers"] += 1
+                m["reads_coalesced"] += b.n - len(b.parts[0].payloads)
+                m.observe("read_batch_queries", b.n)
             for g in np.nonzero(read_rel > 0)[0].tolist():
                 q = self._reads_pending.get(g)
                 rel = self._reads_released.setdefault(g, deque())
@@ -3074,7 +3153,7 @@ class RaftNode:
         if not len(due):
             return
         sentinel = np.iinfo(np.int64).max
-        ready: List[Tuple[int, int, _ReadBatch]] = []
+        ready: List[Tuple[int, int, _ReadOffer]] = []
         with self._read_lock:
             for g in due.tolist():
                 q = self._reads_released.get(g)
@@ -3095,40 +3174,50 @@ class RaftNode:
         if not ready:
             return
         now = time.monotonic()
-        for g, idx, b in ready:
+        queries = 0
+        for g, idx, offer in ready:
             machine = self.dispatcher.machine(g)
             rd = getattr(machine, "read", None)
-            try:
-                for k, payload in enumerate(b.payloads):
-                    b.sink._complete(k, idx if rd is None else rd(payload))
-            except Exception as e:
-                # Query errors are still retry-safe: the read mutated
-                # nothing (SPI contract) and never entered the log.
-                b.sink._fail(as_refusal(e))
-                continue
-            self.metrics["reads_served"] += len(b.payloads)
-            self.metrics.observe("read_barrier_latency_s", now - b.t_enq)
+            for b in offer.parts:
+                try:
+                    for k, payload in enumerate(b.payloads):
+                        b.sink._complete(
+                            k, idx if rd is None else rd(payload))
+                except Exception as e:
+                    # Query errors are still retry-safe: the read mutated
+                    # nothing (SPI contract) and never entered the log.
+                    # The call that sent the query fails; the parts that
+                    # share its barrier do not.
+                    b.sink._fail(as_refusal(e))
+                    continue
+                queries += len(b.payloads)
+                self.metrics.observe("read_barrier_latency_s",
+                                     now - b.t_enq)
+        self.metrics["reads_served"] += queries
+        # What this tick served, on its raft.reads span.
+        self._stages.note(queries=queries, barriers=len(ready))
 
     def _reject_reads(self, g: int, exc: Optional[Exception] = None,
                       drop_released: bool = False) -> None:
-        """Fail every un-served read batch for ``g`` (waiting + offered +
-        pending; ``drop_released`` adds barrier-confirmed batches too —
-        only lane close/purge does that, since a confirmed ReadIndex stays
-        servable across leadership changes).  Always a MARKED refusal:
-        reads never enter the log, so any retry is safe."""
+        """Fail every un-served read batch for ``g`` (waiting + every part
+        of the offered and pending offers; ``drop_released`` adds
+        barrier-confirmed ones too — only lane close/purge does that,
+        since a confirmed ReadIndex stays servable across leadership
+        changes).  Always a MARKED refusal: reads never enter the log, so
+        any retry is safe."""
         with self._read_lock:
             q = self._reads_waiting.pop(g, None)
             batches = list(q) if q else []
-            b = self._reads_offered.pop(g, None)
-            if b is not None:
-                batches.append(b)
+            offer = self._reads_offered.pop(g, None)
+            if offer is not None:
+                batches.extend(offer.parts)
             pend = self._reads_pending.pop(g, None)
             if pend:
-                batches.extend(bb for _, bb in pend)
+                batches.extend(b for _, o in pend for b in o.parts)
             if drop_released:
                 rel = self._reads_released.pop(g, None)
                 if rel:
-                    batches.extend(bb for _, bb in rel)
+                    batches.extend(b for _, o in rel for b in o.parts)
                 self._rel_min[g] = np.iinfo(np.int64).max
             self._read_queued_n[g] = 0
         if not batches:
@@ -3240,16 +3329,18 @@ class RaftNode:
             "conf_idx": int(self.h_conf_idx[group]),
         }
 
-    def catch_up_gap(self, group: int, peer: int) -> int:
-        """Leader-side replication lag of one peer: ``last - match``
-        (0 = fully caught up).  An admin-cadence device read — the
-        rebalancer polls it to decide when a learner is promotable."""
+    def catch_up_gaps(self) -> np.ndarray:
+        """[G, P] leader-side replication lag of every peer: ``last -
+        match`` (0 = fully caught up; the rebalancer polls it to decide
+        when a learner is promotable).  An admin-cadence device read of
+        the two whole planes: an eager index into a device array would
+        compile a slice program per shape at its first use, in whatever
+        tick an evacuation or a transfer happens to fall."""
         import jax
 
         last, match = jax.device_get(
-            (self.state.log.last[group],
-             self.state.match_idx[group, peer]))
-        return max(0, int(last) - int(match))
+            (self.state.log.last, self.state.match_idx))
+        return np.maximum(0, np.asarray(last)[:, None] - np.asarray(match))
 
     def _harvest_membership(self, info: StepInfo, h_role) -> None:
         """Tick thread: refresh config mirrors from StepInfo, resolve
@@ -3618,6 +3709,8 @@ class RaftNode:
                 # never wedged.
                 self.metrics["ckpt_failures"] += 1
         need = self.maintain.need_checkpoint(now, applied, h_base)
+        pressed = self.maintain.ckpt_pressed
+        n_ckpt = n_pressed = 0
         due = np.nonzero(need)[0]
         if len(due) > self.max_checkpoints_per_tick:
             # Rotate the selection across ticks: a fixed [:cap] slice would
@@ -3665,6 +3758,8 @@ class RaftNode:
                     self._ckpt_queue.append((g, ckpt.path, ckpt.index, t))
                     self._ckpt_cv.notify()
                     queued = True
+                    n_ckpt += 1
+                    n_pressed += int(pressed[g])
             if full:
                 self._ckpt_inflight.discard(g)
                 self.metrics["ckpt_backpressure"] += 1
@@ -3677,6 +3772,20 @@ class RaftNode:
             self._ensure_ckpt_workers()
         self._compact_grant = self.maintain.compact_targets(
             now, self.h_commit.astype(np.int64), h_base.astype(np.int64))
+        m = self.metrics
+        m["ckpt_by_pressure"] += n_pressed
+        m["compactions_by_pressure"] += int(
+            self.maintain.compact_pressed.sum())
+        # The fullest ring this node holds (the fsynced tail is the
+        # tick's log tail once its host phase is here), on /metrics and
+        # on this tick's raft.maintain span.
+        ring_used = int((self._durable_tail_m - h_base)[self.h_active]
+                        .max(initial=0))
+        m.gauge("log_ring_used_max", ring_used)
+        self._stages.note(
+            ring_used=ring_used, ring_slots=self.cfg.log_slots,
+            led=int(((self.h_role == LEADER) & self.h_active).sum()),
+            checkpoints=n_ckpt, by_pressure=n_pressed)
         self._maintain_gc(now)
         if now % 32 == 0:
             self._fold_wal_stats()

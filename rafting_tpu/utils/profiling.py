@@ -74,6 +74,14 @@ class StageSpans:
             self._span.__enter__()
         return now
 
+    def note(self, **stats) -> None:
+        """Statistics of the open phase, known only once its work is done
+        (what a tick served, how full a ring stands): they ride the
+        phase's span beside ``node`` and ``tick``.  No session, no span,
+        nothing to do."""
+        if self._span is not None:
+            self._span.set_metadata(**stats)
+
     def total(self, *names: str) -> float:
         """Seconds spent in these phases since begin()."""
         return sum(self.spent.get(n, 0.0) for n in names)

@@ -217,6 +217,86 @@ def test_maintain_policy_compaction_gated_on_snapshot():
     assert t[1] == 48  # min(snap=49, commit-slack=48)
 
 
+def _watched(G, **kw):
+    ma = MaintainAgreement(G, **kw)
+    ma.watch_ring(log_slots=64, max_submit=8)
+    return ma
+
+
+def test_watch_ring_states_the_pressure_point():
+    # RaftConfig's shape: room for RELEASE_TICKS ticks of full intake.
+    assert _watched(1).pressure_at == 64 - 5 * 8 == 24
+    # max_submit large against the ring: never under a quarter of it.
+    ma = MaintainAgreement(1)
+    ma.watch_ring(log_slots=64, max_submit=32)
+    assert ma.pressure_at == 16
+    # A policy nobody told its ring sees no pressure at all.
+    assert MaintainAgreement(1).pressure_at is None
+
+
+def test_pressure_makes_a_group_due_before_its_interval():
+    """g0's ring is past the pressure point one tick after its last
+    checkpoint, with 30 entries changed (under the 64-entry threshold):
+    due now.  g1 is as full but has applied nothing since its snapshot
+    (a checkpoint would release nothing): not due.  g2 is under no
+    pressure and waits for the cadence as it always has."""
+    ma = _watched(3)
+    for g, idx in enumerate((10, 40, 10)):
+        ma.note_checkpoint(g, now=100, index=idx)
+    applied = np.array([40, 40, 30], np.int64)
+    base = np.array([10, 10, 10], np.int64)
+    need = ma.need_checkpoint(101, applied, base)
+    assert list(need) == [True, False, False]
+    assert list(ma.ckpt_pressed) == [True, False, False]
+    # The same group by the calendar: due by cadence, not counted as
+    # pressure.
+    need = ma.need_checkpoint(200, np.array([80, 40, 30], np.int64), base)
+    assert need[0] and not ma.ckpt_pressed[0]
+
+
+def test_pressure_compacts_at_once_but_never_past_the_snapshot():
+    ma = _watched(3, compact_min_interval=10, compact_slack=8)
+    ma.last_compact_tick[:] = 100
+    ma.note_checkpoint(0, now=100, index=30)      # snapshot behind commit
+    ma.note_checkpoint(1, now=100, index=60)      # snapshot ahead of slack
+    commit = np.array([50, 50, 50], np.int64)     # g2: no snapshot at all
+    base = np.array([10, 10, 10], np.int64)
+    t = ma.compact_targets(101, commit, base)     # one tick in: not "due"
+    assert list(t) == [30, 42, 0]     # min(snapshot, commit - slack); none
+    assert list(ma.compact_pressed) == [True, True, False]
+    assert ma.last_compact_tick[0] == 101 and ma.last_compact_tick[2] == 100
+    # Nothing to release (the target is the base): no grant, no count.
+    t = ma.compact_targets(102, commit, np.array([30, 42, 10], np.int64))
+    assert list(t) == [0, 0, 0] and not ma.compact_pressed.any()
+
+
+@pytest.mark.parametrize("watched", [False, True])
+def test_no_pressure_gives_the_cadence_arrays(watched):
+    """With no ring past the pressure point the policy's outputs are the
+    arrays it gave before it knew about rings, tick for tick."""
+    rng = np.random.default_rng(5)
+    kw = dict(state_change_threshold=12, dirty_log_tolerance=4,
+              snap_min_interval=5, compact_min_interval=3, compact_slack=2)
+    ma, ref = MaintainAgreement(8, **kw), MaintainAgreement(8, **kw)
+    if watched:
+        ma.watch_ring(log_slots=64, max_submit=8)
+    applied = np.zeros(8, np.int64)
+    base = np.zeros(8, np.int64)
+    for now in range(1, 120):
+        applied += rng.integers(0, 3, 8)
+        commit = applied + rng.integers(0, 2, 8)
+        a, b = (m.need_checkpoint(now, applied, base) for m in (ma, ref))
+        np.testing.assert_array_equal(a, b)
+        for g in np.nonzero(a)[0]:
+            for m in (ma, ref):
+                m.note_checkpoint(int(g), now, int(applied[g]))
+        a, b = (m.compact_targets(now, commit, base) for m in (ma, ref))
+        np.testing.assert_array_equal(a, b)
+        base = np.maximum(base, a)
+        assert (applied - base <= 24).all()     # the premise: no pressure
+        assert not ma.ckpt_pressed.any() and not ma.compact_pressed.any()
+
+
 def test_apply_batch_partial_failure_resolves_promises(tmp_path):
     """apply_batch that RAISES mid-batch after partially applying: the
     raise discards every result the batch would have returned, so the

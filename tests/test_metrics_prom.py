@@ -340,6 +340,45 @@ def test_self_healing_counters_on_exposition(tmp_path):
         c.close()
 
 
+def test_read_barrier_and_ring_pressure_series_are_on_the_page(tmp_path):
+    """PR 26's series from boot (0 before anything happened), and moving:
+    counters ``read_barriers``, ``reads_coalesced``, ``ckpt_by_pressure``,
+    ``compactions_by_pressure``; histogram ``read_batch_queries``; gauge
+    ``log_ring_used_max``."""
+    from rafting_tpu.core.types import EngineConfig
+    from rafting_tpu.testkit.harness import LocalCluster
+
+    cfg = EngineConfig(n_groups=1, n_peers=3, log_slots=16, batch=4,
+                       max_submit=4, election_ticks=6, heartbeat_ticks=1)
+    c = LocalCluster(cfg, str(tmp_path))
+    try:
+        text = c.nodes[0].metrics.render_prometheus()
+        validate_exposition(text)
+        for name in ("read_barriers", "reads_coalesced", "ckpt_by_pressure",
+                     "compactions_by_pressure"):
+            assert f"raft_{name}_total 0" in text, name
+        node = c.nodes[c.wait_leader(0)]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        reads = [node.read(0, b"q") for _ in range(4)]
+        writes = []
+        for _ in range(30):     # a 16-slot ring at one entry a tick
+            writes.append(node.submit(0, b"w"))
+            c.tick()
+        assert all(f.done() and f.exception() is None
+                   for f in reads + writes[:20])
+        text = node.metrics.render_prometheus()
+        validate_exposition(text)
+        assert "raft_read_barriers_total 1" in text
+        assert "raft_reads_coalesced_total 3" in text
+        assert "raft_read_batch_queries_count 1" in text
+        assert "# TYPE raft_log_ring_used_max gauge" in text
+        assert node.metrics["ckpt_by_pressure"] >= 1
+        assert node.metrics["compactions_by_pressure"] >= 1
+        assert 0 < node.metrics._gauges["log_ring_used_max"] < 16
+    finally:
+        c.close()
+
+
 def test_health_disabled_suppresses_gauges(tmp_path, monkeypatch):
     """RAFT_HEALTH=0 turns the scorecard plane off: no health gauges on
     the page (the counters stay — device 6c still steps down), and the

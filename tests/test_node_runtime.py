@@ -235,3 +235,65 @@ def test_empty_apply_skip_counter(tmp_path):
         assert node.metrics._gauges.get("empty_apply_skips", 0) > 0
     finally:
         c.close()
+
+
+def test_a_group_that_never_rests_keeps_its_ring_moving(tmp_path):
+    """One group fed ``max_submit`` entries a tick for 200 ticks under the
+    policy as it ships (``MaintainAgreement`` defaults: a snapshot no
+    sooner than 20 ticks after the last and only after 64 applied
+    entries — the whole ring): the ring is released by pressure, so
+    intake is never refused for more than a few ticks running, admission
+    sheds nothing and the node never evacuates its only group.  Half way
+    a follower is cut off until the leader has compacted past it; healed,
+    it catches up by snapshot and the three machines agree."""
+    import json
+
+    from rafting_tpu.machine.kv_machine import KVMachineProvider
+
+    cfg = EngineConfig(n_groups=2, n_peers=3, log_slots=64, batch=8,
+                       max_submit=8, election_ticks=10, heartbeat_ticks=1)
+    root = str(tmp_path)
+    c = LocalCluster(cfg, root, provider_factory=lambda i: KVMachineProvider(
+        os.path.join(root, f"kv{i}")))
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        victim = next(i for i in c.nodes if i != lead)
+        S, sent, futs = cfg.max_submit, 0, []
+        stuck = worst = 0
+        for t in range(200):
+            if t == 60:
+                c.faults.isolate(victim)
+            if t == 120:
+                assert node.h_base[0] > c.nodes[victim].h_commit[0], \
+                    "the leader never compacted past the cut follower"
+                c.faults.heal()
+            futs.append(node.submit_batch(0, [
+                json.dumps({"op": "set", "k": f"k{(sent + j) % 50}",
+                            "v": sent + j}).encode() for j in range(S)]))
+            sent += S
+            tail = int(node._durable_tail_m[0])
+            c.tick()
+            moved = int(node._durable_tail_m[0]) > tail
+            stuck = 0 if moved else stuck + 1
+            worst = max(worst, stuck)
+        assert worst <= 3, f"intake refused {worst} ticks running"
+        c.tick_until(lambda: all(f.done() for f in futs), 400,
+                     "every batch acknowledged")
+        assert all(f.exception() is None for f in futs)
+        m = node.metrics
+        assert m["ckpt_by_pressure"] > 0 and m["compactions_by_pressure"] > 0
+        assert sum(n.metrics["leader_evacuations"]
+                   for n in c.nodes.values()) == 0
+        assert sum(n.metrics["admission_shed"]
+                   for n in c.nodes.values()) == 0
+        assert node.is_leader(0), "the leadership moved under load"
+        machines = [n.dispatcher.machine(0) for n in c.nodes.values()]
+        c.tick_until(lambda: len({x.last_applied() for x in machines}) == 1,
+                     400, "the cut follower caught up")
+        assert c.nodes[victim].metrics["snapshots_installed"] > 0
+        assert machines[0].data == machines[1].data == machines[2].data
+        assert machines[0].data["k49"] == sent - 1
+    finally:
+        c.close()

@@ -113,6 +113,60 @@ def test_any_profiler_session_holds_the_stage_spans(tmp_path):
                 "raft.scan_wait"} & set(seen)
 
 
+def test_read_and_maintain_spans_carry_what_the_tick_did(tmp_path):
+    """``raft.reads`` carries ``queries`` and ``barriers`` for what that
+    tick served; ``raft.maintain`` carries ``ring_used``, ``ring_slots``,
+    ``led``, ``checkpoints`` and ``by_pressure``: written when the phase
+    is done (StageSpans.note), read back from the session's xplane."""
+    import jax
+    from jax.profiler import ProfileData
+
+    cfg = EngineConfig(n_groups=4, n_peers=3)
+    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1)
+    trace_dir = str(tmp_path / "trace")
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        with jax.profiler.trace(trace_dir):
+            futs = [node.read(0, b"q%d" % i) for i in range(3)]
+            for _ in range(20):
+                futs.append(node.submit(0, b"w"))
+                c.tick()
+            assert all(f.done() for f in futs[:3])
+    finally:
+        c.close()
+    (path,) = glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True)
+    reads, maintain = [], []
+    for p in ProfileData.from_file(path).planes:
+        if p.name != "/host:CPU":
+            continue
+        for ln in p.lines:
+            for e in ln.events:
+                stats = dict(e.stats)
+                if e.name == "raft.reads" and "barriers" in stats:
+                    reads.append(stats)
+                elif e.name == "raft.maintain" and stats["node"] == lead:
+                    maintain.append(stats)
+    # Three reads waited together: one tick served them under one barrier.
+    assert [(s["queries"], s["barriers"]) for s in reads] == [(3, 1)]
+    assert reads[0]["node"] == lead
+    assert len(maintain) == 20
+    assert all(s["ring_slots"] == cfg.log_slots and s["led"] >= 1
+               for s in maintain)
+    used = [s["ring_used"] for s in maintain]
+    assert used == sorted(used) and used[-1] >= 19   # one entry a tick
+    assert all(s["checkpoints"] == s["by_pressure"] == 0 for s in maintain)
+
+
+def test_note_without_a_session_is_nothing():
+    st = StageSpans(Metrics(), 0)
+    st.begin(1)
+    st.enter("reads")
+    st.note(queries=3, barriers=1)      # no span open: no error, no effect
+    st.leave()
+
+
 def test_no_profiler_session_allocates_no_annotation(monkeypatch):
     """With no session a boundary is the flag test and the histogram
     sample: no annotation object is ever built."""
@@ -167,9 +221,83 @@ def test_host_cost_is_the_stage_spans_own_sum(tmp_path):
         c.close()
 
 
+def test_tick_starts_lie_on_a_staggered_grid(tmp_path):
+    """Node i of P is due a share i/P of a period after node 0, on one
+    clock: the next start is the first grid instant more than half a
+    period away, so a tick that starts on time is followed one period
+    later, one that overran rejoins the grid at the next instant, and no
+    two nodes of a host are ever due together."""
+    c = LocalCluster(EngineConfig(n_groups=4, n_peers=3), str(tmp_path))
+    try:
+        period = 0.2
+        n0, n1, n2 = (c.nodes[i] for i in range(3))
+        for n in (n0, n1, n2):
+            n._tick_stagger = True            # what start(stagger=True) sets
+        assert n0._next_start(10.0, period) == pytest.approx(10.2)
+        assert n1._next_start(10.0, period) == pytest.approx(10.2 + period / 3)
+        assert n2._next_start(10.0, period) == pytest.approx(10.2 - period / 3)
+        # On time (a hair after its slot): one period on.
+        assert n1._next_start(10.0 + period / 3 + 1e-4, period) \
+            == pytest.approx(10.2 + period / 3)
+        # Started 0.3 of a period late: still the next slot, so the grid
+        # holds and the lateness does not carry over.
+        assert n0._next_start(10.06, period) == pytest.approx(10.2)
+        # Started 0.7 late (it overran): the slot after the next, never a
+        # start less than half a period after this one.
+        assert n0._next_start(10.14, period) == pytest.approx(10.4)
+        for t in (10.0, 10.013, 10.077, 10.19):
+            due = sorted(n._next_start(t, period) % period
+                         for n in (n0, n1, n2))
+            gaps = [b - a for a, b in zip(due, due[1:] + [due[0] + period])]
+            assert gaps == pytest.approx([period / 3] * 3)
+        # The loop hands the grid's instant to the deadline test.
+        n0._note_tick_start(10.0, period, n0._next_start(10.0, period))
+        assert n0._tick_due == pytest.approx(10.2)
+    finally:
+        c.close()
+
+
+@pytest.mark.parametrize("node_id", [0, 1, 2])
+@pytest.mark.parametrize("started", [10.0, 10.013, 10.14])
+def test_unstaggered_starts_are_one_period_apart(tmp_path, node_id, started):
+    """Nobody said the nodes share a host (RaftConfig.tick_stagger off,
+    the default): the next start is one period after this one, for every
+    node and wherever in a period the start fell, as before the grid."""
+    c = LocalCluster(EngineConfig(n_groups=4, n_peers=3), str(tmp_path))
+    try:
+        n = c.nodes[node_id]
+        assert n._tick_stagger is False
+        assert n._next_start(started, 0.2) == pytest.approx(started + 0.2)
+    finally:
+        c.close()
+
+
+def test_tick_stagger_reaches_the_loop_from_the_config(tmp_path,
+                                                       monkeypatch):
+    """RaftConfig.tick_stagger (off unless the deployer sets it) is what
+    the container hands the node's loop."""
+    from rafting_tpu.api import RaftConfig
+    from rafting_tpu.api.container import RaftContainer
+    from rafting_tpu.runtime.node import RaftNode
+    seen = []
+    monkeypatch.setattr(
+        RaftNode, "start",
+        lambda self, tick_interval=0.02, stagger=False:
+            seen.append((tick_interval, stagger)))
+    assert RaftConfig(local="raft://127.0.0.1:1",
+                      peers=()).tick_stagger is False
+    for i, flag in enumerate((False, True)):
+        rc = RaftConfig(local="raft://127.0.0.1:1", peers=(),
+                        data_dir=str(tmp_path / f"n{i}"), tick_ms=200,
+                        n_groups=4, tick_stagger=flag)
+        c = RaftContainer(rc, admin=False).create()
+        c.destroy()
+    assert seen == [(0.2, False), (0.2, True)]
+
+
 def test_late_ticks_from_a_fake_clock(tmp_path):
-    """tick_late_s = start - due, the due instant being the previous
-    start plus the interval; ticks_late counts starts more than half a
+    """tick_late_s = start - due, the due instant being what the loop
+    named at the previous start (here one interval on); ticks_late counts starts more than half a
     period late: a tick of 1.2 periods is not counted, one of 1.8 is."""
     c = LocalCluster(EngineConfig(n_groups=4, n_peers=3), str(tmp_path))
     try:
@@ -179,7 +307,7 @@ def test_late_ticks_from_a_fake_clock(tmp_path):
         for periods in (1.0, 1.2, 1.8, 1.0, 0.999):
             starts.append(starts[-1] + periods * period)
         for now in starts:
-            node._note_tick_start(now, period)
+            node._note_tick_start(now, period, now + period)
         h = node.metrics.histogram("tick_late_s")
         assert h.n == 5                       # the first start has no due
         assert h.total == pytest.approx((0.2 + 0.8) * period)

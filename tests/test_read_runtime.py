@@ -13,7 +13,9 @@ from concurrent.futures import Future
 import numpy as np
 import pytest
 
-from rafting_tpu.api.anomaly import NotLeaderError, is_refusal
+from rafting_tpu.api.anomaly import (
+    BatchAbortedError, BusyLoopError, NotLeaderError, is_refusal,
+)
 from rafting_tpu.api.serial import JsonSerializer
 from rafting_tpu.api.stub import RaftStub
 from rafting_tpu.core.types import EngineConfig
@@ -139,6 +141,164 @@ def test_read_survives_veto_pause(kv_cluster):
     lc.tick_until(rf.done, what="read after pause")
     assert rf.result() == 7
     node._tick_interval = None
+
+
+# ------------------------------------------- one barrier for waiting reads --
+
+def _write(lc, node, k, v):
+    wf = node.submit(0, _kv("set", k, v))
+    lc.tick_until(wf.done, what="write applied")
+
+
+@pytest.mark.parametrize("n", [2, 7])
+def test_waiting_single_reads_ride_one_barrier(kv_cluster, n):
+    """N ``read`` calls queued between two ticks are promoted into the
+    group's offer slot together: one stamp, one barrier, each future its
+    own result, in the order they came."""
+    lc = kv_cluster
+    _, node = _ready_leader(lc)
+    for i in range(n):
+        _write(lc, node, f"k{i}", i)
+    m = node.metrics
+    barriers, served, joined = (m["read_barriers"], m["reads_served"],
+                                m["reads_coalesced"])
+    futs = [node.read(0, _kv("get", f"k{i}")) for i in range(n)]
+    lc.tick_until(lambda: all(f.done() for f in futs), what="reads served")
+    assert [f.result() for f in futs] == list(range(n))
+    assert m["read_barriers"] == barriers + 1
+    assert m["reads_served"] == served + n
+    assert m["reads_coalesced"] == joined + n - 1
+    assert m.histogram("read_batch_queries").max == n
+
+
+def test_read_behind_an_offer_in_flight_waits_for_the_next_slot(tmp_path):
+    """The invariant of the merge: only batches WAITING when the slot is
+    won share its stamp.  Under the overlapped order an offer rides the
+    pending tick for a round; a read that arrives meanwhile joins no
+    stamped offer and gets a barrier of its own, one slot later."""
+    root = str(tmp_path)
+    lc = LocalCluster(
+        _cfg(), root, pipeline=True,
+        provider_factory=lambda i: KVMachineProvider(
+            os.path.join(root, f"kv{i}")))
+    try:
+        _, node = _ready_leader(lc)
+        _write(lc, node, "a", 1)
+        barriers = node.metrics["read_barriers"]
+        first = node.read(0, _kv("get", "a"))
+        lc.tick()               # dispatched: the offer rides this tick
+        assert 0 in node._reads_offered or node._reads_pending.get(0)
+        late = node.read(0, _kv("get", "a"))
+        # It waits: it is in no offer, stamped or not.
+        assert [b.sink.future for b in node._reads_waiting[0]] == [late]
+        lc.tick_until(lambda: first.done() and late.done(),
+                      what="both reads served")
+        assert first.result() == late.result() == 1
+        assert node.metrics["read_barriers"] == barriers + 2
+    finally:
+        lc.close()
+
+
+def test_a_raising_query_fails_only_its_own_call(kv_cluster):
+    lc = kv_cluster
+    _, node = _ready_leader(lc)
+    _write(lc, node, "a", 1)
+    barriers = node.metrics["read_barriers"]
+    good1 = node.read(0, _kv("get", "a"))
+    bad = node.read(0, _kv("set", "a", 2))       # not a query: raises
+    good2 = node.read(0, _kv("get", "missing"))
+    lc.tick_until(lambda: good1.done() and bad.done() and good2.done(),
+                  what="reads settled")
+    assert node.metrics["read_barriers"] == barriers + 1
+    assert good1.result() == 1 and good2.result() is None
+    assert isinstance(bad.exception(), ValueError)
+    assert is_refusal(bad.exception())            # a read: always retry-safe
+
+
+def test_explicit_read_batch_keeps_its_atomic_list(kv_cluster):
+    """``read_batch`` under a barrier shared with single reads: one future,
+    one list in order; a query of it that raises fails that call whole
+    (with the per-slot outcomes), and nobody else's."""
+    lc = kv_cluster
+    _, node = _ready_leader(lc)
+    _write(lc, node, "a", 1)
+    _write(lc, node, "b", 2)
+    barriers = node.metrics["read_barriers"]
+    single = node.read(0, _kv("get", "a"))
+    batch = node.read_batch(0, [_kv("get", "b"), _kv("get", "a"),
+                                _kv("get", "missing")])
+    broken = node.read_batch(0, [_kv("get", "a"), _kv("add", "l", 1)])
+    after = node.read(0, _kv("get", "b"))
+    futs = (single, batch, broken, after)
+    lc.tick_until(lambda: all(f.done() for f in futs), what="reads settled")
+    assert node.metrics["read_barriers"] == barriers + 1
+    assert single.result() == 1 and after.result() == 2
+    assert batch.result() == [2, 1, None]
+    exc = broken.exception()
+    assert isinstance(exc, BatchAbortedError)
+    assert exc.completed == [True, False] and exc.results[0] == 1
+
+
+def test_leadership_loss_fails_every_part_as_a_marked_refusal(kv_cluster):
+    """An offer whose barrier can no longer be earned (the leader is cut
+    off and loses its term) fails part by part: every future of it, and
+    of the batches still waiting, gets the retry-safe refusal."""
+    lc = kv_cluster
+    leader, node = _ready_leader(lc)
+    _write(lc, node, "a", 1)
+    lc.faults.isolate(leader)
+    lc.tick(node.cfg.read_fresh_ticks + 2)   # lease evidence goes stale
+    aborted = node.metrics["read_batches_aborted"]
+    parts = [node.read(0, _kv("get", "a")) for _ in range(3)]
+    lc.tick()                                # one offer, three parts
+    waiting = [node.read(0, _kv("get", "a")),
+               node.read_batch(0, [_kv("get", "a"), _kv("get", "a")])]
+    # The majority elects behind the cut; healed, the old leader meets
+    # the higher term and steps down with its reads unconfirmed.
+    lc.tick_until(lambda: any(n.is_leader(0) for i, n in lc.nodes.items()
+                              if i != leader),
+                  what="a new leader behind the cut", max_rounds=400)
+    assert not any(f.done() for f in parts + waiting)
+    lc.faults.heal()
+    lc.tick_until(lambda: all(f.done() for f in parts + waiting),
+                  what="reads refused", max_rounds=400)
+    for f in parts + waiting:
+        exc = f.exception()
+        # (a read_batch's failure carries the refusal as its cause)
+        exc = getattr(exc, "cause", exc)
+        assert isinstance(exc, NotLeaderError) and is_refusal(exc), exc
+    assert node.metrics["read_batches_aborted"] >= aborted + 5
+
+
+def test_group_queue_cap_still_refuses(tmp_path):
+    """The cap counts waiting QUERIES, merged or not: the read that would
+    pass it is refused at the door, and the slot's next win empties it."""
+    from rafting_tpu.runtime.node import RaftNode
+
+    root = str(tmp_path)
+    lc = LocalCluster(
+        _cfg(), root,
+        provider_factory=lambda i: KVMachineProvider(
+            os.path.join(root, f"kv{i}")))
+    try:
+        _, node = _ready_leader(lc)
+        assert isinstance(node, RaftNode)
+        node.group_queue_cap = 4
+        futs = [node.read(0, _kv("get", "a")) for _ in range(4)]
+        over = node.read(0, _kv("get", "a"))
+        assert isinstance(over.exception(), BusyLoopError)
+        assert is_refusal(over.exception())
+        over2 = node.read_batch(0, [_kv("get", "a")] * 2)
+        assert isinstance(over2.exception(), BusyLoopError)
+        lc.tick_until(lambda: all(f.done() for f in futs),
+                      what="queued reads served")
+        assert all(f.exception() is None for f in futs)
+        assert node._read_queued_n[0] == 0
+        again = node.read(0, _kv("get", "a"))
+        lc.tick_until(again.done, what="read after the queue emptied")
+        assert again.exception() is None
+    finally:
+        lc.close()
 
 
 # ------------------------------------------------------- stub redirect cap --

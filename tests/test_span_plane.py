@@ -1,5 +1,5 @@
 """The operation span plane read end to end (utils/latency.py): reads
-stamped submitted -> offered -> served with the wait for the group's one
+stamped submitted -> offered -> served with the wait for the group's
 offer slot split out, every stamp carrying the node's tick number, the
 write phases grouped as the benchmark reads them, and the instruments'
 own reproduction of the standing inbox backlog (PERF.md section 6, PR 23
@@ -47,8 +47,11 @@ def test_read_spans_split_queue_from_confirm(tmp_path, monkeypatch,
         c.tick(4)
         node = c.nodes[lead]
         # Two batches of one group between two ticks: the group's offer
-        # slot takes one batch a tick, so the second waits a tick more.
+        # slot takes every batch that waits when it is won, so both are
+        # offered by the same tick, under one barrier.
+        barriers = node.metrics["read_barriers"]
         _settle(c, [node.read(0, b"q1"), node.read(0, b"q2")])
+        assert node.metrics["read_barriers"] == barriers + 1
         first, second = sorted(
             (sp for sp in node._lat.recent if sp.kind == "r"),
             key=lambda sp: sp.seq)
@@ -58,7 +61,7 @@ def test_read_spans_split_queue_from_confirm(tmp_path, monkeypatch,
             assert 0 <= sp.n[SUBMITTED] <= sp.n[OFFERED] <= sp.n[SERVED]
             assert sp.to_dict()["ticks"]["offered"] == sp.n[OFFERED]
         assert first.n[SUBMITTED] == second.n[SUBMITTED]
-        assert second.n[OFFERED] >= first.n[OFFERED] + 1
+        assert second.n[OFFERED] == first.n[OFFERED]
         h = node.metrics._histograms
         assert h["lat_read_queue_s"].n == h["lat_read_confirm_s"].n \
             == h["lat_read_e2e_s"].n == 2

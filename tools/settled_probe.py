@@ -5,13 +5,13 @@ same set-up, window, drain and comparison as ``benchmark/run.py``), with each
 node's ``ticks`` and ``ticks_settled`` counters read around the window:
 
     python3 tools/settled_probe.py --workload W --seed N --seconds S [--trace 1]
-        [--tick-ms T] [--cpu-lanes L]
+        [--tick-ms T] [--rate R] [--cpu-lanes L]
 
 Prints the run's own lines, one ``[settled]`` line per node (window + drain,
 and the whole process) and the result line last.  ``--tick-ms`` runs the
 cell's cluster at another period: at one shorter than a node's tick work the
 loop has no room and the line shows the fallback (ticks overlapped as the
-pipeline was built).  ``--cpu-lanes`` rehearses the control flow on the CPU
+pipeline was built); ``--rate`` offers another rate than the traffic file's.  ``--cpu-lanes`` rehearses the control flow on the CPU
 at a tiny size; its numbers are counts of ticks, never device numbers.
 """
 
@@ -34,6 +34,7 @@ def main() -> None:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--tick-ms", type=int, default=0)
+    ap.add_argument("--rate", type=float, default=0.0)
     ap.add_argument("--cpu-lanes", type=int, default=0)
     a = ap.parse_args()
     from benchmark import harness, program_marks
@@ -48,6 +49,8 @@ def main() -> None:
         ov = overrides_for(load_config(config_path), a.cpu_lanes)
     if a.tick_ms:
         ov["raft_config"]["tick_ms"] = a.tick_ms
+    if a.rate:
+        ov.setdefault("traffic", {})["rate_ops_s"] = a.rate
     result = harness.run_cell(a.workload, a.seed, a.seconds, bool(a.trace),
                               T_PROCESS, on_chip=not a.cpu_lanes,
                               overrides=ov)
