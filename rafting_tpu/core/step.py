@@ -59,15 +59,16 @@ from __future__ import annotations
 
 import functools
 from functools import partial
-from typing import Tuple
+from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from .packing import Layout
 from .types import (
     CANDIDATE, FOLLOWER, LEADER, NIL, PRE_CANDIDATE, I32,
     EngineConfig, HostInbox, LogState, Messages, RaftState, StepInfo,
-    conf_learners_of, conf_new_of, conf_pack, conf_voters_of,
+    conf_learners_of, conf_new_of, conf_pack, conf_voters_of, init_state,
 )
 
 Array = jax.Array
@@ -1319,3 +1320,74 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         cq_stepdown=cq_down, cq_veto=cq_veto,
     )
     return new_state, outbox, info
+
+
+# ---------------------------------------------------------------------------
+# The packed step: what the served path calls.  A host in the loop pays per
+# transfer, not per byte (some 0.3 ms a call on a TPU, for planes of a few
+# bytes to a few hundred KB), so a tick's ~50 input planes go up as one
+# word buffer and one flag buffer and everything the host reads back comes
+# down as another pair (core/packing.py; in pieces of a few MB where the
+# planes are that large).  node_step itself stays the entry for whatever
+# has no host in the loop: the fused scans, the parity kit, the byte count.
+# ---------------------------------------------------------------------------
+
+class Readback(NamedTuple):
+    """Everything the runtime pulls to the host after a step: the step's
+    own outputs and the lanes of the new state that the host mirrors."""
+
+    info: StepInfo
+    outbox: Messages
+    term: Array
+    voted_for: Array
+    role: Array
+    leader_id: Array
+    commit: Array
+    base: Array
+    base_term: Array
+    heat: Any                 # Optional[HeatState]: None when cfg.heat is off
+
+
+def _step_readback(cfg: EngineConfig, state: RaftState, inbox: Messages,
+                   host: HostInbox) -> Tuple[RaftState, Readback]:
+    state, outbox, info = node_step(cfg, state, inbox, host)
+    return state, Readback(
+        info=info, outbox=outbox, term=state.term,
+        voted_for=state.voted_for, role=state.role,
+        leader_id=state.leader_id, commit=state.commit,
+        base=state.log.base, base_term=state.log.base_term, heat=state.heat)
+
+
+@functools.lru_cache(maxsize=None)
+def step_layouts(cfg: EngineConfig, durable: bool) -> Tuple[Layout, Layout]:
+    """(inputs, readback) layouts of ``node_step_packed`` for ``cfg``:
+    inputs pack ``(HostInbox, Messages)``, with the ``durable_tail`` lane
+    when ``durable``; readback packs a :class:`Readback`.  Both follow the
+    dataclasses (and ``cfg``'s optional subtrees) through
+    ``jax.eval_shape``."""
+    def host_inbox():
+        host = HostInbox.empty(cfg)
+        if durable:
+            host = host.replace(durable_tail=jnp.zeros_like(host.submit_n))
+        return host
+
+    host, inbox, state = (jax.eval_shape(f) for f in (
+        host_inbox, lambda: Messages.empty(cfg),
+        lambda: init_state(cfg, 0)))
+    _, back = jax.eval_shape(partial(_step_readback, cfg), state, inbox, host)
+    return Layout((host, inbox)), Layout(back)
+
+
+@partial(jax.jit, static_argnums=(0, 1), donate_argnums=2)
+def node_step_packed(cfg: EngineConfig, inputs: Layout, state: RaftState,
+                     buffers: Tuple[Array, ...]
+                     ) -> Tuple[RaftState, Tuple[Array, ...]]:
+    """``node_step`` with packed operands and packed results: ``buffers``
+    hold ``(HostInbox, Messages)`` in the layout ``inputs``
+    (``step_layouts(cfg, durable)[0]``); returned beside the new state are
+    the buffers of its :class:`Readback`, in ``step_layouts(cfg,
+    durable)[1]``.  The values that reach the step and the values that
+    come back are node_step's, bit for bit."""
+    host, inbox = inputs.unpack(buffers)
+    state, back = _step_readback(cfg, state, inbox, host)
+    return state, Layout(back).pack(back)

@@ -11,8 +11,12 @@ Tick protocol (the host half of the engine's contract):
 
 1. build the HostInbox: queued client submissions, finished snapshot
    installs, compaction grants from the maintain policy;
-2. drain the transport inbox accumulator into dense device arrays;
-3. run the fused device step (`node_step`) — all groups at once;
+2. drain the transport inbox accumulator into the dense inbox planes,
+   views of the tick's packed upload buffers (core/packing.py: one of
+   words and one of flags, more only when the planes take many MB),
+   which cross to the device in one transfer each;
+3. run the fused device step (`node_step_packed`) — all groups at once —
+   and fetch its packed results, again one transfer a buffer;
 4. PERSIST: stage WAL writes implied by the step (appended entries with
    payloads, truncations, (term, ballot) stable records), then ONE
    fsync-barrier `LogStore.sync()`;
@@ -46,9 +50,9 @@ import numpy as np
 
 from concurrent.futures import ThreadPoolExecutor
 
-from ..core.step import node_step
+from ..core.step import node_step_packed, step_layouts
 from ..core.types import (
-    I32, I32_SAFE_MAX, LEADER, NIL, EngineConfig, HostInbox, Messages,
+    I32, I32_SAFE_MAX, LEADER, NIL, EngineConfig, HostInbox,
     StepInfo, boot_conf_word as _boot_conf_word, init_state,
 )
 from ..log.store import LogStore, restore_raft_state
@@ -271,18 +275,21 @@ class _ReadOffer:
 class _TickCtx:
     """One tick in flight through the durable pipeline.
 
-    Created by ``_dispatch`` holding device-array references (the scan may
-    still be executing); ``_fetch`` swaps them for host numpy arrays; the
-    host phase (``_host_phase``) consumes those.  Carrying the per-tick
+    Created by ``_dispatch`` holding the step's packed result buffers on
+    the device (the scan may still be executing); ``_fetch`` pulls them and
+    sets the host planes, numpy views into the fetched buffers; the host
+    phase (``_host_phase``) consumes those.  Carrying the per-tick
     inputs (inbox arrays, staged payload runs, offered counts) here is
     what lets the NEXT scan dispatch before this tick's host work runs."""
 
     __slots__ = (
         # dispatch-time host inputs
         "submit_n", "read_n", "staged_payloads", "arrays",
-        # device refs (dispatch) -> host arrays (fetch)
+        # the packed results on the device and their layout (dispatch)
+        "packed", "readback",
+        # -> host views of the fetched buffers (fetch)
         "info", "outbox", "term", "voted", "role", "leader", "commit",
-        "base", "base_term", "heat",
+        "base", "base_term",
         # Eager-send bookkeeping (overlapped ticks): per-peer AE columns
         # whose payloads were not staged at fetch time — the host phase
         # packs exactly these after the barrier.  None = nothing left
@@ -848,6 +855,12 @@ class RaftNode:
         # settles_now() found room for).
         self.metrics["ticks"] += 0
         self.metrics["ticks_settled"] += 0
+        # Host-to-device and device-to-host transfers made by the ticks:
+        # the packed buffers of _dispatch and _fetch, a word buffer and a
+        # flag buffer each way unless the planes take more than a few MB
+        # (core/packing.py CHUNK_BYTES).
+        self.metrics["h2d_transfers"] += 0
+        self.metrics["d2h_transfers"] += 0
         # Read plane: offers the device stamped (one ReadIndex barrier
         # each) and the queries that rode a barrier another call opened.
         self.metrics["read_barriers"] += 0
@@ -1741,7 +1754,7 @@ class RaftNode:
                     self._reject_membership(g, exc_f())
                 if purge:
                     purged.append(g)
-            self.state = self.state.replace(active=jnp.asarray(act))
+            self.state = self.state.replace(active=jax.device_put(act))
             self.h_active = act
             if purged:
                 self._purge_lanes(purged)
@@ -1847,42 +1860,47 @@ class RaftNode:
         self._compact_grant = np.zeros(G, np.int64)
 
         # -- 2. network inbox ------------------------------------------------
-        arrays, staged_payloads = self.acc.drain()
+        # This tick's packed upload buffers (core/packing.py), fresh and
+        # zeroed: the dense inbox planes are views of them, filled where
+        # they cross to the device from, and ctx.arrays points at those
+        # views until this tick's host phase is done with them (in an
+        # overlapped tick: through the next dispatch, which fills its
+        # own).
+        inputs, readback = step_layouts(cfg, durable is not None)
+        buffers = inputs.alloc()
+        host, inbox = inputs.unpack(buffers)
+        arrays, staged_payloads = self.acc.drain(
+            {name: getattr(inbox, name) for name in self.template})
         self._fold_inbox_stats()
+        # The [G] host planes built above are copied into theirs (a few
+        # KB; 4 MB of the 48 at 100,000 lanes).
+        jax.tree.map(np.copyto, host, HostInbox(
+            submit_n=submit_n, snap_done=snap_done, snap_idx=snap_idx,
+            snap_term=snap_term, snap_conf=snap_conf, compact_to=compact_to,
+            conf_voters=conf_voters, conf_learners=conf_learners,
+            xfer_target=xfer_target, read_n=read_n, read_veto=read_veto,
+            durable_tail=durable))
 
-        # -- 2b. uploads: every host plane crosses to the device here, after
-        # the whole intake, so that one stage holds them all ----------------
+        # -- 2b. upload: every host plane crosses to the device here, after
+        # the whole intake, in one transfer per buffer ----------------------
         st = self._stages
         st.enter("dispatch_upload")
-        host = HostInbox(
-            submit_n=jnp.asarray(submit_n),
-            snap_done=jnp.asarray(snap_done),
-            snap_idx=jnp.asarray(snap_idx),
-            snap_term=jnp.asarray(snap_term),
-            snap_conf=jnp.asarray(snap_conf),
-            compact_to=jnp.asarray(compact_to),
-            conf_voters=jnp.asarray(conf_voters),
-            conf_learners=jnp.asarray(conf_learners),
-            xfer_target=jnp.asarray(xfer_target),
-            read_n=jnp.asarray(read_n),
-            read_veto=jnp.asarray(read_veto),
-            durable_tail=None if durable is None else jnp.asarray(durable),
-        )
-        inbox = Messages(**{k: jnp.asarray(v) for k, v in arrays.items()})
+        packed = jax.device_put(buffers)
+        st.note(transfers=len(packed))
+        self.metrics["h2d_transfers"] += len(packed)
 
         # -- 3. device step (async dispatch: no transfer, no block) ----------
+        # The step hands back, packed the same way, everything _fetch reads
+        # (core/step.py Readback): the tick keeps no reference to a leaf of
+        # the state, which the next step donates.
         st.enter("dispatch_enqueue")
-        self.state, outbox, info = node_step(cfg, self.state, inbox, host)
+        self.state, packed = node_step_packed(
+            cfg, inputs, self.state, packed)
 
         ctx = _TickCtx()
         ctx.submit_n, ctx.read_n = submit_n, read_n
         ctx.staged_payloads, ctx.arrays = staged_payloads, arrays
-        ctx.info, ctx.outbox = info, outbox
-        ctx.term, ctx.voted = self.state.term, self.state.voted_for
-        ctx.role, ctx.leader = self.state.role, self.state.leader_id
-        ctx.commit = self.state.commit
-        ctx.base, ctx.base_term = self.state.log.base, self.state.log.base_term
-        ctx.heat = self.state.heat
+        ctx.packed, ctx.readback = packed, readback
         ctx.deferred_ae = None
         self._inflight_submit = self._inflight_submit + submit_n
         self._inflight_read = self._inflight_read + read_n
@@ -1920,21 +1938,26 @@ class RaftNode:
         # The wait is split where the work happens: scan_device is the
         # device's remaining work on this tick's step, scan_fetch the
         # device-to-host copy (tick_stage_scan_wait_s, observed by tick(),
-        # stays their sum).  One transfer for everything the host needs
-        # this tick (the heat lanes ride it as a None subtree when
-        # cfg.heat is off).
+        # stays their sum).  One transfer per packed buffer for everything
+        # the host needs this tick; the planes below are views of the
+        # fetched buffers (the heat lanes are a None subtree when cfg.heat
+        # is off).
         st.enter("scan_device")
-        outs = jax.block_until_ready(
-            (ctx.info, ctx.outbox, ctx.term, ctx.voted, ctx.role,
-             ctx.leader, ctx.commit, ctx.base, ctx.base_term, ctx.heat))
+        packed = jax.block_until_ready(ctx.packed)
         st.enter("scan_fetch")
-        (h_info, h_out, h_term, h_voted, h_role, h_leader, h_commit, h_base,
-         h_base_term, h_heat) = jax.device_get(outs)
+        fetched = jax.device_get(packed)
+        st.note(transfers=len(fetched))
+        self.metrics["d2h_transfers"] += len(fetched)
         st.enter("mirrors")
-        ctx.info, ctx.outbox = h_info, h_out
-        ctx.term, ctx.voted, ctx.role = h_term, h_voted, h_role
+        back = ctx.readback.unpack(fetched)
+        ctx.packed = None
+        h_info, h_heat = back.info, back.heat
+        h_term, h_role, h_leader = back.term, back.role, back.leader_id
+        h_commit, h_base = back.commit, back.base
+        ctx.info, ctx.outbox = h_info, back.outbox
+        ctx.term, ctx.voted, ctx.role = h_term, back.voted_for, h_role
         ctx.leader, ctx.commit = h_leader, h_commit
-        ctx.base, ctx.base_term = h_base, h_base_term
+        ctx.base, ctx.base_term = h_base, back.base_term
 
         if cfg.debug_checks:
             from ..core.step import raise_debug_violations

@@ -42,7 +42,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Deque, Dict, List, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -88,22 +88,27 @@ class InboxAccumulator:
                 return   # = network loss; sender's resend timeout recovers
             q.append((fields, payloads, time.perf_counter()))
 
-    def drain(self) -> Tuple[Dict[str, np.ndarray],
-                             Dict[Tuple[int, int], Tuple[int, list]]]:
+    def drain(self, arrays: Optional[Dict[str, np.ndarray]] = None
+              ) -> Tuple[Dict[str, np.ndarray],
+                         Dict[Tuple[int, int], Tuple[int, list]]]:
         """Pop the oldest queued slice of every source and merge them into
         one dense inbox (different sources occupy disjoint [src, :] rows,
         so one slice per source never collides).  A source whose backlog
         exceeds COLLAPSE_BACKLOG has its entire queue collapsed instead
         (newest wins per lane) so lag stays bounded.
 
+        ``arrays``: the zeroed dense planes to fill, one per template
+        field — the runtime hands in views of the tick's packed upload
+        buffer, so the planes are written where they cross to the device
+        from; by default fresh ones are allocated.
+
         Returns the dense arrays (ownership transfers to the caller) and
         the popped slices' payload runs keyed (src, group) — newest-wins
         per group under collapse, matching the field planes."""
         P, G = self.cfg.n_peers, self.cfg.n_groups
-        arrays: Dict[str, np.ndarray] = {
-            name: np.zeros((P, G) + trail, dt)
-            for name, (dt, trail) in self.template.items()
-        }
+        if arrays is None:
+            arrays = {name: np.zeros((P, G) + trail, dt)
+                      for name, (dt, trail) in self.template.items()}
         payloads: Dict[Tuple[int, int], Tuple[int, list]] = {}
         with self._lock:
             st = self._stats

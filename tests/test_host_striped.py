@@ -4,8 +4,9 @@ under partition + crash + stall nemesis, the eager-send crash window
 (acks/futures must never precede the tick's own fsync even though leader
 AE frames release before it), and serial/striped outcome convergence.
 
-The parity tests monkeypatch the runtime's ``node_step`` with a wrapper
-that also runs the scalar oracle on the SAME inputs every tick, so a
+The parity tests monkeypatch the runtime's ``node_step_packed`` with a
+wrapper that also runs the scalar oracle on the SAME inputs (unpacked from
+the tick's upload buffers) every tick, so a
 striped host tier that corrupts what it feeds the device (WAL staging,
 submission arenas, inbox routing) diverges at the exact offending tick —
 the striped workers sit between two oracle-checked device steps."""
@@ -13,10 +14,13 @@ the striped workers sit between two oracle-checked device steps."""
 import os
 import shutil
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import rafting_tpu.runtime.node as node_mod
+from rafting_tpu.core.step import step_layouts
 from rafting_tpu.core.types import EngineConfig, LEADER
 from rafting_tpu.log.store import LogStore, restore_raft_state
 from rafting_tpu.testkit import nemesis
@@ -44,23 +48,29 @@ def _python_host_tier(monkeypatch):
 
 @pytest.fixture
 def oracle_checked_step(monkeypatch):
-    """Cross-check every runtime node_step call against the scalar oracle
-    (oracle FIRST: node_step donates its state buffers).  Serial pipeline
-    mode only — the oracle has no durable_tail lane."""
-    real = node_mod.node_step
+    """Cross-check every runtime node_step_packed call against the scalar
+    oracle: the oracle steps what the tick's upload buffers hold, and the
+    packed step's readback must unpack to the oracle's outputs (oracle
+    FIRST: the step donates its state buffers).  Serial pipeline mode
+    only — the oracle has no durable_tail lane."""
+    real = node_mod.node_step_packed
     calls = {"n": 0}
 
-    def checked(cfg, state, inbox, host):
+    def checked(cfg, inputs, state, buffers):
+        host, inbox = jax.tree.map(
+            jnp.asarray, inputs.unpack(jax.device_get(buffers)))
         o_state, o_out, o_info = oracle_step(cfg, state, inbox, host)
-        k_state, k_out, k_info = real(cfg, state, inbox, host)
+        k_state, packed = real(cfg, inputs, state, buffers)
+        _, readback = step_layouts(cfg, host.durable_tail is not None)
+        back = readback.unpack(jax.device_get(packed))
         tag = f"oracle-checked step #{calls['n']}"
         assert_state_equal(k_state, o_state, tag)
-        assert_messages_equal(k_out, o_out, tag)
-        assert_info_equal(k_info, o_info, tag)
+        assert_messages_equal(back.outbox, o_out, tag)
+        assert_info_equal(back.info, o_info, tag)
         calls["n"] += 1
-        return k_state, k_out, k_info
+        return k_state, packed
 
-    monkeypatch.setattr(node_mod, "node_step", checked)
+    monkeypatch.setattr(node_mod, "node_step_packed", checked)
     return calls
 
 

@@ -9,7 +9,9 @@ regressions only show in benches.  This lint greps the hot methods'
 source for the banned idioms instead; sparse ``np.nonzero(...)``-driven
 ``.tolist()`` loops over dirty subsets remain the approved pattern."""
 
+import ast
 import inspect
+import textwrap
 
 import rafting_tpu.runtime.node as node_mod
 from rafting_tpu.runtime.node import RaftNode
@@ -40,6 +42,32 @@ def test_hot_methods_have_no_dense_group_loops():
                 f"({pat!r}): visit np.nonzero(...) sparse subsets instead "
                 f"— see _persist_stage's wrote/mask idiom and "
                 f"_serve_reads' _rel_min columnar gate")
+
+
+def test_a_tick_crosses_the_device_boundary_packed():
+    """_dispatch and _fetch move a tick's planes as the two packed
+    buffers of core/packing.py.  A transfer per leaf (``jnp.asarray`` of
+    each plane, a ``device_get`` over a tuple of many results) costs a
+    fixed 0.1-0.3 ms a call on a TPU whatever the plane's size: some
+    130 of them were half of a tick's host time, and no functional test
+    sees them come back."""
+    for name in ("_dispatch", "_fetch"):
+        src = textwrap.dedent(inspect.getsource(getattr(RaftNode, name)))
+        assert "jnp.asarray(" not in src, (
+            f"RaftNode.{name} uploads a plane by itself: write it into "
+            f"the tick's packed buffers (step_layouts(...)[0].unpack)")
+        for call in ast.walk(ast.parse(src)):
+            if not (isinstance(call, ast.Call)
+                    and isinstance(call.func, ast.Attribute)
+                    and call.func.attr == "device_get"):
+                continue
+            for arg in call.args:
+                if isinstance(arg, (ast.Tuple, ast.List)):
+                    assert len(arg.elts) <= 2, (
+                        f"RaftNode.{name} fetches {len(arg.elts)} results "
+                        f"in one device_get: add them to core/step.py "
+                        f"Readback so they ride the packed buffers")
+    assert "node_step_packed(" in inspect.getsource(RaftNode._dispatch)
 
 
 def test_send_plane_uses_section_packing():

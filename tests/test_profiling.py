@@ -32,6 +32,18 @@ def test_device_trace_context(tmp_path):
         pass
 
 
+def _raft_spans(trace_dir):
+    """(name, statistics) of every ``raft*`` span on /host:CPU of the one
+    session written under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True)
+    planes = [p for p in ProfileData.from_file(path).planes
+              if p.name == "/host:CPU"]
+    assert planes
+    return [(e.name, dict(e.stats)) for p in planes for ln in p.lines
+            for e in ln.events if e.name.startswith("raft")]
+
+
 def _stage_total(node) -> float:
     h = node.metrics._histograms
     return sum(h[f"tick_stage_{s}_s"].total for s in TOP_STAGES
@@ -80,7 +92,6 @@ def test_any_profiler_session_holds_the_stage_spans(tmp_path):
     eight ticks holds the tick phases on /host:CPU, each with ``node`` and
     ``tick``, for every node of the process, and no parent span."""
     import jax
-    from jax.profiler import ProfileData
 
     cfg = EngineConfig(n_groups=16, n_peers=3)
     c = LocalCluster(cfg, str(tmp_path / "data"), seed=1, pipeline=True)
@@ -92,17 +103,9 @@ def test_any_profiler_session_holds_the_stage_spans(tmp_path):
             c.tick(8)
     finally:
         c.close()
-    (path,) = glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True)
     seen = {}
-    planes = [p for p in ProfileData.from_file(path).planes
-              if p.name == "/host:CPU"]
-    assert planes
-    for ln in planes[0].lines:
-        for e in ln.events:
-            if e.name.startswith("raft"):
-                stats = dict(e.stats)
-                seen.setdefault(e.name, set()).add(
-                    (stats["node"], stats["tick"]))
+    for name, stats in _raft_spans(trace_dir):
+        seen.setdefault(name, set()).add((stats["node"], stats["tick"]))
     for name in ("dispatch_intake", "dispatch_upload", "dispatch_enqueue",
                  "scan_device", "scan_fetch", "mirrors", "eager_send",
                  "tail", "reads", "maintain"):
@@ -119,7 +122,6 @@ def test_read_and_maintain_spans_carry_what_the_tick_did(tmp_path):
     ``led``, ``checkpoints`` and ``by_pressure``: written when the phase
     is done (StageSpans.note), read back from the session's xplane."""
     import jax
-    from jax.profiler import ProfileData
 
     cfg = EngineConfig(n_groups=4, n_peers=3)
     c = LocalCluster(cfg, str(tmp_path / "data"), seed=1)
@@ -136,18 +138,12 @@ def test_read_and_maintain_spans_carry_what_the_tick_did(tmp_path):
             assert all(f.done() for f in futs[:3])
     finally:
         c.close()
-    (path,) = glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True)
     reads, maintain = [], []
-    for p in ProfileData.from_file(path).planes:
-        if p.name != "/host:CPU":
-            continue
-        for ln in p.lines:
-            for e in ln.events:
-                stats = dict(e.stats)
-                if e.name == "raft.reads" and "barriers" in stats:
-                    reads.append(stats)
-                elif e.name == "raft.maintain" and stats["node"] == lead:
-                    maintain.append(stats)
+    for name, stats in _raft_spans(trace_dir):
+        if name == "raft.reads" and "barriers" in stats:
+            reads.append(stats)
+        elif name == "raft.maintain" and stats["node"] == lead:
+            maintain.append(stats)
     # Three reads waited together: one tick served them under one barrier.
     assert [(s["queries"], s["barriers"]) for s in reads] == [(3, 1)]
     assert reads[0]["node"] == lead
@@ -157,6 +153,42 @@ def test_read_and_maintain_spans_carry_what_the_tick_did(tmp_path):
     used = [s["ring_used"] for s in maintain]
     assert used == sorted(used) and used[-1] >= 19   # one entry a tick
     assert all(s["checkpoints"] == s["by_pressure"] == 0 for s in maintain)
+
+
+def test_a_tick_crosses_to_the_device_in_two_transfers_each_way(tmp_path):
+    """A served tick uploads its packed buffers and fetches the step's:
+    ``raft.dispatch_upload`` and ``raft.scan_fetch`` note ``transfers`` of
+    at most 2 on every tick of every node, and the counters
+    ``h2d_transfers`` / ``d2h_transfers`` advance by no more a tick."""
+    import jax
+
+    cfg = EngineConfig(n_groups=16, n_peers=3)
+    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1, pipeline=True)
+    trace_dir = str(tmp_path / "trace")
+    counters = ("h2d_transfers", "d2h_transfers")
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        before = {i: [n.metrics[k] for k in counters]
+                  for i, n in c.nodes.items()}
+        with jax.profiler.trace(trace_dir):
+            for _ in range(8):
+                node.submit(0, b"w")
+                node.read(0, b"q")
+                c.tick()
+        for i, n in c.nodes.items():
+            for k, was in zip(counters, before[i]):
+                assert 8 <= n.metrics[k] - was <= 2 * 8, (i, k)
+    finally:
+        c.close()
+    noted = {"raft.dispatch_upload": [], "raft.scan_fetch": []}
+    for name, stats in _raft_spans(trace_dir):
+        if name in noted:
+            noted[name].append(stats["transfers"])
+    for name, transfers in noted.items():
+        assert len(transfers) == 3 * 8, name
+        assert all(1 <= t <= 2 for t in transfers), (name, transfers)
 
 
 def test_note_without_a_session_is_nothing():

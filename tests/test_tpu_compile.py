@@ -16,10 +16,12 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from rafting_tpu.core.step import node_step
+from rafting_tpu.core.packing import Layout
+from rafting_tpu.core.step import node_step, node_step_packed, step_layouts
 from rafting_tpu.core.types import HostInbox, Messages, init_state
 from rafting_tpu.ops.quorum import quorum_commit_pallas
 
@@ -64,11 +66,15 @@ def test_quorum_kernel_compiles_at_100k_groups(one_chip, n_peers):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_node_step_fits_one_chip_at_the_smoke_shape(one_chip):
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["node_step", "node_step_packed"])
+def test_node_step_fits_one_chip_at_the_smoke_shape(one_chip, packed):
     """node_step at the shape chip_smoke.py serves at, as the pipelined
-    runtime calls it (durable-tail lane present).  Three nodes share the
-    chip: three states resident, and in the worst case three steps'
-    outputs and temporaries in flight at once."""
+    runtime feeds it (durable-tail lane present), and node_step_packed,
+    the program the runtime calls: the same step between the unpacking of
+    the upload buffers and the packing of the result buffers.  Three nodes
+    share the chip: three states resident, and in the worst case three
+    steps' outputs and temporaries in flight at once."""
     if REPO not in sys.path:
         sys.path.insert(0, REPO)
     import chip_smoke
@@ -82,7 +88,20 @@ def test_node_step_fits_one_chip_at_the_smoke_shape(one_chip):
     host = _on(one_chip, jax.eval_shape(
         lambda: HostInbox.empty(cfg).replace(
             durable_tail=jnp.zeros((cfg.n_groups,), jnp.int32))))
-    mem = node_step.lower(cfg, state, inbox, host).compile().memory_analysis()
+    if packed:
+        inputs, readback = step_layouts(cfg, True)
+        assert inputs == Layout((host, inbox))
+        # 48 MB each way at this shape: in pieces (core/packing.py).
+        assert len(inputs.buffers) > 2 and len(readback.buffers) > 2
+        bufs = tuple(jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+                     for dt, n in inputs.buffers)
+        lowered = node_step_packed.lower(cfg, inputs, state, bufs)
+        _, out = lowered.out_info
+        assert tuple((np.dtype(o.dtype), o.shape[0]) for o in out) \
+            == readback.buffers
+    else:
+        lowered = node_step.lower(cfg, state, inbox, host)
+    mem = lowered.compile().memory_analysis()
     per_node = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes)
     assert 3 * per_node < HBM_BYTES, mem
