@@ -123,7 +123,7 @@ def phase_device(n_chips: int):
 # ------------------------------------------------------------------- engine
 
 def engine_cfg(n_groups: int, use_pallas: bool = False):
-    """The shape bench.py drives the fused engine at."""
+    """The shape the fused engine is driven at."""
     from rafting_tpu import EngineConfig
     return EngineConfig(n_groups=n_groups, n_peers=3, log_slots=64, batch=8,
                         max_submit=8, election_ticks=10, heartbeat_ticks=3,
@@ -448,10 +448,9 @@ def phase_served(chip, n_lanes: int, n_groups: int, n_clients: int,
             store = node.store.wal
             say("served.selected", lanes=n_lanes, tick_ms=tick_ms,
                 pipeline=bool(node.pipeline),
-                host_tier=("native" if node._native_host else
-                           "striped" if node._w_eff > 1 else "serial"),
-                host_workers=node._w_native if node._native_host
-                else node._w_eff,
+                host_tier=("native" if node.store.can_stage_native
+                           else "python"),
+                host_workers=node.host_workers,
                 wal_engine=type(store.engines[0]).__name__
                 if hasattr(store, "engines") else type(store).__name__,
                 wal_shards=getattr(store, "n_shards", 1), wal_fsync=True,

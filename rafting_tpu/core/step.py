@@ -624,7 +624,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # whether it occupied a slot (ae_occ, phase 9; symmetric with
     # is_probe), so a reply to a window-full EXEMPT heartbeat can never
     # free a slot whose real ack was lost, and the window count stays
-    # exact (ADVICE r4).  A rejection aborts the whole window so
+    # exact.  A rejection aborts the whole window so
     # replication resumes from the clamped next_idx (reference: nextIndex
     # rollback cancels optimistic sends, Leadership.updateIndex:75-114).
     aer_ack = aer_r & ~inbox.aer_empty.T

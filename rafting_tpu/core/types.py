@@ -176,14 +176,6 @@ class EngineConfig:
                                   #     is None, so the state pytree and the
                                   #     compiled step are bit-identical to a
                                   #     build without the feature.
-    quorum_fixed: bool = False    # BENCH-ONLY baseline: commit quorum via
-                                  #     the legacy fixed-majority order
-                                  #     statistic over all P slots instead
-                                  #     of the masked membership-aware
-                                  #     kernel.  ONLY valid while every
-                                  #     group keeps the boot full-voter
-                                  #     config (the BENCH_MEMBER A/B uses
-                                  #     it to price the masked kernel).
     heat: bool = False            # per-group heat lanes (HeatState):
                                   #     cumulative appended / sent /
                                   #     committed / reads-served counters
@@ -672,7 +664,7 @@ class Messages:
                              #   back as aer_occ so only replies to occupying
                              #   heartbeats release hb_inflight (a reply to a
                              #   window-full EXEMPT heartbeat must not free a
-                             #   slot whose own ack was lost — ADVICE r4)
+                             #   slot whose own ack was lost)
     ae_cents: jax.Array      # [P, G, B] int32 — per-entry packed config
                              #   words (0 = not a config entry): the §6
                              #   membership plane rides the log, so every

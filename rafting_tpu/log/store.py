@@ -69,8 +69,7 @@ class LogStore:
     # cover under 1/_COMPACT_RATIO of the pinned frame and the frame is
     # big enough to matter, the remainder is copied into a compact buffer
     # so one cached entry can no longer pin a frame-sized allocation
-    # (ADVICE r5: resident-memory inflation at 100k groups with mixed
-    # progress).
+    # (resident-memory inflation at 100k groups with mixed progress).
     _COMPACT_MIN_FRAME = 1 << 16
     _COMPACT_RATIO = 4
 
@@ -460,11 +459,10 @@ class LogStore:
         self.wal.sync()
 
     def sync_stripes(self, stripes) -> None:
-        """Fsync only the given WAL stripes (striped host tier: each
-        worker barriers exactly the shards it staged).  The membership
-        sidecar is NOT flushed here — it is a single global file, so the
-        orchestrator flushes it once per tick before any ack leaves
-        (conf-bearing ticks take the serial host path entirely)."""
+        """Fsync only the given WAL stripes (the node's barrier with
+        quarantined stripes carved out: runtime/node.py _barrier).  The
+        membership sidecar is NOT flushed here — it is a single global
+        file, which the caller flushes before any ack leaves."""
         ss = getattr(self.wal, "sync_shards", None)
         if ss is not None:
             ss(stripes)
@@ -474,13 +472,14 @@ class LogStore:
     @property
     def n_stripes(self) -> int:
         """How many independently fsync-able WAL stripes back this store
-        (1 for an unsharded WAL) — the striped host tier's worker-count
+        (1 for an unsharded WAL) — the native engine's thread-count
         ceiling."""
         return int(getattr(self.wal, "n_shards", 1))
 
     def conf_flush(self) -> None:
-        """Flush the membership sidecar alone (striped host tier: the
-        orchestrator's share of the durability barrier)."""
+        """Flush the membership sidecar alone (the tick thread's share
+        of the durability barrier beside stage_and_sync or
+        sync_stripes)."""
         self.conf.flush()
 
     # -- injectable fault table (testkit/faultfs) ----------------------

@@ -1232,9 +1232,8 @@ class ShardedWal:
                        *, workers: int = 1, sync: bool = True):
         """Stage a whole tick's entries/truncations/milestones across every
         shard — and fsync them — in ONE native call with real OS threads
-        (worker k owns shards ``s % W == k``, the striped pool's ownership
-        map, so per-shard record order and segment bytes are identical to
-        the Python paths).  Returns ``(stage_s, fsync_s)``."""
+        (worker k owns shards ``s % W == k``, so per-shard record order
+        and segment bytes are identical to the Python path's).  Returns ``(stage_s, fsync_s)``."""
         return _native_stage_and_sync(
             self._handles, self.n_shards, self.engines, workers, sync,
             groups, idxs, terms, ptrs, lens,
@@ -1315,9 +1314,8 @@ class ShardedWal:
 
     def sync_shards(self, shard_ids) -> None:
         """Fsync only the given shard engines, inline on the calling
-        thread — the striped host tier's durability barrier: each worker
-        owns a disjoint set of shards end-to-end (staging AND fsync), so
-        no cross-thread coordination or pool handoff is needed.  Syncs
+        thread — the durability barrier over the healthy shards once a
+        stripe is quarantined.  Syncs
         EVERY requested shard before raising the merged failure (the
         caller must not acknowledge the tick, but healthy shards still
         become durable)."""

@@ -106,8 +106,7 @@ class ApplyDispatcher:
         # Per-group short-circuit tally behind the warning above — the
         # runtime surfaces the sum as the ``empty_apply_skips`` gauge so
         # a lagging last_applied stays diagnosable after the once-per-
-        # class log line scrolled away.  Keyed by group so the striped
-        # workers' disjoint masks never race an increment.
+        # class log line scrolled away.
         self._empty_skip_n: Dict[int, int] = {}
         # Numpy mirror of every machine's last_applied: advance() visits
         # only lanes whose commit frontier moved past it, so per-tick cost
@@ -285,36 +284,11 @@ class ApplyDispatcher:
 
     # -- the apply loop -----------------------------------------------------
 
-    def warm_mirror(self, n: int) -> None:
-        """Materialize the applied-frontier mirror for ``n`` groups on the
-        CALLING thread.  The striped host tier calls this once from the
-        orchestrator before fanning ``advance`` out to stripe workers —
-        lazy creation inside concurrent advance() calls would race the
-        full-array build."""
-        self._applied_mirror(n)
-
-    def advance(self, commit: np.ndarray,
-                groups: Optional[np.ndarray] = None,
-                max_per_group: int = 0) -> None:
+    def advance(self, commit: np.ndarray, max_per_group: int = 0) -> None:
         """Apply newly committed entries.  `commit` is the [G] frontier;
-        `groups` optionally restricts which lanes are live (active mask or
-        index list).  `max_per_group` bounds work per call (0 = no bound).
-
-        Stripe-sliced calls (striped host tier) pass a pre-sliced index
-        view: disjoint group sets make concurrent advance() calls safe —
-        every structure here (machines, promises, skip ledger, the mirror's
-        per-element writes) is keyed or indexed by group.  An index list is
-        intersected with the behind mask exactly like a bool mask, so a
-        stripe view costs no applies for already-caught-up groups."""
+        `max_per_group` bounds work per call (0 = no bound)."""
         mirror = self._applied_mirror(len(commit))
-        behind = commit > mirror[:len(commit)]
-        if groups is None:
-            gs = np.nonzero(behind)[0]
-        elif groups.dtype == bool:
-            gs = np.nonzero(groups & behind)[0]
-        else:
-            groups = np.asarray(groups, np.int64)
-            gs = groups[behind[groups]]
+        gs = np.nonzero(commit > mirror[:len(commit)])[0]
         retries = self._retry_counts
         for g in gs:
             g = int(g)

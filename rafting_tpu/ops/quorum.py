@@ -29,11 +29,8 @@ rides the TPU lanes; the peer axis (3-10) is a static unroll of an
 odd-even transposition sorting network on [rows, 128] tiles in VMEM (one
 network per voter set; the joint pass reuses the same plane loads).
 
-``quorum_commit`` dispatches to the Pallas kernel, the pure-jnp masked
-reference (identical semantics, parity-tested in tests/test_ops.py), or —
-``cfg.quorum_fixed`` — the legacy fixed-majority baseline kept ONLY for
-the BENCH_MEMBER A/B (valid only while every group holds the boot
-full-voter config).
+``quorum_commit`` dispatches to the Pallas kernel or the pure-jnp masked
+reference (identical semantics, parity-tested in tests/test_ops.py).
 """
 
 from __future__ import annotations
@@ -116,29 +113,6 @@ def quorum_commit_ref(match_full, own_from, last, commit, can_lead,
     can_full = can_lead & (full > commit) & (full <= last)
     return jnp.maximum(jnp.where(can, q, commit),
                        jnp.where(can_full, full, commit))
-
-
-def quorum_commit_fixed(cfg, match_full, last, commit, own_from, can_lead
-                        ) -> jax.Array:
-    """The legacy fixed-majority kernel (pre-membership behavior): order
-    statistic at the STATIC majority over all P slots, full lane = min of
-    the whole row.  Kept as the BENCH_MEMBER baseline; only valid while
-    every group holds the boot full-voter config."""
-    P = match_full.shape[1]
-    if P == 3 and cfg.majority == 2:
-        a, b, c = match_full[:, 0], match_full[:, 1], match_full[:, 2]
-        quorum_idx = jnp.maximum(jnp.minimum(a, b),
-                                 jnp.minimum(jnp.maximum(a, b), c))
-        full_idx = jnp.minimum(jnp.minimum(a, b), c)
-    else:
-        sorted_m = jnp.sort(match_full, axis=1)
-        quorum_idx = sorted_m[:, P - cfg.majority]
-        full_idx = sorted_m[:, 0]
-    can = can_lead & (quorum_idx > commit) & \
-        (quorum_idx >= own_from) & (quorum_idx <= last)
-    can_full = can_lead & (full_idx > commit) & (full_idx <= last)
-    return jnp.maximum(jnp.where(can, quorum_idx, commit),
-                       jnp.where(can_full, full_idx, commit))
 
 
 # ------------------------------------------------------------------- kernel --
@@ -312,13 +286,8 @@ def contact_quorum(voters, voters_new, me, heard, since):
 
 def quorum_commit(cfg, match_full, log, commit, own_from, can_lead,
                   voters, voters_new):
-    """Dispatch: the legacy fixed-majority baseline when
-    ``cfg.quorum_fixed`` (bench A/B only), the Pallas kernel when
-    ``cfg.use_pallas``, else inline jnp (the default; all membership
-    paths are semantically identical)."""
-    if getattr(cfg, "quorum_fixed", False):
-        return quorum_commit_fixed(cfg, match_full, log.last, commit,
-                                   own_from, can_lead)
+    """Dispatch: the Pallas kernel when ``cfg.use_pallas``, else inline
+    jnp (the default; the two are semantically identical)."""
     if getattr(cfg, "use_pallas", False):
         import os
         state_vec = jnp.stack([commit, log.last, can_lead.astype(I32),

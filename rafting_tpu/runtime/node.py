@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from concurrent.futures import ThreadPoolExecutor
 
 from ..core.step import node_step_packed, step_layouts
 from ..core.types import (
@@ -300,12 +299,11 @@ class _TickCtx:
 
 
 class _PersistPrep:
-    """The orchestrator half of a tick's persist, precomputed once and
-    handed to stripe workers: columnar change-detection arrays, the popped
-    submission spans, and the staged-frame metadata.  Building this is
-    cheap (a handful of fancy indexes + one lock'd queue pop); the
-    per-written-group span staging it feeds is the expensive part and is
-    what stripes across workers (``_persist_stage``)."""
+    """One tick's persist plan, precomputed once for either persist
+    step: columnar change-detection arrays, the popped submission spans,
+    and the staged-frame metadata.  Building this is cheap (a handful of
+    fancy indexes + one lock'd queue pop); the per-written-group span
+    staging it feeds is the expensive part."""
 
     __slots__ = (
         "dirty_mask", "log_tail", "h_term", "h_voted",
@@ -348,8 +346,8 @@ class RaftNode:
         the leader-forward relay (api/serial.py; reference CmdSerializer,
         support/serial/CmdSerializer.java:11-24) — default JSON.
         ``pipeline``: build the double-buffered durable pipeline (see
-        ``tick``).  Default: env RAFT_PIPELINE if set (0/false = serial),
-        else ON exactly when the engine runs on an accelerator backend.
+        ``tick``).  Default: ON exactly when the engine runs on an
+        accelerator backend.
         A pipelined node under its own loop (``start``) then chooses the
         order per tick from the loop's deadline: a fetched tick whose
         host phase fits in what is left of the period runs it there (the
@@ -361,12 +359,10 @@ class RaftNode:
         ``wal_shards``: stripe count for the default WAL store (ignored
         when ``store`` is passed) — default from env RAFT_WAL_SHARDS,
         else 4.
-        ``host_workers``: width of the striped host tier — the persist /
-        apply / outbox-packing phase fans out over this many workers,
-        each owning a disjoint, WAL-stripe-aligned set of groups
-        end-to-end (see _host_phase_striped).  1 (the default, or env
-        RAFT_HOST_WORKERS) keeps the classic serial host phase; the
-        effective width is clamped to the store's stripe count.
+        ``host_workers``: thread width of the native WAL engine's
+        stage-and-fsync call and payload pack (default 1), clamped to
+        the store's stripe count; a store whose engine is the Python
+        one stages on the tick thread, width 1, whatever is asked.
         ``latency_slo_s``: end-to-end commit-latency SLO target the
         latency plane's burn gauges measure against (utils/latency.py)
         — default env RAFT_SLO_MS (milliseconds), else 500ms."""
@@ -378,71 +374,28 @@ class RaftNode:
         self.serializer = serializer or JsonSerializer()
         os.makedirs(data_dir, exist_ok=True)
         if pipeline is None:
-            env = os.environ.get("RAFT_PIPELINE", "").strip().lower()
-            if env:
-                pipeline = env not in ("0", "false", "no", "off")
-            else:
-                pipeline = jax.default_backend() != "cpu"
+            pipeline = jax.default_backend() != "cpu"
         self.pipeline = bool(pipeline)
         if wal_shards is None:
             wal_shards = int(os.environ.get("RAFT_WAL_SHARDS", "4"))
-        if host_workers is None:
-            host_workers = int(os.environ.get("RAFT_HOST_WORKERS", "1"))
 
         self.store = store if store is not None \
             else LogStore(os.path.join(data_dir, "wal"),
                           shards=max(1, wal_shards))
-        # Striped host tier (see _host_phase_striped): W workers each own
-        # a disjoint set of WAL stripes end-to-end (arena staging → fsync
-        # → apply → outbox packing), so no two workers ever touch the same
-        # group's store cache, machine, or WAL shard — single-writer per
-        # group is preserved by construction, not by locks.  Width clamps
-        # to the stripe count (a worker without a whole stripe would share
-        # a shard file, breaking the disjoint-fsync barrier) and stays 1
-        # when the store can't fsync stripes independently.
         n_stripes = int(getattr(self.store, "n_stripes", 1))
-        can_stripe = hasattr(self.store, "sync_stripes")
-        self.host_workers = max(1, int(host_workers))
-        self._w_eff = min(self.host_workers, n_stripes) if can_stripe else 1
-        G0 = cfg.n_groups
-        # group -> WAL stripe (the store's g % S map), shared by the
-        # striped host tier and the storage-fault quarantine plane.
-        self._stripe_of = np.arange(G0, dtype=np.int64) % n_stripes
+        # group -> WAL stripe (the store's g % S map): what a storage
+        # fault quarantines.
+        self._stripe_of = np.arange(cfg.n_groups, dtype=np.int64) % n_stripes
         self._n_stripes = n_stripes
-        if self._w_eff > 1:
-            stripe_of = self._stripe_of
-            worker_of = stripe_of % self._w_eff
-            self._worker_masks = [worker_of == k for k in range(self._w_eff)]
-            self._worker_groups = [np.nonzero(m)[0] for m in self._worker_masks]
-            self._worker_stripes = [
-                [s for s in range(n_stripes) if s % self._w_eff == k]
-                for k in range(self._w_eff)]
-        else:
-            self._worker_masks = [np.ones(G0, bool)]
-            self._worker_groups = [np.arange(G0, dtype=np.int64)]
-            self._worker_stripes = [list(range(n_stripes))]
-        # Native host tier (_host_phase_native): the per-tick stage →
-        # fsync hot loop crosses into the WAL engine's C side ONCE, with
-        # real OS threads per stripe-set (no GIL) — auto-selected when
-        # the native WAL engine serves, forced on/off with
-        # RAFT_NATIVE_HOST=1/0.  Byte-identical WAL layout to the Python paths, so recovery is
-        # interchangeable between backends.
-        can_native = bool(getattr(self.store, "can_stage_native", False))
-        env_native = os.environ.get("RAFT_NATIVE_HOST", "").strip().lower()
-        if env_native in ("0", "false", "no", "off"):
-            self._native_host = False
-        elif env_native:
-            self._native_host = can_native
-            if not can_native:
-                log.warning(
-                    "RAFT_NATIVE_HOST=%s but the native stage_and_sync "
-                    "entry point is unavailable — using the Python host "
-                    "tier", env_native)
-        else:
-            self._native_host = can_native
-        self._w_native = min(self.host_workers, n_stripes) \
-            if self._native_host else 1
-        self._host_pool: Optional[ThreadPoolExecutor] = None
+        # The native WAL engine stages and fsyncs a tick in ONE call, on
+        # this many OS threads (worker k owns shards s % W == k, no GIL);
+        # the Python engine stages on the tick thread (see _persist).
+        # Both write byte-identical segments, so recovery is
+        # interchangeable between them.
+        self._native_wal = bool(getattr(self.store, "can_stage_native",
+                                        False))
+        self.host_workers = min(max(1, int(host_workers or 1)), n_stripes) \
+            if self._native_wal else 1
         self.archive = SnapshotArchive(os.path.join(data_dir, "snapshots"))
         self.dispatcher = ApplyDispatcher(
             provider, self._payload,
@@ -746,9 +699,6 @@ class RaftNode:
         # Spans offered to the device THIS tick, awaiting the tick's
         # staged/fsynced/sent stamps (tick/host-phase thread only).
         self._lat_tick: list = []
-        # Recent striped-tier per-worker (stage, fsync, send, apply)
-        # wall times for /timeline + debug dumps; inert in serial mode.
-        self._worker_util: deque = deque(maxlen=256)
         # Last native/Python WAL-engine stats snapshot (cumulative
         # counters — _fold_wal_stats folds deltas into the registry).
         self._wal_stat_last: Optional[dict] = None
@@ -833,19 +783,17 @@ class RaftNode:
         self._inflight_submit = np.zeros(G, np.int32)
         self._inflight_read = np.zeros(G, np.int32)
         # Per-peer outbox sections accumulated across a tick's packing
-        # sites (striped workers' deferred/non-eager sections + the eager
+        # sites (the host phase's deferred/non-eager sections + the eager
         # AE pack) and flushed as ONE frame per peer at end of tick — the
         # accumulator drains one slice per source per tick, so two frames
-        # would back up.  Dict cells are written by at most one worker per
-        # (peer, site): workers stash into per-call lists and the
-        # orchestrator folds, so no cross-thread list.append races.
+        # would back up.  Tick thread only.
         self._held_sections: Dict[int, List[bytes]] = {}
         self.metrics.gauge("pipeline_enabled", int(self.pipeline))
         self.metrics.gauge("wal_shards",
                            getattr(getattr(self.store, "wal", None),
                                    "n_shards", 1))
-        self.metrics.gauge("host_workers", self._w_eff)
-        self.metrics.gauge("native_host", int(self._native_host))
+        self.metrics.gauge("host_workers", self.host_workers)
+        self.metrics.gauge("native_host", int(self._native_wal))
         # Eager leader sends (overlapped ticks): AE frames released right
         # after fetch, ahead of the tick's own fsync (safe — commit only
         # counts fsynced self-matches via HostInbox.durable_tail).
@@ -902,9 +850,8 @@ class RaftNode:
     def latency_snapshot(self) -> dict:
         """The /latency document (runtime/obsrv.py): sampler state, SLO
         burn, per-phase and end-to-end percentiles, recent sampled spans
-        — plus the WAL engines' per-stripe stage/fsync/pack counters and
-        the striped tier's recent per-worker utilization.  Snapshot
-        reads only; safe off the tick thread (same contract as
+        — plus the WAL engines' per-stripe stage/fsync/pack counters.
+        Snapshot reads only; safe off the tick thread (same contract as
         /metrics)."""
         tr = self._lat
         doc = {"enabled": tr is not None}
@@ -915,7 +862,6 @@ class RaftNode:
         if per is not None:
             doc["wal_stripes"] = [
                 dict(s, stripe=i) for i, s in enumerate(per())]
-        doc["worker_util"] = list(self._worker_util)
         doc["txn_plane"] = self.txn.snapshot()
         if self._hops is not None:
             # Hop-phase decomposition of send_commit (the fleet
@@ -1020,9 +966,6 @@ class RaftNode:
             self.store.gc_abort()
         self._gc_phase = 0
         self.dispatcher.close()
-        if self._host_pool is not None:
-            self._host_pool.shutdown(wait=True)
-            self._host_pool = None
         self._fold_wal_stats()   # final engine-counter fold (short runs
         self.store.close()       # never reach a 32-tick maintain pass)
 
@@ -2012,9 +1955,8 @@ class RaftNode:
         # The cheap [G] event-count lane is pulled first; the full rings
         # (and the per-moved-group decode) transfer only on ticks where
         # something actually recorded — a quiet node pays one [G] pull.
-        # NOTE this host drain cost is NOT part of the BENCH_TRACE A/B
-        # (that measures the fused scan); it scales with groups-moved per
-        # tick, like every other host-side per-group path here.
+        # The drain scales with groups-moved per tick, like every other
+        # host-side per-group path here.
         if cfg.trace_depth:
             h_trn = jax.device_get(self.state.trace.n)
             if self.tracelog.moved(h_trn):
@@ -2078,9 +2020,9 @@ class RaftNode:
         each peer receives ONE combined slice per tick (the inbox
         accumulator drains one slice per source per tick).
 
-        With ``host_workers > 1`` the phase fans out across the striped
-        worker pool (``_host_phase_striped``); membership-config ticks
-        fall back to the serial path.
+        Only the persist step varies (``_persist``: the native WAL
+        engine's one call, or the Python engine's stage and barrier);
+        every stage boundary is entered here, once.
 
         Storage faults surface here: a failed durability barrier
         (WalSyncError / WalNoSpace from the store) aborts the rest of
@@ -2092,14 +2034,77 @@ class RaftNode:
         left unconfirmed."""
         pre_tail = self._durable_tail_m.copy()
         self._host_runs += 1
+        G = self.cfg.n_groups
+        st = self._stages
+        m = self.metrics
         try:
             try:
-                if self._native_host and not self._poisoned_stripes:
-                    self._host_phase_native(ctx, defer_send)
-                elif self._w_eff > 1:
-                    self._host_phase_striped(ctx, defer_send)
-                else:
-                    self._host_phase_serial(ctx, defer_send)
+                # -- 4. persistence barrier ----------------------------------
+                # One span, raft.wal, under the native engine (its one
+                # call stages AND fsyncs); raft.wal then raft.fsync under
+                # the Python one.  Either way the two histograms split
+                # the time up to the send boundary by the fsync's own
+                # seconds (observe=False).
+                _t0 = st.enter("wal", observe=False)
+                prep, fsync_s, blob_fn = self._persist(ctx)
+                self._watch_io(fsync_s)
+                if self._lat_tick:
+                    # The Python step stamped STAGED before its barrier
+                    # (a stamp is first-wins); the native call's stage
+                    # and fsync resolve together, here.
+                    self._lat_stamp(STAGED)
+                    self._lat_stamp(FSYNCED)
+                if self._hops is not None:
+                    # The fsynced stamp sits strictly after _barrier_ok():
+                    # a storage-fault abort above means an unsynced tail
+                    # never produces a durability echo.
+                    self._hops.fold_foreign(self._durable_tail_m,
+                                            fsynced=True)
+                self._sweep_rejections(prep)
+                self._hops_scan(ctx)
+                # The arena views the spans pinned are staged: drop the
+                # frame pins early.
+                ctx.staged_payloads = ctx.arrays = None
+                _t1 = st.enter("send")
+                m.observe("tick_stage_wal_s",
+                          max(0.0, (_t1 - _t0) - fsync_s))
+                m.observe("tick_stage_fsync_s", fsync_s)
+
+                # -- 5. release outbox (only ever after the barrier) ---------
+                held = self._stash_outbox_sections(
+                    ctx.outbox, deferred=ctx.deferred_ae, blob_fn=blob_fn)
+                for p, secs in held.items():
+                    self._held_sections.setdefault(p, []).extend(secs)
+                if not defer_send:
+                    self._flush_sends()
+                st.enter("apply")
+                if self._lat_tick:
+                    self._lat_stamp(SENT)
+
+                # -- 6. applies ----------------------------------------------
+                if self._lat is not None:
+                    # Commit stamps strictly precede apply/ack stamps:
+                    # advance() completes promises (and the traced
+                    # batch's ack) below.
+                    self._lat.mark_committed(ctx.commit)
+                before = self.dispatcher.applied_frontier(G)
+                self.dispatcher.advance(ctx.commit)
+                after = self.dispatcher.applied_frontier(G)
+                m["applies"] += int((after - before).sum())
+                m["commits"] = int(ctx.commit.astype(np.int64).sum())
+                st.enter("reads")
+
+                # -- 6b. read plane: stamped/released bookkeeping + serving --
+                self._harvest_reads(ctx.info)
+                self._serve_reads(after)
+                st.enter("maintain")
+
+                # -- 7. maintain: checkpoints, compaction, snapshot downloads
+                self._maintain(after, ctx.base, ctx.term)
+                self._snapshot_requests(ctx.info, ctx.base)
+                # The stages are observed at their boundaries; whichever
+                # phase follows (scan_device, tail, dispatch_intake) ends
+                # maintain.
                 # Empty-payload short-circuits (machine/spi.py
                 # applies_empty opt-in), published behind the apply
                 # phase that counts them: nonzero here explains a
@@ -2107,7 +2112,7 @@ class RaftNode:
                 # digging through warn-once logs.
                 skips = self.dispatcher.empty_skips
                 if skips:
-                    self.metrics.gauge("empty_apply_skips", skips)
+                    m.gauge("empty_apply_skips", skips)
             except (WalNoSpace, WalSyncError) as e:
                 self._storage_fault(e, pre_tail)
         finally:
@@ -2142,24 +2147,43 @@ class RaftNode:
                                    np.asarray(out.ae_prev_idx),
                                    np.asarray(out.ae_n))
 
-    def _host_phase_serial(self, ctx: _TickCtx, defer_send: bool) -> None:
-        G = self.cfg.n_groups
-        st = self._stages
-        st.enter("wal")
-        # -- 4. persistence barrier ------------------------------------------
-        prep = self._persist_prepare(
-            ctx.info, ctx.term, ctx.voted, ctx.leader, ctx.base,
-            ctx.base_term, ctx.staged_payloads, ctx.arrays, ctx.submit_n)
+    def _persist(self, ctx: _TickCtx
+                 ) -> Tuple[_PersistPrep, float, Optional[Callable]]:
+        """The host phase's one varying step: make the tick's writes
+        durable.  Returns the tick's persist plan, the seconds the fsync
+        took, and the outbox pack's ``payload_blob_fn`` (None: the
+        codec's own).  When it returns, the barrier has completed
+        (``_barrier_ok``); a failed one raises out of the host phase.
+
+        The native WAL engine takes the tick in ONE call — arena
+        staging, per-shard fsync on real OS threads with the GIL
+        released — when the store has it, no stripe is quarantined (a
+        poisoned stripe's fsync must never be retried: ``_barrier``
+        carves it out) and the tick carries no membership-config entry
+        (the conf sidecar is one global document, written from Python;
+        ``_persist_prepare`` says so before it mutates anything).
+        Otherwise the Python engine stages on the tick thread and
+        ``_barrier`` fsyncs.  Segment bytes, record order and the
+        ack-after-fsync barrier are the same either way."""
+        prep = None
+        if self._native_wal and not self._poisoned_stripes:
+            prep = self._persist_prepare(ctx, for_stripes=True)
+        if prep is not None:
+            _stage_s, fsync_s = self._persist_stage_native(prep)
+            # The conf sidecar (dirty only when an adoption span
+            # truncated recorded conf entries) flushes before any ack
+            # leaves.
+            self.store.conf_flush()
+            self._barrier_ok()
+            return prep, fsync_s, self._native_blob_fn
+        prep = self._persist_prepare(ctx)
         # NOTE: staging is NOT masked while stripes are quarantined — a
         # poisoned engine only buffers (its flush/fsync never run again),
         # and skipping span-build would drop device-accepted sinks before
         # they register as promises (hung futures).  The carve-out happens
         # at the barrier (_barrier) and at outbox packing (silence).
         need_sync = self._persist_stage(prep)
-        self._sweep_rejections(prep)
-        self._hops_scan(ctx)
-        ctx.staged_payloads = ctx.arrays = None   # drop frame pins early
-        _t1 = st.enter("fsync")
+        _t1 = self._stages.enter("fsync", observe=False)
         if self._lat_tick:
             self._lat_stamp(STAGED)
         if self._hops is not None:
@@ -2167,295 +2191,13 @@ class RaftNode:
         if need_sync or self._sync_pending:
             self._barrier()     # THE durability barrier
             self._barrier_ok()
-        _t2 = st.enter("send")
-        if self._lat_tick:
-            self._lat_stamp(FSYNCED)
-        if self._hops is not None:
-            # The fsynced stamp sits strictly after _barrier_ok(): a
-            # storage-fault abort above means an unsynced tail never
-            # produces a durability echo.
-            self._hops.fold_foreign(self._durable_tail_m, fsynced=True)
-        self._watch_io(_t2 - _t1)
-
-        # -- 5. release outbox (only ever after the barrier) -----------------
-        held = self._stash_outbox_sections(ctx.outbox,
-                                           deferred=ctx.deferred_ae)
-        for p, secs in held.items():
-            self._held_sections.setdefault(p, []).extend(secs)
-        if not defer_send:
-            self._flush_sends()
-        st.enter("apply")
-        if self._lat_tick:
-            self._lat_stamp(SENT)
-
-        # -- 6. applies ------------------------------------------------------
-        if self._lat is not None:
-            # Commit stamps strictly precede apply/ack stamps: advance()
-            # completes promises (and the traced batch's ack) below.
-            self._lat.mark_committed(ctx.commit)
-        before = self.dispatcher.applied_frontier(G)
-        self.dispatcher.advance(ctx.commit)
-        after = self.dispatcher.applied_frontier(G)
-        self.metrics["applies"] += int((after - before).sum())
-        self.metrics["commits"] = int(ctx.commit.astype(np.int64).sum())
-        st.enter("reads")
-
-        # -- 6b. read plane: stamped/released bookkeeping + serving ----------
-        self._harvest_reads(ctx.info)
-        self._serve_reads(after)
-        st.enter("maintain")
-
-        # -- 7. maintain: checkpoints, compaction, snapshot downloads --------
-        self._maintain(after, ctx.base, ctx.term)
-        self._snapshot_requests(ctx.info, ctx.base)
-        # The six stages are observed at their boundaries; whichever
-        # phase follows (scan_device, tail, dispatch_intake) ends maintain.
-
-    def _ensure_host_pool(self) -> ThreadPoolExecutor:
-        """W-1 stripe workers; the tick thread itself is worker 0."""
-        if self._host_pool is None:
-            self._host_pool = ThreadPoolExecutor(
-                max_workers=self._w_eff - 1,
-                thread_name_prefix=f"raft-host-{self.node_id}")
-        return self._host_pool
-
-    def _host_phase_striped(self, ctx: _TickCtx, defer_send: bool) -> None:
-        """The striped host phase: W workers (the tick thread is worker
-        0) each own a disjoint, WAL-stripe-aligned group set end-to-end.
-
-        Phase A — each worker stages ITS groups' durable writes
-        (``_persist_stage`` over its stripe mask) and fsyncs ITS shard
-        files (``store.sync_stripes``); barrier.  Phase B — each worker
-        packs ITS groups' outbox sections and runs ITS groups' applies
-        (``dispatcher.advance`` over a pre-sliced index view); barrier.
-        Reads and maintenance stay on the tick thread (global queues).
-
-        Zero cross-stripe locking: every structure mutated inside a
-        stage is keyed or element-indexed by group, and the stripe map
-        assigns each group to exactly one worker — single-writer-per-
-        group holds by construction.  Ack-after-fsync holds exactly as
-        serial: the Phase A barrier (all shard fsyncs done) strictly
-        precedes any Phase B send or future completion.
-
-        Membership-config ticks (leader conf appends or adopted conf
-        words) return None from prepare and run the serial phase: the
-        conf sidecar is one global JSON doc and conf traffic is rare."""
-        # Phases A and B interleave two stages each inside the workers:
-        # the spans are raft.wal (A) and raft.send (B), and the four
-        # histograms keep the workers' own maxima (observe=False).
-        st = self._stages
-        st.enter("wal", observe=False)
-        prep = self._persist_prepare(
-            ctx.info, ctx.term, ctx.voted, ctx.leader, ctx.base,
-            ctx.base_term, ctx.staged_payloads, ctx.arrays, ctx.submit_n,
-            for_stripes=True)
-        if prep is None:
-            self._host_phase_serial(ctx, defer_send)
-            return
-        G = self.cfg.n_groups
-        W = self._w_eff
-        pool = self._ensure_host_pool()
-        masks, stripes = self._worker_masks, self._worker_stripes
-
-        poisoned = self._poisoned_stripes
-
-        def _phase_a(k: int):
-            a0 = time.perf_counter()
-            staged = self._persist_stage(prep, mask=masks[k])
-            a1 = time.perf_counter()
-            if staged or self._sync_pending:
-                mine = [s for s in stripes[k] if s not in poisoned] \
-                    if poisoned else stripes[k]
-                if mine:
-                    self.store.sync_stripes(mine)
-            return a1 - a0, time.perf_counter() - a1
-
-        futs = [pool.submit(_phase_a, k) for k in range(1, W)]
-        res_a: List[Tuple[float, float]] = []
-        errs: List[Exception] = []
-        try:
-            res_a.append(_phase_a(0))
-        except (WalNoSpace, WalSyncError) as e:
-            errs.append(e)
-            res_a.append((0.0, 0.0))
-        for f in futs:
-            try:
-                res_a.append(f.result())
-            except (WalNoSpace, WalSyncError) as e:
-                errs.append(e)
-                res_a.append((0.0, 0.0))
-        if errs:
-            # EVERY worker has finished — no staging races the fault
-            # handler — and sync_shards already fsynced each worker's
-            # healthy shards before raising, so only the failed stripes'
-            # groups are unconfirmed.  Merge and surface.
-            from ..log.wal import _merge_wal_errors
-            raise _merge_wal_errors(errs)
-        self._watch_io(max(r[1] for r in res_a))
-        # Orchestrator-only tail of the barrier: the conf sidecar (dirty
-        # only when an adoption span truncated recorded conf entries) is
-        # one global file and flushes before any ack leaves; refusal
-        # sweeps touch the submit lock.
-        self.store.conf_flush()
-        self._barrier_ok()
-        if self._lat_tick:
-            # Staged/fsynced resolve at the Phase A barrier (per-stripe
-            # stage and fsync interleave inside the workers, so the
-            # stamps share the all-shards-durable instant).
-            self._lat_stamp(STAGED)
-            self._lat_stamp(FSYNCED)
-        if self._hops is not None:
-            # Staged/fsynced collapse to the Phase A barrier here too;
-            # one fsynced fold stamps both and readies echoes for the
-            # Phase B flush.
-            self._hops.fold_foreign(self._durable_tail_m, fsynced=True)
-        self._sweep_rejections(prep)
-        self._hops_scan(ctx)
-        ctx.staged_payloads = ctx.arrays = None
-
-        st.enter("send", observe=False)
-        self.dispatcher.warm_mirror(G)
-        before = self.dispatcher.applied_frontier(G)
-        groups = self._worker_groups
-        if self._lat is not None:
-            # Commit stamps strictly precede apply/ack stamps: Phase B's
-            # advance() completes promises (and the traced batch's ack).
-            self._lat.mark_committed(ctx.commit)
-
-        def _phase_b(k: int):
-            b0 = time.perf_counter()
-            held = self._stash_outbox_sections(
-                ctx.outbox, deferred=ctx.deferred_ae, mask=masks[k])
-            b1 = time.perf_counter()
-            self.dispatcher.advance(ctx.commit, groups=groups[k])
-            return held, b1 - b0, time.perf_counter() - b1
-
-        futs = [pool.submit(_phase_b, k) for k in range(1, W)]
-        res_b = [_phase_b(0)] + [f.result() for f in futs]
-        for held, _ts, _ta in res_b:
-            for p, secs in held.items():
-                self._held_sections.setdefault(p, []).extend(secs)
-        if not defer_send:
-            self._flush_sends()
-        if self._lat_tick:
-            self._lat_stamp(SENT)
-        after = self.dispatcher.applied_frontier(G)
-        self.metrics["applies"] += int((after - before).sum())
-        self.metrics["commits"] = int(ctx.commit.astype(np.int64).sum())
-        st.enter("reads")
-
-        self._harvest_reads(ctx.info)
-        self._serve_reads(after)
-        st.enter("maintain")
-
-        self._maintain(after, ctx.base, ctx.term)
-        self._snapshot_requests(ctx.info, ctx.base)
-
-        m = self.metrics
-        # Stage times report the BARRIER (max-across-workers) cost — the
-        # wall-clock shape of the tick; per-worker utilization goes to
-        # the stripe_busy_s histogram (one sample per worker per tick).
-        m.observe("tick_stage_wal_s", max(r[0] for r in res_a))
-        m.observe("tick_stage_fsync_s", max(r[1] for r in res_a))
-        m.observe("tick_stage_send_s", max(r[1] for r in res_b))
-        m.observe("tick_stage_apply_s", max(r[2] for r in res_b))
-        for k in range(W):
-            m.observe("stripe_busy_s",
-                      res_a[k][0] + res_a[k][1]
-                      + res_b[k][1] + res_b[k][2])
-        # Per-worker utilization intervals for /timeline + debug dumps:
-        # (stage, fsync, send, apply) wall seconds per worker this tick.
-        self._worker_util.append(
-            {"tick": self.ticks,
-             "workers": [[round(res_a[k][0], 6), round(res_a[k][1], 6),
-                          round(res_b[k][1], 6), round(res_b[k][2], 6)]
-                         for k in range(W)]})
-
-    def _host_phase_native(self, ctx: _TickCtx, defer_send: bool) -> None:
-        """The native host phase: the tick's durable hot loop — arena
-        staging, per-shard fsync, and the AppendEntries payload-blob
-        pack — crosses into the WAL engine's C side, which fans out over
-        real OS threads with the GIL released, while the tick thread
-        stays pure orchestration.  Segment bytes, record order, and the
-        ack-after-fsync barrier are identical to the Python serial and
-        striped paths (recovery is interchangeable between backends).
-
-        Membership-config ticks fall back to the serial phase exactly
-        like the striped path (one global conf sidecar, rare traffic);
-        any native staging failure is an IOError from the store — same
-        failure surface as a Python-path write error."""
-        G = self.cfg.n_groups
-        # One C call stages AND fsyncs: the span is raft.wal for both,
-        # and the two histograms split it by the engine's own fsync time
-        # (observe=False).
-        st = self._stages
-        _t0 = st.enter("wal", observe=False)
-        prep = self._persist_prepare(
-            ctx.info, ctx.term, ctx.voted, ctx.leader, ctx.base,
-            ctx.base_term, ctx.staged_payloads, ctx.arrays, ctx.submit_n,
-            for_stripes=True)
-        if prep is None:
-            self._host_phase_serial(ctx, defer_send)
-            return
-        _st_s, fs_s = self._persist_stage_native(prep)
-        self._watch_io(fs_s)
-        # Orchestrator tail of the barrier (same as striped): the conf
-        # sidecar flushes before any ack leaves; refusal sweeps touch
-        # the submit lock.
-        self.store.conf_flush()
-        self._barrier_ok()
-        if self._lat_tick:
-            # One C call stages AND fsyncs — both stamps resolve at its
-            # return (the split lives in the engine's wal_stats()).
-            self._lat_stamp(STAGED)
-            self._lat_stamp(FSYNCED)
-        if self._hops is not None:
-            self._hops.fold_foreign(self._durable_tail_m, fsynced=True)
-        self._sweep_rejections(prep)
-        self._hops_scan(ctx)
-        # The native call is done — the arena views the spans pinned are
-        # no longer referenced from C.
-        ctx.staged_payloads = ctx.arrays = None
-        _t1 = st.enter("send")
-
-        held = self._stash_outbox_sections(
-            ctx.outbox, deferred=ctx.deferred_ae,
-            blob_fn=self._native_blob_fn)
-        for p, secs in held.items():
-            self._held_sections.setdefault(p, []).extend(secs)
-        if not defer_send:
-            self._flush_sends()
-        st.enter("apply")
-        if self._lat_tick:
-            self._lat_stamp(SENT)
-
-        if self._lat is not None:
-            self._lat.mark_committed(ctx.commit)
-        before = self.dispatcher.applied_frontier(G)
-        self.dispatcher.advance(ctx.commit)
-        after = self.dispatcher.applied_frontier(G)
-        self.metrics["applies"] += int((after - before).sum())
-        self.metrics["commits"] = int(ctx.commit.astype(np.int64).sum())
-        st.enter("reads")
-
-        self._harvest_reads(ctx.info)
-        self._serve_reads(after)
-        st.enter("maintain")
-
-        self._maintain(after, ctx.base, ctx.term)
-        self._snapshot_requests(ctx.info, ctx.base)
-
-        m = self.metrics
-        # wal_s is everything up to the barrier minus the C-measured
-        # fsync share: prepare + span assembly + the native stage.
-        m.observe("tick_stage_wal_s", max(0.0, (_t1 - _t0) - fs_s))
-        m.observe("tick_stage_fsync_s", fs_s)
+        return prep, time.perf_counter() - _t1, None
 
     def _native_blob_fn(self, cols, starts, ns):
         """codec ``payload_blob_fn``: native AE blob pack (None → the
         codec's Python per-column loop)."""
         return self.store.pack_ae_blob(cols, starts, ns,
-                                       workers=self._w_native)
+                                       workers=self.host_workers)
 
     # ------------------------------------------------- storage-fault policy
 
@@ -2604,24 +2346,26 @@ class RaftNode:
 
     # ---------------------------------------------------------- persistence
 
-    def _persist_prepare(self, info: StepInfo, h_term, h_voted, h_leader,
-                         h_base, h_base_term, staged_payloads, inbox_arrays,
-                         submit_n, for_stripes: bool = False
+    def _persist_prepare(self, ctx: _TickCtx, for_stripes: bool = False
                          ) -> Optional[_PersistPrep]:
         """Precompute one tick's persist inputs — change-detection masks,
         the staged-frame metadata fancy-indexes, and the ONE lock'd
-        submission-queue pop — for ``_persist_stage`` to consume, either
-        over the whole group space (serial) or per stripe mask (striped
-        workers, which share one prep).
+        submission-queue pop — for ``_persist_stage`` or
+        ``_persist_stage_native`` to consume.
 
-        ``for_stripes=True`` bails out (returns None) when the tick
-        carries membership-config entries — leader conf appends or
-        adopted conf words: the conf sidecar is one global doc and conf
-        traffic is rare, so those ticks run the serial phase instead.
-        The bail happens BEFORE any mutation (in particular before the
-        submission pop): the serial fallback re-runs prepare, and a
+        ``for_stripes=True`` (the plan is for the native engine, whose
+        threads each own whole stripes) bails out (returns None) when
+        the tick carries membership-config entries — leader conf appends
+        or adopted conf words: the conf sidecar is one global doc and
+        conf traffic is rare, so those ticks take the Python step
+        instead.  The bail happens BEFORE any mutation (in particular
+        before the submission pop): the caller re-runs prepare, and a
         double pop would desynchronize the durable log from the promise
         map."""
+        info, submit_n = ctx.info, ctx.submit_n
+        h_term, h_voted, h_leader = ctx.term, ctx.voted, ctx.leader
+        h_base, h_base_term = ctx.base, ctx.base_term
+        staged_payloads, inbox_arrays = ctx.staged_payloads, ctx.arrays
         dirty_mask = np.asarray(info.dirty)
         app_from = np.asarray(info.appended_from)
         app_to = np.asarray(info.appended_to)
@@ -2646,7 +2390,7 @@ class RaftNode:
             fr_cents = inbox_arrays.get("ae_cents")
             if for_stripes and fr_cents is not None \
                     and bool(fr_cents[src_clip, wrote].any()):
-                # Adopted config words would put_conf from stripe workers.
+                # Adopted config words: put_conf is the Python step's.
                 return None
         else:
             fr_valid = [False] * len(wrote_l)
@@ -2676,7 +2420,7 @@ class RaftNode:
         p.fr_ents, p.fr_cents = fr_ents, fr_cents
         # (term, ballot) change detection (reference RaftMember ctor
         # persists first, context/member/RaftMember.java:25) — the store
-        # writes + mirror updates happen per stage, under its mask.
+        # writes + mirror updates happen in the stage.
         p.stable_mask = dirty_mask & ((h_term != self._stable_term_m)
                                       | (h_voted != self._stable_voted_m))
         noop_arr = np.asarray(info.noop_idx)
@@ -2803,18 +2547,16 @@ class RaftNode:
         self.admission.expired += nb
         out.append((g, b.sink))
 
-    def _stage_stable(self, prep: _PersistPrep,
-                      mask: Optional[np.ndarray] = None) -> bool:
-        """Stage this share's (term, ballot) stable records (durable
+    def _stage_stable(self, prep: _PersistPrep) -> bool:
+        """Stage the tick's (term, ballot) stable records (durable
         before any reply leaves) as ONE batch of moved lanes (steady
         state: an empty call) and refresh the stable mirrors.  Returns
-        whether anything was staged.  Shared by the serial/striped
-        ``_persist_stage`` and the native host phase (stable records are
-        Python-staged into the engine buffers ahead of the native call —
-        the per-shard record order stays stable → entries → truncates →
-        milestones, matching the serial path byte-for-byte)."""
-        st_changed = prep.stable_mask if mask is None \
-            else prep.stable_mask & mask
+        whether anything was staged.  Shared by ``_persist_stage`` and
+        ``_persist_stage_native`` (stable records are Python-staged into
+        the engine buffers ahead of the native call — the per-shard
+        record order stays stable → entries → truncates → milestones,
+        the same bytes either way)."""
+        st_changed = prep.stable_mask
         h_term, h_voted = prep.h_term, prep.h_voted
         if not st_changed.any():
             return False
@@ -2830,17 +2572,12 @@ class RaftNode:
         self._stable_voted_m[st_changed] = h_voted[st_changed]
         return True
 
-    def _build_spans(self, prep: _PersistPrep,
-                     mask: Optional[np.ndarray] = None) -> List[tuple]:
-        """Build this share's arena spans — ``(g, start, piece, lens,
+    def _build_spans(self, prep: _PersistPrep) -> List[tuple]:
+        """Build the tick's arena spans — ``(g, start, piece, lens,
         terms)`` — plus the promise-range registrations and membership
         sidecar records that travel with them.  Pure assembly: no WAL
-        write happens here, so the serial/striped staging path and the
-        native columnar handoff consume identical spans.
-
-        Thread safety under a stripe mask: every dispatcher / sidecar
-        mutation below is keyed by group and worker masks are disjoint —
-        no locks (_host_phase_striped)."""
+        write happens here, so the Python staging path and the native
+        columnar handoff consume identical spans."""
         # Entries appended/overwritten this tick land as contiguous
         # arena SPANS — crossing into the WAL engine once per stage with
         # numpy vectors (VERDICT r4 #2: the per-entry Python staging
@@ -2853,9 +2590,8 @@ class RaftNode:
         # WAL replay order must match index order (an append drops the
         # suffix at >= its index).
         for g in prep.noop_g:
-            if mask is None or mask[g]:
-                spans.append((g, int(prep.noop_idx[g]), b"",
-                              _NOOP_LENS, int(prep.noop_term[g])))
+            spans.append((g, int(prep.noop_idx[g]), b"",
+                          _NOOP_LENS, int(prep.noop_term[g])))
         reg_range = self.dispatcher.register_promise_range
         staged_payloads = prep.staged_payloads
         own_by_g = prep.own_by_g
@@ -2866,10 +2602,7 @@ class RaftNode:
         fr_ents, fr_cents = prep.fr_ents, prep.fr_cents
         put_conf = getattr(self.store, "put_conf", None)
         conf_overwrite = getattr(self.store, "conf_overwrite", None)
-        j_iter = range(len(wrote_l)) if mask is None \
-            else np.nonzero(mask[prep.wrote])[0].tolist()
-        for j in j_iter:
-            g = wrote_l[j]
+        for j, g in enumerate(wrote_l):
             lo, hi = lo_l[j], hi_l[j]
             n_sub = nsub_l[j]
             sub_lo = sublo_l[j]
@@ -2931,7 +2664,7 @@ class RaftNode:
             elif n_sub:
                 # Adoption gap ahead of a same-tick submission range:
                 # unreachable by kernel phase order, asserted like the
-                # queue-depth invariant above (ADVICE r5).  Reaching here
+                # queue-depth invariant above.  Reaching here
                 # needs one tick to BOTH adopt follower entries (phase 4,
                 # gated role != LEADER after the phase-3 election update)
                 # AND accept own submissions (phase 8, requires LEADER) —
@@ -2951,9 +2684,9 @@ class RaftNode:
         # like the §8 no-op — appended AFTER the per-group spans above, so
         # WAL replay order matches index order (a conf entry's index is
         # the tick's highest) — plus the sidecar record recovery rebuilds
-        # the conf ring from.  Serial path only: striped prepare bails on
-        # conf-bearing ticks, so a masked stage never reaches this.
-        if mask is None and (prep.conf_app > 0).any():
+        # the conf ring from.  The Python step only: prepare keeps
+        # conf-bearing ticks from the native engine.
+        if (prep.conf_app > 0).any():
             conf_app, conf_term = prep.conf_app, prep.conf_term
             conf_word = prep.conf_word
             for g in np.nonzero(conf_app > 0)[0].tolist():
@@ -2963,22 +2696,15 @@ class RaftNode:
                     put_conf(int(g), int(conf_app[g]), int(conf_word[g]))
         return spans
 
-    def _persist_stage(self, prep: _PersistPrep,
-                       mask: Optional[np.ndarray] = None) -> bool:
-        """Stage one share of the tick's durable writes (entries, stable
-        records, truncations, floors) into the WAL: the whole group
-        space (mask None — the serial phase) or one stripe worker's
-        groups.  Returns whether the share needs an fsync — the caller
-        issues the barrier (``store.sync`` / ``store.sync_stripes``)
-        and must not release the share's outbox or complete futures
-        before it.  Truncations alone do NOT request a sync (unchanged
-        serial contract: a shrink is re-derived at recovery).
-
-        Thread safety under a stripe mask: every store / dispatcher /
-        mirror mutation below is keyed or element-indexed by group, and
-        worker masks are disjoint — no locks (_host_phase_striped)."""
-        any_write = self._stage_stable(prep, mask)
-        spans = self._build_spans(prep, mask)
+    def _persist_stage(self, prep: _PersistPrep) -> bool:
+        """The Python step's staging: the tick's durable writes
+        (entries, stable records, truncations, floors) into the WAL.
+        Returns whether they need an fsync — the caller issues the
+        barrier (``_barrier``) and must not release the outbox or
+        complete futures before it.  Truncations alone do NOT request a
+        sync (a shrink is re-derived at recovery)."""
+        any_write = self._stage_stable(prep)
+        spans = self._build_spans(prep)
         if spans:
             append_spans = getattr(self.store, "append_spans", None)
             if append_spans is not None:
@@ -3011,8 +2737,6 @@ class RaftNode:
         # Change-detected via the durable-tail mirror (shrinks happen only
         # on conflict/snapshot discard — rare).
         shrunk = prep.dirty_mask & (self._durable_tail_m > prep.log_tail)
-        if mask is not None:
-            shrunk = shrunk & mask
         for g in np.nonzero(shrunk)[0].tolist():
             self.store.truncate_to(g, int(prep.log_tail[g]))
             self._durable_tail_m[g] = prep.log_tail[g]
@@ -3021,8 +2745,6 @@ class RaftNode:
         # mirror keeps this loop over only the groups that moved.
         h_base, h_base_term = prep.h_base, prep.h_base_term
         floors = h_base > self._wal_floor
-        if mask is not None:
-            floors = floors & mask
         wal_floors_moved = False
         for g in np.nonzero(floors)[0].tolist():
             self.store.set_floor(g, int(h_base[g]), int(h_base_term[g]))
@@ -3032,20 +2754,20 @@ class RaftNode:
             wal_floors_moved = True
         return bool(any_write or wal_floors_moved)
 
-    def _persist_stage_native(self, prep: _PersistPrep,
-                              sync: bool = True) -> Tuple[float, float]:
+    def _persist_stage_native(self, prep: _PersistPrep
+                              ) -> Tuple[float, float]:
         """Stage the WHOLE tick's durable writes through the store's
         native ``stage_and_sync`` entry point — entries by raw arena
         pointer, truncations and milestones as columns — and fsync them
         in the same call with real OS threads (worker k owns WAL shards
-        ``s % W == k``, the striped pool's ownership map).  Returns the
-        C-measured ``(stage_s, fsync_s)`` max-across-workers wall times.
+        ``s % W == k``).  Returns the C-measured ``(stage_s, fsync_s)``
+        max-across-workers wall times.
 
-        Per-shard record order matches the serial path byte-for-byte:
+        Per-shard record order matches ``_persist_stage`` byte-for-byte:
         stable records (Python-staged into the engine buffers first) →
         entry frames → truncate records → milestone records.  The
-        truncation/floor sets below are the exact serial change-detected
-        sets; only the store-side staging crosses into C."""
+        truncation/floor sets below are its exact change-detected sets;
+        only the store-side staging crosses into C."""
         any_write = self._stage_stable(prep)
         spans = self._build_spans(prep)
         for g, start_idx, _piece, lens, _terms in spans:
@@ -3055,7 +2777,7 @@ class RaftNode:
         any_write = bool(any_write or spans)
         # Truncations: durable tail must not exceed the device tail.  A
         # span this tick never lifts the mirror past log_tail, so this
-        # post-span mask equals the serial loop's; the store applies the
+        # post-span mask equals _persist_stage's; the store applies the
         # rows verbatim (the caller owns the guard on this path).
         shrunk = prep.dirty_mask & (self._durable_tail_m > prep.log_tail)
         t_gs = np.nonzero(shrunk)[0]
@@ -3070,17 +2792,16 @@ class RaftNode:
         self._wal_floor[f_gs] = f_idx
         self._durable_tail_m[f_gs] = np.maximum(
             self._durable_tail_m[f_gs], f_idx)
-        # Truncations alone do NOT request a sync (serial contract), but
-        # they still stage their records.  A pending barrier (ENOSPC
+        # Truncations alone do NOT request a sync (_persist_stage's
+        # contract), but they still stage their records.  A pending barrier (ENOSPC
         # retry: engines kept their staged buffers) forces the fsync
         # even on a write-free tick, else the buffers never flush.
-        need_sync = sync and bool(any_write or len(f_gs)
-                                  or self._sync_pending)
+        need_sync = bool(any_write or len(f_gs) or self._sync_pending)
         if not (spans or len(t_gs) or len(f_gs) or need_sync):
             return 0.0, 0.0
         return self.store.stage_and_sync(
             spans, t_gs, t_tails, f_gs, f_idx, f_term,
-            workers=self._w_native, sync=need_sync)
+            workers=self.host_workers, sync=need_sync)
 
     def _sweep_rejections(self, prep: _PersistPrep) -> None:
         """Submissions offered but refused because we are no longer
@@ -3581,15 +3302,12 @@ class RaftNode:
     def _stash_outbox_sections(self, h_out,
                                deferred: Optional[Dict[int, np.ndarray]]
                                = None,
-                               mask: Optional[np.ndarray] = None,
                                blob_fn: Optional[Callable] = None
                                ) -> Dict[int, List[bytes]]:
-        """Pack (a share of) one tick's outbox into per-peer kind
+        """Pack one tick's outbox into per-peer kind
         sections and return {peer: [sections]} — the caller folds into
         ``_held_sections``; ``_flush_sends`` assembles each peer's
-        sections into ONE MSGS frame.  ``mask`` restricts to a stripe
-        worker's groups (sections from different stripes concatenate in
-        the frame; unpack_slice merges them).  ``deferred`` replaces the
+        sections into ONE MSGS frame.  ``deferred`` replaces the
         valid-column scan for the eager kinds: only the AE columns the
         eager pack dropped (payloads not yet staged) are packed here —
         the rest of the AE traffic already left right after fetch."""
@@ -3598,9 +3316,7 @@ class RaftNode:
         # ever leaves (their staged ranges may not be durable here — a
         # resent AE could let followers quorum-commit a range this node
         # cannot back).  Central choke point for every packing site.
-        hm = self._healthy_groups
-        if hm is not None:
-            mask = hm if mask is None else (mask & hm)
+        mask = self._healthy_groups
         fields_all = {name: np.asarray(getattr(h_out, name))
                       for name in self.template}
         win = self.store.payloads_window

@@ -14,8 +14,7 @@ curl and scraped by Prometheus, with no new dependencies:
   (reference Leader.isReady, Leader.java:52-64), plus tick/uptime vitals;
 * ``GET /timeline?group=N``   — the flight recorder's decoded per-group
   event timeline (``utils/tracelog.TraceLog``), the "which replica did
-  what when" view; empty unless ``cfg.trace_depth > 0`` — plus the
-  striped host tier's recent per-worker utilization intervals;
+  what when" view; empty unless ``cfg.trace_depth > 0``;
 * ``GET /latency``            — the sampled commit-path latency plane
   (``utils/latency.py``): sampler state, SLO burn, per-phase and
   end-to-end percentile tables, recent sampled spans with per-phase
@@ -222,9 +221,6 @@ class ObservabilityServer:
             "trace_depth": int(n.cfg.trace_depth),
             "events": n.tracelog.timeline(g),
             "dropped_total": int(n.tracelog.dropped_total),
-            # Striped host tier: recent per-worker (stage, fsync, send,
-            # apply) wall seconds per tick — empty in serial mode.
-            "worker_util": list(getattr(n, "_worker_util", ())),
         }
 
     # --------------------------------------------------------- lifecycle --

@@ -59,6 +59,17 @@ def free_ports(n: int) -> List[int]:
             s.close()
 
 
+def wal_store_factory(root: str, engine: str, shards: int = 4):
+    """A ``store_factory`` for a LocalCluster under ``root`` whose nodes'
+    WAL stores run ``engine`` — ``"native"`` or ``"python"``.  The engine
+    is a property of the store (``LogStore(force_python=...)``), and a
+    node takes its persist step from it."""
+    from ..log.store import LogStore
+    return lambda i: LogStore(os.path.join(root, f"node{i}", "wal"),
+                              force_python=(engine == "python"),
+                              shards=shards)
+
+
 class LocalCluster:
     def __init__(self, cfg: EngineConfig, root: str,
                  provider_factory: Optional[Callable[[int], object]] = None,
@@ -87,7 +98,7 @@ class LocalCluster:
         test/resources/raft1.xml:3-7).
         ``pipeline`` / ``wal_shards`` / ``host_workers``: forwarded to
         every RaftNode (see RaftNode.__init__; None = the node's
-        env-driven defaults)."""
+        defaults)."""
         self.cfg = cfg
         self.root = root
         self.seed = seed

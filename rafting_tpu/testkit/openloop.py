@@ -1,7 +1,7 @@
 """Open-loop traffic harness: offered load that does NOT wait for you.
 
-The closed-loop drivers everywhere else in this repo (bench.py,
-LocalCluster tests) submit, wait, submit — so offered load automatically
+The closed-loop drivers elsewhere in this repo (the LocalCluster
+tests) submit, wait, submit — so offered load automatically
 tracks capacity and latency collapse is INVISIBLE: the system can't be
 overloaded by a driver that politely blocks (ROADMAP item 5: "the
 current closed-loop burst bench can't see latency collapse").  Real

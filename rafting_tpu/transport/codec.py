@@ -422,10 +422,11 @@ def pack_kind_section(kind: str, fields: Dict[str, np.ndarray],
     """Pack ONE kind's wire section (the ``<BI>`` kind header + columns +
     field planes [+ ae payload blob]) for the given column ids.
 
-    ``cols`` defaults to every valid column; a striped packer passes its
-    own group subset so each stripe packs independently and the per-peer
-    sections concatenate via :func:`assemble_slice` (``unpack_slice``
-    accumulates repeated kinds).  Returns ``(section, n_cols, dropped)``:
+    ``cols`` defaults to every valid column; a packer of a subset (the
+    deferred AE columns, the healthy groups under a quarantine) passes
+    its own, and the per-peer sections concatenate via
+    :func:`assemble_slice` (``unpack_slice`` accumulates repeated
+    kinds).  Returns ``(section, n_cols, dropped)``:
     ``dropped`` lists the ``ae`` columns whose payloads were unavailable —
     an eager (pre-persist) packer defers them to the host phase, where the
     entries are staged; the serial pack path treats a drop as network loss
@@ -580,8 +581,8 @@ def unpack_slice(body: bytes, template: Dict[str, Tuple[np.dtype, tuple]],
     materialize).  ``n_groups`` bounds-checks column ids so a corrupt or
     shape-mismatched frame can't scatter out of range.
 
-    A kind may appear in SEVERAL sections (striped packers and the
-    eager/deferred AE split each contribute one per frame —
+    A kind may appear in SEVERAL sections (the eager/deferred AE split
+    contributes one each per frame —
     :func:`assemble_slice`): their columns CONCATENATE in section order,
     so the consumer's dense scatter is last-wins for a duplicated
     (kind, group) lane, and a later section's payload run replaces an

@@ -1,7 +1,7 @@
 """Persistent XLA compile cache, placed from outside the process.
 
-Every entry script (chip_smoke.py, bench.py --child, bench_runtime.py,
-noderun, __graft_entry__) calls :func:`enable_compile_cache` once before
+Every entry script (chip_smoke.py, benchmark/run.py, noderun,
+__graft_entry__) calls :func:`enable_compile_cache` once before
 its first jit.  A cold ``node_step`` at 100k groups compiles for about a
 minute; without a persistent cache every process pays that again.
 

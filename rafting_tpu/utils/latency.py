@@ -29,12 +29,12 @@ Design:
   close — retires with ``outcome-unknown`` (or ``refused`` for marked
   pre-log refusals) and contributes NO latency sample: a crashed span
   must never fabricate a latency.
-* **Rings**: spans retire into per-thread ring buffers (client threads,
-  stripe workers, the tick thread each own one deque; registration of
+* **Rings**: spans retire into per-thread ring buffers (client threads
+  and the tick thread each own one deque; registration of
   a new ring takes the only lock in the retire path).  The tick thread
   merges rings at :meth:`harvest` and is the sole writer of the shared
   histograms — the registry keeps its single-writer contract (see
-  utils/metrics.py) with W striped workers in play.
+  utils/metrics.py).
 * **Admission** is bounded (``max_live``): the sampler's *selection* is
   deterministic, but at most ``max_live`` spans are in flight at once —
   overflow candidates are counted (``span_overflow``), not traced, so

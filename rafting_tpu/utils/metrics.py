@@ -53,10 +53,10 @@ class Histogram:
 
     Thread contract — SINGLE WRITER, many readers.  ``observe`` (and
     ``reset``/``merge``) must only be called from one thread at a time;
-    in the node runtime that is the tick thread: the striped host tier's
-    W workers return their stage timings through the phase barrier and
-    the tick thread observes the per-tick max (runtime/node.py striped
-    phase), and the latency tracer's client-thread samples park in
+    in the node runtime that is the tick thread: the native WAL
+    engine's threads return their stage and fsync timings through the
+    one call and the tick thread observes them (runtime/node.py
+    _host_phase), and the latency tracer's client-thread samples park in
     per-thread rings that the tick thread drains in ``harvest``
     (utils/latency.py).  Concurrent ``observe`` from two threads would
     lose increments (``counts[i] += 1`` is a read-modify-write) — grow a

@@ -2,34 +2,19 @@
 logback lines only, support/RaftConfig.java:137-141 — so the TPU build adds
 JAX profiler integration from the start).
 
-* :func:`device_trace` — context manager wrapping a measurement region in
-  ``jax.profiler.trace`` so XLA device timelines land in TensorBoard format
-  (bench.py uses it around its measure loop via BENCH_PROFILE_DIR).
-* :class:`StageSpans` — names the phase a node's tick thread is in.  At
-  each phase boundary it observes ``tick_stage_<name>_s`` in the node's
-  registry and, while ANY ``jax.profiler`` session runs (whoever started
-  it), emits a ``raft.<name>`` span carrying ``node`` and ``tick`` on
-  ``/host:CPU`` of that session, on the device trace's clock.
+:class:`StageSpans` names the phase a node's tick thread is in.  At
+each phase boundary it observes ``tick_stage_<name>_s`` in the node's
+registry and, while ANY ``jax.profiler`` session runs (whoever started
+it), emits a ``raft.<name>`` span carrying ``node`` and ``tick`` on
+``/host:CPU`` of that session, on the device trace's clock.
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Dict, Optional
 
 from jax.profiler import TraceAnnotation
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: Optional[str]):
-    """Trace the enclosed region to ``log_dir`` (no-op if falsy)."""
-    if not log_dir:
-        yield
-        return
-    import jax
-    with jax.profiler.trace(log_dir):
-        yield
 
 
 class StageSpans:

@@ -151,7 +151,7 @@ def test_context_lifecycle(tcp_cluster):
     # Budgets are deliberately WIDE (120s lifecycle, 90s waits): this test
     # runs after the heavy cluster suites and their background tick loops
     # contend for CPU — the in-suite flake was a WaitTimeoutError on a
-    # lifecycle tx that passes comfortably in isolation (ADVICE r5).  The
+    # lifecycle tx that passes comfortably in isolation.  The
     # wide budget costs nothing on the healthy path (every wait returns as
     # soon as its predicate holds).
     cs = tcp_cluster

@@ -1,16 +1,18 @@
-"""Hot-path lint (tier-1): the columnar rewrites that feed the striped
-host tier must not silently regress into per-group Python loops.
+"""Hot-path lint (tier-1): the columnar rewrites that feed the host
+phase must not silently regress into per-group Python loops.
 
 The durable tick's cost model is O(groups-VISITED), not O(n_groups): a
 single reintroduced ``for g in range(n_groups)`` on the persist/send/
 apply/read path turns a 100k-group tick from microseconds back into
 hundreds of milliseconds and no functional test catches it — throughput
-regressions only show in benches.  This lint greps the hot methods'
+regressions only show on the chip.  This lint greps the hot methods'
 source for the banned idioms instead; sparse ``np.nonzero(...)``-driven
 ``.tolist()`` loops over dirty subsets remain the approved pattern."""
 
 import ast
 import inspect
+import os
+import re
 import textwrap
 
 import rafting_tpu.runtime.node as node_mod
@@ -22,7 +24,7 @@ HOT_METHODS = (
     "_persist_prepare", "_persist_stage", "_sweep_rejections",
     "_stash_outbox_sections", "_eager_send", "_flush_sends",
     "_harvest_reads", "_serve_reads",
-    "_host_phase_serial", "_host_phase_striped",
+    "_host_phase", "_persist", "_persist_stage_native", "_build_spans",
     "_recover_machines",
 )
 BANNED = (
@@ -42,6 +44,27 @@ def test_hot_methods_have_no_dense_group_loops():
                 f"({pat!r}): visit np.nonzero(...) sparse subsets instead "
                 f"— see _persist_stage's wrote/mask idiom and "
                 f"_serve_reads' _rel_min columnar gate")
+
+
+def test_one_host_phase_and_no_switch_to_fork_it():
+    """The host phase is written once (``_host_phase``; its one varying
+    step is ``_persist``, chosen from what the store can do), and no
+    source file of the package names an environment switch that used to
+    fork it, or any knob of the retired CPU-era bench scripts."""
+    phases = [n for n in vars(RaftNode) if n.startswith("_host_phase")]
+    assert phases == ["_host_phase"], phases
+    gone = re.compile(
+        r"RAFT_PIPELINE|RAFT_NATIVE_HOST|RAFT_HOST_WORKERS|BENCH_[A-Z]")
+    pkg = os.path.dirname(os.path.dirname(node_mod.__file__))
+    for root, _dirs, files in os.walk(pkg):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(root, f)) as fh:
+                    hit = gone.search(fh.read())
+                assert hit is None, (
+                    f"{os.path.join(root, f)} names {hit.group(0)}: the "
+                    f"engine is the store's (LogStore(force_python=...)), "
+                    f"the order the node's own (pipeline=, settles_now)")
 
 
 def test_a_tick_crosses_the_device_boundary_packed():

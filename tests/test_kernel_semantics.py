@@ -242,7 +242,7 @@ def test_heartbeat_reply_echoes_empty_flag():
 
 def test_exempt_heartbeat_reply_cannot_release_hb_slot():
     """Only replies to OCCUPYING heartbeats (aer_empty & aer_occ) release
-    hb_inflight (ADVICE r4): a reply to a window-full slot-EXEMPT
+    hb_inflight: a reply to a window-full slot-EXEMPT
     heartbeat (ae_occ=False) must not free a slot whose real ack was
     lost — that would disarm the RPC-timeout failure detector for the
     lost reply.  The follower echoes the AE's ae_occ verbatim; the

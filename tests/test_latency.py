@@ -18,7 +18,7 @@ from rafting_tpu.core.types import EngineConfig
 from rafting_tpu.log import wal as wal_mod
 from rafting_tpu.log.store import LogStore
 from rafting_tpu.api import StorageFaultError
-from rafting_tpu.testkit.harness import LocalCluster
+from rafting_tpu.testkit.harness import LocalCluster, wal_store_factory
 from rafting_tpu.utils.latency import (
     ACKED, COMMITTED, PHASE_PAIRS, SUBMITTED, LatencyTracer,
     tracer_from_env,
@@ -98,18 +98,27 @@ def test_disabled_plane_holds_no_tracer(tmp_path, monkeypatch):
 # -------------------------------------------- span completeness (e2e) --
 
 
+@pytest.mark.parametrize("engine", [
+    "python", pytest.param("native", marks=pytest.mark.skipif(
+        not wal_mod.native_available(), reason="native WAL engine unavailable"))])
 @pytest.mark.parametrize("pipeline", [False, True],
                          ids=["serial", "pipelined"])
 def test_span_completeness_and_reconciliation(tmp_path, monkeypatch,
-                                              pipeline):
+                                              pipeline, engine):
     """Rate-1 sampling through a live cluster: every acked submit yields
     an outcome-ok span with every write-phase stamp in protocol order,
     and the phase-pair histograms telescope — the sum of per-phase means
     equals the end-to-end mean (the /latency vs /metrics reconciliation
-    the acceptance criteria call for)."""
+    the acceptance criteria call for).  Under either persist step: the
+    Python one stamps ``staged`` before its barrier and ``fsynced``
+    behind it, the native one both at its one call's return."""
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
-    c = LocalCluster(CFG, str(tmp_path), pipeline=pipeline)
+    c = LocalCluster(
+        CFG, str(tmp_path), pipeline=pipeline,
+        store_factory=wal_store_factory(str(tmp_path), engine))
     try:
+        assert all(n.store.can_stage_native == (engine == "native")
+                   for n in c.nodes.values())
         c.wait_leader(0)
         for i in range(6):
             c.submit_via_leader(0, b"span-%d" % i)
@@ -170,12 +179,9 @@ def test_crashed_span_is_outcome_unknown_never_a_latency(tmp_path,
     agree — a crashed span must never fabricate a latency."""
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
 
-    def store_factory(i):
-        import os
-        return LogStore(os.path.join(str(tmp_path), f"node{i}", "wal"),
-                        force_python=True, shards=4)
-
-    c = LocalCluster(CFG, str(tmp_path), store_factory=store_factory)
+    c = LocalCluster(
+        CFG, str(tmp_path),
+        store_factory=wal_store_factory(str(tmp_path), "python"))
     try:
         lead = c.wait_leader(0)
         c.submit_via_leader(0, b"pre-fault")
@@ -248,14 +254,6 @@ def test_latency_endpoint_and_exposition_roundtrip(tmp_path, monkeypatch):
         assert h["latency"]["slo_target_s"] > 0
         assert "e2e_p999_s" in h["latency"]
         assert "io_slow" in h["latency"]
-
-        # /timeline carries striped worker-utilization intervals.
-        status, body = _get(srv.port, "/timeline?group=0")
-        t = json.loads(body)
-        assert "worker_util" in t
-        for iv in t["worker_util"]:
-            assert len(iv["workers"]) == 2     # host_workers=2
-            assert all(len(w) == 4 for w in iv["workers"])
 
         # Discoverability: the 404 page lists /latency.
         status, body = _get(srv.port, "/nope")
@@ -384,13 +382,13 @@ def test_histogram_merge_shards():
         a.merge(Histogram(bounds=[1.0, 2.0]))
 
 
-def test_striped_tier_observes_only_from_tick_thread(tmp_path,
-                                                     monkeypatch):
-    """The documented single-writer contract, enforced: with W=4 striped
-    workers under submit load, every Histogram.observe lands on the tick
-    thread — workers hand their timings through the phase barrier and
-    client threads park samples in tracer rings, so the registry never
-    sees a second writer."""
+def test_host_phase_observes_only_from_tick_thread(tmp_path,
+                                                   monkeypatch):
+    """The documented single-writer contract, enforced: with the WAL
+    engine at width 4 under submit load, every Histogram.observe lands
+    on the tick thread — the engine's threads hand their timings back
+    through the one call and client threads park samples in tracer
+    rings, so the registry never sees a second writer."""
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
     seen = set()
     orig = Histogram.observe

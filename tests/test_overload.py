@@ -15,7 +15,7 @@ lost acks — every OK-acked payload is applied, no shed payload ever is.
 
 The 2x-capacity no-collapse A/B sweep (goodput plateau + bounded
 admitted p999 with admission on; latency collapse with RAFT_ADMISSION=0)
-is ``slow``-marked; BENCH_OPENLOOP=1 in bench.py runs the full version.
+is ``slow``-marked.
 """
 
 import errno
@@ -524,8 +524,7 @@ def test_openloop_2x_no_collapse_ab(tmp_path, monkeypatch):
     """The ISSUE 15 acceptance demo, sized for CI: at ~2x capacity the
     admission-controlled cluster keeps goodput >= 85% of peak with the
     admitted p999 inside the SLO, while the SAME offered load with
-    RAFT_ADMISSION=0 blows the tail (late/pending work piles up).
-    BENCH_OPENLOOP=1 in bench.py runs the full 0.5x-3x sweep."""
+    RAFT_ADMISSION=0 blows the tail (late/pending work piles up)."""
     import time as _time
 
     # Bench-sized engine: enough log slack that snapshot compaction
@@ -537,7 +536,7 @@ def test_openloop_2x_no_collapse_ab(tmp_path, monkeypatch):
 
     def probe_capacity(c):
         # Closed-loop throughput at this scale: burst-submit to every
-        # leader, tick until drained, repeat (same probe as bench.py).
+        # leader, tick until drained, repeat.
         t0 = _time.monotonic()
         done = 0
         for _ in range(12):

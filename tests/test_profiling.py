@@ -1,7 +1,6 @@
 """JAX profiler hooks (SURVEY §5: the reference ships zero tracing; the TPU
-build integrates the device profiler from the start): ``device_trace``
-around a region, and the tick loop's stage spans (utils/profiling.py
-StageSpans) — a ``tick_stage_<name>_s`` sample per phase per tick and a
+build integrates the device profiler from the start): the tick loop's
+stage spans (utils/profiling.py StageSpans) — a ``tick_stage_<name>_s`` sample per phase per tick and a
 ``raft.<name>`` span in whatever ``jax.profiler`` session is running.
 All assertions are on counts or on one thread's own clock."""
 
@@ -11,25 +10,16 @@ import time
 import pytest
 
 from rafting_tpu.core.types import EngineConfig
-from rafting_tpu.testkit.harness import LocalCluster
+from rafting_tpu.log import native_available
+from rafting_tpu.testkit.harness import LocalCluster, wal_store_factory
 from rafting_tpu.utils import profiling
 from rafting_tpu.utils.metrics import Metrics
-from rafting_tpu.utils.profiling import StageSpans, device_trace
+from rafting_tpu.utils.profiling import StageSpans
 
 # The top-level stages: every instant of tick() is in exactly one of them
 # (dispatch = intake + upload + enqueue, scan_wait = device + fetch).
 TOP_STAGES = ("dispatch", "wal", "fsync", "send", "apply", "reads",
               "maintain", "scan_wait", "mirrors", "eager_send", "tail")
-
-
-def test_device_trace_context(tmp_path):
-    import jax.numpy as jnp
-    d = str(tmp_path / "t")
-    with device_trace(d):
-        jnp.ones((8, 8)).sum().block_until_ready()
-    assert glob.glob(d + "/**/*.xplane.pb", recursive=True)
-    with device_trace(""):   # falsy -> no-op
-        pass
 
 
 def _raft_spans(trace_dir):
@@ -50,17 +40,26 @@ def _stage_total(node) -> float:
                if f"tick_stage_{s}_s" in h)
 
 
+@pytest.mark.parametrize("engine", [
+    "python", pytest.param("native", marks=pytest.mark.skipif(
+        not native_available(), reason="native WAL engine unavailable"))])
 @pytest.mark.parametrize("pipeline", [False, True],
                          ids=["serial", "pipelined"])
-def test_stages_cover_the_tick(tmp_path, pipeline):
+def test_stages_cover_the_tick(tmp_path, pipeline, engine):
     """Over 50 manual ticks of a 16-lane node the stage totals sum to at
     least 95% of the time spent inside tick(), on the ticking thread's
-    own clock, and the composite stages are the sums of their parts."""
+    own clock, and the composite stages are the sums of their parts —
+    under either persist step: the Python one enters ``wal`` and
+    ``fsync``, the native one ``wal`` alone, and both leave one sample
+    a host phase in each of the two histograms."""
     cfg = EngineConfig(n_groups=16, n_peers=3)
-    c = LocalCluster(cfg, str(tmp_path), seed=1, pipeline=pipeline)
+    c = LocalCluster(
+        cfg, str(tmp_path), seed=1, pipeline=pipeline,
+        store_factory=wal_store_factory(str(tmp_path), engine))
     try:
         c.wait_leader(0)
         node = c.nodes[0]
+        assert node.store.can_stage_native == (engine == "native")
         before, loop = _stage_total(node), 0.0
         n0 = node.metrics.histogram("tick_latency_s").n
         for _ in range(50):
@@ -83,6 +82,9 @@ def test_stages_cover_the_tick(tmp_path, pipeline):
         assert h["tick_latency_s"].total + h["tick_stage_tail_s"].total \
             == pytest.approx(_stage_total(node), rel=1e-6)
         assert ("tick_stage_eager_send_s" in h) == pipeline
+        assert h["tick_stage_wal_s"].n == h["tick_stage_fsync_s"].n \
+            == h["tick_stage_send_s"].n
+        assert ("fsync" in node._stages.spent) == (engine == "python")
     finally:
         c.close()
 

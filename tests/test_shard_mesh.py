@@ -89,8 +89,8 @@ def test_sharded_matches_unsharded_bitexact():
 
 
 def test_sharded_bench_shape_5peer_L256():
-    """The tuned bench shape on a sharded mesh (VERDICT r4 #4): 5 peers
-    and L=256 — config-4's peer count with bench_runtime's ring — with
+    """A deep-ring shape on a sharded mesh (VERDICT r4 #4): 5 peers
+    and L=256 — config-4's peer count with a 256-slot ring — with
     the node axis replicated (5 does not divide the device count; the
     group axis carries the parallelism, exactly the single-chip scaling
     story) and the group axis split 8 ways.  Bit-exact parity with the

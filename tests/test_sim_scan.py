@@ -1,5 +1,5 @@
 """The fused scan path (`run_cluster_ticks`) under test — the exact program
-the driver artifacts (bench.py, __graft_entry__.dryrun_multichip) run.
+__graft_entry__.dryrun_multichip runs.
 
 These tests pin (a) bit-parity between the fused scan and the per-tick
 `DeviceCluster.tick` path and (b) the group-blocked runner's protocol

@@ -16,8 +16,8 @@ end on full RaftNode clusters (ISSUE 12 acceptance scenarios).
   parity) and by the background scrubber (quarantined to ``*.corrupt``
   before any reader trusts it).
 
-Parametrized over both WAL tiers (Python / native) and host-worker
-widths W ∈ {1, 4}, like the striped host-tier suite.
+Parametrized over both WAL engines, the native one at thread widths
+W ∈ {1, 2, 4} (the Python one stages on the tick thread, width 1).
 """
 
 import errno
@@ -29,24 +29,23 @@ import pytest
 
 from rafting_tpu.api import BusyLoopError, StorageFaultError
 from rafting_tpu.core.types import EngineConfig
-from rafting_tpu.log import LogStore, native_available
+from rafting_tpu.log import native_available
 from rafting_tpu.snapshot.policy import MaintainAgreement
 from rafting_tpu.testkit import faultfs
-from rafting_tpu.testkit.harness import LocalCluster
+from rafting_tpu.testkit.harness import LocalCluster, wal_store_factory
 
 CFG = EngineConfig(n_groups=4, n_peers=3, log_slots=32, batch=4,
                    max_submit=4, election_ticks=10, heartbeat_ticks=3,
                    rpc_timeout_ticks=8)
 
-TIERS = [("python", 1), ("python", 4)] + (
-    [("native", 1), ("native", 4)] if native_available() else [])
+TIERS = [("python", 1)] + (
+    [("native", 1), ("native", 2), ("native", 4)]
+    if native_available() else [])
 
 
 def make_cluster(root, tier, workers, maintain_factory=None):
-    def store_factory(i):
-        return LogStore(os.path.join(root, f"node{i}", "wal"),
-                        force_python=(tier == "python"), shards=4)
-    return LocalCluster(CFG, root, store_factory=store_factory,
+    return LocalCluster(CFG, root,
+                        store_factory=wal_store_factory(root, tier),
                         host_workers=workers,
                         maintain_factory=maintain_factory)
 

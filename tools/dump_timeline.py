@@ -17,8 +17,7 @@ selects the node axis of a stacked [N, G, D] cluster dump (default 0).
 Dumps saved with ``meta={"latency": node.latency_snapshot()}`` also
 carry the PR 13 latency plane: sampled lifecycle spans interleave with
 the group's flight-recorder events on the shared tick axis (a span
-prints after the last event at or before its accept tick), and the
-striped host tier's per-worker utilization intervals print per tick.
+prints after the last event at or before its accept tick).
 Use tools/latency_report.py for the percentile/SLO view of the same
 snapshot.
 """
@@ -68,9 +67,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     lanes = load_dump(args.dump)
-    # Latency-plane meta (optional): sampled spans + per-worker
-    # utilization ride the artifact's _meta lane, which load_dump's
-    # typed-lane view drops — read the raw JSON for it
+    # Latency-plane meta (optional): sampled spans ride the artifact's
+    # _meta lane, which load_dump's typed-lane view drops — read the
+    # raw JSON for it
     # (gzip-transparent: dumps may be .json or .json.gz).
     with tracelog._open_dump(args.dump) as f:
         meta = json.load(f).get("_meta") or {}
@@ -78,8 +77,6 @@ def main(argv=None) -> int:
     spans_by_g = {}
     for sp in lat.get("recent") or []:
         spans_by_g.setdefault(sp.get("group", -1), []).append(sp)
-    util_by_tick = {u.get("tick"): u.get("workers") or []
-                    for u in lat.get("worker_util") or []}
     stacked = lanes["n"].ndim == 2
     counts = lanes["n"][args.node] if stacked else lanes["n"]
     groups = ([args.group] if args.group is not None
@@ -93,8 +90,7 @@ def main(argv=None) -> int:
                     "total": int(counts[g]), "spans": spans_by_g.get(g, [])})
     try:
         if args.as_json:
-            print(json.dumps({"groups": out,
-                              "worker_util": lat.get("worker_util") or []}))
+            print(json.dumps({"groups": out}))
             return 0
         for doc in out:
             head = (f"group {doc['group']}: {doc['total']} events"
@@ -113,10 +109,6 @@ def main(argv=None) -> int:
                 print(f"  #{ev['seq']:<5d} tick {ev['tick']:<8d} "
                       f"term {ev['term']:<6d} {ev['event']:<22s} "
                       f"aux={tracelog.format_aux(ev['kind'], ev['aux'])}")
-                util = util_by_tick.pop(ev["tick"], None)
-                if util is not None:
-                    print(f"         tick {ev['tick']:<8d} workers "
-                          f"[stage,fsync,send,apply]s: {util}")
             for sp in spans[si:]:
                 _print_span(sp)
         if not out:

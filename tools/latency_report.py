@@ -7,9 +7,8 @@ wrong saves its flight-recorder rings with
 ``rafting_tpu.utils.tracelog.save_dump(path, trace,
 meta={"latency": node.latency_snapshot()})``, and this tool renders the
 embedded snapshot — per-phase and end-to-end percentile tables, the SLO
-burn, recent sampled spans with per-phase breakdowns, per-stripe WAL
-engine timings and striped-worker utilization — with no engine, device,
-or live process required (same zero-dependency contract as
+burn, recent sampled spans with per-phase breakdowns and per-stripe WAL
+engine timings — with no engine, device, or live process required (same zero-dependency contract as
 tools/dump_timeline.py).
 
 Usage:
@@ -113,14 +112,6 @@ def render(doc: dict, spans: int = 8, out=sys.stdout) -> None:
                   f"pack={_fmt_s(s.get('pack_ns', 0) / 1e9)} "
                   f"bytes={s.get('bytes', 0)} "
                   f"fsyncs={s.get('fsync_calls', 0)}", file=out)
-    util = doc.get("worker_util") or []
-    if util:
-        last = util[-1]
-        print(f"striped workers (tick {last.get('tick')}, "
-              f"{len(util)} intervals recorded): "
-              "[stage, fsync, send, apply] seconds", file=out)
-        for k, w in enumerate(last.get("workers") or []):
-            print(f"  worker {k}: {w}", file=out)
 
 
 def main(argv=None) -> int:
