@@ -81,11 +81,11 @@ def info_pspecs(qc: bool = False) -> StepInfo:
 def host_pspecs(durable: bool = False) -> HostInbox:
     """Specs for a stacked [N, ...] HostInbox (callers that device_put a
     pre-built inbox instead of folding ``auto_host_inbox`` into the scan).
-    ``read_veto`` is a per-node scalar; ``durable`` must match whether the
-    inbox carries the durable-tail feedback lane (a None subtree needs a
+    ``read_veto`` and ``clock`` are per-node scalars; ``durable`` must
+    match whether the inbox carries the durable-tail feedback lane (a None subtree needs a
     None spec, exactly like the trace lanes in :func:`state_pspecs`)."""
     kw = {f.name: _NODE_GROUP for f in dataclasses.fields(HostInbox)}
-    kw["read_veto"] = _NODE
+    kw["read_veto"] = kw["clock"] = _NODE
     kw["durable_tail"] = _NODE_GROUP if durable else None
     return HostInbox(**kw)
 

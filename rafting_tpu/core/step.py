@@ -229,7 +229,12 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     G, P, B, L, S = (cfg.n_groups, cfg.n_peers, cfg.batch, cfg.log_slots,
                      cfg.max_submit)
     s = state
-    now = s.now + 1
+    # The clock is the host's to advance (HostInbox.clock): by 1 on the
+    # step a period's timer starts, by 0 on a step that arriving work
+    # starts inside the period.  Every deadline below compares against
+    # it, so the steps of one period are one tick of the protocol's
+    # clock, delivered in pieces.
+    now = s.now + host.clock
     rng, k_to = jax.random.split(s.rng)
     # One randomized election window per group per tick, consumed by whichever
     # lanes reset their timer (reference RaftConfig.electionTimeout re-draws on
@@ -669,6 +674,29 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # host.read_veto (host runtime detected a wall-clock tick gap) drops
     # stored AND same-tick evidence: a paused host's inbox may hold acks
     # queued before the pause, which receipt anchoring must not trust.
+    #
+    # Ticks and steps.  The proofs above speak of global ticks.  A node
+    # under its own loop may take several steps in one period (a timer
+    # step with host.clock 1, then steps with clock 0 as work arrives):
+    # they are ONE tick of that model, delivered in pieces, all at one
+    # value of `now`.
+    # * lease: evidence stored in an earlier step of this `now` may
+    #   release a read stamped in a later step of it (evid == stamp).
+    #   That is the window a single tick always had, in wall time: an ack
+    #   that reached the host early in a period waited in the inbox
+    #   accumulator for the tick that stamped, beside it, a read that
+    #   arrived up to a period later; both were then "this tick".  The
+    #   follower side holds as before, because its vote-denying lease
+    #   (phase 2 lease_open) runs on ITS `now`, which its arrival steps do
+    #   not move either: no node's clock runs faster than its timer.
+    #   Evidence of an earlier `now` never releases (evid < stamp).
+    # * strict: an AE sent in an earlier step of this `now` echoes
+    #   aer_tick == now although it left before a read offered in a
+    #   later step of it: the echo would confirm nothing.  So strict
+    #   mode stamps reads only in the step that advances the clock
+    #   (phase 8b stamp_open): that step is the first at its `now`, every
+    #   AE carrying the stamp's value leaves in it or after it, and an
+    #   offer made in a clock-0 step stays offered until the timer's.
     read_evid = s.read_evid
     if cfg.read_lease:
         evid_hit = aer_r & ~self_hot & \
@@ -859,7 +887,11 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # — a fresh leader's commit index may lag entries committed by its
     # predecessors until its own-term entry commits, Raft §5.4.2; serving
     # before that could miss them).
-    n_read = jnp.where(keep_reads & (commit >= own_from) & (rq_len < K),
+    # Strict ReadIndex stamps only where the clock advances (6b, "ticks
+    # and steps"); the lease stamps in any step.
+    stamp_open = True if cfg.read_lease else host.clock > 0
+    n_read = jnp.where(keep_reads & (commit >= own_from) & (rq_len < K)
+                       & stamp_open,
                        jnp.maximum(host.read_n, 0), 0)
     read_acc = n_read > 0
     rows_g = jnp.arange(G, dtype=I32)

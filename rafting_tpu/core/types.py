@@ -801,6 +801,20 @@ class HostInbox:
                                #   cannot stretch the lease window (the host
                                #   analog of the device model's
                                #   stall-loses-inbound rule)
+    # The engine's clock (``RaftState.now``) advances by this much in the
+    # step: 1 on a step the period's timer started, 0 on a step that work
+    # started between two timer ticks (the runtime's loop steps when a
+    # slice, a submission or a read arrives; runtime/node.py _run).  Every
+    # timer of the engine compares against ``now``, so the steps of one
+    # period are ONE tick of the protocol's clock, delivered in pieces:
+    # none of them can expire an election, RPC, CheckQuorum or transfer
+    # deadline, none sends a cadence heartbeat (``now >= hb_due`` stays
+    # false; a read's barrier heartbeat and data AppendEntries leave),
+    # and everything stamped in them (``sent_at``, ``ok_at``, ``rq_stamp``,
+    # ``ae_tick``) carries the period's value.  Whoever steps with no
+    # loop advances the clock every step (``empty()`` gives 1).  A node's
+    # first step is a timer step: stamps are >= 1, evidence 0 means none.
+    clock: jax.Array           # scalar int32 — 0 or 1
     # Durable-tail feedback (the pipelined runtime's safety lane): the
     # highest log index per group the host has FSYNCED.  When present, the
     # commit quorum counts this node's own match only up to it — an entry
@@ -827,6 +841,7 @@ class HostInbox:
             snap_conf=jnp.zeros((G,), I32),
             read_n=jnp.zeros((G,), I32),
             read_veto=jnp.asarray(False),
+            clock=jnp.asarray(1, I32),
             durable_tail=None,
         )
 

@@ -113,6 +113,29 @@ def settles_now(now: float, due: Optional[float], cost: float) -> bool:
     return now + SETTLE_MARGIN * cost <= due
 
 
+def arrival_step_at(now: float, ended: float, took: float,
+                    due: Optional[float], cost: float) -> Optional[float]:
+    """When a loop with work waiting at ``now`` may start a step for it
+    between two timer ticks, or None when the work is to ride the
+    timer's own tick at ``due``.  ``ended`` and ``took``: when the last
+    step ended and how long it ran; ``cost``: what a whole step has
+    lately taken (all in seconds, on the clock of ``now``).  Two rules,
+    both about what the loop observes of itself:
+
+    * the gap: no step starts before the last step's own duration has
+      passed since it ended.  Arrivals inside the gap share the next
+      step, so the batching a dense load needs comes back by itself as
+      load rises, and one node's arrival steps never take more than
+      about half of an interpreter;
+    * room: ``SETTLE_MARGIN`` times ``cost`` fits between the start and
+      ``due``, so an arrival step settles (``settles_now``) and never
+      makes a timer tick late.  A node whose step fills its period gets
+      None every time and ticks by the timer alone, as it always did.
+    """
+    at = max(now, ended + took)
+    return at if settles_now(at, due, cost) else None
+
+
 class BatchSubmit:
     """One future for a whole batch of commands (resolves to the list of
     apply results in submission order; ``single=True`` — the plain
@@ -284,6 +307,9 @@ class _TickCtx:
     __slots__ = (
         # dispatch-time host inputs
         "submit_n", "read_n", "staged_payloads", "arrays",
+        # whether the step advanced the engine's clock (a timer tick) or
+        # was started for arriving work inside a period
+        "timer",
         # the packed results on the device and their layout (dispatch)
         "packed", "readback",
         # -> host views of the fetched buffers (fetch)
@@ -405,7 +431,12 @@ class RaftNode:
         self.maintain.watch_ring(cfg.log_slots, cfg.max_submit)
         self.template = messages_template(cfg)
         self.acc = InboxAccumulator(cfg, self.template)
-        self.transport = transport_factory(self, self.acc.merge,
+        # Set when work for the next step waits: a peer's slice merged, a
+        # submission or a read queued, a drain that left slices behind.
+        # A started loop sleeps on it between steps (_run); nobody else
+        # reads it.
+        self._wake = threading.Event()
+        self.transport = transport_factory(self, self._on_slice,
                                            self._serve_snapshot)
 
         # Crash recovery: device state from the WAL (reference
@@ -615,7 +646,15 @@ class RaftNode:
         self._gc_phase = 0       # 0 idle / 1 rewriting / 2 finish / -1 abort
         self._gc_thread: Optional[threading.Thread] = None
 
+        # Two counts.  ``ticks``: every step, whoever started it: the
+        # (node, tick) identifier on spans and stamps.  ``timer_ticks``:
+        # the steps that advanced the engine's clock (HostInbox.clock 1),
+        # one per period under a loop, every step for a caller that drives
+        # tick() itself: what every host-side cadence and hold counts in
+        # (a read veto, checkpoint and compaction intervals, evacuation
+        # cool-downs, heat decay), since those mean time.
         self.ticks = 0
+        self.timer_ticks = 0
         # Counter/gauge/histogram registry (SURVEY §5: the build must add
         # commits/sec, election counts, per-step latency histograms).
         self.metrics = Metrics()
@@ -772,6 +811,9 @@ class RaftNode:
         # against the time left until _tick_due.
         self._host_costs: deque = deque(maxlen=HOST_COST_MEMORY)
         self._host_runs = 0                      # host phases this tick
+        # Seconds per whole step, the same memory: arrival_step_at()
+        # weighs their median against the time left until _tick_due.
+        self._step_costs: deque = deque(maxlen=HOST_COST_MEMORY)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # Double-buffered pipeline state: the fetched-but-not-yet-host-
@@ -803,6 +845,9 @@ class RaftNode:
         # settles_now() found room for).
         self.metrics["ticks"] += 0
         self.metrics["ticks_settled"] += 0
+        # Those of them a loop started for arriving work, between two
+        # timer ticks (HostInbox.clock 0).
+        self.metrics["ticks_on_arrival"] += 0
         # Host-to-device and device-to-host transfers made by the ticks:
         # the packed buffers of _dispatch and _fetch, a word buffer and a
         # flag buffer each way unless the planes take more than a few MB
@@ -904,6 +949,7 @@ class RaftNode:
 
     def close(self) -> None:
         self._stop.set()
+        self._wake.set()
         if self._thread is not None:
             self._thread.join(timeout=10)
         # Settle the pipeline: the pending tick's host work (WAL staging,
@@ -1033,6 +1079,7 @@ class RaftNode:
                 seq = tr.next_seq_w(1)
                 if tr.sampled(seq):
                     sink.span = tr.make_span(seq, "w", 0)
+        self._wake.set()
         return fut
 
     def submit_batch(self, group: int, payloads,
@@ -1088,6 +1135,7 @@ class RaftNode:
                 k = tr.first_in(seq0, n)
                 if k >= 0:
                     batch.span = tr.make_span(seq0 + k, "w", k)
+        self._wake.set()
         return fut
 
     def submit_batch_many(self, groups, payloads) -> List[BatchSubmit]:
@@ -1172,6 +1220,7 @@ class RaftNode:
                     k = tr.first_in(seq0, n)
                     if k >= 0:
                         sink.span = tr.make_span(seq0 + k, "w", k)
+        self._wake.set()
         return sinks
 
     def read(self, group: int, payload: bytes,
@@ -1240,6 +1289,7 @@ class RaftNode:
                     if sp is not None:
                         sp.group = group
                     sink.span = sp
+        self._wake.set()
         return fut
 
     def _refusal(self, group: int) -> Optional[Exception]:
@@ -1265,7 +1315,7 @@ class RaftNode:
         if self.h_role[group] != LEADER:
             hint = int(self.h_leader[group])
             ev = self._evacuated.get(group)
-            if ev is not None and self.ticks < ev[1]:
+            if ev is not None and self.timer_ticks < ev[1]:
                 # Health-driven hand-off: the typed refusal carries the
                 # evacuation target so clients re-point in one hop even
                 # before the leader mirror catches up (api/anomaly.py).
@@ -1325,9 +1375,10 @@ class RaftNode:
 
     def _note_tick_start(self, now: float, interval: float,
                          due_next: float) -> None:
-        """Tick thread, once per loop period: how late this tick starts
-        against when it was due, and when the loop is to start the next
-        one (``due_next``)."""
+        """Tick thread, once per loop period, at the timer's tick: how
+        late it starts against when it was due, and when the loop is to
+        start the next one (``due_next``).  Steps started for arriving
+        work in between are not the timer's and are not counted here."""
         due, self._tick_due = self._tick_due, due_next
         if due is None:
             return
@@ -1336,22 +1387,64 @@ class RaftNode:
         if late > self.LATE_TICK_SHARE * interval:
             self.metrics["ticks_late"] += 1
 
+    def _on_slice(self, src: int, fields, payloads) -> None:
+        """Reader threads: a peer's slice is queued for the next step, and
+        a loop asleep between two timer ticks hears of it."""
+        self.acc.merge(src, fields, payloads)
+        self._wake.set()
+
     def _run(self, interval: float) -> None:
+        """The loop: a step when the period's timer fires, and a step when
+        work waits in between (``_wake``) and ``arrival_step_at`` finds
+        the gap passed and room before the timer.  The period stays the
+        ENGINE's clock whatever the arrival rate: only the timer's step
+        advances it (``tick(arrival=False)``), and ``_next_start``,
+        ``tick_late_s`` and ``ticks_late`` are about timer ticks alone.
+        The first step is the timer's."""
         st = self._stages
         while not self._stop.is_set():
             t0 = time.perf_counter()
-            due = self._next_start(t0, interval)
-            self._note_tick_start(t0, interval, due)
+            arrival = self._tick_due is not None and t0 < self._tick_due
+            if not arrival:
+                self._note_tick_start(t0, interval,
+                                      self._next_start(t0, interval))
             try:
-                self.tick()
+                self.tick(arrival=arrival)
             except Exception:
                 log.exception("node %d tick failed", self.node_id)
                 st.leave()
-            left = due - time.perf_counter()
-            if left > 0:
-                st.enter("wait")
-                time.sleep(left)
-                st.leave()
+            ended = time.perf_counter()
+            self._step_costs.append(ended - t0)
+            st.enter("wait")
+            self._await_step(ended, ended - t0)
+            st.leave()
+
+    def _await_step(self, ended: float, took: float) -> None:
+        """Tick thread, between two steps: sleep until the timer's tick is
+        due, or until work waits and ``arrival_step_at`` lets a step
+        start for it, whichever is first."""
+        due, wake, stop = self._tick_due, self._wake, self._stop
+        # What a step has lately cost: the median of the last few, not
+        # their maximum.  The one step that a pause stretched is paid for
+        # by the gap it leaves behind it; remembered as the cost, it
+        # would hold the next eight periods' arrivals back to the timer
+        # (and a backlog with them), and a step that starts a little too
+        # close to the timer costs that tick a few milliseconds, no more.
+        costs = sorted(self._step_costs)
+        cost = costs[len(costs) // 2] if costs else 0.0
+        while not stop.is_set():
+            now = time.perf_counter()
+            if now >= due:
+                return
+            if not wake.is_set():
+                wake.wait(due - now)
+                continue
+            at = arrival_step_at(now, ended, took, due, cost)
+            if at is not None and at <= now:
+                return
+            # Inside the gap, or no room before the timer: the work waits
+            # (and what arrives meanwhile shares its step).
+            stop.wait((due if at is None else at) - now)
 
     def set_active(self, group: int, active: bool,
                    purge: bool = False) -> None:
@@ -1386,8 +1479,19 @@ class RaftNode:
             os.replace(tmp, self._lane_gens_path)
         self.set_active(lane, True)
 
-    def tick(self) -> StepInfo:
-        """Advance the node one tick and return its StepInfo.
+    def tick(self, arrival: bool = False) -> StepInfo:
+        """Advance the node one step and return its StepInfo.
+
+        ``arrival``: the node's own loop (``_run``) says so of a step it
+        starts because work arrived between two timer ticks.  Such a step
+        is a whole tick (dispatch, fetch, host phase behind its own fsync
+        barrier) that leaves the engine's clock where it is
+        (``HostInbox.clock`` 0): no timer of the engine can expire in it,
+        and the host-side cadences that mean time (``timer_ticks``) stand
+        still too.  Every other caller (``LocalCluster``,
+        ``chip_smoke.py``, the lock-step tests) leaves it False, and each
+        of their steps is a period of the engine's clock, as it always
+        was.
 
         Serial mode (``pipeline=False``): the classic strictly ordered
         tick — scan, wait, persist+fsync, send, apply, maintain — nothing
@@ -1441,9 +1545,15 @@ class RaftNode:
         if self._lat is not None:
             self._lat.tick = self.ticks
         _tick_t0 = st.enter("dispatch_intake")
+        st.note(arrival=int(arrival))
         m = self.metrics
         m["ticks"] += 1
-        ctx = self._dispatch()
+        if arrival:
+            m["ticks_on_arrival"] += 1
+        # Whatever was queued before this instant the intake below sees;
+        # whatever is queued after it sets the event again.
+        self._wake.clear()
+        ctx = self._dispatch(arrival)
         if self.pipeline:
             prev, self._pending = self._pending, None
             try:
@@ -1496,20 +1606,26 @@ class RaftNode:
             "dispatch_intake", "dispatch_upload", "dispatch_enqueue"))
         m.observe("tick_stage_scan_wait_s",
                   st.total("scan_device", "scan_fetch"))
-        # The admission controller's tick is the time from one tick's
-        # start to the next: a submission queues for whole tick PERIODS,
-        # however short the tick's own work is.  Fed the work time alone,
-        # a loop paced slower than its work (tick_ms=1000 on the chip)
-        # saw every one-period wait as a standing queue and shed an idle
-        # cluster's traffic.
-        prev, self._tick_started = self._tick_started, _tick_t0
-        self._admission_tick(time.perf_counter() - _tick_t0
-                             if prev is None else _tick_t0 - prev)
-        # Txn plane: fold driver/resolver counters and (every
-        # sweep_every ticks) resolve expired write-intents on groups
-        # this node leads (runtime/txn.py — coordinator timeouts are
-        # driven off this tick loop, not off any client thread).
-        self.txn.tick(self)
+        # The admission controller's tick is the time from one timer
+        # tick's start to the next: a submission can queue for a whole
+        # PERIOD (a step for it starts at once only where the loop has
+        # the room), however short the tick's own work is.  Fed the work
+        # time alone, a loop paced slower than its work (tick_ms=1000 on
+        # the chip) saw every one-period wait as a standing queue and
+        # shed an idle cluster's traffic.  Every step feeds its sojourn
+        # sample; only the timer's feeds the period.
+        if arrival:
+            self._admission_tick(None)
+        else:
+            prev, self._tick_started = self._tick_started, _tick_t0
+            self._admission_tick(time.perf_counter() - _tick_t0
+                                 if prev is None else _tick_t0 - prev)
+            # Txn plane: fold driver/resolver counters and (every
+            # sweep_every timer ticks) resolve expired write-intents on
+            # groups this node leads (runtime/txn.py — coordinator
+            # timeouts are driven off this tick loop, not off any client
+            # thread).
+            self.txn.tick(self)
         if self._lat is not None:
             # Merge retired spans from every thread's ring into the
             # shared histograms — tick thread only, so the registry
@@ -1521,14 +1637,19 @@ class RaftNode:
             # carries its outcome.
             self._hops.fold(self.metrics)
         # Health scorecards last: the fold above just refreshed the hop
-        # histograms this tick's peer scoring reads.
-        self._health_tick()
+        # histograms this tick's peer scoring reads.  Once a period: a
+        # score is a sum of per-tick penalties and its decay a count of
+        # ticks.
+        if not arrival:
+            self._health_tick()
         st.leave()
         return ctx.info
 
-    def _admission_tick(self, tick_s: float) -> None:
-        """Per-tick admission-controller feed + metrics fold (tick thread
-        only — the registry's single-writer contract).  The sojourn
+    def _admission_tick(self, tick_s: Optional[float]) -> None:
+        """Per-step admission-controller feed + metrics fold (tick thread
+        only — the registry's single-writer contract).  ``tick_s``: the
+        period just measured, on the timer's tick; None on a step
+        started for arriving work.  The sojourn
         sample was stashed by this tick's ``_persist_prepare`` pop; when
         nothing popped AND the queues are empty, 0.0 is fed (the queue
         drained — the strongest good signal); a non-empty queue with no
@@ -1536,7 +1657,8 @@ class RaftNode:
         adm = self.admission
         if not adm.enabled:
             return
-        adm.note_tick(tick_s)
+        if tick_s is not None:
+            adm.note_tick(tick_s)
         d, self._adm_delay = self._adm_delay, None
         if d is None and self._queued_total == 0:
             d = 0.0
@@ -1558,11 +1680,12 @@ class RaftNode:
     # ------------------------------------------------- tick: health plane
 
     def _health_tick(self) -> None:
-        """Per-tick gray-failure scorecard feed + leadership evacuation
-        (tick thread only).  The registry folds this tick's self signals
-        (slow-I/O watchdog, stripe quarantine, ENOSPC backpressure,
-        reconnects, admission shed level) and the hop histograms' per-
-        peer windowed deltas; when the SELF score crosses the degraded
+        """Per-period gray-failure scorecard feed + leadership evacuation
+        (tick thread only, on the timer's tick; its cadences and
+        cool-downs count ``timer_ticks``).  The registry folds this
+        tick's self signals (slow-I/O watchdog, stripe quarantine, ENOSPC
+        backpressure, reconnects, admission shed level) and the hop
+        histograms' per-peer windowed deltas; when the SELF score crosses the degraded
         threshold, up to ``RAFT_EVAC_GROUPS`` led groups are handed to
         their most caught-up non-degraded voter via the §3.10 transfer
         plane — proactive step-down while this node can still replicate,
@@ -1573,26 +1696,26 @@ class RaftNode:
         if h is None:
             return
         adm = self.admission
-        h.ingest(self.ticks, self.metrics,
+        h.ingest(self.timer_ticks, self.metrics,
                  io_slow=self._io_slow,
                  poisoned_stripes=len(self._poisoned_stripes),
                  backpressure=self._io_backpressure,
                  admission_level=adm.level if adm.enabled else 0.0)
         # Contact feed from the device qc lanes (max over groups -> [P]
         # last-heard ticks), at an admin cadence like catch_up_gaps.
-        if self.state.qc is not None and self.ticks % 16 == 0:
+        if self.state.qc is not None and self.timer_ticks % 16 == 0:
             heard = np.asarray(jax.device_get(self.state.qc.heard))
             h.note_contact(heard.max(axis=0))
         # Expired evacuation markers age out (the fleet has re-pointed).
         for g in [g for g, (_, exp) in self._evacuated.items()
-                  if self.ticks >= exp]:
+                  if self.timer_ticks >= exp]:
             del self._evacuated[g]
         bad = h.degraded_peers()
         m = self.metrics
         m.gauge("health_self_score", round(h._decayed(h.self_score), 4))
         m.gauge("health_self_degraded", int(h.self_degraded()))
         m.gauge("health_degraded_peers", len(bad))
-        if not h.self_degraded() or self.ticks < self._evac_next_ok:
+        if not h.self_degraded() or self.timer_ticks < self._evac_next_ok:
             return
         led = np.nonzero(self.h_role == LEADER)[0]
         if led.size == 0:
@@ -1622,13 +1745,13 @@ class RaftNode:
             fut = self.transfer_leadership(g, target)
             if fut.done() and fut.exception() is not None:
                 continue   # refused (raced a role change) — not an evac
-            self._evacuated[g] = (target,
-                                  self.ticks + 8 * self.cfg.election_ticks)
+            self._evacuated[g] = (
+                target, self.timer_ticks + 8 * self.cfg.election_ticks)
             m["leader_evacuations"] += 1
             h.note_evacuation(g, target)
             moved += 1
         if moved:
-            self._evac_next_ok = self.ticks + self._evac_cooldown
+            self._evac_next_ok = self.timer_ticks + self._evac_cooldown
             log.warning(
                 "node %d degraded (score %.2f): evacuated %d group(s)",
                 self.node_id, h._decayed(h.self_score), moved)
@@ -1649,7 +1772,7 @@ class RaftNode:
 
     # ------------------------------------------------------- tick: dispatch
 
-    def _dispatch(self) -> _TickCtx:
+    def _dispatch(self, arrival: bool) -> _TickCtx:
         cfg = self.cfg
         G = cfg.n_groups
 
@@ -1759,7 +1882,9 @@ class RaftNode:
                 self._read_veto_hold = max(cfg.read_fresh_ticks, 2)
                 self.metrics["read_vetoes"] += 1
         read_veto = self._read_veto_hold > 0
-        if read_veto:
+        if read_veto and not arrival:
+            # The hold is a length of TIME: read_fresh_ticks periods,
+            # not that many steps a few milliseconds apart.
             self._read_veto_hold -= 1
         self._last_tick_wall = wall
         snap_done = np.zeros(G, bool)
@@ -1822,7 +1947,7 @@ class RaftNode:
             snap_term=snap_term, snap_conf=snap_conf, compact_to=compact_to,
             conf_voters=conf_voters, conf_learners=conf_learners,
             xfer_target=xfer_target, read_n=read_n, read_veto=read_veto,
-            durable_tail=durable))
+            clock=int(not arrival), durable_tail=durable))
 
         # -- 2b. upload: every host plane crosses to the device here, after
         # the whole intake, in one transfer per buffer ----------------------
@@ -1842,6 +1967,7 @@ class RaftNode:
 
         ctx = _TickCtx()
         ctx.submit_n, ctx.read_n = submit_n, read_n
+        ctx.timer = not arrival
         ctx.staged_payloads, ctx.arrays = staged_payloads, arrays
         ctx.packed, ctx.readback = packed, readback
         ctx.deferred_ae = None
@@ -1860,7 +1986,13 @@ class RaftNode:
         m = self.metrics
         for w in stats.waits_s:
             m.observe("inbox_wait_s", w)
-        m.observe("inbox_backlog", max(stats.depth.values(), default=0))
+        backlog = max(stats.depth.values(), default=0)
+        m.observe("inbox_backlog", backlog)
+        if backlog:
+            # A slice per source per step (drain): what this step left
+            # queued is work for the next one, so a started loop works a
+            # backlog off instead of carrying it from period to period.
+            self._wake.set()
         for src, depth in stats.depth.items():
             m.gauge(f"inbox_backlog_src{src}", depth)
         if stats.collapsed:
@@ -1912,7 +2044,7 @@ class RaftNode:
         # long-horizon story is snapshots + lane purge (index resets), not
         # wider lanes.
         hi_lane = max(int(np.asarray(h_info.log_tail).max(initial=0)),
-                      int(h_term.max(initial=0)), self.ticks)
+                      int(h_term.max(initial=0)), self.timer_ticks)
         if hi_lane >= I32_SAFE_MAX:
             raise OverflowError(
                 f"node {self.node_id}: an int32 engine lane reached "
@@ -1972,7 +2104,7 @@ class RaftNode:
         # a skipped drain (storage-fault tick) loses nothing.
         if self.heat is not None and h_heat is not None:
             d_app, d_sent, d_com, d_rd = self.heat.ingest(
-                self.ticks, h_heat.appended, h_heat.sent,
+                self.timer_ticks, h_heat.appended, h_heat.sent,
                 h_heat.commits, h_heat.reads)
             m = self.metrics
             if d_app:
@@ -1999,6 +2131,7 @@ class RaftNode:
                 self.metrics["lease_vetoes"] += n_veto
 
         self.ticks += 1
+        self.timer_ticks += int(ctx.timer)
         self.metrics.gauge("groups_active", int(self.h_active.sum()))
         self.metrics.gauge(
             "groups_led", int((h_role == LEADER).sum()))
@@ -2100,7 +2233,7 @@ class RaftNode:
                 st.enter("maintain")
 
                 # -- 7. maintain: checkpoints, compaction, snapshot downloads
-                self._maintain(after, ctx.base, ctx.term)
+                self._maintain(after, ctx.base, ctx.term, ctx.timer)
                 self._snapshot_requests(ctx.info, ctx.base)
                 # The stages are observed at their boundaries; whichever
                 # phase follows (scan_device, tail, dispatch_intake) ends
@@ -3427,8 +3560,43 @@ class RaftNode:
 
     # -------------------------------------------------------------- maintain
 
-    def _maintain(self, applied: np.ndarray, h_base, h_term) -> None:
-        now = self.ticks
+    def _maintain(self, applied: np.ndarray, h_base, h_term,
+                  timer: bool) -> None:
+        """Checkpoints, compaction grants, WAL GC and the scrubber, once a
+        period: on the host phase of the timer's tick (``timer``), with
+        every cadence counted in ``timer_ticks``.  A step started for
+        arriving work skips the policy pass unless a log ring under
+        pressure asks for it (snapshot/policy.py ``pressed``: such a ring
+        is due at once, and its release chain of save, harvest, grant
+        and compaction moves a step at a time); every step notes how
+        full the fullest ring stands."""
+        now = self.timer_ticks
+        n_ckpt = n_pressed = 0
+        if timer or self.maintain.pressed(self.h_commit, h_base).any():
+            n_ckpt, n_pressed = self._maintain_pass(now, applied, h_base)
+        # The fullest ring this node holds (the fsynced tail is the
+        # tick's log tail once its host phase is here), on /metrics and
+        # on this tick's raft.maintain span.
+        ring_used = int((self._durable_tail_m - h_base)[self.h_active]
+                        .max(initial=0))
+        self.metrics.gauge("log_ring_used_max", ring_used)
+        self._stages.note(
+            ring_used=ring_used, ring_slots=self.cfg.log_slots,
+            led=int(((self.h_role == LEADER) & self.h_active).sum()),
+            checkpoints=n_ckpt, by_pressure=n_pressed)
+        if not timer:
+            return
+        self._maintain_gc(now)
+        if now % 32 == 0:
+            self._fold_wal_stats()
+        if self.scrub_interval_ticks \
+                and now % self.scrub_interval_ticks == 0:
+            self._scrub_archive()
+
+    def _maintain_pass(self, now: int, applied: np.ndarray, h_base
+                       ) -> Tuple[int, int]:
+        """One pass of the checkpoint and compaction policy at timer tick
+        ``now``; returns (checkpoints serialized, of them by pressure)."""
         # Harvest completed off-thread saves FIRST: a milestone feeds the
         # compaction policy only once its archive copy is durable on disk
         # (a compaction grant must never outrun its snapshot).
@@ -3515,22 +3683,7 @@ class RaftNode:
         m["ckpt_by_pressure"] += n_pressed
         m["compactions_by_pressure"] += int(
             self.maintain.compact_pressed.sum())
-        # The fullest ring this node holds (the fsynced tail is the
-        # tick's log tail once its host phase is here), on /metrics and
-        # on this tick's raft.maintain span.
-        ring_used = int((self._durable_tail_m - h_base)[self.h_active]
-                        .max(initial=0))
-        m.gauge("log_ring_used_max", ring_used)
-        self._stages.note(
-            ring_used=ring_used, ring_slots=self.cfg.log_slots,
-            led=int(((self.h_role == LEADER) & self.h_active).sum()),
-            checkpoints=n_ckpt, by_pressure=n_pressed)
-        self._maintain_gc(now)
-        if now % 32 == 0:
-            self._fold_wal_stats()
-        if self.scrub_interval_ticks \
-                and now % self.scrub_interval_ticks == 0:
-            self._scrub_archive()
+        return n_ckpt, n_pressed
 
     def _fold_wal_stats(self) -> None:
         """Fold the WAL engines' cumulative stage/fsync/pack counters
@@ -3815,7 +3968,8 @@ class RaftNode:
                     # (device re-requests) converges once space frees.
                     self._sync_pending = True
                     raise
-                self.maintain.note_checkpoint(g, self.ticks, snap.index)
+                self.maintain.note_checkpoint(g, self.timer_ticks,
+                                              snap.index)
                 self.metrics["snapshots_installed"] += 1
                 done.append((g, snap.index, snap.term, cw))
             except Exception:

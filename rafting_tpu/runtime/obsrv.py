@@ -203,6 +203,7 @@ class ObservabilityServer:
             "ok": True,
             "node_id": int(n.node_id),
             "ticks": int(n.ticks),
+            "timer_ticks": int(n.timer_ticks),
             "groups_active": int(n.h_active.sum()),
             "groups_led": led,
             "groups_ready": ready,

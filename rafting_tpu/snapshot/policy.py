@@ -78,8 +78,10 @@ class MaintainAgreement:
         self.pressure_at = max(log_slots - RELEASE_TICKS * max_submit,
                                int(log_slots * PRESSURE_FLOOR_SHARE))
 
-    def _pressed(self, frontier: np.ndarray,
-                 log_base: np.ndarray) -> np.ndarray:
+    def pressed(self, frontier: np.ndarray,
+                log_base: np.ndarray) -> np.ndarray:
+        """[G] bool: groups whose un-compacted log up to ``frontier`` (the
+        applied or the commit index) stands past ``pressure_at``."""
         if self.pressure_at is None:
             return np.zeros(len(log_base), bool)
         return frontier - log_base > self.pressure_at
@@ -94,7 +96,7 @@ class MaintainAgreement:
         due = now - self.last_snap_tick >= self.snap_min_interval
         by_cadence = ((changed >= self.state_change_threshold)
                       & (dirty >= self.dirty_log_tolerance) & due)
-        self.ckpt_pressed = (self._pressed(applied, log_base)
+        self.ckpt_pressed = (self.pressed(applied, log_base)
                              & (changed > 0) & ~by_cadence)
         return by_cadence | self.ckpt_pressed
 
@@ -112,7 +114,7 @@ class MaintainAgreement:
         a ring under pressure is compacted without waiting out the
         interval, to the same target."""
         due = now - self.last_compact_tick >= self.compact_min_interval
-        pressed = self._pressed(commit, log_base)
+        pressed = self.pressed(commit, log_base)
         target = np.minimum(self.snap_index,
                             np.maximum(commit - self.compact_slack, 0))
         target = np.where((due | pressed) & (target > log_base), target, 0)

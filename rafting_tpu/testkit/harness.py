@@ -182,6 +182,27 @@ class LocalCluster:
         for i in list(self.nodes):
             self.kill_node(i)
 
+    def start_loops(self, tick_interval: float) -> None:
+        """Hand every live node to its own loop (``RaftNode.start``), as a
+        container does: steps at the period's timer and when work
+        arrives.  Loopback transport only (``start`` starts the node's
+        transport too, and a TCP one is started already)."""
+        assert self.transport == "loopback"
+        for node in self.nodes.values():
+            node.start(tick_interval)
+
+    def stop_loops(self) -> None:
+        """End the loops ``start_loops`` began and wait for them; the
+        nodes stay open, to be read and closed (not to be ticked by hand
+        again: their workers have seen the stop too)."""
+        for node in self.nodes.values():
+            node._stop.set()
+            node._wake.set()
+        for node in self.nodes.values():
+            if node._thread is not None:
+                node._thread.join(timeout=30)
+                node._thread = None
+
     # -- stepping ------------------------------------------------------------
 
     def tick(self, rounds: int = 1) -> None:

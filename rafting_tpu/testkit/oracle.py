@@ -124,7 +124,7 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     h = _np(host)
 
     me = int(s["node_id"])
-    now = int(s["now"]) + 1
+    now = int(s["now"]) + int(h["clock"])
 
     # Same PRNG stream as the kernel (shared on purpose; see module doc).
     rng, k_to = jax.random.split(state.rng)
@@ -687,8 +687,11 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
             rq_len[g] = 0
             read_evid[g, :] = 0
         n_read = 0
+        # Strict ReadIndex stamps only in the step that advances the
+        # clock (kernel 6b, "ticks and steps").
         if (keep_reads and commit[g] >= own_from_a[g]
-                and int(rq_len[g]) < K):
+                and int(rq_len[g]) < K
+                and (cfg.read_lease or int(h["clock"]) > 0)):
             n_read = max(0, int(h["read_n"][g]))
         if n_read > 0:
             slot = (int(rq_head[g]) + int(rq_len[g])) % K

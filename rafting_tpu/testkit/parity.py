@@ -89,12 +89,17 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
                drop_p: float = 0.15, part_p: float = 0.1,
                crash_p: float = 0.0, stall_p: float = 0.0,
                conf_p: float = 0.0, xfer_p: float = 0.0,
-               n_voters=None):
+               n_voters=None, arrival_p: float = 0.0):
     """``conf_p``/``xfer_p``: per-group per-tick probability of offering a
     random membership-change / leadership-transfer request through the
     host inbox (the §6 plane's chaos input — only leaders take them, and
     the one-in-flight gate drops the rest, all of which is part of the
-    checked semantics).  ``n_voters`` bounds the boot voter set."""
+    checked semantics).  ``n_voters`` bounds the boot voter set.
+    ``arrival_p``: per-node per-round probability that the step does NOT
+    advance the engine's clock (``HostInbox.clock`` 0: a step the runtime
+    starts for arriving work between two timer ticks); each node's first
+    step always advances it, as a loop's does.  At 0.0 (the default) no
+    draw is made and the schedule is the one it always was."""
     N, G = cfg.n_peers, cfg.n_groups
     rng = np.random.default_rng(seed)
     states = [init_state(cfg, i, seed=seed, n_voters=n_voters)
@@ -103,7 +108,7 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
     infos = [None] * N
     partition_left = 0
     partition = None
-    stats = {"partitions": 0, "crashes": 0, "stalls": 0}
+    stats = {"partitions": 0, "crashes": 0, "stalls": 0, "arrival_steps": 0}
 
     for t in range(n_ticks):
         # --- chaos schedule: random drops plus occasional partitions -----
@@ -174,6 +179,10 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
                           rng.integers(0, N, size=G),
                           -1).astype(np.int32)
             host = HostInbox.empty(cfg)
+            if arrival_p and rng.random() < arrival_p \
+                    and int(states[n].now) > 0:
+                host = host.replace(clock=np.asarray(0, np.int32))
+                stats["arrival_steps"] += 1
             if conf_p or xfer_p:
                 host = host.replace(conf_voters=cv, conf_learners=cl,
                                     xfer_target=xt)

@@ -370,3 +370,66 @@ def test_leader_isolate_hostage_when_check_quorum_off(tmp_path):
         cluster.tick_until(committed, 800, "post-heal commit")
     finally:
         cluster.close()
+
+
+# ------------------------------------------- loops that step on arrival --
+#
+# The same judgment with the nodes under their own loops
+# (runtime/node.py _run): a step when the period's timer fires and a step
+# whenever a slice, a write or a read arrives in between, the engine's
+# clock advanced by the timer's steps alone (HostInbox.clock).  Most steps
+# of this run are arrival steps; the leader of the loaded group is cut off
+# half way, so an election, a step-down and the read plane's aborts all
+# happen between and across them.
+
+@pytest.mark.parametrize("lease", [True, False], ids=["lease", "strict"])
+def test_linearizable_with_loops_stepping_on_arrival(tmp_path, lease):
+    import os as _os
+    import time as _time
+    from rafting_tpu.core.types import EngineConfig as _EC
+    from rafting_tpu.machine.kv_machine import KVMachineProvider
+    from rafting_tpu.testkit.chaos import KVWorkload
+    from rafting_tpu.testkit.harness import LocalCluster
+    from rafting_tpu.testkit.history import History
+
+    cfg = _EC(n_groups=3, n_peers=3, log_slots=64, batch=8, max_submit=8,
+              election_ticks=10, heartbeat_ticks=1, rpc_timeout_ticks=8,
+              read_lease=lease, check_quorum=True)
+    root = str(tmp_path)
+    cluster = LocalCluster(
+        cfg, root, seed=17,
+        provider_factory=lambda i: KVMachineProvider(
+            _os.path.join(root, f"node{i}", "kv")))
+    try:
+        for g in range(cfg.n_groups):
+            cluster.wait_leader(g)
+        now0 = {i: int(n.state.now) for i, n in cluster.nodes.items()}
+        timer0 = {i: n.timer_ticks for i, n in cluster.nodes.items()}
+        history = History()
+        load = KVWorkload(cluster, history, group=1, clients=3, seed=17)
+        period = 0.05
+        cluster.start_loops(period)
+        load.start()
+        _time.sleep(30 * period)
+        victim = cluster.leader_of(1)
+        if victim is not None:
+            cluster.faults.isolate(victim)
+        _time.sleep(40 * period)
+        cluster.faults.heal()
+        _time.sleep(40 * period)
+        load.stop()
+        load.join()
+        cluster.stop_loops()
+        steps = sum(int(n.metrics["ticks"]) for n in cluster.nodes.values())
+        arrival = sum(int(n.metrics["ticks_on_arrival"])
+                      for n in cluster.nodes.values())
+        assert arrival > steps - arrival, \
+            f"the loops hardly woke: {arrival} of {steps} steps"
+        for i, n in cluster.nodes.items():
+            assert int(n.state.now) - now0[i] == n.timer_ticks - timer0[i]
+        counts = history.counts()
+        assert counts["ok"] >= 10, f"workload starved: {counts}"
+        res = linz.check(history)
+        assert res.ok, res.render()
+    finally:
+        cluster.close()

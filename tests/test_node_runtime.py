@@ -237,6 +237,52 @@ def test_empty_apply_skip_counter(tmp_path):
         c.close()
 
 
+def test_arrival_steps_maintain_only_a_pressed_ring(tmp_path):
+    """Between two timer ticks a step passes maintenance by, unless a log
+    ring under pressure asks: a group fed in arrival steps alone is
+    checkpointed and compacted by pressure while the timer count, and
+    with it every cadence, stands; an idle one is left alone."""
+    import json
+
+    from rafting_tpu.machine.kv_machine import KVMachineProvider
+
+    cfg = EngineConfig(n_groups=2, n_peers=3, log_slots=64, batch=8,
+                       max_submit=8, election_ticks=10, heartbeat_ticks=1)
+    root = str(tmp_path)
+    c = LocalCluster(cfg, root, provider_factory=lambda i: KVMachineProvider(
+        os.path.join(root, f"kv{i}")))
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        passes = []
+        run_pass = node._maintain_pass
+        node._maintain_pass = lambda *a: passes.append(a[0]) or run_pass(*a)
+        for _ in range(5):                      # idle arrival steps
+            for n in c.nodes.values():
+                n.tick(arrival=True)
+        assert not passes
+        timer0, sent, futs = node.timer_ticks, 0, []
+        for t in range(60):                     # a ring that fills
+            futs.append(node.submit_batch(0, [
+                json.dumps({"op": "set", "k": f"k{j}",
+                            "v": sent + j}).encode() for j in range(8)]))
+            sent += 8
+            for n in c.nodes.values():
+                n.tick(arrival=True)
+        assert node.timer_ticks == timer0
+        assert passes and set(passes) == {timer0}
+        m = node.metrics
+        assert m["ckpt_by_pressure"] > 0 and m["compactions_by_pressure"] > 0
+        assert int(node._durable_tail_m[0]) > cfg.log_slots, \
+            "the ring never turned over"
+        c.tick_until(lambda: all(f.done() for f in futs), 400,
+                     "every batch acknowledged")
+        assert all(f.exception() is None for f in futs)
+    finally:
+        c.close()
+
+
 def test_a_group_that_never_rests_keeps_its_ring_moving(tmp_path):
     """One group fed ``max_submit`` entries a tick for 200 ticks under the
     policy as it ships (``MaintainAgreement`` defaults: a snapshot no

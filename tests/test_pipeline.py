@@ -18,7 +18,9 @@ import pytest
 from rafting_tpu.core.types import EngineConfig, LEADER
 from rafting_tpu.log.store import LogStore, restore_raft_state
 from rafting_tpu.log.wal import native_available
-from rafting_tpu.runtime.node import SETTLE_MARGIN, settles_now
+from rafting_tpu.runtime.node import (
+    SETTLE_MARGIN, arrival_step_at, settles_now,
+)
 from rafting_tpu.snapshot.policy import MaintainAgreement
 from rafting_tpu.testkit.fixtures import NullProvider
 from rafting_tpu.testkit.harness import LocalCluster
@@ -233,6 +235,48 @@ def test_settles_now_on_made_up_readings(now, due, cost, settles):
         # more, and the margin is the whole of the rule.
         assert settles_now(now, due + 1.0, cost) or not settles
         assert settles == (now + SETTLE_MARGIN * cost <= due)
+
+
+@pytest.mark.parametrize("now,ended,took,due,cost,at", [
+    (10.0, 9.9, 0.008, None, 0.008, None),      # no loop, no step
+    (10.0, 9.999, 0.008, 10.15, 0.008, 10.007),  # inside the gap: wait it out
+    (10.02, 9.999, 0.008, 10.15, 0.008, 10.02),  # gap passed, room: now
+    (10.14, 9.999, 0.008, 10.15, 0.008, None),   # gap passed, no room
+    (10.0, 9.999, 0.14, 10.15, 0.008, None),     # the gap ends past the room
+    (10.0, 9.0, 0.19, 11.0, 0.19, 10.0),         # 100,000 lanes, 1 s period
+    (10.7, 9.0, 0.19, 11.0, 0.19, None),         # ... late in the period
+    (10.0, 9.9, 0.6, 10.5, 0.6, None),           # a step as long as a period
+    (10.134, 9.0, 0.0, 10.15, 0.008, 10.134),    # fits with the margin exactly
+], ids=["no-deadline", "in-gap", "now", "no-room", "gap-past-room",
+        "big-node", "big-node-late", "over-period", "on-the-dot"])
+def test_arrival_step_at_on_made_up_readings(now, ended, took, due, cost,
+                                             at):
+    """When to step for waiting work is a function of what the loop
+    observes of itself: the gap its last step leaves, the room before
+    its timer.  No clock is read, no node is built, no size is asked."""
+    got = arrival_step_at(now, ended, took, due, cost)
+    assert got == pytest.approx(at) if at is not None else got is None
+    if got is not None:
+        # Never before the gap is over, never in the past, and the step
+        # settles: settles_now agrees at the instant it would start.
+        assert got >= now and got >= ended + took - 1e-12
+        assert settles_now(got, due, cost)
+        # More room never takes a step away.
+        assert arrival_step_at(now, ended, took, due + 1.0, cost) == got
+
+
+def test_arrival_steps_leave_half_the_time_to_others():
+    """The gap rule, run forward: a node that always has work waiting and
+    always has room steps, waits its own step's length, steps again:
+    half of the time at most, whatever a step costs."""
+    for cost in (0.002, 0.008, 0.19):
+        t, busy, ended, due = 0.0, 0.0, 0.0, 1e9
+        for _ in range(100):
+            at = arrival_step_at(t, ended, cost, due, cost)
+            t = at + cost
+            busy += cost
+            ended = t
+        assert busy / t <= 0.5 + 1e-9
 
 
 CFG_HB1 = EngineConfig(n_groups=4, n_peers=3, log_slots=32, batch=4,

@@ -143,6 +143,62 @@ def test_read_survives_veto_pause(kv_cluster):
     node._tick_interval = None
 
 
+def test_read_veto_lasts_periods_not_steps(kv_cluster):
+    """The veto after a pause is a length of time: it is counted down by
+    the timer's ticks (``read_fresh_ticks`` of them), however many steps
+    arriving work starts in between, and a read waits it out."""
+    lc = kv_cluster
+    _, node = _ready_leader(lc)
+    wf = node.submit(0, _kv("set", "p", 7))
+    lc.tick_until(wf.done, what="write applied")
+    hold = max(node.cfg.read_fresh_ticks, 2)
+    node._tick_interval = 0.02
+    node._last_tick_wall = time.monotonic() - 10.0
+    rf = node.read(0, _kv("get", "p"))
+    node.tick(arrival=True)         # the step that notices the pause
+    assert node._read_veto_hold == hold
+    timer0, steps0 = node.timer_ticks, node.ticks
+    for _ in range(3 * hold):       # steps a few milliseconds apart
+        node._last_tick_wall = time.monotonic()
+        for n in lc.nodes.values():
+            n.tick(arrival=True)
+        assert node._read_veto_hold == hold
+    assert not rf.done(), "served on evidence the veto should have dropped"
+    assert node.timer_ticks == timer0 and node.ticks == steps0 + 3 * hold
+    assert node.metrics["ticks_on_arrival"] >= 3 * hold + 1
+    for k in range(1, hold + 1):    # the timer's ticks count it down
+        node._last_tick_wall = time.monotonic()
+        lc.tick(1)
+        assert node._read_veto_hold == hold - k
+    lc.tick_until(rf.done, what="read after the veto")
+    assert rf.result() == 7
+    node._tick_interval = None
+
+
+def test_host_cadences_count_the_timer(kv_cluster):
+    """``ticks`` names a step, ``timer_ticks`` counts periods: arrival
+    steps move the first and leave alone the second and everything that
+    keeps time by it (the engine's clock, the transaction sweep, the
+    health plane), while a caller that says nothing moves both together."""
+    lc = kv_cluster
+    _, node = _ready_leader(lc)
+    assert node.ticks == node.timer_ticks > 0
+    now0 = int(node.state.now)
+    timer0, txn0 = node.timer_ticks, node.txn._tick_n
+    health0 = node.health.tick if node.health is not None else None
+    for _ in range(7):
+        for n in lc.nodes.values():
+            n.tick(arrival=True)
+    assert node.timer_ticks == timer0 and int(node.state.now) == now0
+    assert node.ticks == timer0 + 7
+    assert node.txn._tick_n == txn0
+    if health0 is not None:
+        assert node.health.tick == health0
+    lc.tick(2)
+    assert node.timer_ticks == timer0 + 2 and int(node.state.now) == now0 + 2
+    assert node.txn._tick_n == txn0 + 2
+
+
 # ------------------------------------------- one barrier for waiting reads --
 
 def _write(lc, node, k, v):

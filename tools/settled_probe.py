@@ -2,7 +2,11 @@
 """How often the tick loop settles a tick in its own period (PR 25): one run
 of a benchmark cell through the benchmark's own ``harness.run_cell`` (the
 same set-up, window, drain and comparison as ``benchmark/run.py``), with each
-node's ``ticks`` and ``ticks_settled`` counters read around the window:
+node's ``ticks``, ``ticks_settled`` and ``ticks_on_arrival`` counters read
+around the window (PR 29: a step is the timer's, or one the loop started for
+arriving work; the engine's clock ``state.now`` moves by the timer's alone,
+so ``engine_now`` equals ``timer_ticks`` on a node booted from an empty
+directory, give or take the step in flight when the two were read):
 
     python3 tools/settled_probe.py --workload W --seed N --seconds S [--trace 1]
         [--tick-ms T] [--rate R] [--cpu-lanes L]
@@ -25,6 +29,18 @@ import sys          # noqa: E402
 import traceback    # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def engine_now(node) -> int:
+    """``state.now`` of a node whose loop is running: the step in flight
+    donates the state it was handed, so a reading may have to be taken
+    again."""
+    for _ in range(20):
+        try:
+            return int(node.state.now)
+        except RuntimeError:
+            time.sleep(0.001)
+    return -1
 
 
 def main() -> None:
@@ -61,8 +77,14 @@ def main() -> None:
                     window_settled=w.get("ticks_settled", 0),
                     window_share=round(w.get("ticks_settled", 0)
                                        / max(1, w.get("ticks", 0)), 4),
+                    window_on_arrival=w.get("ticks_on_arrival", 0),
+                    arrival_share=round(w.get("ticks_on_arrival", 0)
+                                        / max(1, w.get("ticks", 0)), 4),
                     process_ticks=whole["ticks"],
                     process_settled=whole["ticks_settled"],
+                    process_on_arrival=whole["ticks_on_arrival"],
+                    timer_ticks=node.timer_ticks,
+                    engine_now=engine_now(node),
                     ticks_late=w.get("ticks_late", 0))
     harness.finish(result)
 
