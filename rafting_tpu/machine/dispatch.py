@@ -284,9 +284,10 @@ class ApplyDispatcher:
 
     # -- the apply loop -----------------------------------------------------
 
-    def advance(self, commit: np.ndarray, max_per_group: int = 0) -> None:
+    def advance(self, commit: np.ndarray, max_per_group: int = 0) -> int:
         """Apply newly committed entries.  `commit` is the [G] frontier;
-        `max_per_group` bounds work per call (0 = no bound)."""
+        `max_per_group` bounds work per call (0 = no bound).  Returns the
+        lanes visited (those whose commit lies past what is applied)."""
         mirror = self._applied_mirror(len(commit))
         gs = np.nonzero(commit > mirror[:len(commit)])[0]
         retries = self._retry_counts
@@ -452,6 +453,7 @@ class ApplyDispatcher:
             mirror[g] = idx - 1 if idx - 1 > before else before
             if self._on_applied is not None and idx - 1 > before:
                 self._on_applied(g, idx - 1)
+        return len(gs)
 
     def applied_frontier(self, n_groups: int) -> np.ndarray:
         out = np.zeros(n_groups, np.int32)

@@ -285,13 +285,16 @@ class _ReadOffer:
     ReadIndex; awaiting the quorum barrier) -> RELEASED (barrier
     confirmed; served once ``applied >= read_index``).  The device stamps
     an offer whole or not at all, so there is no ``taken`` cursor; each
-    part keeps its own sink, span and result list."""
+    part keeps its own sink, span and result list.  ``lease``: the
+    step that stamped it also released it (StepInfo.read_lease), with no
+    ReadIndex round trip."""
 
-    __slots__ = ("parts", "n")
+    __slots__ = ("parts", "n", "lease")
 
     def __init__(self, parts: List[_ReadBatch]):
         self.parts = parts
         self.n = sum(len(b.payloads) for b in parts)
+        self.lease = False
 
 
 class _TickCtx:
@@ -337,7 +340,7 @@ class _PersistPrep:
         "wrote", "wrote_l", "lo_l", "hi_l", "nsub_l", "sublo_l",
         "src_l", "term_l", "fr_valid", "fr_n", "fr_start",
         "fr_ents", "fr_cents", "own_by_g", "staged_payloads",
-        "noop_g", "noop_idx", "noop_term",
+        "noop_g", "noop_idx", "noop_term", "lanes",
         "conf_app", "conf_term", "conf_word",
         "stable_mask", "sub_acc", "submit_n",
     )
@@ -697,7 +700,7 @@ class RaftNode:
         for _c in ("fsync_failures", "enospc_backpressure",
                    "storage_transient_errors", "slow_io_ticks",
                    "ckpt_failures", "scrub_ok", "scrub_corrupt",
-                   "reconnects_total"):
+                   "reconnects_total", "connects_refused_total"):
             self.metrics[_c] += 0
         # Network-nemesis counters (transport/faults.py): rendered at 0
         # so a clean cluster exposes the whole injection family and a
@@ -2132,7 +2135,16 @@ class RaftNode:
 
         self.ticks += 1
         self.timer_ticks += int(ctx.timer)
-        self.metrics.gauge("groups_active", int(self.h_active.sum()))
+        # Open lanes for which this node neither leads ready nor knows a
+        # leader: what tells a store that is electing from one that is
+        # sick or overloaded.  Sampled every step, on /metrics and on the
+        # step's raft.mirrors span.
+        n_open = int(self.h_active.sum())
+        leaderless = int((self.h_active & np.where(
+            h_role == LEADER, ~self.h_ready, h_leader == NIL)).sum())
+        self.metrics.gauge("groups_active", n_open)
+        self.metrics.gauge("groups_leaderless", leaderless)
+        st.note(leaderless=leaderless, open=n_open)
         self.metrics.gauge(
             "groups_led", int((h_role == LEADER).sum()))
 
@@ -2204,12 +2216,13 @@ class RaftNode:
                 m.observe("tick_stage_fsync_s", fsync_s)
 
                 # -- 5. release outbox (only ever after the barrier) ---------
-                held = self._stash_outbox_sections(
+                held, sent = self._stash_outbox_sections(
                     ctx.outbox, deferred=ctx.deferred_ae, blob_fn=blob_fn)
                 for p, secs in held.items():
                     self._held_sections.setdefault(p, []).extend(secs)
                 if not defer_send:
                     self._flush_sends()
+                st.note(lanes=sent)
                 st.enter("apply")
                 if self._lat_tick:
                     self._lat_stamp(SENT)
@@ -2221,15 +2234,15 @@ class RaftNode:
                     # batch's ack) below.
                     self._lat.mark_committed(ctx.commit)
                 before = self.dispatcher.applied_frontier(G)
-                self.dispatcher.advance(ctx.commit)
+                st.note(lanes=self.dispatcher.advance(ctx.commit))
                 after = self.dispatcher.applied_frontier(G)
                 m["applies"] += int((after - before).sum())
                 m["commits"] = int(ctx.commit.astype(np.int64).sum())
                 st.enter("reads")
 
                 # -- 6b. read plane: stamped/released bookkeeping + serving --
-                self._harvest_reads(ctx.info)
-                self._serve_reads(after)
+                st.note(lanes=self._harvest_reads(ctx.info)
+                        + self._serve_reads(after))
                 st.enter("maintain")
 
                 # -- 7. maintain: checkpoints, compaction, snapshot downloads
@@ -2302,6 +2315,7 @@ class RaftNode:
         if self._native_wal and not self._poisoned_stripes:
             prep = self._persist_prepare(ctx, for_stripes=True)
         if prep is not None:
+            self._stages.note(lanes=prep.lanes)
             _stage_s, fsync_s = self._persist_stage_native(prep)
             # The conf sidecar (dirty only when an adoption span
             # truncated recorded conf entries) flushes before any ack
@@ -2310,6 +2324,7 @@ class RaftNode:
             self._barrier_ok()
             return prep, fsync_s, self._native_blob_fn
         prep = self._persist_prepare(ctx)
+        self._stages.note(lanes=prep.lanes)
         # NOTE: staging is NOT masked while stripes are quarantined — a
         # poisoned engine only buffers (its flush/fsync never run again),
         # and skipping span-build would drop device-accepted sinks before
@@ -2560,6 +2575,10 @@ class RaftNode:
         p.noop_idx = noop_arr
         p.noop_term = np.asarray(info.noop_term)
         p.noop_g = np.nonzero(noop_arr > 0)[0].tolist()
+        # Lanes the persist step visits one by one in Python: a span per
+        # written lane and per election no-op, a stable record per lane
+        # whose (term, ballot) moved.
+        p.lanes = len(wrote_l) + len(p.noop_g) + int(p.stable_mask.sum())
         p.conf_app = conf_app
         p.conf_term = np.asarray(info.conf_app_term)
         p.conf_word = np.asarray(info.conf_app_word)
@@ -2972,21 +2991,25 @@ class RaftNode:
 
     # ------------------------------------------------------------ read plane
 
-    def _harvest_reads(self, info: StepInfo) -> None:
+    def _harvest_reads(self, info: StepInfo) -> int:
         """Tick thread: mirror the device read FIFO's transitions reported
         in StepInfo — offers the device STAMPED move to pending with
         their ReadIndex; pending offers whose barrier RELEASED move to
         released (FIFO, exactly read_rel of them); device-side ABORTS
         (leadership/term change dropped the whole FIFO) fail every
-        un-served batch as a retry-safe refusal."""
+        un-served batch as a retry-safe refusal.  Returns the lanes
+        visited."""
         read_acc = np.asarray(info.read_acc)
         read_idx = np.asarray(info.read_index)
         read_rel = np.asarray(info.read_rel)
         read_abort = np.asarray(info.read_abort)
-        self.metrics["read_lease_hits"] += int(
-            np.asarray(info.read_lease).sum())
+        read_lease = np.asarray(info.read_lease)
+        self.metrics["read_lease_hits"] += int(read_lease.sum())
+        stamped = np.nonzero(read_acc > 0)[0].tolist()
+        released = np.nonzero(read_rel > 0)[0].tolist()
+        aborted = np.nonzero(read_abort)[0].tolist()
         with self._read_lock:
-            for g in np.nonzero(read_acc > 0)[0].tolist():
+            for g in stamped:
                 b = self._reads_offered.pop(g, None)
                 # The device stamps exactly the offer, whole (its intake
                 # reads HostInbox.read_n built from this mirror) — a
@@ -2995,13 +3018,14 @@ class RaftNode:
                 assert b is not None and int(read_acc[g]) == b.n, \
                     (f"g={g}: device stamped {int(read_acc[g])} reads "
                      "beyond the offer")
+                b.lease = bool(read_lease[g])
                 self._reads_pending.setdefault(g, deque()).append(
                     (int(read_idx[g]), b))
                 m = self.metrics
                 m["read_barriers"] += 1
                 m["reads_coalesced"] += b.n - len(b.parts[0].payloads)
                 m.observe("read_batch_queries", b.n)
-            for g in np.nonzero(read_rel > 0)[0].tolist():
+            for g in released:
                 q = self._reads_pending.get(g)
                 rel = self._reads_released.setdefault(g, deque())
                 for _ in range(int(read_rel[g])):
@@ -3013,14 +3037,16 @@ class RaftNode:
                 # apply frontier actually reached one.
                 if rel[0][0] < self._rel_min[g]:
                     self._rel_min[g] = rel[0][0]
-        for g in np.nonzero(read_abort)[0].tolist():
-            self._reject_reads(int(g))
+        for g in aborted:
+            self._reject_reads(g)
+        return len(stamped) + len(released) + len(aborted)
 
-    def _serve_reads(self, applied: np.ndarray) -> None:
+    def _serve_reads(self, applied: np.ndarray) -> int:
         """Tick thread: serve released batches whose ReadIndex the apply
         frontier covers.  Machine ``read`` runs here — the same
         single-writer thread as applies, so queries see a consistent
-        machine with no extra locking (machine/spi.py read SPI)."""
+        machine with no extra locking (machine/spi.py read SPI).
+        Returns the lanes visited."""
         # Columnar gate: one vector compare picks the groups whose apply
         # frontier reached a released batch's ReadIndex — the every-tick
         # walk over all groups holding a released deque was a per-group
@@ -3028,7 +3054,7 @@ class RaftNode:
         G = len(applied)
         due = np.nonzero(applied >= self._rel_min[:G])[0]
         if not len(due):
-            return
+            return 0
         sentinel = np.iinfo(np.int64).max
         ready: List[Tuple[int, int, _ReadOffer]] = []
         with self._read_lock:
@@ -3049,12 +3075,13 @@ class RaftNode:
                     self._rel_min[g] = sentinel
                     del self._reads_released[g]
         if not ready:
-            return
+            return len(due)
         now = time.monotonic()
-        queries = 0
+        queries = lease_hits = 0
         for g, idx, offer in ready:
             machine = self.dispatcher.machine(g)
             rd = getattr(machine, "read", None)
+            served = queries
             for b in offer.parts:
                 try:
                     for k, payload in enumerate(b.payloads):
@@ -3070,9 +3097,15 @@ class RaftNode:
                 queries += len(b.payloads)
                 self.metrics.observe("read_barrier_latency_s",
                                      now - b.t_enq)
+            # A barrier the lease released in the step that stamped it
+            # (counter read_lease_hits, counted at the stamp), where it
+            # served a query: never more than ``queries``.
+            lease_hits += int(offer.lease and queries > served)
         self.metrics["reads_served"] += queries
         # What this tick served, on its raft.reads span.
-        self._stages.note(queries=queries, barriers=len(ready))
+        self._stages.note(queries=queries, barriers=len(ready),
+                          lease_hits=lease_hits)
+        return len(due)
 
     def _reject_reads(self, g: int, exc: Optional[Exception] = None,
                       drop_released: bool = False) -> None:
@@ -3436,9 +3469,10 @@ class RaftNode:
                                deferred: Optional[Dict[int, np.ndarray]]
                                = None,
                                blob_fn: Optional[Callable] = None
-                               ) -> Dict[int, List[bytes]]:
+                               ) -> Tuple[Dict[int, List[bytes]], int]:
         """Pack one tick's outbox into per-peer kind
-        sections and return {peer: [sections]} — the caller folds into
+        sections and return {peer: [sections]} and the message columns
+        (lane by kind by peer) packed — the caller folds into
         ``_held_sections``; ``_flush_sends`` assembles each peer's
         sections into ONE MSGS frame.  ``deferred`` replaces the
         valid-column scan for the eager kinds: only the AE columns the
@@ -3455,6 +3489,7 @@ class RaftNode:
         win = self.store.payloads_window
         runs = getattr(self.store, "payload_runs", None)
         held: Dict[int, List[bytes]] = {}
+        packed = 0
         for p in range(P):
             if p == self.node_id:
                 continue
@@ -3481,9 +3516,10 @@ class RaftNode:
                     payload_blob_fn=blob_fn)
                 if n_cols:
                     secs.append(sec)
+                    packed += n_cols
             if secs:
                 held[p] = secs
-        return held
+        return held, packed
 
     def _eager_send(self, ctx: _TickCtx) -> None:
         """Overlapped ticks: pack THIS tick's AE sections right after
@@ -3571,9 +3607,10 @@ class RaftNode:
         and compaction moves a step at a time); every step notes how
         full the fullest ring stands."""
         now = self.timer_ticks
-        n_ckpt = n_pressed = 0
+        n_ckpt = n_pressed = lanes = 0
         if timer or self.maintain.pressed(self.h_commit, h_base).any():
-            n_ckpt, n_pressed = self._maintain_pass(now, applied, h_base)
+            n_ckpt, n_pressed, lanes = self._maintain_pass(
+                now, applied, h_base)
         # The fullest ring this node holds (the fsynced tail is the
         # tick's log tail once its host phase is here), on /metrics and
         # on this tick's raft.maintain span.
@@ -3583,7 +3620,7 @@ class RaftNode:
         self._stages.note(
             ring_used=ring_used, ring_slots=self.cfg.log_slots,
             led=int(((self.h_role == LEADER) & self.h_active).sum()),
-            checkpoints=n_ckpt, by_pressure=n_pressed)
+            checkpoints=n_ckpt, by_pressure=n_pressed, lanes=lanes)
         if not timer:
             return
         self._maintain_gc(now)
@@ -3594,9 +3631,10 @@ class RaftNode:
             self._scrub_archive()
 
     def _maintain_pass(self, now: int, applied: np.ndarray, h_base
-                       ) -> Tuple[int, int]:
+                       ) -> Tuple[int, int, int]:
         """One pass of the checkpoint and compaction policy at timer tick
-        ``now``; returns (checkpoints serialized, of them by pressure)."""
+        ``now``; returns (checkpoints serialized, of them by pressure,
+        lanes visited: saves harvested and groups found due)."""
         # Harvest completed off-thread saves FIRST: a milestone feeds the
         # compaction policy only once its archive copy is durable on disk
         # (a compaction grant must never outrun its snapshot).
@@ -3683,7 +3721,7 @@ class RaftNode:
         m["ckpt_by_pressure"] += n_pressed
         m["compactions_by_pressure"] += int(
             self.maintain.compact_pressed.sum())
-        return n_ckpt, n_pressed
+        return n_ckpt, n_pressed, len(done) + len(due)
 
     def _fold_wal_stats(self) -> None:
         """Fold the WAL engines' cumulative stage/fsync/pack counters

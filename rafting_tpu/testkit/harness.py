@@ -59,6 +59,20 @@ def free_ports(n: int) -> List[int]:
             s.close()
 
 
+def kv_factory():
+    """A ``RaftFactory`` whose nodes run ``KVMachine``s under their own
+    ``data_dir``: what a TCP test of served containers passes to
+    ``RaftContainer``."""
+    from ..api import RaftFactory
+    from ..machine.kv_machine import KVMachineProvider
+
+    class KVFactory(RaftFactory):
+        def machine_provider(self, config, node_id):
+            return KVMachineProvider(
+                os.path.join(config.data_dir, "machines"))
+    return KVFactory()
+
+
 def wal_store_factory(root: str, engine: str, shards: int = 4):
     """A ``store_factory`` for a LocalCluster under ``root`` whose nodes'
     WAL stores run ``engine`` — ``"native"`` or ``"python"``.  The engine

@@ -61,6 +61,13 @@ class PeerSender:
             target=self._run, name=f"raft-send-{my_id}->{peer_id}",
             daemon=True)
         self.connected = False
+        # Whether this channel ever reached its peer.  Until it has, a
+        # refused connect is a peer that is not up yet (a node that boots
+        # before its peers), counted as ``connects_refused_total``; after,
+        # every drop is a link that flaps, ``reconnects_total``, which the
+        # health plane reads as a sickness of this node's own
+        # (utils/health.py).
+        self._reached = False
         self._held: Optional[bytes] = None  # reorder nemesis holdback
 
     def start(self):
@@ -131,7 +138,7 @@ class PeerSender:
                 sock = socket.create_connection(self.addr, timeout=5)
                 sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
                 sock.sendall(self.hello)
-                self.connected = True
+                self.connected = self._reached = True
                 attempts = 0  # established: next drop restarts the ladder
                 while not self._stop.is_set():
                     try:
@@ -178,7 +185,8 @@ class PeerSender:
                         pass
             if not self._stop.is_set():
                 attempts += 1
-                self._count("reconnects_total")
+                self._count("reconnects_total" if self._reached
+                            else "connects_refused_total")
                 # stop.wait, not sleep: close() shouldn't stall on backoff
                 self._stop.wait(self._backoff(attempts))
 

@@ -95,6 +95,24 @@ def test_self_penalties_fold_storage_and_admission_signals():
     assert h.self_degraded()
 
 
+def test_a_peer_never_reached_scores_nothing_and_a_flapping_link_does():
+    """The transport tells a peer that is not up yet
+    (``connects_refused_total``: a node that boots before its peers) from a
+    link that flaps (``reconnects_total``); only the second is a sickness
+    of this node's own.  A cold boot of 10,000 lanes held the first member
+    alone for 20 s and was evacuated on its refused connects (PERF.md,
+    PR 31)."""
+    h = HealthRegistry(3, 0, half_life_ticks=1e6)
+    m = Metrics()
+    m["connects_refused_total"] += 40
+    h.ingest(1, m)
+    assert h.self_score == 0.0 and not h.self_degraded()
+    m["reconnects_total"] += 8
+    h.ingest(2, m)
+    assert h.self_score == pytest.approx(4.0)
+    assert h.self_degraded()
+
+
 def test_snapshot_shape_and_evacuation_audit():
     h = HealthRegistry(3, 0)
     h.note_contact(np.array([0, 7, 0], np.int64))

@@ -193,6 +193,71 @@ def test_a_tick_crosses_to_the_device_in_two_transfers_each_way(tmp_path):
         assert all(1 <= t <= 2 for t in transfers), (name, transfers)
 
 
+def test_lease_hits_lanes_and_leaderless_ride_the_spans(tmp_path):
+    """PR 31's statistics.  ``raft.reads``: ``lease_hits`` (barriers the
+    lease released in the step that stamped them, where they served a
+    query) never passes ``queries`` and sums over a run to the counter
+    ``read_lease_hits``.  The host phase's spans carry ``lanes`` (what the
+    phase walked one by one: a step that moved one group walks a few lanes,
+    not every lane the node holds); ``raft.mirrors`` carries ``leaderless``
+    and ``open``, 0 of them leaderless once every group is led."""
+    import jax
+
+    cfg = EngineConfig(n_groups=64, n_peers=3)
+    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1)
+    trace_dir = str(tmp_path / "trace")
+    try:
+        for g in range(cfg.n_groups):
+            c.wait_leader(g)
+        lead = c.leader_of(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        c.tick(3)
+        hits0 = node.metrics["read_lease_hits"]
+        served0 = node.metrics["reads_served"]
+        with jax.profiler.trace(trace_dir):
+            futs = []
+            for i in range(12):
+                if i == 5:
+                    # A vetoed step drops the lease evidence: the read it
+                    # stamps pays a ReadIndex round trip (no lease hit).
+                    node._read_veto_hold = 1
+                futs.append(node.read(0, b"q%d" % i))
+                if i % 3 == 0:
+                    futs.append(node.read(0, b"r%d" % i))   # shares a barrier
+                futs.append(node.submit(0, b"w%d" % i))
+                c.tick()
+            c.tick(4)
+            assert all(f.done() and f.exception() is None for f in futs)
+        hits = node.metrics["read_lease_hits"] - hits0
+        served = node.metrics["reads_served"] - served0
+    finally:
+        c.close()
+    reads, lanes, mirrors = [], {}, []
+    for name, stats in _raft_spans(trace_dir):
+        if name == "raft.reads" and stats["node"] == lead \
+                and "queries" in stats:
+            reads.append(stats)
+        if "lanes" in stats:
+            lanes.setdefault(name, []).append(stats["lanes"])
+        if name == "raft.mirrors":
+            mirrors.append(stats)
+    assert served == 16 and sum(s["queries"] for s in reads) == 16
+    assert all(0 <= s["lease_hits"] <= s["barriers"] <= s["queries"]
+               for s in reads)
+    assert sum(s["lease_hits"] for s in reads) == hits
+    assert 0 < hits < sum(s["barriers"] for s in reads)     # the vetoed one
+    assert set(lanes) == {"raft.wal", "raft.send", "raft.apply",
+                          "raft.reads", "raft.maintain"}
+    # One loaded group of 64: no phase of any step walks a tenth of the
+    # lanes, and the sends are the heartbeat columns (lane by peer).
+    for name in ("raft.wal", "raft.apply", "raft.reads", "raft.maintain"):
+        assert max(lanes[name]) <= 6, (name, max(lanes[name]))
+    assert max(lanes["raft.apply"]) >= 1 and max(lanes["raft.wal"]) >= 1
+    assert mirrors and all(s["open"] == cfg.n_groups
+                           and s["leaderless"] == 0 for s in mirrors)
+
+
 def test_note_without_a_session_is_nothing():
     st = StageSpans(Metrics(), 0)
     st.begin(1)
@@ -347,5 +412,27 @@ def test_late_ticks_from_a_fake_clock(tmp_path):
         assert h.total == pytest.approx((0.2 + 0.8) * period)
         assert h.max == pytest.approx(0.8 * period)
         assert node.metrics["ticks_late"] == 1
+    finally:
+        c.close()
+
+
+def test_leaderless_gauge_counts_open_lanes_until_they_are_led(tmp_path):
+    """``groups_leaderless``: every open lane before the first election,
+    none once every group is led and the followers know by whom; a store
+    that is electing reads differently from one that is sick."""
+    cfg = EngineConfig(n_groups=16, n_peers=3)
+    c = LocalCluster(cfg, str(tmp_path / "data"), seed=2)
+    try:
+        c.tick(1)
+        for node in c.nodes.values():
+            g = node.metrics._gauges
+            assert g["groups_leaderless"] == g["groups_active"] \
+                == cfg.n_groups
+        for g in range(cfg.n_groups):
+            c.wait_leader(g)
+        c.tick_until(lambda: all(
+            n.metrics._gauges["groups_leaderless"] == 0
+            for n in c.nodes.values()),
+            what="every lane led and known")
     finally:
         c.close()

@@ -273,9 +273,13 @@ def test_reconnect_backoff_math():
     assert s._backoff(20) <= RECONNECT_MAX
 
 
-def test_reconnect_counter_on_dead_peer():
-    """A sender pointed at a dead address increments reconnects_total on
-    every drop, and stop() interrupts the backoff wait promptly."""
+def test_refused_connects_of_a_peer_never_reached_are_not_reconnects():
+    """A sender pointed at an address nobody listens on yet (a node that
+    boots before its peers) counts ``connects_refused_total`` on every
+    attempt and no ``reconnects_total``: the health plane reads reconnects
+    as this node's own flapping link and evacuates leadership on them
+    (PERF.md, PR 31: a cold boot's 20 s alone degraded node 0).  stop()
+    interrupts the backoff wait promptly."""
     import socket as _socket
 
     from rafting_tpu.transport.tcp import PeerSender
@@ -291,13 +295,46 @@ def test_reconnect_counter_on_dead_peer():
     s = PeerSender(0, 1, ("127.0.0.1", port), b"hello", metrics=m)
     s.start()
     deadline = time.monotonic() + 5
-    while time.monotonic() < deadline and m["reconnects_total"] < 1:
+    while time.monotonic() < deadline and m["connects_refused_total"] < 1:
         time.sleep(0.02)
     t0 = time.monotonic()
     s.stop()
     assert time.monotonic() - t0 < 5   # stop never waits out the backoff
-    assert m["reconnects_total"] >= 1
+    assert m["connects_refused_total"] >= 1
+    assert m["reconnects_total"] == 0
     assert not s.connected
+
+
+def test_a_drop_after_the_peer_was_reached_is_a_reconnect():
+    """Once a channel has reached its peer, every later failure is a link
+    that flaps: ``reconnects_total``, whether or not the peer is back."""
+    import socket as _socket
+
+    from rafting_tpu.transport.tcp import PeerSender
+    from rafting_tpu.utils.metrics import Metrics
+
+    srv = _socket.socket()
+    srv.bind(("127.0.0.1", 0))
+    srv.listen(1)
+    m = Metrics()
+    s = PeerSender(0, 1, srv.getsockname(), b"hello", metrics=m)
+    s.start()
+    try:
+        conn, _ = srv.accept()
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline and not s.connected:
+            time.sleep(0.01)
+        assert s.connected
+        conn.close()
+        srv.close()                     # the peer is gone for good
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline and m["reconnects_total"] < 1:
+            s.send(b"x" * 64)           # a send finds the reset
+            time.sleep(0.05)
+        assert m["reconnects_total"] >= 1
+        assert m["connects_refused_total"] == 0
+    finally:
+        s.stop()
 
 
 # ------------------------------------------------------- fault injection --
