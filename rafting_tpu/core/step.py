@@ -64,6 +64,7 @@ from typing import Any, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.select import take_plane, take_slots
 from .packing import Layout
 from .types import (
     CANDIDATE, FOLLOWER, LEADER, NIL, PRE_CANDIDATE, I32,
@@ -120,40 +121,48 @@ def ring_term_at(log: LogState, idx: Array) -> Array:
     are committed, hence matched, the reference's purgeEntries rationale,
     Follower.java:209-221).  idx > last -> -1 (absent).
     """
-    L = log.term.shape[1]
-    slot = jnp.remainder(idx, L)
-    t = jnp.take_along_axis(log.term, slot[:, None], axis=1)[:, 0]
-    return jnp.where(idx <= log.base, log.base_term,
-                     jnp.where(idx <= log.last, t, jnp.asarray(-1, I32)))
+    return ring_terms_batch(log, idx[:, None])[:, 0]
 
 
 def ring_terms_batch(log: LogState, idx: Array) -> Array:
     """Terms for a [G, K] index matrix (absent -> -1)."""
     L = log.term.shape[1]
-    slot = jnp.remainder(idx, L)
-    t = jnp.take_along_axis(log.term, slot, axis=1)
+    t = take_slots(log.term, jnp.remainder(idx, L))
     return jnp.where(idx <= log.base[:, None], log.base_term[:, None],
                      jnp.where(idx <= log.last[:, None], t, jnp.asarray(-1, I32)))
 
 
 def ring_write_batch(log_term: Array, idx: Array, vals: Array, mask: Array) -> Array:
-    """Masked scatter of entry terms at [G, K] indices into the [G, L] ring."""
-    G, L = log_term.shape
-    rows = jnp.broadcast_to(jnp.arange(G, dtype=I32)[:, None], idx.shape)
-    slot = jnp.where(mask, jnp.remainder(idx, L), L)  # L = out of range -> dropped
-    return log_term.at[rows, slot].set(vals, mode="drop")
+    """Masked write of entry terms at [G, K] indices into the [G, L] ring
+    (a row's masked indices are distinct mod L: K <= L consecutive ones).
+    K selects over the whole ring, one pass once fused."""
+    L = log_term.shape[1]
+    j = jnp.arange(L, dtype=I32)[None, :]
+    slot = jnp.remainder(idx, L)
+    for k in range(idx.shape[1]):
+        hit = (j == slot[:, k:k + 1]) & mask[:, k:k + 1]
+        log_term = jnp.where(hit, vals[:, k:k + 1], log_term)
+    return log_term
+
+
+def ring_span(L: int, start: Array, n: Array) -> Array:
+    """[G, L] mask of the ring slots that hold the ``n`` consecutive
+    indices from ``start`` ([G] each, 0 <= n <= L): where one value goes
+    to the whole span (a submission's term, a cleared config word) the
+    write is one select on it."""
+    j = jnp.arange(L, dtype=I32)[None, :]
+    return jnp.remainder(j - start[:, None], L) < n[:, None]
 
 
 def ring_conf_batch(log: LogState, idx: Array) -> Array:
     """Packed config words for a [G, K] index matrix.
 
     0 outside the live window (compacted entries' configs are folded into
-    ``base_conf``; absent entries carry nothing) — the AE build gathers
+    ``base_conf``; absent entries carry nothing) — the AE build reads
     entry config words with exactly these semantics, so followers adopt
     configs with the same window rules as terms."""
     L = log.conf.shape[1]
-    slot = jnp.remainder(idx, L)
-    w = jnp.take_along_axis(log.conf, slot, axis=1)
+    w = take_slots(log.conf, jnp.remainder(idx, L))
     live = (idx > log.base[:, None]) & (idx <= log.last[:, None])
     return jnp.where(live, w, jnp.asarray(0, I32))
 
@@ -167,9 +176,8 @@ def latest_conf(log: LogState, upto: Array) -> Tuple[Array, Array]:
     derivation: a node uses the newest config present in its log whether
     committed or not, and a conflict truncation that removes an
     uncommitted config entry automatically reverts to the previous one —
-    no separate rollback state to maintain.  One [G, L] sweep, the same
-    shape of work as the replication gather (``ring_terms_batch`` over
-    [G, P*B])."""
+    no separate rollback state to maintain.  Two [G, L] sweeps: one finds
+    the index, one selects its word (``take_slots``, as every ring read)."""
     G, L = log.conf.shape
     j = jnp.arange(L, dtype=I32)[None, :]
     # The unique index congruent to slot j (mod L) within (last-L, last].
@@ -177,8 +185,7 @@ def latest_conf(log: LogState, upto: Array) -> Tuple[Array, Array]:
     isc = (idx > log.base[:, None]) & (idx <= upto[:, None]) \
         & (log.conf != 0)
     cidx = jnp.where(isc, idx, 0).max(axis=1)
-    w = jnp.take_along_axis(log.conf, jnp.remainder(cidx, L)[:, None],
-                            axis=1)[:, 0]
+    w = take_slots(log.conf, jnp.remainder(cidx, L)[:, None])[:, 0]
     has = cidx > 0
     return (jnp.where(has, cidx, 0),
             jnp.where(has, w, log.base_conf))
@@ -209,14 +216,6 @@ def _pick_peer(flag_pg: Array) -> Tuple[Array, Array]:
     Returns (peer_index [G], any_flag [G])."""
     any_f = flag_pg.any(axis=0)
     return jnp.argmax(flag_pg, axis=0).astype(I32), any_f
-
-
-def _gather_peer(field_pg: Array, peer: Array) -> Array:
-    """field[[P, G] or [P, G, K]], peer [G] -> per-group selected [G] / [G, K]."""
-    if field_pg.ndim == 2:
-        return jnp.take_along_axis(field_pg, peer[None, :], axis=0)[0]
-    return jnp.take_along_axis(
-        field_pg, peer[None, :, None], axis=0)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -412,11 +411,10 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # Every term-ring write clears/overwrites the conf-ring slot too: a
     # reused ring slot must never leak a dead entry's config word into
     # the latest_conf derivation.
+    noop_at = ring_span(L, log.last + 1, noop_ok.astype(I32))
     log = log.replace(
-        term=ring_write_batch(log.term, (log.last + 1)[:, None],
-                              term[:, None], noop_ok[:, None]),
-        conf=ring_write_batch(log.conf, (log.last + 1)[:, None],
-                              jnp.zeros((G, 1), I32), noop_ok[:, None]),
+        term=jnp.where(noop_at, term[:, None], log.term),
+        conf=jnp.where(noop_at, 0, log.conf),
         last=log.last + noop_ok.astype(I32))
 
     # ---- 4. AppendEntries requests ----------------------------------------
@@ -436,12 +434,12 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     leader_id = jnp.where(ae_any, ae_peer, leader_id)
     elect_dl = jnp.where(ae_any, now + rand_to, elect_dl)
 
-    prev_i = _gather_peer(inbox.ae_prev_idx, ae_peer)
-    prev_t = _gather_peer(inbox.ae_prev_term, ae_peer)
-    n_e = _gather_peer(inbox.ae_n, ae_peer)
-    lc = _gather_peer(inbox.ae_commit, ae_peer)
-    ents = _gather_peer(inbox.ae_ents, ae_peer)                  # [G, B]
-    cents = _gather_peer(inbox.ae_cents, ae_peer)                # [G, B]
+    prev_i = take_plane(inbox.ae_prev_idx, ae_peer)
+    prev_t = take_plane(inbox.ae_prev_term, ae_peer)
+    n_e = take_plane(inbox.ae_n, ae_peer)
+    lc = take_plane(inbox.ae_commit, ae_peer)
+    ents = take_plane(inbox.ae_ents, ae_peer)                    # [G, B]
+    cents = take_plane(inbox.ae_cents, ae_peer)                  # [G, B]
     # Bounded-window partial accept: the live window (base, last] must never
     # exceed the ring capacity L, or new entries would alias committed slots.
     # A follower whose compaction floor lags the leader's clamps the batch to
@@ -525,9 +523,9 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     role = jnp.where(is_any, FOLLOWER, role)
     leader_id = jnp.where(is_any, is_peer, leader_id)
     elect_dl = jnp.where(is_any, now + rand_to, elect_dl)
-    off_idx = _gather_peer(inbox.is_idx, is_peer)
-    off_term = _gather_peer(inbox.is_last_term, is_peer)
-    off_conf = _gather_peer(inbox.is_conf, is_peer)
+    off_idx = take_plane(inbox.is_idx, is_peer)
+    off_term = take_plane(inbox.is_last_term, is_peer)
+    off_conf = take_plane(inbox.is_conf, is_peer)
     # Success only once the milestone is covered: either our snapshot floor
     # already includes it, or we hold a matching entry at that index.  While
     # the bulk download is in flight we answer failure so the leader keeps
@@ -588,12 +586,10 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     sw_idx = log.last[:, None] - jnp.remainder(log.last[:, None] - jL, L)
     sw_isc = (sw_idx > log.base[:, None]) & (log.conf != 0)
     cidx_all = jnp.where(sw_isc, sw_idx, 0).max(axis=1)
-    w_all = jnp.take_along_axis(
-        log.conf, jnp.remainder(cidx_all, L)[:, None], axis=1)[:, 0]
+    w_all = take_slots(log.conf, jnp.remainder(cidx_all, L)[:, None])[:, 0]
     cidx_ct = jnp.where(sw_isc & (sw_idx <= ct[:, None]), sw_idx, 0) \
         .max(axis=1)
-    w_ct = jnp.take_along_axis(
-        log.conf, jnp.remainder(cidx_ct, L)[:, None], axis=1)[:, 0]
+    w_ct = take_slots(log.conf, jnp.remainder(cidx_ct, L)[:, None])[:, 0]
     ct_conf = jnp.where(cidx_ct > 0, w_ct, log.base_conf)
     log = log.replace(base=jnp.where(do_c, ct, log.base),
                       base_term=jnp.where(do_c, ct_term, log.base_term),
@@ -773,10 +769,11 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         # them via keep_reads — this lane just counts what was saved).
         K_cq = cfg.read_slots
         jcol = jnp.arange(K_cq, dtype=I32)[None, :]
-        pend_slot = jnp.remainder(s.rq_head[:, None] + jcol, K_cq)
-        pend_n = jnp.where(jcol < s.rq_len[:, None],
-                           jnp.take_along_axis(s.rq_n, pend_slot, axis=1),
-                           0).sum(axis=1)
+        # FIFO position of each physical slot: the pending ones are the
+        # first rq_len from the head.
+        pend_pos = jnp.remainder(jcol - s.rq_head[:, None], K_cq)
+        pend_n = jnp.where(pend_pos < s.rq_len[:, None], s.rq_n, 0) \
+            .sum(axis=1)
         cq_veto = jnp.where(cq_down, pend_n, 0)
     else:
         cq_down = None
@@ -852,14 +849,10 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     n_acc = jnp.where(active & (role == LEADER) & ~fenced,
                       jnp.clip(host.submit_n, 0, jnp.minimum(free, S)), 0)
     sub_start = log.last + 1
-    scol = jnp.arange(S, dtype=I32)[None, :]
-    sidx = log.last[:, None] + 1 + scol
-    smask = scol < n_acc[:, None]
-    new_ring = ring_write_batch(log.term, sidx,
-                                jnp.broadcast_to(term[:, None], (G, S)), smask)
-    new_cring = ring_write_batch(log.conf, sidx, jnp.zeros((G, S), I32),
-                                 smask)
-    log = log.replace(term=new_ring, conf=new_cring, last=log.last + n_acc)
+    sub_at = ring_span(L, sub_start, n_acc)                      # n_acc <= S
+    log = log.replace(term=jnp.where(sub_at, term[:, None], log.term),
+                      conf=jnp.where(sub_at, 0, log.conf),
+                      last=log.last + n_acc)
     app_from = jnp.where((n_acc > 0) & (app_from == 0), sub_start, app_from)
     app_to = jnp.where(n_acc > 0, log.last, app_to)
 
@@ -894,11 +887,12 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
                        & stamp_open,
                        jnp.maximum(host.read_n, 0), 0)
     read_acc = n_read > 0
-    rows_g = jnp.arange(G, dtype=I32)
-    slot_in = jnp.where(read_acc, jnp.remainder(rq_head + rq_len, K), K)
-    rq_idx = rq_idx.at[rows_g, slot_in].set(commit, mode="drop")
-    rq_stamp = rq_stamp.at[rows_g, slot_in].set(now, mode="drop")
-    rq_n = rq_n.at[rows_g, slot_in].set(n_read, mode="drop")
+    slot_in = (jnp.arange(K, dtype=I32)[None, :]
+               == jnp.remainder(rq_head + rq_len, K)[:, None]) \
+        & read_acc[:, None]                                      # [G, K]
+    rq_idx = jnp.where(slot_in, commit[:, None], rq_idx)
+    rq_stamp = jnp.where(slot_in, now, rq_stamp)
+    rq_n = jnp.where(slot_in, n_read[:, None], rq_n)
     rq_len = rq_len + read_acc.astype(I32)
     read_index_out = jnp.where(read_acc, commit, 0)
     # Release (ops/quorum.py): with the lease, evidence received THIS
@@ -944,11 +938,10 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     conf_app = want_enter | want_leave
     app_word = jnp.where(want_leave, leave_word, enter_word)
     nidx = log.last + 1
+    conf_at = ring_span(L, nidx, conf_app.astype(I32))
     log = log.replace(
-        term=ring_write_batch(log.term, nidx[:, None], term[:, None],
-                              conf_app[:, None]),
-        conf=ring_write_batch(log.conf, nidx[:, None], app_word[:, None],
-                              conf_app[:, None]),
+        term=jnp.where(conf_at, term[:, None], log.term),
+        conf=jnp.where(conf_at, app_word[:, None], log.conf),
         last=log.last + conf_app.astype(I32))
     conf_app_idx = jnp.where(conf_app, nidx, 0)
     conf_app_term = jnp.where(conf_app, term, 0)
@@ -1022,7 +1015,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     send_ae = send_data | send_hb                                # [G, P]
     n_send = jnp.where(send_data, n_avail, 0)
     prev = send_next - 1
-    # One fused gather for all peers' batches: [G, P*B] -> [P, G, B].
+    # One read for all peers' batches: [G, P*B] -> [P, G, B].
     flat_idx = (send_next[:, :, None] + col[None, :, :]).reshape(G, P * B)
     ents_all = ring_terms_batch(log, flat_idx).reshape(G, P, B)
     cents_all = ring_conf_batch(log, flat_idx).reshape(G, P, B)
@@ -1087,8 +1080,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # whole log, tell it to campaign.  Re-sent every tick while the
     # condition holds — duplicates are fenced by the receiver's term
     # check, and loss costs one tick, not the transfer.
-    tgt_match = jnp.take_along_axis(
-        match_idx, jnp.clip(xfer_to, 0, P - 1)[:, None], axis=1)[:, 0]
+    tgt_match = take_plane(match_idx.T, jnp.clip(xfer_to, 0, P - 1))
     xfer_fire = (active & (role == LEADER) & (xfer_to != NIL)
                  & (tgt_match >= log.last))
     out_tn_valid = (peer_ids[:, None] == xfer_to[None, :]) & xfer_fire[None, :]
@@ -1205,10 +1197,12 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         # over-D select ~3-6x).  Instead the fired events compact into a
         # dense NE-wide window ([G, NE, NE] one-hot, D-independent), and
         # the ring blends it in with one take_along_axis per varying lane
-        # — the same gather idiom as ring_terms_batch.  Ring position d
-        # takes window offset (d - n) mod D when that offset < n_new;
-        # tick/term are uniform across a tick's events, so those two
-        # lanes need only the write mask.
+        # (the one gather left in the step; the recorder is off in every
+        # served configuration, and the log rings, the read FIFO and the
+        # peer planes are addressed by compare-and-select, ops/select.py).
+        # Ring position d takes window offset (d - n) mod D when that
+        # offset < n_new; tick/term are uniform across a tick's events,
+        # so those two lanes need only the write mask.
         off_hit = (prior[:, :, None] ==
                    jnp.arange(NE, dtype=I32)[None, None, :]) \
             & ev_masks[:, :, None]                           # [G, NE, NE]

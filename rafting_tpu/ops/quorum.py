@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.types import I32
+from .select import take_slots
 
 BLOCK_ROWS = 8          # sublanes per grid step
 LANES = 128
@@ -244,8 +245,8 @@ def read_barrier_release(voters, voters_new, me, read_evid, rq_stamp,
     P = read_evid.shape[1]
     j = jnp.arange(K, dtype=I32)[None, :]                       # FIFO pos
     slot = jnp.remainder(rq_head[:, None] + j, K)               # [G, K]
-    st = jnp.take_along_axis(rq_stamp, slot, axis=1)
-    n = jnp.take_along_axis(rq_n, slot, axis=1)
+    st = take_slots(rq_stamp, slot)
+    n = take_slots(rq_n, slot)
     pending = j < rq_len[:, None]
     # Evidence 0 means "none this leadership"; stamps are >= 1 (the tick
     # clock starts at 1), so the comparison needs no extra guard.

@@ -221,3 +221,268 @@ def test_node_metrics_report(tmp_path):
         assert total_led == 2
     finally:
         c.close()
+
+
+# ---------------------------------------------------------------------------
+# The ring primitives against a plain numpy model.  Every read and write
+# the step makes over the log ring, the read FIFO and the peer planes is a
+# compare-and-select along that axis (ops/select.py); the model below
+# addresses one element at a time, as the gathers and scatters they
+# replaced did.
+# ---------------------------------------------------------------------------
+
+_G, _L, _B, _P, _K = 37, 64, 8, 3, 4      # odd G; the shipped L, B = S, K
+
+
+def _ring_case(seed):
+    """A log whose live window wraps the ring in most rows: bases up to
+    3 L, lengths 0 .. L."""
+    from rafting_tpu.core.types import LogState
+    rng = np.random.default_rng(seed)
+    base = rng.integers(0, 3 * _L, _G).astype(np.int32)
+    last = base + rng.integers(0, _L + 1, _G).astype(np.int32)
+    log = LogState(
+        term=jnp.asarray(rng.integers(1, 50, (_G, _L)), jnp.int32),
+        conf=jnp.asarray(np.where(rng.random((_G, _L)) < 0.15,
+                                  rng.integers(1, 1 << 9, (_G, _L)), 0),
+                         jnp.int32),
+        base=jnp.asarray(base), base_term=jnp.asarray(
+            rng.integers(1, 50, _G), jnp.int32),
+        base_conf=jnp.asarray(rng.integers(1, 8, _G), jnp.int32),
+        last=jnp.asarray(last))
+    return rng, log
+
+
+def _windows(rng, log, K):
+    """[G, K] consecutive indices from starts around the live window's
+    two ends: rows with idx < base, == base, inside, == last, > last."""
+    base, last = np.asarray(log.base), np.asarray(log.last)
+    start = np.where(rng.random(_G) < 0.5, base - 2, last - K + 3) \
+        + rng.integers(-2, 3, _G)
+    start[:4] = [base[0], last[1] + 1, base[2] - K, last[3]]
+    return (np.maximum(start, 0)[:, None]
+            + np.arange(K)[None, :]).astype(np.int32)
+
+
+def _np_read(log, idx, ring, under_base, absent):
+    out = np.empty(idx.shape, np.int32)
+    ring, base, last = (np.asarray(a) for a in (ring, log.base, log.last))
+    for g in range(idx.shape[0]):
+        for k in range(idx.shape[1]):
+            i = idx[g, k]
+            out[g, k] = under_base(g) if i <= base[g] else \
+                ring[g, i % _L] if i <= last[g] else absent
+    return out
+
+
+def _case_read(kind, K):
+    def run():
+        from rafting_tpu.core.step import (
+            ring_conf_batch, ring_term_at, ring_terms_batch)
+        rng, log = _ring_case(100 + K)
+        idx = _windows(rng, log, K)
+        if kind == "terms":
+            got = ring_terms_batch(log, jnp.asarray(idx))
+            want = _np_read(log, idx, log.term,
+                            lambda g: int(log.base_term[g]), -1)
+        elif kind == "conf":
+            got = ring_conf_batch(log, jnp.asarray(idx))
+            want = _np_read(log, idx, log.conf, lambda g: 0, 0)
+        else:
+            got = ring_term_at(log, jnp.asarray(idx[:, 0]))[:, None]
+            want = _np_read(log, idx[:, :1], log.term,
+                            lambda g: int(log.base_term[g]), -1)
+        np.testing.assert_array_equal(np.asarray(got), want)
+    return run
+
+
+def _case_latest_conf(short):
+    def run():
+        from rafting_tpu.core.step import latest_conf
+        rng, log = _ring_case(7 + short)
+        base, last = np.asarray(log.base), np.asarray(log.last)
+        upto = np.maximum(last - rng.integers(0, 9, _G), base) \
+            .astype(np.int32) if short else last
+        cidx, word = latest_conf(log, jnp.asarray(upto))
+        conf = np.asarray(log.conf)
+        for g in range(_G):
+            want = (0, int(log.base_conf[g]))
+            for i in range(base[g] + 1, min(upto[g], last[g]) + 1):
+                if conf[g, i % _L]:
+                    want = (i, conf[g, i % _L])
+            assert (int(cidx[g]), int(word[g])) == want, g
+    return run
+
+
+def _case_write(K, mask_kind):
+    def run():
+        from rafting_tpu.core.step import ring_write_batch
+        rng, log = _ring_case(300 + K)
+        idx = _windows(rng, log, K)
+        vals = rng.integers(100, 200, (_G, K)).astype(np.int32)
+        col = np.arange(K)[None, :]
+        mask = {
+            "none": np.zeros((_G, K), bool),
+            "all": np.ones((_G, K), bool),
+            # What the follower's append and the submit write: the first
+            # n of the window, n = 0 .. K by row.
+            "prefix": col < rng.integers(0, K + 1, _G)[:, None],
+            "suffix": col >= rng.integers(0, K + 1, _G)[:, None],
+            # Whole rows dropped (the scatter's out-of-range rows).
+            "rows": np.broadcast_to((rng.random(_G) < 0.5)[:, None],
+                                    (_G, K)),
+        }[mask_kind]
+        got = ring_write_batch(log.term, jnp.asarray(idx), jnp.asarray(vals),
+                               jnp.asarray(mask))
+        want = np.asarray(log.term).copy()
+        for g in range(_G):
+            for k in range(K):
+                if mask[g, k]:
+                    want[g, idx[g, k] % _L] = vals[g, k]
+        np.testing.assert_array_equal(np.asarray(got), want)
+    return run
+
+
+def _case_span():
+    from rafting_tpu.core.step import ring_span
+    rng = np.random.default_rng(11)
+    start = rng.integers(0, 5 * _L, _G).astype(np.int32)
+    n = rng.integers(0, _L + 1, _G).astype(np.int32)
+    n[:3] = [0, _L, 1]
+    got = np.asarray(ring_span(_L, jnp.asarray(start), jnp.asarray(n)))
+    want = np.zeros((_G, _L), bool)
+    for g in range(_G):
+        for i in range(start[g], start[g] + n[g]):
+            want[g, i % _L] = True
+    np.testing.assert_array_equal(got, want)
+
+
+def _case_take_plane(ndim):
+    def run():
+        from rafting_tpu.ops.select import take_plane
+        rng = np.random.default_rng(13 + ndim)
+        shape = (_P, _G) if ndim == 2 else (_P, _G, _B)
+        field = rng.integers(0, 1000, shape).astype(np.int32)
+        peer = rng.integers(0, _P, _G).astype(np.int32)
+        got = take_plane(jnp.asarray(field), jnp.asarray(peer))
+        np.testing.assert_array_equal(
+            np.asarray(got), field[peer, np.arange(_G)])
+    return run
+
+
+def _case_fifo_slots():
+    """take_slots over the K-slot read FIFO, the other axis it addresses."""
+    from rafting_tpu.ops.select import take_slots
+    rng = np.random.default_rng(17)
+    fifo = rng.integers(1, 99, (_G, _K)).astype(np.int32)
+    slot = ((rng.integers(0, _K, _G)[:, None] + np.arange(_K)[None, :])
+            % _K).astype(np.int32)
+    np.testing.assert_array_equal(
+        np.asarray(take_slots(jnp.asarray(fifo), jnp.asarray(slot))),
+        np.take_along_axis(fifo, slot, axis=1))
+
+
+def _case_barrier(lens):
+    def run():
+        from rafting_tpu.ops.quorum import read_barrier_release
+        rng = np.random.default_rng(19)
+        me = 1
+        head = rng.integers(0, _K, _G).astype(np.int32)
+        rq_len = {"empty": np.zeros(_G), "full": np.full(_G, _K),
+                  "mixed": rng.integers(0, _K + 1, _G)}[lens] \
+            .astype(np.int32)
+        # Stamps rise along the FIFO from the head, as the step writes
+        # them; evidence falls somewhere among them.
+        t0 = rng.integers(1, 20, _G)
+        stamp = np.zeros((_G, _K), np.int32)
+        n = rng.integers(1, 9, (_G, _K)).astype(np.int32)
+        for g in range(_G):
+            for j in range(_K):
+                stamp[g, (head[g] + j) % _K] = t0[g] + 2 * j
+        evid = (t0[:, None] + rng.integers(-2, 2 * _K + 1, (_G, _P))) \
+            .astype(np.int32)
+        voters = rng.integers(1, 8, _G).astype(np.int32)
+        vnew = np.where(rng.random(_G) < 0.3, rng.integers(1, 8, _G), 0) \
+            .astype(np.int32)
+        n_rel, n_served = read_barrier_release(
+            *(jnp.asarray(a) for a in (voters, vnew)), me,
+            *(jnp.asarray(a) for a in (evid, stamp, head, rq_len, n)))
+
+        def quorum(bits, s, g):
+            members = [p for p in range(_P) if bits >> p & 1]
+            yes = [p for p in members if p == me or evid[g, p] >= s]
+            return len(yes) >= len(members) // 2 + 1
+
+        for g in range(_G):
+            rel = served = 0
+            for j in range(rq_len[g]):
+                k = (head[g] + j) % _K
+                if not (quorum(voters[g], stamp[g, k], g) and
+                        (vnew[g] == 0 or quorum(vnew[g], stamp[g, k], g))):
+                    break
+                rel, served = rel + 1, served + n[g, k]
+            assert (int(n_rel[g]), int(n_served[g])) == (rel, served), g
+    return run
+
+
+def _case_fifo_intake():
+    """The step's own write into the read FIFO: a ready leader with
+    rq_len 0 .. K is offered a batch; a full FIFO takes nothing and no
+    slot but the tail's moves."""
+    from rafting_tpu.core.step import node_step
+    from rafting_tpu.core.types import (
+        LEADER, HostInbox, Messages, init_state)
+    cfg = EngineConfig(n_groups=16, n_peers=_P, read_slots=_K)
+    G = cfg.n_groups
+    rng = np.random.default_rng(23)
+    head = rng.integers(0, _K, G).astype(np.int32)
+    rq_len = (np.arange(G) % (_K + 1)).astype(np.int32)
+    fifo = {name: rng.integers(1, 99, (G, _K)).astype(np.int32)
+            for name in ("rq_idx", "rq_stamp", "rq_n")}
+    st = init_state(cfg, 0, seed=0)
+    st = st.replace(
+        role=jnp.full((G,), LEADER, jnp.int32),
+        term=jnp.ones((G,), jnp.int32), commit=jnp.full((G,), 5, jnp.int32),
+        leader_id=jnp.zeros((G,), jnp.int32),
+        hb_due=jnp.full((G,), 1 << 20, jnp.int32),
+        rq_head=jnp.asarray(head), rq_len=jnp.asarray(rq_len),
+        **{k: jnp.asarray(v) for k, v in fifo.items()})
+    active = np.asarray(st.active)
+    host = HostInbox.empty(cfg).replace(
+        read_n=jnp.full((G,), 3, jnp.int32))
+    now = int(st.now) + 1
+    new, _, info = node_step(cfg, st, Messages.empty(cfg), host)
+    took = active & (rq_len < _K)
+    np.testing.assert_array_equal(np.asarray(info.read_acc) > 0, took)
+    np.testing.assert_array_equal(np.asarray(info.read_rel), 0)
+    np.testing.assert_array_equal(np.asarray(new.rq_len),
+                                  np.where(active, rq_len + took, 0))
+    for name, put in (("rq_idx", 5), ("rq_stamp", now), ("rq_n", 3)):
+        want = fifo[name].copy()
+        for g in np.nonzero(took)[0]:
+            want[g, (head[g] + rq_len[g]) % _K] = put
+        np.testing.assert_array_equal(np.asarray(getattr(new, name)), want)
+
+
+_RING_CASES = (
+    [(f"read_{kind}_K{K}", _case_read(kind, K))
+     for kind in ("terms", "conf") for K in (1, _B, _P * _B)]
+    + [("read_term_at", _case_read("at", 1)),
+       ("latest_conf_upto_last", _case_latest_conf(0)),
+       ("latest_conf_upto_short", _case_latest_conf(1))]
+    + [(f"write_K{K}_mask_{m}", _case_write(K, m))
+       for K in (1, _B) for m in ("none", "all", "prefix", "suffix", "rows")]
+    + [("span", _case_span),
+       ("take_plane_2d", _case_take_plane(2)),
+       ("take_plane_3d", _case_take_plane(3)),
+       ("fifo_take_slots", _case_fifo_slots),
+       ("barrier_rq_len_0", _case_barrier("empty")),
+       ("barrier_rq_len_K", _case_barrier("full")),
+       ("barrier_rq_len_mixed", _case_barrier("mixed")),
+       ("fifo_intake_rq_len_0_to_K", _case_fifo_intake)])
+
+
+@pytest.mark.parametrize("case", [c for _, c in _RING_CASES],
+                         ids=[n for n, _ in _RING_CASES])
+def test_ring_primitive_matches_numpy_model(case):
+    case()

@@ -11,7 +11,9 @@ conftest.py), every compile happens in the test's own process, and all
 cases stay in this one file.
 """
 
+import json
 import os
+import re
 import sys
 
 import jax
@@ -56,6 +58,24 @@ def _on(sharding, tree):
         tree)
 
 
+def _lower_step(sharding, cfg, packed):
+    """``node_step`` as the pipelined runtime feeds it (durable-tail lane
+    present), or ``node_step_packed``, the program the runtime calls,
+    lowered for the described chip at ``cfg``'s shape."""
+    state = _on(sharding, jax.eval_shape(lambda: init_state(cfg, 0, seed=0)))
+    inbox = _on(sharding, jax.eval_shape(lambda: Messages.empty(cfg)))
+    host = _on(sharding, jax.eval_shape(
+        lambda: HostInbox.empty(cfg).replace(
+            durable_tail=jnp.zeros((cfg.n_groups,), jnp.int32))))
+    if not packed:
+        return node_step.lower(cfg, state, inbox, host)
+    inputs, _ = step_layouts(cfg, True)
+    assert inputs == Layout((host, inbox))
+    bufs = tuple(jax.ShapeDtypeStruct((n,), dt, sharding=sharding)
+                 for dt, n in inputs.buffers)
+    return node_step_packed.lower(cfg, inputs, state, bufs)
+
+
 @pytest.mark.parametrize("n_peers", [3, 5])
 def test_quorum_kernel_compiles_at_100k_groups(one_chip, n_peers):
     G = 100_000
@@ -83,25 +103,45 @@ def test_node_step_fits_one_chip_at_the_smoke_shape(one_chip, packed):
         uris, 0, chip_smoke.SERVED_LANES, "unused").engine_config()
     assert cfg.n_groups == chip_smoke.SERVED_LANES and cfg.n_peers == 3
 
-    state = _on(one_chip, jax.eval_shape(lambda: init_state(cfg, 0, seed=0)))
-    inbox = _on(one_chip, jax.eval_shape(lambda: Messages.empty(cfg)))
-    host = _on(one_chip, jax.eval_shape(
-        lambda: HostInbox.empty(cfg).replace(
-            durable_tail=jnp.zeros((cfg.n_groups,), jnp.int32))))
+    lowered = _lower_step(one_chip, cfg, packed)
     if packed:
         inputs, readback = step_layouts(cfg, True)
-        assert inputs == Layout((host, inbox))
         # 48 MB each way at this shape: in pieces (core/packing.py).
         assert len(inputs.buffers) > 2 and len(readback.buffers) > 2
-        bufs = tuple(jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
-                     for dt, n in inputs.buffers)
-        lowered = node_step_packed.lower(cfg, inputs, state, bufs)
         _, out = lowered.out_info
         assert tuple((np.dtype(o.dtype), o.shape[0]) for o in out) \
             == readback.buffers
-    else:
-        lowered = node_step.lower(cfg, state, inbox, host)
     mem = lowered.compile().memory_analysis()
     per_node = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes)
     assert 3 * per_node < HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["node_step", "node_step_packed"])
+def test_node_step_holds_no_gather_and_no_scatter(one_chip, packed):
+    """The step addresses its rings, the read FIFO and the peer planes by
+    compare-and-select along the axis (ops/select.py): at the shape of the
+    10,000-Region cell, as its configuration file builds the engine, the
+    chip's compiler is left with no gather and no scatter (35 of them
+    were 8 of a step's 11 ms on the chip), and with no [G, K, L] one-hot
+    among its temporaries (25,023,488 bytes of them before; the one-hot of
+    the AppendEntries build alone would be 61 MB)."""
+    from rafting_tpu.api import RaftConfig
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "multiraft-10k-3v.json")) as f:
+        raft = json.load(f)["raft_config"]
+    uris = [f"raft://127.0.0.1:{7001 + i}" for i in range(3)]
+    cfg = RaftConfig(local=uris[0], peers=tuple(uris[1:]),
+                     data_dir="unused", **raft).engine_config()
+    assert (cfg.n_groups, cfg.n_peers, cfg.log_slots, cfg.batch,
+            cfg.max_submit, cfg.read_slots) == (10_000, 3, 64, 8, 8, 4)
+
+    compiled = _lower_step(one_chip, cfg, packed).compile()
+    hlo = compiled.as_text()
+    found = {op: len(re.findall(rf"\b{op}\(", hlo))
+             for op in ("gather", "scatter")}
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"{found} temp_size_in_bytes={temp}")
+    assert found == {"gather": 0, "scatter": 0}
+    assert temp < 32 * 1024 ** 2, temp
