@@ -99,6 +99,11 @@ SETTLE_MARGIN = 2.0
 # as its upper envelope, so the order does not flip tick by tick.
 HOST_COST_MEMORY = 8
 
+# Heartbeat rounds a node follows at once (``hb_round_s``).  A started loop
+# has one open, the period's; a caller that steps its nodes in lock step
+# advances the clock every step and hears of a round two steps later.
+HB_ROUNDS_OPEN = 4
+
 
 def settles_now(now: float, due: Optional[float], cost: float) -> bool:
     """Whether a pipelined tick, just fetched at ``now``, runs its own
@@ -312,7 +317,7 @@ class _TickCtx:
         "submit_n", "read_n", "staged_payloads", "arrays",
         # whether the step advanced the engine's clock (a timer tick) or
         # was started for arriving work inside a period
-        "timer",
+        "timer", "started",
         # the packed results on the device and their layout (dispatch)
         "packed", "readback",
         # -> host views of the fetched buffers (fetch)
@@ -817,6 +822,12 @@ class RaftNode:
         # Seconds per whole step, the same memory: arrival_step_at()
         # weighs their median against the time left until _tick_due.
         self._step_costs: deque = deque(maxlen=HOST_COST_MEMORY)
+        # Heartbeat rounds in flight, oldest first: [the engine's clock of
+        # the timer's step that sent the period's heartbeats, that step's
+        # start, the peers whose acknowledgement of it is still out].
+        self._hb_rounds: deque = deque(maxlen=HB_ROUNDS_OPEN)
+        self._hb_closed: Optional[float] = None   # start of a round this
+        #                           step's drain closed (tick() ends it)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # Double-buffered pipeline state: the fetched-but-not-yet-host-
@@ -857,6 +868,7 @@ class RaftNode:
         # (core/packing.py CHUNK_BYTES).
         self.metrics["h2d_transfers"] += 0
         self.metrics["d2h_transfers"] += 0
+        self.metrics["hb_rounds_closed"] += 0
         # Read plane: offers the device stamped (one ReadIndex barrier
         # each) and the queries that rode a barrier another call opened.
         self.metrics["read_barriers"] += 0
@@ -1556,7 +1568,7 @@ class RaftNode:
         # Whatever was queued before this instant the intake below sees;
         # whatever is queued after it sets the event again.
         self._wake.clear()
-        ctx = self._dispatch(arrival)
+        ctx = self._dispatch(arrival, _tick_t0)
         if self.pipeline:
             prev, self._pending = self._pending, None
             try:
@@ -1645,6 +1657,14 @@ class RaftNode:
         # ticks.
         if not arrival:
             self._health_tick()
+        opened, self._hb_closed = self._hb_closed, None
+        if opened is not None:
+            # This step's drain held the last acknowledgement of a
+            # heartbeat round: the round ends where the step does.
+            took = time.perf_counter() - opened
+            m["hb_rounds_closed"] += 1
+            m.observe("hb_round_s", took)
+            st.note(hb_round_s=took)
         st.leave()
         return ctx.info
 
@@ -1775,7 +1795,7 @@ class RaftNode:
 
     # ------------------------------------------------------- tick: dispatch
 
-    def _dispatch(self, arrival: bool) -> _TickCtx:
+    def _dispatch(self, arrival: bool, started: float) -> _TickCtx:
         cfg = self.cfg
         G = cfg.n_groups
 
@@ -1943,6 +1963,8 @@ class RaftNode:
         arrays, staged_payloads = self.acc.drain(
             {name: getattr(inbox, name) for name in self.template})
         self._fold_inbox_stats()
+        if self._hb_rounds:
+            self._hb_acknowledged(arrays)
         # The [G] host planes built above are copied into theirs (a few
         # KB; 4 MB of the 48 at 100,000 lanes).
         jax.tree.map(np.copyto, host, HostInbox(
@@ -1957,7 +1979,7 @@ class RaftNode:
         st = self._stages
         st.enter("dispatch_upload")
         packed = jax.device_put(buffers)
-        st.note(transfers=len(packed))
+        st.note(transfers=len(packed), bytes=sum(b.nbytes for b in buffers))
         self.metrics["h2d_transfers"] += len(packed)
 
         # -- 3. device step (async dispatch: no transfer, no block) ----------
@@ -1971,12 +1993,46 @@ class RaftNode:
         ctx = _TickCtx()
         ctx.submit_n, ctx.read_n = submit_n, read_n
         ctx.timer = not arrival
+        ctx.started = started
         ctx.staged_payloads, ctx.arrays = staged_payloads, arrays
         ctx.packed, ctx.readback = packed, readback
         ctx.deferred_ae = None
         self._inflight_submit = self._inflight_submit + submit_n
         self._inflight_read = self._inflight_read + read_n
         return ctx
+
+    def _hb_open(self, outbox, started: float) -> None:
+        """Tick thread, at the fetch of the timer's step: the period's
+        heartbeat round opens against every peer this step addresses an
+        AppendEntries to.  The outbox stamps every lane with the engine's
+        clock (``ae_tick``), which the acknowledgements echo; a node that
+        leads nothing opens nothing."""
+        peers = {p for p in range(self.cfg.n_peers)
+                 if p != self.node_id and outbox.ae_valid[p].any()}
+        if peers:
+            self._hb_rounds.append(
+                [int(outbox.ae_tick.flat[0]), started, peers])
+
+    def _hb_acknowledged(self, arrays) -> None:
+        """Tick thread, right after ``acc.drain()``: strike from each open
+        round the peers whose drained slices acknowledge a heartbeat of
+        that round's period (``aer_tick`` echoes the clock it was sent
+        at).  The round whose last peer this step's drain strikes closes
+        with this step (``tick`` notes ``hb_round_s`` at its end); rounds
+        opened before it can no longer close and go with it."""
+        valid, echo = arrays["aer_valid"], arrays["aer_tick"]
+        heard = [p for p in range(self.cfg.n_peers) if valid[p].any()]
+        if not heard:
+            return
+        rounds = self._hb_rounds
+        for clock, started, peers in rounds:
+            for p in heard:
+                if p in peers and (valid[p] & (echo[p] == clock)).any():
+                    peers.discard(p)
+        while rounds and any(not r[2] for r in rounds):
+            clock, started, peers = rounds.popleft()
+            if not peers:
+                self._hb_closed = started
 
     def _fold_inbox_stats(self) -> None:
         """Tick thread, right after ``acc.drain()``: fold what the drain
@@ -2024,7 +2080,7 @@ class RaftNode:
         packed = jax.block_until_ready(ctx.packed)
         st.enter("scan_fetch")
         fetched = jax.device_get(packed)
-        st.note(transfers=len(fetched))
+        st.note(transfers=len(fetched), bytes=sum(b.nbytes for b in fetched))
         self.metrics["d2h_transfers"] += len(fetched)
         st.enter("mirrors")
         back = ctx.readback.unpack(fetched)
@@ -2135,6 +2191,8 @@ class RaftNode:
 
         self.ticks += 1
         self.timer_ticks += int(ctx.timer)
+        if ctx.timer:
+            self._hb_open(back.outbox, ctx.started)
         # Open lanes for which this node neither leads ready nor knows a
         # leader: what tells a store that is electing from one that is
         # sick or overloaded.  Sampled every step, on /metrics and on the
