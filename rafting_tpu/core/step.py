@@ -59,13 +59,13 @@ from __future__ import annotations
 
 import functools
 from functools import partial
-from typing import Any, NamedTuple, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.select import take_plane, take_slots
-from .packing import Layout
+from .packing import WORD, ColumnLayout, Layout
 from .types import (
     CANDIDATE, FOLLOWER, LEADER, NIL, PRE_CANDIDATE, I32,
     EngineConfig, HostInbox, LogState, Messages, RaftState, StepInfo,
@@ -1384,13 +1384,10 @@ def _step_readback(cfg: EngineConfig, state: RaftState, inbox: Messages,
         base=state.log.base, base_term=state.log.base_term, heat=state.heat)
 
 
-@functools.lru_cache(maxsize=None)
-def step_layouts(cfg: EngineConfig, durable: bool) -> Tuple[Layout, Layout]:
-    """(inputs, readback) layouts of ``node_step_packed`` for ``cfg``:
-    inputs pack ``(HostInbox, Messages)``, with the ``durable_tail`` lane
-    when ``durable``; readback packs a :class:`Readback`.  Both follow the
-    dataclasses (and ``cfg``'s optional subtrees) through
-    ``jax.eval_shape``."""
+def _step_shapes(cfg: EngineConfig, durable: bool):
+    """Shapes of (HostInbox, Messages, Readback) as the served step takes
+    and returns them, through ``jax.eval_shape``: they follow the
+    dataclasses and ``cfg``'s optional subtrees."""
     def host_inbox():
         host = HostInbox.empty(cfg)
         if durable:
@@ -1401,6 +1398,15 @@ def step_layouts(cfg: EngineConfig, durable: bool) -> Tuple[Layout, Layout]:
         host_inbox, lambda: Messages.empty(cfg),
         lambda: init_state(cfg, 0)))
     _, back = jax.eval_shape(partial(_step_readback, cfg), state, inbox, host)
+    return host, inbox, back
+
+
+@functools.lru_cache(maxsize=None)
+def step_layouts(cfg: EngineConfig, durable: bool) -> Tuple[Layout, Layout]:
+    """(inputs, readback) layouts of ``node_step_packed`` for ``cfg``:
+    inputs pack ``(HostInbox, Messages)``, with the ``durable_tail`` lane
+    when ``durable``; readback packs a :class:`Readback`."""
+    host, inbox, back = _step_shapes(cfg, durable)
     return Layout((host, inbox)), Layout(back)
 
 
@@ -1417,3 +1423,91 @@ def node_step_packed(cfg: EngineConfig, inputs: Layout, state: RaftState,
     host, inbox = inputs.unpack(buffers)
     state, back = _step_readback(cfg, state, inbox, host)
     return state, Layout(back).pack(back)
+
+
+# ---------------------------------------------------------------------------
+# The column step: node_step_packed for a node so large that its message
+# planes take several buffers.  There the planes are tens of MB each way
+# and a step moves a handful of their columns, so the Messages operand and
+# result cross as columns (core/packing.py ColumnLayout) and are dense on
+# the device alone; the [G] planes (HostInbox, StepInfo, the mirrored
+# lanes) stay packed as they are.  A step whose messages do not fit the
+# column buffers (a heartbeat round, an election storm) crosses densely
+# exactly as node_step_packed's does: decided by a count, nothing cut.
+# ---------------------------------------------------------------------------
+
+class ColumnLayouts(NamedTuple):
+    """The layouts of ``node_step_columns`` for one ``(cfg, durable)``."""
+
+    host: Layout            # HostInbox alone: what goes up beside columns
+    inputs: Layout          # (HostInbox, Messages) dense: step_layouts' own
+    back: Layout            # a Readback without its outbox
+    outbox: Layout          # the dense outbox, for a step that overflowed
+    columns: ColumnLayout   # Messages in column form, both directions
+
+
+# Columns engage where the step's dense operand takes at least this many
+# word buffers (core/packing.py CHUNK_BYTES each: some 33,000 lanes at P=3,
+# B=8).  What the column form costs does not depend on the lanes (K-row
+# scatters and gathers on the device, a buffer pair more each way: 3 ms of a
+# step on a TPU v5e at either size), what it saves does (the dense planes'
+# allocation, transfer, unpacking and packing: 0.13 ms a step a thousand
+# lanes): at 10,000 lanes (2 word buffers) the column step cost the
+# 10,000-Region cell 3 ms a step and 4-7 ms of a 22 ms read, at 100,000 (12)
+# it saves 14 ms of 50 and 30 ms of a 100 ms read; the two cross near three
+# buffers (PERF.md, PR 35).
+COLUMN_BUFFERS = 4
+
+
+@functools.lru_cache(maxsize=None)
+def column_layouts(cfg: EngineConfig, durable: bool
+                   ) -> Optional[ColumnLayouts]:
+    """The column step's layouts, or None for a shape that keeps
+    ``node_step_packed``: a rule on the packed layout, which the shape
+    alone decides (``COLUMN_BUFFERS``); below it the dense planes cross
+    at little more than a transfer's fixed cost and columns lose."""
+    inputs, _ = step_layouts(cfg, durable)
+    if sum(dt == WORD for dt, _ in inputs.buffers) < COLUMN_BUFFERS:
+        return None
+    host, inbox, back = _step_shapes(cfg, durable)
+    return ColumnLayouts(
+        host=Layout(host), inputs=inputs,
+        back=Layout(back._replace(outbox=None)), outbox=Layout(back.outbox),
+        columns=ColumnLayout(inbox))
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=3)
+def node_step_columns(cfg: EngineConfig, lay: ColumnLayouts,
+                      columns_in: bool, state: RaftState,
+                      buffers: Tuple[Array, ...]):
+    """``node_step`` with its messages in column form.  ``buffers``:
+    ``lay.host``'s followed by the inbox's column pair when
+    ``columns_in``, else ``lay.inputs``' (the dense operand of
+    ``node_step_packed``).  Returns the new state, the buffers of the
+    Readback without its outbox (``lay.back``), the outbox's column pair
+    (whose counts say whether it fits: ``lay.columns.K``), and the dense
+    outbox itself (``lay.columns.stack``'s few arrays, not its forty
+    planes: a result is a Python object a call), left on the device for
+    ``pack_outbox`` should it not.
+    ``node_step`` gets the planes it always got, bit for bit: the columns
+    are expanded into zero planes by a K-row scatter and compacted from
+    the outbox by K-row gathers, nothing is addressed G rows at a time."""
+    if columns_in:
+        n_host = len(lay.host.buffers)
+        host = lay.host.unpack(buffers[:n_host])
+        inbox = lay.columns.expand(buffers[n_host:])
+    else:
+        host, inbox = lay.inputs.unpack(buffers)
+    state, back = _step_readback(cfg, state, inbox, host)
+    dense = lay.columns.stack(back.outbox)
+    return (state, lay.back.pack(back._replace(outbox=None)),
+            lay.columns.compact(dense, stacked=True), dense)
+
+
+@partial(jax.jit, static_argnums=0)
+def pack_outbox(lay: ColumnLayouts, dense: Tuple[Array, ...]
+                ) -> Tuple[Array, ...]:
+    """The dense outbox a column step left on the device, packed for the
+    fetch of a step whose outbox did not fit its columns (``lay.outbox``:
+    as node_step_packed's readback holds it)."""
+    return lay.outbox.pack(lay.columns.unstack(dense))

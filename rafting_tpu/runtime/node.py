@@ -49,7 +49,10 @@ import jax.numpy as jnp
 import numpy as np
 
 
-from ..core.step import node_step_packed, step_layouts
+from ..core.packing import DenseView
+from ..core.step import (
+    column_layouts, node_step_columns, node_step_packed, pack_outbox,
+    step_layouts)
 from ..core.types import (
     I32, I32_SAFE_MAX, LEADER, NIL, EngineConfig, HostInbox,
     StepInfo, boot_conf_word as _boot_conf_word, init_state,
@@ -60,6 +63,7 @@ from ..machine.spi import Checkpoint, MachineProvider
 from ..snapshot.archive import SnapshotArchive
 from ..snapshot.policy import MaintainAgreement
 from ..transport import InboxAccumulator, messages_template
+from ..transport.inbox import fill_columns, scatter_dense
 from ..transport.codec import (
     EAGER_KINDS, KIND_FIELDS, assemble_slice, pack_hops, pack_kind_section,
 )
@@ -318,8 +322,11 @@ class _TickCtx:
         # whether the step advanced the engine's clock (a timer tick) or
         # was started for arriving work inside a period
         "timer", "started",
-        # the packed results on the device and their layout (dispatch)
-        "packed", "readback",
+        # the packed results on the device and their layout (dispatch);
+        # for a column step (core/step.py node_step_columns) its layouts,
+        # the outbox's column pair at the end of ``packed`` and the dense
+        # outbox left on the device
+        "packed", "readback", "columns", "out_dense",
         # -> host views of the fetched buffers (fetch)
         "info", "outbox", "term", "voted", "role", "leader", "commit",
         "base", "base_term",
@@ -868,6 +875,12 @@ class RaftNode:
         # (core/packing.py CHUNK_BYTES).
         self.metrics["h2d_transfers"] += 0
         self.metrics["d2h_transfers"] += 0
+        # Steps whose messages crossed as columns, and steps of a shape
+        # that takes columns whose messages did not fit them, each way
+        # (core/step.py node_step_columns).
+        for name in ("steps_columns_in", "steps_columns_out",
+                     "column_overflows_in", "column_overflows_out"):
+            self.metrics[name] += 0
         self.metrics["hb_rounds_closed"] += 0
         # Read plane: offers the device stamped (one ReadIndex barrier
         # each) and the queries that rode a barrier another call opened.
@@ -1951,22 +1964,40 @@ class RaftNode:
         self._compact_grant = np.zeros(G, np.int64)
 
         # -- 2. network inbox ------------------------------------------------
-        # This tick's packed upload buffers (core/packing.py), fresh and
-        # zeroed: the dense inbox planes are views of them, filled where
-        # they cross to the device from, and ctx.arrays points at those
-        # views until this tick's host phase is done with them (in an
-        # overlapped tick: through the next dispatch, which fills its
-        # own).
+        # This tick's upload buffers (core/packing.py), fresh: the [G]
+        # host planes packed, and the drained slices' messages either as
+        # the columns they arrived as (a shape whose dense planes do not
+        # fit a buffer, core/step.py column_layouts, and a step whose
+        # every source fits the column buffers: nothing here is then
+        # allocated, zeroed or walked P x G) or, as ever, as zeroed dense
+        # planes that are views of the packed buffers, filled where they
+        # cross to the device from.  ctx.arrays reads either form
+        # (DenseView / ColumnView) until this tick's host phase is done
+        # with it (in an overlapped tick: through the next dispatch,
+        # which fills its own).
         inputs, readback = step_layouts(cfg, durable is not None)
-        buffers = inputs.alloc()
-        host, inbox = inputs.unpack(buffers)
-        arrays, staged_payloads = self.acc.drain(
-            {name: getattr(inbox, name) for name in self.template})
+        lay = column_layouts(cfg, durable is not None)
+        batches, staged_payloads = self.acc.pop()
+        arrays = None
+        if lay is not None:
+            pair = lay.columns.alloc()
+            view = lay.columns.view(pair)
+            if fill_columns(batches, view):
+                arrays = view
+                buffers = lay.host.alloc()
+                host = lay.host.unpack(buffers)
+                buffers += pair
+        if arrays is None:
+            buffers = inputs.alloc()
+            host, inbox = inputs.unpack(buffers)
+            arrays = DenseView({name: getattr(inbox, name)
+                                for name in self.template})
+            scatter_dense(batches, arrays.planes)
         self._fold_inbox_stats()
         if self._hb_rounds:
             self._hb_acknowledged(arrays)
         # The [G] host planes built above are copied into theirs (a few
-        # KB; 4 MB of the 48 at 100,000 lanes).
+        # KB; 4 MB at 100,000 lanes).
         jax.tree.map(np.copyto, host, HostInbox(
             submit_n=submit_n, snap_done=snap_done, snap_idx=snap_idx,
             snap_term=snap_term, snap_conf=snap_conf, compact_to=compact_to,
@@ -1979,16 +2010,31 @@ class RaftNode:
         st = self._stages
         st.enter("dispatch_upload")
         packed = jax.device_put(buffers)
-        st.note(transfers=len(packed), bytes=sum(b.nbytes for b in buffers))
+        columns_in = arrays.columns
+        st.note(transfers=len(packed), bytes=sum(b.nbytes for b in buffers),
+                columns=columns_in or 0, dense=int(columns_in is None))
         self.metrics["h2d_transfers"] += len(packed)
 
         # -- 3. device step (async dispatch: no transfer, no block) ----------
         # The step hands back, packed the same way, everything _fetch reads
         # (core/step.py Readback): the tick keeps no reference to a leaf of
-        # the state, which the next step donates.
+        # the state, which the next step donates.  The column step hands
+        # back its outbox twice: as columns, fetched with the rest, and
+        # dense, left on the device for the fetch of a step whose outbox
+        # does not fit them.
         st.enter("dispatch_enqueue")
-        self.state, packed = node_step_packed(
-            cfg, inputs, self.state, packed)
+        out_dense = None
+        if lay is None:
+            self.state, packed = node_step_packed(
+                cfg, inputs, self.state, packed)
+        else:
+            if columns_in is None:
+                self.metrics["column_overflows_in"] += 1
+            else:
+                self.metrics["steps_columns_in"] += 1
+            self.state, back, pair, out_dense = node_step_columns(
+                cfg, lay, columns_in is not None, self.state, packed)
+            packed, readback = back + pair, lay.back
 
         ctx = _TickCtx()
         ctx.submit_n, ctx.read_n = submit_n, read_n
@@ -1996,6 +2042,7 @@ class RaftNode:
         ctx.started = started
         ctx.staged_payloads, ctx.arrays = staged_payloads, arrays
         ctx.packed, ctx.readback = packed, readback
+        ctx.columns, ctx.out_dense = lay, out_dense
         ctx.deferred_ae = None
         self._inflight_submit = self._inflight_submit + submit_n
         self._inflight_read = self._inflight_read + read_n
@@ -2007,27 +2054,37 @@ class RaftNode:
         AppendEntries to.  The outbox stamps every lane with the engine's
         clock (``ae_tick``), which the acknowledgements echo; a node that
         leads nothing opens nothing."""
-        peers = {p for p in range(self.cfg.n_peers)
-                 if p != self.node_id and outbox.ae_valid[p].any()}
+        clock, peers = None, set()
+        for p in range(self.cfg.n_peers):
+            if p == self.node_id:
+                continue
+            valid = outbox.row("ae_valid", p)
+            if valid.any():
+                peers.add(p)
+                if clock is None:
+                    clock = int(outbox.row("ae_tick", p)[valid.argmax()])
         if peers:
-            self._hb_rounds.append(
-                [int(outbox.ae_tick.flat[0]), started, peers])
+            self._hb_rounds.append([clock, started, peers])
 
     def _hb_acknowledged(self, arrays) -> None:
-        """Tick thread, right after ``acc.drain()``: strike from each open
+        """Tick thread, right after the drain (``acc.pop()`` merged into
+        ``arrays``, a DenseView or a ColumnView): strike from each open
         round the peers whose drained slices acknowledge a heartbeat of
         that round's period (``aer_tick`` echoes the clock it was sent
         at).  The round whose last peer this step's drain strikes closes
         with this step (``tick`` notes ``hb_round_s`` at its end); rounds
         opened before it can no longer close and go with it."""
-        valid, echo = arrays["aer_valid"], arrays["aer_tick"]
-        heard = [p for p in range(self.cfg.n_peers) if valid[p].any()]
+        heard = {}
+        for p in range(self.cfg.n_peers):
+            valid = arrays.row("aer_valid", p)
+            if valid.any():
+                heard[p] = arrays.row("aer_tick", p)[valid]
         if not heard:
             return
         rounds = self._hb_rounds
         for clock, started, peers in rounds:
-            for p in heard:
-                if p in peers and (valid[p] & (echo[p] == clock)).any():
+            for p, echo in heard.items():
+                if p in peers and (echo == clock).any():
                     peers.discard(p)
         while rounds and any(not r[2] for r in rounds):
             clock, started, peers = rounds.popleft()
@@ -2080,15 +2137,40 @@ class RaftNode:
         packed = jax.block_until_ready(ctx.packed)
         st.enter("scan_fetch")
         fetched = jax.device_get(packed)
-        st.note(transfers=len(fetched), bytes=sum(b.nbytes for b in fetched))
-        self.metrics["d2h_transfers"] += len(fetched)
+        lay, outbox, extra = ctx.columns, None, ()
+        if lay is not None:
+            # A column step: the outbox came down as its columns, whose
+            # counts say whether they hold it.  If a row overflowed, the
+            # dense outbox still on the device is packed there and
+            # fetched as node_step_packed's is: the whole step goes
+            # dense, no column is cut.
+            fetched, pair = fetched[:-2], fetched[-2:]
+            outbox = lay.columns.view(pair)
+            if (outbox.n > lay.columns.K).any():
+                outbox = None
+                extra = jax.device_get(pack_outbox(lay, ctx.out_dense))
+                self.metrics["column_overflows_out"] += 1
+            else:
+                self.metrics["steps_columns_out"] += 1
+            ctx.out_dense = None
+            extra = pair + extra
+        st.note(transfers=len(fetched) + len(extra),
+                bytes=sum(b.nbytes for b in fetched + extra),
+                columns=0 if outbox is None else outbox.columns,
+                dense=int(outbox is None))
+        self.metrics["d2h_transfers"] += len(fetched) + len(extra)
         st.enter("mirrors")
         back = ctx.readback.unpack(fetched)
         ctx.packed = None
+        if outbox is None:
+            dense = back.outbox if lay is None else \
+                lay.outbox.unpack(extra[2:])
+            outbox = DenseView({name: getattr(dense, name)
+                                for name in self.template})
         h_info, h_heat = back.info, back.heat
         h_term, h_role, h_leader = back.term, back.role, back.leader_id
         h_commit, h_base = back.commit, back.base
-        ctx.info, ctx.outbox = h_info, back.outbox
+        ctx.info, ctx.outbox = h_info, outbox
         ctx.term, ctx.voted, ctx.role = h_term, back.voted_for, h_role
         ctx.leader, ctx.commit = h_leader, h_commit
         ctx.base, ctx.base_term = h_base, back.base_term
@@ -2192,7 +2274,7 @@ class RaftNode:
         self.ticks += 1
         self.timer_ticks += int(ctx.timer)
         if ctx.timer:
-            self._hb_open(back.outbox, ctx.started)
+            self._hb_open(outbox, ctx.started)
         # Open lanes for which this node neither leads ready nor knows a
         # leader: what tells a store that is electing from one that is
         # sick or overloaded.  Sampled every step, on /metrics and on the
@@ -2347,9 +2429,9 @@ class RaftNode:
         spans); a node with no live spans pays one attribute check."""
         if self._hops is not None:
             out = ctx.outbox
-            self._hops.scan_outbox(np.asarray(out.ae_valid),
-                                   np.asarray(out.ae_prev_idx),
-                                   np.asarray(out.ae_n))
+            self._hops.scan_outbox(out.dense("ae_valid"),
+                                   out.dense("ae_prev_idx"),
+                                   out.dense("ae_n"))
 
     def _persist(self, ctx: _TickCtx
                  ) -> Tuple[_PersistPrep, float, Optional[Callable]]:
@@ -2585,17 +2667,18 @@ class RaftNode:
         # Staged-frame metadata for the whole wrote set in three fancy
         # indexes (the per-group [src, g] scalar reads were ~3 numpy
         # scalar indexings per adopting group).
-        if inbox_arrays and len(wrote):
+        if inbox_arrays is not None and len(wrote):
+            # Rows of the wrote set alone, whichever form the inbox
+            # crossed in: [len(wrote), ...] copies, indexed like wrote_l.
             src_clip = np.maximum(h_leader[wrote], 0)
-            fr_valid = (inbox_arrays["ae_valid"][src_clip, wrote]
+            at = inbox_arrays.at
+            fr_valid = (at("ae_valid", src_clip, wrote)
                         & (h_leader[wrote] >= 0)).tolist()
-            fr_n = inbox_arrays["ae_n"][src_clip, wrote].tolist()
-            fr_start = (inbox_arrays["ae_prev_idx"][src_clip, wrote]
-                        + 1).tolist()
-            fr_ents = inbox_arrays["ae_ents"]
-            fr_cents = inbox_arrays.get("ae_cents")
-            if for_stripes and fr_cents is not None \
-                    and bool(fr_cents[src_clip, wrote].any()):
+            fr_n = at("ae_n", src_clip, wrote).tolist()
+            fr_start = (at("ae_prev_idx", src_clip, wrote) + 1).tolist()
+            fr_ents = at("ae_ents", src_clip, wrote)
+            fr_cents = at("ae_cents", src_clip, wrote)
+            if for_stripes and bool(fr_cents.any()):
                 # Adopted config words: put_conf is the Python step's.
                 return None
         else:
@@ -2842,7 +2925,7 @@ class RaftNode:
                     k = lo - run.start
                     cnt = end_cov - lo + 1
                     koff = lo - fr_start[j]
-                    terms = fr_ents[leader_src, g, koff:koff + cnt]
+                    terms = fr_ents[j, koff:koff + cnt]
                     spans.append((g, lo, run.piece(k, cnt),
                                   run.lens[k:k + cnt], terms))
                     # The membership sidecar mirrors the WAL's overwrite
@@ -2856,7 +2939,7 @@ class RaftNode:
                     if conf_overwrite is not None:
                         conf_overwrite(g, lo)
                     if put_conf is not None and fr_cents is not None:
-                        cw = fr_cents[leader_src, g, koff:koff + cnt]
+                        cw = fr_cents[j, koff:koff + cnt]
                         if cw.any():
                             for kk in np.nonzero(cw)[0].tolist():
                                 put_conf(g, lo + kk, int(cw[kk]))
@@ -3542,8 +3625,6 @@ class RaftNode:
         # resent AE could let followers quorum-commit a range this node
         # cannot back).  Central choke point for every packing site.
         mask = self._healthy_groups
-        fields_all = {name: np.asarray(getattr(h_out, name))
-                      for name in self.template}
         win = self.store.payloads_window
         runs = getattr(self.store, "payload_runs", None)
         held: Dict[int, List[bytes]] = {}
@@ -3551,7 +3632,8 @@ class RaftNode:
         for p in range(P):
             if p == self.node_id:
                 continue
-            fields = {name: arr[p] for name, arr in fields_all.items()}
+            fields = h_out.fields(p)
+            healthy = None if mask is None else h_out.over(p, mask)
             secs: List[bytes] = []
             for kind in KIND_FIELDS:
                 if deferred is not None and kind in EAGER_KINDS:
@@ -3563,10 +3645,10 @@ class RaftNode:
                         if not len(cols):
                             continue
                 else:
-                    valid = fields[KIND_FIELDS[kind][0]]
-                    if mask is not None:
-                        valid = valid & mask
-                    cols = np.nonzero(valid)[0].astype(np.uint32)
+                    valid = h_out.row(KIND_FIELDS[kind][0], p)
+                    if healthy is not None:
+                        valid = valid & healthy
+                    cols = h_out.lanes(p, valid)
                     if not len(cols):
                         continue
                 sec, n_cols, _dropped = pack_kind_section(
@@ -3597,8 +3679,7 @@ class RaftNode:
             ctx.deferred_ae = None
             return
         P = self.cfg.n_peers
-        fields_all = {name: np.asarray(getattr(ctx.outbox, name))
-                      for name in self.template}
+        out = ctx.outbox
         win = self.store.payloads_window
         runs = getattr(self.store, "payload_runs", None)
         deferred: Dict[int, np.ndarray] = {}
@@ -3606,10 +3687,13 @@ class RaftNode:
         for p in range(P):
             if p == self.node_id:
                 continue
-            fields = {name: arr[p] for name, arr in fields_all.items()}
+            fields = out.fields(p)
             for kind in EAGER_KINDS:
+                cols = out.lanes(p, out.row(KIND_FIELDS[kind][0], p))
+                if not len(cols):
+                    continue
                 sec, n_cols, dropped = pack_kind_section(
-                    kind, fields, win, runs)
+                    kind, fields, win, runs, cols=cols)
                 if n_cols:
                     self._held_sections.setdefault(p, []).append(sec)
                     n_eager += n_cols

@@ -88,27 +88,17 @@ class InboxAccumulator:
                 return   # = network loss; sender's resend timeout recovers
             q.append((fields, payloads, time.perf_counter()))
 
-    def drain(self, arrays: Optional[Dict[str, np.ndarray]] = None
-              ) -> Tuple[Dict[str, np.ndarray],
-                         Dict[Tuple[int, int], Tuple[int, list]]]:
-        """Pop the oldest queued slice of every source and merge them into
-        one dense inbox (different sources occupy disjoint [src, :] rows,
-        so one slice per source never collides).  A source whose backlog
-        exceeds COLLAPSE_BACKLOG has its entire queue collapsed instead
-        (newest wins per lane) so lag stays bounded.
-
-        ``arrays``: the zeroed dense planes to fill, one per template
-        field — the runtime hands in views of the tick's packed upload
-        buffer, so the planes are written where they cross to the device
-        from; by default fresh ones are allocated.
-
-        Returns the dense arrays (ownership transfers to the caller) and
-        the popped slices' payload runs keyed (src, group) — newest-wins
-        per group under collapse, matching the field planes."""
-        P, G = self.cfg.n_peers, self.cfg.n_groups
-        if arrays is None:
-            arrays = {name: np.zeros((P, G) + trail, dt)
-                      for name, (dt, trail) in self.template.items()}
+    def pop(self) -> Tuple[Dict[int, List[Dict]],
+                           Dict[Tuple[int, int], Tuple[int, list]]]:
+        """Pop the oldest queued slice of every source: ``{src: [fields,
+        ...]}`` in arrival order (one slice; a source whose backlog
+        exceeds COLLAPSE_BACKLOG hands over its entire queue instead, to
+        be merged newest-wins per lane so lag stays bounded) and the
+        popped slices' payload runs keyed (src, group), newest-wins per
+        group under collapse, matching the fields.  The caller merges
+        the fields into the form its step takes: :func:`scatter_dense`
+        or :func:`fill_columns`."""
+        batches: Dict[int, List[Dict]] = {}
         payloads: Dict[Tuple[int, int], Tuple[int, list]] = {}
         with self._lock:
             st = self._stats
@@ -124,12 +114,34 @@ class InboxAccumulator:
                 else:
                     batch = [q.popleft()]
                 st.depth[src] = len(self._queues[src])
+                mine = batches[src] = []
                 for fields, pl, arrived in batch:
                     st.waits_s.append(now - arrived)
-                    for name, (cols, vals) in fields.items():
-                        arrays[name][src, cols] = vals
+                    mine.append(fields)
                     for g, run in pl.items():
                         payloads[(src, g)] = run
+        return batches, payloads
+
+    def drain(self, arrays: Optional[Dict[str, np.ndarray]] = None
+              ) -> Tuple[Dict[str, np.ndarray],
+                         Dict[Tuple[int, int], Tuple[int, list]]]:
+        """:meth:`pop` merged into one dense inbox (different sources
+        occupy disjoint [src, :] rows, so one slice per source never
+        collides).
+
+        ``arrays``: the zeroed dense planes to fill, one per template
+        field — the runtime hands in views of the tick's packed upload
+        buffer, so the planes are written where they cross to the device
+        from; by default fresh ones are allocated.
+
+        Returns the dense arrays (ownership transfers to the caller) and
+        the payload runs."""
+        P, G = self.cfg.n_peers, self.cfg.n_groups
+        if arrays is None:
+            arrays = {name: np.zeros((P, G) + trail, dt)
+                      for name, (dt, trail) in self.template.items()}
+        batches, payloads = self.pop()
+        scatter_dense(batches, arrays)
         return arrays, payloads
 
     def take_stats(self) -> InboxStats:
@@ -143,3 +155,51 @@ class InboxAccumulator:
     def has_traffic(self) -> bool:
         with self._lock:
             return any(self._queues.values())
+
+
+def scatter_dense(batches: Dict[int, List[Dict]],
+                  arrays: Dict[str, np.ndarray]) -> None:
+    """Write popped slices into zeroed dense ``[P, G, ...]`` planes, in
+    arrival order: the newest wins a lane."""
+    for src, batch in batches.items():
+        for fields in batch:
+            for name, (cols, vals) in fields.items():
+                arrays[name][src, cols] = vals
+
+
+def fill_columns(batches: Dict[int, List[Dict]], view) -> bool:
+    """Write popped slices into an empty column buffer pair (``view``: a
+    core/packing.py ColumnView of an ``alloc()``-ed pair) as
+    :func:`scatter_dense` writes them into dense planes: a source's
+    columns are the union of its slices' lanes, ascending, and each field
+    lands at its lanes' places in arrival order.  False, with nothing
+    written, when a source holds more columns than the buffers take: the
+    step then crosses densely.  Work follows the columns that arrived,
+    never P x G."""
+    K = view.cols.shape[1]
+    plans = []
+    for src, batch in batches.items():
+        # A kind's fields share one lanes array: place each once.
+        shared: List[Tuple[np.ndarray, List]] = []
+        for fields in batch:
+            by_lanes: Dict[int, Tuple[np.ndarray, List]] = {}
+            for name, (cols, vals) in fields.items():
+                by_lanes.setdefault(id(cols), (cols, []))[1].append(
+                    (name, vals))
+            shared.extend(by_lanes.values())
+        if any(len(cols) > K for cols, _ in shared):
+            return False
+        if not shared:
+            continue
+        union = np.unique(np.concatenate([cols for cols, _ in shared]))
+        if len(union) > K:
+            return False
+        plans.append((src, union, shared))
+    for src, union, shared in plans:
+        view.n[src] = len(union)
+        view.cols[src, :len(union)] = union
+        for cols, parts in shared:
+            at = np.searchsorted(union, cols)
+            for name, vals in parts:
+                view.planes[name][src, at] = vals
+    return True

@@ -1,0 +1,350 @@
+"""Serving with the step's messages in column form (core/packing.py
+ColumnLayout, core/step.py node_step_columns, PERF.md PR 35).
+
+At 10,000 lanes and more the dense message planes do not fit one packed
+buffer, and there a step's messages cross as the columns that moved; a step
+whose messages do not fit the column buffers (a heartbeat round, an
+election) crosses densely, decided by a count.  These tests force both at
+a size a CPU holds, by a small ``CHUNK_BYTES`` (the shape rule engages) and
+a small ``COLUMNS`` (rounds and elections overflow): (a) a lock-step
+cluster whose every step is cross-checked against the scalar oracle while
+it elects, serves single operations and runs its heartbeat rounds; (b)
+three served containers that take writes and linearizable reads through
+``RaftStub`` over such steps, pass ``testkit/linz.py``, and whose four
+counters say what the spans' ``columns`` / ``dense`` say.
+"""
+
+import json
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import rafting_tpu.runtime.node as node_mod
+from rafting_tpu.api import RaftConfig, RaftContainer
+from rafting_tpu.core import packing
+from rafting_tpu.core.step import column_layouts, step_layouts
+from rafting_tpu.core.types import EngineConfig, LEADER
+from rafting_tpu.testkit import linz
+from rafting_tpu.testkit.fixtures import NullProvider
+from rafting_tpu.testkit.harness import (
+    LocalCluster, free_ports, kv_factory, scaled_election_mul)
+from rafting_tpu.testkit.history import History
+from rafting_tpu.testkit.oracle import oracle_step
+from rafting_tpu.testkit.parity import (
+    assert_info_equal, assert_messages_equal, assert_state_equal)
+from rafting_tpu.transport import InboxAccumulator, messages_template
+from rafting_tpu.transport.codec import KIND_FIELDS
+from rafting_tpu.transport.inbox import fill_columns, scatter_dense
+from rafting_tpu.utils.profiling import StageSpans
+
+K = 3                       # columns a peer row: four led lanes overflow
+COUNTERS = ("steps_columns_in", "column_overflows_in",
+            "steps_columns_out", "column_overflows_out")
+
+
+@pytest.fixture
+def small_columns(monkeypatch):
+    """Layouts built while this holds close a buffer every 2 KB and take
+    K columns a row.  The layout caches are emptied on both sides so that
+    no other test sees them."""
+    step_layouts.cache_clear()
+    column_layouts.cache_clear()
+    monkeypatch.setattr(packing, "CHUNK_BYTES", 2048)
+    monkeypatch.setattr(packing, "COLUMNS", K)
+    yield
+    step_layouts.cache_clear()
+    column_layouts.cache_clear()
+
+
+@pytest.fixture
+def noted(monkeypatch):
+    """Every ``st.note`` of the upload and the fetch, caught where it is
+    written: node -> phase -> [statistics]."""
+    seen = {}
+    real = StageSpans.note
+
+    def spy(self, **stats):
+        if "dense" in stats:
+            seen.setdefault(self._node, {}).setdefault(
+                self._name, []).append(stats)
+        return real(self, **stats)
+
+    monkeypatch.setattr(StageSpans, "note", spy)
+    return seen
+
+
+def assert_counters_match_spans(node, notes):
+    """The four counters are the spans' ``dense`` counted, and a dense
+    step carries no column count."""
+    m = node.metrics
+    up, down = notes["dispatch_upload"], notes["scan_fetch"]
+    assert m["steps_columns_in"] == sum(not s["dense"] for s in up)
+    assert m["column_overflows_in"] == sum(s["dense"] for s in up)
+    assert m["steps_columns_out"] == sum(not s["dense"] for s in down)
+    assert m["column_overflows_out"] == sum(s["dense"] for s in down)
+    assert all(s["columns"] == 0 for s in up + down if s["dense"])
+    assert all(m[name] > 0 for name in COUNTERS), \
+        {name: m[name] for name in COUNTERS}
+    assert sum(s["columns"] for s in up) > 0
+    assert sum(s["columns"] for s in down) > 0
+    lay = column_layouts(node.cfg, node.pipeline
+                         or node._acked_tail is not None)
+    # A column step moves the [G] planes and one pair each way; a dense
+    # one the whole layouts (and, down, the pair that said so).
+    small = {s["bytes"] for s in up if not s["dense"]}
+    assert small == {sum(b.nbytes for b in lay.host.alloc())
+                     + lay.columns.nbytes}
+    assert {s["transfers"] for s in up if s["dense"]} \
+        == {len(lay.inputs.buffers)}
+    assert {s["transfers"] for s in down if not s["dense"]} \
+        == {len(lay.back.buffers) + 2}
+    assert {s["transfers"] for s in down if s["dense"]} \
+        == {len(lay.back.buffers) + 2 + len(lay.outbox.buffers)}
+
+
+# ------------------------------------------- (a) lock step, oracle-checked ----
+
+
+@pytest.fixture
+def oracle_checked_columns(monkeypatch):
+    """Cross-check every runtime node_step_columns call against the scalar
+    oracle, whichever way the messages went in: the oracle steps what the
+    upload buffers stand for, and state, dense outbox and StepInfo must be
+    the oracle's (oracle FIRST: the step donates its state).  Serial mode
+    only: the oracle has no durable_tail lane."""
+    real = node_mod.node_step_columns
+    calls = {True: 0, False: 0}
+
+    def checked(cfg, lay, columns_in, state, buffers):
+        bufs = jax.device_get(buffers)
+        if columns_in:
+            n_host = len(lay.host.buffers)
+            host = lay.host.unpack(bufs[:n_host])
+            inbox = lay.columns.expand(bufs[n_host:])
+        else:
+            host, inbox = lay.inputs.unpack(bufs)
+        host, inbox = jax.tree.map(jnp.asarray, (host, inbox))
+        o_state, o_out, o_info = oracle_step(cfg, state, inbox, host)
+        k_state, back, pair, dense = real(cfg, lay, columns_in, state, buffers)
+        tag = f"oracle-checked column step #{sum(calls.values())}"
+        assert_state_equal(k_state, o_state, tag)
+        assert_messages_equal(lay.columns.unstack(dense), o_out, tag)
+        assert_info_equal(lay.back.unpack(jax.device_get(back)).info,
+                          o_info, tag)
+        calls[bool(columns_in)] += 1
+        return k_state, back, pair, dense
+
+    monkeypatch.setattr(node_mod, "node_step_columns", checked)
+    return calls
+
+
+def test_column_steps_match_the_oracle_through_election_rounds_and_traffic(
+        tmp_path, small_columns, oracle_checked_columns, noted):
+    cfg = EngineConfig(n_groups=16, n_peers=3, log_slots=16, batch=4,
+                       max_submit=4, election_ticks=8, heartbeat_ticks=4,
+                       rpc_timeout_ticks=6, pre_vote=True)
+    assert column_layouts(cfg, False) is not None
+    c = LocalCluster(cfg, str(tmp_path), provider_factory=NullProvider,
+                     seed=5, pipeline=False)
+    try:
+        for g in range(cfg.n_groups):       # the election: 16 lanes at once
+            assert c.wait_leader(g, max_rounds=200) is not None
+        for t in range(60):                 # rounds every fourth tick, and
+            n = c.nodes[t % 3]              # single operations between them
+            led = np.nonzero((n.h_role == LEADER) & n.h_ready)[0]
+            if len(led):
+                g = int(led[t % len(led)])
+                n.submit_batch(g, [b"w%d" % t])
+                n.read(g, b"r%d" % t)
+            c.tick()
+        assert min(oracle_checked_columns.values()) > 20, \
+            oracle_checked_columns
+        assert sum(int(n.h_commit.astype(np.int64).sum())
+                   for n in c.nodes.values()) > 0
+        for i, n in c.nodes.items():
+            assert_counters_match_spans(n, noted[i])
+    finally:
+        c.close()
+
+
+# ------------------------------------------------ (b) served, linearizable ----
+
+
+@pytest.fixture
+def served(tmp_path, small_columns):
+    ports = free_ports(3)
+    uris = [f"raft://127.0.0.1:{p}" for p in ports]
+    cs = [RaftContainer(RaftConfig(
+        local=u, peers=tuple(p for p in uris if p != u), n_groups=16,
+        log_slots=32, batch=4, max_submit=4, tick_ms=20, seed=3,
+        data_dir=str(tmp_path / f"node{i}"),
+        election_mul=scaled_election_mul(10)), kv_factory()).create()
+        for i, u in enumerate(uris)]
+    yield cs
+    for c in cs:
+        c.destroy()
+
+
+def _cmd(op, k, v=None):
+    d = {"op": op, "k": k}
+    if v is not None:
+        d["v"] = v
+    return json.dumps(d)
+
+
+def test_served_cluster_over_columns_is_linearizable(noted, served):
+    cs = served
+    names = [f"kv{i}" for i in range(12)]   # twelve groups: their election
+    for c in cs:                            # and their rounds overflow K
+        for i, name in enumerate(names):
+            assert c.open_context(name) == i + 1
+    assert column_layouts(cs[0].node.cfg, True) is not None
+    stubs = [[c.get_stub(name) for name in names] for c in cs]
+    model = {}
+    for i in range(12):
+        k, v = f"s{i % 4}", f"seq-{i}"
+        assert stubs[i % 3][i].execute(_cmd("set", k, v), timeout=30) == v
+        model[(i, k)] = v
+        got = stubs[(i + 1) % 3][i].execute_read(_cmd("get", k), timeout=30)
+        assert got == v, (i, got)
+    # Concurrent phase on one group: three recording clients, one a member.
+    history = History()
+    rec = [c.get_stub(names[0]).attach_history(history, f"c{i}")
+           for i, c in enumerate(cs)]
+
+    def client(i):
+        rng = np.random.default_rng(200 + i)
+        for seq in range(25):
+            k = f"r{rng.integers(3)}"
+            try:
+                if rng.random() < 0.5:
+                    rec[i].execute_read(_cmd("get", k), timeout=10)
+                else:
+                    rec[i].execute(_cmd("set", k, f"c{i}-{seq}"), timeout=10)
+            except Exception:
+                pass        # recorded as fail or info by the stub
+
+    threads = [threading.Thread(target=client, args=(i,)) for i in range(3)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    counts = history.counts()
+    assert counts["ok"] >= 40, counts
+    res = linz.check(history)
+    assert res.ok, res.render()
+    # The three replicas end identical, group by group.
+    deadline = time.monotonic() + 20
+    for g in range(1, 13):
+        machines = [c.node.dispatcher.machine(g) for c in cs]
+        while time.monotonic() < deadline and len(
+                {m.last_applied() for m in machines}) != 1:
+            time.sleep(0.05)
+        assert machines[0].data == machines[1].data == machines[2].data
+    for (i, k), v in model.items():
+        assert cs[0].node.dispatcher.machine(i + 1).data[k] == v
+    for row in stubs:
+        for s in row:
+            s.close()
+    for s in rec:
+        s.close()
+    # Stop the loops, then read what they counted against what they noted.
+    for c in cs:
+        c.node._stop.set()
+        c.node._wake.set()
+    for c in cs:
+        if c.node._thread is not None:
+            c.node._thread.join(timeout=30)
+    for i, c in enumerate(cs):
+        assert_counters_match_spans(c.node, noted[c.node.node_id])
+
+
+# --------------------------------------- (c) the drain's two destinations ----
+
+
+def _random_slice(rng, template, G, n_max):
+    """One unpacked slice as codec.unpack_slice hands it over: per kind
+    one lanes array shared by its fields; now and then a kind twice (the
+    eager / deferred split), its lanes concatenated and so not shared."""
+    fields = {}
+    for kind, (vfield, dfields) in KIND_FIELDS.items():
+        if rng.random() < 0.5:
+            continue
+        parts = []
+        for _ in range(1 + (rng.random() < 0.3)):
+            cols = np.sort(rng.choice(G, rng.integers(1, n_max + 1),
+                                      replace=False)).astype(np.int64)
+            parts.append((cols, {f: rng.integers(
+                1, 50, (len(cols),) + template[f][1]).astype(template[f][0])
+                for f in dfields}))
+        if len(parts) == 1:
+            cols, vals = parts[0]
+            fields[vfield] = (cols, np.ones(len(cols), bool))
+            fields.update({f: (cols, v) for f, v in vals.items()})
+        else:
+            cat = lambda xs: np.concatenate(xs)
+            fields[vfield] = (cat([c for c, _ in parts]),
+                              np.ones(sum(len(c) for c, _ in parts), bool))
+            for f in dfields:
+                fields[f] = (cat([c for c, _ in parts]),
+                             cat([v[f] for _, v in parts]))
+    return fields
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_columns_are_filled_as_dense_planes_are(seed, small_columns,
+                                                monkeypatch):
+    """What a drain pops lands in the column buffers exactly as it lands
+    in dense planes: one slice a source, a collapsed backlog of several
+    (the newest wins a lane, kind by kind), a kind in two sections; and a
+    source with more columns than the buffers take fills nothing."""
+    cfg = EngineConfig(n_groups=128, n_peers=3)
+    monkeypatch.setattr(packing, "COLUMNS", cfg.n_groups)
+    column_layouts.cache_clear()
+    lay = column_layouts(cfg, True).columns
+    template = messages_template(cfg)
+    rng = np.random.default_rng(seed)
+    acc = InboxAccumulator(cfg, template)
+    for src in (1, 2):
+        for _ in range(1 if (seed + src) % 2 else 5):     # 5: a collapse
+            acc.merge(src, _random_slice(rng, template, cfg.n_groups, 3), {})
+    batches, _ = acc.pop()
+    assert {len(b) for b in batches.values()} == {1, 5}
+    dense = {name: np.zeros((3, cfg.n_groups) + trail, dt)
+             for name, (dt, trail) in template.items()}
+    scatter_dense(batches, dense)
+    pair = lay.alloc()
+    view = lay.view(pair)
+    assert fill_columns(batches, view)
+    back = lay.expand(pair)
+    for name, plane in dense.items():
+        np.testing.assert_array_equal(getattr(back, name), plane, name)
+        np.testing.assert_array_equal(view.dense(name), plane, name)
+    for p in range(3):
+        lanes = view.cols[p, :view.n[p]]
+        assert (np.diff(lanes) > 0).all() and (view.cols[p, view.n[p]:]
+                                               == cfg.n_groups).all()
+    # One source past K: nothing is written, the step goes dense.
+    monkeypatch.setattr(packing, "COLUMNS", max(view.n) - 1)
+    column_layouts.cache_clear()
+    lay = column_layouts(cfg, True).columns
+    empty = lay.alloc()
+    assert not fill_columns(batches, lay.view(empty))
+    for a, b in zip(empty, lay.alloc()):
+        np.testing.assert_array_equal(a, b)
+    # ... also when no single kind of it is: the union counts.
+    monkeypatch.setattr(packing, "COLUMNS", 4)
+    column_layouts.cache_clear()
+    lay = column_layouts(cfg, True).columns
+    wide = np.arange(lay.K + 1, dtype=np.int64)
+    batches[1].append({"tn_valid": (wide, np.ones(len(wide), bool)),
+                       "tn_term": (wide, np.ones(len(wide), np.int32))})
+    empty = lay.alloc()
+    assert not fill_columns(batches, lay.view(empty))
+    for a, b in zip(empty, lay.alloc()):
+        np.testing.assert_array_equal(a, b)

@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from rafting_tpu.api import RaftConfig, RaftContainer
+from rafting_tpu.core.packing import DenseView
 from rafting_tpu.core.types import EngineConfig, StepInfo, LEADER, NIL
 from rafting_tpu.log.store import LogStore
 from rafting_tpu.transport.codec import PayloadRun
@@ -124,7 +125,7 @@ class _Ctx:
         self.submit_n = np.zeros(G, np.int32)
         self.base = np.zeros(G, np.int32)
         self.base_term = np.zeros(G, np.int32)
-        self.arrays = arrays
+        self.arrays = None if arrays is None else DenseView(arrays)
         self.staged_payloads = staged_payloads or {}
 
 

@@ -438,8 +438,7 @@ def _count_steps(node):
             return True
         if prep.fr_cents is None or not len(prep.wrote):
             return False
-        src = np.maximum(np.asarray(prep.src_l), 0)
-        return bool(prep.fr_cents[src, prep.wrote].any())
+        return bool(prep.fr_cents.any())      # rows of the wrote set
 
     def wrap(name, fn):
         def counted(prep):

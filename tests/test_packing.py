@@ -2,7 +2,9 @@
 runs (core/step.py node_step_packed): the layout follows the dataclasses
 through every optional subtree, the host side is views and not copies, the
 packed step is node_step bit for bit, and a tick's upload buffers are its
-own until its host phase is done with them."""
+own until its host phase is done with them.  And the column form of the
+message planes (ColumnLayout, node_step_columns): the same step, bit for
+bit, whichever way its messages cross."""
 
 import jax
 import jax.numpy as jnp
@@ -11,9 +13,10 @@ import pytest
 
 from rafting_tpu.core.cluster import route
 from rafting_tpu.core import packing
-from rafting_tpu.core.packing import Layout
+from rafting_tpu.core.packing import ColumnLayout, Layout
 from rafting_tpu.core.step import (
-    Readback, node_step, node_step_packed, step_layouts,
+    COLUMN_BUFFERS, Readback, column_layouts, node_step, node_step_columns,
+    node_step_packed, pack_outbox, step_layouts,
 )
 from rafting_tpu.core.types import (
     EngineConfig, HostInbox, Messages, init_state,
@@ -188,7 +191,7 @@ def test_next_dispatch_leaves_a_pending_ticks_arrays_alone(tmp_path):
         held = []       # (the pending tick's planes, their copies)
         for _ in range(6):
             c.tick()
-            arrays = node._pending.arrays
+            arrays = node._pending.arrays.planes     # a DenseView's
             if held:
                 before, copies = held[-1]
                 for name, plane in before.items():
@@ -199,3 +202,231 @@ def test_next_dispatch_leaves_a_pending_ticks_arrays_alone(tmp_path):
                    for a, _ in held) >= 4, "no traffic reached the node"
     finally:
         c.close()
+
+
+# ------------------------------- (d) the column step == node_step ----
+
+
+@pytest.fixture
+def small_columns(monkeypatch):
+    """``set(K)``: column buffers of K columns a row, and buffers so small
+    that the 8-lane shape's planes do not fit one (the shape rule then
+    engages columns)."""
+    def set_k(k):
+        monkeypatch.setattr(packing, "COLUMNS", k)
+        monkeypatch.setattr(packing, "CHUNK_BYTES", 256)
+        step_layouts.cache_clear()
+        column_layouts.cache_clear()
+    yield set_k
+    monkeypatch.undo()
+    step_layouts.cache_clear()
+    column_layouts.cache_clear()
+
+
+def _occupied(msgs):
+    """[P, G]: the columns that hold a valid message of any kind."""
+    return np.any([np.asarray(getattr(msgs, f))
+                   for f in msgs.__dataclass_fields__
+                   if f.endswith("_valid")], axis=0)
+
+
+def _on_columns(msgs, occ):
+    """``msgs`` with everything outside its occupied columns zeroed: what
+    the column form carries of a dense tree."""
+    return jax.tree.map(
+        lambda a: np.where(occ.reshape(occ.shape + (1,) * (a.ndim - 2)),
+                           a, np.zeros((), a.dtype)), msgs)
+
+
+@pytest.mark.parametrize("durable", [True, False], ids=["durable", "serial"])
+@pytest.mark.parametrize("k_out", [8, 2], ids=["columns-out", "overflow-out"])
+@pytest.mark.parametrize("columns_in", [True, False],
+                         ids=["columns-in", "dense-in"])
+def test_column_step_is_node_step_bit_for_bit(small_columns, columns_in,
+                                              k_out, durable):
+    """The cluster of the packed test stepped through node_step and
+    through node_step_columns: the messages go in as columns whenever
+    every source's fit the K of the case (else densely, as the runtime
+    decides), come out as columns or, where a row overflows, through
+    pack_outbox; state, StepInfo, mirrors and the dense outbox agree leaf
+    for leaf, the columns are the outbox on its occupied columns, and the
+    counts are the true ones."""
+    small_columns(k_out)
+    cfg = EngineConfig(n_peers=3, **BASE)
+    N, G = cfg.n_peers, cfg.n_groups
+    lay = column_layouts(cfg, durable)
+    assert lay is not None and lay.columns.K == k_out
+    assert lay.inputs == step_layouts(cfg, durable)[0]
+    rng = np.random.default_rng(11)
+    plain = [init_state(cfg, n, seed=3) for n in range(N)]
+    cols = [init_state(cfg, n, seed=3) for n in range(N)]
+    outboxes = [jax.device_get(Messages.empty(cfg))] * N
+    tails = [np.zeros(G, np.int32)] * N
+    seen = dict(columns_in=0, dense_in=0, columns_out=0, overflow_out=0)
+    for t in range(40):
+        inflight = jax.tree.map(lambda *a: np.stack(a), *outboxes)
+        inboxes = jax.device_get(
+            route(inflight, jnp.asarray(rng.random((N, N)) > 0.4)))
+        outboxes = []
+        for n in range(N):
+            # What a drain delivers: only valid columns hold anything.
+            inbox = jax.tree.map(lambda a: a[n], inboxes)
+            inbox = _on_columns(inbox, _occupied(inbox))
+            host = jax.device_get(HostInbox.empty(cfg)).replace(
+                submit_n=rng.integers(0, cfg.max_submit + 1, G,
+                                      dtype=np.int32),
+                read_n=rng.integers(0, 3, G, dtype=np.int32),
+                durable_tail=tails[n] if durable else None)
+            plain[n], p_out, p_info = node_step(
+                cfg, plain[n], *jax.tree.map(jnp.asarray, (inbox, host)))
+            pair = lay.columns.compact(inbox)
+            fits = bool((pair[0][:, 0] <= lay.columns.K).all())
+            if columns_in and fits:
+                bufs = lay.host.pack(host) + pair
+                seen["columns_in"] += 1
+            else:
+                bufs = lay.inputs.pack((host, inbox))
+                seen["dense_in"] += 1
+            cols[n], back, pair, dense = node_step_columns(
+                cfg, lay, columns_in and fits, cols[n], bufs)
+            tag = f"tick {t} node {n}"
+            assert_trees_equal(cols[n], plain[n], tag)
+            s = plain[n]
+            assert_trees_equal(
+                lay.back.unpack(jax.device_get(back)), Readback(
+                    info=p_info, outbox=None, term=s.term,
+                    voted_for=s.voted_for, role=s.role,
+                    leader_id=s.leader_id, commit=s.commit, base=s.log.base,
+                    base_term=s.log.base_term, heat=s.heat), tag)
+            assert_trees_equal(lay.columns.unstack(dense), p_out, tag)
+            p_out = jax.device_get(p_out)
+            occ = _occupied(p_out)
+            pair = jax.device_get(pair)
+            np.testing.assert_array_equal(pair[0][:, 0], occ.sum(axis=1), tag)
+            if (pair[0][:, 0] <= lay.columns.K).all():
+                seen["columns_out"] += 1
+                assert_trees_equal(lay.columns.expand(pair),
+                                   _on_columns(p_out, occ), tag)
+                view = lay.columns.view(pair)
+                for p in range(N):
+                    np.testing.assert_array_equal(
+                        view.lanes(p, view.row("ae_valid", p)),
+                        np.nonzero(p_out.ae_valid[p])[0], tag)
+            else:
+                seen["overflow_out"] += 1
+                assert_trees_equal(lay.outbox.unpack(jax.device_get(
+                    pack_outbox(lay, dense))), p_out, tag)
+            outboxes.append(p_out)
+            tails[n] = np.asarray(p_info.log_tail)
+    assert seen["columns_in"] > 20 if columns_in else not seen["columns_in"]
+    assert seen["columns_out"] > 20
+    assert seen["overflow_out"] > 20 if k_out < G else not seen["overflow_out"]
+    assert not columns_in or k_out == G or seen["dense_in"] > 5
+
+
+@pytest.mark.parametrize("counts", [(0, 1, 4), (4, 4, 4), (5, 0, 1),
+                                    (3, 5, 4), (0, 0, 0)],
+                         ids=lambda c: "-".join(map(str, c)))
+def test_column_counts_per_row(small_columns, counts):
+    """Rows of 0, 1, K and K + 1 columns, different in every row, on
+    planes made by hand with junk outside the occupied columns: the device
+    and the host compact alike, the count is the true one also beyond K,
+    the first K columns are kept in lane order, and expanding what fits
+    gives the planes back on their occupied columns."""
+    small_columns(4)
+    cfg = EngineConfig(n_peers=3, **{**BASE, "n_groups": 300})
+    lay = column_layouts(cfg, True).columns
+    assert (lay.P, lay.G, lay.K) == (3, 300, 4)
+    rng = np.random.default_rng(sum(counts))
+    tree = jax.tree.map(
+        lambda a: (rng.integers(1, 99, a.shape, dtype=np.int32)
+                   if a.dtype == np.int32 else np.zeros(a.shape, bool)),
+        jax.device_get(Messages.empty(cfg)))
+    valid = [f for f in tree.__dataclass_fields__ if f.endswith("_valid")]
+    lanes = []
+    for p, c in enumerate(counts):
+        at = np.sort(rng.choice(cfg.n_groups, c, replace=False))
+        lanes.append(at)
+        for g in at:
+            getattr(tree, valid[rng.integers(len(valid))])[p, g] = True
+            tree.aer_success[p, g] = rng.random() < 0.5     # a plain flag
+    pair = lay.compact(tree)
+    for a, b in zip(pair, jax.jit(lay.compact)(
+            jax.tree.map(jnp.asarray, tree))):
+        np.testing.assert_array_equal(a, b)
+    n, held, _, _ = lay._parts(pair)
+    np.testing.assert_array_equal(n, counts)
+    for p, at in enumerate(lanes):
+        np.testing.assert_array_equal(held[p, :min(len(at), lay.K)],
+                                      at[:lay.K])
+        assert (held[p, len(at):] == lay.G).all()
+    if max(counts) <= lay.K:
+        want = _on_columns(tree, _occupied(tree))
+        assert_trees_equal(lay.expand(pair), want)
+        assert_trees_equal(jax.jit(lay.expand)(
+            tuple(map(jnp.asarray, pair))), want)
+        # host round trip: the identity, in both orders
+        again = lay.compact(lay.expand(pair))
+        for a, b in zip(pair, again):
+            np.testing.assert_array_equal(a, b)
+
+
+def test_a_new_field_finds_its_place_in_the_columns(small_columns):
+    """The column layout follows the tree: a kind nobody listed anywhere
+    takes its words and flags and, being named ``*_valid``, counts as
+    occupancy."""
+    small_columns(3)
+    P, G = 2, 10
+    tree = {"a_valid": np.zeros((P, G), bool),
+            "a_x": np.zeros((P, G), np.int32),
+            "a_wide": np.zeros((P, G, 5), np.int32),
+            "a_flag": np.zeros((P, G), bool)}
+    before = ColumnLayout(tree)
+    assert (before.W, before.F, len(before.occupancy)) == (6, 2, 1)
+    tree["zz_valid"] = np.zeros((P, G), bool)
+    tree["zz_word"] = np.zeros((P, G, 2), np.int32)
+    lay = ColumnLayout(tree)
+    assert (lay.W, lay.F, len(lay.occupancy)) == (8, 3, 2) and lay != before
+    assert lay.buffers == ((np.dtype(np.int32), (P, 1 + 3 + 3 * 8)),
+                           (np.dtype(np.uint8), (P, 3 * 3)))
+    tree["zz_valid"][1, 7] = tree["a_valid"][1, 2] = True
+    tree["zz_word"][1, 7] = (5, 6)
+    tree["a_wide"][1, 2] = np.arange(5)
+    tree["a_x"][0, 3] = 9           # not in an occupied column: stays behind
+    pair = lay.compact(tree)
+    view = lay.view(pair)
+    assert view.n.tolist() == [0, 2] and view.columns == 2
+    assert view.lanes(1, view.row("zz_valid", 1)).tolist() == [7]
+    assert view.fields(1)["zz_word"][np.array([7])].tolist() == [[5, 6]]
+    back = lay.expand(pair)
+    assert back["a_x"].sum() == 0 and back["zz_word"][1, 7].tolist() == [5, 6]
+    np.testing.assert_array_equal(back["a_wide"], tree["a_wide"])
+    with pytest.raises(TypeError, match="int32 or bool"):
+        ColumnLayout({"x": np.zeros((P, G), np.float32)})
+
+
+@pytest.mark.parametrize("config, columns", [
+    ("coord-1g-3v", False), ("multiraft-1k-3v", False),
+    ("multiraft-10k-3v", False), ("multiraft-100k-3v", True),
+])
+def test_shape_rule_on_the_benchmarks_configurations(config, columns):
+    """Which program a node runs follows from its shape alone: the cells
+    whose dense planes take fewer than COLUMN_BUFFERS word buffers keep
+    node_step_packed (at 10,000 lanes, two buffers, columns cost the chip
+    more than they saved: PERF.md, PR 35), the 100,000-Region cell takes
+    columns."""
+    import json
+    import os
+    from rafting_tpu.api import RaftConfig
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           config + ".json")) as f:
+        raft = json.load(f)["raft_config"]
+    uris = [f"raft://127.0.0.1:{7001 + i}" for i in range(3)]
+    cfg = RaftConfig(local=uris[0], peers=tuple(uris[1:]),
+                     data_dir="unused", **raft).engine_config()
+    lay = column_layouts(cfg, True)
+    assert (lay is not None) == columns
+    words = sum(dt == packing.WORD
+                for dt, _ in step_layouts(cfg, True)[0].buffers)
+    assert (words >= COLUMN_BUFFERS) == columns

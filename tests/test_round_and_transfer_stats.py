@@ -15,7 +15,8 @@ import numpy as np
 import pytest
 
 from rafting_tpu.core import packing
-from rafting_tpu.core.step import step_layouts
+from rafting_tpu.core.packing import DenseView
+from rafting_tpu.core.step import column_layouts, step_layouts
 from rafting_tpu.core.types import EngineConfig, LEADER
 from rafting_tpu.testkit.harness import LocalCluster
 from rafting_tpu.transport import InboxAccumulator, messages_template
@@ -121,12 +122,11 @@ def test_hb_acknowledged_matches_the_period_and_the_peer(tmp_path):
         node = lc.nodes[0]
         P, G = node.cfg.n_peers, node.cfg.n_groups
 
-        class Out:
-            ae_valid = np.zeros((P, G), bool)
-            ae_tick = np.full((P, G), 41, np.int32)
-        Out.ae_valid[1, 2] = Out.ae_valid[2, 5] = True
+        out = DenseView({"ae_valid": np.zeros((P, G), bool),
+                         "ae_tick": np.full((P, G), 41, np.int32)})
+        out.planes["ae_valid"][1, 2] = out.planes["ae_valid"][2, 5] = True
         node._hb_rounds.clear()
-        node._hb_open(Out, started=1.5)
+        node._hb_open(out, started=1.5)
         assert [r[:2] + [sorted(r[2])] for r in node._hb_rounds] == \
             [[41, 1.5, [1, 2]]]
 
@@ -136,7 +136,7 @@ def test_hb_acknowledged_matches_the_period_and_the_peer(tmp_path):
             for p, g, tick in rows:
                 arrays["aer_valid"][p, g] = True
                 arrays["aer_tick"][p, g] = tick
-            return arrays
+            return DenseView(arrays)
 
         node._hb_acknowledged(acks([(1, 2, 40), (2, 5, 40)]))   # last period
         assert node._hb_closed is None and len(node._hb_rounds) == 1
@@ -149,17 +149,27 @@ def test_hb_acknowledged_matches_the_period_and_the_peer(tmp_path):
         assert node._hb_closed == 1.5 and not node._hb_rounds
         # A node that addresses no AppendEntries opens nothing.
         node._hb_closed = None
-        Out.ae_valid[:] = False
-        node._hb_open(Out, started=2.5)
+        out.planes["ae_valid"][:] = False
+        node._hb_open(out, started=2.5)
         assert not node._hb_rounds
     finally:
         lc.close()
 
 
-def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch):
+@pytest.mark.parametrize("columns", [False, True], ids=["packed", "columns"])
+def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch,
+                                                       columns):
     """Every ``st.note`` of a step, caught where it is written: the upload
     and the fetch say how many buffers crossed and how many bytes, and
-    those are the layouts' own sizes."""
+    those are the layouts' own sizes; on a shape whose messages cross as
+    columns (forced here by a small ``CHUNK_BYTES``; ``COLUMNS`` = G, so
+    nothing overflows) they are the [G] planes' buffers and one column
+    pair each way, and ``columns`` is the count that crossed."""
+    if columns:
+        monkeypatch.setattr(packing, "CHUNK_BYTES", 512)
+        monkeypatch.setattr(packing, "COLUMNS", _cfg().n_groups)
+    step_layouts.cache_clear()
+    column_layouts.cache_clear()
     lc = LocalCluster(_cfg(), str(tmp_path), seed=5)
     try:
         lc.tick(3)
@@ -174,25 +184,41 @@ def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch):
 
         monkeypatch.setattr(StageSpans, "note", spy)
         node.tick()
+        durable = node.pipeline or node._acked_tail is not None
+        inputs, readback = step_layouts(node.cfg, durable)
+        lay = column_layouts(node.cfg, durable)
+        fetched = node._pending.outbox if node._pending else None
     finally:
         monkeypatch.undo()
+        step_layouts.cache_clear()
+        column_layouts.cache_clear()
         lc.close()
-    inputs, readback = step_layouts(node.cfg, node.pipeline
-                                    or node._acked_tail is not None)
 
-    def size(layout):
+    def size(*layouts):
         return sum(int(np.prod(shape)) * np.dtype(dt).itemsize
-                   for dt, shape in layout.buffers)
+                   for layout in layouts for dt, shape in layout.buffers)
 
     by_phase = {name: kw for name, kw in notes if "bytes" in kw}
     assert set(by_phase) == {"dispatch_upload", "scan_fetch"}
     up, down = by_phase["dispatch_upload"], by_phase["scan_fetch"]
-    assert up["transfers"] == len(inputs.buffers)
-    assert down["transfers"] == len(readback.buffers)
-    assert up["bytes"] == size(inputs) > 0
-    assert down["bytes"] == size(readback) > 0
-    assert packing.CHUNK_BYTES >= max(up["bytes"], down["bytes"]) // max(
-        up["transfers"], down["transfers"]) > 0
+    assert (lay is not None) == columns
+    if columns:
+        assert up["transfers"] == len(lay.host.buffers) + 2
+        assert down["transfers"] == len(lay.back.buffers) + 2
+        assert up["bytes"] == size(lay.host, lay.columns) > 0
+        assert down["bytes"] == size(lay.back, lay.columns) > 0
+        assert up["dense"] == down["dense"] == 0
+        if fetched is not None:
+            assert down["columns"] == fetched.columns
+    else:
+        assert up["transfers"] == len(inputs.buffers)
+        assert down["transfers"] == len(readback.buffers)
+        assert up["bytes"] == size(inputs) > 0
+        assert down["bytes"] == size(readback) > 0
+        assert (up["dense"], up["columns"]) == (1, 0)
+        assert (down["dense"], down["columns"]) == (1, 0)
+        assert packing.CHUNK_BYTES >= max(up["bytes"], down["bytes"]) // max(
+            up["transfers"], down["transfers"]) > 0
 
 
 # ------------------------------------------------------------------ inbox

@@ -23,7 +23,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from rafting_tpu.core.packing import Layout
-from rafting_tpu.core.step import node_step, node_step_packed, step_layouts
+from rafting_tpu.core.step import (
+    column_layouts, node_step, node_step_columns, node_step_packed,
+    step_layouts)
 from rafting_tpu.core.types import HostInbox, Messages, init_state
 from rafting_tpu.ops.quorum import quorum_commit_pallas
 
@@ -145,3 +147,68 @@ def test_node_step_holds_no_gather_and_no_scatter(one_chip, packed):
     print(f"{found} temp_size_in_bytes={temp}")
     assert found == {"gather": 0, "scatter": 0}
     assert temp < 32 * 1024 ** 2, temp
+
+
+def _index_rows(hlo):
+    """(op, index rows) of every gather and scatter of a compiled module's
+    text: how many index tuples its indices operand holds."""
+    shapes = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]", hlo))
+    out = []
+    for op, args, rest in re.findall(
+            r"\b(gather|scatter)\(([^)]*)\)([^\n]*)", hlo):
+        indices = re.findall(r"%([\w.-]+)", args)[1]
+        dims = [int(d) for d in shapes[indices].split(",") if d]
+        vector = int(re.search(r"index_vector_dim=(\d+)", rest).group(1))
+        if vector < len(dims):      # else: scalar indices, no such axis
+            dims.pop(vector)
+        out.append((op, int(np.prod(dims, dtype=np.int64))))
+    return out
+
+
+@pytest.mark.parametrize("columns_in", [True, False],
+                         ids=["columns-in", "dense-in"])
+def test_column_step_compiles_at_100k_lanes_and_addresses_k_rows(
+        one_chip, columns_in):
+    """node_step_columns at the 100,000-Region cell's shape, as its
+    configuration file builds the engine, in both forms of its operand:
+    it fits the chip three nodes at a time, and whatever gathers and
+    scatters the chip's compiler is left with (the expansion of the
+    inbox's columns, the compaction of the outbox) address at most P x K
+    index rows a leaf (the [P, G] leaves of a kind share one scatter and
+    one gather, an index row a leaf, peer and column): none walks the G
+    lanes."""
+    from rafting_tpu.api import RaftConfig
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "multiraft-100k-3v.json")) as f:
+        raft = json.load(f)["raft_config"]
+    uris = [f"raft://127.0.0.1:{7001 + i}" for i in range(3)]
+    cfg = RaftConfig(local=uris[0], peers=tuple(uris[1:]),
+                     data_dir="unused", **raft).engine_config()
+    assert (cfg.n_groups, cfg.n_peers) == (100_000, 3)
+    lay = column_layouts(cfg, True)
+    assert lay is not None
+    P, K = cfg.n_peers, lay.columns.K
+    assert lay.columns.nbytes <= 512 * 1024     # one transfer's fixed cost
+
+    def bufs(layout):
+        return tuple(jax.ShapeDtypeStruct(
+            (n,) if isinstance(n, int) else n, dt, sharding=one_chip)
+            for dt, n in layout.buffers)
+
+    state = _on(one_chip, jax.eval_shape(lambda: init_state(cfg, 0, seed=0)))
+    operand = bufs(lay.host) + bufs(lay.columns) if columns_in \
+        else bufs(lay.inputs)
+    compiled = node_step_columns.lower(
+        cfg, lay, columns_in, state, operand).compile()
+    mem = compiled.memory_analysis()
+    per_node = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert 3 * per_node < HBM_BYTES, mem
+    found = _index_rows(compiled.as_text())
+    print(f"K={K} {len(found)} gathers and scatters, "
+          f"most index rows {max(r for _, r in found)}")
+    most = max(lay.columns.Ws, lay.columns.Fs) * P * K
+    assert found and all(rows <= most < cfg.n_groups
+                         for _, rows in found), found
+    assert len(found) <= 12, found      # not one a leaf: some 50 us each
+    assert ("scatter" in {op for op, _ in found}) == columns_in
