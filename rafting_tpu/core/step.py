@@ -1372,16 +1372,53 @@ class Readback(NamedTuple):
     base: Array
     base_term: Array
     heat: Any                 # Optional[HeatState]: None when cfg.heat is off
+    windows: Array            # [len(WINDOW_SUMS)] int32: window_sums()
+
+
+# What Readback.windows holds, in order: the leader's replication windows
+# summed over the (lane, follower) pairs this node leads.
+WINDOW_SUMS = ("pairs", "occupied", "full", "cooling", "timed_out")
+
+
+def window_sums(cfg: EngineConfig, old: RaftState, new: RaftState) -> Array:
+    """The state of the leader's windows after a step, as five sums over
+    the ``[G, P]`` planes the step has just written (``WINDOW_SUMS``):
+    the (lane, follower) ``pairs`` this node leads (phase 9's
+    ``lead_peer``, on the step's final roles), the slots ``occupied``
+    over them (``inflight + hb_inflight``), the pairs whose window is
+    ``full`` (``cfg.inflight_limit``), the pairs ``cooling`` (inside
+    ``cfg.recovery_ticks`` of their last RPC timeout: the follower counts
+    as unhealthy) and the pairs that ``timed_out`` in THIS step: phase 9
+    is ``fail_at``'s only writer beside the election's reset to 0, so
+    they are where it differs from the old state's and is not 0 (not
+    ``fail_at == now``: every arrival step of the period would count the
+    pair again)."""
+    w = new.conf_word
+    member = mask_bits(
+        conf_voters_of(w) | conf_new_of(w) | conf_learners_of(w), cfg.n_peers)
+    self_hot = jnp.arange(cfg.n_peers, dtype=I32)[None, :] == new.node_id
+    pair = (new.active & (new.role == LEADER))[:, None] & ~self_hot & member
+    used = new.inflight + new.hb_inflight
+    cooling = (new.fail_at != 0) & (new.now - new.fail_at < cfg.recovery_ticks)
+    timed_out = (new.fail_at != old.fail_at) & (new.fail_at != 0)
+    return jnp.stack([
+        jnp.sum(pair, dtype=I32),
+        jnp.sum(jnp.where(pair, used, 0), dtype=I32),
+        jnp.sum(pair & (used >= cfg.inflight_limit), dtype=I32),
+        jnp.sum(pair & cooling, dtype=I32),
+        jnp.sum(pair & timed_out, dtype=I32)])
 
 
 def _step_readback(cfg: EngineConfig, state: RaftState, inbox: Messages,
                    host: HostInbox) -> Tuple[RaftState, Readback]:
-    state, outbox, info = node_step(cfg, state, inbox, host)
+    old = state
+    state, outbox, info = node_step(cfg, old, inbox, host)
     return state, Readback(
         info=info, outbox=outbox, term=state.term,
         voted_for=state.voted_for, role=state.role,
         leader_id=state.leader_id, commit=state.commit,
-        base=state.log.base, base_term=state.log.base_term, heat=state.heat)
+        base=state.log.base, base_term=state.log.base_term, heat=state.heat,
+        windows=window_sums(cfg, old, state))
 
 
 def _step_shapes(cfg: EngineConfig, durable: bool):

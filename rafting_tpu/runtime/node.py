@@ -51,8 +51,8 @@ import numpy as np
 
 from ..core.packing import DenseView
 from ..core.step import (
-    column_layouts, node_step_columns, node_step_packed, pack_outbox,
-    step_layouts)
+    WINDOW_SUMS, column_layouts, node_step_columns, node_step_packed,
+    pack_outbox, step_layouts)
 from ..core.types import (
     I32, I32_SAFE_MAX, LEADER, NIL, EngineConfig, HostInbox,
     StepInfo, boot_conf_word as _boot_conf_word, init_state,
@@ -506,6 +506,18 @@ class RaftNode:
         # Readiness gate (reference Leader.isReady, Leader.java:52-64): a
         # fresh leader reports not-ready until a majority of peers reply.
         self.h_ready = np.zeros(G, bool)
+        # Unready episodes: the timer tick at which a lane this node led
+        # READY stopped being ready (0: no episode open), and how many led
+        # lanes the last step found unready.  _fetch touches the array
+        # only in a step that has such a lane or follows one
+        # (_track_unready); the refusal gate reads it.
+        self._unready_since = np.zeros(G, np.int32)
+        self._unready_n = 0
+        # NotReady refusals by the gate: bumped on the caller's thread
+        # (plain adds: a count for an operator, not an account), folded
+        # into the registry by the tick thread, its one writer (tick()).
+        self._not_ready_refusals = 0
+        self._not_ready_folded = 0
 
         # Client submissions: group -> FIFO of _SubBatch arenas, bounded
         # (reference EventLoop queue capacity + busy threshold,
@@ -1220,8 +1232,7 @@ class RaftNode:
                         g, None if hint == NIL else hint)))
                     continue
                 if not ready[g]:
-                    sink._refuse(as_refusal(NotReadyError(
-                        f"group {g}: leader lacks a healthy majority")))
+                    sink._refuse(self._not_ready(g))
                     continue
                 ra = adm.admit(n)
                 if ra is not None:
@@ -1352,9 +1363,20 @@ class RaftNode:
             return as_refusal(
                 NotLeaderError(group, None if hint == NIL else hint))
         if not self.h_ready[group]:
-            return as_refusal(NotReadyError(
-                f"group {group}: leader lacks a healthy majority"))
+            return self._not_ready(group)
         return None
+
+    def _not_ready(self, group: int) -> Exception:
+        """The gate's refusal on a led lane that is not ready, with the
+        episode it falls in (``_unready_since``: none is open on a leader
+        that has not been ready yet since its election)."""
+        self._not_ready_refusals += 1
+        why = f"group {group}: leader lacks a healthy majority"
+        since = int(self._unready_since[group])
+        if since:
+            why += (f" (unready for {self.timer_ticks - since} periods, "
+                    f"since tick {since})")
+        return as_refusal(NotReadyError(why))
 
     def is_leader(self, group: int) -> bool:
         return bool(self.h_role[group] == LEADER)
@@ -1444,8 +1466,12 @@ class RaftNode:
             ended = time.perf_counter()
             self._step_costs.append(ended - t0)
             st.enter("wait")
+            # From the loop's second step on, a stage that outlasts the
+            # period is a stall (the first loads or compiles the program).
+            st.period = interval
             self._await_step(ended, ended - t0)
             st.leave()
+        st.period = None    # whoever steps the node next has no period
 
     def _await_step(self, ended: float, took: float) -> None:
         """Tick thread, between two steps: sleep until the timer's tick is
@@ -1670,6 +1696,10 @@ class RaftNode:
         # ticks.
         if not arrival:
             self._health_tick()
+        refused = self._not_ready_refusals
+        if refused != self._not_ready_folded:
+            m["refused_not_ready"] += refused - self._not_ready_folded
+            self._not_ready_folded = refused
         opened, self._hb_closed = self._hb_closed, None
         if opened is not None:
             # This step's drain held the last acknowledgement of a
@@ -2096,8 +2126,11 @@ class RaftNode:
         saw (transport/inbox.py InboxStats) into the registry — the wait
         of every slice popped, the deepest queue left behind (one sample
         a tick, so the histogram's mean over a window is a depth in
-        slices), the same per source as gauges, and the slices collapsed
-        or dropped.  Reader threads only ever touch the accumulator."""
+        slices), the same per source as gauges, the slices collapsed or
+        dropped and the replies a collapse overwrote (each of which the
+        leader's window waits for in vain: core/step.py window_sums);
+        the last two ride the step's raft.dispatch_intake span too.
+        Reader threads only ever touch the accumulator."""
         stats = self.acc.take_stats()
         m = self.metrics
         for w in stats.waits_s:
@@ -2113,8 +2146,10 @@ class RaftNode:
             m.gauge(f"inbox_backlog_src{src}", depth)
         if stats.collapsed:
             m["inbox_collapsed"] += stats.collapsed
+            m["inbox_replies_merged"] += stats.merged
         if stats.dropped:
             m["inbox_dropped"] += stats.dropped
+        self._stages.note(collapsed=stats.collapsed, merged=stats.merged)
 
     # --------------------------------------------------------- tick: fetch
 
@@ -2193,7 +2228,7 @@ class RaftNode:
                 "needs a snapshot + lane purge before its log index/term "
                 "wraps (see core/types.py)")
 
-        old_role = self.h_role
+        old_role, was_ready = self.h_role, self.h_ready
         self.h_role, self.h_leader = h_role, h_leader
         self.h_commit, self.h_base = h_commit, h_base
         self.h_term = h_term
@@ -2279,14 +2314,70 @@ class RaftNode:
         # leader: what tells a store that is electing from one that is
         # sick or overloaded.  Sampled every step, on /metrics and on the
         # step's raft.mirrors span.
-        n_open = int(self.h_active.sum())
-        leaderless = int((self.h_active & np.where(
-            h_role == LEADER, ~self.h_ready, h_leader == NIL)).sum())
-        self.metrics.gauge("groups_active", n_open)
-        self.metrics.gauge("groups_leaderless", leaderless)
-        st.note(leaderless=leaderless, open=n_open)
-        self.metrics.gauge(
-            "groups_led", int((h_role == LEADER).sum()))
+        # The two halves apart: open lanes this node leads and is not
+        # ready on (``unready``: a fresh leader still waiting for its
+        # first majority, or a leader whose followers' windows timed out
+        # and cool down), and open lanes it follows without knowing whom.
+        led = h_role == LEADER
+        unready = self.h_active & led & ~self.h_ready
+        n_open, n_led = int(self.h_active.sum()), int(led.sum())
+        n_unready = int(unready.sum())
+        leaderless = n_unready + int(
+            (self.h_active & ~led & (h_leader == NIL)).sum())
+        # The leader's windows as the step left them (core/step.py
+        # window_sums, reduced on the device).
+        win = back.windows.tolist()
+        pairs, occupied, full, cooling, timed_out = win
+        m = self.metrics
+        m.gauge("groups_active", n_open)
+        m.gauge("groups_leaderless", leaderless)
+        m.gauge("groups_led", n_led)
+        m.gauge("groups_led_unready", n_unready)
+        m.gauge("window_slots_occupied", occupied)
+        m.gauge("window_pairs_cooling", cooling)
+        if timed_out:
+            m["window_timeouts"] += timed_out
+        st.note(leaderless=leaderless, open=n_open, led=n_led,
+                unready=n_unready, win_pairs=pairs,
+                win_slots=pairs * cfg.inflight_limit, win_occupied=occupied,
+                win_full=full, win_cooling=cooling, win_timeouts=timed_out)
+        was_unready, self._unready_n = self._unready_n, n_unready
+        if n_unready or was_unready:
+            self._track_unready(unready, was_ready, ctx.timer, win)
+
+    def _track_unready(self, unready: np.ndarray, was_ready: np.ndarray,
+                       timer: bool, win: List[int]) -> None:
+        """Tick thread, in a step that finds a led lane unready or follows
+        one that did (no other step does ``[G]`` work for this): close the
+        episodes of lanes that are ready again or no longer led (their
+        length in periods, as seconds of the loop's period, into
+        ``lane_unready_s``; a caller that steps the node itself has no
+        period and a period counts as a second), open one where a lane
+        led READY by the last step is unready now (a fresh leader that
+        has not been ready yet opens none: that is an election, and
+        ``groups_led_unready`` counts it), and say so once a period while
+        an episode has lasted longer than an RPC timeout."""
+        since, now = self._unready_since, self.timer_ticks
+        over = np.nonzero((since != 0) & ~unready)[0]
+        if over.size:
+            period_s = self._tick_interval or 1.0
+            lengths = self.metrics.histogram("lane_unready_s")
+            for periods, n in zip(*np.unique(now - since[over],
+                                             return_counts=True)):
+                lengths.observe(int(periods) * period_s, int(n))
+            since[over] = 0
+        since[unready & was_ready] = now
+        if not timer:       # the timer's step alone: a line a period
+            return
+        lanes = np.nonzero(since)[0]
+        if lanes.size and now - int(since[lanes].min()) \
+                > self.cfg.rpc_timeout_ticks:
+            oldest = lanes[np.argsort(since[lanes], kind="stable")[:3]]
+            log.warning(
+                "node %d tick %d: %d led lane(s) unready, the oldest "
+                "(lane, since) %s; windows: %s", self.node_id, now,
+                self._unready_n, [(int(g), int(since[g])) for g in oldest],
+                dict(zip(WINDOW_SUMS, win)))
 
     # ---------------------------------------------------- tick: host phase
 

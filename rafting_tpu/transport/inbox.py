@@ -31,7 +31,8 @@ the runtime moves them into the durable LogStore.
 The backlog is measured where it stands: every slice is stamped at
 ``merge()``, and ``drain()`` records (under the queue lock) how long each
 slice it popped had waited, how many slices each source still holds after
-the pop, and how many were collapsed or dropped.  ``take_stats()`` hands
+the pop, how many were collapsed or dropped, and how many replies a
+collapse overwrote.  ``take_stats()`` hands
 the record to the draining thread — the node's tick thread, which folds it
 into its registry; reader threads never touch a registry.
 """
@@ -58,6 +59,29 @@ class InboxStats:
     #                                   left queued after the last pop
     collapsed: int = 0        # slices merged into one by a collapse
     dropped: int = 0          # slices refused at MAX_QUEUED_SLICES
+    merged: int = 0           # replies a collapse overwrote (_merged)
+
+
+# The replies a leader's window counts one by one (core/step.py phase 6
+# releases ONE slot per AppendEntries reply, heartbeat echoes included, and
+# the snapshot offer's slot per InstallSnapshot reply): a collapse keeps a
+# lane's newest and the window never hears of the others.
+COUNTED_REPLIES = tuple(KIND_FIELDS[kind][0] for kind in ("aer", "isr"))
+
+
+def _merged(batch: List[tuple], n_groups: int) -> int:
+    """Replies that merging ``batch`` (one source's slices) newest-wins per
+    lane overwrites: per counted kind, the lanes over the slices less the
+    distinct lanes."""
+    lost = 0
+    for valid in COUNTED_REPLIES:
+        lanes = [fields[valid][0] for fields, _, _ in batch
+                 if valid in fields]
+        if len(lanes) > 1:
+            seen = np.zeros(n_groups, bool)
+            seen[np.concatenate(lanes)] = True
+            lost += sum(map(len, lanes)) - int(seen.sum())
+    return lost
 
 
 class InboxAccumulator:
@@ -100,6 +124,7 @@ class InboxAccumulator:
         or :func:`fill_columns`."""
         batches: Dict[int, List[Dict]] = {}
         payloads: Dict[Tuple[int, int], Tuple[int, list]] = {}
+        collapsed: List[List[tuple]] = []
         with self._lock:
             st = self._stats
             now = time.perf_counter()
@@ -111,6 +136,7 @@ class InboxAccumulator:
                     batch, q_new = list(q), deque()
                     self._queues[src] = q_new
                     st.collapsed += len(batch)
+                    collapsed.append(batch)
                 else:
                     batch = [q.popleft()]
                 st.depth[src] = len(self._queues[src])
@@ -120,6 +146,12 @@ class InboxAccumulator:
                     mine.append(fields)
                     for g, run in pl.items():
                         payloads[(src, g)] = run
+        if collapsed:
+            # Counted with the queues free again (a storm's collapse is
+            # seventeen slices of tens of thousands of lanes), and written
+            # without the lock: this thread alone swaps the record
+            # (take_stats) and no other writes this field.
+            st.merged += sum(_merged(b, self.cfg.n_groups) for b in collapsed)
         return batches, payloads
 
     def drain(self, arrays: Optional[Dict[str, np.ndarray]] = None

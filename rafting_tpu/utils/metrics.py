@@ -78,10 +78,11 @@ class Histogram:
         self.n = 0
         self.max = 0.0
 
-    def observe(self, v: float) -> None:
-        self.counts[bisect.bisect_right(self.bounds, v)] += 1
-        self.total += v
-        self.n += 1
+    def observe(self, v: float, n: int = 1) -> None:
+        """One sample of ``v``, or ``n`` of them at once."""
+        self.counts[bisect.bisect_right(self.bounds, v)] += n
+        self.total += v * n
+        self.n += n
         if v > self.max:
             self.max = v
 

@@ -11,10 +11,16 @@ it), emits a ``raft.<name>`` span carrying ``node`` and ``tick`` on
 
 from __future__ import annotations
 
+import logging
 import time
 from typing import Dict, Optional
 
 from jax.profiler import TraceAnnotation
+
+log = logging.getLogger(__name__)
+
+# The sleep between two steps: the one phase that may outlast a period.
+WAIT = "wait"
 
 
 class StageSpans:
@@ -24,15 +30,24 @@ class StageSpans:
     no parent span: a trace reducer that labels a device gap with the
     host span of greatest overlap would hand every gap to the parent.
     Tick thread only (the registry's single writer).  With no profiler
-    session a boundary costs one flag test beside its histogram sample."""
+    session a boundary costs one flag test beside its histogram sample.
 
-    __slots__ = ("_metrics", "_node", "tick", "spent", "_name", "_t0",
-                 "_observe", "_span")
+    A phase of a step that outlasts the loop's ``period`` is a STALL:
+    counter ``stage_stalls`` and one warning, from the two instants the
+    boundary has taken anyway.  It is what leaves the peers' slices
+    queued past the inbox's collapse (transport/inbox.py)."""
+
+    __slots__ = ("_metrics", "_node", "tick", "period", "spent", "_name",
+                 "_t0", "_observe", "_span")
 
     def __init__(self, metrics, node_id: int):
         self._metrics = metrics
         self._node = int(node_id)
         self.tick = 0
+        # What a started loop says its period is (seconds), once its
+        # first step (which loads or compiles the program) is done; None
+        # under a caller that steps the node itself, who has no period.
+        self.period: Optional[float] = None
         # Seconds per phase since begin(): what the node sums into the
         # composite stages (dispatch, scan_wait) and the tick's total.
         self.spent: Dict[str, float] = {}
@@ -83,4 +98,10 @@ class StageSpans:
             self.spent[name] = self.spent.get(name, 0.0) + dt
             if self._observe:
                 self._metrics.observe(f"tick_stage_{name}_s", dt)
+            if self.period is not None and dt > self.period \
+                    and name != WAIT:
+                self._metrics["stage_stalls"] += 1
+                log.warning(
+                    "node %d tick %d: stage %s took %.3f s (period %.3f s)",
+                    self._node, self.tick, name, dt, self.period)
         return now

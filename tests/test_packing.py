@@ -97,7 +97,10 @@ def test_packed_step_is_node_step_bit_for_bit(extra, durable):
                 info=p_info, outbox=p_out, term=s.term,
                 voted_for=s.voted_for, role=s.role, leader_id=s.leader_id,
                 commit=s.commit, base=s.log.base,
-                base_term=s.log.base_term, heat=s.heat), tag)
+                base_term=s.log.base_term, heat=s.heat,
+                # Derived from the states on either side of the step:
+                # tests/test_window_stats.py recomputes it.
+                windows=back.windows), tag)
             assert (back.heat is None) == (not cfg.heat)
             assert (back.info.cq_stepdown is None) == (not cfg.check_quorum)
             outboxes.append(back.outbox)
@@ -292,12 +295,14 @@ def test_column_step_is_node_step_bit_for_bit(small_columns, columns_in,
             tag = f"tick {t} node {n}"
             assert_trees_equal(cols[n], plain[n], tag)
             s = plain[n]
+            h_back = lay.back.unpack(jax.device_get(back))
             assert_trees_equal(
-                lay.back.unpack(jax.device_get(back)), Readback(
+                h_back, Readback(
                     info=p_info, outbox=None, term=s.term,
                     voted_for=s.voted_for, role=s.role,
                     leader_id=s.leader_id, commit=s.commit, base=s.log.base,
-                    base_term=s.log.base_term, heat=s.heat), tag)
+                    base_term=s.log.base_term, heat=s.heat,
+                    windows=h_back.windows), tag)
             assert_trees_equal(lay.columns.unstack(dense), p_out, tag)
             p_out = jax.device_get(p_out)
             occ = _occupied(p_out)

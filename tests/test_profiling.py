@@ -9,7 +9,7 @@ import time
 
 import pytest
 
-from rafting_tpu.core.types import EngineConfig
+from rafting_tpu.core.types import LEADER, EngineConfig
 from rafting_tpu.log import native_available
 from rafting_tpu.testkit.harness import LocalCluster, wal_store_factory
 from rafting_tpu.utils import profiling
@@ -258,6 +258,99 @@ def test_lease_hits_lanes_and_leaderless_ride_the_spans(tmp_path):
                            and s["leaderless"] == 0 for s in mirrors)
 
 
+def test_windows_unready_and_merged_replies_ride_the_spans(tmp_path):
+    """PR 37's statistics.  ``raft.mirrors`` carries ``led`` and
+    ``unready`` beside ``leaderless`` / ``open``, and the five window sums
+    of the step's readback with the slots they are a share of
+    (``win_slots`` = ``win_pairs`` x ``inflight_limit``);
+    ``raft.dispatch_intake`` carries ``collapsed`` and ``merged`` beside
+    ``arrival``: on every step of every node, 0 where nothing happened."""
+    import jax
+
+    cfg = EngineConfig(n_groups=16, n_peers=3)
+    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1)
+    trace_dir = str(tmp_path / "trace")
+    try:
+        for g in range(cfg.n_groups):
+            c.wait_leader(g)
+        c.tick_until(lambda: all(
+            n.h_ready[n.h_role == LEADER].all() for n in c.nodes.values()),
+            what="every leader ready")
+        with jax.profiler.trace(trace_dir):
+            c.tick(6)
+        led = {i: int((n.h_role == LEADER).sum()) for i, n in c.nodes.items()}
+    finally:
+        c.close()
+    mirrors, intake = [], []
+    for name, stats in _raft_spans(trace_dir):
+        if name == "raft.mirrors":
+            mirrors.append(stats)
+        elif name == "raft.dispatch_intake":
+            intake.append(stats)
+    assert len(mirrors) == len(intake) == 3 * 6
+    assert sum(led.values()) == cfg.n_groups
+    for s in mirrors:
+        assert s["led"] == led[s["node"]] and s["unready"] == 0
+        assert s["win_pairs"] == 2 * s["led"]
+        assert s["win_slots"] == s["win_pairs"] * cfg.inflight_limit
+        assert 0 <= s["win_occupied"] <= s["win_slots"]
+        assert s["win_full"] == s["win_cooling"] == s["win_timeouts"] == 0
+    assert all(s["arrival"] == s["collapsed"] == s["merged"] == 0
+               for s in intake)
+
+
+def test_a_stage_that_outlasts_the_period_is_a_stall(caplog):
+    """``stage_stalls`` and ONE warning (node, tick, stage, seconds) for a
+    stage longer than the loop's period, from the instants the boundary
+    takes anyway; nothing for ``wait``, nothing for a stage inside the
+    period, nothing under a caller that has no period."""
+    m = Metrics()
+    st = StageSpans(m, 2)
+    st.begin(41)
+    for period, name, slow in ((None, "apply", True), (0.01, "apply", False),
+                               (0.01, "wait", True), (0.01, "send", True)):
+        st.period = period
+        st.enter(name)
+        if slow:
+            time.sleep(0.02)
+        st.leave()
+    assert m["stage_stalls"] == 1
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert line.startswith("node 2 tick 41: stage send took 0.0")
+    assert line.endswith("(period 0.010 s)")
+
+
+def test_a_loops_first_step_is_never_a_stall(tmp_path, caplog):
+    """``_run`` names the period to the stage spans after the loop's first
+    step (which loads or compiles the program): that step may take many
+    periods and says nothing, a later one that does is one line, and the
+    period goes when the loop does."""
+    c = LocalCluster(EngineConfig(n_groups=4, n_peers=3), str(tmp_path))
+    try:
+        node, period, calls = c.nodes[0], 0.02, []
+        st = node._stages
+
+        def tick(arrival=False):
+            st.begin(len(calls))
+            st.enter("apply")
+            calls.append(st.period)
+            if len(calls) in (1, 3):        # the first step, and a later
+                time.sleep(3 * period)
+            if len(calls) == 5:
+                node._stop.set()
+            st.leave()
+
+        node.tick = tick
+        node._run(period)
+        assert calls == [None] + [period] * 4 and st.period is None
+        assert node.metrics["stage_stalls"] == 1
+        (line,) = [r.getMessage() for r in caplog.records
+                   if "stage" in r.getMessage()]
+        assert line.startswith("node 0 tick 2: stage apply took")
+    finally:
+        c.close()
+
+
 def test_note_without_a_session_is_nothing():
     st = StageSpans(Metrics(), 0)
     st.begin(1)
@@ -290,6 +383,7 @@ def test_no_profiler_session_allocates_no_annotation(monkeypatch):
     assert st.spent["dispatch_intake"] == pytest.approx(b - a)
     assert st.spent["wal"] == pytest.approx(end - b)
     assert st.leave() >= end and len(st.spent) == 2     # nothing open
+    assert st.period is None and "stage_stalls" not in m._counters
 
 
 def test_host_cost_is_the_stage_spans_own_sum(tmp_path):

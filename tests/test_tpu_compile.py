@@ -24,8 +24,8 @@ from jax.sharding import SingleDeviceSharding
 
 from rafting_tpu.core.packing import Layout
 from rafting_tpu.core.step import (
-    column_layouts, node_step, node_step_columns, node_step_packed,
-    step_layouts)
+    WINDOW_SUMS, column_layouts, node_step, node_step_columns,
+    node_step_packed, step_layouts)
 from rafting_tpu.core.types import HostInbox, Messages, init_state
 from rafting_tpu.ops.quorum import quorum_commit_pallas
 
@@ -139,7 +139,17 @@ def test_node_step_holds_no_gather_and_no_scatter(one_chip, packed):
     assert (cfg.n_groups, cfg.n_peers, cfg.log_slots, cfg.batch,
             cfg.max_submit, cfg.read_slots) == (10_000, 3, 64, 8, 8, 4)
 
-    compiled = _lower_step(one_chip, cfg, packed).compile()
+    lowered = _lower_step(one_chip, cfg, packed)
+    if packed:
+        # The served program: the window sums (core/step.py window_sums,
+        # five reductions over the [G, P] planes the step has written)
+        # are in it, and come down in a buffer that crossed already.
+        _, readback = step_layouts(cfg, True)
+        assert (len(WINDOW_SUMS),) in [shape for *_, shape in readback.slots]
+        _, out = lowered.out_info
+        assert tuple((np.dtype(o.dtype), o.shape[0]) for o in out) \
+            == readback.buffers and len(out) == 3
+    compiled = lowered.compile()
     hlo = compiled.as_text()
     found = {op: len(re.findall(rf"\b{op}\(", hlo))
              for op in ("gather", "scatter")}
@@ -189,6 +199,10 @@ def test_column_step_compiles_at_100k_lanes_and_addresses_k_rows(
     assert lay is not None
     P, K = cfg.n_peers, lay.columns.K
     assert lay.columns.nbytes <= 512 * 1024     # one transfer's fixed cost
+    # The window sums ride the [G] part of the readback, whose buffers are
+    # as many as before them (20 bytes more in one that crossed already).
+    assert (len(WINDOW_SUMS),) in [shape for *_, shape in lay.back.slots]
+    assert len(lay.back.buffers) == 4
 
     def bufs(layout):
         return tuple(jax.ShapeDtypeStruct(
