@@ -428,6 +428,328 @@ def _first_columns(occ, K: int):
 
 
 # ---------------------------------------------------------------------------
+# Row form: a tree of [G] planes as the lanes that hold something.  The [G]
+# side of a large node's step (HostInbox going up; StepInfo and the mirrored
+# state lanes coming down) is some fifty planes of 100,000 lanes of which a
+# step touches a handful; they cross as ROWS (a lane id and every plane's
+# value there) and are dense on the device alone, where the step program
+# expands them into planes and compacts its results from planes
+# (core/step.py node_step_columns), exactly as the messages' columns do.
+# ---------------------------------------------------------------------------
+
+# The most rows that cross in row form, going up and coming down; a step
+# with more crosses as whole planes, decided by the count, nothing is cut.
+# Chosen on a TPU v5e at 100,000 lanes (PERF.md, PR 38): a row costs the
+# device what its planes' elements cost a gather or a scatter (0.37 us
+# coming down over 38 planes, 0.08 us going up over 11, on a fixed 0.1 ms
+# of search and comparison), so the step with its compaction reads 5.23 /
+# 5.25 / 5.48 / 5.67 ms a call at 128 / 256 / 512 / 1,024 rows each way
+# against 5.16 for the program whose pack and unpack they replace (which
+# cost the device next to nothing: what rows buy is on the host): the
+# largest power of two within 0.1 ms of it.  A steady step of the
+# 100,000-Region cell moves a few tens of lanes.
+ROWS_IN = 256
+ROWS_OUT = 256
+
+HEAD = np.dtype(np.int64)       # a marker, not a dtype that crosses
+
+
+def _path_name(path) -> str:
+    return ".".join(str(getattr(k, "name", getattr(k, "key", k)))
+                    for k in path)
+
+
+def lane_names(tree: Any, G: int) -> list:
+    """The path names of ``tree``'s ``[G]`` leaves, in the tree's order."""
+    return [_path_name(path) for path, leaf
+            in jax.tree_util.tree_flatten_with_path(tree)[0]
+            if tuple(leaf.shape) == (G,)]
+
+
+class RowLayout:
+    """Where each leaf of one tree of ``[G]`` planes lies in the row form:
+    a count ``n``, the lanes ``ids [K]`` of the rows (``G`` from ``n`` on)
+    and every ``[G]`` leaf's values there, field by field: the ``int32``
+    leaves in ``words [W, K]``, the ``bool`` leaves in ``flags [F, K]``.
+    A leaf of any other shape (a scalar, a few sums) is no plane: it rides
+    the HEADER, ``H`` words behind the count.  Two buffers: the word buffer
+    ``[1 + H + K + W * K]`` (count, header, lanes, words) and the flag
+    buffer ``[F * K]`` (a byte a flag).
+
+    Leaves are named by their path (``info.commit``, ``commit``).  The
+    ``[G]`` leaves named in ``levels`` are LEVELS (a lane's row crosses
+    when one differs from what the other side holds), those in
+    ``carried`` cross with a row and never cause one, every other is an
+    EVENT (zero unless something happened: a row crosses when one is not
+    zero); :meth:`moved` says which lanes those are.  The stacks hold the
+    levels first, then the events, then the carried leaves, each group in
+    the tree's order.
+
+    On the device the planes are addressed STACKED (:meth:`stack`), the
+    leaves of a kind as one ``[n, G]`` array that shares ONE K-row scatter
+    going in and ONE K-row gather coming out, as ColumnLayout's are.
+    Derived from the tree's own structure: a field added to ``HostInbox``
+    or ``StepInfo`` finds its place by itself (as an event)."""
+
+    __slots__ = ("treedef", "names", "slots", "at", "G", "K", "W", "F", "H",
+                 "Lw", "Ew", "Lf", "Ef", "buffers", "_key", "_hash")
+
+    def __init__(self, tree: Any, G: int, K: int, levels=(), carried=()):
+        flat, self.treedef = jax.tree_util.tree_flatten_with_path(tree)
+        self.G, self.K = int(G), int(K)
+        names, kinds, shapes = [], [], []
+        for path, leaf in flat:
+            dt = np.dtype(leaf.dtype)
+            if dt not in (WORD, BOOL):
+                raise TypeError(f"a row leaf is int32 or bool, not {dt} "
+                                f"(shape {tuple(leaf.shape)})")
+            lane = tuple(leaf.shape) == (self.G,)
+            names.append(_path_name(path))
+            kinds.append((FLAG if dt == BOOL else WORD) if lane else HEAD)
+            shapes.append((dt, tuple(int(d) for d in leaf.shape)))
+        unknown = (set(levels) | set(carried)) - {
+            n for n, k in zip(names, kinds) if k != HEAD}
+        if unknown:
+            raise ValueError(f"no [G] leaf named {sorted(unknown)}")
+        rank = lambda name: (0 if name in levels else
+                             2 if name in carried else 1)
+        width = {WORD: 0, FLAG: 0, HEAD: 0}
+        offs = [0] * len(names)
+        counts = {}
+        for r in (0, 1, 2):
+            for i, (name, kind) in enumerate(zip(names, kinds)):
+                if kind != HEAD and rank(name) == r:
+                    offs[i] = width[kind]
+                    width[kind] += 1
+            counts[r] = (width[WORD], width[FLAG])
+        for i, kind in enumerate(kinds):
+            if kind == HEAD:
+                offs[i] = width[HEAD]
+                width[HEAD] += int(np.prod(shapes[i][1], dtype=np.int64))
+        self.names: Tuple[str, ...] = tuple(names)
+        self.slots = tuple(
+            (kind, off, dt, shape)
+            for kind, off, (dt, shape) in zip(kinds, offs, shapes))
+        # name -> (kind, place in the kind's stack or in the header)
+        self.at = {name: slot[:2] for name, slot
+                   in zip(self.names, self.slots)}
+        self.W, self.F, self.H = width[WORD], width[FLAG], width[HEAD]
+        self.Lw, self.Lf = counts[0]
+        self.Ew, self.Ef = counts[1][0] - self.Lw, counts[1][1] - self.Lf
+        self.buffers = ((WORD, 1 + self.H + self.K + self.W * self.K),
+                        (FLAG, self.F * self.K))
+        self._key = (self.treedef, self.names, self.slots, self.G, self.K)
+        self._hash = hash(self._key)
+
+    def __eq__(self, other):
+        return self is other or (isinstance(other, RowLayout)
+                                 and self._key == other._key)
+
+    def __hash__(self):
+        return self._hash
+
+    @property
+    def nbytes(self) -> int:
+        return sum(np.dtype(dt).itemsize * n for dt, n in self.buffers)
+
+    # ------------------------------------------------------------ the parts
+
+    def _parts(self, buffers):
+        """(n, header [H], ids [K], words [W, K], flags [F, K]) of a buffer
+        pair, numpy views or traced slices."""
+        wbuf, fbuf = buffers
+        H, K = self.H, self.K
+        flags = fbuf.reshape(self.F, K)
+        flags = flags.view(BOOL) if isinstance(flags, np.ndarray) \
+            else flags != 0
+        return (wbuf[0], wbuf[1:1 + H], wbuf[1 + H:1 + H + K],
+                wbuf[1 + H + K:].reshape(self.W, K), flags)
+
+    def _head(self, header):
+        """name -> the header's leaves, in their own shape and dtype."""
+        out = {}
+        for name, (kind, off, dt, shape) in zip(self.names, self.slots):
+            if kind == HEAD:
+                flat = header[off:off + int(np.prod(shape, dtype=np.int64))]
+                out[name] = (flat != 0 if dt == BOOL else flat).reshape(shape)
+        return out
+
+    # ------------------------------------------------------------- the host
+
+    def alloc(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Fresh buffers on the host that hold no row."""
+        wbuf, fbuf = (np.zeros(n, dt) for dt, n in self.buffers)
+        wbuf[1 + self.H:1 + self.H + self.K] = self.G
+        return wbuf, fbuf
+
+    def view(self, buffers) -> "RowView":
+        """The host's view of a numpy buffer pair: fill an ``alloc()``-ed
+        pair in place through it, or read a fetched pair where it lies."""
+        return RowView(self, buffers[0], *self._parts(buffers)[1:])
+
+    def planes(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Fresh zero planes on the host, stacked: ``(words [W, G], flags
+        [F, G])``; :meth:`unstack` gives the tree of views into them."""
+        return (np.zeros((self.W, self.G), WORD),
+                np.zeros((self.F, self.G), BOOL))
+
+    def copy_levels(self, tree: Any, words, flags) -> None:
+        """Host: the level leaves of a dense numpy ``tree`` copied into the
+        stacked planes ``words`` and ``flags``; nothing else is touched."""
+        for (kind, off, _, _), leaf in zip(
+                self.slots, self.treedef.flatten_up_to(tree)):
+            if kind == WORD and off < self.Lw:
+                np.copyto(words[off], leaf)
+            elif kind == FLAG and off < self.Lf:
+                np.copyto(flags[off], leaf)
+
+    def whole(self, tree: Any) -> Tuple[np.ndarray, np.ndarray]:
+        """Host: the buffer pair that goes up beside planes that crossed
+        whole: no row, a count of -1, and ``tree``'s header leaves."""
+        pair = self.alloc()
+        view = self.view(pair)
+        view.set_n(-1)
+        for name, (kind, *_), leaf in zip(
+                self.names, self.slots, self.treedef.flatten_up_to(tree)):
+            if kind == HEAD:
+                view.set_head(name, leaf)
+        return pair
+
+    # ------------------------------------------------------- stacked planes
+
+    def stack(self, tree: Any) -> tuple:
+        """``(words [W, G], flags [F, G], header [H])`` of a dense tree."""
+        leaves = self.treedef.flatten_up_to(tree)
+        xp = np if all(isinstance(leaf, (np.ndarray, np.generic, int, bool))
+                       for leaf in leaves) else jnp
+        rows = {WORD: [None] * self.W, FLAG: [None] * self.F}
+        head = []
+        for (kind, off, dt, shape), leaf in zip(self.slots, leaves):
+            if kind == HEAD:
+                head.append(xp.ravel(xp.asarray(leaf)).astype(WORD))
+            else:
+                rows[kind][off] = leaf
+        return (xp.stack(rows[WORD]) if self.W else xp.zeros((0, self.G), WORD),
+                xp.stack(rows[FLAG]) if self.F else xp.zeros((0, self.G), BOOL),
+                xp.concatenate(head) if head else xp.zeros((0,), WORD))
+
+    def unstack(self, words, flags, header) -> Any:
+        """The dense tree of :meth:`stack`'s arrays (numpy: views)."""
+        head = self._head(header)
+        return jax.tree.unflatten(self.treedef, [
+            head[name] if kind == HEAD else
+            (flags if kind == FLAG else words)[off]
+            for name, (kind, off, _, _) in zip(self.names, self.slots)])
+
+    # -------------------------------------------------------- rows -> dense
+
+    def expand(self, buffers, words, flags) -> tuple:
+        """``(words, flags, header)``: the stacked planes ``words [W, G]``
+        and ``flags [F, G]`` with the rows of a buffer pair written over
+        them, and the pair's header.  Under ``jit`` one K-row scatter a
+        kind: cost follows K, not G."""
+        n, header, ids, rw, rf = self._parts(buffers)
+        G, K = self.G, self.K
+        host = isinstance(ids, np.ndarray)
+        xp = np if host else jnp
+        held = xp.arange(K) < n
+        idx = xp.where(held, ids, G)
+
+        def scatter(base, vals):
+            if host:
+                out = base.copy()
+                out[:, idx[held]] = vals[:, held]
+                return out
+            rows = base.shape[0]
+            # One index row a (leaf, row), no window over the leaves (see
+            # ColumnLayout.expand).
+            return base.at[
+                jnp.arange(rows)[:, None],
+                jnp.broadcast_to(idx[None], (rows, K))].set(
+                    vals.astype(base.dtype), mode="drop")
+
+        return scatter(words, rw), scatter(flags, rf), header
+
+    # -------------------------------------------------------- dense -> rows
+
+    def moved(self, words, flags, prev_words, prev_flags):
+        """``[G] bool``: the lanes whose row crosses.  A level differs
+        from ``prev_*`` (stacked planes of the same layout: what the other
+        side holds), or an event is not zero."""
+        Lw, Ew, Lf, Ef = self.Lw, self.Ew, self.Lf, self.Ef
+        return ((words[:Lw] != prev_words[:Lw]).any(axis=0)
+                | (flags[:Lf] != prev_flags[:Lf]).any(axis=0)
+                | (words[Lw:Lw + Ew] != 0).any(axis=0)
+                | flags[Lf:Lf + Ef].any(axis=0))
+
+    def compact(self, words, flags, header, moved) -> tuple:
+        """The buffer pair of stacked planes: the count of ``moved`` lanes
+        (the TRUE count, also beyond K), the first K of them ascending and
+        every plane's value there.  Under ``jit`` the lanes are found by
+        block (``_first_columns``) and the values by one K-row gather a
+        kind: nothing is addressed G rows at a time."""
+        G, K = self.G, self.K
+        host = isinstance(words, np.ndarray)
+        xp = np if host else jnp
+        if host:
+            at = np.nonzero(moved)[0]
+            n = np.asarray(len(at), WORD)
+            ids = np.full(K, G, WORD)
+            ids[:min(K, len(at))] = at[:K]
+        else:
+            n, ids = _first_columns(moved[None, :], K)
+            n, ids = n[0], ids[0]
+        held = xp.arange(K) < n
+        at = xp.minimum(ids, G - 1)
+
+        def gather(plane):
+            if host:
+                vals = plane[:, at]
+            else:
+                rows = plane.shape[0]
+                vals = plane[jnp.arange(rows)[:, None],
+                             jnp.broadcast_to(at[None], (rows, K))]
+            return xp.where(held[None, :], vals, xp.zeros((), vals.dtype))
+
+        wbuf = xp.concatenate([
+            xp.reshape(n, (1,)).astype(WORD), header.astype(WORD),
+            ids.astype(WORD), gather(words).reshape(-1)])
+        return wbuf, gather(flags).reshape(-1).astype(FLAG)
+
+
+class RowView:
+    """A numpy buffer pair of a :class:`RowLayout`: the count ``n`` (set
+    it with :meth:`set_n`), the lanes ``ids [K]``, ``words [W, K]`` and
+    ``flags [F, K]`` field by field, and by name ``field(name)`` (a ``[K]``
+    view) and ``head(name)`` / ``set_head(name, value)``."""
+
+    __slots__ = ("layout", "_wbuf", "header", "ids", "words", "flags")
+
+    def __init__(self, layout: RowLayout, wbuf, header, ids, words, flags):
+        self.layout, self._wbuf, self.header = layout, wbuf, header
+        self.ids, self.words, self.flags = ids, words, flags
+
+    @property
+    def n(self) -> int:
+        return int(self._wbuf[0])
+
+    def set_n(self, n: int) -> None:
+        self._wbuf[0] = n
+
+    def field(self, name: str) -> np.ndarray:
+        kind, off = self.layout.at[name]
+        return (self.flags if kind == FLAG else self.words)[off]
+
+    def head(self, name: str):
+        return self.layout._head(self.header)[name]
+
+    def set_head(self, name: str, value) -> None:
+        flat = np.ravel(np.asarray(value))
+        off = self.layout.at[name][1]
+        self.header[off:off + flat.size] = flat
+
+
+# ---------------------------------------------------------------------------
 # What the host reads messages through: one interface over the two forms, so
 # that a reader asks for a row's held lanes and the values there and never
 # for a [G] plane.  ``row`` gives a field over the lanes a peer row HOLDS

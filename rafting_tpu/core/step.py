@@ -65,7 +65,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.select import take_plane, take_slots
-from .packing import WORD, ColumnLayout, Layout
+from . import packing
+from .packing import WORD, ColumnLayout, Layout, RowLayout
 from .types import (
     CANDIDATE, FOLLOWER, LEADER, NIL, PRE_CANDIDATE, I32,
     EngineConfig, HostInbox, LogState, Messages, RaftState, StepInfo,
@@ -1465,12 +1466,16 @@ def node_step_packed(cfg: EngineConfig, inputs: Layout, state: RaftState,
 # ---------------------------------------------------------------------------
 # The column step: node_step_packed for a node so large that its message
 # planes take several buffers.  There the planes are tens of MB each way
-# and a step moves a handful of their columns, so the Messages operand and
-# result cross as columns (core/packing.py ColumnLayout) and are dense on
-# the device alone; the [G] planes (HostInbox, StepInfo, the mirrored
-# lanes) stay packed as they are.  A step whose messages do not fit the
-# column buffers (a heartbeat round, an election storm) crosses densely
-# exactly as node_step_packed's does: decided by a count, nothing cut.
+# and a step moves a handful of their columns and of their lanes, so three
+# things cross by shape and count and are dense on the device alone: the
+# Messages operand and result as COLUMNS (core/packing.py ColumnLayout),
+# HostInbox as the ROWS of the lanes that have something to say and the
+# Readback's [G] planes (StepInfo, the mirrored state lanes) as the rows
+# of the lanes that moved (RowLayout).  A step whose messages do not fit
+# the column buffers (a heartbeat round, an election storm), whose
+# HostInbox holds more lanes than its row buffer or whose results moved
+# more crosses that part densely, exactly as node_step_packed's does:
+# decided by a count, nothing cut.
 # ---------------------------------------------------------------------------
 
 class ColumnLayouts(NamedTuple):
@@ -1481,6 +1486,35 @@ class ColumnLayouts(NamedTuple):
     back: Layout            # a Readback without its outbox
     outbox: Layout          # the dense outbox, for a step that overflowed
     columns: ColumnLayout   # Messages in column form, both directions
+    rows_in: RowLayout      # HostInbox in row form
+    rows_out: RowLayout     # a Readback without its outbox in row form
+
+
+class RowCarry(NamedTuple):
+    """What a column step leaves on the device beside the state: the
+    ``durable_tail`` plane the host's rows patch (None for a layout
+    without one; never a field of ``RaftState``), which the next step
+    takes, and the Readback without its outbox as stacked ``[G]`` planes
+    and a header (``rows_out.stack``): what ``compact_readback`` compares
+    with the last step's to find the lanes that moved, and what
+    ``pack_readback`` packs for a step whose rows did not fit."""
+
+    durable: Any
+    words: Array
+    flags: Array
+    header: Array
+
+
+# The fields of StepInfo that are LEVELS (they hold from step to step: a
+# lane's row comes down when one differs from the last step's) and the one
+# that is carried (``submit_start`` is ``log.last + 1`` on every lane and
+# read only where ``submit_acc`` is not zero: it crosses with a row and
+# causes none).  Every other field of StepInfo is an event, zero unless
+# something happened; every mirrored state lane of the Readback is a
+# level.
+INFO_LEVELS = ("log_tail", "commit", "leader", "ready", "conf_word",
+               "conf_idx", "conf_pending")
+INFO_CARRIED = ("submit_start",)
 
 
 # Columns engage where the step's dense operand takes at least this many
@@ -1507,38 +1541,107 @@ def column_layouts(cfg: EngineConfig, durable: bool
     if sum(dt == WORD for dt, _ in inputs.buffers) < COLUMN_BUFFERS:
         return None
     host, inbox, back = _step_shapes(cfg, durable)
+    outbox, back = back.outbox, back._replace(outbox=None)
+    G = cfg.n_groups
     return ColumnLayouts(
         host=Layout(host), inputs=inputs,
-        back=Layout(back._replace(outbox=None)), outbox=Layout(back.outbox),
-        columns=ColumnLayout(inbox))
+        back=Layout(back), outbox=Layout(outbox),
+        columns=ColumnLayout(inbox),
+        rows_in=RowLayout(host, G, packing.ROWS_IN),
+        rows_out=RowLayout(
+            back, G, packing.ROWS_OUT,
+            levels=[n for n in packing.lane_names(back, G)
+                    if not n.startswith("info.")]
+            + ["info." + n for n in INFO_LEVELS],
+            carried=["info." + n for n in INFO_CARRIED]))
+
+
+def first_carry(lay: ColumnLayouts) -> RowCarry:
+    """The carry before a node's first column step: zero planes.  What
+    that step's rows say against them means nothing, so the host takes
+    the step's results whole (``pack_readback``) and uploads
+    ``durable_tail`` whole (a count of -1); from then on the carry is the
+    last step's."""
+    rl = lay.rows_out
+    durable = jnp.zeros((rl.G,), I32) \
+        if "durable_tail" in lay.rows_in.at else None
+    return RowCarry(durable, jnp.zeros((rl.W, rl.G), I32),
+                    jnp.zeros((rl.F, rl.G), jnp.bool_),
+                    jnp.zeros((rl.H,), I32))
+
+
+def _host_from_rows(rl: RowLayout, base: HostInbox, rows, durable
+                    ) -> Tuple[HostInbox, Any]:
+    """The HostInbox a step's rows stand for: ``base``'s planes with the
+    rows written over them by one K-row scatter a kind, the scalars from
+    the rows' header.  ``base`` holds no event when the rows do (the
+    resident zero planes) and the whole HostInbox when they do not fit
+    (then the count is -1 and no row is held).  ``durable_tail`` is a
+    level: the rows patch the plane the device keeps (``durable``) and a
+    count of -1 replaces it with ``base``'s.  Returns the HostInbox and
+    the plane to keep."""
+    words, flags, _ = rl.stack(base)
+    if durable is not None:
+        at = rl.at["durable_tail"][1]
+        words = words.at[at].set(
+            jnp.where(rows[0][0] < 0, words[at], durable))
+    host = rl.unstack(*rl.expand(rows, words, flags))
+    return host, host.durable_tail
 
 
 @partial(jax.jit, static_argnums=(0, 1, 2), donate_argnums=3)
 def node_step_columns(cfg: EngineConfig, lay: ColumnLayouts,
-                      columns_in: bool, state: RaftState,
+                      columns_in: bool, state: RaftState, carry: RowCarry,
                       buffers: Tuple[Array, ...]):
-    """``node_step`` with its messages in column form.  ``buffers``:
-    ``lay.host``'s followed by the inbox's column pair when
-    ``columns_in``, else ``lay.inputs``' (the dense operand of
-    ``node_step_packed``).  Returns the new state, the buffers of the
-    Readback without its outbox (``lay.back``), the outbox's column pair
+    """``node_step`` with its messages in column form and its ``[G]``
+    planes in row form.  ``buffers``: ``lay.host``'s followed by the
+    inbox's column pair when ``columns_in``, else ``lay.inputs``' (the
+    dense operand of ``node_step_packed``); then, either way, HostInbox's
+    row pair (``lay.rows_in``), whose rows are written over the
+    HostInbox planes that came before it (see ``_host_from_rows``).
+    ``carry`` is what the last call returned (``first_carry`` for the
+    first); the step reads its ``durable`` plane alone.  Returns the new
+    state, the new carry (the patched ``durable_tail`` plane; the
+    Readback without its outbox, stacked: left on the device for
+    ``compact_readback`` to find the rows that moved, and for
+    ``pack_readback`` should they not fit), the outbox's column pair
     (whose counts say whether it fits: ``lay.columns.K``), and the dense
     outbox itself (``lay.columns.stack``'s few arrays, not its forty
     planes: a result is a Python object a call), left on the device for
     ``pack_outbox`` should it not.
-    ``node_step`` gets the planes it always got, bit for bit: the columns
-    are expanded into zero planes by a K-row scatter and compacted from
-    the outbox by K-row gathers, nothing is addressed G rows at a time."""
+    ``node_step`` gets the planes it always got, bit for bit: columns and
+    rows are expanded into planes by K-row scatters, nothing is addressed
+    G rows at a time."""
+    buffers, rows = buffers[:-2], buffers[-2:]
     if columns_in:
         n_host = len(lay.host.buffers)
         host = lay.host.unpack(buffers[:n_host])
         inbox = lay.columns.expand(buffers[n_host:])
     else:
         host, inbox = lay.inputs.unpack(buffers)
+    host, durable = _host_from_rows(lay.rows_in, host, rows, carry.durable)
     state, back = _step_readback(cfg, state, inbox, host)
     dense = lay.columns.stack(back.outbox)
-    return (state, lay.back.pack(back._replace(outbox=None)),
+    return (state,
+            RowCarry(durable, *lay.rows_out.stack(back._replace(outbox=None))),
             lay.columns.compact(dense, stacked=True), dense)
+
+
+@partial(jax.jit, static_argnums=0)
+def compact_readback(lay: ColumnLayouts, carry: RowCarry, last: RowCarry
+                     ) -> Tuple[Array, Array]:
+    """The row pair of a column step's Readback (``lay.rows_out``): the
+    lanes of ``carry`` (the step's) where a level differs from ``last``
+    (the step's before it: what the host's mirrors hold) or an event is
+    not zero, their true count, the first K of them and every plane's
+    value there, by a prefix sum and one K-row gather a kind; the header
+    holds the leaves that are no planes.  A program of its own beside the
+    step: compiled into it, the search and the gathers came out of the
+    chip's compiler writing over the step's donated state (PERF.md, PR
+    38), and on its own it overlaps nothing the host waits for."""
+    rl = lay.rows_out
+    moved = rl.moved(carry.words, carry.flags, last.words, last.flags)
+    return rl.compact(carry.words, carry.flags, carry.header, moved)
 
 
 @partial(jax.jit, static_argnums=0)
@@ -1548,3 +1651,13 @@ def pack_outbox(lay: ColumnLayouts, dense: Tuple[Array, ...]
     fetch of a step whose outbox did not fit its columns (``lay.outbox``:
     as node_step_packed's readback holds it)."""
     return lay.outbox.pack(lay.columns.unstack(dense))
+
+
+@partial(jax.jit, static_argnums=0)
+def pack_readback(lay: ColumnLayouts, carry: RowCarry) -> Tuple[Array, ...]:
+    """The Readback without its outbox that a column step left on the
+    device (the carry's planes and header), packed for the fetch of a
+    step whose rows did not hold it (``lay.back``: as node_step_packed's
+    readback holds it)."""
+    return lay.back.pack(
+        lay.rows_out.unstack(carry.words, carry.flags, carry.header))

@@ -51,8 +51,9 @@ import numpy as np
 
 from ..core.packing import DenseView
 from ..core.step import (
-    WINDOW_SUMS, column_layouts, node_step_columns, node_step_packed,
-    pack_outbox, step_layouts)
+    WINDOW_SUMS, column_layouts, compact_readback, first_carry,
+    node_step_columns, node_step_packed, pack_outbox, pack_readback,
+    step_layouts)
 from ..core.types import (
     I32, I32_SAFE_MAX, LEADER, NIL, EngineConfig, HostInbox,
     StepInfo, boot_conf_word as _boot_conf_word, init_state,
@@ -327,6 +328,8 @@ class _TickCtx:
         # the outbox's column pair at the end of ``packed`` and the dense
         # outbox left on the device
         "packed", "readback", "columns", "out_dense",
+        # a column step's [G] side (_RowStep; None on a packed step)
+        "rows",
         # -> host views of the fetched buffers (fetch)
         "info", "outbox", "term", "voted", "role", "leader", "commit",
         "base", "base_term",
@@ -336,6 +339,24 @@ class _TickCtx:
         # eagerly (serial and settled ticks: every kind packs post-fsync,
         # the classic send).
         "deferred_ae",
+    )
+
+
+class _RowStep:
+    """The ``[G]`` side of one column step in flight (core/step.py
+    node_step_columns): what the step left on the device for a fetch
+    whose rows do not hold its results, and the lanes the step wrote into
+    the node's persistent planes, which its host phase clears again."""
+
+    __slots__ = (
+        # the step's RowCarry on the device (pack_readback's operand)
+        "carry",
+        # the (submit_n, read_n) planes ctx.submit_n / ctx.read_n are, the
+        # lanes written into them at dispatch and the values there
+        "up", "sub_ids", "sub_n", "read_ids", "read_n",
+        # the lanes whose event planes the fetch wrote (None: the step's
+        # results came down whole and wrote none)
+        "down_ids",
     )
 
 
@@ -857,6 +878,28 @@ class RaftNode:
         # device accepting both would outrun the host queues).
         self._inflight_submit = np.zeros(G, np.int32)
         self._inflight_read = np.zeros(G, np.int32)
+        # The [G] side of a shape that takes the column step (core/step.py
+        # column_layouts; all of it unused on any other): what the device
+        # keeps from step to step (RowCarry), the host's copy of the
+        # durable_tail plane the device holds (None: not told yet), the
+        # device's resident zero HostInbox planes by layout, the
+        # (submit_n, read_n) planes of the steps in flight, the node's
+        # persistent stacked Readback planes (words, flags) and the tree
+        # of views into them that the mirrors and ctx.info are once rows
+        # came down, the last
+        # Readback that came down whole while they are stale, whether the
+        # next fetch must take the results whole (the first step, a step
+        # after a purge), and the running counts behind the gauges of
+        # _fetch (None: recount).
+        self._carry = None
+        self._dur_sent: Optional[np.ndarray] = None
+        self._resident: Dict[object, tuple] = {}
+        self._up_planes: List[Tuple[np.ndarray, np.ndarray]] = []
+        self._back_planes: Optional[Tuple[np.ndarray, np.ndarray]] = None
+        self._back_tree = None
+        self._whole_back = None
+        self._rows_whole_out = True
+        self._lane_counts: Optional[List[int]] = None
         # Per-peer outbox sections accumulated across a tick's packing
         # sites (the host phase's deferred/non-eager sections + the eager
         # AE pack) and flushed as ONE frame per peer at end of tick — the
@@ -891,7 +934,9 @@ class RaftNode:
         # that takes columns whose messages did not fit them, each way
         # (core/step.py node_step_columns).
         for name in ("steps_columns_in", "steps_columns_out",
-                     "column_overflows_in", "column_overflows_out"):
+                     "column_overflows_in", "column_overflows_out",
+                     "steps_rows_in", "steps_rows_out",
+                     "row_overflows_in", "row_overflows_out"):
             self.metrics[name] += 0
         self.metrics["hb_rounds_closed"] += 0
         # Read plane: offers the device stamped (one ReadIndex barrier
@@ -1888,10 +1933,17 @@ class RaftNode:
                     purged.append(g)
             self.state = self.state.replace(active=jax.device_put(act))
             self.h_active = act
+            self._lane_counts = None
             if purged:
                 self._purge_lanes(purged)
 
         # -- 1. host inbox ---------------------------------------------------
+        # A shape that takes the column step (core/step.py column_layouts)
+        # keeps no fresh [G] planes: its intake is _dispatch_rows'.
+        lay = column_layouts(
+            cfg, self.pipeline or self._acked_tail is not None)
+        if lay is not None:
+            return self._dispatch_rows(lay, arrival, started, fetched)
         with self._submit_lock:
             # One vector op over the entry-count mirror — the dict walk
             # was O(groups-with-queues) per tick.  Offers already riding
@@ -1901,58 +1953,10 @@ class RaftNode:
             submit_n = np.minimum(
                 np.maximum(self._queued_n - self._inflight_submit, 0),
                 cfg.max_submit).astype(np.int32)
-        # Read plane: when a group's offer slot is free, promote ALL of
-        # its waiting batches into it as one offer — one read_n, one
-        # stamp, one barrier for every read the group holds.  What keeps
-        # that linearizable: only batches WAITING at this instant are
-        # merged, so every merged read was invoked before the step that
-        # stamps its ReadIndex is even dispatched; a read that arrives
-        # while an offer exists (offered, or riding the pending tick)
-        # waits for the next slot and never joins a stamped offer.  An
-        # unstamped offer (no free device slot / not leader yet) simply
-        # stays offered, as it is, and is re-offered next tick.  An offer
-        # riding the pending tick is masked out until that tick's harvest
-        # (an offer must reach the device exactly once per stamp attempt).
         read_n = np.zeros(G, np.int32)
-        with self._read_lock:
-            for g, q in self._reads_waiting.items():
-                if q and g not in self._reads_offered \
-                        and not self._inflight_read[g]:
-                    offer = _ReadOffer(list(q))
-                    q.clear()
-                    self._read_queued_n[g] -= offer.n
-                    self._reads_offered[g] = offer
-                    for b in offer.parts:
-                        if b.sink.span is not None:
-                            # The group's offer slot is won: submitted ->
-                            # offered was the wait for it
-                            # (lat_read_queue_s).
-                            b.sink.span.mark(OFFERED)
-            for g, offer in self._reads_offered.items():
-                if not self._inflight_read[g]:
-                    read_n[g] = offer.n
-        # Wall-clock pause detection (HostInbox.read_veto contract): a gap
-        # beyond read_fresh_ticks tick intervals invalidates stored lease
-        # evidence AND whatever acks queued in the inbox across the pause.
-        # The veto is HELD for read_fresh_ticks consecutive ticks, not one:
-        # pause-era acks still sitting in socket buffers drain through the
-        # reader threads into the accumulator over the FOLLOWING ticks too,
-        # and a single-tick veto would let receipt-anchored lease evidence
-        # resurrect from them one tick later (the tick clock did not
-        # advance during the pause, so the freshness bound alone cannot
-        # reject them).
-        wall = time.monotonic()
-        if self._tick_interval and self._last_tick_wall is not None:
-            gap = wall - self._last_tick_wall
-            if gap > self._tick_interval * max(cfg.read_fresh_ticks, 2):
-                self._read_veto_hold = max(cfg.read_fresh_ticks, 2)
-                self.metrics["read_vetoes"] += 1
-        read_veto = self._read_veto_hold > 0
-        if read_veto and not arrival:
-            # The hold is a length of TIME: read_fresh_ticks periods,
-            # not that many steps a few milliseconds apart.
-            self._read_veto_hold -= 1
-        self._last_tick_wall = wall
+        for g, n in self._offer_reads():
+            read_n[g] = n
+        read_veto = self._hold_read_veto(arrival)
         snap_done = np.zeros(G, bool)
         snap_idx = np.zeros(G, np.int32)
         snap_term = np.zeros(G, np.int32)
@@ -1995,34 +1999,18 @@ class RaftNode:
 
         # -- 2. network inbox ------------------------------------------------
         # This tick's upload buffers (core/packing.py), fresh: the [G]
-        # host planes packed, and the drained slices' messages either as
-        # the columns they arrived as (a shape whose dense planes do not
-        # fit a buffer, core/step.py column_layouts, and a step whose
-        # every source fits the column buffers: nothing here is then
-        # allocated, zeroed or walked P x G) or, as ever, as zeroed dense
+        # host planes and the drained slices' messages as zeroed dense
         # planes that are views of the packed buffers, filled where they
-        # cross to the device from.  ctx.arrays reads either form
-        # (DenseView / ColumnView) until this tick's host phase is done
-        # with it (in an overlapped tick: through the next dispatch,
-        # which fills its own).
+        # cross to the device from.  ctx.arrays reads them (DenseView)
+        # until this tick's host phase is done with it (in an overlapped
+        # tick: through the next dispatch, which fills its own).
         inputs, readback = step_layouts(cfg, durable is not None)
-        lay = column_layouts(cfg, durable is not None)
         batches, staged_payloads = self.acc.pop()
-        arrays = None
-        if lay is not None:
-            pair = lay.columns.alloc()
-            view = lay.columns.view(pair)
-            if fill_columns(batches, view):
-                arrays = view
-                buffers = lay.host.alloc()
-                host = lay.host.unpack(buffers)
-                buffers += pair
-        if arrays is None:
-            buffers = inputs.alloc()
-            host, inbox = inputs.unpack(buffers)
-            arrays = DenseView({name: getattr(inbox, name)
-                                for name in self.template})
-            scatter_dense(batches, arrays.planes)
+        buffers = inputs.alloc()
+        host, inbox = inputs.unpack(buffers)
+        arrays = DenseView({name: getattr(inbox, name)
+                            for name in self.template})
+        scatter_dense(batches, arrays.planes)
         self._fold_inbox_stats()
         if self._hb_rounds:
             self._hb_acknowledged(arrays)
@@ -2040,31 +2028,17 @@ class RaftNode:
         st = self._stages
         st.enter("dispatch_upload")
         packed = jax.device_put(buffers)
-        columns_in = arrays.columns
         st.note(transfers=len(packed), bytes=sum(b.nbytes for b in buffers),
-                columns=columns_in or 0, dense=int(columns_in is None))
+                columns=0, dense=1, rows=0, planes_dense=1)
         self.metrics["h2d_transfers"] += len(packed)
 
         # -- 3. device step (async dispatch: no transfer, no block) ----------
         # The step hands back, packed the same way, everything _fetch reads
         # (core/step.py Readback): the tick keeps no reference to a leaf of
-        # the state, which the next step donates.  The column step hands
-        # back its outbox twice: as columns, fetched with the rest, and
-        # dense, left on the device for the fetch of a step whose outbox
-        # does not fit them.
+        # the state, which the next step donates.
         st.enter("dispatch_enqueue")
-        out_dense = None
-        if lay is None:
-            self.state, packed = node_step_packed(
-                cfg, inputs, self.state, packed)
-        else:
-            if columns_in is None:
-                self.metrics["column_overflows_in"] += 1
-            else:
-                self.metrics["steps_columns_in"] += 1
-            self.state, back, pair, out_dense = node_step_columns(
-                cfg, lay, columns_in is not None, self.state, packed)
-            packed, readback = back + pair, lay.back
+        self.state, packed = node_step_packed(
+            cfg, inputs, self.state, packed)
 
         ctx = _TickCtx()
         ctx.submit_n, ctx.read_n = submit_n, read_n
@@ -2072,11 +2046,271 @@ class RaftNode:
         ctx.started = started
         ctx.staged_payloads, ctx.arrays = staged_payloads, arrays
         ctx.packed, ctx.readback = packed, readback
-        ctx.columns, ctx.out_dense = lay, out_dense
+        ctx.columns = ctx.out_dense = ctx.rows = None
         ctx.deferred_ae = None
         self._inflight_submit = self._inflight_submit + submit_n
         self._inflight_read = self._inflight_read + read_n
         return ctx
+
+    def _dispatch_rows(self, lay, arrival: bool, started: float,
+                       fetched) -> _TickCtx:
+        """``_dispatch`` from the host inbox on, for a shape that takes the
+        column step: HostInbox goes up as the ROWS of the lanes that have
+        something to say (a queued write, an offered read, a snapshot
+        done, a pending membership change or transfer, a compaction
+        grant, a durable tail that moved since the device was last told:
+        core/packing.py RowLayout) beside the device's resident zero
+        planes, and no fresh [G] plane is built, copied or uploaded.  A
+        step with more such lanes than the row buffer holds (an election
+        storm's durable tails), and the first step, upload the planes
+        whole as a packed step does: decided by the count, nothing cut."""
+        cfg = self.cfg
+        rl = lay.rows_in
+        # -- 1. host inbox, as (lanes, values) --------------------------------
+        with self._submit_lock:
+            # Offers already riding the pending (un-persisted) tick are
+            # subtracted: the device must never be offered the same
+            # queued entry twice (see _dispatch).
+            sub_ids = np.flatnonzero(self._queued_n)
+            sub_n = np.minimum(
+                self._queued_n[sub_ids] - self._inflight_submit[sub_ids],
+                cfg.max_submit).astype(np.int32)
+        keep = sub_n > 0
+        sub_ids, sub_n = sub_ids[keep], sub_n[keep]
+        offers = self._offer_reads()
+        read_ids = np.fromiter((g for g, _ in offers), np.int64, len(offers))
+        read_n = np.fromiter((n for _, n in offers), np.int32, len(offers))
+        read_veto = self._hold_read_veto(arrival)
+        snaps = list(self._install_snapshots(fetched))
+        with self._member_lock:
+            confs = [(g, ent[0], ent[1])
+                     for g, ent in self._conf_pending.items()]
+            xfers = [(g, ent[0]) for g, ent in self._xfer_pending.items()]
+        grant_ids = np.flatnonzero(self._compact_grant)
+        grants = self._compact_grant[grant_ids].astype(np.int32)
+        self._compact_grant[grant_ids] = 0
+        # Durability feedback (see _dispatch): a LEVEL, which the device
+        # keeps beside its state and the rows patch where the host's
+        # plane moved since it was last told (_dur_sent is what the
+        # device holds, lane for lane: the commit clamp of phase 10
+        # reads it).
+        durable = None
+        dur_ids = sub_ids[:0]
+        if "durable_tail" in rl.at:
+            durable = self._durable_tail_m if self._acked_tail is None \
+                else self._acked_tail
+            if self._dur_sent is not None:
+                dur_ids = np.flatnonzero(durable != self._dur_sent)
+        else:
+            # A serial node between failed barriers feeds no tail and the
+            # device keeps none: the next one it is fed goes up whole.
+            self._dur_sent = None
+        fields = [
+            ("submit_n", sub_ids, sub_n), ("read_n", read_ids, read_n),
+            ("compact_to", grant_ids, grants)]
+        if snaps:
+            at = np.asarray([g for g, *_ in snaps], np.int64)
+            fields += [
+                ("snap_done", at, True),
+                ("snap_idx", at, [idx for _, idx, _, _ in snaps]),
+                ("snap_term", at, [term for _, _, term, _ in snaps]),
+                ("snap_conf", at, [cw for _, _, _, cw in snaps])]
+        if confs:
+            at = np.asarray([g for g, _, _ in confs], np.int64)
+            fields += [("conf_voters", at, [v for _, v, _ in confs]),
+                       ("conf_learners", at, [ln for _, _, ln in confs])]
+        if xfers:
+            fields.append(("xfer_target",
+                           np.asarray([g for g, _ in xfers], np.int64),
+                           [t for _, t in xfers]))
+        # The count decides (a storm moves every lane's durable tail: no
+        # sort of 100,000 lanes to find that out).
+        said = [dur_ids] + [at for _, at, _ in fields]
+        whole = (durable is not None and self._dur_sent is None) \
+            or max(len(at) for at in said) > rl.K
+        if not whole:
+            ids = np.unique(np.concatenate(said))
+            whole = ids.size > rl.K
+        rows = rl.alloc()
+        view = rl.view(rows)
+        view.set_head("read_veto", read_veto)
+        view.set_head("clock", int(not arrival))
+
+        # -- 2. network inbox ------------------------------------------------
+        # The drained slices' messages as the columns they arrived as
+        # when every source fits the column buffers (nothing is then
+        # allocated, zeroed or walked P x G), else as zeroed dense planes
+        # that are views of the packed buffers (_dispatch).
+        batches, staged_payloads = self.acc.pop()
+        pair = lay.columns.alloc()
+        arrays = lay.columns.view(pair)
+        resident = ()
+        if fill_columns(batches, arrays):
+            if whole:
+                buffers = lay.host.alloc()
+                host = lay.host.unpack(buffers)
+            else:
+                buffers, host = (), None
+                resident = self._resident_zero(lay)
+            buffers += pair
+        else:
+            buffers = lay.inputs.alloc()
+            host, inbox = lay.inputs.unpack(buffers)
+            arrays = DenseView({name: getattr(inbox, name)
+                                for name in self.template})
+            scatter_dense(batches, arrays.planes)
+        self._fold_inbox_stats()
+        if self._hb_rounds:
+            self._hb_acknowledged(arrays)
+        if host is not None:
+            host.xfer_target[...] = NIL
+        if whole:
+            # The planes whole, written where they cross from: what
+            # _dispatch builds, from the same (lanes, values).
+            view.set_n(-1)
+            for name, at, vals in fields:
+                getattr(host, name)[at] = vals
+            if durable is not None:
+                np.copyto(host.durable_tail,
+                          np.minimum(durable, I32_SAFE_MAX),
+                          casting="unsafe")
+                self._dur_sent = durable.copy()
+        else:
+            n = ids.size
+            view.set_n(n)
+            view.ids[:n] = ids
+            view.field("xfer_target")[:n] = NIL
+            for name, at, vals in fields:
+                view.field(name)[np.searchsorted(ids, at)] = vals
+            if durable is not None:
+                # Every row says its lane's level, moved or not.
+                view.field("durable_tail")[:n] = np.minimum(
+                    durable[ids], I32_SAFE_MAX)
+                self._dur_sent[dur_ids] = durable[dur_ids]
+        buffers += rows
+
+        # -- 2b. upload ------------------------------------------------------
+        st = self._stages
+        st.enter("dispatch_upload")
+        packed = resident + jax.device_put(buffers)
+        columns_in = arrays.columns
+        st.note(transfers=len(buffers), bytes=sum(b.nbytes for b in buffers),
+                columns=columns_in or 0, dense=int(columns_in is None),
+                rows=0 if whole else int(ids.size), planes_dense=int(whole))
+        m = self.metrics
+        m["h2d_transfers"] += len(buffers)
+        m["column_overflows_in" if columns_in is None
+          else "steps_columns_in"] += 1
+        m["row_overflows_in" if whole else "steps_rows_in"] += 1
+
+        # -- 3. device step (async dispatch: no transfer, no block) ----------
+        # The step hands back its results twice: as the rows that moved
+        # (compacted by a program of their own, enqueued behind the step)
+        # and the outbox's columns, fetched, and whole (the carry's planes,
+        # the dense outbox), left on the device for the fetch of a step
+        # that does not fit them.
+        st.enter("dispatch_enqueue")
+        if self._carry is None:
+            self._carry = first_carry(lay)
+        elif durable is None and self._carry.durable is not None:
+            self._carry = self._carry._replace(durable=None)
+        last = self._carry
+        self.state, self._carry, out, out_dense = node_step_columns(
+            cfg, lay, columns_in is not None, self.state, last, packed)
+        back = compact_readback(lay, self._carry, last)
+
+        ctx = _TickCtx()
+        step = ctx.rows = _RowStep()
+        step.carry, step.down_ids = self._carry, None
+        step.up = self._up_planes.pop() if self._up_planes else (
+            np.zeros(cfg.n_groups, np.int32), np.zeros(cfg.n_groups, np.int32))
+        ctx.submit_n, ctx.read_n = step.up
+        step.sub_ids, step.sub_n = sub_ids, sub_n
+        step.read_ids, step.read_n = read_ids, read_n
+        ctx.submit_n[sub_ids] = sub_n
+        ctx.read_n[read_ids] = read_n
+        self._inflight_submit[sub_ids] += sub_n
+        self._inflight_read[read_ids] += read_n
+        ctx.timer = not arrival
+        ctx.started = started
+        ctx.staged_payloads, ctx.arrays = staged_payloads, arrays
+        ctx.packed, ctx.readback = back + out, lay.back
+        ctx.columns, ctx.out_dense = lay, out_dense
+        ctx.deferred_ae = None
+        return ctx
+
+    def _resident_zero(self, lay) -> tuple:
+        """The device's own copy of ``lay.host``'s planes holding no event
+        (zero; ``xfer_target`` NIL), uploaded once: what a step's rows are
+        written over."""
+        zero = self._resident.get(lay)
+        if zero is None:
+            buffers = lay.host.alloc()
+            lay.host.unpack(buffers).xfer_target[...] = NIL
+            zero = self._resident[lay] = tuple(jax.device_put(buffers))
+        return zero
+
+    def _offer_reads(self) -> List[Tuple[int, int]]:
+        """Tick thread, at a step's intake: the (lane, reads) this step
+        offers the device (``HostInbox.read_n``)."""
+        # Read plane: when a group's offer slot is free, promote ALL of
+        # its waiting batches into it as one offer — one read_n, one
+        # stamp, one barrier for every read the group holds.  What keeps
+        # that linearizable: only batches WAITING at this instant are
+        # merged, so every merged read was invoked before the step that
+        # stamps its ReadIndex is even dispatched; a read that arrives
+        # while an offer exists (offered, or riding the pending tick)
+        # waits for the next slot and never joins a stamped offer.  An
+        # unstamped offer (no free device slot / not leader yet) simply
+        # stays offered, as it is, and is re-offered next tick.  An offer
+        # riding the pending tick is masked out until that tick's harvest
+        # (an offer must reach the device exactly once per stamp attempt).
+        offers = []
+        with self._read_lock:
+            for g, q in self._reads_waiting.items():
+                if q and g not in self._reads_offered \
+                        and not self._inflight_read[g]:
+                    offer = _ReadOffer(list(q))
+                    q.clear()
+                    self._read_queued_n[g] -= offer.n
+                    self._reads_offered[g] = offer
+                    for b in offer.parts:
+                        if b.sink.span is not None:
+                            # The group's offer slot is won: submitted ->
+                            # offered was the wait for it
+                            # (lat_read_queue_s).
+                            b.sink.span.mark(OFFERED)
+            for g, offer in self._reads_offered.items():
+                if not self._inflight_read[g]:
+                    offers.append((g, offer.n))
+        return offers
+
+    def _hold_read_veto(self, arrival: bool) -> bool:
+        """Tick thread, at a step's intake: ``HostInbox.read_veto``."""
+        cfg = self.cfg
+        # Wall-clock pause detection (HostInbox.read_veto contract): a gap
+        # beyond read_fresh_ticks tick intervals invalidates stored lease
+        # evidence AND whatever acks queued in the inbox across the pause.
+        # The veto is HELD for read_fresh_ticks consecutive ticks, not one:
+        # pause-era acks still sitting in socket buffers drain through the
+        # reader threads into the accumulator over the FOLLOWING ticks too,
+        # and a single-tick veto would let receipt-anchored lease evidence
+        # resurrect from them one tick later (the tick clock did not
+        # advance during the pause, so the freshness bound alone cannot
+        # reject them).
+        wall = time.monotonic()
+        if self._tick_interval and self._last_tick_wall is not None:
+            gap = wall - self._last_tick_wall
+            if gap > self._tick_interval * max(cfg.read_fresh_ticks, 2):
+                self._read_veto_hold = max(cfg.read_fresh_ticks, 2)
+                self.metrics["read_vetoes"] += 1
+        read_veto = self._read_veto_hold > 0
+        if read_veto and not arrival:
+            # The hold is a length of TIME: read_fresh_ticks periods,
+            # not that many steps a few milliseconds apart.
+            self._read_veto_hold -= 1
+        self._last_tick_wall = wall
+        return read_veto
 
     def _hb_open(self, outbox, started: float) -> None:
         """Tick thread, at the fetch of the timer's step: the period's
@@ -2159,7 +2393,6 @@ class RaftNode:
         overlapped tick this runs AFTER that tick's host phase, so the
         wait here is whatever device time the host work did not cover;
         after a settled tick it is the whole step."""
-        cfg = self.cfg
         st = self._stages
         # The wait is split where the work happens: scan_device is the
         # device's remaining work on this tick's step, scan_fetch the
@@ -2172,40 +2405,78 @@ class RaftNode:
         packed = jax.block_until_ready(ctx.packed)
         st.enter("scan_fetch")
         fetched = jax.device_get(packed)
-        lay, outbox, extra = ctx.columns, None, ()
-        if lay is not None:
-            # A column step: the outbox came down as its columns, whose
-            # counts say whether they hold it.  If a row overflowed, the
-            # dense outbox still on the device is packed there and
-            # fetched as node_step_packed's is: the whole step goes
-            # dense, no column is cut.
-            fetched, pair = fetched[:-2], fetched[-2:]
-            outbox = lay.columns.view(pair)
-            if (outbox.n > lay.columns.K).any():
-                outbox = None
-                extra = jax.device_get(pack_outbox(lay, ctx.out_dense))
-                self.metrics["column_overflows_out"] += 1
-            else:
-                self.metrics["steps_columns_out"] += 1
-            ctx.out_dense = None
-            extra = pair + extra
+        ctx.packed = None
+        if ctx.columns is not None:
+            counts = self._fetch_rows(ctx, fetched)
+        else:
+            st.note(transfers=len(fetched),
+                    bytes=sum(b.nbytes for b in fetched),
+                    columns=0, dense=1, rows=0, planes_dense=1)
+            self.metrics["d2h_transfers"] += len(fetched)
+            st.enter("mirrors")
+            back = ctx.readback.unpack(fetched)
+            ctx.outbox = DenseView({name: getattr(back.outbox, name)
+                                    for name in self.template})
+            counts = self._mirrors_whole(ctx, back)
+        self._mirrors_tail(ctx, *counts)
+
+    def _fetch_rows(self, ctx: _TickCtx, fetched) -> tuple:
+        """``_fetch`` for a column step, from the fetched pairs on: the
+        outbox came down as its columns and the Readback's [G] planes as
+        the rows of the lanes that moved, and each pair's counts say
+        whether it holds its part.  A part that does not fit (a row of
+        the outbox beyond the column buffers; more lanes moved than the
+        row buffer holds: a storm, a step after a purge, the first) is
+        packed on the device from what the step left there and fetched
+        as node_step_packed's is: that part crosses whole, nothing is
+        cut."""
+        lay, st, m = ctx.columns, self._stages, self.metrics
+        rows = lay.rows_out.view(fetched[:2])
+        outbox = lay.columns.view(fetched[2:])
+        n_rows = rows.n
+        whole = n_rows > lay.rows_out.K or self._rows_whole_out
+        extra = ()
+        if whole:
+            extra += pack_readback(lay, ctx.rows.carry)
+            m["row_overflows_out"] += 1
+        else:
+            m["steps_rows_out"] += 1
+        if (outbox.n > lay.columns.K).any():
+            outbox = None
+            extra += pack_outbox(lay, ctx.out_dense)
+            m["column_overflows_out"] += 1
+        else:
+            m["steps_columns_out"] += 1
+        ctx.out_dense = ctx.rows.carry = None
+        extra = jax.device_get(extra)
         st.note(transfers=len(fetched) + len(extra),
                 bytes=sum(b.nbytes for b in fetched + extra),
                 columns=0 if outbox is None else outbox.columns,
-                dense=int(outbox is None))
-        self.metrics["d2h_transfers"] += len(fetched) + len(extra)
+                dense=int(outbox is None),
+                rows=0 if whole else n_rows, planes_dense=int(whole))
+        m["d2h_transfers"] += len(fetched) + len(extra)
         st.enter("mirrors")
-        back = ctx.readback.unpack(fetched)
-        ctx.packed = None
+        n_back = len(lay.back.buffers) if whole else 0
         if outbox is None:
-            dense = back.outbox if lay is None else \
-                lay.outbox.unpack(extra[2:])
+            dense = lay.outbox.unpack(extra[n_back:])
             outbox = DenseView({name: getattr(dense, name)
                                 for name in self.template})
-        h_info, h_heat = back.info, back.heat
+        ctx.outbox = outbox
+        if not whole:
+            return self._mirrors_rows(ctx, rows)
+        self._rows_whole_out = False
+        self._whole_back = back = lay.back.unpack(extra[:n_back])
+        return self._mirrors_whole(ctx, back)
+
+    def _mirrors_whole(self, ctx: _TickCtx, back) -> tuple:
+        """The mirrors of a step whose Readback came down whole: they are
+        the fetched planes, swapped in.  Returns ``_mirrors_tail``'s
+        arguments."""
+        cfg = self.cfg
+        h_info = back.info
         h_term, h_role, h_leader = back.term, back.role, back.leader_id
         h_commit, h_base = back.commit, back.base
-        ctx.info, ctx.outbox = h_info, outbox
+        ctx.info = h_info
         ctx.term, ctx.voted, ctx.role = h_term, back.voted_for, h_role
         ctx.leader, ctx.commit = h_leader, h_commit
         ctx.base, ctx.base_term = h_base, back.base_term
@@ -2219,14 +2490,8 @@ class RaftNode:
         # loudly with ~2^20 of headroom rather than wrap silently.  The
         # long-horizon story is snapshots + lane purge (index resets), not
         # wider lanes.
-        hi_lane = max(int(np.asarray(h_info.log_tail).max(initial=0)),
-                      int(h_term.max(initial=0)), self.timer_ticks)
-        if hi_lane >= I32_SAFE_MAX:
-            raise OverflowError(
-                f"node {self.node_id}: an int32 engine lane reached "
-                f"{hi_lane} (>= I32_SAFE_MAX {I32_SAFE_MAX}); a group "
-                "needs a snapshot + lane purge before its log index/term "
-                "wraps (see core/types.py)")
+        self._guard_i32(int(np.asarray(h_info.log_tail).max(initial=0)),
+                        int(h_term.max(initial=0)))
 
         old_role, was_ready = self.h_role, self.h_ready
         self.h_role, self.h_leader = h_role, h_leader
@@ -2235,14 +2500,165 @@ class RaftNode:
         self.h_ready = np.asarray(h_info.ready)
         self.metrics["elections"] += int(
             ((h_role == LEADER) & (old_role != LEADER)).sum())
+        self._leadership_lost(
+            np.nonzero((old_role == LEADER) & (h_role != LEADER))[0])
+
+        # Membership plane: refresh config mirrors, settle pending
+        # change/transfer futures, fold the tick's counters.
+        self._harvest_membership(h_info, h_role)
+
+        # -- CheckQuorum fold ------------------------------------------------
+        # Device 6c step-downs (a leader lost voter-quorum contact) and
+        # the lease reads they vetoed, folded into counters so a gray
+        # failure is visible on the ordinary /metrics page.  None
+        # subtrees when cfg.check_quorum is off.
+        if h_info.cq_stepdown is not None:
+            self._fold_checkquorum(int(np.asarray(h_info.cq_stepdown).sum()),
+                                   int(np.asarray(h_info.cq_veto).sum()))
+
+        # Open lanes for which this node neither leads ready nor knows a
+        # leader: what tells a store that is electing from one that is
+        # sick or overloaded.  Sampled every step, on /metrics and on the
+        # step's raft.mirrors span.
+        # The two halves apart: open lanes this node leads and is not
+        # ready on (``unready``: a fresh leader still waiting for its
+        # first majority, or a leader whose followers' windows timed out
+        # and cool down), and open lanes it follows without knowing whom.
+        led = h_role == LEADER
+        unready = self.h_active & led & ~self.h_ready
+        self._lane_counts = [
+            int(self.h_active.sum()), int(led.sum()), int(unready.sum()),
+            int((self.h_active & ~led & (h_leader == NIL)).sum())]
+        return back.heat, back.windows, lambda: (unready, was_ready)
+
+    def _mirrors_rows(self, ctx: _TickCtx, rows) -> tuple:
+        """The mirrors of a step whose Readback came down as rows: the
+        node's persistent planes, patched at the lanes that moved, and
+        every pass of ``_mirrors_whole`` made over those lanes alone.
+        ``ctx.info``'s level fields and the mirrors are views of the
+        persistent planes (the next fetch patches them: a step's host
+        phase always ends before the next fetch); its event fields are
+        persistent zero planes written here at the rows and cleared at
+        the same rows when the step's host phase is done (``_rows_done``).
+        Returns ``_mirrors_tail``'s arguments."""
+        cfg = self.cfg
+        rl = ctx.columns.rows_out
+        n = rows.n
+        ids = rows.ids[:n]
+        back = self._persistent_back(rl)
+        h_info = ctx.info = back.info
+        ctx.term, ctx.voted, ctx.role = back.term, back.voted_for, back.role
+        ctx.leader, ctx.commit = back.leader_id, back.commit
+        ctx.base, ctx.base_term = back.base, back.base_term
+        new = lambda name: rows.field(name)[:n]
+        self._guard_i32(int(new("info.log_tail").max(initial=0)),
+                        int(new("term").max(initial=0)))
+
+        # What the mirrors hold at the rows, before the patch.
+        act = self.h_active[ids]
+        old_role, old_ready = self.h_role[ids], self.h_ready[ids]
+        old_leader = self.h_leader[ids]
+        old_pending = self.h_conf_pending[ids]
+        old_conf_idx = self.h_conf_idx[ids]
+        # Client threads read h_role, h_leader and h_ready lane by lane
+        # while this patches them: the flags first, so that no lane reads
+        # as led before its readiness is the new step's.
+        words, flags = self._back_planes
+        flags[:, ids] = rows.flags[:, :n]
+        words[:, ids] = rows.words[:, :n]
+        ctx.rows.down_ids = ids
+        if cfg.debug_checks:
+            from ..core.step import raise_debug_violations
+            raise_debug_violations(h_info, f"node {self.node_id}")
+
+        role, ready, leader = new("role"), new("info.ready"), new("leader_id")
+        m = self.metrics
+        m["elections"] += int(((role == LEADER) & (old_role != LEADER)).sum())
+        self._leadership_lost(ids[(old_role == LEADER) & (role != LEADER)])
+
+        conf_idx = new("info.conf_idx")
+        m["membership_changes_entered"] += int(
+            (new("info.conf_app_idx") > 0).sum())
+        m["membership_changes_committed"] += int(
+            (old_pending & ~new("info.conf_pending")
+             & (old_conf_idx == conf_idx) & (conf_idx > 0)).sum())
+        m["timeout_now_sent"] += int(new("info.xfer_fired").sum())
+        self._settle_membership(h_info, back.role)
+        if h_info.cq_stepdown is not None:
+            self._fold_checkquorum(int(new("info.cq_stepdown").sum()),
+                                   int(new("info.cq_veto").sum()))
+
+        # The gauges' counts, kept running: each row takes its lane's old
+        # share out and puts its new one in.
+        if self._lane_counts is None:
+            self._lane_counts = self.count_lanes()
+        else:
+            counts = self._lane_counts
+            for sign, r, rd, ld in ((-1, old_role, old_ready, old_leader),
+                                    (1, role, ready, leader)):
+                led = r == LEADER
+                counts[1] += sign * int(led.sum())
+                counts[2] += sign * int((act & led & ~rd).sum())
+                counts[3] += sign * int((act & ~led & (ld == NIL)).sum())
+
+        def masks():
+            """``_track_unready``'s [G] masks, in the rare step that asks:
+            the lanes whose readiness changed are among the rows."""
+            was_ready = self.h_ready.copy()
+            was_ready[ids] = old_ready
+            return (self.h_active & (self.h_role == LEADER) & ~self.h_ready,
+                    was_ready)
+
+        return back.heat, rows.head("windows"), masks
+
+    def _persistent_back(self, rl):
+        """The Readback whose leaves are the node's persistent planes,
+        which the mirrors are views of while rows come down.  After a
+        step whose results came down whole (the mirrors are then that
+        fetch's planes) the levels are copied over from it, once."""
+        if self._back_planes is None:
+            self._back_planes = rl.planes()
+            self._back_tree = rl.unstack(*self._back_planes,
+                                         np.zeros(rl.H, np.int32))
+        back, stale = self._back_tree, self._whole_back
+        if stale is not None:
+            self._whole_back = None
+            rl.copy_levels(stale, *self._back_planes)
+            self.h_role, self.h_leader = back.role, back.leader_id
+            self.h_commit, self.h_base = back.commit, back.base
+            self.h_term = back.term
+            self.h_ready = back.info.ready
+            self.h_conf_word = back.info.conf_word
+            self.h_conf_idx = back.info.conf_idx
+            self.h_conf_pending = back.info.conf_pending
+        return back
+
+    def count_lanes(self) -> List[int]:
+        """[open lanes, lanes led, open led lanes not ready, open lanes
+        followed without a known leader], recounted over the mirrors:
+        what ``_fetch`` keeps running while rows come down."""
+        led = self.h_role == LEADER
+        return [int(self.h_active.sum()), int(led.sum()),
+                int((self.h_active & led & ~self.h_ready).sum()),
+                int((self.h_active & ~led & (self.h_leader == NIL)).sum())]
+
+    def _guard_i32(self, log_tail: int, term: int) -> None:
+        hi_lane = max(log_tail, term, self.timer_ticks)
+        if hi_lane >= I32_SAFE_MAX:
+            raise OverflowError(
+                f"node {self.node_id}: an int32 engine lane reached "
+                f"{hi_lane} (>= I32_SAFE_MAX {I32_SAFE_MAX}); a group "
+                "needs a snapshot + lane purge before its log index/term "
+                "wraps (see core/types.py)")
+
+    def _leadership_lost(self, lanes: np.ndarray) -> None:
         # Leadership lost: abort outstanding client promises BEFORE any
         # apply could complete them with a different command's result at
         # the same index (reference abortPromise on role change,
         # context/RaftContext.java:165-187).  The command may still commit
         # cluster-wide — NotLeader tells the client to re-check, the
         # standard Raft client contract.
-        for g in np.nonzero((old_role == LEADER) & (h_role != LEADER))[0]:
-            g = int(g)
+        for g in lanes.tolist():
             self.dispatcher.abort_promises(
                 g, NotLeaderError(g, self.leader_hint(g)))
             self._reject_submissions(g)
@@ -2252,10 +2668,16 @@ class RaftNode:
             # linearization point under any later leadership.
             self._reject_reads(g)
 
-        # Membership plane: refresh config mirrors, settle pending
-        # change/transfer futures, fold the tick's counters.
-        self._harvest_membership(h_info, h_role)
+    def _fold_checkquorum(self, n_down: int, n_veto: int) -> None:
+        if n_down:
+            self.metrics["checkquorum_stepdowns"] += n_down
+        if n_veto:
+            self.metrics["lease_vetoes"] += n_veto
 
+    def _mirrors_tail(self, ctx: _TickCtx, h_heat, windows, masks) -> None:
+        """What ``_fetch`` does once the mirrors are the step's, whichever
+        form they came down in."""
+        cfg, st = self.cfg, self._stages
         # -- flight-recorder drain -------------------------------------------
         # Opt-in with the recorder itself: decoded events feed per-group
         # timelines (HTTP /timeline) and the labeled metrics aggregate
@@ -2293,40 +2715,15 @@ class RaftNode:
                 m["heat_reads"] += d_rd
             m.gauge("heat_active_set", self.heat.active_set_size())
 
-        # -- CheckQuorum fold ------------------------------------------------
-        # Device 6c step-downs (a leader lost voter-quorum contact) and
-        # the lease reads they vetoed, folded into counters so a gray
-        # failure is visible on the ordinary /metrics page.  None
-        # subtrees when cfg.check_quorum is off.
-        if h_info.cq_stepdown is not None:
-            n_down = int(np.asarray(h_info.cq_stepdown).sum())
-            n_veto = int(np.asarray(h_info.cq_veto).sum())
-            if n_down:
-                self.metrics["checkquorum_stepdowns"] += n_down
-            if n_veto:
-                self.metrics["lease_vetoes"] += n_veto
-
         self.ticks += 1
         self.timer_ticks += int(ctx.timer)
         if ctx.timer:
-            self._hb_open(outbox, ctx.started)
-        # Open lanes for which this node neither leads ready nor knows a
-        # leader: what tells a store that is electing from one that is
-        # sick or overloaded.  Sampled every step, on /metrics and on the
-        # step's raft.mirrors span.
-        # The two halves apart: open lanes this node leads and is not
-        # ready on (``unready``: a fresh leader still waiting for its
-        # first majority, or a leader whose followers' windows timed out
-        # and cool down), and open lanes it follows without knowing whom.
-        led = h_role == LEADER
-        unready = self.h_active & led & ~self.h_ready
-        n_open, n_led = int(self.h_active.sum()), int(led.sum())
-        n_unready = int(unready.sum())
-        leaderless = n_unready + int(
-            (self.h_active & ~led & (h_leader == NIL)).sum())
+            self._hb_open(ctx.outbox, ctx.started)
+        n_open, n_led, n_unready, n_lost = self._lane_counts
+        leaderless = n_unready + n_lost
         # The leader's windows as the step left them (core/step.py
         # window_sums, reduced on the device).
-        win = back.windows.tolist()
+        win = windows.tolist()
         pairs, occupied, full, cooling, timed_out = win
         m = self.metrics
         m.gauge("groups_active", n_open)
@@ -2343,7 +2740,7 @@ class RaftNode:
                 win_full=full, win_cooling=cooling, win_timeouts=timed_out)
         was_unready, self._unready_n = self._unready_n, n_unready
         if n_unready or was_unready:
-            self._track_unready(unready, was_ready, ctx.timer, win)
+            self._track_unready(*masks(), ctx.timer, win)
 
     def _track_unready(self, unready: np.ndarray, was_ready: np.ndarray,
                        timer: bool, win: List[int]) -> None:
@@ -2499,8 +2896,28 @@ class RaftNode:
             # A mid-persist failure can instead re-offer an entry the
             # device already accepted — a client-retry-style duplicate,
             # strictly better than permanent starvation.
-            self._inflight_submit = self._inflight_submit - ctx.submit_n
-            self._inflight_read = self._inflight_read - ctx.read_n
+            if ctx.rows is None:
+                self._inflight_submit = self._inflight_submit - ctx.submit_n
+                self._inflight_read = self._inflight_read - ctx.read_n
+            else:
+                self._rows_done(ctx)
+
+    def _rows_done(self, ctx: _TickCtx) -> None:
+        """A column step's host phase is over: settle its offers lane by
+        lane and clear what the step wrote into the node's persistent
+        planes at the lanes it wrote (its offers; the events of the rows
+        that came down), so that the next step finds zero planes."""
+        step = ctx.rows
+        self._inflight_submit[step.sub_ids] -= step.sub_n
+        self._inflight_read[step.read_ids] -= step.read_n
+        ctx.submit_n[step.sub_ids] = 0
+        ctx.read_n[step.read_ids] = 0
+        self._up_planes.append(step.up)
+        if step.down_ids is not None:
+            rl = ctx.columns.rows_out
+            words, flags = self._back_planes
+            words[rl.Lw:, step.down_ids] = 0
+            flags[rl.Lf:, step.down_ids] = False
 
     def _lat_stamp(self, phase: int) -> None:
         """Stamp one lifecycle phase on every span the device accepted
@@ -3487,14 +3904,11 @@ class RaftNode:
     def _harvest_membership(self, info: StepInfo, h_role) -> None:
         """Tick thread: refresh config mirrors from StepInfo, resolve
         pending change/transfer futures, fold membership counters."""
-        from ..core.types import conf_pack
-
         conf_word = np.asarray(info.conf_word)
         conf_idx = np.asarray(info.conf_idx)
         conf_pending = np.asarray(info.conf_pending)
         app_idx = np.asarray(info.conf_app_idx)
         fired = np.asarray(info.xfer_fired)
-        x_abort = np.asarray(info.xfer_abort)
         m = self.metrics
         m["membership_changes_entered"] += int((app_idx > 0).sum())
         # A config entry COMMITTED when its pending flag clears at the
@@ -3507,6 +3921,18 @@ class RaftNode:
         self.h_conf_word = conf_word
         self.h_conf_idx = conf_idx
         self.h_conf_pending = conf_pending
+        self._settle_membership(info, h_role)
+
+    def _settle_membership(self, info: StepInfo, h_role) -> None:
+        """Tick thread, once the config mirrors are the step's: resolve
+        the pending change/transfer futures the step settles (a walk over
+        the pending operations, each reading its own lane)."""
+        from ..core.types import conf_pack
+
+        conf_word, conf_pending = info.conf_word, info.conf_pending
+        app_idx, fired = info.conf_app_idx, info.xfer_fired
+        x_abort = info.xfer_abort
+        m = self.metrics
         settled: List[Tuple[Future, Optional[Exception], object]] = []
         with self._member_lock:
             for g, ent in list(self._conf_pending.items()):
@@ -3669,6 +4095,9 @@ class RaftNode:
             # a negative delta for the recreated lane.
             for g in lanes:
                 self.heat.reset_group(int(g))
+        # The lanes' device state and the mirrors below change under the
+        # column step's rows: its next results come down whole.
+        self._rows_whole_out = True
         # device_get arrays may be read-only views; replace, don't mutate
         hc = np.array(self.h_commit)
         hb = np.array(self.h_base)

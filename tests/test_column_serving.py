@@ -26,7 +26,8 @@ import pytest
 import rafting_tpu.runtime.node as node_mod
 from rafting_tpu.api import RaftConfig, RaftContainer
 from rafting_tpu.core import packing
-from rafting_tpu.core.step import column_layouts, step_layouts
+from rafting_tpu.core.step import (
+    _host_from_rows, column_layouts, pack_readback, step_layouts)
 from rafting_tpu.core.types import EngineConfig, LEADER
 from rafting_tpu.testkit import linz
 from rafting_tpu.testkit.fixtures import NullProvider
@@ -42,8 +43,11 @@ from rafting_tpu.transport.inbox import fill_columns, scatter_dense
 from rafting_tpu.utils.profiling import StageSpans
 
 K = 3                       # columns a peer row: four led lanes overflow
+ROWS = 3                    # rows each way: four lanes at once overflow
 COUNTERS = ("steps_columns_in", "column_overflows_in",
-            "steps_columns_out", "column_overflows_out")
+            "steps_columns_out", "column_overflows_out",
+            "steps_rows_in", "row_overflows_in",
+            "steps_rows_out", "row_overflows_out")
 
 
 @pytest.fixture
@@ -55,6 +59,8 @@ def small_columns(monkeypatch):
     column_layouts.cache_clear()
     monkeypatch.setattr(packing, "CHUNK_BYTES", 2048)
     monkeypatch.setattr(packing, "COLUMNS", K)
+    monkeypatch.setattr(packing, "ROWS_IN", ROWS)
+    monkeypatch.setattr(packing, "ROWS_OUT", ROWS)
     yield
     step_layouts.cache_clear()
     column_layouts.cache_clear()
@@ -77,33 +83,48 @@ def noted(monkeypatch):
     return seen
 
 
-def assert_counters_match_spans(node, notes):
-    """The four counters are the spans' ``dense`` counted, and a dense
-    step carries no column count."""
+def assert_counters_match_spans(node, notes, maybe=()):
+    """The eight counters are the spans' ``dense`` and ``planes_dense``
+    counted, a dense step carries no column count and a step whose [G]
+    planes crossed whole no row count; every form was taken, and a span's
+    bytes and transfers are those of the forms it says."""
     m = node.metrics
     up, down = notes["dispatch_upload"], notes["scan_fetch"]
     assert m["steps_columns_in"] == sum(not s["dense"] for s in up)
     assert m["column_overflows_in"] == sum(s["dense"] for s in up)
     assert m["steps_columns_out"] == sum(not s["dense"] for s in down)
     assert m["column_overflows_out"] == sum(s["dense"] for s in down)
+    assert m["steps_rows_in"] == sum(not s["planes_dense"] for s in up)
+    assert m["row_overflows_in"] == sum(s["planes_dense"] for s in up)
+    assert m["steps_rows_out"] == sum(not s["planes_dense"] for s in down)
+    assert m["row_overflows_out"] == sum(s["planes_dense"] for s in down)
     assert all(s["columns"] == 0 for s in up + down if s["dense"])
-    assert all(m[name] > 0 for name in COUNTERS), \
+    assert all(s["rows"] == 0 for s in up + down if s["planes_dense"])
+    assert all(s["rows"] <= ROWS for s in up + down)
+    assert all(m[name] > 0 for name in COUNTERS if name not in maybe), \
         {name: m[name] for name in COUNTERS}
     assert sum(s["columns"] for s in up) > 0
     assert sum(s["columns"] for s in down) > 0
+    assert sum(s["rows"] for s in up) > 0
+    assert sum(s["rows"] for s in down) > 0
     lay = column_layouts(node.cfg, node.pipeline
                          or node._acked_tail is not None)
-    # A column step moves the [G] planes and one pair each way; a dense
-    # one the whole layouts (and, down, the pair that said so).
-    small = {s["bytes"] for s in up if not s["dense"]}
-    assert small == {sum(b.nbytes for b in lay.host.alloc())
-                     + lay.columns.nbytes}
-    assert {s["transfers"] for s in up if s["dense"]} \
-        == {len(lay.inputs.buffers)}
-    assert {s["transfers"] for s in down if not s["dense"]} \
-        == {len(lay.back.buffers) + 2}
-    assert {s["transfers"] for s in down if s["dense"]} \
-        == {len(lay.back.buffers) + 2 + len(lay.outbox.buffers)}
+    # Up: the messages as a column pair or as the dense operand (whose
+    # buffers hold HostInbox's planes too), HostInbox's planes beside a
+    # column pair only when they cross whole, and the row pair always.
+    # Down: the two pairs, and what either says it does not hold.
+    size = lambda layout: sum(b.nbytes for b in layout.alloc())
+    for s in up:
+        planes = lay.inputs if s["dense"] else lay.columns
+        extra = lay.host if s["planes_dense"] and not s["dense"] else None
+        assert s["bytes"] == size(planes) + lay.rows_in.nbytes \
+            + (size(extra) if extra else 0), s
+        assert s["transfers"] == len(planes.buffers) + 2 \
+            + (len(extra.buffers) if extra else 0), s
+    for s in down:
+        assert s["transfers"] == 4 \
+            + len(lay.back.buffers) * s["planes_dense"] \
+            + len(lay.outbox.buffers) * s["dense"], s
 
 
 # ------------------------------------------- (a) lock step, oracle-checked ----
@@ -119,24 +140,29 @@ def oracle_checked_columns(monkeypatch):
     real = node_mod.node_step_columns
     calls = {True: 0, False: 0}
 
-    def checked(cfg, lay, columns_in, state, buffers):
+    def checked(cfg, lay, columns_in, state, carry, buffers):
         bufs = jax.device_get(buffers)
+        bufs, rows = bufs[:-2], bufs[-2:]
         if columns_in:
             n_host = len(lay.host.buffers)
             host = lay.host.unpack(bufs[:n_host])
             inbox = lay.columns.expand(bufs[n_host:])
         else:
             host, inbox = lay.inputs.unpack(bufs)
+        # The HostInbox the rows stand for, over the planes they ride
+        # beside (the resident zero planes, or the planes whole).
+        host, _ = _host_from_rows(lay.rows_in, host, rows, None)
         host, inbox = jax.tree.map(jnp.asarray, (host, inbox))
         o_state, o_out, o_info = oracle_step(cfg, state, inbox, host)
-        k_state, back, pair, dense = real(cfg, lay, columns_in, state, buffers)
+        out = real(cfg, lay, columns_in, state, carry, buffers)
+        k_state, k_carry, pair, dense = out
         tag = f"oracle-checked column step #{sum(calls.values())}"
         assert_state_equal(k_state, o_state, tag)
         assert_messages_equal(lay.columns.unstack(dense), o_out, tag)
-        assert_info_equal(lay.back.unpack(jax.device_get(back)).info,
-                          o_info, tag)
+        assert_info_equal(lay.back.unpack(jax.device_get(pack_readback(
+            lay, k_carry))).info, o_info, tag)
         calls[bool(columns_in)] += 1
-        return k_state, back, pair, dense
+        return out
 
     monkeypatch.setattr(node_mod, "node_step_columns", checked)
     return calls
@@ -160,13 +186,21 @@ def test_column_steps_match_the_oracle_through_election_rounds_and_traffic(
                 g = int(led[t % len(led)])
                 n.submit_batch(g, [b"w%d" % t])
                 n.read(g, b"r%d" % t)
+            if t % 20 == 10:                # a write to every led lane at
+                for g in led.tolist():      # once: more than ROWS of them
+                    n.submit_batch(g, [b"burst%d" % t])
             c.tick()
         assert min(oracle_checked_columns.values()) > 20, \
             oracle_checked_columns
         assert sum(int(n.h_commit.astype(np.int64).sum())
                    for n in c.nodes.values()) > 0
         for i, n in c.nodes.items():
-            assert_counters_match_spans(n, noted[i])
+            # HostInbox overflows its rows on the node that leads more
+            # than ROWS lanes (a serial node uploads no durable tail).
+            assert_counters_match_spans(n, noted[i],
+                                        maybe=("row_overflows_in",))
+        assert sum(n.metrics["row_overflows_in"]
+                   for n in c.nodes.values()) > 0
     finally:
         c.close()
 
@@ -180,7 +214,7 @@ def served(tmp_path, small_columns):
     uris = [f"raft://127.0.0.1:{p}" for p in ports]
     cs = [RaftContainer(RaftConfig(
         local=u, peers=tuple(p for p in uris if p != u), n_groups=16,
-        log_slots=32, batch=4, max_submit=4, tick_ms=20, seed=3,
+        log_slots=32, batch=4, max_submit=4, tick_ms=50, seed=3,
         data_dir=str(tmp_path / f"node{i}"),
         election_mul=scaled_election_mul(10)), kv_factory()).create()
         for i, u in enumerate(uris)]
@@ -211,6 +245,16 @@ def test_served_cluster_over_columns_is_linearizable(noted, served):
         model[(i, k)] = v
         got = stubs[(i + 1) % 3][i].execute_read(_cmd("get", k), timeout=30)
         assert got == v, (i, got)
+    # A write to every group at once, through each member in turn: the
+    # member that leads four lanes or more (one does) finds more queued
+    # writes than HostInbox's rows hold and uploads its planes whole, and
+    # their commits move more lanes than the Readback's rows hold.
+    for r in range(6):
+        futs = [stubs[r % 3][i].submit(_cmd("set", "burst", f"b{r}-{i}"))
+                for i in range(12)]
+        for i, fut in enumerate(futs):
+            assert fut.result(timeout=30) == f"b{r}-{i}"
+            model[(i, "burst")] = f"b{r}-{i}"
     # Concurrent phase on one group: three recording clients, one a member.
     history = History()
     rec = [c.get_stub(names[0]).attach_history(history, f"c{i}")
@@ -261,7 +305,12 @@ def test_served_cluster_over_columns_is_linearizable(noted, served):
         if c.node._thread is not None:
             c.node._thread.join(timeout=30)
     for i, c in enumerate(cs):
-        assert_counters_match_spans(c.node, noted[c.node.node_id])
+        assert_counters_match_spans(c.node, noted[c.node.node_id],
+                                    maybe=("row_overflows_in",))
+    # (A serial node uploads no durable tail, so only the bursts overflow
+    # HostInbox's rows, on the member that leads most.)
+    assert sum(c.node.metrics["row_overflows_in"] for c in cs) > 0
+    assert all(c.node.metrics["row_overflows_out"] > 1 for c in cs)
 
 
 # --------------------------------------- (c) the drain's two destinations ----

@@ -13,10 +13,11 @@ import pytest
 
 from rafting_tpu.core.cluster import route
 from rafting_tpu.core import packing
-from rafting_tpu.core.packing import ColumnLayout, Layout
+from rafting_tpu.core.packing import ColumnLayout, Layout, RowLayout
 from rafting_tpu.core.step import (
-    COLUMN_BUFFERS, Readback, column_layouts, node_step, node_step_columns,
-    node_step_packed, pack_outbox, step_layouts,
+    COLUMN_BUFFERS, Readback, column_layouts, first_carry, node_step,
+    node_step_columns, node_step_packed, pack_outbox, pack_readback,
+    step_layouts,
 )
 from rafting_tpu.core.types import (
     EngineConfig, HostInbox, Messages, init_state,
@@ -263,6 +264,7 @@ def test_column_step_is_node_step_bit_for_bit(small_columns, columns_in,
     rng = np.random.default_rng(11)
     plain = [init_state(cfg, n, seed=3) for n in range(N)]
     cols = [init_state(cfg, n, seed=3) for n in range(N)]
+    carries = [first_carry(lay) for n in range(N)]
     outboxes = [jax.device_get(Messages.empty(cfg))] * N
     tails = [np.zeros(G, np.int32)] * N
     seen = dict(columns_in=0, dense_in=0, columns_out=0, overflow_out=0)
@@ -290,12 +292,16 @@ def test_column_step_is_node_step_bit_for_bit(small_columns, columns_in,
             else:
                 bufs = lay.inputs.pack((host, inbox))
                 seen["dense_in"] += 1
-            cols[n], back, pair, dense = node_step_columns(
-                cfg, lay, columns_in and fits, cols[n], bufs)
+            # HostInbox whole (its planes packed, a row pair that holds
+            # none): tests/test_plane_rows.py has the rows.
+            cols[n], carries[n], pair, dense = node_step_columns(
+                cfg, lay, columns_in and fits, cols[n], carries[n],
+                bufs + lay.rows_in.whole(host))
             tag = f"tick {t} node {n}"
             assert_trees_equal(cols[n], plain[n], tag)
             s = plain[n]
-            h_back = lay.back.unpack(jax.device_get(back))
+            h_back = lay.back.unpack(jax.device_get(
+                pack_readback(lay, carries[n])))
             assert_trees_equal(
                 h_back, Readback(
                     info=p_info, outbox=None, term=s.term,
@@ -408,6 +414,90 @@ def test_a_new_field_finds_its_place_in_the_columns(small_columns):
     np.testing.assert_array_equal(back["a_wide"], tree["a_wide"])
     with pytest.raises(TypeError, match="int32 or bool"):
         ColumnLayout({"x": np.zeros((P, G), np.float32)})
+
+
+# ---------------------------------------- (e) the [G] planes as rows ----
+
+
+@pytest.mark.parametrize("count", [0, 1, 4, 5, 300],
+                         ids=lambda c: f"{c}-rows")
+def test_row_counts_round_trip(count):
+    """0, 1, K, K + 1 and G moved lanes of a Readback made by hand (junk
+    everywhere, so every lane moves unless told not to): the device and
+    the host compact alike, the count is the true one also beyond K, the
+    first K lanes are kept ascending with every plane's value, the leaves
+    that are no planes ride the header, and writing what fits over the
+    planes it was compared with gives the planes back."""
+    G, K = 300, 4
+    rng = np.random.default_rng(count)
+    tree = {"info": {"log_tail": rng.integers(1, 99, G, dtype=np.int32),
+                     "ready": rng.random(G) < 0.5,
+                     "acc": np.zeros(G, np.int32),
+                     "abort": np.zeros(G, bool),
+                     "start": rng.integers(1, 99, G, dtype=np.int32)},
+            "term": rng.integers(1, 99, G, dtype=np.int32),
+            "sums": np.arange(5, dtype=np.int32), "veto": np.asarray(True)}
+    lay = RowLayout(tree, G, K, levels=["info.log_tail", "info.ready", "term"],
+                    carried=["info.start"])
+    assert (lay.W, lay.F, lay.H) == (4, 2, 6)
+    assert (lay.Lw, lay.Ew, lay.Lf, lay.Ef) == (2, 1, 1, 1)
+    assert lay.buffers == ((np.dtype(np.int32), 1 + 6 + K + 4 * K),
+                           (np.dtype(np.uint8), 2 * K))
+    # ``count`` lanes move: a level differs from what the other side
+    # holds, or an event happened.
+    at = np.sort(rng.choice(G, count, replace=False))
+    for i, g in enumerate(at):
+        if i % 6 == 2:
+            tree["info"]["acc"][g] = 7
+        elif i % 6 == 5:
+            tree["info"]["abort"][g] = True
+    words, flags, header = lay.stack(tree)
+    assert header.tolist() == [0, 1, 2, 3, 4, 1]
+    assert_trees_equal(lay.unstack(words, flags, header), tree)
+    prev_w, prev_f = words.copy(), flags.copy()
+    for i, g in enumerate(at):
+        if i % 3 == 0:
+            prev_w[rng.integers(lay.Lw), g] += 1
+        elif i % 3 == 1:
+            prev_f[0, g] ^= True
+    prev_w[lay.Lw:] = prev_f[lay.Lf:] = 0       # events are not kept
+    moved = lay.moved(words, flags, prev_w, prev_f)
+    np.testing.assert_array_equal(np.nonzero(moved)[0], at)
+    pair = lay.compact(words, flags, header, moved)
+    on_device = jax.jit(lambda w, f, h, pw, pf: lay.compact(
+        w, f, h, lay.moved(w, f, pw, pf)))(
+            *map(jnp.asarray, (words, flags, header, prev_w, prev_f)))
+    for a, b in zip(pair, on_device):
+        np.testing.assert_array_equal(a, np.asarray(b))
+    view = lay.view(pair)
+    assert view.n == count and view.head("sums").tolist() == [0, 1, 2, 3, 4]
+    assert bool(view.head("veto")) is True
+    held = min(count, K)
+    np.testing.assert_array_equal(view.ids[:held], at[:K])
+    assert (view.ids[held:] == G).all()
+    np.testing.assert_array_equal(view.field("info.start")[:held],
+                                  tree["info"]["start"][at[:K]])
+    np.testing.assert_array_equal(view.field("info.abort")[:held],
+                                  tree["info"]["abort"][at[:K]])
+    if count <= K:
+        # The levels the other side holds, patched by the rows, are the
+        # planes; so are zero event planes written at the rows.
+        base_w, base_f = prev_w.copy(), prev_f.copy()
+        base_w[lay.Lw + lay.Ew:] = words[lay.Lw + lay.Ew:]  # carried: as is
+        for got in (lay.expand(pair, base_w, base_f),
+                    jax.jit(lay.expand)(tuple(map(jnp.asarray, pair)),
+                                        jnp.asarray(base_w),
+                                        jnp.asarray(base_f))):
+            np.testing.assert_array_equal(np.asarray(got[0]), words)
+            np.testing.assert_array_equal(np.asarray(got[1]), flags)
+            np.testing.assert_array_equal(np.asarray(got[2]), header)
+    # A whole upload: no row, a count of -1 and the header.
+    whole = lay.view(lay.whole(tree))
+    assert whole.n == -1 and whole.head("sums").tolist() == [0, 1, 2, 3, 4]
+    with pytest.raises(ValueError, match="no \\[G\\] leaf"):
+        RowLayout(tree, G, K, levels=["sums"])
+    with pytest.raises(TypeError, match="int32 or bool"):
+        RowLayout({"x": np.zeros(G, np.float32)}, G, K)
 
 
 @pytest.mark.parametrize("config, columns", [

@@ -163,8 +163,9 @@ def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch,
     and the fetch say how many buffers crossed and how many bytes, and
     those are the layouts' own sizes; on a shape whose messages cross as
     columns (forced here by a small ``CHUNK_BYTES``; ``COLUMNS`` = G, so
-    nothing overflows) they are the [G] planes' buffers and one column
-    pair each way, and ``columns`` is the count that crossed."""
+    nothing overflows) they are one column pair and one row pair each
+    way, beside the [G] planes' buffers only where those crossed whole, and
+    ``columns`` and ``rows`` are the counts that crossed."""
     if columns:
         monkeypatch.setattr(packing, "CHUNK_BYTES", 512)
         monkeypatch.setattr(packing, "COLUMNS", _cfg().n_groups)
@@ -203,10 +204,18 @@ def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch,
     up, down = by_phase["dispatch_upload"], by_phase["scan_fetch"]
     assert (lay is not None) == columns
     if columns:
-        assert up["transfers"] == len(lay.host.buffers) + 2
-        assert down["transfers"] == len(lay.back.buffers) + 2
-        assert up["bytes"] == size(lay.host, lay.columns) > 0
-        assert down["bytes"] == size(lay.back, lay.columns) > 0
+        # A column pair and a row pair each way, and the [G] planes'
+        # buffers beside them only where a span says they crossed whole
+        # (``planes_dense``: rows is then 0).
+        rows = lambda layout: sum(
+            n * np.dtype(dt).itemsize for dt, n in layout.buffers)
+        for span, planes, pair in ((up, lay.host, lay.rows_in),
+                                   (down, lay.back, lay.rows_out)):
+            whole = span["planes_dense"]
+            assert whole in (0, 1) and span["rows"] <= pair.K * (1 - whole)
+            assert span["transfers"] == 4 + len(planes.buffers) * whole
+            assert span["bytes"] == size(lay.columns) + rows(pair) \
+                + size(planes) * whole > 0
         assert up["dense"] == down["dense"] == 0
         if fetched is not None:
             assert down["columns"] == fetched.columns
@@ -217,6 +226,8 @@ def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch,
         assert down["bytes"] == size(readback) > 0
         assert (up["dense"], up["columns"]) == (1, 0)
         assert (down["dense"], down["columns"]) == (1, 0)
+        assert (up["planes_dense"], up["rows"]) == (1, 0)
+        assert (down["planes_dense"], down["rows"]) == (1, 0)
         assert packing.CHUNK_BYTES >= max(up["bytes"], down["bytes"]) // max(
             up["transfers"], down["transfers"]) > 0
 

@@ -24,8 +24,8 @@ from jax.sharding import SingleDeviceSharding
 
 from rafting_tpu.core.packing import Layout
 from rafting_tpu.core.step import (
-    WINDOW_SUMS, column_layouts, node_step, node_step_columns,
-    node_step_packed, step_layouts)
+    WINDOW_SUMS, column_layouts, compact_readback, first_carry, node_step,
+    node_step_columns, node_step_packed, step_layouts)
 from rafting_tpu.core.types import HostInbox, Messages, init_state
 from rafting_tpu.ops.quorum import quorum_commit_pallas
 
@@ -210,19 +210,34 @@ def test_column_step_compiles_at_100k_lanes_and_addresses_k_rows(
             for dt, n in layout.buffers)
 
     state = _on(one_chip, jax.eval_shape(lambda: init_state(cfg, 0, seed=0)))
+    carry = _on(one_chip, jax.eval_shape(lambda: first_carry(lay)))
     operand = bufs(lay.host) + bufs(lay.columns) if columns_in \
         else bufs(lay.inputs)
     compiled = node_step_columns.lower(
-        cfg, lay, columns_in, state, operand).compile()
+        cfg, lay, columns_in, state, carry,
+        operand + bufs(lay.rows_in)).compile()
     mem = compiled.memory_analysis()
     per_node = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes)
     assert 3 * per_node < HBM_BYTES, mem
     found = _index_rows(compiled.as_text())
     print(f"K={K} {len(found)} gathers and scatters, "
-          f"most index rows {max(r for _, r in found)}")
-    most = max(lay.columns.Ws, lay.columns.Fs) * P * K
+          f"most index rows {max(r for _, r in found)}: {found}")
+    # The [G] planes' rows going in: one scatter a kind (the K_in rows of
+    # HostInbox over the resident planes), beside the columns' own.
+    rin, rout = lay.rows_in, lay.rows_out
+    most = max(lay.columns.Ws * P * K, lay.columns.Fs * P * K,
+               rin.W * rin.K)
     assert found and all(rows <= most < cfg.n_groups
                          for _, rows in found), found
-    assert len(found) <= 12, found      # not one a leaf: some 50 us each
-    assert ("scatter" in {op for op, _ in found}) == columns_in
+    assert len(found) <= 14, found      # not one a leaf: some 50 us each
+    assert "scatter" in {op for op, _ in found}
+    # ... and coming out, in the program of their own: one gather a kind
+    # (the K_out rows that moved) and the one-block-a-row gather of the
+    # search for them.
+    rows = compact_readback.lower(lay, carry, carry).compile()
+    found = _index_rows(rows.as_text())
+    print(f"compact_readback: {found}")
+    assert found and {op for op, _ in found} == {"gather"}, found
+    assert all(n <= rout.W * rout.K < cfg.n_groups for _, n in found), found
+    assert len(found) <= 3, found

@@ -25,8 +25,8 @@ from rafting_tpu.api.anomaly import NotReadyError
 from rafting_tpu.core import packing
 from rafting_tpu.core.cluster import route
 from rafting_tpu.core.step import (
-    WINDOW_SUMS, column_layouts, node_step_columns, node_step_packed,
-    step_layouts)
+    WINDOW_SUMS, column_layouts, first_carry, node_step_columns,
+    node_step_packed, pack_readback, step_layouts)
 from rafting_tpu.core.types import (
     EngineConfig, HostInbox, LEADER, Messages, conf_learners_of, conf_new_of,
     conf_voters_of, init_state)
@@ -302,14 +302,18 @@ def _packed_step(cfg):
 def _column_step(cfg):
     lay = column_layouts(cfg, True)
     assert lay is not None
+    # HostInbox and the Readback cross whole here (a row pair that holds
+    # none; pack_readback), so one carry serves every node of the test.
+    carry = [first_carry(lay)]
 
     def step(state, host, inbox):
         pair = lay.columns.compact(inbox)
         fits = bool((pair[0][:, 0] <= lay.columns.K).all())
         bufs = lay.host.pack(host) + pair if fits \
             else lay.inputs.pack((host, inbox))
-        state, back, _, dense = node_step_columns(cfg, lay, fits, state, bufs)
-        back = lay.back.unpack(jax.device_get(back))
+        state, carry[0], _, dense = node_step_columns(
+            cfg, lay, fits, state, carry[0], bufs + lay.rows_in.whole(host))
+        back = lay.back.unpack(jax.device_get(pack_readback(lay, carry[0])))
         return state, back._replace(outbox=lay.columns.unstack(dense))
     return step
 
