@@ -23,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from .types import (
     EngineConfig, FaultSchedule, HeatState, HostInbox, LogState, Messages,
-    QuorumContact, RaftState, StepInfo, TraceState,
+    LeaseGuard, QuorumContact, RaftState, StepInfo, TraceState,
 )
 
 # RaftState fields with no group axis: per-node scalars and the PRNG key.
@@ -35,7 +35,7 @@ _NODE_PEER_GROUP = PS("node", None, "group")  # [N, P, G, ...] message planes
 
 
 def state_pspecs(trace: bool = False, heat: bool = False,
-                 qc: bool = False) -> RaftState:
+                 qc: bool = False, lease: bool = False) -> RaftState:
     """A RaftState-shaped pytree of PartitionSpecs for stacked [N, ...] state.
 
     ``trace`` must match whether the state carries flight-recorder lanes
@@ -44,7 +44,8 @@ def state_pspecs(trace: bool = False, heat: bool = False,
     per-group lane.  ``heat`` likewise matches cfg.heat — heat lanes are
     plain [N, G] group-major counters — and ``qc`` matches
     cfg.check_quorum (contact lanes are [N, G, P] / [N, G], group-major
-    like the match matrix)."""
+    like the match matrix), ``lease`` whether the lease is carried
+    (cfg.lease_carry_ticks > 0: two [N, G] guard lanes)."""
     kw = {f.name: _NODE_GROUP for f in dataclasses.fields(RaftState)}
     for name in _STATE_NODE_ONLY:
         kw[name] = _NODE
@@ -59,6 +60,8 @@ def state_pspecs(trace: bool = False, heat: bool = False,
         reads=_NODE_GROUP) if heat else None
     kw["qc"] = QuorumContact(
         heard=_NODE_GROUP, since=_NODE_GROUP) if qc else None
+    kw["lease"] = LeaseGuard(
+        vote_hold=_NODE_GROUP, carry_bar=_NODE_GROUP) if lease else None
     return RaftState(**kw)
 
 
@@ -165,7 +168,8 @@ def shard_cluster(mesh: Mesh, cfg: EngineConfig, states: RaftState,
 
     states = put(states, state_pspecs(trace=states.trace is not None,
                                       heat=states.heat is not None,
-                                      qc=states.qc is not None))
+                                      qc=states.qc is not None,
+                                      lease=states.lease is not None))
     inflight = put(inflight, messages_pspecs())
     info = put(info, info_pspecs(qc=info.cq_stepdown is not None))
     conn = jax.device_put(conn, NamedSharding(mesh, CONN_PSPEC))

@@ -133,17 +133,23 @@ def run_cluster_ticks_nemesis(cfg: EngineConfig, states: RaftState,
     the read plane's lease safety argument is tested against.  The
     self-driving host policy (``auto_host_inbox``: slack compaction +
     instant snapshot service) is folded into the scan body, with a
-    stalled node's StepInfo frozen so its host half stalls with it.
+    stalled node's StepInfo frozen so its host half stalls with it, and
+    ``HostInbox.read_veto`` on the step it wakes in.
     """
     def body(carry, fault):
-        states, inflight, info = carry
+        states, inflight, info, slept = carry
         host = auto_host_inbox(cfg, states, submit_n, True, info, read_n)
+        # A node that sat out the last tick wakes with a clock that lags
+        # its peers': its host reads the pause off the wall clock and
+        # vetoes the lease evidence it holds (core/step.py phase 6b a).
+        host = host.replace(read_veto=slept & ~fault.stall)
         states, inflight, info = cluster_step_nemesis(
             cfg, states, inflight, host, info, fault)
-        return (states, inflight, info), ()
+        return (states, inflight, info, fault.stall), ()
 
-    (states, inflight, info), _ = jax.lax.scan(
-        body, (states, inflight, prev_info), sched)
+    (states, inflight, info, _), _ = jax.lax.scan(
+        body, (states, inflight, prev_info,
+               jnp.zeros_like(sched.stall[0])), sched)
     return states, inflight, info
 
 
@@ -215,7 +221,8 @@ def run_cluster_ticks_blocked(cfg: EngineConfig, n_ticks: int,
     st_specs, msg_specs, inf_specs = (
         state_pspecs(trace=states.trace is not None,
                      heat=states.heat is not None,
-                     qc=states.qc is not None), messages_pspecs(),
+                     qc=states.qc is not None,
+                     lease=states.lease is not None), messages_pspecs(),
         info_pspecs(qc=prev_info.cq_stepdown is not None))
     states_b = _to_blocks(states, st_specs, nb, gb)
     inflight_b = _to_blocks(inflight, msg_specs, nb, gb)

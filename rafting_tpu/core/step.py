@@ -41,7 +41,8 @@ Phase order within a tick (messages produced in tick t are delivered in t+1):
                            pending transfer fences submissions
   8. submissions         — leader accepts client commands into the log
   8b. read plane         — stamp ReadIndex batches, release on quorum
-                           barrier (lease fast path: same-tick evidence)
+                           barrier (lease fast path: evidence of this
+                           tick, or of the cfg.lease_carry_ticks before)
   8c. membership         — config-change intake (§6 joint consensus) +
                            automatic C_new leave once C_old,new commits
   9. replication         — leader builds AppendEntries / snapshot offers
@@ -325,6 +326,17 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # have detected leader silence (lease), log up-to-date, term ahead.  No
     # durable state changes.
     lease_open = (now >= elect_dl) | (leader_id == NIL)
+    # The carried lease (6b) leans on this refusal, so two more nodes
+    # keep it: one that restarted with a term on disk, for as long as a
+    # lease it may have acknowledged can last (case b: leader_id is NIL
+    # after a restart, which would open the vote at once), and a leader
+    # that still hears acknowledgements (its own elect_dl, which 6b
+    # refreshes and nothing else reads while it leads: it counts itself
+    # in every evidence quorum, so it promises what its followers do).
+    carry = cfg.lease_carry_ticks
+    guard = s.lease
+    if carry:
+        lease_open = lease_open & (now >= guard.vote_hold)
     grant_pv = (rv_v & pv & (inbox.rv_term > term[None, :]) & utd &
                 lease_open[None, :])
     out_rvr_valid = rv_v
@@ -686,7 +698,8 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     #   follower side holds as before, because its vote-denying lease
     #   (phase 2 lease_open) runs on ITS `now`, which its arrival steps do
     #   not move either: no node's clock runs faster than its timer.
-    #   Evidence of an earlier `now` never releases (evid < stamp).
+    #   Without a carried lease (below), evidence of an earlier `now`
+    #   never releases (evid < stamp).
     # * strict: an AE sent in an earlier step of this `now` echoes
     #   aer_tick == now although it left before a read offered in a
     #   later step of it: the echo would confirm nothing.  So strict
@@ -694,6 +707,70 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     #   (phase 8b stamp_open): that step is the first at its `now`, every
     #   AE carrying the stamp's value leaves in it or after it, and an
     #   offer made in a clock-0 step stays offered until the timer's.
+    #
+    # The carried lease (cfg.lease_carry_ticks = heartbeat_ticks - 1 > 0).
+    # With a heartbeat every h ticks a lane hears acknowledgements in one
+    # tick of h, so evidence of its own tick alone would leave the reads
+    # of the other h - 1 to a barrier round trip each.  Evidence stored at
+    # receipt tick r therefore releases batches stamped at r .. r + h - 1,
+    # and none stamped later (8b; the next round is due by then).  The
+    # anchoring stays the receipt: the echo bounds how old the
+    # acknowledgement can be, and a lock-step cluster, whose round trip is
+    # two ticks, would never see an echo-anchored lease of two.
+    #
+    # What the follower promised, in real time.  It processed our AE at
+    # an instant p, no earlier than the instant our clock turned the
+    # echoed send tick e >= r - read_fresh_ticks, and reset elect_dl to
+    # ITS now + [T, 2T) (T = election_ticks).  Until its clock has
+    # advanced T ticks it starts no pre-vote and grants none (phase 2),
+    # and with pre_vote on a candidacy above our term begins with a
+    # pre-vote majority, which must contain a member of our evidence
+    # quorum.  A clock advances once a timer step, timer steps are a
+    # period apart and at least half of one (a late step under
+    # tick_stagger is followed by the next grid point more than half a
+    # period away): k ticks take more than k - 2 periods.  The promise
+    # lasts more than T - 2 periods after p.
+    # What the lease covers, on our clock.  A batch stamped at s <= r +
+    # h - 1 is released by an AE sent in tick e >= s - (lease_ticks - 1),
+    # lease_ticks = h + read_fresh_ticks: from the turn of e to the stamp
+    # our clock passes through at most lease_ticks ticks.  If our timer
+    # is on time that is lease_ticks periods, and the host vetoes
+    # (host.read_veto: all evidence dropped) once the wall clock says the
+    # last lease_ticks ticks took more than lease_ticks + 1 periods
+    # (runtime/node.py _hold_read_veto).  So a stamp lies within
+    # lease_ticks + 1 periods of p, and the lease is sound when
+    #
+    #     lease_ticks + 1 <= T - 2, i.e. h + read_fresh_ticks + 3 <= T
+    #
+    # (EngineConfig.lease_carry_ticks: 0 where this fails, with its
+    # arithmetic).  Four ways the lease could still outrun the promise,
+    # each closed here and each a test (tests/test_lease_carry.py):
+    # (a) our loop stood still, so our `now` lags the followers' clocks:
+    #     the veto above (whoever steps nodes by hand is the host: a
+    #     node that sat out a step is given read_veto when it wakes).
+    # (b) a follower that acknowledged restarts inside the lease and comes
+    #     back with leader_id NIL, which opens its vote: a node that
+    #     recovers a term holds its pre-vote for lease_hold_ticks =
+    #     lease_ticks + 3 ticks, more than lease_ticks + 1 periods
+    #     (LeaseGuard.vote_hold; nothing on a first boot).
+    # (c) a leadership transfer: TimeoutNow makes its target a candidate
+    #     at once, with no pre-vote asked, so nobody's promise stands in
+    #     the way.  The step that fires it drops the stored evidence
+    #     (phase 9) and bars the carry for two election timeouts
+    #     (LeaseGuard.carry_bar), the longest the target's candidacy can
+    #     stay open on its timer: until then evidence releases its own
+    #     tick only, as it always did.
+    # (d) a configuration under which the inequality fails, or pre_vote
+    #     off (a follower whose timer runs out bumps its term at once and
+    #     asks for real votes, which carry no such refusal): no carry.
+    # Two more members of an evidence quorum must keep the promise for it
+    # to mean anything.  WE are one: a leader counts itself, so while it
+    # hears acknowledgements it refuses pre-votes as a follower would
+    # (elect_dl, which nothing else reads on a leader, is pushed T ahead
+    # on every receipt below; a leader cut off from its acknowledgements
+    # still opens its vote after T, which is what frees a group from an
+    # outbound-only cut).  And a candidate whose election ran out asks
+    # for pre-votes again instead of bumping its term unasked (phase 7).
     read_evid = s.read_evid
     if cfg.read_lease:
         evid_hit = aer_r & ~self_hot & \
@@ -705,6 +782,9 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     read_evid = jnp.where(evid_hit, evid_val, read_evid)
     read_evid = jnp.where(host.read_veto, jnp.zeros_like(read_evid),
                           read_evid)
+    if carry:
+        elect_dl = jnp.where(evid_hit.any(axis=1),
+                             now + cfg.election_ticks, elect_dl)
 
     # Snapshot response: success means the follower now covers our offered
     # milestone — resume log replication from just past our floor (reference
@@ -789,7 +869,13 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # log stays quiet; it still grants votes and accepts AEs).
     voter_self = (jnp.right_shift(voters1 | vnew1, me) & 1) > 0
     expired = active & (now >= elect_dl) & (role != LEADER) & voter_self
-    if cfg.pre_vote:
+    if cfg.pre_vote and carry:
+        # The carried lease rests on pre-votes being asked (6b): a
+        # candidate whose election ran out asks again, as a follower
+        # would, instead of taking the next term unasked.
+        start_pre = expired
+        timer_cand = jnp.zeros((G,), jnp.bool_)
+    elif cfg.pre_vote:
         start_pre = expired & ((role == FOLLOWER) | (role == PRE_CANDIDATE))
         timer_cand = expired & (role == CANDIDATE)
     else:
@@ -900,12 +986,23 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # tick carries receipt == now == the fresh batch's stamp, so a
     # heartbeat-ack burst releases a same-tick read with zero extra round
     # trips — the lease fast path IS the general rule at its freshness
-    # limit.  Strict mode can only release on a later tick's echo.
-    n_rel, n_served = read_barrier_release(
-        voters1, vnew1, me, read_evid, rq_stamp, rq_head, rq_len, rq_n)
+    # limit.  Strict mode can only release on a later tick's echo.  A
+    # carried lease (6b) lets a receipt reach `carry` ticks further,
+    # unless a transfer has barred it (case c).
+    if carry:
+        n_rel, n_served, n_rel_own = read_barrier_release(
+            voters1, vnew1, me, read_evid, rq_stamp, rq_head, rq_len, rq_n,
+            jnp.where(now >= guard.carry_bar, carry, 0))
+    else:
+        n_rel, n_served = read_barrier_release(
+            voters1, vnew1, me, read_evid, rq_stamp, rq_head, rq_len, rq_n)
+        n_rel_own = n_rel
     rq_head = jnp.remainder(rq_head + n_rel, K)
     rq_len = rq_len - n_rel
     read_lease_hit = read_acc & (n_rel > 0) & (rq_len == 0)
+    # The fresh batch is the FIFO's last: evidence of this tick alone
+    # would have released fewer, so it went out on an earlier tick's.
+    read_carried = read_lease_hit & (n_rel_own < n_rel)
     # A batch left pending kicks an immediate barrier heartbeat (phase 9)
     # instead of waiting out the cadence: release latency is one round
     # trip, not heartbeat_ticks + one round trip.
@@ -1086,6 +1183,13 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
                  & (tgt_match >= log.last))
     out_tn_valid = (peer_ids[:, None] == xfer_to[None, :]) & xfer_fire[None, :]
     out_tn_term = jnp.broadcast_to(term[None, :], (P, G))
+    if carry:
+        # 6b case c: the target campaigns with no pre-vote asked, so what
+        # is stored stops releasing now and nothing is carried until its
+        # candidacy has run out on its timer.
+        read_evid = jnp.where(xfer_fire[:, None], 0, read_evid)
+        guard = guard.replace(carry_bar=jnp.where(
+            xfer_fire, now + 2 * cfg.election_ticks, guard.carry_bar))
 
     # Election broadcasts (PreVote at speculative term+1 carrying our log
     # position, reference Follower.prepareElection:223-279; RequestVote at
@@ -1306,6 +1410,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         trace=trace,
         heat=heat,
         qc=qc,
+        lease=guard,
     )
     outbox = Messages(
         ae_valid=out_ae_valid, ae_term=out_ae_term,
@@ -1339,6 +1444,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         read_acc=n_read, read_index=read_index_out,
         read_rel=n_rel, read_served=n_served,
         read_lease=read_lease_hit, read_abort=read_abort,
+        read_carried=read_carried, read_kick=read_kick,
         conf_app_idx=conf_app_idx, conf_app_term=conf_app_term,
         conf_app_word=conf_app_word,
         conf_word=w2, conf_idx=cidx2, conf_pending=cidx2 > commit,

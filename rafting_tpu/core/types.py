@@ -225,6 +225,51 @@ class EngineConfig:
     def majority(self) -> int:
         return self.n_peers // 2 + 1
 
+    # The carried lease (core/step.py phase 6b has the proof).  Nothing
+    # here is a setting: the lease's length follows from the heartbeat
+    # cadence, and whether it may be carried at all from the inequality
+    # between that length and the followers' vote-denying promise.
+    @property
+    def lease_ticks(self) -> int:
+        """The most ticks of the leader's clock that lie between the SEND
+        of an AppendEntries and the last tick its acknowledgement releases
+        reads in, both ends counted: the echo is at most
+        ``read_fresh_ticks`` old at receipt, and the receipt covers its
+        own tick and ``heartbeat_ticks - 1`` more."""
+        return self.heartbeat_ticks + self.read_fresh_ticks
+
+    @property
+    def lease_carry_ticks(self) -> int:
+        """How many ticks past its own lease evidence releases reads in:
+        ``heartbeat_ticks - 1`` (evidence reaches as far as the next
+        heartbeat round), or 0 (today's rule: its own tick only) where
+        the carried lease would not be sound.  It is sound when
+
+            heartbeat_ticks + read_fresh_ticks + 3 <= election_ticks
+
+        (``lease_ticks`` plus the one period of lateness the leader's
+        veto lets pass must fit the T - 2 periods that a follower's
+        promise of T ticks is certain to last; phase 6b), when
+        ``pre_vote`` is on (the promise is the refusal of PRE-votes: with
+        direct candidacies there is none) and with the lease itself
+        (``read_lease``).  ``RaftConfig`` as it ships (election 3) fails
+        it; TiKV's timing (heartbeat 2, election 10) holds it with 2 to
+        spare."""
+        carry = self.heartbeat_ticks - 1
+        sound = (self.read_lease and self.pre_vote
+                 and self.lease_ticks + 3 <= self.election_ticks)
+        return carry if sound and carry > 0 else 0
+
+    @property
+    def lease_hold_ticks(self) -> int:
+        """How long a node that restarts with a term on disk grants no
+        pre-vote (``LeaseGuard.vote_hold``): the lease it may have
+        acknowledged just before it went down lasts ``lease_ticks + 1``
+        periods at most (the veto), and ``k`` ticks of a clock take more
+        than ``k - 2`` periods.  Less than ``election_ticks`` wherever the
+        lease is carried at all; 0 where it is not."""
+        return self.lease_ticks + 3 if self.lease_carry_ticks else 0
+
 
 @struct.dataclass
 class LogState:
@@ -368,6 +413,35 @@ class QuorumContact:
                    since=jnp.zeros((n_groups,), I32))
 
 
+@struct.dataclass
+class LeaseGuard:
+    """Per-group lanes of the carried lease (``cfg.lease_carry_ticks`` >
+    0; None otherwise, so that a configuration whose lease is not carried
+    compiles the program it always did).  Both close a way in which a
+    leader's stored evidence could outlive the promise behind it
+    (core/step.py phase 6b, cases b and c); both are volatile.
+
+    ``vote_hold[g]``: no pre-vote is granted before this tick.  Set by a
+    restart that recovered a term (``crash_restart``, ``log/store.py
+    restore_raft_state``): the node may have acknowledged a heartbeat a
+    moment before it went down, and comes back with ``leader_id`` NIL,
+    which would open its vote at once.  0 on a first boot.
+
+    ``carry_bar[g]``: a leader's evidence releases reads of its own tick
+    only before this tick.  Set when TimeoutNow fires: the target's
+    candidacy asks no pre-vote, so no promise stands in its way.
+    """
+
+    vote_hold: jax.Array   # [G] int32 — own-clock tick (0 = none)
+    carry_bar: jax.Array   # [G] int32 — own-clock tick (0 = none)
+
+    @classmethod
+    def empty(cls, n_groups: int) -> "LeaseGuard":
+        # Two distinct buffers (donation: never alias donated leaves).
+        return cls(vote_hold=jnp.zeros((n_groups,), I32),
+                   carry_bar=jnp.zeros((n_groups,), I32))
+
+
 def trace_append(tr: TraceState, mask: jax.Array, kind: int,
                  tick, term, aux) -> TraceState:
     """Branchless masked append of one event kind across all groups.
@@ -489,7 +563,9 @@ class RaftState:
                               #   tick of the last fresh same-term AE ack;
                               #   without, the ECHOED send tick (aer_tick) —
                               #   acks to heartbeats sent at/after a stamp.
-                              #   0 = none this leadership.
+                              #   0 = none this leadership.  A receipt
+                              #   releases batches stamped in its tick and
+                              #   in the cfg.lease_carry_ticks after it.
     rq_idx: jax.Array         # [G, K] int32 — pending batch read indices
     rq_stamp: jax.Array       # [G, K] int32 — pending batch stamp ticks
     rq_n: jax.Array           # [G, K] int32 — reads per pending batch
@@ -509,6 +585,12 @@ class RaftState:
     # Quorum-contact lanes (cfg.check_quorum).  Same None-subtree
     # contract: a build without CheckQuorum compiles bit-identically.
     qc: Any = None            # Optional[QuorumContact]
+
+    # The carried lease's guards (cfg.lease_carry_ticks > 0).  Same
+    # None-subtree contract: a configuration whose lease is not carried
+    # (a 1-tick heartbeat, a short election timeout, no pre-vote)
+    # compiles bit-identically.
+    lease: Any = None         # Optional[LeaseGuard]
 
 
 @struct.dataclass
@@ -599,9 +681,18 @@ def crash_restart(cfg: EngineConfig, s: "RaftState") -> "RaftState":
     if qc is not None:
         qc = qc.replace(heard=jnp.zeros_like(qc.heard),
                         since=jnp.zeros_like(qc.since))
+    # A node that comes back with a term on disk may have acknowledged
+    # a heartbeat just before it went down: it grants no pre-vote until
+    # that lease has run out (phase 6b, case b).
+    lease = s.lease
+    if lease is not None:
+        lease = LeaseGuard(
+            vote_hold=jnp.where(s.term > 0, s.now + cfg.lease_hold_ticks, 0),
+            carry_bar=jnp.zeros_like(lease.carry_bar))
     return s.replace(
         trace=trace,
         qc=qc,
+        lease=lease,
         rng=rng,
         role=z(G),
         leader_id=jnp.full((G,), NIL, I32),
@@ -901,6 +992,14 @@ class StepInfo:
                               #   (leadership/term changed); the host fails
                               #   them with NotLeader — clients retry safely
                               #   (reads never enter the log)
+    read_carried: jax.Array   # [G] bool — that same-step release
+                              #   (read_lease) needed evidence of an EARLIER
+                              #   tick: the lease was carried
+                              #   (cfg.lease_carry_ticks); never set
+                              #   without read_lease
+    read_kick: jax.Array      # [G] bool — the batch stamped this step was
+                              #   left pending and asked for a barrier
+                              #   heartbeat (phase 9 sends it at once)
     # Membership plane outputs.
     conf_app_idx: jax.Array   # [G] int32 — index of the config entry THIS
                               #   node appended as leader this tick (0 =
@@ -951,6 +1050,8 @@ class StepInfo:
             read_acc=z(), read_index=z(), read_rel=z(), read_served=z(),
             read_lease=jnp.zeros((G,), jnp.bool_),
             read_abort=jnp.zeros((G,), jnp.bool_),
+            read_carried=jnp.zeros((G,), jnp.bool_),
+            read_kick=jnp.zeros((G,), jnp.bool_),
             conf_app_idx=z(), conf_app_term=z(), conf_app_word=z(),
             conf_word=z(), conf_idx=z(),
             conf_pending=jnp.zeros((G,), jnp.bool_),
@@ -1036,4 +1137,5 @@ def init_state(cfg: EngineConfig, node_id: int, seed: int = 0,
                if cfg.trace_depth else None),
         heat=(HeatState.empty(G) if cfg.heat else None),
         qc=(QuorumContact.empty(G, P) if cfg.check_quorum else None),
+        lease=(LeaseGuard.empty(G) if cfg.lease_carry_ticks else None),
     )

@@ -725,7 +725,17 @@ def restore_raft_state(cfg, node_id: int, store: LogStore, seed: int = 0):
                     bconf[g] = word
             if conf_idx[g] == 0:
                 conf_word[g] = bconf[g]
+    lease = state.lease
+    if lease is not None:
+        # A lane that recovers a term may have acknowledged a heartbeat a
+        # moment before the process went down: it grants no pre-vote
+        # until that lease has run out (core/step.py phase 6b, case b; the
+        # clock restarts at 0).  A first boot recovers no term and holds
+        # nothing.
+        lease = lease.replace(vote_hold=jnp.asarray(
+            np.where(term > 0, cfg.lease_hold_ticks, 0).astype(np.int32)))
     return state.replace(
+        lease=lease,
         conf_idx=jnp.asarray(conf_idx), conf_word=jnp.asarray(conf_word),
         term=jnp.asarray(term), voted_for=jnp.asarray(voted),
         commit=jnp.asarray(commit),

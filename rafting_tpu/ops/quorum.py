@@ -221,7 +221,7 @@ def quorum_commit_pallas(match_full, own_from, state_vec,
 # ------------------------------------------------------------ read barrier --
 
 def read_barrier_release(voters, voters_new, me, read_evid, rq_stamp,
-                         rq_head, rq_len, rq_n):
+                         rq_head, rq_len, rq_n, reach=None):
     """ReadIndex barrier for every group at once: how many pending read
     batches (FIFO from ``rq_head``) have a confirmed leadership quorum.
 
@@ -235,8 +235,16 @@ def read_barrier_release(voters, voters_new, me, read_evid, rq_stamp,
     releasable — but the cumulative-AND guard below keeps FIFO order
     even if a caller hands in unordered stamps.
 
+    ``reach`` ([G] int32): the carried lease (core/step.py phase 6b).
+    Evidence stored at tick ``e`` then confirms batches stamped up to
+    ``e + reach``: the set above becomes {p : read_evid[g, p] + reach[g]
+    >= s}, over real evidence only (0 stays "none"), and a third result
+    says how many batches evidence of its own tick alone (reach 0) would
+    have released.
+
     Returns ``(n_rel [G] int32, n_served [G] int32)``: batches released
-    and the total individual reads inside them.  This lives beside the
+    and the total individual reads inside them (with ``reach``: ``(n_rel,
+    n_served, n_rel_own)``).  This lives beside the
     commit kernel because it is the same shape of op — a quorum order
     statistic over the peer axis feeding a masked monotone update — and
     the Pallas treatment, if ever needed, would tile identically.
@@ -251,15 +259,24 @@ def read_barrier_release(voters, voters_new, me, read_evid, rq_stamp,
     # Evidence 0 means "none this leadership"; stamps are >= 1 (the tick
     # clock starts at 1), so the comparison needs no extra guard.
     self_hot = (jnp.arange(P, dtype=I32) == me)[None, None, :]
-    flags = (read_evid[:, None, :] >= st[:, :, None]) | self_hot  # [G,K,P]
     vb = _bits(voters, P)[:, None, :]
     nb = _bits(voters_new, P)[:, None, :]
-    ok_v = ((flags & vb).sum(axis=2)
-            >= vb.sum(axis=2) // 2 + 1)                         # [G, K]
-    ok_n = (flags & nb).sum(axis=2) >= nb.sum(axis=2) // 2 + 1
-    ok = pending & ok_v & ((voters_new == 0)[:, None] | ok_n)
-    rel = pending & (jnp.cumsum((~ok).astype(I32), axis=1) == 0)
-    return rel.sum(axis=1).astype(I32), (rel * n).sum(axis=1).astype(I32)
+
+    def released(covers):
+        flags = (covers[:, None, :] >= st[:, :, None]) | self_hot  # [G,K,P]
+        ok_v = ((flags & vb).sum(axis=2)
+                >= vb.sum(axis=2) // 2 + 1)                     # [G, K]
+        ok_n = (flags & nb).sum(axis=2) >= nb.sum(axis=2) // 2 + 1
+        ok = pending & ok_v & ((voters_new == 0)[:, None] | ok_n)
+        return pending & (jnp.cumsum((~ok).astype(I32), axis=1) == 0)
+
+    count = lambda rel: rel.sum(axis=1).astype(I32)
+    if reach is None:
+        rel = released(read_evid)
+        return count(rel), (rel * n).sum(axis=1).astype(I32)
+    rel = released(jnp.where(read_evid > 0, read_evid + reach[:, None], 0))
+    return (count(rel), (rel * n).sum(axis=1).astype(I32),
+            count(released(read_evid)))
 
 
 def contact_quorum(voters, voters_new, me, heard, since):

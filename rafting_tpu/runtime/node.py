@@ -297,14 +297,16 @@ class _ReadOffer:
     an offer whole or not at all, so there is no ``taken`` cursor; each
     part keeps its own sink, span and result list.  ``lease``: the
     step that stamped it also released it (StepInfo.read_lease), with no
-    ReadIndex round trip."""
+    ReadIndex round trip; ``carried``: on evidence of an earlier tick
+    (StepInfo.read_carried: the lease outlived the period it was
+    acknowledged in, core/step.py phase 6b)."""
 
-    __slots__ = ("parts", "n", "lease")
+    __slots__ = ("parts", "n", "lease", "carried")
 
     def __init__(self, parts: List[_ReadBatch]):
         self.parts = parts
         self.n = sum(len(b.payloads) for b in parts)
-        self.lease = False
+        self.lease = self.carried = False
 
 
 class _TickCtx:
@@ -600,6 +602,10 @@ class RaftNode:
         self._tick_interval: Optional[float] = None
         self._last_tick_wall: Optional[float] = None
         self._read_veto_hold = 0   # ticks of veto left after a pause
+        # A carried lease (cfg.lease_carry_ticks) is as long as
+        # cfg.lease_ticks of this node's clock: the wall-clock starts of
+        # that many timer steps, the newest last (_hold_read_veto).
+        self._tick_walls: deque = deque(maxlen=cfg.lease_ticks)
 
         # Membership plane (§6): pending change/transfer requests, offered
         # to the device every tick until accepted or failed (the device
@@ -943,6 +949,11 @@ class RaftNode:
         # each) and the queries that rode a barrier another call opened.
         self.metrics["read_barriers"] += 0
         self.metrics["reads_coalesced"] += 0
+        # Barriers released in the step that stamped them by evidence of
+        # an earlier tick (never more than read_lease_hits), and barrier
+        # heartbeats that a batch left pending asked for.
+        self.metrics["read_lease_carried"] += 0
+        self.metrics["read_kicks"] += 0
         # Maintenance a full log ring asked for ahead of the cadence
         # (snapshot/policy.py): checkpoints serialized, grants issued.
         self.metrics["ckpt_by_pressure"] += 0
@@ -2301,9 +2312,21 @@ class RaftNode:
         wall = time.monotonic()
         if self._tick_interval and self._last_tick_wall is not None:
             gap = wall - self._last_tick_wall
-            if gap > self._tick_interval * max(cfg.read_fresh_ticks, 2):
-                self._read_veto_hold = max(cfg.read_fresh_ticks, 2)
-                self.metrics["read_vetoes"] += 1
+            # A carried lease (core/step.py phase 6b, case a) is sound
+            # while the last cfg.lease_ticks ticks of this clock took no
+            # more than one period over their number: a loop that stood
+            # still, or ran late by that much in sum, has a `now` that
+            # lags the clocks the followers' promise runs on.  The
+            # starts are the timer steps', this one's included.
+            walls = self._tick_walls
+            if not arrival:
+                walls.append(wall)
+            late = (cfg.lease_carry_ticks and len(walls) == walls.maxlen
+                    and wall - walls[0]
+                    > self._tick_interval * (cfg.lease_ticks + 1))
+            if late or gap > self._tick_interval * max(cfg.read_fresh_ticks,
+                                                       2):
+                self.note_pause()
         read_veto = self._read_veto_hold > 0
         if read_veto and not arrival:
             # The hold is a length of TIME: read_fresh_ticks periods,
@@ -2312,17 +2335,37 @@ class RaftNode:
         self._last_tick_wall = wall
         return read_veto
 
-    def _hb_open(self, outbox, started: float) -> None:
+    def note_pause(self) -> None:
+        """For whoever steps the node by hand (no loop, so no wall clock
+        to see a pause on): the node has sat out one period or more.
+        What the loop's own detection does: stored lease evidence, and
+        what queued up meanwhile, is dropped for the veto's hold."""
+        self._read_veto_hold = max(self.cfg.read_fresh_ticks, 2)
+        self.metrics["read_vetoes"] += 1
+
+    def _hb_open(self, outbox, started: float, kicked=None) -> None:
         """Tick thread, at the fetch of the timer's step: the period's
         heartbeat round opens against every peer this step addresses an
         AppendEntries to.  The outbox stamps every lane with the engine's
         clock (``ae_tick``), which the acknowledgements echo; a node that
-        leads nothing opens nothing."""
+        leads nothing opens nothing.  Nor does a timer step in which no
+        lane's heartbeat was due (a heartbeat every second period leaves
+        such steps): entries for a write, or the barrier heartbeat of a
+        read left pending (``kicked``: StepInfo.read_kick), are a lane's
+        own traffic and no round, and ``hb_round_s`` stays a reading per
+        round."""
         clock, peers = None, set()
+        cadence = self.cfg.heartbeat_ticks > 1
+        if cadence and kicked is not None:
+            kicked = np.asarray(kicked)
         for p in range(self.cfg.n_peers):
             if p == self.node_id:
                 continue
             valid = outbox.row("ae_valid", p)
+            if cadence and valid.any():
+                valid = valid & (outbox.row("ae_n", p) == 0)
+                if kicked is not None:
+                    valid = valid & ~outbox.over(p, kicked)
             if valid.any():
                 peers.add(p)
                 if clock is None:
@@ -2718,7 +2761,7 @@ class RaftNode:
         self.ticks += 1
         self.timer_ticks += int(ctx.timer)
         if ctx.timer:
-            self._hb_open(ctx.outbox, ctx.started)
+            self._hb_open(ctx.outbox, ctx.started, ctx.info.read_kick)
         n_open, n_led, n_unready, n_lost = self._lane_counts
         leaderless = n_unready + n_lost
         # The leader's windows as the step left them (core/step.py
@@ -3653,8 +3696,16 @@ class RaftNode:
         read_rel = np.asarray(info.read_rel)
         read_abort = np.asarray(info.read_abort)
         read_lease = np.asarray(info.read_lease)
+        read_carried = np.asarray(info.read_carried)
         self.metrics["read_lease_hits"] += int(read_lease.sum())
+        self.metrics["read_lease_carried"] += int(read_carried.sum())
         stamped = np.nonzero(read_acc > 0)[0].tolist()
+        if stamped:
+            # Batches this step stamped and left pending: each asked for
+            # a barrier heartbeat of its own (phase 9).
+            kicks = int(np.asarray(info.read_kick).sum())
+            self.metrics["read_kicks"] += kicks
+            self._stages.note(kicks=kicks)
         released = np.nonzero(read_rel > 0)[0].tolist()
         aborted = np.nonzero(read_abort)[0].tolist()
         with self._read_lock:
@@ -3668,6 +3719,7 @@ class RaftNode:
                     (f"g={g}: device stamped {int(read_acc[g])} reads "
                      "beyond the offer")
                 b.lease = bool(read_lease[g])
+                b.carried = bool(read_carried[g])
                 self._reads_pending.setdefault(g, deque()).append(
                     (int(read_idx[g]), b))
                 m = self.metrics
@@ -3726,7 +3778,7 @@ class RaftNode:
         if not ready:
             return len(due)
         now = time.monotonic()
-        queries = lease_hits = 0
+        queries = lease_hits = lease_carried = 0
         for g, idx, offer in ready:
             machine = self.dispatcher.machine(g)
             rd = getattr(machine, "read", None)
@@ -3750,10 +3802,12 @@ class RaftNode:
             # (counter read_lease_hits, counted at the stamp), where it
             # served a query: never more than ``queries``.
             lease_hits += int(offer.lease and queries > served)
+            lease_carried += int(offer.carried and queries > served)
         self.metrics["reads_served"] += queries
         # What this tick served, on its raft.reads span.
         self._stages.note(queries=queries, barriers=len(ready),
-                          lease_hits=lease_hits)
+                          lease_hits=lease_hits,
+                          lease_carried=lease_carried)
         return len(due)
 
     def _reject_reads(self, g: int, exc: Optional[Exception] = None,

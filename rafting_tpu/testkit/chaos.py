@@ -313,6 +313,11 @@ class ChaosConductor:
         for i, node in list(self.cluster.nodes.items()):
             if self._stalled_until.get(i, -1) > self.t:
                 continue   # clock stall: the node's world freezes
+            if self._stalled_until.pop(i, None) is not None:
+                # It wakes: a loop would read the pause off the wall
+                # clock and veto its lease evidence; here the conductor
+                # is the host and says so.
+                node.note_pause()
             node.tick()
         self.t += 1
 

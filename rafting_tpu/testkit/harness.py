@@ -267,8 +267,11 @@ class LocalCluster:
                 self.net.set_conn(link[t])
                 self.net.set_dup(dup[t])
                 for i, node in list(self.nodes.items()):
-                    if not stall[t, i]:
-                        node.tick()
+                    if stall[t, i]:
+                        continue
+                    if t and stall[t - 1, i]:
+                        node.note_pause()   # the host says it slept
+                    node.tick()
                 if audit is not None:
                     audit(t)
         finally:

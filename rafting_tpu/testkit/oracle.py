@@ -198,6 +198,13 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         qc_heard = s["qc.heard"].copy()
         qc_since = s["qc.since"].copy()
 
+    # The carried lease (cfg.lease_carry_ticks; kernel phase 6b): how far
+    # a receipt reaches, and its two guard lanes.
+    carry = cfg.lease_carry_ticks
+    if carry:
+        vote_hold = s["lease.vote_hold"].copy()
+        carry_bar = s["lease.carry_bar"].copy()
+
     old_term = term.copy()
     old_voted = voted.copy()
     old_last = last.copy()
@@ -240,6 +247,7 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         "read_acc": zi(G), "read_index": zi(G),
         "read_rel": zi(G), "read_served": zi(G),
         "read_lease": zb(G), "read_abort": zb(G),
+        "read_carried": zb(G), "read_kick": zb(G),
         "conf_app_idx": zi(G), "conf_app_term": zi(G),
         "conf_app_word": zi(G),
         "conf_word": zi(G), "conf_idx": zi(G), "conf_pending": zb(G),
@@ -310,6 +318,9 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         if any(grant_rv):
             elect_dl[g] = now + rand_to[g]
         lease_open = now >= elect_dl[g] or leader_id[g] == NIL
+        if carry:
+            # A restart that recovered a term holds its pre-vote (6b b).
+            lease_open = lease_open and now >= int(vote_hold[g])
         for p in range(P):
             if rv_v[p]:
                 pv = bool(ib["rv_prevote"][p, g])
@@ -561,6 +572,10 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
             if cfg.read_lease:
                 if now - echoed <= cfg.read_fresh_ticks:
                     read_evid[g, p] = now
+                    if carry:
+                        # A leader that hears acknowledgements refuses
+                        # pre-votes as its followers do.
+                        elect_dl[g] = now + cfg.election_ticks
             else:
                 read_evid[g, p] = max(int(read_evid[g, p]), echoed)
         if h["read_veto"]:
@@ -610,7 +625,10 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         # Only voters campaign (§6; kernel phase 7 gate on C1).
         if (active[g] and now >= elect_dl[g] and role[g] != LEADER
                 and voter_self):
-            if cfg.pre_vote:
+            if cfg.pre_vote and carry:
+                # A candidate whose election ran out asks again.
+                start_pre = True
+            elif cfg.pre_vote:
                 if role[g] in (FOLLOWER, PRE_CANDIDATE):
                     start_pre = True
                 elif role[g] == CANDIDATE:
@@ -701,22 +719,35 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
             rq_len[g] += 1
             info["read_index"][g] = commit[g]
         info["read_acc"][g] = n_read
-        n_rel, n_served = 0, 0
-        for j in range(int(rq_len[g])):
-            slot = (int(rq_head[g]) + j) % K
-            flags = [p == me or int(read_evid[g, p]) >= int(rq_stamp[g, slot])
-                     for p in range(P)]
-            if not _dual_quorum(flags, voters1, vnew1):
-                break   # FIFO: an unreleasable batch blocks younger ones
-            n_rel += 1
-            n_served += int(rq_n[g, slot])
+        def released(reach):
+            """(batches, reads) a receipt releases when it confirms stamps
+            up to ``reach`` ticks past its own."""
+            n_rel, n_served = 0, 0
+            for j in range(int(rq_len[g])):
+                slot = (int(rq_head[g]) + j) % K
+                flags = [p == me or (int(read_evid[g, p]) > 0 and
+                                     int(read_evid[g, p]) + reach
+                                     >= int(rq_stamp[g, slot]))
+                         for p in range(P)]
+                if not _dual_quorum(flags, voters1, vnew1):
+                    break   # FIFO: an unreleasable batch blocks younger
+                n_rel += 1
+                n_served += int(rq_n[g, slot])
+            return n_rel, n_served
+        n_rel_own, n_served = released(0)
+        n_rel = n_rel_own
+        if carry and now >= int(carry_bar[g]):
+            n_rel, n_served = released(carry)
         rq_head[g] = (int(rq_head[g]) + n_rel) % K
         rq_len[g] -= n_rel
         info["read_rel"][g] = n_rel
         info["read_served"][g] = n_served
         info["read_lease"][g] = (n_read > 0 and n_rel > 0
                                  and int(rq_len[g]) == 0)
+        info["read_carried"][g] = (bool(info["read_lease"][g])
+                                   and n_rel_own < n_rel)
         read_kick = n_read > 0 and int(rq_len[g]) > 0
+        info["read_kick"][g] = read_kick
 
         # ---- 8c. membership-change intake + automatic joint leave ---------
         # (kernel phase 8c: one config entry per request — joint when the
@@ -858,6 +889,10 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         if fire:
             out["tn_valid"][xt, g] = True
             out["tn_term"][xt, g] = term[g]
+            if carry:
+                # Its target asks no pre-vote (6b c).
+                read_evid[g, :] = 0
+                carry_bar[g] = now + 2 * cfg.election_ticks
 
         if active[g] and (became_cand or start_pre):
             for p in range(P):
@@ -1018,4 +1053,7 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         })
     if has_qc:
         new_state.update({"qc.heard": qc_heard, "qc.since": qc_since})
+    if carry:
+        new_state.update({"lease.vote_hold": vote_hold,
+                          "lease.carry_bar": carry_bar})
     return new_state, out, info

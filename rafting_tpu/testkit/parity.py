@@ -108,7 +108,8 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
     infos = [None] * N
     partition_left = 0
     partition = None
-    stats = {"partitions": 0, "crashes": 0, "stalls": 0, "arrival_steps": 0}
+    stats = {"partitions": 0, "crashes": 0, "stalls": 0, "arrival_steps": 0,
+             "lease_reads": 0, "lease_carried": 0}
 
     for t in range(n_ticks):
         # --- chaos schedule: random drops plus occasional partitions -----
@@ -218,6 +219,9 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
             states[n] = k_state
             new_outboxes.append(k_out)
             infos[n] = k_info
+            stats["lease_reads"] += int(np.asarray(k_info.read_lease).sum())
+            stats["lease_carried"] += int(
+                np.asarray(k_info.read_carried).sum())
         outboxes = new_outboxes
 
     # The schedule must have actually elected leaders / committed entries.

@@ -31,8 +31,8 @@ GROUP = 1
 
 
 def _mk_cluster(tmp_path, lease=True, stale=False, seed=0,
-                transport="loopback"):
-    cfg = EngineConfig(read_lease=lease, **CFG_KW)
+                transport="loopback", **shape):
+    cfg = EngineConfig(read_lease=lease, **{**CFG_KW, **shape})
     root = str(tmp_path)
     return LocalCluster(
         cfg, root, seed=seed,
@@ -108,6 +108,32 @@ def test_mixed_nemesis_smoke_linearizable(tmp_path, lease):
         assert counts["ok"] >= 20, f"workload starved: {counts}"
         res = linz.check(history)
         assert res.ok, res.render()
+        _assert_replicas_converge(cluster)
+    finally:
+        cluster.close()
+
+
+def test_mixed_nemesis_linearizable_on_tikv_cadence(tmp_path):
+    """The same acceptance run on TiKV's cadence (a heartbeat every second
+    tick, an election timeout of ten), where a lane hears acknowledgements
+    one tick in two and the lease is carried over the other (core/step.py
+    phase 6b): partitions, restarts (a restarted follower holds its
+    pre-vote), stalls (the conductor tells the node it slept), transfers
+    (TimeoutNow ends the carry), and the history stays linearizable with
+    reads really served from carried evidence."""
+    cluster = _mk_cluster(tmp_path, lease=True, seed=13, heartbeat_ticks=2)
+    assert cluster.cfg.lease_carry_ticks == 1
+    try:
+        history, conductor = _soak(cluster, seed=13, ticks=140)
+        kinds = {a["kind"] for a in conductor.applied if "error" not in a}
+        assert kinds & {"stall"} and kinds & {"kill"}, kinds
+        assert kinds & {"churn_transfer"} and kinds & {"part", "asym_cut"}, kinds
+        counts = history.counts()
+        assert counts["ok"] >= 20, f"workload starved: {counts}"
+        res = linz.check(history)
+        assert res.ok, res.render()
+        assert sum(int(n.metrics["read_lease_carried"])
+                   for n in cluster.nodes.values()) > 0
         _assert_replicas_converge(cluster)
     finally:
         cluster.close()

@@ -82,3 +82,42 @@ def test_parity_heat_lanes_under_chaos():
     assert int(np.asarray(final.heat.appended).sum()) > 0
     assert int(np.asarray(final.heat.sent).sum()) > 0
     assert int(np.asarray(final.heat.commits).sum()) > 0
+
+
+# The final states of the heartbeat_ticks=1 case below, as the parent of
+# PR 39 (the commit before the carried lease) left them: sha256 over every
+# leaf of every node's state, in tree order.
+PARENT_DIGEST_HB1 = \
+    "d562be343027e877ccceb4b3a8616ebd48946f8b94a5c0ff082de6d4801bfc2e"
+
+
+@pytest.mark.parametrize("hb", [1, 2])
+def test_parity_tikv_timing_with_reads(hb):
+    """TiKV's timing (election 10) with reads in the schedule, restarts,
+    stalls, transfers and steps that do not advance the clock.  At a
+    1-tick heartbeat the lease is not carried and the run ends in the
+    states the parent ended in, bit for bit; at 2 it is carried (core/
+    step.py phase 6b), the oracle's read plane moves in lock step, leaf
+    for leaf, and some reads really ride carried evidence."""
+    import hashlib
+
+    import jax
+
+    cfg = EngineConfig(n_groups=8, n_peers=3, log_slots=16, batch=4,
+                       max_submit=4, election_ticks=10, heartbeat_ticks=hb,
+                       rpc_timeout_ticks=5)
+    assert cfg.lease_carry_ticks == hb - 1
+    states, stats = run_parity(23, n_ticks=90, cfg=cfg, crash_p=0.03,
+                               stall_p=0.04, xfer_p=0.02, arrival_p=0.3)
+    assert stats["lease_reads"] > 0 and stats["crashes"] > 0
+    if hb == 1:
+        assert stats["lease_carried"] == 0
+        assert all(s.lease is None for s in states)
+        h = hashlib.sha256()
+        for st in states:
+            for leaf in jax.tree.leaves(st):
+                h.update(np.ascontiguousarray(np.asarray(leaf)).tobytes())
+        assert h.hexdigest() == PARENT_DIGEST_HB1
+    else:
+        assert stats["lease_carried"] > 0
+        assert all(s.lease is not None for s in states)

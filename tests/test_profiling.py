@@ -530,3 +530,55 @@ def test_leaderless_gauge_counts_open_lanes_until_they_are_led(tmp_path):
             what="every lane led and known")
     finally:
         c.close()
+
+
+def test_lease_carried_and_kicks_ride_the_reads_span(tmp_path):
+    """PR 39's statistics.  ``raft.reads`` carries ``lease_carried``
+    (barriers released in the step that stamped them on evidence of an
+    EARLIER tick: never more than ``lease_hits``) on every step that
+    served a query and ``kicks`` (batches left pending, which ask for a
+    barrier heartbeat) on every step that stamped one; over a window both
+    sum to the counters ``read_lease_carried`` and ``read_kicks``.  At the
+    engine's shipped timing (heartbeat 3, election 10) a lane hears
+    acknowledgements one tick in three and the lease reaches the two
+    after it."""
+    import jax
+
+    cfg = EngineConfig(n_groups=16, n_peers=3)
+    assert cfg.lease_carry_ticks == 2
+    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1)
+    trace_dir = str(tmp_path / "trace")
+    names = ("read_lease_hits", "read_lease_carried", "read_kicks",
+             "reads_served")
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        c.tick(6)                      # the election's own traffic is over
+        before = [node.metrics[k] for k in names]
+        with jax.profiler.trace(trace_dir):
+            futs = []
+            for i in range(18):
+                if i == 9:
+                    # A vetoed step drops the evidence: until the next
+                    # round's acknowledgements the reads are left pending
+                    # and each kicks a heartbeat of its own.
+                    node.note_pause()
+                futs.append(node.read(0, b"q%d" % i))
+                c.tick()
+            c.tick(4)
+            assert all(f.done() and f.exception() is None for f in futs)
+        hits, carried, kicks, served = (
+            node.metrics[k] - was for k, was in zip(names, before))
+    finally:
+        c.close()
+    reads = [stats for name, stats in _raft_spans(trace_dir)
+             if name == "raft.reads" and stats["node"] == lead]
+    served_in = [s for s in reads if "queries" in s]
+    assert served == 18 and sum(s["queries"] for s in served_in) == 18
+    assert all(0 <= s["lease_carried"] <= s["lease_hits"] <= s["barriers"]
+               for s in served_in)
+    assert sum(s["lease_carried"] for s in served_in) == carried
+    assert sum(s["lease_hits"] for s in served_in) == hits
+    assert sum(s.get("kicks", 0) for s in reads) == kicks
+    assert 0 < carried < hits and kicks >= 1

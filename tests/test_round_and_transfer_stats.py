@@ -84,6 +84,35 @@ def test_hb_round_closes_once_a_period_on_a_node_that_leads(one_leader):
     assert "raft_hb_round_s" in lc.nodes[lead].metrics.render_prometheus()
 
 
+def test_a_timer_step_with_no_heartbeat_due_opens_no_round(tmp_path):
+    """A heartbeat every second period (TiKV's cadence): the periods in
+    between open no round and close none, whatever a lane sends of its own
+    in them (a write's entries here), so twenty periods close ten rounds
+    and ``hb_round_s`` stays a reading per round."""
+    lc = LocalCluster(_cfg(n_groups=1, heartbeat_ticks=2), str(tmp_path),
+                      seed=7)
+    try:
+        lead = lc.wait_leader(0)
+        node = lc.nodes[lead]
+        lc.tick_until(lambda: node.is_ready(0), what="leader ready")
+        lc.tick(6)
+        before = _closed(lc)[lead]
+        samples = node.metrics.histogram("hb_round_s").n
+        futs, off = [], 0
+        for t in range(20):
+            if int(node.state.now) + 1 < int(np.asarray(node.state.hb_due)[0]):
+                futs.append(node.submit(0, b"w%d" % t))     # no heartbeat due
+                off += 1
+            lc.tick()
+        assert off == 10
+        assert _closed(lc)[lead] - before == 10
+        assert node.metrics.histogram("hb_round_s").n - samples == 10
+        lc.tick(3)
+        assert all(f.done() and f.exception() is None for f in futs)
+    finally:
+        lc.close()
+
+
 def test_hb_round_is_absent_on_a_node_that_leads_nothing(one_leader):
     lc, lead = one_leader
     before = _closed(lc)        # a node may have led before the fixture's

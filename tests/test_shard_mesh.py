@@ -67,12 +67,16 @@ def test_sharded_matches_unsharded_bitexact():
     sh_states, _, sh_info = run_cluster_ticks(cfg, 64, s1, m1, i1,
                                               conn1, sub1)
 
+    assert ref_states.lease is not None      # heartbeat 3: a carried lease
     for f in dataclasses.fields(RaftState):
-        a = np.asarray(getattr(ref_states, f.name))
-        b = np.asarray(getattr(sh_states, f.name))
         if f.name == "log":
             continue
-        assert np.array_equal(a, b), f"state field {f.name} diverged"
+        # a plain lane, or a sub-state of lanes (the lease's guards)
+        a = jax.tree.leaves(getattr(ref_states, f.name))
+        b = jax.tree.leaves(getattr(sh_states, f.name))
+        assert len(a) == len(b) and all(
+            np.array_equal(np.asarray(x), np.asarray(y))
+            for x, y in zip(a, b)), f"state field {f.name} diverged"
     for f in dataclasses.fields(type(ref_states.log)):
         a = np.asarray(getattr(ref_states.log, f.name))
         b = np.asarray(getattr(sh_states.log, f.name))

@@ -119,25 +119,31 @@ def test_node_step_fits_one_chip_at_the_smoke_shape(one_chip, packed):
     assert 3 * per_node < HBM_BYTES, mem
 
 
+@pytest.mark.parametrize("config, carry", [("multiraft-10k-3v", 0),
+                                           ("multiraft-10k-3v-hb2", 1)])
 @pytest.mark.parametrize("packed", [False, True],
                          ids=["node_step", "node_step_packed"])
-def test_node_step_holds_no_gather_and_no_scatter(one_chip, packed):
+def test_node_step_holds_no_gather_and_no_scatter(one_chip, packed, config,
+                                                  carry):
     """The step addresses its rings, the read FIFO and the peer planes by
     compare-and-select along the axis (ops/select.py): at the shape of the
     10,000-Region cell, as its configuration file builds the engine, the
     chip's compiler is left with no gather and no scatter (35 of them
     were 8 of a step's 11 ms on the chip), and with no [G, K, L] one-hot
     among its temporaries (25,023,488 bytes of them before; the one-hot of
-    the AppendEntries build alone would be 61 MB)."""
+    the AppendEntries build alone would be 61 MB).  The same on TiKV's own
+    heartbeat, where the lease is carried (core/step.py phase 6b: a second
+    pass of the read barrier and two guard lanes)."""
     from rafting_tpu.api import RaftConfig
     with open(os.path.join(REPO, "benchmark", "configs",
-                           "multiraft-10k-3v.json")) as f:
+                           config + ".json")) as f:
         raft = json.load(f)["raft_config"]
     uris = [f"raft://127.0.0.1:{7001 + i}" for i in range(3)]
     cfg = RaftConfig(local=uris[0], peers=tuple(uris[1:]),
                      data_dir="unused", **raft).engine_config()
     assert (cfg.n_groups, cfg.n_peers, cfg.log_slots, cfg.batch,
             cfg.max_submit, cfg.read_slots) == (10_000, 3, 64, 8, 8, 4)
+    assert cfg.lease_carry_ticks == carry
 
     lowered = _lower_step(one_chip, cfg, packed)
     if packed:
