@@ -114,6 +114,15 @@ class ApplyDispatcher:
         # Lazily sized from the first commit array; always <= the machine's
         # true last_applied is the invariant that makes skipping safe.
         self._applied_arr: Optional[np.ndarray] = None
+        # Entries the mirror has moved by, over this dispatcher's life, and
+        # the lanes the last advance() left with commit past what is
+        # applied (a halted or retrying machine, a payload not yet local,
+        # ``max_per_group``), ascending: a caller that hands advance() the
+        # lanes whose commit moved hands it these too.  Both are only as
+        # good as the mirror: whoever lowers it behind advance()'s back
+        # (drop_machine) owes the next call every lane.
+        self.applied_total = 0
+        self.backlog = np.zeros(0, np.int64)
 
     @property
     def empty_skips(self) -> int:
@@ -284,17 +293,24 @@ class ApplyDispatcher:
 
     # -- the apply loop -----------------------------------------------------
 
-    def advance(self, commit: np.ndarray, max_per_group: int = 0) -> int:
+    def advance(self, commit: np.ndarray, max_per_group: int = 0,
+                lanes: Optional[np.ndarray] = None) -> int:
         """Apply newly committed entries.  `commit` is the [G] frontier;
-        `max_per_group` bounds work per call (0 = no bound).  Returns the
-        lanes visited (those whose commit lies past what is applied)."""
+        `max_per_group` bounds work per call (0 = no bound); `lanes`, where
+        given, are the only lanes looked at (ascending: those whose commit
+        moved since the last call, and ``backlog``).  Returns the lanes
+        visited (those whose commit lies past what is applied)."""
         mirror = self._applied_mirror(len(commit))
-        gs = np.nonzero(commit > mirror[:len(commit)])[0]
+        if lanes is None:
+            gs = np.nonzero(commit > mirror[:len(commit)])[0]
+        else:
+            gs = lanes[commit[lanes] > mirror[lanes]]
         retries = self._retry_counts
         for g in gs:
             g = int(g)
             if self._halted.get(g):
                 continue
+            was = int(mirror[g])    # before a first machine() sets it
             m = self.machine(g)
             apply_fn = m.apply
             applies_empty = bool(getattr(m, "applies_empty", False))
@@ -451,9 +467,17 @@ class ApplyDispatcher:
             # failed apply it simply stays behind and the lane is revisited
             # next tick.
             mirror[g] = idx - 1 if idx - 1 > before else before
+            self.applied_total += int(mirror[g]) - was
             if self._on_applied is not None and idx - 1 > before:
                 self._on_applied(g, idx - 1)
+        self.backlog = gs[commit[gs] > mirror[gs]] if len(gs) else gs
         return len(gs)
+
+    def applied_view(self, n_groups: int) -> np.ndarray:
+        """The apply frontier of lanes ``[0, n_groups)`` as advance() keeps
+        it: a view, good until the next advance(), for a caller that reads
+        it at a few lanes."""
+        return self._applied_mirror(n_groups)[:n_groups]
 
     def applied_frontier(self, n_groups: int) -> np.ndarray:
         out = np.zeros(n_groups, np.int32)

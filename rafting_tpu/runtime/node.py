@@ -357,8 +357,9 @@ class _RowStep:
         # lanes written into them at dispatch and the values there
         "up", "sub_ids", "sub_n", "read_ids", "read_n",
         # the lanes whose event planes the fetch wrote (None: the step's
-        # results came down whole and wrote none)
-        "down_ids",
+        # results came down whole and wrote none), and what those rows
+        # moved the sum of the commit plane by
+        "down_ids", "commit_delta",
     )
 
 
@@ -370,15 +371,23 @@ class _PersistPrep:
     staging it feeds is the expensive part."""
 
     __slots__ = (
-        "dirty_mask", "log_tail", "h_term", "h_voted",
+        # the lanes the plan was selected among (None: every lane) and
+        # those of them whose (term, ballot) moved; dirty and log_tail are
+        # the step's planes, read at lanes
+        "ids", "dirty", "stable_g", "log_tail", "h_term", "h_voted",
         "h_base", "h_base_term",
         "wrote", "wrote_l", "lo_l", "hi_l", "nsub_l", "sublo_l",
         "src_l", "term_l", "fr_valid", "fr_n", "fr_start",
         "fr_ents", "fr_cents", "own_by_g", "staged_payloads",
         "noop_g", "noop_idx", "noop_term", "lanes",
-        "conf_app", "conf_term", "conf_word",
-        "stable_mask", "sub_acc", "submit_n",
+        "conf_g", "conf_app", "conf_term", "conf_word",
+        "sub_acc", "submit_n",
     )
+
+
+def _at(plane: np.ndarray, lanes: Optional[np.ndarray]) -> np.ndarray:
+    """``plane`` over ``lanes`` (None: over every lane, the plane itself)."""
+    return plane if lanes is None else plane[lanes]
 
 
 class RaftNode:
@@ -906,6 +915,28 @@ class RaftNode:
         self._whole_back = None
         self._rows_whole_out = True
         self._lane_counts: Optional[List[int]] = None
+        # Open lanes this node leads (raft.maintain's ``led``), counted by
+        # every fetch beside _lane_counts.
+        self._led_open = 0
+        # What the host phase holds between steps so that a step whose
+        # Readback came down as rows can work from those rows alone
+        # (_host_lanes has the rule; a whole step of such a node rebuilds
+        # them, no other node keeps them): whether they are good, the sum
+        # of the commit plane, the lanes whose ring stands under pressure
+        # ([G] bool and their count), and each open lane's ring fill (-1:
+        # closed) with the lanes counted per fill (_ring_state).  The
+        # apply backlog is the dispatcher's; released reads wait in
+        # _reads_released.
+        self._host_sets_ok = False
+        self._commit_sum = 0
+        self._pressed_m: Optional[np.ndarray] = None
+        self._pressed_n = 0
+        self._fill_m: Optional[np.ndarray] = None
+        self._fill_hist: Optional[np.ndarray] = None
+        # Lanes the open host stage's selection passes have run over
+        # (_where and the sums beside it), folded into the stage's span
+        # and the counter host_lanes_scanned by _note_scanned.
+        self._scanned = 0
         # Per-peer outbox sections accumulated across a tick's packing
         # sites (the host phase's deferred/non-eager sections + the eager
         # AE pack) and flushed as ONE frame per peer at end of tick — the
@@ -945,6 +976,11 @@ class RaftNode:
                      "row_overflows_in", "row_overflows_out"):
             self.metrics[name] += 0
         self.metrics["hb_rounds_closed"] += 0
+        # Lanes the host phase's selection passes ran over (``scanned`` on
+        # raft.wal / apply / reads / maintain): n_groups a pass on a step
+        # that looks at every lane, the rows that moved on one that came
+        # down as rows.
+        self.metrics["host_lanes_scanned"] += 0
         # Read plane: offers the device stamped (one ReadIndex barrier
         # each) and the queries that rode a barrier another call opened.
         self.metrics["read_barriers"] += 0
@@ -1911,6 +1947,11 @@ class RaftNode:
             prev, self._pending = self._pending, None
             self._host_phase(prev)
             self._stages.enter("dispatch_intake")
+        if changes or fetched:
+            # Lanes open, close or are wiped, and an installed snapshot
+            # moves a durable tail, a floor and the apply frontier: the
+            # next host phase looks at every lane (_host_lanes).
+            self._host_sets_ok = False
         if changes:
             act = np.asarray(self.state.active).copy()
             purged = []
@@ -2572,6 +2613,7 @@ class RaftNode:
         self._lane_counts = [
             int(self.h_active.sum()), int(led.sum()), int(unready.sum()),
             int((self.h_active & ~led & (h_leader == NIL)).sum())]
+        self._led_open = int((self.h_active & led).sum())
         return back.heat, back.windows, lambda: (unready, was_ready)
 
     def _mirrors_rows(self, ctx: _TickCtx, rows) -> tuple:
@@ -2603,6 +2645,7 @@ class RaftNode:
         old_leader = self.h_leader[ids]
         old_pending = self.h_conf_pending[ids]
         old_conf_idx = self.h_conf_idx[ids]
+        old_commit = int(self.h_commit[ids].sum(dtype=np.int64))
         # Client threads read h_role, h_leader and h_ready lane by lane
         # while this patches them: the flags first, so that no lane reads
         # as led before its readiness is the new step's.
@@ -2610,6 +2653,8 @@ class RaftNode:
         flags[:, ids] = rows.flags[:, :n]
         words[:, ids] = rows.words[:, :n]
         ctx.rows.down_ids = ids
+        ctx.rows.commit_delta = \
+            int(new("commit").sum(dtype=np.int64)) - old_commit
         if cfg.debug_checks:
             from ..core.step import raise_debug_violations
             raise_debug_violations(h_info, f"node {self.node_id}")
@@ -2635,6 +2680,8 @@ class RaftNode:
         # share out and puts its new one in.
         if self._lane_counts is None:
             self._lane_counts = self.count_lanes()
+            self._led_open = int(
+                ((self.h_role == LEADER) & self.h_active).sum())
         else:
             counts = self._lane_counts
             for sign, r, rd, ld in ((-1, old_role, old_ready, old_leader),
@@ -2643,6 +2690,7 @@ class RaftNode:
                 counts[1] += sign * int(led.sum())
                 counts[2] += sign * int((act & led & ~rd).sum())
                 counts[3] += sign * int((act & ~led & (ld == NIL)).sum())
+                self._led_open += sign * int((act & led).sum())
 
         def masks():
             """``_track_unready``'s [G] masks, in the rare step that asks:
@@ -2845,10 +2893,18 @@ class RaftNode:
         the phase — nothing past the barrier (sends, future
         completions, reads) runs for this tick — and feeds the
         failure-response policy in ``_storage_fault``.  ``pre_tail``
-        snapshots the durable-tail mirror BEFORE any staging so the
-        policy knows exactly which per-group tails a failed barrier
-        left unconfirmed."""
-        pre_tail = self._durable_tail_m.copy()
+        is the durable-tail mirror BEFORE any staging, at the lanes the
+        staging can move (every lane, or the step's ``ids``: spans,
+        truncations and floors are selected among them), so the policy
+        knows exactly which per-group tails a failed barrier left
+        unconfirmed.
+
+        Every stage selects its work among ``ids`` (``_host_lanes``: the
+        rows that moved and the lanes the step offered on; None: among
+        all lanes, the same expressions over whole planes)."""
+        ids = self._host_lanes(ctx)
+        pre_tail = (ids, self._durable_tail_m.copy() if ids is None
+                    else self._durable_tail_m[ids])
         self._host_runs += 1
         G = self.cfg.n_groups
         st = self._stages
@@ -2862,7 +2918,7 @@ class RaftNode:
                 # the time up to the send boundary by the fsync's own
                 # seconds (observe=False).
                 _t0 = st.enter("wal", observe=False)
-                prep, fsync_s, blob_fn = self._persist(ctx)
+                prep, fsync_s, blob_fn = self._persist(ctx, ids)
                 self._watch_io(fsync_s)
                 if self._lat_tick:
                     # The Python step stamped STAGED before its barrier
@@ -2904,21 +2960,39 @@ class RaftNode:
                     # advance() completes promises (and the traced
                     # batch's ack) below.
                     self._lat.mark_committed(ctx.commit)
-                before = self.dispatcher.applied_frontier(G)
-                st.note(lanes=self.dispatcher.advance(ctx.commit))
-                after = self.dispatcher.applied_frontier(G)
-                m["applies"] += int((after - before).sum())
-                m["commits"] = int(ctx.commit.astype(np.int64).sum())
+                # The lanes whose commit moved are among the ids; a lane
+                # that an earlier advance() left behind is in its backlog.
+                disp = self.dispatcher
+                lanes = ids
+                if ids is not None and len(disp.backlog):
+                    lanes = np.union1d(ids, disp.backlog)
+                self._scanned += G if lanes is None else len(lanes)
+                done = disp.applied_total
+                st.note(lanes=disp.advance(ctx.commit, lanes=lanes))
+                m["applies"] += disp.applied_total - done
+                applied = disp.applied_view(G)
+                if ids is None:
+                    self._scanned += G
+                    self._commit_sum = int(ctx.commit.sum(dtype=np.int64))
+                else:
+                    self._commit_sum += ctx.rows.commit_delta
+                m["commits"] = self._commit_sum
+                # (The rejection sweep's pass, made behind the barrier,
+                # is counted here too.)
+                self._note_scanned()
                 st.enter("reads")
 
                 # -- 6b. read plane: stamped/released bookkeeping + serving --
-                st.note(lanes=self._harvest_reads(ctx.info)
-                        + self._serve_reads(after))
+                st.note(lanes=self._harvest_reads(ctx.info, ids)
+                        + self._serve_reads(applied, ids))
+                self._note_scanned()
                 st.enter("maintain")
 
                 # -- 7. maintain: checkpoints, compaction, snapshot downloads
-                self._maintain(after, ctx.base, ctx.term, ctx.timer)
-                self._snapshot_requests(ctx.info, ctx.base)
+                self._maintain(applied, ctx.base, ctx.term, ctx.timer,
+                               ids, keep=ctx.rows is not None)
+                self._snapshot_requests(ctx.info, ctx.base, ids)
+                self._note_scanned()
                 # The stages are observed at their boundaries; whichever
                 # phase follows (scan_device, tail, dispatch_intake) ends
                 # maintain.
@@ -2930,8 +3004,11 @@ class RaftNode:
                 skips = self.dispatcher.empty_skips
                 if skips:
                     m.gauge("empty_apply_skips", skips)
+                # The phase ran to its end: what it keeps for the next
+                # one is good (_host_lanes), on a node that keeps it.
+                self._host_sets_ok = ctx.rows is not None
             except (WalNoSpace, WalSyncError) as e:
-                self._storage_fault(e, pre_tail)
+                self._storage_fault(e, *pre_tail)
         finally:
             # This tick's offers are settled even on failure: leaking the
             # inflight counts would mask those groups from every future
@@ -2944,6 +3021,61 @@ class RaftNode:
                 self._inflight_read = self._inflight_read - ctx.read_n
             else:
                 self._rows_done(ctx)
+
+    def _host_lanes(self, ctx: _TickCtx) -> Optional[np.ndarray]:
+        """The lanes this step's host phase looks at, ascending, or None
+        for every lane.  THE rule of the phase, stated once: **a step
+        whose Readback came down as rows is worked from the rows that
+        moved, united with the lanes the step itself offered on, if the
+        last host phase ran to its end and nothing has moved the host's
+        planes behind the phases' backs since; every other step is
+        worked whole and rebuilds what the phases keep.**
+
+        Why the rows are enough: every event field of ``ctx.info`` is
+        zero outside them and every level differs from what the last
+        phase saw only there (_mirrors_rows), a refused offer sits on a
+        lane the step offered on, and what needs work on a lane whose
+        row did not move the phases keep as sets, each patched at the
+        ids: the apply backlog (machine/dispatch.py ``backlog``), the
+        released reads (``_reads_released``), the rings under pressure
+        and every open ring's fill (``_ring_state``), the commit sum.
+        A WAL floor still to push cannot hide outside the rows either:
+        a phase that ran to its end left ``h_base <= _wal_floor`` on
+        every lane.
+
+        What makes the next phase run whole (``_host_sets_ok`` False;
+        the planes themselves are good, only the sets are not): a phase
+        that did not reach its end (a failed barrier and everything
+        ``_storage_fault`` does about it, any other exception), a
+        lifecycle change (``h_active``, a purge, the dispatcher's
+        mirror), an installed snapshot (durable tail, floor and apply
+        frontier move at dispatch), a node's first phase.  While the
+        confirmed-tail clamp stands (``_acked_tail``: a barrier failed
+        and has not been made good, or a stripe is quarantined) every
+        phase runs whole."""
+        step = ctx.rows
+        ok, self._host_sets_ok = self._host_sets_ok, False
+        if step is None or step.down_ids is None or not ok \
+                or self._acked_tail is not None:
+            return None
+        return np.unique(np.concatenate(
+            (step.down_ids, step.sub_ids, step.read_ids)))
+
+    def _where(self, lanes: Optional[np.ndarray],
+               mask: np.ndarray) -> np.ndarray:
+        """One selection pass of the host phase: the lanes whose ``mask``
+        holds, ascending, where ``mask`` was taken over ``lanes`` (None:
+        over every lane)."""
+        self._scanned += len(mask)
+        hit = np.flatnonzero(mask)
+        return hit if lanes is None else lanes[hit]
+
+    def _note_scanned(self) -> None:
+        """A host stage is done: the lanes its selection passes ran over,
+        on its span and on /metrics."""
+        n, self._scanned = self._scanned, 0
+        self.metrics["host_lanes_scanned"] += n
+        self._stages.note(scanned=n)
 
     def _rows_done(self, ctx: _TickCtx) -> None:
         """A column step's host phase is over: settle its offers lane by
@@ -2984,7 +3116,7 @@ class RaftNode:
                                    out.dense("ae_prev_idx"),
                                    out.dense("ae_n"))
 
-    def _persist(self, ctx: _TickCtx
+    def _persist(self, ctx: _TickCtx, ids: Optional[np.ndarray] = None
                  ) -> Tuple[_PersistPrep, float, Optional[Callable]]:
         """The host phase's one varying step: make the tick's writes
         durable.  Returns the tick's persist plan, the seconds the fsync
@@ -3004,17 +3136,18 @@ class RaftNode:
         ack-after-fsync barrier are the same either way."""
         prep = None
         if self._native_wal and not self._poisoned_stripes:
-            prep = self._persist_prepare(ctx, for_stripes=True)
+            prep = self._persist_prepare(ctx, ids, for_stripes=True)
         if prep is not None:
             self._stages.note(lanes=prep.lanes)
             _stage_s, fsync_s = self._persist_stage_native(prep)
+            self._note_scanned()
             # The conf sidecar (dirty only when an adoption span
             # truncated recorded conf entries) flushes before any ack
             # leaves.
             self.store.conf_flush()
             self._barrier_ok()
             return prep, fsync_s, self._native_blob_fn
-        prep = self._persist_prepare(ctx)
+        prep = self._persist_prepare(ctx, ids)
         self._stages.note(lanes=prep.lanes)
         # NOTE: staging is NOT masked while stripes are quarantined — a
         # poisoned engine only buffers (its flush/fsync never run again),
@@ -3022,6 +3155,7 @@ class RaftNode:
         # they register as promises (hung futures).  The carve-out happens
         # at the barrier (_barrier) and at outbox packing (silence).
         need_sync = self._persist_stage(prep)
+        self._note_scanned()
         _t1 = self._stages.enter("fsync", observe=False)
         if self._lat_tick:
             self._lat_stamp(STAGED)
@@ -3094,7 +3228,8 @@ class RaftNode:
             self._io_slow = False
             self.metrics.gauge("io_slow", 0)
 
-    def _storage_fault(self, exc: Exception, pre_tail: np.ndarray) -> None:
+    def _storage_fault(self, exc: Exception, lanes: Optional[np.ndarray],
+                       pre_tail: np.ndarray) -> None:
         """Failure-response policy for a failed durability barrier —
         the principled taxonomy the storage nemesis exercises:
 
@@ -3113,8 +3248,10 @@ class RaftNode:
         nothing past the failed barrier (sends, future completions,
         read serving) ran, preserving ack-after-fsync — and the
         device-feed clamp ``_acked_tail`` pins the affected groups at
-        ``pre_tail`` so the scan can never self-ack a staged-but-
-        unsynced range into a commit."""
+        ``pre_tail`` (the durable tails before the phase staged
+        anything, at ``lanes``: every lane for None, else the lanes the
+        phase could move a tail on) so the scan can never self-ack a
+        staged-but-unsynced range into a commit."""
         G = self.cfg.n_groups
         poisoned = set(getattr(exc, "shards", ()) or ())
         nospace = set(getattr(exc, "nospace", ()) or ())
@@ -3131,9 +3268,10 @@ class RaftNode:
             unconfirmed = np.ones(G, bool)
         if self._acked_tail is None:
             self._acked_tail = self._durable_tail_m.copy()
-        np.copyto(self._acked_tail,
-                  np.minimum(self._acked_tail, pre_tail),
-                  where=unconfirmed)
+        at = slice(None) if lanes is None else lanes
+        held = self._acked_tail[at]
+        self._acked_tail[at] = np.where(
+            unconfirmed[at], np.minimum(held, pre_tail), held)
         self._sync_pending = True
         if nospace:
             if not self._io_backpressure:
@@ -3185,12 +3323,17 @@ class RaftNode:
 
     # ---------------------------------------------------------- persistence
 
-    def _persist_prepare(self, ctx: _TickCtx, for_stripes: bool = False
+    def _persist_prepare(self, ctx: _TickCtx,
+                         ids: Optional[np.ndarray] = None,
+                         for_stripes: bool = False
                          ) -> Optional[_PersistPrep]:
-        """Precompute one tick's persist inputs — change-detection masks,
-        the staged-frame metadata fancy-indexes, and the ONE lock'd
+        """Precompute one tick's persist inputs — the change-detected
+        lane lists (selected among ``ids``; None: among all lanes), the
+        staged-frame metadata fancy-indexes, and the ONE lock'd
         submission-queue pop — for ``_persist_stage`` or
-        ``_persist_stage_native`` to consume.
+        ``_persist_stage_native`` to consume.  Every list is ascending,
+        whichever lanes it was selected among: the WAL's per-shard record
+        order follows them.
 
         ``for_stripes=True`` (the plan is for the native engine, whose
         threads each own whole stripes) bails out (returns None) when
@@ -3205,14 +3348,14 @@ class RaftNode:
         h_term, h_voted, h_leader = ctx.term, ctx.voted, ctx.leader
         h_base, h_base_term = ctx.base, ctx.base_term
         staged_payloads, inbox_arrays = ctx.staged_payloads, ctx.arrays
-        dirty_mask = np.asarray(info.dirty)
         app_from = np.asarray(info.appended_from)
         app_to = np.asarray(info.appended_to)
         sub_start = np.asarray(info.submit_start)
         sub_acc = np.asarray(info.submit_acc)
-        wrote = np.nonzero(app_to > 0)[0]
+        wrote = self._where(ids, _at(app_to, ids) > 0)
         conf_app = np.asarray(info.conf_app_idx)
-        if for_stripes and bool((conf_app > 0).any()):
+        conf_g = self._where(ids, _at(conf_app, ids) > 0)
+        if for_stripes and len(conf_g):
             return None
         wrote_l = wrote.tolist()
         # Staged-frame metadata for the whole wrote set in three fancy
@@ -3240,8 +3383,9 @@ class RaftNode:
             fr_cents = None
 
         p = _PersistPrep()
-        p.dirty_mask = dirty_mask
-        p.log_tail = np.asarray(info.log_tail).astype(np.int64)
+        p.ids = ids
+        p.dirty = dirty = np.asarray(info.dirty)
+        p.log_tail = np.asarray(info.log_tail)
         p.h_term, p.h_voted = h_term, h_voted
         p.h_base, p.h_base_term = h_base, h_base_term
         p.submit_n, p.sub_acc = submit_n, sub_acc
@@ -3261,16 +3405,18 @@ class RaftNode:
         # (term, ballot) change detection (reference RaftMember ctor
         # persists first, context/member/RaftMember.java:25) — the store
         # writes + mirror updates happen in the stage.
-        p.stable_mask = dirty_mask & ((h_term != self._stable_term_m)
-                                      | (h_voted != self._stable_voted_m))
+        p.stable_g = self._where(ids, _at(dirty, ids) & (
+            (_at(h_term, ids) != _at(self._stable_term_m, ids))
+            | (_at(h_voted, ids) != _at(self._stable_voted_m, ids))))
         noop_arr = np.asarray(info.noop_idx)
         p.noop_idx = noop_arr
         p.noop_term = np.asarray(info.noop_term)
-        p.noop_g = np.nonzero(noop_arr > 0)[0].tolist()
+        p.noop_g = self._where(ids, _at(noop_arr, ids) > 0).tolist()
         # Lanes the persist step visits one by one in Python: a span per
         # written lane and per election no-op, a stable record per lane
         # whose (term, ballot) moved.
-        p.lanes = len(wrote_l) + len(p.noop_g) + int(p.stable_mask.sum())
+        p.lanes = len(wrote_l) + len(p.noop_g) + len(p.stable_g)
+        p.conf_g = conf_g.tolist()
         p.conf_app = conf_app
         p.conf_term = np.asarray(info.conf_app_term)
         p.conf_word = np.asarray(info.conf_app_word)
@@ -3400,11 +3546,10 @@ class RaftNode:
         the engine buffers ahead of the native call — the per-shard
         record order stays stable → entries → truncates → milestones,
         the same bytes either way)."""
-        st_changed = prep.stable_mask
+        moved = prep.stable_g
         h_term, h_voted = prep.h_term, prep.h_voted
-        if not st_changed.any():
+        if not len(moved):
             return False
-        moved = np.nonzero(st_changed)[0]
         put_batch = getattr(self.store, "put_stable_batch", None)
         if put_batch is not None:
             put_batch(moved.tolist(), h_term[moved].tolist(),
@@ -3412,8 +3557,8 @@ class RaftNode:
         else:
             for g in moved.tolist():
                 self.store.put_stable(g, int(h_term[g]), int(h_voted[g]))
-        self._stable_term_m[st_changed] = h_term[st_changed]
-        self._stable_voted_m[st_changed] = h_voted[st_changed]
+        self._stable_term_m[moved] = h_term[moved]
+        self._stable_voted_m[moved] = h_voted[moved]
         return True
 
     def _build_spans(self, prep: _PersistPrep) -> List[tuple]:
@@ -3530,14 +3675,14 @@ class RaftNode:
         # the tick's highest) — plus the sidecar record recovery rebuilds
         # the conf ring from.  The Python step only: prepare keeps
         # conf-bearing ticks from the native engine.
-        if (prep.conf_app > 0).any():
+        if prep.conf_g:
             conf_app, conf_term = prep.conf_app, prep.conf_term
             conf_word = prep.conf_word
-            for g in np.nonzero(conf_app > 0)[0].tolist():
-                spans.append((int(g), int(conf_app[g]), b"",
+            for g in prep.conf_g:
+                spans.append((g, int(conf_app[g]), b"",
                               _NOOP_LENS, int(conf_term[g])))
                 if put_conf is not None:
-                    put_conf(int(g), int(conf_app[g]), int(conf_word[g]))
+                    put_conf(g, int(conf_app[g]), int(conf_word[g]))
         return spans
 
     def _persist_stage(self, prep: _PersistPrep) -> bool:
@@ -3580,17 +3725,20 @@ class RaftNode:
         # Truncations: durable tail must not exceed the device tail.
         # Change-detected via the durable-tail mirror (shrinks happen only
         # on conflict/snapshot discard — rare).
-        shrunk = prep.dirty_mask & (self._durable_tail_m > prep.log_tail)
-        for g in np.nonzero(shrunk)[0].tolist():
+        ids = prep.ids
+        shrunk = self._where(ids, _at(prep.dirty, ids) & (
+            _at(self._durable_tail_m, ids) > _at(prep.log_tail, ids)))
+        for g in shrunk.tolist():
             self.store.truncate_to(g, int(prep.log_tail[g]))
             self._durable_tail_m[g] = prep.log_tail[g]
 
         # WAL floor follows the device compaction floor; the pushed-floor
         # mirror keeps this loop over only the groups that moved.
         h_base, h_base_term = prep.h_base, prep.h_base_term
-        floors = h_base > self._wal_floor
+        floors = self._where(ids, _at(h_base, ids)
+                             > _at(self._wal_floor, ids))
         wal_floors_moved = False
-        for g in np.nonzero(floors)[0].tolist():
+        for g in floors.tolist():
             self.store.set_floor(g, int(h_base[g]), int(h_base_term[g]))
             self._wal_floor[g] = h_base[g]
             if h_base[g] > self._durable_tail_m[g]:
@@ -3623,14 +3771,15 @@ class RaftNode:
         # span this tick never lifts the mirror past log_tail, so this
         # post-span mask equals _persist_stage's; the store applies the
         # rows verbatim (the caller owns the guard on this path).
-        shrunk = prep.dirty_mask & (self._durable_tail_m > prep.log_tail)
-        t_gs = np.nonzero(shrunk)[0]
-        t_tails = prep.log_tail[t_gs]
+        ids = prep.ids
+        t_gs = self._where(ids, _at(prep.dirty, ids) & (
+            _at(self._durable_tail_m, ids) > _at(prep.log_tail, ids)))
+        t_tails = prep.log_tail[t_gs].astype(np.int64)
         self._durable_tail_m[t_gs] = t_tails
         # WAL floor follows the device compaction floor (the store
         # re-checks its own wal-floor guard per row).
-        floors = prep.h_base > self._wal_floor
-        f_gs = np.nonzero(floors)[0]
+        f_gs = self._where(ids, _at(prep.h_base, ids)
+                           > _at(self._wal_floor, ids))
         f_idx = prep.h_base[f_gs].astype(np.int64)
         f_term = prep.h_base_term[f_gs].astype(np.int64)
         self._wal_floor[f_gs] = f_idx
@@ -3655,9 +3804,10 @@ class RaftNode:
         support/anomaly/).  Refusals carry no durability dependency, so
         they may precede the tick's fsync barrier.  Orchestrator-only
         (touches the submit lock and client futures)."""
-        rejected = np.nonzero((prep.submit_n > 0)
-                              & (prep.sub_acc < prep.submit_n)
-                              & (self.h_role != LEADER))[0]
+        ids = prep.ids
+        offered, took = _at(prep.submit_n, ids), _at(prep.sub_acc, ids)
+        rejected = self._where(ids, (offered > 0) & (took < offered)
+                               & (_at(self.h_role, ids) != LEADER))
         for g in rejected.tolist():
             self._reject_submissions(int(g))
 
@@ -3683,31 +3833,37 @@ class RaftNode:
 
     # ------------------------------------------------------------ read plane
 
-    def _harvest_reads(self, info: StepInfo) -> int:
+    def _harvest_reads(self, info: StepInfo,
+                       ids: Optional[np.ndarray] = None) -> int:
         """Tick thread: mirror the device read FIFO's transitions reported
         in StepInfo — offers the device STAMPED move to pending with
         their ReadIndex; pending offers whose barrier RELEASED move to
         released (FIFO, exactly read_rel of them); device-side ABORTS
         (leadership/term change dropped the whole FIFO) fail every
-        un-served batch as a retry-safe refusal.  Returns the lanes
+        un-served batch as a retry-safe refusal.  All of them are events:
+        selected among ``ids`` (None: among all lanes).  Returns the lanes
         visited."""
         read_acc = np.asarray(info.read_acc)
         read_idx = np.asarray(info.read_index)
         read_rel = np.asarray(info.read_rel)
-        read_abort = np.asarray(info.read_abort)
         read_lease = np.asarray(info.read_lease)
         read_carried = np.asarray(info.read_carried)
-        self.metrics["read_lease_hits"] += int(read_lease.sum())
-        self.metrics["read_lease_carried"] += int(read_carried.sum())
-        stamped = np.nonzero(read_acc > 0)[0].tolist()
+        lease, carried = _at(read_lease, ids), _at(read_carried, ids)
+        self._scanned += 2 * len(lease)
+        self.metrics["read_lease_hits"] += int(lease.sum())
+        self.metrics["read_lease_carried"] += int(carried.sum())
+        stamped = self._where(ids, _at(read_acc, ids) > 0).tolist()
         if stamped:
             # Batches this step stamped and left pending: each asked for
             # a barrier heartbeat of its own (phase 9).
-            kicks = int(np.asarray(info.read_kick).sum())
+            kick = _at(np.asarray(info.read_kick), ids)
+            self._scanned += len(kick)
+            kicks = int(kick.sum())
             self.metrics["read_kicks"] += kicks
             self._stages.note(kicks=kicks)
-        released = np.nonzero(read_rel > 0)[0].tolist()
-        aborted = np.nonzero(read_abort)[0].tolist()
+        released = self._where(ids, _at(read_rel, ids) > 0).tolist()
+        aborted = self._where(
+            ids, _at(np.asarray(info.read_abort), ids)).tolist()
         with self._read_lock:
             for g in stamped:
                 b = self._reads_offered.pop(g, None)
@@ -3742,7 +3898,8 @@ class RaftNode:
             self._reject_reads(g)
         return len(stamped) + len(released) + len(aborted)
 
-    def _serve_reads(self, applied: np.ndarray) -> int:
+    def _serve_reads(self, applied: np.ndarray,
+                     ids: Optional[np.ndarray] = None) -> int:
         """Tick thread: serve released batches whose ReadIndex the apply
         frontier covers.  Machine ``read`` runs here — the same
         single-writer thread as applies, so queries see a consistent
@@ -3751,9 +3908,17 @@ class RaftNode:
         # Columnar gate: one vector compare picks the groups whose apply
         # frontier reached a released batch's ReadIndex — the every-tick
         # walk over all groups holding a released deque was a per-group
-        # Python loop on the hot path.
-        G = len(applied)
-        due = np.nonzero(applied >= self._rel_min[:G])[0]
+        # Python loop on the hot path.  A step worked from its rows
+        # (``ids``) makes the compare over the lanes that hold a released
+        # batch, which is where _rel_min is not its sentinel.
+        lanes = None
+        if ids is not None:
+            with self._read_lock:
+                lanes = np.fromiter(self._reads_released, np.int64,
+                                    len(self._reads_released))
+            lanes.sort()
+        due = self._where(lanes, _at(applied, lanes)
+                          >= _at(self._rel_min[:len(applied)], lanes))
         if not len(due):
             return 0
         sentinel = np.iinfo(np.int64).max
@@ -4313,7 +4478,8 @@ class RaftNode:
     # -------------------------------------------------------------- maintain
 
     def _maintain(self, applied: np.ndarray, h_base, h_term,
-                  timer: bool) -> None:
+                  timer: bool, ids: Optional[np.ndarray] = None,
+                  keep: bool = False) -> None:
         """Checkpoints, compaction grants, WAL GC and the scrubber, once a
         period: on the host phase of the timer's tick (``timer``), with
         every cadence counted in ``timer_ticks``.  A step started for
@@ -4321,21 +4487,20 @@ class RaftNode:
         pressure asks for it (snapshot/policy.py ``pressed``: such a ring
         is due at once, and its release chain of save, harvest, grant
         and compaction moves a step at a time); every step notes how
-        full the fullest ring stands."""
+        full the fullest ring stands.  ``ids`` / ``keep``: _ring_state."""
         now = self.timer_ticks
         n_ckpt = n_pressed = lanes = 0
-        if timer or self.maintain.pressed(self.h_commit, h_base).any():
+        pressed, ring_used = self._ring_state(h_base, ids, keep)
+        if timer or pressed:
             n_ckpt, n_pressed, lanes = self._maintain_pass(
                 now, applied, h_base)
         # The fullest ring this node holds (the fsynced tail is the
         # tick's log tail once its host phase is here), on /metrics and
         # on this tick's raft.maintain span.
-        ring_used = int((self._durable_tail_m - h_base)[self.h_active]
-                        .max(initial=0))
         self.metrics.gauge("log_ring_used_max", ring_used)
         self._stages.note(
             ring_used=ring_used, ring_slots=self.cfg.log_slots,
-            led=int(((self.h_role == LEADER) & self.h_active).sum()),
+            led=self._led_open,
             checkpoints=n_ckpt, by_pressure=n_pressed, lanes=lanes)
         if not timer:
             return
@@ -4345,6 +4510,53 @@ class RaftNode:
         if self.scrub_interval_ticks \
                 and now % self.scrub_interval_ticks == 0:
             self._scrub_archive()
+
+    def _ring_state(self, h_base, ids: Optional[np.ndarray],
+                    keep: bool) -> Tuple[bool, int]:
+        """(does any log ring stand under pressure, the fullest open
+        ring's fill: ``durable tail - base``) once the step's writes are
+        staged.  Over whole planes for ``ids`` None, where a node whose
+        steps can come down as rows (``keep``) also files what it found:
+        the pressed lanes as a mask and their count, each open lane's
+        fill and the lanes counted per fill.  A step worked from its rows
+        patches those at ``ids`` (both operands of ``pressed`` are levels
+        that come down with a row; a durable tail moves only on a lane
+        whose row moved) and reads the answers off them: the fullest ring
+        is the highest occupied count.  A fill lies in 0..log_slots; one
+        beyond (a snapshot installed ahead of its base) is counted in one
+        more bucket, and while that one is occupied the maximum is taken
+        over the planes."""
+        slots = self.cfg.log_slots
+        tail, active = self._durable_tail_m, self.h_active
+        if ids is None:
+            pressed = self.maintain.pressed(self.h_commit, h_base)
+            fill = tail - h_base
+            self._scanned += 2 * len(fill)
+            if keep:
+                self._pressed_m = pressed
+                self._pressed_n = int(pressed.sum())
+                self._fill_m = np.where(
+                    active, np.clip(fill, 0, slots + 1), -1)
+                self._fill_hist = np.bincount(
+                    self._fill_m[active], minlength=slots + 2)
+            return bool(pressed.any()), int(fill[active].max(initial=0))
+        pressed = self.maintain.pressed(self.h_commit[ids], h_base[ids])
+        self._pressed_n += int(pressed.sum()) \
+            - int(self._pressed_m[ids].sum())
+        self._pressed_m[ids] = pressed
+        fill = np.where(active[ids],
+                        np.clip(tail[ids] - h_base[ids], 0, slots + 1), -1)
+        was, hist = self._fill_m[ids], self._fill_hist
+        np.subtract.at(hist, was[was >= 0], 1)
+        np.add.at(hist, fill[fill >= 0], 1)
+        self._fill_m[ids] = fill
+        self._scanned += 2 * len(ids)
+        top = np.flatnonzero(hist)
+        ring_used = int(top[-1]) if len(top) else 0
+        if ring_used > slots:
+            self._scanned += len(tail)
+            ring_used = int((tail - h_base)[active].max(initial=0))
+        return self._pressed_n > 0, ring_used
 
     def _maintain_pass(self, now: int, applied: np.ndarray, h_base
                        ) -> Tuple[int, int, int]:
@@ -4372,6 +4584,10 @@ class RaftNode:
         need = self.maintain.need_checkpoint(now, applied, h_base)
         pressed = self.maintain.ckpt_pressed
         n_ckpt = n_pressed = 0
+        # The policy pass looks at every lane, whatever moved: it asks
+        # what is due, which is a matter of time (the two calls of the
+        # policy, the two selections made of their answers).
+        self._scanned += 4 * len(need)
         due = np.nonzero(need)[0]
         if len(due) > self.max_checkpoints_per_tick:
             # Rotate the selection across ticks: a fixed [:cap] slice would
@@ -4582,8 +4798,9 @@ class RaftNode:
             return None
         return snap.index, snap.term, snap.path
 
-    def _snapshot_requests(self, info: StepInfo, h_base) -> None:
-        req = np.nonzero(np.asarray(info.snap_req))[0]
+    def _snapshot_requests(self, info: StepInfo, h_base,
+                           ids: Optional[np.ndarray] = None) -> None:
+        req = self._where(ids, _at(np.asarray(info.snap_req), ids))
         queued = False
         for g in req.tolist():
             g = int(g)

@@ -68,19 +68,43 @@ def small_columns(monkeypatch):
 
 @pytest.fixture
 def noted(monkeypatch):
-    """Every ``st.note`` of the upload and the fetch, caught where it is
-    written: node -> phase -> [statistics]."""
+    """Every ``st.note`` of the upload and the fetch, and every ``scanned``
+    of the host phase, caught where it is written: node -> phase ->
+    [statistics]."""
     seen = {}
     real = StageSpans.note
 
     def spy(self, **stats):
-        if "dense" in stats:
+        if "dense" in stats or "scanned" in stats:
             seen.setdefault(self._node, {}).setdefault(
                 self._name, []).append(stats)
         return real(self, **stats)
 
     monkeypatch.setattr(StageSpans, "note", spy)
     return seen
+
+
+def assert_scanned_follows_the_rows(node, notes):
+    """``scanned`` on the four host-phase spans sums to the counter
+    ``host_lanes_scanned``.  The persist stage makes six selections: a
+    step that looked at every lane scanned ``6 * n_groups`` there (every
+    selection a pass over the planes), and on a node whose Readback comes
+    down as rows most steps scanned less than that, down to nothing: what
+    they looked at are the rows that moved."""
+    G = node.cfg.n_groups
+    assert set(notes) >= {"wal", "apply", "reads", "maintain"}
+    spans = [s["scanned"] for phase in ("wal", "apply", "reads", "maintain")
+             for s in notes[phase]]
+    assert sum(spans) == node.metrics["host_lanes_scanned"] > 0
+    wal = [s["scanned"] for s in notes["wal"]]
+    whole = [n for n in wal if n >= 6 * G]
+    assert whole and all(n % G == 0 for n in whole), whole[:5]
+    by_rows = [n for n in wal if n < 6 * G]
+    assert len(by_rows) > len(wal) // 2, (len(by_rows), len(wal))
+    assert any(by_rows), "no row step ever had a row to look at"
+    assert all(n % 6 == 0 for n in by_rows)     # six selections, one id set
+    # ... and far less in all than looking at every lane every step would.
+    assert sum(wal) < len(wal) * 6 * G // 2
 
 
 def assert_counters_match_spans(node, notes, maybe=()):
@@ -307,6 +331,7 @@ def test_served_cluster_over_columns_is_linearizable(noted, served):
     for i, c in enumerate(cs):
         assert_counters_match_spans(c.node, noted[c.node.node_id],
                                     maybe=("row_overflows_in",))
+        assert_scanned_follows_the_rows(c.node, noted[c.node.node_id])
     # (A serial node uploads no durable tail, so only the bursts overflow
     # HostInbox's rows, on the member that leads most.)
     assert sum(c.node.metrics["row_overflows_in"] for c in cs) > 0

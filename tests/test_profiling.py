@@ -258,6 +258,59 @@ def test_lease_hits_lanes_and_leaderless_ride_the_spans(tmp_path):
                            and s["leaderless"] == 0 for s in mirrors)
 
 
+def test_scanned_rides_the_host_spans_in_whole_planes_on_a_packed_shape(
+        tmp_path):
+    """PR 40's statistic.  ``raft.wal``, ``raft.apply``, ``raft.reads`` and
+    ``raft.maintain`` carry ``scanned``: the lanes the stage's selection
+    passes ran over.  On a shape that keeps ``node_step_packed`` every pass
+    looks at every lane, so every stage of every step reads a multiple of
+    ``n_groups`` (the timer's policy pass on top), whatever moved; over a
+    run the four sum to the counter ``host_lanes_scanned``."""
+    import jax
+
+    cfg = EngineConfig(n_groups=64, n_peers=3)
+    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1)
+    trace_dir = str(tmp_path / "trace")
+    try:
+        lead = c.wait_leader(0)
+        node = c.nodes[lead]
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        before = {i: n.metrics["host_lanes_scanned"]
+                  for i, n in c.nodes.items()}
+        steps = {i: n.ticks for i, n in c.nodes.items()}
+        with jax.profiler.trace(trace_dir):
+            futs = []
+            for i in range(8):
+                futs += [node.submit(0, b"w%d" % i), node.read(0, b"q%d" % i)]
+                c.tick()
+            for n in c.nodes.values():      # steps between two timer ticks
+                n.tick(arrival=True)
+            c.tick(3)
+            assert all(f.done() and f.exception() is None for f in futs)
+        counted = {i: n.metrics["host_lanes_scanned"] - before[i]
+                   for i, n in c.nodes.items()}
+        steps = {i: n.ticks - steps[i] for i, n in c.nodes.items()}
+    finally:
+        c.close()
+    G = cfg.n_groups
+    scanned = {}
+    for name, stats in _raft_spans(trace_dir):
+        if "scanned" in stats:
+            scanned.setdefault(stats["node"], {}).setdefault(
+                name, []).append(stats["scanned"])
+    for i, by_span in scanned.items():
+        assert set(by_span) == {"raft.wal", "raft.apply", "raft.reads",
+                                "raft.maintain"}
+        for name, values in by_span.items():
+            assert len(values) == steps[i], (i, name)
+            assert all(v > 0 and v % G == 0 for v in values), (i, name)
+        assert sum(map(sum, by_span.values())) == counted[i]
+        # The arrival step skipped the policy pass that every timer step
+        # makes: it scanned the least.
+        assert min(by_span["raft.maintain"]) < max(by_span["raft.maintain"])
+    assert sorted(scanned) == [0, 1, 2]
+
+
 def test_windows_unready_and_merged_replies_ride_the_spans(tmp_path):
     """PR 37's statistics.  ``raft.mirrors`` carries ``led`` and
     ``unready`` beside ``leaderless`` / ``open``, and the five window sums

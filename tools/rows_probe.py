@@ -19,8 +19,11 @@ device's durable plane must agree after every step.
 ``run``: ``benchmark/run.py`` with, for ``--half up``, every Readback taken
 whole (``pack_readback``) and the mirrors swapped as a packed step's are: the
 up half alone.  ``--half both`` is ``benchmark/run.py`` itself.  Either prints, per node over
-window + drain, the row and column counters and the means of the stages the
-row form touches (``[rows]`` lines); ``--watch 1`` adds a line a node every
+window + drain, the row and column counters, the lanes the host phase's
+selection passes ran over (``host_lanes_scanned``; 0 on a tree without the
+counter) and the untraced means of the stages the row form touches, the
+fetch's and the host phase's (``wal``, ``apply``, ``reads``, ``maintain``)
+(``[rows]`` lines); ``--watch 1`` adds a line a node every
 10 s of the boot (lanes whose commit lies past what is applied, and why);
 ``--cpu-lanes N`` rehearses the control flow on the CPU at N lanes.
 """
@@ -302,14 +305,16 @@ def run(a):
         node_mod.RaftNode._fetch_rows = whole
     names = ("ticks", "steps_rows_in", "row_overflows_in", "steps_rows_out",
              "row_overflows_out", "steps_columns_in", "column_overflows_in",
-             "steps_columns_out", "column_overflows_out", "stage_stalls")
+             "steps_columns_out", "column_overflows_out", "stage_stalls",
+             "host_lanes_scanned")
     stages = ("dispatch_intake", "dispatch_upload", "dispatch_enqueue",
-              "scan_device", "scan_fetch", "mirrors")
+              "scan_device", "scan_fetch", "mirrors",
+              "wal", "apply", "reads", "maintain")
     real_window = harness.window
 
     def window(cluster, *args, **kw):
         """The window, with each node's row counters and the means of the
-        stages this PR touches printed over window + drain."""
+        stages the row form touches printed over window + drain."""
         nodes = [c.node for c in cluster.containers]
         hist = lambda n, k: n.metrics.histogram(f"tick_stage_{k}_s")
         before = [([n.metrics[k] for k in names],
