@@ -59,6 +59,14 @@ class RaftConfig:
     # period apart wherever its boot put them: members on hosts of their
     # own have clocks of their own and nothing to stagger against.
     tick_stagger: bool = False
+    # TiKV's raftstore.hibernate-regions, by its name: a group nobody has
+    # asked anything of for an election timeout stops ticking (no heartbeat
+    # round, no election timer) until a request or a message wakes it, and
+    # a node whose every lane sleeps still sends each peer one empty frame
+    # a period, so that a dead node is not silent the way a healthy one is
+    # (core/step.py "hibernation"; runtime/node.py "the node-level beat").
+    # Off as shipped here: every group ticks, as it always did.
+    hibernate_regions: bool = False
     # engine shapes
     n_groups: int = 16
     log_slots: int = 64
@@ -153,6 +161,7 @@ class RaftConfig:
             pre_vote=self.pre_vote,
             avail_crit=self.avail_critical_point,
             recovery_ticks=self.recovery_cool_down_ticks,
+            hibernate=self.hibernate_regions,
         )
 
     def maintain(self):
@@ -185,7 +194,8 @@ def load_xml_config(path: str) -> RaftConfig:
             <remote>raft://127.0.0.1:6003</remote>
           </cluster>
           <timing tick="100" heartbeat="1" election="3" broadcast="0.5"
-                  pre-vote="true" tick-stagger="false"/>
+                  pre-vote="true" tick-stagger="false"
+                  hibernate-regions="false"/>
           <engine groups="16" log-slots="64" batch="8" max-submit="8"/>
           <snapshot state-change-threshold="64" dirty-log-tolerance="16"
                     snap-min-interval="20" compact-min-interval="10"
@@ -220,6 +230,8 @@ def load_xml_config(path: str) -> RaftConfig:
         broadcast_mul=attr("timing", "broadcast", 0.5, float),
         pre_vote=attr("timing", "pre-vote", True, boolean),
         tick_stagger=attr("timing", "tick-stagger", False, boolean),
+        hibernate_regions=attr("timing", "hibernate-regions", False,
+                               boolean),
         n_groups=attr("engine", "groups", 16, int),
         log_slots=attr("engine", "log-slots", 64, int),
         batch=attr("engine", "batch", 8, int),

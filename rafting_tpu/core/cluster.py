@@ -154,6 +154,8 @@ def cluster_step_nemesis(cfg: EngineConfig, states: RaftState,
         for name in names:
             old = getattr(inflight, name)
             new = getattr(outboxes, name)
+            if new is None:     # a flag of a feature that is off
+                continue
             k = keep if old.ndim == keep.ndim else keep[..., None]
             reps[name] = jnp.where(k, old, new)
         reps[vname] = getattr(outboxes, vname) | keep

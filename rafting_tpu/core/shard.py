@@ -62,13 +62,17 @@ def state_pspecs(trace: bool = False, heat: bool = False,
         heard=_NODE_GROUP, since=_NODE_GROUP) if qc else None
     kw["lease"] = LeaseGuard(
         vote_hold=_NODE_GROUP, carry_bar=_NODE_GROUP) if lease else None
+    # Hibernation (cfg.hibernate) is a served deployment's: the sharded
+    # harness runs without it.
+    kw["hib"] = None
     return RaftState(**kw)
 
 
 def messages_pspecs() -> Messages:
     """Specs for stacked [N, P, G, ...] message planes (axis 2 = group)."""
-    return Messages(**{f.name: _NODE_PEER_GROUP
-                       for f in dataclasses.fields(Messages)})
+    kw = {f.name: _NODE_PEER_GROUP for f in dataclasses.fields(Messages)}
+    kw["ae_sleep"] = kw["aer_asleep"] = None
+    return Messages(**kw)
 
 
 def info_pspecs(qc: bool = False) -> StepInfo:
@@ -78,6 +82,7 @@ def info_pspecs(qc: bool = False) -> StepInfo:
     if not qc:
         kw["cq_stepdown"] = None
         kw["cq_veto"] = None
+    kw["asleep"] = None
     return StepInfo(**kw)
 
 
@@ -90,6 +95,7 @@ def host_pspecs(durable: bool = False) -> HostInbox:
     kw = {f.name: _NODE_GROUP for f in dataclasses.fields(HostInbox)}
     kw["read_veto"] = kw["clock"] = _NODE
     kw["durable_tail"] = _NODE_GROUP if durable else None
+    kw["wake"] = None
     return HostInbox(**kw)
 
 

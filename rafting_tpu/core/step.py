@@ -54,6 +54,10 @@ Phase order within a tick (messages produced in tick t are delivered in t+1):
  11. flight recorder     — branchless per-group event-ring writes of the
                            tick's phase-boundary events (cfg.trace_depth;
                            compiled away entirely when 0)
+
+Hibernation (cfg.hibernate; compiled away entirely when off) threads
+through phases 2, 4, 6, 6b, 6c, 7 and 9: the rule and its proof stand at
+the top of ``node_step``.
 """
 
 from __future__ import annotations
@@ -70,8 +74,9 @@ from . import packing
 from .packing import WORD, ColumnLayout, Layout, RowLayout
 from .types import (
     CANDIDATE, FOLLOWER, LEADER, NIL, PRE_CANDIDATE, I32,
-    EngineConfig, HostInbox, LogState, Messages, RaftState, StepInfo,
-    conf_learners_of, conf_new_of, conf_pack, conf_voters_of, init_state,
+    EngineConfig, Hibernate, HostInbox, LogState, Messages, RaftState,
+    StepInfo, conf_learners_of, conf_new_of, conf_pack, conf_voters_of,
+    init_state,
 )
 
 Array = jax.Array
@@ -263,6 +268,82 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
 
     old_term, old_voted, old_last = term, voted, log.last
 
+    # ---- hibernation (cfg.hibernate) ---------------------------------------
+    # TiKV's Hibernate Region: a group nobody has asked anything of for an
+    # election timeout stops ticking, and the first request or message
+    # wakes it.  The lanes are Hibernate's (asleep, busy_at, slept), the
+    # wire carries one flag each way (ae_sleep, aer_asleep), the host one
+    # signal (HostInbox.wake).  With the field off none of this traces.
+    #
+    # Entry.  A LEADER is `quiet` when nothing but heartbeats and their
+    # acknowledgements has touched the lane for election_ticks ticks of
+    # its clock (busy_at), every member's match equals its last index,
+    # its commit equals it, and no read batch, config change, joint
+    # config, transfer or snapshot offer is pending (the apply frontier
+    # is the host's, which applies off its commit mirror whether the lane
+    # ticks or not: the device holds none to wait for).  While quiet, its
+    # cadence heartbeats carry ae_sleep.  A FOLLOWER that accepts such a
+    # heartbeat at its own term with nothing in it, its log ending where
+    # the leader's does and its commit there too falls asleep and says so
+    # (aer_asleep).  The leader latches each member's word (slept; only
+    # an echo of a heartbeat sent inside this quiet stretch counts) and
+    # falls asleep itself when EVERY member has given it, until when it
+    # heartbeats as ever: an awake follower of a sleeping leader would
+    # time out and disturb the rest.  What the acknowledgements prove is
+    # that nothing is in flight, so entry clears the lane's window
+    # counters (a phantom slot left by a merged reply would otherwise
+    # stand for good, with no heartbeat to time it out) and its lease
+    # evidence.
+    # Asleep.  A leader opens no heartbeat round (phase 9: hb_due does not
+    # fire), runs no CheckQuorum (6c) and stores no lease evidence (6b); a
+    # follower's election timer does not expire (7) and it grants no
+    # pre-vote on the strength of an elect_dl it slept past (2).  The
+    # step still runs over the lane; it produces nothing.
+    # Wake.  Any message for the lane other than the two that keep it
+    # asleep (the sleep heartbeat of its own leader's term that it still
+    # agrees to; the acknowledgement that says asleep), the host's
+    # peer-lost signal, and on a leader any request of the host's (a
+    # submit, a read, a config change, a transfer, a compaction grant, an
+    # installed snapshot).  A woken follower starts a whole new election
+    # timeout; a woken leader heartbeats in the same step, unflagged,
+    # which wakes its followers.
+    #
+    # Sleep may only LENGTHEN a follower's promise and END a leader's
+    # lease.  Five ways that could fail, each closed here and each a test
+    # (tests/test_hibernate.py):
+    # (a) a promise cut short: lease_open (2) reads elect_dl, which a
+    #     sleeper leaves where its last heartbeat put it.  Asleep, the
+    #     deadline is taken as not reached however long ago it passed,
+    #     and the wake step itself still refuses; waking sets elect_dl a
+    #     whole timeout ahead.  No instant's promise is earlier for
+    #     having slept.
+    # (b) a lease that spans a sleep: entry zeroes read_evid, no evidence
+    #     is stored in a step the lane entered asleep (the wake step
+    #     included: what arrives then answers a heartbeat from before),
+    #     so a woken leader's first read is stamped against nothing and
+    #     pays a barrier (read_kick).
+    # (c) a leader deposed in its sleep (cut off; its followers woken by
+    #     the peer-lost signal elected another): it holds no evidence,
+    #     its barrier heartbeat is answered at a higher term or not at
+    #     all, and the read fails or waits; it is never served.
+    # (d) a sleep heartbeat lost, duplicated or overtaken.  Lost: the
+    #     leader keeps proposing.  Behind a data AE: the follower's log
+    #     no longer ends at prev_idx, so it stays awake.  Behind an
+    #     unflagged heartbeat, or twice: the follower may fall asleep
+    #     under an awake leader, whose next cadence heartbeat (unflagged:
+    #     it is not quiet, or it would be proposing) wakes it within one
+    #     heartbeat period, and whose latch ignores the word (the echo
+    #     predates busy_at + election_ticks).  At a stale term: the
+    #     heartbeat fails the term check, is not the selected AE, and so
+    #     wakes instead.
+    # (e) the field off: every line below is under `if hiber`.
+    hiber = cfg.hibernate
+    if hiber:
+        asleep0, busy_at, slept = s.hib.asleep, s.hib.busy_at, s.hib.slept
+        host_req = ((host.submit_n > 0) | (host.read_n > 0)
+                    | (host.conf_voters != 0) | (host.xfer_target >= 0)
+                    | (host.compact_to > 0) | host.snap_done)
+
     # ---- 0. membership view C0 (tick-start) -------------------------------
     # The active config is a function of the log (§6 apply-on-append +
     # truncation rollback, see latest_conf); the state carries it as the
@@ -326,6 +407,9 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # have detected leader silence (lease), log up-to-date, term ahead.  No
     # durable state changes.
     lease_open = (now >= elect_dl) | (leader_id == NIL)
+    if hiber:
+        # (a): a sleeper's deadline is not reached, whatever the clock.
+        lease_open = ((now >= elect_dl) & ~asleep0) | (leader_id == NIL)
     # The carried lease (6b) leans on this refusal, so two more nodes
     # keep it: one that restarted with a term on disk, for as long as a
     # lease it may have acknowledged can last (case b: leader_id is NIL
@@ -523,6 +607,32 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # same-term reply proves we processed the leader's AE — the read
     # plane's barrier evidence (the occupancy-echo idiom again).
     out_aer_tick = jnp.where(ae_v, inbox.ae_tick, 0)
+    if hiber:
+        # The sleep heartbeat this follower agrees to: the selected AE,
+        # accepted, empty, flagged, and both logs and commits level.
+        sleep_ok = (acc & take_plane(inbox.ae_sleep, ae_peer) & (n_e == 0)
+                    & (log.last == prev_i) & (commit == lc)
+                    & (commit == log.last))
+        out_aer_asleep = keeps = is_sel & sleep_ok[None, :]
+        # A leader's heartbeat acknowledgements, and among them those
+        # that say asleep: neither counts as activity, the second does
+        # not wake.
+        hb_reply = (inbox.aer_valid & inbox.aer_empty & inbox.aer_success
+                    & (inbox.aer_term == term[None, :]))
+        q_aer = hb_reply & inbox.aer_asleep
+        other = (inbox.rv_valid | inbox.rvr_valid | inbox.is_valid
+                 | inbox.isr_valid | inbox.tn_valid)
+        m_ok = active[None, :] & not_me_col
+        msgs_busy = (m_ok & (inbox.ae_valid | other
+                             | (inbox.aer_valid & ~hb_reply))).any(axis=0)
+        loud = (m_ok & ((inbox.ae_valid & ~keeps) | other
+                        | (inbox.aer_valid & ~q_aer))).any(axis=0)
+        asleep = ((sleep_ok | asleep0) & ~loud & active
+                  & ~(host.wake | (host_req & (role == LEADER))))
+        woke = asleep0 & ~asleep
+        # A woken follower starts a whole new timeout (a).
+        elect_dl = jnp.where(woke & (role != LEADER), now + rand_to,
+                             elect_dl)
 
     # ---- 5. InstallSnapshot ------------------------------------------------
     # Device plane: an offer merely tells the follower's host to start the
@@ -779,6 +889,14 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     else:
         evid_hit = aer_r & ~self_hot
         evid_val = jnp.maximum(read_evid, inbox.aer_tick.T)
+    if hiber:
+        # (b): nothing is stored in a step the lane entered asleep.  The
+        # members' word is latched here: an acknowledgement that says
+        # asleep to a heartbeat sent inside this quiet stretch.
+        evid_hit = evid_hit & ~asleep0[:, None]
+        slept = slept | (aer_r & q_aer.T & ~self_hot
+                         & (inbox.aer_tick.T
+                            >= (busy_at + cfg.election_ticks)[:, None]))
     read_evid = jnp.where(evid_hit, evid_val, read_evid)
     read_evid = jnp.where(host.read_veto, jnp.zeros_like(read_evid),
                           read_evid)
@@ -838,6 +956,11 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         since = jnp.where(vote_win, now, qc.since)
         cq_due = active & (role == LEADER) \
             & (now - since >= cfg.election_ticks)
+        if hiber:
+            # No leader is deposed for the silence it agreed to: none is
+            # due asleep, and a woken one gets a whole window.
+            since = jnp.where(woke, now, since)
+            cq_due = cq_due & ~asleep & ~woke
         cq_ok = contact_quorum(voters1, vnew1, me, heard, since)
         cq_down = cq_due & ~cq_ok
         since = jnp.where(cq_due & cq_ok, now, since)
@@ -869,6 +992,8 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # log stays quiet; it still grants votes and accepts AEs).
     voter_self = (jnp.right_shift(voters1 | vnew1, me) & 1) > 0
     expired = active & (now >= elect_dl) & (role != LEADER) & voter_self
+    if hiber:
+        expired = expired & ~asleep
     if cfg.pre_vote and carry:
         # The carried lease rests on pre-votes being asked (6b): a
         # candidate whose election ran out asks again, as a follower
@@ -1086,6 +1211,25 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     hb_inflight = jnp.where(timed_out, 0, hb_inflight)
 
     heartbeat = (role == LEADER) & ((now >= hb_due) | read_kick)
+    if hiber:
+        lead = active & (role == LEADER)
+        others = member2 & ~self_hot
+        moved = active & (msgs_busy | host_req | host.wake | woke
+                          | (term != s.term) | (role != s.role)
+                          | (log.last != old_last) | (commit != s.commit))
+        busy_at = jnp.where(moved, now, busy_at)
+        slept = jnp.where(moved[:, None], False, slept)
+        quiet = (lead & (now - busy_at >= cfg.election_ticks)
+                 & (~others | (match_idx == log.last[:, None])).all(axis=1)
+                 & (commit == log.last) & (rq_len == 0)
+                 & (cidx2 <= commit) & (vnew2 == 0) & (xfer_to == NIL)
+                 & ~need_snap.any(axis=1))
+        go_sleep = quiet & ~asleep & (~others | slept).all(axis=1)
+        asleep = asleep | go_sleep
+        # An asleep leader's cadence does not fire; a woken one sends at
+        # once (unflagged: it is not quiet), which wakes its followers.
+        heartbeat = (role == LEADER) & (((now >= hb_due) & ~asleep)
+                                       | read_kick | (woke & lead))
     has_data = (log.last[:, None] >= send_next) & ~need_snap
     n_avail = jnp.clip(log.last[:, None] - send_next + 1, 0, B)  # [G, P]
     # Data flows whenever the window has room; empty heartbeat AEs keep
@@ -1129,6 +1273,8 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     out_ae_occ = hb_occupy.T
     # Send tick, echoed back as aer_tick (read-barrier evidence, 6b).
     out_ae_tick = jnp.broadcast_to(now, (P, G)).astype(I32)
+    if hiber:
+        out_ae_sleep = (send_hb & quiet[:, None]).T
     # Snapshot offer for laggards (reference Leader.java:168-190); occupies
     # the whole window (one offer at a time), re-offered on the heartbeat
     # cadence while un-acked — the re-offer is window-exempt like a
@@ -1155,6 +1301,14 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     hb_inflight = jnp.where(hb_occupy, hb_inflight + 1, hb_inflight)
     sent_at = jnp.where(occupy | hb_occupy, now, sent_at)
     hb_due = jnp.where(heartbeat, now + cfg.heartbeat_ticks, hb_due)
+    if hiber:
+        # Entry: every member's word says nothing is in flight, whatever
+        # the counters hold; the evidence goes with the heartbeats (b).
+        gs = go_sleep[:, None]
+        inflight = jnp.where(gs, 0, inflight)
+        hb_inflight = jnp.where(gs, 0, hb_inflight)
+        read_evid = jnp.where(gs, 0, read_evid)
+        slept = jnp.where(gs, False, slept)
 
     # Leader readiness (reference Leader.isReady, Leader.java:52-64 +
     # Leadership.isReady/isUnhealthy, Leadership.java:44-51): a follower
@@ -1356,6 +1510,15 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     dirty = (term != old_term) | (voted != old_voted) | (log.last != old_last) \
         | (app_to > 0)
 
+    hib = s.hib
+    if hiber:
+        # Phase 10 moved nothing on a lane that was quiet (its commit
+        # stood at its last index); where it did, that is activity too.
+        busy_at = jnp.where(active & ((commit != s.commit) | resigned), now,
+                            busy_at)
+        hib = Hibernate(asleep=asleep & ~resigned, busy_at=busy_at,
+                        slept=slept)
+
     # In-kernel invariant checks (cfg.debug_checks; zero cost when off —
     # the branch is resolved at trace time).  The vectorized analog of the
     # reference's hot-path AssertionErrors (ring/log continuity
@@ -1411,6 +1574,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         heat=heat,
         qc=qc,
         lease=guard,
+        hib=hib,
     )
     outbox = Messages(
         ae_valid=out_ae_valid, ae_term=out_ae_term,
@@ -1433,6 +1597,8 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         isr_valid=out_isr_valid, isr_term=out_isr_term,
         isr_success=out_isr_success, isr_probe=out_isr_probe,
         tn_valid=out_tn_valid, tn_term=out_tn_term,
+        ae_sleep=out_ae_sleep if hiber else None,
+        aer_asleep=out_aer_asleep if hiber else None,
     )
     info = StepInfo(
         submit_start=sub_start, submit_acc=n_acc, dirty=dirty,
@@ -1451,6 +1617,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         xfer_fired=xfer_fire, xfer_abort=xfer_abort,
         debug_viol=debug_viol,
         cq_stepdown=cq_down, cq_veto=cq_veto,
+        asleep=hib.asleep if hiber else None,
     )
     return new_state, outbox, info
 
@@ -1619,7 +1786,7 @@ class RowCarry(NamedTuple):
 # something happened; every mirrored state lane of the Readback is a
 # level.
 INFO_LEVELS = ("log_tail", "commit", "leader", "ready", "conf_word",
-               "conf_idx", "conf_pending")
+               "conf_idx", "conf_pending")     # and "asleep" (cfg.hibernate)
 INFO_CARRIED = ("submit_start",)
 
 
@@ -1658,7 +1825,8 @@ def column_layouts(cfg: EngineConfig, durable: bool
             back, G, packing.ROWS_OUT,
             levels=[n for n in packing.lane_names(back, G)
                     if not n.startswith("info.")]
-            + ["info." + n for n in INFO_LEVELS],
+            + ["info." + n for n in INFO_LEVELS
+               + (("asleep",) if cfg.hibernate else ())],
             carried=["info." + n for n in INFO_CARRIED]))
 
 

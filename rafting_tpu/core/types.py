@@ -202,6 +202,21 @@ class EngineConfig:
                                   #     QuorumContact lanes; False keeps
                                   #     the subtree None (same zero-cost-
                                   #     when-off contract as trace/heat).
+    hibernate: bool = False       # Hibernate Region (TiKV's
+                                  #     raftstore.hibernate-regions): a group
+                                  #     idle for an election timeout whose
+                                  #     members all hold the leader's whole
+                                  #     log stops ticking: its leader opens
+                                  #     no heartbeat round, its followers'
+                                  #     election timers do not run, and the
+                                  #     first request or message wakes it
+                                  #     (core/step.py "hibernation" has the
+                                  #     rule and its proof).  Adds the
+                                  #     Hibernate lanes, a flag on an
+                                  #     AppendEntries and on its reply, and
+                                  #     HostInbox.wake; False keeps all of
+                                  #     them None (same zero-cost-when-off
+                                  #     contract as trace/heat/qc/lease).
 
     def __post_init__(self):
         assert self.n_peers >= 1
@@ -442,6 +457,33 @@ class LeaseGuard:
                    carry_bar=jnp.zeros((n_groups,), I32))
 
 
+@struct.dataclass
+class Hibernate:
+    """Per-group lanes of hibernation (``cfg.hibernate``; None otherwise,
+    so that a configuration without it compiles the program it always
+    did).  All volatile (core/step.py "hibernation").
+
+    ``asleep[g]``: the lane does not tick.  A leader opens no heartbeat
+    round, runs no CheckQuorum and holds no lease evidence; a follower's
+    election timer does not expire and its vote-denying promise stands.
+    ``busy_at[g]``: own-clock tick of the lane's last activity (anything
+    but a heartbeat and its acknowledgement): a leader proposes sleep
+    ``election_ticks`` ticks after it.
+    ``slept[g, p]``: as leader, member ``p`` has said it fell asleep under
+    the proposal now standing (cleared by any activity).
+    """
+
+    asleep: jax.Array      # [G] bool
+    busy_at: jax.Array     # [G] int32 — own-clock tick
+    slept: jax.Array       # [G, P] bool
+
+    @classmethod
+    def empty(cls, n_groups: int, n_peers: int) -> "Hibernate":
+        return cls(asleep=jnp.zeros((n_groups,), jnp.bool_),
+                   busy_at=jnp.zeros((n_groups,), I32),
+                   slept=jnp.zeros((n_groups, n_peers), jnp.bool_))
+
+
 def trace_append(tr: TraceState, mask: jax.Array, kind: int,
                  tick, term, aux) -> TraceState:
     """Branchless masked append of one event kind across all groups.
@@ -592,6 +634,9 @@ class RaftState:
     # compiles bit-identically.
     lease: Any = None         # Optional[LeaseGuard]
 
+    # Hibernation's lanes (cfg.hibernate).  Same None-subtree contract.
+    hib: Any = None           # Optional[Hibernate]
+
 
 @struct.dataclass
 class FaultSchedule:
@@ -689,10 +734,18 @@ def crash_restart(cfg: EngineConfig, s: "RaftState") -> "RaftState":
         lease = LeaseGuard(
             vote_hold=jnp.where(s.term > 0, s.now + cfg.lease_hold_ticks, 0),
             carry_bar=jnp.zeros_like(lease.carry_bar))
+    # A restarted node is awake, and its idle stretch starts now.
+    hib = s.hib
+    if hib is not None:
+        hib = Hibernate(asleep=jnp.zeros_like(hib.asleep),
+                        busy_at=jnp.broadcast_to(s.now, hib.busy_at.shape)
+                        .astype(I32),
+                        slept=jnp.zeros_like(hib.slept))
     return s.replace(
         trace=trace,
         qc=qc,
         lease=lease,
+        hib=hib,
         rng=rng,
         role=z(G),
         leader_id=jnp.full((G,), NIL, I32),
@@ -826,6 +879,15 @@ class Messages:
     tn_valid: jax.Array      # [P, G] bool
     tn_term: jax.Array       # [P, G] int32 — sender's term (receiver must match)
 
+    # Hibernation (cfg.hibernate; None otherwise: the pytree, the packed
+    # layouts and the wire sections are then what they always were).
+    ae_sleep: Any = None     # Optional[[P, G] bool] — this heartbeat proposes
+                             #   sleep: its sender has been idle for an
+                             #   election timeout and holds every member's
+                             #   acknowledgement of its whole log
+    aer_asleep: Any = None   # Optional[[P, G] bool] — the replier fell (or
+                             #   stays) asleep on that heartbeat
+
     @classmethod
     def empty(cls, cfg: EngineConfig) -> "Messages":
         P, G, B = cfg.n_peers, cfg.n_groups, cfg.batch
@@ -848,6 +910,8 @@ class Messages:
             isr_valid=f(P, G), isr_term=z(P, G), isr_success=f(P, G),
             isr_probe=f(P, G),
             tn_valid=f(P, G), tn_term=z(P, G),
+            ae_sleep=f(P, G) if cfg.hibernate else None,
+            aer_asleep=f(P, G) if cfg.hibernate else None,
         )
 
 
@@ -916,6 +980,12 @@ class HostInbox:
     # log tail is durable the moment it is written — the serial runtime's
     # invariant, unchanged.
     durable_tail: Optional[jax.Array] = None   # [G] int32, or None
+    # Hibernation (cfg.hibernate; None otherwise): the host's peer-lost
+    # signal.  The node this lane's leader lives on has been silent for an
+    # election timeout (runtime/node.py: the node-level beat), so an asleep
+    # follower wakes and starts a whole new election timeout.  Every other
+    # wake the step reads off the fields above and off its messages.
+    wake: Optional[jax.Array] = None           # [G] bool, or None
 
     @classmethod
     def empty(cls, cfg: EngineConfig) -> "HostInbox":
@@ -934,6 +1004,7 @@ class HostInbox:
             read_veto=jnp.asarray(False),
             clock=jnp.asarray(1, I32),
             durable_tail=None,
+            wake=jnp.zeros((G,), jnp.bool_) if cfg.hibernate else None,
         )
 
 
@@ -1032,6 +1103,11 @@ class StepInfo:
                               #   lease reads vetoed by that step-down
                               #   (the reads a deposed-but-unaware leader
                               #   would otherwise have served stale)
+    # Hibernation (cfg.hibernate; None-subtree when off).
+    asleep: Any = None        # Optional[[G] bool] — the lane is asleep
+                              #   after this step (a level: the host's
+                              #   mirror; what woke or fell asleep is its
+                              #   difference from the last step's)
 
     @classmethod
     def empty(cls, cfg: EngineConfig) -> "StepInfo":
@@ -1063,6 +1139,7 @@ class StepInfo:
             cq_stepdown=(jnp.zeros((G,), jnp.bool_)
                          if cfg.check_quorum else None),
             cq_veto=(z() if cfg.check_quorum else None),
+            asleep=(jnp.zeros((G,), jnp.bool_) if cfg.hibernate else None),
         )
 
 
@@ -1138,4 +1215,5 @@ def init_state(cfg: EngineConfig, node_id: int, seed: int = 0,
         heat=(HeatState.empty(G) if cfg.heat else None),
         qc=(QuorumContact.empty(G, P) if cfg.check_quorum else None),
         lease=(LeaseGuard.empty(G) if cfg.lease_carry_ticks else None),
+        hib=(Hibernate.empty(G, P) if cfg.hibernate else None),
     )

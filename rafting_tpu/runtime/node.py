@@ -66,7 +66,8 @@ from ..snapshot.policy import MaintainAgreement
 from ..transport import InboxAccumulator, messages_template
 from ..transport.inbox import fill_columns, scatter_dense
 from ..transport.codec import (
-    EAGER_KINDS, KIND_FIELDS, assemble_slice, pack_hops, pack_kind_section,
+    BEAT, EAGER_KINDS, KIND_FIELDS, assemble_slice, frame, pack_beat,
+    pack_hops, pack_kind_section,
 )
 from ..api.anomaly import (
     BatchAbortedError, BusyLoopError, LeadershipEvacuatedError,
@@ -299,14 +300,16 @@ class _ReadOffer:
     step that stamped it also released it (StepInfo.read_lease), with no
     ReadIndex round trip; ``carried``: on evidence of an earlier tick
     (StepInfo.read_carried: the lease outlived the period it was
-    acknowledged in, core/step.py phase 6b)."""
+    acknowledged in, core/step.py phase 6b); ``woke``: that step woke the
+    lane from hibernation, so the barrier is the heartbeat that wakes its
+    followers."""
 
-    __slots__ = ("parts", "n", "lease", "carried")
+    __slots__ = ("parts", "n", "lease", "carried", "woke")
 
     def __init__(self, parts: List[_ReadBatch]):
         self.parts = parts
         self.n = sum(len(b.payloads) for b in parts)
-        self.lease = self.carried = False
+        self.lease = self.carried = self.woke = False
 
 
 class _TickCtx:
@@ -341,6 +344,10 @@ class _TickCtx:
         # eagerly (serial and settled ticks: every kind packs post-fsync,
         # the classic send).
         "deferred_ae",
+        # Hibernation (cfg.hibernate): the lanes the step's peer-lost
+        # signal named and those it asked something beside a write or a
+        # read (dispatch); the lanes it woke (fetch).
+        "wake_ids", "asked_ids", "woke_ids",
     )
 
 
@@ -538,6 +545,30 @@ class RaftNode:
         # Readiness gate (reference Leader.isReady, Leader.java:52-64): a
         # fresh leader reports not-ready until a majority of peers reply.
         self.h_ready = np.zeros(G, bool)
+        # Hibernation (cfg.hibernate; core/step.py "hibernation"): the
+        # mirror of StepInfo.asleep, the open lanes asleep (kept running
+        # while rows come down), and the lanes the host's peer-lost signal
+        # wakes at the next step (HostInbox.wake).
+        self.h_asleep = np.zeros(G, bool)
+        self._asleep_n = 0
+        self._wake_ids = np.zeros(0, np.int64)
+        # The node-level beat.  A node whose every lane sleeps sends no
+        # lane traffic, so a dead node would be silent the way a healthy
+        # one is.  With hibernation on, a peer that got no frame of ours
+        # between two timer steps gets an empty one (_node_beat), every
+        # frame a peer's node sends bumps the transport's ``heard`` count
+        # for it, and a peer whose count has stood for election_ticks
+        # timer steps in a row is taken as lost: every asleep lane that
+        # follows it is woken (as rows) and starts a whole new election
+        # timeout.  Counts of our own timer steps, no clock: a cold group
+        # whose leader's node dies has a new leader within election_ticks
+        # + 2 x election_ticks periods of the death, or on the first
+        # request to any of its members.  What TiKV gets from its store
+        # heartbeat through PD.
+        self._beat_frame = frame(BEAT, pack_beat(node_id))
+        self._beat_sent: set = set()
+        self._heard_n: Dict[int, int] = {}
+        self._silent: Dict[int, int] = {}
         # Unready episodes: the timer tick at which a lane this node led
         # READY stopped being ready (0: no episode open), and how many led
         # lanes the last step found unready.  _fetch touches the array
@@ -646,6 +677,7 @@ class RaftNode:
         self._snap_cv = threading.Condition(self._snap_lock)
         self._snap_fetched: List[Tuple[int, int, int, str]] = []
         self._snap_inflight: set = set()
+        self._snap_serial = 0       # downloads started (names their files)
         # Queue entries carry the lane's fetch epoch: a purge bumps it, so
         # a stale queued fetch can never run against a recreated lane even
         # if the lane has re-entered _snap_inflight by the time a worker
@@ -990,6 +1022,13 @@ class RaftNode:
         # heartbeats that a batch left pending asked for.
         self.metrics["read_lease_carried"] += 0
         self.metrics["read_kicks"] += 0
+        # Hibernation: lanes that fell asleep and woke, the wakes by what
+        # caused them (a request of this host's, a message, the peer-lost
+        # signal), and the empty frames of the node-level beat.
+        for name in ("lane_sleeps", "lane_wakes", "wake_request",
+                     "wake_message", "wake_peer_lost", "node_beats_sent"):
+            self.metrics[name] += 0
+        self.metrics.gauge("lanes_asleep", 0)
         # Maintenance a full log ring asked for ahead of the cadence
         # (snapshot/policy.py): checkpoints serialized, grants issued.
         self.metrics["ckpt_by_pressure"] += 0
@@ -1788,6 +1827,8 @@ class RaftNode:
         # ticks.
         if not arrival:
             self._health_tick()
+            if self.cfg.hibernate:
+                self._node_beat()
         refused = self._not_ready_refusals
         if refused != self._not_ready_folded:
             m["refused_not_ready"] += refused - self._not_ready_folded
@@ -2048,6 +2089,16 @@ class RaftNode:
             durable = np.minimum(src, I32_SAFE_MAX).astype(np.int32)
         compact_to = self._compact_grant.astype(np.int32)
         self._compact_grant = np.zeros(G, np.int64)
+        wake, wake_ids, asked = None, self._wake_ids, None
+        if cfg.hibernate:
+            # The peer-lost signal, and the lanes asked something beside
+            # a write or a read (what a wake is put down to).
+            self._wake_ids = wake_ids[:0]
+            wake = np.zeros(G, bool)
+            wake[wake_ids] = True
+            asked = np.flatnonzero(
+                (conf_voters != 0) | (xfer_target != NIL) | (compact_to > 0)
+                | snap_done)
 
         # -- 2. network inbox ------------------------------------------------
         # This tick's upload buffers (core/packing.py), fresh: the [G]
@@ -2073,7 +2124,7 @@ class RaftNode:
             snap_term=snap_term, snap_conf=snap_conf, compact_to=compact_to,
             conf_voters=conf_voters, conf_learners=conf_learners,
             xfer_target=xfer_target, read_n=read_n, read_veto=read_veto,
-            clock=int(not arrival), durable_tail=durable))
+            clock=int(not arrival), durable_tail=durable, wake=wake))
 
         # -- 2b. upload: every host plane crosses to the device here, after
         # the whole intake, in one transfer per buffer ----------------------
@@ -2100,6 +2151,7 @@ class RaftNode:
         ctx.packed, ctx.readback = packed, readback
         ctx.columns = ctx.out_dense = ctx.rows = None
         ctx.deferred_ae = None
+        ctx.wake_ids, ctx.asked_ids, ctx.woke_ids = wake_ids, asked, None
         self._inflight_submit = self._inflight_submit + submit_n
         self._inflight_read = self._inflight_read + read_n
         return ctx
@@ -2175,6 +2227,14 @@ class RaftNode:
             fields.append(("xfer_target",
                            np.asarray([g for g, _ in xfers], np.int64),
                            [t for _, t in xfers]))
+        wake_ids, asked = self._wake_ids, None
+        if cfg.hibernate:
+            # The lanes asked something beside a write or a read (what a
+            # wake is put down to), then the peer-lost signal.
+            asked = np.concatenate([at for _, at, _ in fields[2:]])
+            self._wake_ids = wake_ids[:0]
+            if wake_ids.size:
+                fields.append(("wake", wake_ids, True))
         # The count decides (a storm moves every lane's durable tail: no
         # sort of 100,000 lanes to find that out).
         said = [dur_ids] + [at for _, at, _ in fields]
@@ -2279,6 +2339,7 @@ class RaftNode:
         ctx.submit_n, ctx.read_n = step.up
         step.sub_ids, step.sub_n = sub_ids, sub_n
         step.read_ids, step.read_n = read_ids, read_n
+        ctx.wake_ids, ctx.asked_ids, ctx.woke_ids = wake_ids, asked, None
         ctx.submit_n[sub_ids] = sub_n
         ctx.read_n[read_ids] = read_n
         self._inflight_submit[sub_ids] += sub_n
@@ -2582,6 +2643,12 @@ class RaftNode:
         self.h_commit, self.h_base = h_commit, h_base
         self.h_term = h_term
         self.h_ready = np.asarray(h_info.ready)
+        if cfg.hibernate:
+            asleep, was = np.asarray(h_info.asleep), self.h_asleep
+            self.h_asleep = asleep
+            self._asleep_n = int(asleep.sum())
+            self._note_sleep(ctx, np.flatnonzero(was & ~asleep),
+                             int((asleep & ~was).sum()))
         self.metrics["elections"] += int(
             ((h_role == LEADER) & (old_role != LEADER)).sum())
         self._leadership_lost(
@@ -2646,6 +2713,7 @@ class RaftNode:
         old_pending = self.h_conf_pending[ids]
         old_conf_idx = self.h_conf_idx[ids]
         old_commit = int(self.h_commit[ids].sum(dtype=np.int64))
+        old_asleep = self.h_asleep[ids] if cfg.hibernate else None
         # Client threads read h_role, h_leader and h_ready lane by lane
         # while this patches them: the flags first, so that no lane reads
         # as led before its readiness is the new step's.
@@ -2661,6 +2729,13 @@ class RaftNode:
 
         role, ready, leader = new("role"), new("info.ready"), new("leader_id")
         m = self.metrics
+        if cfg.hibernate:
+            # A lane that woke or fell asleep is a row that moved.
+            asleep = new("info.asleep")
+            woke = ids[old_asleep & ~asleep]
+            slept = int((asleep & ~old_asleep).sum())
+            self._asleep_n += slept - woke.size
+            self._note_sleep(ctx, woke, slept)
         m["elections"] += int(((role == LEADER) & (old_role != LEADER)).sum())
         self._leadership_lost(ids[(old_role == LEADER) & (role != LEADER)])
 
@@ -2722,6 +2797,8 @@ class RaftNode:
             self.h_conf_word = back.info.conf_word
             self.h_conf_idx = back.info.conf_idx
             self.h_conf_pending = back.info.conf_pending
+            if self.cfg.hibernate:
+                self.h_asleep = back.info.asleep
         return back
 
     def count_lanes(self) -> List[int]:
@@ -2732,6 +2809,59 @@ class RaftNode:
         return [int(self.h_active.sum()), int(led.sum()),
                 int((self.h_active & led & ~self.h_ready).sum()),
                 int((self.h_active & ~led & (self.h_leader == NIL)).sum())]
+
+    def _note_sleep(self, ctx: _TickCtx, woke: np.ndarray,
+                    slept: int) -> None:
+        """Tick thread, where a step's ``asleep`` level reaches the
+        mirror: count the lanes that fell asleep and those that woke, the
+        wakes by cause.  A lane the peer-lost signal named woke of that; a
+        lane this step offered something on (a write, a read, a config
+        change, a transfer, a grant, an installed snapshot) of a request;
+        every other of a message."""
+        ctx.woke_ids = woke
+        m = self.metrics
+        if slept:
+            m["lane_sleeps"] += slept
+        if not woke.size:
+            return
+        lost = np.isin(woke, ctx.wake_ids)
+        asked = ~lost & ((ctx.submit_n[woke] > 0) | (ctx.read_n[woke] > 0)
+                         | np.isin(woke, ctx.asked_ids))
+        n_lost, n_asked = int(lost.sum()), int(asked.sum())
+        m["lane_wakes"] += int(woke.size)
+        m["wake_peer_lost"] += n_lost
+        m["wake_request"] += n_asked
+        m["wake_message"] += int(woke.size) - n_lost - n_asked
+
+    def _node_beat(self) -> None:
+        """Tick thread, at the end of a timer step with hibernation on:
+        the node-level beat (``__init__`` has the rule).  A peer this node
+        sent no frame since the last timer step gets an empty one; a peer
+        whose frames have not moved the transport's count for
+        ``election_ticks`` timer steps in a row is taken as lost, and the
+        asleep lanes that follow it are woken at the next step."""
+        t = self.transport
+        heard = getattr(t, "heard", None)
+        if heard is None:       # a transport of someone else's: no beat
+            return
+        T = self.cfg.election_ticks
+        for p in range(self.cfg.n_peers):
+            if p == self.node_id:
+                continue
+            if p not in self._beat_sent:
+                t.send_slice(p, self._beat_frame)
+                self.metrics["node_beats_sent"] += 1
+            n = heard.get(p, 0)
+            if n != self._heard_n.get(p):
+                self._heard_n[p], self._silent[p] = n, 0
+                continue
+            silent = self._silent[p] = self._silent.get(p, 0) + 1
+            if silent % T == 0:
+                lost = np.flatnonzero(self.h_asleep & (self.h_leader == p))
+                if lost.size:
+                    self._wake_ids = np.union1d(self._wake_ids, lost)
+                    self._wake.set()
+        self._beat_sent.clear()
 
     def _guard_i32(self, log_tail: int, term: int) -> None:
         hi_lane = max(log_tail, term, self.timer_ticks)
@@ -2809,7 +2939,15 @@ class RaftNode:
         self.ticks += 1
         self.timer_ticks += int(ctx.timer)
         if ctx.timer:
-            self._hb_open(ctx.outbox, ctx.started, ctx.info.read_kick)
+            kicked = ctx.info.read_kick
+            if cfg.hibernate and ctx.woke_ids is not None \
+                    and ctx.woke_ids.size:
+                # A woken leader's first heartbeat is its lane's own
+                # traffic, as a read's barrier heartbeat is: the round is
+                # the awake lanes' cadence.
+                kicked = np.array(kicked)
+                kicked[ctx.woke_ids] = True
+            self._hb_open(ctx.outbox, ctx.started, kicked)
         n_open, n_led, n_unready, n_lost = self._lane_counts
         leaderless = n_unready + n_lost
         # The leader's windows as the step left them (core/step.py
@@ -2829,6 +2967,15 @@ class RaftNode:
                 unready=n_unready, win_pairs=pairs,
                 win_slots=pairs * cfg.inflight_limit, win_occupied=occupied,
                 win_full=full, win_cooling=cooling, win_timeouts=timed_out)
+        # Hibernation: open lanes asleep after the step (a timer step's:
+        # the gauge and the span say how the period begins), and lanes it
+        # woke; 0 and 0 where the field is off.
+        woken = 0 if ctx.woke_ids is None else int(ctx.woke_ids.size)
+        if ctx.timer:
+            m.gauge("lanes_asleep", self._asleep_n)
+            st.note(asleep=self._asleep_n, woken=woken)
+        elif woken:
+            st.note(woken=woken)
         was_unready, self._unready_n = self._unready_n, n_unready
         if n_unready or was_unready:
             self._track_unready(*masks(), ctx.timer, win)
@@ -2983,7 +3130,8 @@ class RaftNode:
                 st.enter("reads")
 
                 # -- 6b. read plane: stamped/released bookkeeping + serving --
-                st.note(lanes=self._harvest_reads(ctx.info, ids)
+                st.note(lanes=self._harvest_reads(ctx.info, ids,
+                                                  ctx.woke_ids)
                         + self._serve_reads(applied, ids))
                 self._note_scanned()
                 st.enter("maintain")
@@ -3059,7 +3207,7 @@ class RaftNode:
                 or self._acked_tail is not None:
             return None
         return np.unique(np.concatenate(
-            (step.down_ids, step.sub_ids, step.read_ids)))
+            (step.down_ids, step.sub_ids, step.read_ids, ctx.wake_ids)))
 
     def _where(self, lanes: Optional[np.ndarray],
                mask: np.ndarray) -> np.ndarray:
@@ -3834,7 +3982,8 @@ class RaftNode:
     # ------------------------------------------------------------ read plane
 
     def _harvest_reads(self, info: StepInfo,
-                       ids: Optional[np.ndarray] = None) -> int:
+                       ids: Optional[np.ndarray] = None,
+                       woke_ids: Optional[np.ndarray] = None) -> int:
         """Tick thread: mirror the device read FIFO's transitions reported
         in StepInfo — offers the device STAMPED move to pending with
         their ReadIndex; pending offers whose barrier RELEASED move to
@@ -3864,6 +4013,10 @@ class RaftNode:
         released = self._where(ids, _at(read_rel, ids) > 0).tolist()
         aborted = self._where(
             ids, _at(np.asarray(info.read_abort), ids)).tolist()
+        # Lanes this step woke (cfg.hibernate): a batch stamped on one
+        # pays the barrier that wakes its followers.
+        woke = set(woke_ids.tolist()) if stamped and woke_ids is not None \
+            else ()
         with self._read_lock:
             for g in stamped:
                 b = self._reads_offered.pop(g, None)
@@ -3876,6 +4029,7 @@ class RaftNode:
                      "beyond the offer")
                 b.lease = bool(read_lease[g])
                 b.carried = bool(read_carried[g])
+                b.woke = g in woke
                 self._reads_pending.setdefault(g, deque()).append(
                     (int(read_idx[g]), b))
                 m = self.metrics
@@ -3943,7 +4097,7 @@ class RaftNode:
         if not ready:
             return len(due)
         now = time.monotonic()
-        queries = lease_hits = lease_carried = 0
+        queries = lease_hits = lease_carried = woke = 0
         for g, idx, offer in ready:
             machine = self.dispatcher.machine(g)
             rd = getattr(machine, "read", None)
@@ -3968,11 +4122,15 @@ class RaftNode:
             # served a query: never more than ``queries``.
             lease_hits += int(offer.lease and queries > served)
             lease_carried += int(offer.carried and queries > served)
+            woke += int(offer.woke and queries > served)
         self.metrics["reads_served"] += queries
         # What this tick served, on its raft.reads span.
+        # ``woke``: barriers stamped in a step that woke their lane from
+        # hibernation, where they served a query (woke + lease_hits <=
+        # queries; 0 where the field is off).
         self._stages.note(queries=queries, barriers=len(ready),
                           lease_hits=lease_hits,
-                          lease_carried=lease_carried)
+                          lease_carried=lease_carried, woke=woke)
         return len(due)
 
     def _reject_reads(self, g: int, exc: Optional[Exception] = None,
@@ -4474,6 +4632,8 @@ class RaftNode:
                         blob += pack_hops(HOP_ECHO, self.node_id, echoes)
             if blob:
                 self.transport.send_slice(p, blob)
+                if self.cfg.hibernate:
+                    self._beat_sent.add(p)
 
     # -------------------------------------------------------------- maintain
 
@@ -4862,7 +5022,18 @@ class RaftNode:
         while the fetch was in flight, this download belongs to a dead
         incarnation — it must neither surface its bytes, nor fail the NEW
         incarnation's pending, nor cancel its in-flight marker."""
-        tmp = os.path.join(self.data_dir, f"snap-recv-g{g}-e{ep}.tmp")
+        # A file of its own for every download: a lane leaves
+        # _snap_inflight when its download is done, the device goes on
+        # asking until the snapshot is INSTALLED (the next dispatch), and a
+        # pipelined node's pending host phase, run as that dispatch's
+        # barrier, then starts a second download beside the tick thread
+        # that is archiving the first.  Under one name the second
+        # truncated what the first install was reading (an empty snapshot
+        # installed at the right index: tests/test_host_rows.py's
+        # snapshot_install twins, one run in ten under load).
+        self._snap_serial += 1
+        tmp = os.path.join(self.data_dir,
+                           f"snap-recv-g{g}-e{ep}-{self._snap_serial}.tmp")
         ok = False
 
         def current() -> bool:

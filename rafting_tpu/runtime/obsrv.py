@@ -207,6 +207,10 @@ class ObservabilityServer:
             "groups_active": int(n.h_active.sum()),
             "groups_led": led,
             "groups_ready": ready,
+            # Hibernation (RaftConfig.hibernate_regions): lanes that do
+            # not tick; /metrics has the counters (lane_sleeps, lane_wakes
+            # and the wakes by cause, node_beats_sent).
+            "groups_asleep": int(n.h_asleep.sum()),
             "storage": storage,
             "latency": latency,
             "overload": overload,

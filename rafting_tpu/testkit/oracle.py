@@ -205,6 +205,14 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         vote_hold = s["lease.vote_hold"].copy()
         carry_bar = s["lease.carry_bar"].copy()
 
+    # Hibernation (cfg.hibernate; kernel "hibernation" block): the three
+    # lanes, and the two flags on the wire.
+    hiber = cfg.hibernate
+    if hiber:
+        asleep_a = s["hib.asleep"].copy()
+        busy_at = s["hib.busy_at"].copy()
+        slept = s["hib.slept"].copy()
+
     old_term = term.copy()
     old_voted = voted.copy()
     old_last = last.copy()
@@ -256,6 +264,10 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     if has_qc:
         info["cq_stepdown"] = zb(G)
         info["cq_veto"] = zi(G)
+    if hiber:
+        out["ae_sleep"] = zb(P, G)
+        out["aer_asleep"] = zb(P, G)
+        info["asleep"] = zb(G)
 
     for g in range(G):
         log = _Log(ring[g], cring[g], int(base[g]), int(base_term[g]),
@@ -318,6 +330,16 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         if any(grant_rv):
             elect_dl[g] = now + rand_to[g]
         lease_open = now >= elect_dl[g] or leader_id[g] == NIL
+        if hiber:
+            asleep0 = bool(asleep_a[g])
+            host_req = (int(h["submit_n"][g]) > 0 or int(h["read_n"][g]) > 0
+                        or int(h["conf_voters"][g]) != 0
+                        or int(h["xfer_target"][g]) >= 0
+                        or int(h["compact_to"][g]) > 0
+                        or bool(h["snap_done"][g]))
+            # (a): a sleeper's deadline is not reached.
+            lease_open = ((now >= elect_dl[g] and not asleep0)
+                          or leader_id[g] == NIL)
         if carry:
             # A restart that recovered a term holds its pre-vote (6b b).
             lease_open = lease_open and now >= int(vote_hold[g])
@@ -447,6 +469,35 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
                 # echoes it on success AND failure — any same-term reply
                 # proves the AE was processed).
                 out["aer_tick"][p, g] = ib["ae_tick"][p, g]
+        if hiber:
+            # The sleep heartbeat this follower agrees to.
+            sleep_ok = (ae_any and acc and bool(ib["ae_sleep"][ae_peer, g])
+                        and n_e == 0 and log.last == prev_i
+                        and commit[g] == lc and commit[g] == log.last)
+            msgs_busy = loud = False
+            for p in range(P):
+                if p == me or not active[g]:
+                    continue
+                keeps = sleep_ok and ae_ok[p] and p == ae_peer
+                out["aer_asleep"][p, g] = keeps
+                aer = bool(ib["aer_valid"][p, g])
+                hb_reply = (aer and bool(ib["aer_empty"][p, g])
+                            and bool(ib["aer_success"][p, g])
+                            and int(ib["aer_term"][p, g]) == term[g])
+                q_aer = hb_reply and bool(ib["aer_asleep"][p, g])
+                other = any(bool(ib[k][p, g]) for k in
+                            ("rv_valid", "rvr_valid", "is_valid",
+                             "isr_valid", "tn_valid"))
+                ae = bool(ib["ae_valid"][p, g])
+                msgs_busy = msgs_busy or ae or other or (aer and not hb_reply)
+                loud = loud or (ae and not keeps) or other \
+                    or (aer and not q_aer)
+            asleep = ((sleep_ok or asleep0) and not loud and bool(active[g])
+                      and not (bool(h["wake"][g])
+                               or (host_req and role[g] == LEADER)))
+            woke = asleep0 and not asleep
+            if woke and role[g] != LEADER:
+                elect_dl[g] = now + rand_to[g]
 
         # ---- 5. InstallSnapshot -------------------------------------------
         # (reference Follower.installSnapshot:130-153 + host completion,
@@ -569,6 +620,16 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
             if not r:
                 continue
             echoed = int(ib["aer_tick"][p, g])
+            if hiber:
+                # The member's word, for a heartbeat of this quiet
+                # stretch; and (b): no evidence in a step entered asleep.
+                if (bool(ib["aer_empty"][p, g])
+                        and bool(ib["aer_success"][p, g])
+                        and bool(ib["aer_asleep"][p, g])
+                        and echoed >= int(busy_at[g]) + cfg.election_ticks):
+                    slept[g, p] = True
+                if asleep0:
+                    continue
             if cfg.read_lease:
                 if now - echoed <= cfg.read_fresh_ticks:
                     read_evid[g, p] = now
@@ -602,6 +663,10 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
                 qc_since[g] = now
             cq_due = (active[g] and role[g] == LEADER
                       and now - int(qc_since[g]) >= cfg.election_ticks)
+            if hiber:
+                if woke:
+                    qc_since[g] = now
+                cq_due = cq_due and not asleep and not woke
             if cq_due:
                 flags = [p == me or int(qc_heard[g, p]) >= int(qc_since[g])
                          for p in range(P)]
@@ -624,7 +689,7 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         timer_cand = False
         # Only voters campaign (§6; kernel phase 7 gate on C1).
         if (active[g] and now >= elect_dl[g] and role[g] != LEADER
-                and voter_self):
+                and voter_self and not (hiber and asleep)):
             if cfg.pre_vote and carry:
                 # A candidate whose election ran out asks again.
                 start_pre = True
@@ -791,6 +856,29 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         # pipelined up to inflight_limit batches, Leadership.java:10-11;
         # fan-out gated to MEMBER slots of the active config.)
         heartbeat = role[g] == LEADER and (now >= hb_due[g] or read_kick)
+        quiet = False
+        if hiber:
+            lead = bool(active[g]) and role[g] == LEADER
+            others = [p for p in range(P)
+                      if p != me and (member2 >> p) & 1]
+            moved = bool(active[g]) and (
+                msgs_busy or host_req or bool(h["wake"][g]) or woke
+                or term[g] != old_term[g] or role[g] != old_role[g]
+                or log.last != old_last[g] or commit[g] != old_commit[g])
+            if moved:
+                busy_at[g] = now
+                slept[g, :] = False
+            quiet = (lead and now - int(busy_at[g]) >= cfg.election_ticks
+                     and all(int(match_idx[g, p]) == log.last for p in others)
+                     and commit[g] == log.last and int(rq_len[g]) == 0
+                     and cidx2 <= commit[g] and vnew2 == 0
+                     and int(xfer_to[g]) == NIL and not need_snap[g].any())
+            go_sleep = (quiet and not asleep
+                        and all(slept[g, p] for p in others))
+            asleep = asleep or go_sleep
+            heartbeat = role[g] == LEADER and (
+                (now >= hb_due[g] and not asleep) or read_kick
+                or (woke and lead))
         if active[g] and role[g] == LEADER:
             for p in range(P):
                 if p == me or not (member2 >> p) & 1:
@@ -834,6 +922,8 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
                     out["ae_commit"][p, g] = commit[g]
                     out["ae_n"][p, g] = n_send
                     out["ae_tick"][p, g] = now
+                    if hiber:
+                        out["ae_sleep"][p, g] = send_hb and quiet
                     for k in range(B):
                         idx = int(send_next[g, p]) + k
                         out["ae_ents"][p, g, k] = (
@@ -860,6 +950,11 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
                     sent_at[g, p] = now
         if heartbeat:
             hb_due[g] = now + cfg.heartbeat_ticks
+        if hiber and go_sleep:
+            inflight[g, :] = 0
+            hb_inflight[g, :] = 0
+            read_evid[g, :] = 0
+            slept[g, :] = False
 
         # Leader readiness (reference Leader.isReady, Leader.java:52-64),
         # as a masked quorum over the active config; self counts iff self
@@ -943,11 +1038,17 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
 
         # §6 epilogue (kernel post-phase-10): a leader removed by its
         # committed simple config resigns.
-        if (active[g] and role[g] == LEADER and vnew2 == 0
-                and cidx2 <= commit[g] and not (voters2 >> me) & 1):
+        resigned = bool(active[g] and role[g] == LEADER and vnew2 == 0
+                        and cidx2 <= commit[g] and not (voters2 >> me) & 1)
+        if resigned:
             role[g] = FOLLOWER
             leader_id[g] = NIL
             elect_dl[g] = now + rand_to[g]
+        if hiber:
+            if active[g] and (commit[g] != old_commit[g] or resigned):
+                busy_at[g] = now
+            asleep_a[g] = asleep and not resigned
+            info["asleep"][g] = asleep_a[g]
 
         info["conf_word"][g] = w2
         info["conf_idx"][g] = cidx2
@@ -1056,4 +1157,7 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     if carry:
         new_state.update({"lease.vote_hold": vote_hold,
                           "lease.carry_bar": carry_bar})
+    if hiber:
+        new_state.update({"hib.asleep": asleep_a, "hib.busy_at": busy_at,
+                          "hib.slept": slept})
     return new_state, out, info

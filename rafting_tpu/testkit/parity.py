@@ -37,6 +37,9 @@ MSG_GROUPS = {
 }
 
 
+HIBERNATE_FLAGS = {"ae_valid": ["ae_sleep"], "aer_valid": ["aer_asleep"]}
+
+
 def assert_messages_equal(kernel_out: Messages, oracle_out: dict, tag: str):
     k = _np(kernel_out)
     for vfield, deps in MSG_GROUPS.items():
@@ -44,6 +47,8 @@ def assert_messages_equal(kernel_out: Messages, oracle_out: dict, tag: str):
         np.testing.assert_array_equal(
             kv, ov, err_msg=f"{tag}: {vfield} mismatch")
         mask = kv
+        # The flags of a feature that is on ride their kind's lane.
+        deps = deps + [f for f in HIBERNATE_FLAGS.get(vfield, ()) if f in k]
         for f in deps:
             a, b = k[f], oracle_out[f]
             m = mask[..., None] if a.ndim == 3 else mask
@@ -68,7 +73,8 @@ def assert_info_equal(kernel_info, oracle_info: dict, tag: str):
 
 def route_numpy(outboxes, conn):
     """inbox[dst].field[src] = outbox[src].field[dst], masked by conn."""
-    fields = [f.name for f in dataclasses.fields(Messages)]
+    fields = [f.name for f in dataclasses.fields(Messages)
+              if getattr(outboxes[0], f.name) is not None]
     raw = {f: np.stack([np.asarray(getattr(ob, f)) for ob in outboxes])
            for f in fields}  # [N(src), P(dst), G, ...]
     inboxes = []
@@ -89,7 +95,8 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
                drop_p: float = 0.15, part_p: float = 0.1,
                crash_p: float = 0.0, stall_p: float = 0.0,
                conf_p: float = 0.0, xfer_p: float = 0.0,
-               n_voters=None, arrival_p: float = 0.0):
+               n_voters=None, arrival_p: float = 0.0,
+               calm=(), wake_p: float = 0.0):
     """``conf_p``/``xfer_p``: per-group per-tick probability of offering a
     random membership-change / leadership-transfer request through the
     host inbox (the §6 plane's chaos input — only leaders take them, and
@@ -99,21 +106,32 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
     advance the engine's clock (``HostInbox.clock`` 0: a step the runtime
     starts for arriving work between two timer ticks); each node's first
     step always advances it, as a loop's does.  At 0.0 (the default) no
-    draw is made and the schedule is the one it always was."""
+    draw is made and the schedule is the one it always was.
+    ``calm``: ``(start, end)`` tick ranges in which nothing is dropped,
+    cut, crashed or stalled and the hosts offer nothing: long enough, a
+    cluster with ``cfg.hibernate`` falls asleep in them and the chaos
+    after them wakes it.  ``wake_p``: per-group per-tick probability of
+    the host's peer-lost signal (``HostInbox.wake``; only with
+    ``cfg.hibernate``).  Neither draws anything at its default."""
     N, G = cfg.n_peers, cfg.n_groups
     rng = np.random.default_rng(seed)
     states = [init_state(cfg, i, seed=seed, n_voters=n_voters)
               for i in range(N)]
     outboxes = [Messages.empty(cfg) for _ in range(N)]
     infos = [None] * N
+    was_asleep = [np.zeros(G, bool) for _ in range(N)]
     partition_left = 0
     partition = None
     stats = {"partitions": 0, "crashes": 0, "stalls": 0, "arrival_steps": 0,
-             "lease_reads": 0, "lease_carried": 0}
+             "lease_reads": 0, "lease_carried": 0, "asleep_steps": 0,
+             "wakes": 0}
 
     for t in range(n_ticks):
+        quiet = any(a <= t < b for a, b in calm)
         # --- chaos schedule: random drops plus occasional partitions -----
-        if partition_left == 0 and rng.random() < part_p:
+        if quiet:
+            partition_left = 0
+        elif partition_left == 0 and rng.random() < part_p:
             stats["partitions"] += 1
             k = rng.integers(1, N)
             side = rng.permutation(N)[:k]
@@ -127,7 +145,8 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
             partition_left -= 1
         else:
             conn = np.ones((N, N), bool)
-        conn &= rng.random((N, N)) > drop_p
+        if not quiet:
+            conn &= rng.random((N, N)) > drop_p
         np.fill_diagonal(conn, True)
 
         # Crash-restarts and clock stalls (the device nemesis fault model,
@@ -137,8 +156,8 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
         # covers the post-crash lanes, read FIFO drop included); a stalled
         # node does not step at all and loses inbound + sends nothing,
         # drifting its clock from its peers' (the lease's adversary).
-        crashed = rng.random(N) < crash_p
-        stalled = rng.random(N) < stall_p
+        crashed = rng.random(N) < (0.0 if quiet else crash_p)
+        stalled = rng.random(N) < (0.0 if quiet else stall_p)
         stats["crashes"] += int(crashed.sum())
         stats["stalls"] += int(stalled.sum())
         for n in range(N):
@@ -184,10 +203,14 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
                     and int(states[n].now) > 0:
                 host = host.replace(clock=np.asarray(0, np.int32))
                 stats["arrival_steps"] += 1
-            if conf_p or xfer_p:
+            if (conf_p or xfer_p) and not quiet:
                 host = host.replace(conf_voters=cv, conf_learners=cl,
                                     xfer_target=xt)
-            if infos[n] is not None:
+            if wake_p:
+                host = host.replace(wake=rng.random(G) < wake_p)
+            if quiet:
+                pass
+            elif infos[n] is not None:
                 prev = infos[n]
                 compact = np.where(
                     rng.random(G) < 0.3,
@@ -222,6 +245,11 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
             stats["lease_reads"] += int(np.asarray(k_info.read_lease).sum())
             stats["lease_carried"] += int(
                 np.asarray(k_info.read_carried).sum())
+            if cfg.hibernate:
+                now_asleep = np.asarray(k_info.asleep)
+                stats["asleep_steps"] += int(now_asleep.sum())
+                stats["wakes"] += int((was_asleep[n] & ~now_asleep).sum())
+                was_asleep[n] = now_asleep
         outboxes = new_outboxes
 
     # The schedule must have actually elected leaders / committed entries.

@@ -63,6 +63,13 @@ CONF_OP_CHANGE, CONF_OP_TRANSFER = 1, 2
 # reader's dispatch unhandled, and the ignored context simply expires
 # leader-side (never fabricates a latency).
 HOPS = 9
+# The node-level beat (RaftConfig.hibernate_regions; runtime/node.py): an
+# empty frame a node sends a peer it sent nothing else in a period, so
+# that a node whose every lane sleeps is still heard.  It carries its
+# source and no message; a transport counts it, as it counts every frame
+# of a peer's (``heard``), and hands nothing on.  Outside SCHEMA_TAG like
+# HOPS: a node without hibernation never sends one and ignores one.
+BEAT = 10
 
 MAX_BODY = 64 << 20  # 64 MB cap, matching the reference (EventCodec.java:26)
 
@@ -150,6 +157,21 @@ KIND_FIELDS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # TimeoutNow (§3.10 leadership transfer).
     "tn": ("tn_valid", ("tn_term",)),
 }
+# Flags of an engine feature that is off are not fields of the cluster's
+# Messages (core/types.py: None leaves), and the wire is then what it
+# always was.  Where the feature is on (every member of a cluster runs one
+# configuration) they close their kind's section, after the fields above.
+OPTIONAL_FIELDS: Dict[str, Tuple[str, ...]] = {
+    "ae": ("ae_sleep",), "aer": ("aer_asleep",)}
+
+
+def kind_fields(kind: str, have) -> Tuple[str, Tuple[str, ...]]:
+    """``KIND_FIELDS[kind]`` with the kind's optional fields that ``have``
+    (a template, or the planes of an outbox) holds."""
+    vfield, dfields = KIND_FIELDS[kind]
+    extra = tuple(f for f in OPTIONAL_FIELDS.get(kind, ()) if f in have)
+    return vfield, dfields + extra if extra else dfields
+
 KIND_IDS = {k: i for i, k in enumerate(KIND_FIELDS)}
 KIND_BY_ID = {i: k for k, i in KIND_IDS.items()}
 
@@ -275,6 +297,14 @@ def unpack_hops(body: bytes):
     return direction, origin, [
         rec.unpack_from(body, _HOPS_HDR.size + i * rec.size)
         for i in range(n)]
+
+
+def pack_beat(node_id: int) -> bytes:
+    return struct.pack("<I", node_id)
+
+
+def unpack_beat(body: bytes) -> int:
+    return struct.unpack("<I", body)[0]
 
 
 def pack_snap_req(group: int, index: int, term: int) -> bytes:
@@ -440,7 +470,7 @@ def pack_kind_section(kind: str, fields: Dict[str, np.ndarray],
     are dropped/deferred exactly like a Python-path payload miss.  A
     ``None`` return falls back to the Python loop.
     """
-    vfield, dfields = KIND_FIELDS[kind]
+    vfield, dfields = kind_fields(kind, fields)
     if cols is None:
         cols = np.nonzero(fields[vfield])[0].astype(np.uint32)
     else:
@@ -612,7 +642,7 @@ def unpack_slice(body: bytes, template: Dict[str, Tuple[np.dtype, tuple]],
         if kid not in KIND_BY_ID:
             raise IOError(f"unknown message kind id {kid}")
         kind = KIND_BY_ID[kid]
-        vfield, dfields = KIND_FIELDS[kind]
+        vfield, dfields = kind_fields(kind, template)
         if n_cols == 0:
             continue
         need(4 * n_cols, off)

@@ -123,6 +123,8 @@ class LoopbackTransport:
         self.result_encoder = result_encoder
         self.read_handler = read_handler
         self.conf_node = conf_node
+        # Frames delivered per source node (transport/tcp.py ``heard``).
+        self.heard: Dict[int, int] = {}
 
     def start(self) -> None:
         self.net.transports[self.node_id] = self
@@ -182,7 +184,11 @@ class LoopbackTransport:
             if ftype == codec.MSGS:
                 src, fields, payloads = codec.unpack_slice(
                     body, self.template, self.cfg.n_groups)
+                self.heard[src] = self.heard.get(src, 0) + 1
                 self.on_slice(src, fields, payloads)
+            elif ftype == codec.BEAT:
+                src = codec.unpack_beat(body)
+                self.heard[src] = self.heard.get(src, 0) + 1
             elif ftype == codec.HOPS:
                 # Hop-tracing sideband — ``on_hops`` is assigned by the
                 # runtime after construction (see TcpTransport); unset

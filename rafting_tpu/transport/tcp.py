@@ -235,6 +235,11 @@ class TcpTransport:
         self._hello = codec.pack_hello(node_id, cfg.n_groups, cfg.n_peers,
                                        cfg.batch)
         self._senders: Dict[int, PeerSender] = {}
+        # Frames read per source node, of whatever type, once its channel
+        # has said who it is: what the runtime's node-level beat watches
+        # (a count that stands still is a silent peer).  Reader threads
+        # bump it; a lost bump costs nothing.
+        self.heard: Dict[int, int] = {}
         self._server: Optional[socket.socket] = None
         self._stop = threading.Event()
         self._threads = []
@@ -377,6 +382,8 @@ class TcpTransport:
                 if not data:
                     return
                 for ftype, body in reader.feed(data):
+                    if src is not None:
+                        self.heard[src] = self.heard.get(src, 0) + 1
                     if ftype == codec.HELLO:
                         nid, G, P, B, tag = codec.unpack_hello(body)
                         if (G, P, B) != (self.cfg.n_groups, self.cfg.n_peers,

@@ -121,3 +121,43 @@ def test_parity_tikv_timing_with_reads(hb):
     else:
         assert stats["lease_carried"] > 0
         assert all(s.lease is not None for s in states)
+
+
+@pytest.mark.parametrize("hibernate", [False, True])
+@pytest.mark.parametrize("seed", [23, 5])
+def test_parity_hibernation(seed, hibernate):
+    """TiKV's timing with calm stretches in the schedule, long enough
+    for a cluster with ``hibernate`` to fall asleep, and chaos (drops,
+    cuts, restarts, stalls, transfers, config changes, the host's
+    peer-lost signal, steps that do not advance the clock) to wake it:
+    the oracle's timers, heartbeats and read plane move in lock step at
+    both settings, leaf for leaf.  On, lanes really sleep and wake; off,
+    the state holds no hibernation lane and the wire no flag (core/
+    step.py "hibernation", case e; the digest above pins the program
+    itself)."""
+    cfg = EngineConfig(n_groups=8, n_peers=3, log_slots=16, batch=4,
+                       max_submit=4, election_ticks=10, heartbeat_ticks=2,
+                       rpc_timeout_ticks=5, hibernate=hibernate)
+    states, stats = run_parity(
+        seed, n_ticks=150, cfg=cfg, crash_p=0.03, stall_p=0.04,
+        xfer_p=0.02, conf_p=0.01, arrival_p=0.3,
+        calm=((30, 70), (95, 130)), wake_p=0.01 if hibernate else 0.0)
+    assert stats["crashes"] > 0 and stats["lease_reads"] > 0
+    if hibernate:
+        assert stats["asleep_steps"] > 200 and stats["wakes"] > 10
+        assert all(s.hib is not None for s in states)
+    else:
+        assert stats["asleep_steps"] == 0
+        assert all(s.hib is None for s in states)
+
+
+def test_parity_hibernation_five_nodes_check_quorum():
+    """Five members, CheckQuorum on: a leader asleep is not deposed for
+    the silence it agreed to, and the oracle agrees step for step."""
+    cfg = EngineConfig(n_groups=4, n_peers=5, log_slots=16, batch=2,
+                       max_submit=2, election_ticks=8, heartbeat_ticks=2,
+                       rpc_timeout_ticks=6, check_quorum=True,
+                       hibernate=True)
+    _, stats = run_parity(11, n_ticks=120, cfg=cfg, drop_p=0.25,
+                          part_p=0.15, calm=((40, 80),))
+    assert stats["asleep_steps"] > 100 and stats["wakes"] > 0

@@ -181,10 +181,12 @@ def _index_rows(hlo):
     return out
 
 
-@pytest.mark.parametrize("columns_in", [True, False],
-                         ids=["columns-in", "dense-in"])
+@pytest.mark.parametrize("columns_in, config", [
+    (True, "multiraft-100k-3v"), (False, "multiraft-100k-3v"),
+    (True, "multiraft-100k-3v-hib")],
+    ids=["columns-in", "dense-in", "columns-in-hibernate"])
 def test_column_step_compiles_at_100k_lanes_and_addresses_k_rows(
-        one_chip, columns_in):
+        one_chip, columns_in, config):
     """node_step_columns at the 100,000-Region cell's shape, as its
     configuration file builds the engine, in both forms of its operand:
     it fits the chip three nodes at a time, and whatever gathers and
@@ -192,15 +194,18 @@ def test_column_step_compiles_at_100k_lanes_and_addresses_k_rows(
     inbox's columns, the compaction of the outbox) address at most P x K
     index rows a leaf (the [P, G] leaves of a kind share one scatter and
     one gather, an index row a leaf, peer and column): none walks the G
-    lanes."""
+    lanes.  The same with hibernation compiled in (PR 41's cell: three
+    more lanes of state, a flag each way on the wire, one more level and
+    one more row field), which adds no gather and no scatter."""
     from rafting_tpu.api import RaftConfig
     with open(os.path.join(REPO, "benchmark", "configs",
-                           "multiraft-100k-3v.json")) as f:
+                           config + ".json")) as f:
         raft = json.load(f)["raft_config"]
     uris = [f"raft://127.0.0.1:{7001 + i}" for i in range(3)]
     cfg = RaftConfig(local=uris[0], peers=tuple(uris[1:]),
                      data_dir="unused", **raft).engine_config()
     assert (cfg.n_groups, cfg.n_peers) == (100_000, 3)
+    assert cfg.hibernate == config.endswith("-hib")
     lay = column_layouts(cfg, True)
     assert lay is not None
     P, K = cfg.n_peers, lay.columns.K

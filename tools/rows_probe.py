@@ -2,7 +2,7 @@
 """Chip probes for the row form of a column step's [G] planes (PERF.md, PR 38).
 
     python3 tools/rows_probe.py device [--rows 512,512 --rows 1024,512 ...]
-    python3 tools/rows_probe.py check [--steps 40]
+    python3 tools/rows_probe.py check [--steps 40] [--config NAME]
     python3 tools/rows_probe.py run --half up|both --workload W --seed N \
         --seconds S [--trace 1]
 
@@ -14,7 +14,10 @@ messages and a handful of rows, as a step between two heartbeat rounds does.
 
 ``check``: three nodes at that shape stepped through the packed step and
 through the row form on this backend; every state, outbox, mirror and the
-device's durable plane must agree after every step.
+device's durable plane must agree after every step (``--config
+multiraft-100k-3v-hib --steps 90``: with hibernation compiled into the step,
+long enough for the lanes to fall asleep; the line every ten steps counts
+them).
 
 ``run``: ``benchmark/run.py`` with, for ``--half up``, every Readback taken
 whole (``pack_readback``) and the mirrors swapped as a packed step's are: the
@@ -42,10 +45,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+CONFIG = "multiraft-100k-3v"
+
+
 def engine_config():
     from rafting_tpu.api import RaftConfig
     with open(os.path.join(ROOT, "benchmark", "configs",
-                           "multiraft-100k-3v.json")) as f:
+                           CONFIG + ".json")) as f:
         raft = json.load(f)["raft_config"]
     uris = [f"raft://127.0.0.1:{7001 + i}" for i in range(3)]
     return RaftConfig(local=uris[0], peers=tuple(uris[1:]),
@@ -143,6 +149,30 @@ def device(rows):
             first = time.perf_counter() - t0
             print(f"  dense operand + pack_outbox: first call {first:.1f} s, "
                   f"median {median_ms(dense_in, 10):.3f} ms", flush=True)
+
+
+def on_valid(msgs):
+    """``msgs`` with every field zeroed outside its kind's valid lanes.
+    What a step writes there follows what its inbox held on lanes that
+    carried no message (a reply's match hint is computed from the
+    request's fields, valid or not), and the two forms differ exactly
+    there: a dense inbox keeps what the sender's planes held, an inbox
+    expanded from columns holds zeros.  No receiver reads such a lane.
+    (Unseen while every step after the elections was a heartbeat round
+    and crossed densely in both forms; a store whose lanes sleep takes
+    the column form on most steps.)"""
+    import numpy as np
+    out = {}
+    for name in msgs.__dataclass_fields__:
+        a = getattr(msgs, name)
+        if a is None:
+            continue
+        a = np.asarray(a)
+        valid = np.asarray(getattr(msgs, name.split("_", 1)[0] + "_valid"))
+        out[name] = np.where(
+            valid.reshape(valid.shape + (1,) * (a.ndim - 2)), a,
+            np.zeros((), a.dtype))
+    return msgs.replace(**out)
 
 
 def check(steps, whole_in=False):
@@ -257,8 +287,8 @@ def check(steps, whole_in=False):
             np.testing.assert_array_equal(
                 np.asarray(carry[n].durable), tails[n], tag + " durable")
             got_out = jax.device_get(lay.columns.unstack(o_dense))
-            for a, b in zip(jax.tree.leaves(got_out),
-                            jax.tree.leaves(want.outbox)):
+            for a, b in zip(jax.tree.leaves(on_valid(got_out)),
+                            jax.tree.leaves(on_valid(want.outbox))):
                 np.testing.assert_array_equal(a, b, tag + " outbox")
             view = rout.view(jax.device_get(r_pair))
             words, flags = mirror[n]
@@ -288,7 +318,9 @@ def check(steps, whole_in=False):
             tails[n] = np.asarray(want.info.log_tail)
         if t % 10 == 0:
             print("step", t, seen, "led", [int((np.asarray(
-                s.role) == 3).sum()) for s in plain], flush=True)
+                s.role) == 3).sum()) for s in plain], "asleep",
+                [0 if s.hib is None else int(np.asarray(s.hib.asleep).sum())
+                 for s in plain], flush=True)
     print("CHECK OK", seen, flush=True)
 
 
@@ -391,6 +423,7 @@ def run(a):
 
 
 def main():
+    global CONFIG
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="cmd", required=True)
     d = sub.add_parser("device")
@@ -398,6 +431,10 @@ def main():
     c = sub.add_parser("check")
     c.add_argument("--steps", type=int, default=40)
     c.add_argument("--whole-in", type=int, default=0)
+    c.add_argument("--config", default=CONFIG,
+                   help="benchmark/configs/<name>.json: the shape and the "
+                        "engine's fields (multiraft-100k-3v-hib: the step "
+                        "with hibernation compiled in)")
     r = sub.add_parser("run")
     r.add_argument("--half", choices=("up", "both"), required=True)
     r.add_argument("--workload", required=True)
@@ -408,6 +445,7 @@ def main():
     r.add_argument("--watch", type=int, default=0)
     a = ap.parse_args()
     if a.cmd == "check":
+        CONFIG = a.config
         check(a.steps, bool(a.whole_in))
     elif a.cmd == "device":
         device([tuple(int(k) for k in s.split(","))
