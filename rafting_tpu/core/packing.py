@@ -1,27 +1,38 @@
 """Packed pytrees: every leaf of a pytree in a few flat buffers, so that a
-tree of some eighty small planes crosses between host and device in two
-transfers instead of one per leaf.
+tree of some eighty small planes crosses between host and device in ONE
+transfer instead of one per leaf.
 
-The engine's planes are ``int32`` and ``bool`` only.  A :class:`Layout`
-puts the ``int32`` leaves, ravelled and end to end, into ``int32`` word
-buffers and the ``bool`` leaves into ``uint8`` flag buffers (a byte per
-flag: the host then sees ``bool`` planes as views, with the dtype its
-masks and its codec expect, at a quarter of the bytes a widened flag would
-cross with).  A buffer holds whole leaves and is closed once another leaf
-would take it past ``CHUNK_BYTES``: at the sizes a call's fixed cost
-matters (a transfer of a few KB and one of 0.5 MB both cost 0.6 ms on a
-TPU v5e) that is one word buffer and one flag buffer, and a tree of tens of
-MB crosses in pieces of a few MB, which the runtime moves side by side (one
-48 MB fetch took 55 ms there, twelve of 4 MB 4 ms).  The layout is derived
-from the tree's own structure (``jax.tree.flatten`` order; a ``None``
-subtree has no leaves and so no room), never from a list of field names: a
-field added to ``Messages`` or ``StepInfo`` finds its place by itself.
+The engine's planes are ``int32`` and ``bool`` only, and there is one kind
+of buffer: ``int32`` words.  A :class:`Layout` puts a buffer's ``int32``
+leaves, ravelled and end to end, at its head and its ``bool`` leaves behind
+them as a byte each, the flag region padded to a whole word (a byte per
+flag: the host then sees ``bool`` planes as views, with the dtype its masks
+and its codec expect, at a quarter of the bytes a widened flag would cross
+with; in a buffer of their own they cost every step a transfer more each
+way).  A buffer holds whole leaves in the tree's order and is closed to
+words once another ``int32`` leaf would take it past ``CHUNK_BYTES``; a
+``bool`` leaf goes into the first buffer that has room left for it, and
+into a new one when none has.  At the sizes a call's fixed cost matters (a
+transfer of a few KB and one of 0.5 MB both cost 0.6 ms on a TPU v5e) that
+is one buffer, and a tree of tens of MB crosses in pieces of a few MB,
+which the runtime moves side by side (one 48 MB fetch took 55 ms there,
+twelve of 4 MB 4 ms).  The layout is derived from the tree's own structure
+(``jax.tree.flatten`` order; a ``None`` subtree has no leaves and so no
+room), never from a list of field names: a field added to ``Messages`` or
+``StepInfo`` finds its place by itself.
 
 Both directions work on either side of the boundary.  On the host
 ``unpack`` returns numpy **views** into the buffers (no copy: fill an
 ``alloc()``-ed set in place, or read a fetched set where it lies); under
-``jit`` it is static slices and reshapes, and ``pack`` one concatenate of
-the ravelled leaves per buffer.
+``jit`` it is static slices and reshapes (a flag region's words taken apart
+into their bytes first), and ``pack`` one concatenate of the ravelled
+leaves per buffer (the flags' bytes put together into words first).  A
+flag's byte lies where the host's memory has it: byte ``k`` of a word is
+its bits ``8k`` to ``8k + 7`` (little-endian, as every host JAX runs on).
+
+The column and row forms below are regions of such a word buffer too, so
+that a step's rows and columns cross in one array each way
+(:func:`alloc_regions`, :func:`regions`).
 """
 
 from __future__ import annotations
@@ -40,6 +51,71 @@ BOOL = np.dtype(np.bool_)
 # (a larger leaf has a buffer to itself).
 CHUNK_BYTES = 4 << 20
 
+_SHIFTS = (0, 8, 16, 24)        # of a word's four flag bytes
+
+# Under ``jit`` a word's four flag bytes are taken apart and put together
+# 128 words (a vector register's lanes) at a time: the four byte planes of a
+# row of words side by side are 512 values in PLANAR order (byte k of word c
+# at 128 k + c), the flags they stand for are the same values INTERLEAVED
+# (flag 4 c + k), and a fixed 512 x 512 permutation takes one to the other as
+# a matrix product of 0/1 values, exact in bfloat16.  The chip's compiler
+# turns every elementwise spelling of that interleave (a stack and a reshape,
+# a bit-cast to [n, 4] bytes) into a relayout through arrays whose minor
+# dimension is 4: 4.5 ms for the 11 MB of flags of a 100,000-lane packed
+# step, against 6.2 ms for the whole step before (PERF.md, PR 42).
+_LANES = 128
+
+
+def _interleave() -> np.ndarray:
+    """``[512, 512]`` 0/1: planar place ``128 k + c`` -> flag ``4 c + k``."""
+    k, c = np.divmod(np.arange(4 * _LANES), _LANES)
+    perm = np.zeros((4 * _LANES, 4 * _LANES), np.float32)
+    perm[np.arange(4 * _LANES), 4 * c + k] = 1
+    return perm
+
+
+def _permute(values, perm: np.ndarray):
+    """``values [rows, 512]`` (0/1, or a byte) times the permutation."""
+    return jnp.dot(values.astype(jnp.bfloat16),
+                   jnp.asarray(perm, jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+
+
+def flag_words(n: int) -> int:
+    """The words ``n`` flags take, a byte each."""
+    return -(-int(n) // 4)
+
+
+def flags_in(words, n: int):
+    """``[n] bool``: the flags that lie a byte each at the head of the
+    word array ``words``.  Numpy gives a view that shares its memory."""
+    if isinstance(words, np.ndarray):
+        return words.view(FLAG)[:n].view(BOOL)
+    words = words[:flag_words(n)]
+    rows = -(-words.shape[0] // _LANES)
+    words = jnp.pad(words, (0, rows * _LANES - words.shape[0])).reshape(
+        rows, _LANES)
+    planar = jnp.concatenate([(words >> s) & 0xFF for s in _SHIFTS], axis=1)
+    return _permute(planar, _interleave()).reshape(-1)[:n] != 0
+
+
+def words_of(flags):
+    """``[ceil(n / 4)] int32``: the flat ``[n] bool`` array ``flags`` as a
+    byte each, padded with zero bytes to a whole word."""
+    n = flags.shape[0]
+    if isinstance(flags, np.ndarray):
+        out = np.zeros(4 * flag_words(n), FLAG)
+        out[:n] = flags
+        return out.view(WORD)
+    rows = -(-n // (4 * _LANES))
+    flags = jnp.pad(flags, (0, rows * 4 * _LANES - n)).reshape(
+        rows, 4 * _LANES)
+    planar = _permute(flags, _interleave().T).astype(WORD)
+    words = planar[:, :_LANES]
+    for k, s in enumerate(_SHIFTS[1:], 1):
+        words = words | (planar[:, k * _LANES:(k + 1) * _LANES] << s)
+    return words.reshape(-1)[:flag_words(n)]
+
 
 class Layout:
     """Where each leaf of one pytree shape lies, and in which buffer.
@@ -47,34 +123,51 @@ class Layout:
     Built from anything ``jax.tree.flatten`` takes whose leaves carry
     ``shape`` and ``dtype`` (arrays, or what ``jax.eval_shape`` returns).
     ``buffers`` is the (dtype, length) of each buffer, in the order every
-    method takes and returns them.  Hashable and comparable by structure,
-    so a jitted function can take it as a static argument."""
+    method takes and returns them: ``int32`` words all, ``words[b]`` of
+    them a buffer's ``int32`` leaves and the rest its flag region.
+    ``slots`` has a leaf's buffer, offset (in words for an ``int32`` leaf,
+    in bytes from the buffer's start for a ``bool`` one), size, whether it
+    is a flag, and shape.  Hashable and comparable by structure, so a
+    jitted function can take it as a static argument."""
 
-    __slots__ = ("treedef", "slots", "buffers", "_hash")
+    __slots__ = ("treedef", "slots", "buffers", "words", "_hash")
 
     def __init__(self, tree: Any):
         leaves, self.treedef = jax.tree.flatten(tree)
-        slots, kinds, sizes = [], [], []
-        filling = {WORD: None, FLAG: None}      # the open buffer of a kind
-        for leaf in leaves:
-            dt = np.dtype(leaf.dtype)
+        sizes = [int(np.prod(leaf.shape, dtype=np.int64)) for leaf in leaves]
+        kinds = [np.dtype(leaf.dtype) for leaf in leaves]
+        for dt, leaf in zip(kinds, leaves):
             if dt not in (WORD, BOOL):
                 raise TypeError(
                     f"a packed leaf is int32 or bool, not {dt} "
                     f"(shape {tuple(leaf.shape)})")
-            kind = FLAG if dt == BOOL else WORD
-            size = int(np.prod(leaf.shape, dtype=np.int64))
-            b = filling[kind]
-            if b is None or (sizes[b] and (sizes[b] + size) * kind.itemsize
-                             > CHUNK_BYTES):
-                b = filling[kind] = len(kinds)
-                kinds.append(kind)
-                sizes.append(0)
-            slots.append((b, sizes[b], size, tuple(leaf.shape)))
-            sizes[b] += size
-        self.slots: Tuple[Tuple[int, int, int, tuple], ...] = tuple(slots)
+        # The words first, as if there were no flags (the buffers that
+        # hold words are the ones a tree without flags would have); then
+        # each flag leaf into the first buffer with room left for it.
+        place, counts = {}, []          # counts[b] = [words, flags]
+        for i, (dt, size) in enumerate(zip(kinds, sizes)):
+            if dt == WORD:
+                if not counts or (counts[-1][0] and 4 * (counts[-1][0] + size)
+                                  > CHUNK_BYTES):
+                    counts.append([0, 0])
+                place[i] = (len(counts) - 1, counts[-1][0])
+                counts[-1][0] += size
+        for i, (dt, size) in enumerate(zip(kinds, sizes)):
+            if dt == BOOL:
+                b = next((b for b, (w, f) in enumerate(counts)
+                          if 4 * w + f + size <= CHUNK_BYTES), len(counts))
+                if b == len(counts):
+                    counts.append([0, 0])
+                place[i] = (b, counts[b][1])
+                counts[b][1] += size
+        placed = [place[i] + (sizes[i], kinds[i] == BOOL, tuple(leaf.shape))
+                  for i, leaf in enumerate(leaves)]
+        self.words: Tuple[int, ...] = tuple(w for w, _ in counts)
+        self.slots: Tuple[Tuple[int, int, int, bool, tuple], ...] = tuple(
+            (b, off + 4 * self.words[b] * flag, size, flag, shape)
+            for b, off, size, flag, shape in placed)
         self.buffers: Tuple[Tuple[np.dtype, int], ...] = tuple(
-            zip(kinds, sizes))
+            (WORD, w + flag_words(f)) for w, f in counts)
         # Hashed on every call of a function that takes it statically.
         self._hash = hash((self.treedef, self.slots, self.buffers))
 
@@ -93,13 +186,19 @@ class Layout:
     def unpack(self, buffers: Sequence) -> Any:
         """The tree whose leaves lie in ``buffers``.  Numpy buffers give
         views that share their memory; traced or device buffers give
-        slices."""
-        leaves = []
-        for b, off, size, shape in self.slots:
-            flat = buffers[b][off:off + size]
-            if self.buffers[b][0] == FLAG:
-                flat = flat.view(BOOL) if isinstance(flat, np.ndarray) \
-                    else flat != 0
+        slices (of a buffer's flag region taken apart once)."""
+        leaves, region = [], {}
+        for b, off, size, flag, shape in self.slots:
+            buf = buffers[b]
+            if not flag:
+                flat = buf[off:off + size]
+            elif isinstance(buf, np.ndarray):
+                flat = buf.view(FLAG)[off:off + size].view(BOOL)
+            else:
+                w = self.words[b]
+                if b not in region:
+                    region[b] = flags_in(buf[w:], 4 * (buf.shape[0] - w))
+                flat = region[b][off - 4 * w:off - 4 * w + size]
             leaves.append(flat.reshape(shape))
         return jax.tree.unflatten(self.treedef, leaves)
 
@@ -114,10 +213,13 @@ class Layout:
                                   leaves):
                 view[...] = leaf
             return buffers
-        parts = [[] for _ in self.buffers]
-        for (b, _, _, _), leaf in zip(self.slots, leaves):
-            parts[b].append(jnp.ravel(leaf).astype(self.buffers[b][0]))
-        return tuple(jnp.concatenate(p) for p in parts)
+        parts = [([], []) for _ in self.buffers]
+        for (b, _, _, flag, _), leaf in zip(self.slots, leaves):
+            parts[b][flag].append(jnp.ravel(leaf))
+        return tuple(jnp.concatenate(
+            [w.astype(WORD) for w in words]
+            + ([words_of(jnp.concatenate(flags))] if flags else []))
+            for words, flags in parts)
 
 
 # ---------------------------------------------------------------------------
@@ -132,7 +234,7 @@ class Layout:
 # K: the most columns one peer row may hold and still cross in column form;
 # a row beyond it sends the whole step over the dense path, nothing is cut.
 # Chosen once, on a TPU v5e at 100,000 lanes x 3 peers (PERF.md, PR 35): the
-# largest power of two at which (a) the column pair stays within a
+# largest power of two at which (a) the column buffer stays within a
 # transfer's fixed-cost regime (0.5 MB: K <= 1,024; 253 KB here, 0.6-0.7 ms
 # up like a few bytes) and (b) BOTH forms of the column step cost the
 # device less than the packed step they replace (6.17 ms a call there):
@@ -157,8 +259,10 @@ class ColumnLayout:
     leaf's values at them, the ``int32`` leaves side by side in ``words
     [K, W]`` and the ``bool`` leaves in ``flags [K, F]`` (the ``[P, G]``
     leaves first, then the wider ones, each group in the tree's order).
-    Two buffers: the word buffer ``[P, 1 + K + K * W]`` (count, lanes,
-    words) and the flag buffer ``[P, K * F]`` (a byte a flag).  A column is
+    ONE word buffer of ``size`` words (a region of a larger one, where a
+    step's rows cross beside it: :func:`regions`): ``[P, 1 + K + K * W]``
+    words (count, lanes, words), then the flags ``[P, K * F]`` a byte
+    each, padded to a whole word.  A column is
     OCCUPIED when a flag leaf named ``*_valid`` is set in it (every
     ``[P, G]`` flag leaf, in a tree that has none so named): what else a
     column of the dense planes holds there crosses with it, what it holds
@@ -176,7 +280,7 @@ class ColumnLayout:
     ``COLUMNS`` when the layout is built."""
 
     __slots__ = ("treedef", "names", "slots", "occupancy", "P", "G", "K",
-                 "W", "F", "Ws", "Fs", "buffers", "_key", "_hash")
+                 "W", "F", "Ws", "Fs", "size", "_key", "_hash")
 
     def __init__(self, tree: Any):
         flat, self.treedef = jax.tree_util.tree_flatten_with_path(tree)
@@ -212,8 +316,8 @@ class ColumnLayout:
         named = [off for name, off in flags if name.endswith("_valid")]
         self.occupancy: Tuple[int, ...] = tuple(
             named or [off for _, off in flags])
-        self.buffers = ((WORD, (self.P, 1 + self.K + self.K * self.W)),
-                        (FLAG, (self.P, self.K * self.F)))
+        self.size = self.P * (1 + self.K + self.K * self.W) \
+            + flag_words(self.P * self.K * self.F)
         # Compared and hashed on every call of a function that takes it
         # statically.
         self._key = (self.treedef, self.names, self.slots, self.K, self.P,
@@ -229,21 +333,20 @@ class ColumnLayout:
 
     @property
     def nbytes(self) -> int:
-        return sum(np.dtype(dt).itemsize * int(np.prod(shape))
-                   for dt, shape in self.buffers)
+        return WORD.itemsize * self.size
 
     # ------------------------------------------------------------ the parts
 
-    def _parts(self, buffers):
+    def _parts(self, buffer):
         """(n [P], cols [P, K], words [P, K, W], flags [P, K, F]) of a
-        buffer pair, numpy views or traced slices."""
-        wbuf, fbuf = buffers
+        buffer, numpy views or traced slices."""
         P, K = self.P, self.K
-        flags = fbuf.reshape(P, K, self.F)
-        flags = flags.view(BOOL) if isinstance(flags, np.ndarray) \
-            else flags != 0
+        n_words = P * (1 + K + K * self.W)
+        wbuf = buffer[:n_words].reshape(P, 1 + K + K * self.W)
+        flags = flags_in(buffer[n_words:], P * K * self.F)
         return (wbuf[:, 0], wbuf[:, 1:1 + K],
-                wbuf[:, 1 + K:].reshape(P, K, self.W), flags)
+                wbuf[:, 1 + K:].reshape(P, K, self.W),
+                flags.reshape(P, K, self.F))
 
     def _wide(self):
         """(leaf number, kind, offset, width, trail) of the leaves wider
@@ -253,16 +356,19 @@ class ColumnLayout:
 
     # ------------------------------------------------------------- the host
 
-    def alloc(self) -> Tuple[np.ndarray, np.ndarray]:
-        """Fresh buffers on the host that hold no column."""
-        wbuf, fbuf = (np.zeros(shape, dt) for dt, shape in self.buffers)
-        wbuf[:, 1:1 + self.K] = self.G
-        return wbuf, fbuf
+    def clear(self, buffer: np.ndarray) -> np.ndarray:
+        """``buffer`` (zeroed, on the host) made to hold no column."""
+        self._parts(buffer)[1][...] = self.G
+        return buffer
 
-    def view(self, buffers) -> "ColumnView":
-        """The host's view of a numpy buffer pair: fill an ``alloc()``-ed
-        pair in place through it, or read a fetched pair where it lies."""
-        n, cols, words, flags = self._parts(buffers)
+    def alloc(self) -> np.ndarray:
+        """A fresh buffer on the host that holds no column."""
+        return self.clear(np.zeros(self.size, WORD))
+
+    def view(self, buffer) -> "ColumnView":
+        """The host's view of a numpy buffer: fill an ``alloc()``-ed one
+        in place through it, or read a fetched one where it lies."""
+        n, cols, words, flags = self._parts(buffer)
         planes = {}
         for name, (kind, off, trail) in zip(self.names, self.slots):
             block = flags if kind == FLAG else words
@@ -295,12 +401,12 @@ class ColumnLayout:
 
     # ----------------------------------------------------- columns -> dense
 
-    def expand(self, buffers, stacked: bool = False) -> Any:
-        """The dense tree a buffer pair stands for (its :meth:`stack`
+    def expand(self, buffer, stacked: bool = False) -> Any:
+        """The dense tree a buffer stands for (its :meth:`stack`
         with ``stacked``): zero planes with the columns written into them.
         Under ``jit`` one K-row scatter for the stacked ``[P, G]`` leaves of
         a kind and one for each wider leaf: cost follows K, not G."""
-        n, cols, words, flags = self._parts(buffers)
+        n, cols, words, flags = self._parts(buffer)
         P, G, K = self.P, self.G, self.K
         host = isinstance(cols, np.ndarray)
         xp = np if host else jnp
@@ -343,8 +449,8 @@ class ColumnLayout:
 
     # ----------------------------------------------------- dense -> columns
 
-    def compact(self, tree: Any, stacked: bool = False) -> tuple:
-        """The buffer pair of a dense tree (of its :meth:`stack`, with
+    def compact(self, tree: Any, stacked: bool = False):
+        """The buffer of a dense tree (of its :meth:`stack`, with
         ``stacked``): per row the count of occupied columns (the TRUE
         count, also beyond K), the first K of them and every leaf's values
         there.  Under ``jit`` the lanes are found by block (a count per
@@ -393,9 +499,8 @@ class ColumnLayout:
             [n[:, None].astype(WORD), cols.astype(WORD),
              xp.concatenate(blocks[WORD], axis=2).reshape(P, K * self.W)],
             axis=1)
-        fbuf = xp.concatenate(blocks[FLAG], axis=2).reshape(
-            P, K * self.F).astype(FLAG)
-        return wbuf, fbuf
+        return xp.concatenate([wbuf.reshape(-1), words_of(
+            xp.concatenate(blocks[FLAG], axis=2).reshape(-1))])
 
 
 def _first_columns(occ, K: int):
@@ -472,9 +577,11 @@ class RowLayout:
     and every ``[G]`` leaf's values there, field by field: the ``int32``
     leaves in ``words [W, K]``, the ``bool`` leaves in ``flags [F, K]``.
     A leaf of any other shape (a scalar, a few sums) is no plane: it rides
-    the HEADER, ``H`` words behind the count.  Two buffers: the word buffer
-    ``[1 + H + K + W * K]`` (count, header, lanes, words) and the flag
-    buffer ``[F * K]`` (a byte a flag).
+    the HEADER, ``H`` words behind the count.  ONE word buffer of ``size``
+    words (a region of a larger one, where a step's columns cross beside
+    it: :func:`regions`): ``[1 + H + K + W * K]`` words (count, header,
+    lanes, words), then the flags ``[F * K]`` a byte each, padded to a
+    whole word.
 
     Leaves are named by their path (``info.commit``, ``commit``).  The
     ``[G]`` leaves named in ``levels`` are LEVELS (a lane's row crosses
@@ -492,7 +599,7 @@ class RowLayout:
     or ``StepInfo`` finds its place by itself (as an event)."""
 
     __slots__ = ("treedef", "names", "slots", "at", "G", "K", "W", "F", "H",
-                 "Lw", "Ew", "Lf", "Ef", "buffers", "_key", "_hash")
+                 "Lw", "Ew", "Lf", "Ef", "size", "_key", "_hash")
 
     def __init__(self, tree: Any, G: int, K: int, levels=(), carried=()):
         flat, self.treedef = jax.tree_util.tree_flatten_with_path(tree)
@@ -536,8 +643,8 @@ class RowLayout:
         self.W, self.F, self.H = width[WORD], width[FLAG], width[HEAD]
         self.Lw, self.Lf = counts[0]
         self.Ew, self.Ef = counts[1][0] - self.Lw, counts[1][1] - self.Lf
-        self.buffers = ((WORD, 1 + self.H + self.K + self.W * self.K),
-                        (FLAG, self.F * self.K))
+        self.size = 1 + self.H + self.K + self.W * self.K \
+            + flag_words(self.F * self.K)
         self._key = (self.treedef, self.names, self.slots, self.G, self.K)
         self._hash = hash(self._key)
 
@@ -550,20 +657,19 @@ class RowLayout:
 
     @property
     def nbytes(self) -> int:
-        return sum(np.dtype(dt).itemsize * n for dt, n in self.buffers)
+        return WORD.itemsize * self.size
 
     # ------------------------------------------------------------ the parts
 
-    def _parts(self, buffers):
-        """(n, header [H], ids [K], words [W, K], flags [F, K]) of a buffer
-        pair, numpy views or traced slices."""
-        wbuf, fbuf = buffers
+    def _parts(self, buffer):
+        """(n, header [H], ids [K], words [W, K], flags [F, K]) of a
+        buffer, numpy views or traced slices."""
         H, K = self.H, self.K
-        flags = fbuf.reshape(self.F, K)
-        flags = flags.view(BOOL) if isinstance(flags, np.ndarray) \
-            else flags != 0
-        return (wbuf[0], wbuf[1:1 + H], wbuf[1 + H:1 + H + K],
-                wbuf[1 + H + K:].reshape(self.W, K), flags)
+        at = 1 + H + K
+        n_words = at + self.W * K
+        return (buffer[0], buffer[1:1 + H], buffer[1 + H:at],
+                buffer[at:n_words].reshape(self.W, K),
+                flags_in(buffer[n_words:], self.F * K).reshape(self.F, K))
 
     def _head(self, header):
         """name -> the header's leaves, in their own shape and dtype."""
@@ -576,16 +682,19 @@ class RowLayout:
 
     # ------------------------------------------------------------- the host
 
-    def alloc(self) -> Tuple[np.ndarray, np.ndarray]:
-        """Fresh buffers on the host that hold no row."""
-        wbuf, fbuf = (np.zeros(n, dt) for dt, n in self.buffers)
-        wbuf[1 + self.H:1 + self.H + self.K] = self.G
-        return wbuf, fbuf
+    def clear(self, buffer: np.ndarray) -> np.ndarray:
+        """``buffer`` (zeroed, on the host) made to hold no row."""
+        buffer[1 + self.H:1 + self.H + self.K] = self.G
+        return buffer
 
-    def view(self, buffers) -> "RowView":
-        """The host's view of a numpy buffer pair: fill an ``alloc()``-ed
-        pair in place through it, or read a fetched pair where it lies."""
-        return RowView(self, buffers[0], *self._parts(buffers)[1:])
+    def alloc(self) -> np.ndarray:
+        """A fresh buffer on the host that holds no row."""
+        return self.clear(np.zeros(self.size, WORD))
+
+    def view(self, buffer) -> "RowView":
+        """The host's view of a numpy buffer: fill an ``alloc()``-ed one
+        in place through it, or read a fetched one where it lies."""
+        return RowView(self, buffer, *self._parts(buffer)[1:])
 
     def planes(self) -> Tuple[np.ndarray, np.ndarray]:
         """Fresh zero planes on the host, stacked: ``(words [W, G], flags
@@ -603,17 +712,17 @@ class RowLayout:
             elif kind == FLAG and off < self.Lf:
                 np.copyto(flags[off], leaf)
 
-    def whole(self, tree: Any) -> Tuple[np.ndarray, np.ndarray]:
-        """Host: the buffer pair that goes up beside planes that crossed
+    def whole(self, tree: Any) -> np.ndarray:
+        """Host: the buffer that goes up beside planes that crossed
         whole: no row, a count of -1, and ``tree``'s header leaves."""
-        pair = self.alloc()
-        view = self.view(pair)
+        buffer = self.alloc()
+        view = self.view(buffer)
         view.set_n(-1)
         for name, (kind, *_), leaf in zip(
                 self.names, self.slots, self.treedef.flatten_up_to(tree)):
             if kind == HEAD:
                 view.set_head(name, leaf)
-        return pair
+        return buffer
 
     # ------------------------------------------------------- stacked planes
 
@@ -643,12 +752,12 @@ class RowLayout:
 
     # -------------------------------------------------------- rows -> dense
 
-    def expand(self, buffers, words, flags) -> tuple:
+    def expand(self, buffer, words, flags) -> tuple:
         """``(words, flags, header)``: the stacked planes ``words [W, G]``
-        and ``flags [F, G]`` with the rows of a buffer pair written over
-        them, and the pair's header.  Under ``jit`` one K-row scatter a
-        kind: cost follows K, not G."""
-        n, header, ids, rw, rf = self._parts(buffers)
+        and ``flags [F, G]`` with the rows of a buffer written over them,
+        and the buffer's header.  Under ``jit`` one K-row scatter a kind:
+        cost follows K, not G."""
+        n, header, ids, rw, rf = self._parts(buffer)
         G, K = self.G, self.K
         host = isinstance(ids, np.ndarray)
         xp = np if host else jnp
@@ -682,8 +791,8 @@ class RowLayout:
                 | (words[Lw:Lw + Ew] != 0).any(axis=0)
                 | flags[Lf:Lf + Ef].any(axis=0))
 
-    def compact(self, words, flags, header, moved) -> tuple:
-        """The buffer pair of stacked planes: the count of ``moved`` lanes
+    def compact(self, words, flags, header, moved):
+        """The buffer of stacked planes: the count of ``moved`` lanes
         (the TRUE count, also beyond K), the first K of them ascending and
         every plane's value there.  Under ``jit`` the lanes are found by
         block (``_first_columns``) and the values by one K-row gather a
@@ -711,14 +820,14 @@ class RowLayout:
                              jnp.broadcast_to(at[None], (rows, K))]
             return xp.where(held[None, :], vals, xp.zeros((), vals.dtype))
 
-        wbuf = xp.concatenate([
+        return xp.concatenate([
             xp.reshape(n, (1,)).astype(WORD), header.astype(WORD),
-            ids.astype(WORD), gather(words).reshape(-1)])
-        return wbuf, gather(flags).reshape(-1).astype(FLAG)
+            ids.astype(WORD), gather(words).reshape(-1),
+            words_of(gather(flags).reshape(-1))])
 
 
 class RowView:
-    """A numpy buffer pair of a :class:`RowLayout`: the count ``n`` (set
+    """A numpy buffer of a :class:`RowLayout`: the count ``n`` (set
     it with :meth:`set_n`), the lanes ``ids [K]``, ``words [W, K]`` and
     ``flags [F, K]`` field by field, and by name ``field(name)`` (a ``[K]``
     view) and ``head(name)`` / ``set_head(name, value)``."""
@@ -747,6 +856,33 @@ class RowView:
         flat = np.ravel(np.asarray(value))
         off = self.layout.at[name][1]
         self.header[off:off + flat.size] = flat
+
+
+# ---------------------------------------------------------------------------
+# One array a direction: a column step's rows and columns are regions of one
+# word buffer (going up: HostInbox's rows, then the inbox's columns; coming
+# down: the Readback's rows, then the outbox's columns).
+# ---------------------------------------------------------------------------
+
+def regions(buffer, *layouts) -> list:
+    """The buffers of ``layouts`` (row or column layouts) that lie end to
+    end in ``buffer``: numpy views, or traced slices."""
+    out, at = [], 0
+    for lay in layouts:
+        out.append(buffer[at:at + lay.size])
+        at += lay.size
+    return out
+
+
+def alloc_regions(*layouts) -> Tuple[np.ndarray, list]:
+    """One fresh word buffer on the host that holds a buffer of each of
+    ``layouts`` end to end, each holding nothing yet, and those buffers
+    as views of it: filled in place, they cross in one transfer."""
+    buffer = np.zeros(sum(lay.size for lay in layouts), WORD)
+    parts = regions(buffer, *layouts)
+    for lay, part in zip(layouts, parts):
+        lay.clear(part)
+    return buffer, parts
 
 
 # ---------------------------------------------------------------------------
@@ -802,7 +938,7 @@ class _RowField:
 
 
 class ColumnView:
-    """A numpy buffer pair of a :class:`ColumnLayout`, by field name."""
+    """A numpy buffer of a :class:`ColumnLayout`, by field name."""
 
     __slots__ = ("layout", "n", "cols", "planes")
 

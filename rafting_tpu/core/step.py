@@ -71,7 +71,7 @@ import jax.numpy as jnp
 
 from ..ops.select import take_plane, take_slots
 from . import packing
-from .packing import WORD, ColumnLayout, Layout, RowLayout
+from .packing import ColumnLayout, Layout, RowLayout
 from .types import (
     CANDIDATE, FOLLOWER, LEADER, NIL, PRE_CANDIDATE, I32,
     EngineConfig, Hibernate, HostInbox, LogState, Messages, RaftState,
@@ -1626,10 +1626,11 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
 # The packed step: what the served path calls.  A host in the loop pays per
 # transfer, not per byte (some 0.3 ms a call on a TPU, for planes of a few
 # bytes to a few hundred KB), so a tick's ~50 input planes go up as one
-# word buffer and one flag buffer and everything the host reads back comes
-# down as another pair (core/packing.py; in pieces of a few MB where the
-# planes are that large).  node_step itself stays the entry for whatever
-# has no host in the loop: the fused scans, the parity kit, the byte count.
+# word buffer, the flags a byte each behind the words, and everything the
+# host reads back comes down as another (core/packing.py; in pieces of a
+# few MB where the planes are that large).  node_step itself stays the
+# entry for whatever has no host in the loop: the fused scans, the parity
+# kit, the byte count.
 # ---------------------------------------------------------------------------
 
 class Readback(NamedTuple):
@@ -1748,7 +1749,8 @@ def node_step_packed(cfg: EngineConfig, inputs: Layout, state: RaftState,
 # the column buffers (a heartbeat round, an election storm), whose
 # HostInbox holds more lanes than its row buffer or whose results moved
 # more crosses that part densely, exactly as node_step_packed's does:
-# decided by a count, nothing cut.
+# decided by a count, nothing cut.  A step that fits crosses in ONE array
+# each way: rows and columns are regions of one word buffer.
 # ---------------------------------------------------------------------------
 
 class ColumnLayouts(NamedTuple):
@@ -1790,11 +1792,14 @@ INFO_LEVELS = ("log_tail", "commit", "leader", "ready", "conf_word",
 INFO_CARRIED = ("submit_start",)
 
 
-# Columns engage where the step's dense operand takes at least this many
-# word buffers (core/packing.py CHUNK_BYTES each: some 33,000 lanes at P=3,
-# B=8).  What the column form costs does not depend on the lanes (K-row
-# scatters and gathers on the device, a buffer pair more each way: 3 ms of a
-# step on a TPU v5e at either size), what it saves does (the dense planes'
+# Columns engage where the ``int32`` planes of the step's dense operand
+# take at least this many buffers (core/packing.py CHUNK_BYTES each: some
+# 33,000 lanes at P=3, B=8; a layout places its words as if it had no
+# flags, so the flags that ride behind them since PR 42 move no shape
+# across the rule).  What the column form costs does not depend on the
+# lanes (K-row scatters and gathers on the device: 3 ms of a step on a TPU
+# v5e at either size, with the buffer pair more each way that it then
+# crossed in), what it saves does (the dense planes'
 # allocation, transfer, unpacking and packing: 0.13 ms a step a thousand
 # lanes): at 10,000 lanes (2 word buffers) the column step cost the
 # 10,000-Region cell 3 ms a step and 4-7 ms of a 22 ms read, at 100,000 (12)
@@ -1811,7 +1816,7 @@ def column_layouts(cfg: EngineConfig, durable: bool
     alone decides (``COLUMN_BUFFERS``); below it the dense planes cross
     at little more than a transfer's fixed cost and columns lose."""
     inputs, _ = step_layouts(cfg, durable)
-    if sum(dt == WORD for dt, _ in inputs.buffers) < COLUMN_BUFFERS:
+    if sum(w > 0 for w in inputs.words) < COLUMN_BUFFERS:
         return None
     host, inbox, back = _step_shapes(cfg, durable)
     outbox, back = back.outbox, back._replace(outbox=None)
@@ -1858,7 +1863,7 @@ def _host_from_rows(rl: RowLayout, base: HostInbox, rows, durable
     if durable is not None:
         at = rl.at["durable_tail"][1]
         words = words.at[at].set(
-            jnp.where(rows[0][0] < 0, words[at], durable))
+            jnp.where(rows[0] < 0, words[at], durable))
     host = rl.unstack(*rl.expand(rows, words, flags))
     return host, host.durable_tail
 
@@ -1868,31 +1873,41 @@ def node_step_columns(cfg: EngineConfig, lay: ColumnLayouts,
                       columns_in: bool, state: RaftState, carry: RowCarry,
                       buffers: Tuple[Array, ...]):
     """``node_step`` with its messages in column form and its ``[G]``
-    planes in row form.  ``buffers``: ``lay.host``'s followed by the
-    inbox's column pair when ``columns_in``, else ``lay.inputs``' (the
-    dense operand of ``node_step_packed``); then, either way, HostInbox's
-    row pair (``lay.rows_in``), whose rows are written over the
-    HostInbox planes that came before it (see ``_host_from_rows``).
+    planes in row form.  ``buffers``: ``lay.host``'s when ``columns_in``,
+    else ``lay.inputs``' (the dense operand of ``node_step_packed``); then
+    ONE word buffer more, which is what a step that fits uploads:
+    HostInbox's rows (``lay.rows_in``), written over the HostInbox planes
+    that came before (see ``_host_from_rows``), and behind them, when
+    ``columns_in``, the inbox's columns (``lay.columns``;
+    ``packing.regions``).
     ``carry`` is what the last call returned (``first_carry`` for the
     first); the step reads its ``durable`` plane alone.  Returns the new
     state, the new carry (the patched ``durable_tail`` plane; the
     Readback without its outbox, stacked: left on the device for
     ``compact_readback`` to find the rows that moved, and for
-    ``pack_readback`` should they not fit), the outbox's column pair
-    (whose counts say whether it fits: ``lay.columns.K``), and the dense
+    ``pack_readback`` should they not fit), the outbox's column buffer
+    (whose counts say whether it fits: ``lay.columns.K``; it comes down
+    behind the rows, in ``compact_readback``'s one result), and the dense
     outbox itself (``lay.columns.stack``'s few arrays, not its forty
     planes: a result is a Python object a call), left on the device for
     ``pack_outbox`` should it not.
     ``node_step`` gets the planes it always got, bit for bit: columns and
     rows are expanded into planes by K-row scatters, nothing is addressed
     G rows at a time."""
-    buffers, rows = buffers[:-2], buffers[-2:]
+    *buffers, up = buffers
     if columns_in:
-        n_host = len(lay.host.buffers)
-        host = lay.host.unpack(buffers[:n_host])
-        inbox = lay.columns.expand(buffers[n_host:])
+        rows, columns = packing.regions(up, lay.rows_in, lay.columns)
+        host = lay.host.unpack(buffers)
+        inbox = lay.columns.expand(columns)
     else:
+        # The dense operand's planes are whole before the step reads one:
+        # with its unpacking fused into the step, the chip's compiler
+        # wrote ``now`` over two thirds of the donated ``fail_at`` plane at
+        # 100,000 lanes where the CPU backend was right (PERF.md, PR 42;
+        # the fault of PR 38 again, in another leaf).  What is compiled
+        # into this program goes through ``tools/rows_probe.py check``.
         host, inbox = lay.inputs.unpack(buffers)
+        host, inbox, rows = jax.lax.optimization_barrier((host, inbox, up))
     host, durable = _host_from_rows(lay.rows_in, host, rows, carry.durable)
     state, back = _step_readback(cfg, state, inbox, host)
     dense = lay.columns.stack(back.outbox)
@@ -1902,20 +1917,24 @@ def node_step_columns(cfg: EngineConfig, lay: ColumnLayouts,
 
 
 @partial(jax.jit, static_argnums=0)
-def compact_readback(lay: ColumnLayouts, carry: RowCarry, last: RowCarry
-                     ) -> Tuple[Array, Array]:
-    """The row pair of a column step's Readback (``lay.rows_out``): the
-    lanes of ``carry`` (the step's) where a level differs from ``last``
-    (the step's before it: what the host's mirrors hold) or an event is
-    not zero, their true count, the first K of them and every plane's
-    value there, by a prefix sum and one K-row gather a kind; the header
-    holds the leaves that are no planes.  A program of its own beside the
-    step: compiled into it, the search and the gathers came out of the
-    chip's compiler writing over the step's donated state (PERF.md, PR
-    38), and on its own it overlaps nothing the host waits for."""
+def compact_readback(lay: ColumnLayouts, carry: RowCarry, last: RowCarry,
+                     columns: Array) -> Array:
+    """What the host fetches of a column step, in ONE word buffer: the
+    rows of its Readback (``lay.rows_out``) and behind them ``columns``,
+    the outbox's column buffer as the step returned it, copied
+    (``packing.regions``).  The rows: the lanes of ``carry`` (the step's)
+    where a level differs from ``last`` (the step's before it: what the
+    host's mirrors hold) or an event is not zero, their true count, the
+    first K of them and every plane's value there, by a prefix sum and
+    one K-row gather a kind; the header holds the leaves that are no
+    planes.  A program of its own beside the step: compiled into it, the
+    search and the gathers came out of the chip's compiler writing over
+    the step's donated state (PERF.md, PR 38), and on its own it overlaps
+    nothing the host waits for; it donates nothing."""
     rl = lay.rows_out
     moved = rl.moved(carry.words, carry.flags, last.words, last.flags)
-    return rl.compact(carry.words, carry.flags, carry.header, moved)
+    return jnp.concatenate(
+        [rl.compact(carry.words, carry.flags, carry.header, moved), columns])
 
 
 @partial(jax.jit, static_argnums=0)

@@ -12,9 +12,9 @@ Tick protocol (the host half of the engine's contract):
 1. build the HostInbox: queued client submissions, finished snapshot
    installs, compaction grants from the maintain policy;
 2. drain the transport inbox accumulator into the dense inbox planes,
-   views of the tick's packed upload buffers (core/packing.py: one of
-   words and one of flags, more only when the planes take many MB),
-   which cross to the device in one transfer each;
+   views of the tick's packed upload buffers (core/packing.py: one word
+   buffer, the flags a byte each behind the words; more only when the
+   planes take many MB), which cross to the device in one transfer each;
 3. run the fused device step (`node_step_packed`) — all groups at once —
    and fetch its packed results, again one transfer a buffer;
 4. PERSIST: stage WAL writes implied by the step (appended entries with
@@ -49,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 
-from ..core.packing import DenseView
+from ..core.packing import DenseView, alloc_regions, regions
 from ..core.step import (
     WINDOW_SUMS, column_layouts, compact_readback, first_carry,
     node_step_columns, node_step_packed, pack_outbox, pack_readback,
@@ -329,9 +329,9 @@ class _TickCtx:
         # was started for arriving work inside a period
         "timer", "started",
         # the packed results on the device and their layout (dispatch);
-        # for a column step (core/step.py node_step_columns) its layouts,
-        # the outbox's column pair at the end of ``packed`` and the dense
-        # outbox left on the device
+        # for a column step (core/step.py node_step_columns) its layouts
+        # (``packed`` is then the one buffer of its rows and the outbox's
+        # columns) and the dense outbox left on the device
         "packed", "readback", "columns", "out_dense",
         # a column step's [G] side (_RowStep; None on a packed step)
         "rows",
@@ -994,9 +994,9 @@ class RaftNode:
         # timer ticks (HostInbox.clock 0).
         self.metrics["ticks_on_arrival"] += 0
         # Host-to-device and device-to-host transfers made by the ticks:
-        # the packed buffers of _dispatch and _fetch, a word buffer and a
-        # flag buffer each way unless the planes take more than a few MB
-        # (core/packing.py CHUNK_BYTES).
+        # the packed buffers of _dispatch and _fetch, one word buffer each
+        # way unless the planes take more than a few MB (core/packing.py
+        # CHUNK_BYTES).
         self.metrics["h2d_transfers"] += 0
         self.metrics["d2h_transfers"] += 0
         # Steps whose messages crossed as columns, and steps of a shape
@@ -2243,7 +2243,9 @@ class RaftNode:
         if not whole:
             ids = np.unique(np.concatenate(said))
             whole = ids.size > rl.K
-        rows = rl.alloc()
+        # What a step that fits uploads, in ONE array: the rows, and
+        # behind them the columns (core/packing.py alloc_regions).
+        up, (rows, columns) = alloc_regions(rl, lay.columns)
         view = rl.view(rows)
         view.set_head("read_veto", read_veto)
         view.set_head("clock", int(not arrival))
@@ -2254,8 +2256,7 @@ class RaftNode:
         # allocated, zeroed or walked P x G), else as zeroed dense planes
         # that are views of the packed buffers (_dispatch).
         batches, staged_payloads = self.acc.pop()
-        pair = lay.columns.alloc()
-        arrays = lay.columns.view(pair)
+        arrays = lay.columns.view(columns)
         resident = ()
         if fill_columns(batches, arrays):
             if whole:
@@ -2264,13 +2265,13 @@ class RaftNode:
             else:
                 buffers, host = (), None
                 resident = self._resident_zero(lay)
-            buffers += pair
         else:
             buffers = lay.inputs.alloc()
             host, inbox = lay.inputs.unpack(buffers)
             arrays = DenseView({name: getattr(inbox, name)
                                 for name in self.template})
             scatter_dense(batches, arrays.planes)
+            up = rows           # the columns stay behind
         self._fold_inbox_stats()
         if self._hb_rounds:
             self._hb_acknowledged(arrays)
@@ -2299,7 +2300,7 @@ class RaftNode:
                 view.field("durable_tail")[:n] = np.minimum(
                     durable[ids], I32_SAFE_MAX)
                 self._dur_sent[dur_ids] = durable[dur_ids]
-        buffers += rows
+        buffers += (up,)
 
         # -- 2b. upload ------------------------------------------------------
         st = self._stages
@@ -2317,10 +2318,10 @@ class RaftNode:
 
         # -- 3. device step (async dispatch: no transfer, no block) ----------
         # The step hands back its results twice: as the rows that moved
-        # (compacted by a program of their own, enqueued behind the step)
-        # and the outbox's columns, fetched, and whole (the carry's planes,
-        # the dense outbox), left on the device for the fetch of a step
-        # that does not fit them.
+        # and the outbox's columns (put into one buffer by a program of
+        # its own, enqueued behind the step), fetched, and whole (the
+        # carry's planes, the dense outbox), left on the device for the
+        # fetch of a step that does not fit them.
         st.enter("dispatch_enqueue")
         if self._carry is None:
             self._carry = first_carry(lay)
@@ -2329,7 +2330,7 @@ class RaftNode:
         last = self._carry
         self.state, self._carry, out, out_dense = node_step_columns(
             cfg, lay, columns_in is not None, self.state, last, packed)
-        back = compact_readback(lay, self._carry, last)
+        back = compact_readback(lay, self._carry, last, out)
 
         ctx = _TickCtx()
         step = ctx.rows = _RowStep()
@@ -2347,7 +2348,7 @@ class RaftNode:
         ctx.timer = not arrival
         ctx.started = started
         ctx.staged_payloads, ctx.arrays = staged_payloads, arrays
-        ctx.packed, ctx.readback = back + out, lay.back
+        ctx.packed, ctx.readback = (back,), lay.back
         ctx.columns, ctx.out_dense = lay, out_dense
         ctx.deferred_ae = None
         return ctx
@@ -2566,18 +2567,18 @@ class RaftNode:
         self._mirrors_tail(ctx, *counts)
 
     def _fetch_rows(self, ctx: _TickCtx, fetched) -> tuple:
-        """``_fetch`` for a column step, from the fetched pairs on: the
-        outbox came down as its columns and the Readback's [G] planes as
-        the rows of the lanes that moved, and each pair's counts say
-        whether it holds its part.  A part that does not fit (a row of
-        the outbox beyond the column buffers; more lanes moved than the
-        row buffer holds: a storm, a step after a purge, the first) is
-        packed on the device from what the step left there and fetched
-        as node_step_packed's is: that part crosses whole, nothing is
-        cut."""
+        """``_fetch`` for a column step, from its one fetched buffer on:
+        the Readback's [G] planes came down as the rows of the lanes that
+        moved and, behind them, the outbox as its columns, and each
+        region's counts say whether it holds its part.  A part that does
+        not fit (a row of the outbox beyond the column buffers; more
+        lanes moved than the row buffer holds: a storm, a step after a
+        purge, the first) is packed on the device from what the step left
+        there and fetched as node_step_packed's is: that part crosses
+        whole, nothing is cut."""
         lay, st, m = ctx.columns, self._stages, self.metrics
-        rows = lay.rows_out.view(fetched[:2])
-        outbox = lay.columns.view(fetched[2:])
+        rows, outbox = regions(fetched[0], lay.rows_out, lay.columns)
+        rows, outbox = lay.rows_out.view(rows), lay.columns.view(outbox)
         n_rows = rows.n
         whole = n_rows > lay.rows_out.K or self._rows_whole_out
         extra = ()

@@ -453,7 +453,33 @@ def overflow(t: Twins):
     assert t.with_ids > before
 
 
+def one_array(t: Twins):
+    """A step whose phase is worked from its rows also crossed in ONE
+    array each way (the rows, the columns behind them; column buffers
+    wide enough here for a heartbeat's messages): the counters
+    ``h2d_transfers`` / ``d2h_transfers`` move by one each over some step
+    of every node of both clusters, and by no less over any (a storm's
+    rows: more, the dense part whole beside the one array)."""
+    t.elect()
+    names = ("h2d_transfers", "d2h_transfers")
+    nodes = [n for c in t.both for n in c.nodes.values()]
+    steps = {id(n): set() for n in nodes}
+    futs = []
+    for k in range(12):
+        if k % 4 == 0:
+            futs += t.write(3 + k, b"one") + t.read(5 + k)
+        before = [[n.metrics[name] for name in names] for n in nodes]
+        t.tick()
+        for n, was in zip(nodes, before):
+            steps[id(n)].add(tuple(n.metrics[name] - w
+                                   for name, w in zip(names, was)))
+    t.done(futs)
+    for moved in steps.values():
+        assert (1, 1) in moved and min(min(m) for m in moved) == 1, moved
+
+
 SCENARIOS = {
+    "one_array": (one_array, dict(packing=dict(COLUMNS=16))),
     "lease_reads": (lease_reads, {}),
     "readindex_reads": (readindex_reads, dict(engine=dict(read_lease=False))),
     "halted_machine": (halted_machine, {}),
@@ -476,7 +502,12 @@ SCENARIOS = {
 def test_selections_over_the_rows_are_the_selections_over_whole_planes(
         tmp_path, monkeypatch, small, scenario, pipeline):
     run, kw = SCENARIOS[scenario]
-    t = Twins(tmp_path, monkeypatch, pipeline, **dict(kw))
+    kw = dict(kw)
+    for name, value in kw.pop("packing", {}).items():
+        monkeypatch.setattr(packing, name, value)
+        step_layouts.cache_clear()
+        column_layouts.cache_clear()
+    t = Twins(tmp_path, monkeypatch, pipeline, **kw)
     try:
         run(t)
         t.tick(10)

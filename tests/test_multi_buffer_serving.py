@@ -2,9 +2,12 @@
 
 At 10,000 lanes the dense message planes are 4.83 MB a node each way, past
 ``core/packing.py CHUNK_BYTES`` (4 MiB): the step's upload and readback each
-cross in two word buffers and a flag buffer (``multiraft-10k-3v``, PERF.md
-PR 31).  These tests force that shape at a size a CPU holds, by a small
-``CHUNK_BYTES``: (a) the step over many buffers is leaf for leaf the step
+cross in two word buffers, the flags behind the words of the second
+(``multiraft-10k-3v``, PERF.md PR 31; a flag buffer more until PR 42).
+These tests force such a shape at a size a CPU holds, by a small
+``CHUNK_BYTES`` (three buffers each way: one more and the shape would take
+the column step, ``core/step.py COLUMN_BUFFERS``, as it did unnoticed at
+2 KB while that step moved four arrays a way): (a) the step over many buffers is leaf for leaf the step
 over one; (b) three served containers take writes and linearizable reads
 through ``RaftStub`` over such layouts, agree with a sequential model and
 pass ``testkit/linz.py``.
@@ -22,7 +25,8 @@ import pytest
 from rafting_tpu.api import RaftConfig, RaftContainer
 from rafting_tpu.core import packing
 from rafting_tpu.core.cluster import route
-from rafting_tpu.core.step import node_step_packed, step_layouts
+from rafting_tpu.core.step import (
+    column_layouts, node_step_packed, step_layouts)
 from rafting_tpu.core.types import (
     EngineConfig, HostInbox, Messages, init_state)
 from rafting_tpu.testkit import linz
@@ -30,22 +34,25 @@ from rafting_tpu.testkit.harness import (
     free_ports, kv_factory, scaled_election_mul)
 from rafting_tpu.testkit.history import History
 
-SMALL_CHUNK = 2048          # bytes: a few [P, G] planes a buffer at 16 lanes
+SMALL_CHUNK = 3072          # bytes: a few [P, G] planes a buffer at 16 lanes
 
 
 @pytest.fixture
 def small_chunks(monkeypatch):
-    """Layouts built while this holds close a buffer every 2 KB.  The
+    """Layouts built while this holds close a buffer every 3 KB.  The
     layout cache is emptied on both sides so that no other test sees
     them."""
     step_layouts.cache_clear()
+    column_layouts.cache_clear()
     monkeypatch.setattr(packing, "CHUNK_BYTES", SMALL_CHUNK)
     yield
     step_layouts.cache_clear()
+    column_layouts.cache_clear()
 
 
 def _words(layout):
-    return sum(dt == packing.WORD for dt, _ in layout.buffers)
+    """The buffers that hold words (none holds flags alone here)."""
+    return sum(w > 0 for w in layout.words)
 
 
 def test_step_over_many_buffers_is_the_one_buffer_step(small_chunks,
@@ -60,7 +67,7 @@ def test_step_over_many_buffers_is_the_one_buffer_step(small_chunks,
         m.setattr(packing, "CHUNK_BYTES", 4 << 20)
         step_layouts.cache_clear()
         one_in, one_back = step_layouts(cfg, True)
-        assert len(one_in.buffers) == len(one_back.buffers) == 2
+        assert len(one_in.buffers) == len(one_back.buffers) == 1
         rng = np.random.default_rng(11)
         one = [init_state(cfg, n, seed=5) for n in range(N)]
         outboxes = [jax.device_get(Messages.empty(cfg))] * N
@@ -133,6 +140,8 @@ def test_served_cluster_over_many_buffers_is_linearizable(served):
         assert c.open_context("kv") == 1
     inputs, readback = step_layouts(cs[0].node.cfg, True)
     assert _words(inputs) >= 2 and _words(readback) >= 2
+    assert len(inputs.buffers) >= 3 and len(readback.buffers) >= 3
+    assert column_layouts(cs[0].node.cfg, True) is None     # the packed step
     stubs = [c.get_stub("kv") for c in cs]
     # Sequential phase: one client through every member in turn against a
     # dict (writes through one member, read back through the next).
@@ -172,11 +181,13 @@ def test_served_cluster_over_many_buffers_is_linearizable(served):
     assert counts["ok"] >= 40, counts
     res = linz.check(history)
     assert res.ok, res.render()
-    # Every step moved more than one word buffer each way.
+    # Every step moved three buffers each way (the loops run on: a step
+    # that has started may not have uploaded yet, nor the one before it
+    # been fetched).
     for c in cs:
         m = c.node.metrics
-        assert m["h2d_transfers"] >= 3 * m["ticks"] > 0
-        assert m["d2h_transfers"] >= 3 * m["ticks"]
+        assert m["h2d_transfers"] >= 3 * (m["ticks"] - 2) > 0
+        assert m["d2h_transfers"] >= 3 * (m["ticks"] - 2)
     # The three replicas end identical.
     deadline = time.monotonic() + 20
     machines = [c.node.dispatcher.machine(1) for c in cs]

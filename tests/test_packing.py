@@ -63,8 +63,10 @@ def test_packed_step_is_node_step_bit_for_bit(extra, durable):
     inputs, readback = step_layouts(cfg, durable)
     assert inputs == step_layouts(cfg, durable)[0]
     assert inputs != step_layouts(cfg, not durable)[0]
-    # At the sizes where a call's fixed cost matters: two buffers each way.
-    assert len(inputs.buffers) == len(readback.buffers) == 2
+    # At the sizes where a call's fixed cost matters: one buffer each way,
+    # the flags a byte each behind the words.
+    assert len(inputs.buffers) == len(readback.buffers) == 1
+    assert {dt for dt, _ in inputs.buffers + readback.buffers} == {packing.WORD}
     rng = np.random.default_rng(7)
     plain = [init_state(cfg, n, seed=3) for n in range(N)]
     packed = [init_state(cfg, n, seed=3) for n in range(N)]
@@ -120,17 +122,80 @@ def _tree(rng):
             "scalar": np.asarray(True), "c": [i32(1), flag(7)]}
 
 
-def test_host_round_trip_is_the_identity_and_unpack_gives_views():
-    tree = _tree(np.random.default_rng(1))
+def _odd_tree(rng):
+    """Flag bytes that are no multiple of four (13 + 1 + 3), and no word
+    leaf behind the last flag."""
+    i32 = lambda *shape: rng.integers(-9, 9, shape, dtype=np.int32)
+    flag = lambda *shape: rng.random(shape) < 0.5
+    return [flag(13), i32(2, 3), np.asarray(False), flag(3)]
+
+
+def _flag_run_tree(rng):
+    """A run of flag leaves that a 64-byte chunk closes inside: the
+    buffer of the words has room for the first and the fourth, the others
+    open a buffer that holds flags alone."""
+    i32 = lambda *shape: rng.integers(-9, 9, shape, dtype=np.int32)
+    flag = lambda *shape: rng.random(shape) < 0.5
+    return [i32(6), (flag(18), flag(21), flag(30), flag(5)), i32(3),
+            flag(2, 3)]
+
+
+def _long_flags_tree(rng):
+    """Flag regions of several rows of 512 (the device takes a word's
+    bytes apart 128 words at a time), the last one part full, and leaves
+    that start in the middle of a row."""
+    i32 = lambda *shape: rng.integers(-9, 9, shape, dtype=np.int32)
+    flag = lambda *shape: rng.random(shape) < 0.5
+    return {"a": flag(3, 433), "b": i32(7), "c": flag(515), "d": flag(2)}
+
+
+# name -> (tree maker, CHUNK_BYTES, the layout's buffers in words)
+LAYOUT_CASES = {
+    # 5 + 24 + 1 words, then 12 + 1 + 7 flags: five words of them
+    "one-buffer": (_tree, None, (35,)),
+    # 6 words, then 17 flags: five words, three bytes of padding
+    "odd-flag-bytes": (_odd_tree, None, (11,)),
+    # 36 B of words + 18 flags = 54 B: the next 21 flags would pass 64 and
+    # open buffer two, which takes the 30 too; the 5 still fit buffer one
+    # (59 B), the last 6 do not: 23 flags in 6 words, 57 in 15
+    "chunk-closes-in-a-flag-run": (_flag_run_tree, 64, (9 + 6, 15)),
+    # 7 words, then 1,299 + 515 + 2 flags in 454 words: three rows and a half
+    "flag-rows": (_long_flags_tree, None, (7 + 454,)),
+}
+
+
+@pytest.fixture(params=list(LAYOUT_CASES))
+def layout_case(request, monkeypatch):
+    make, chunk, words = LAYOUT_CASES[request.param]
+    if chunk is not None:
+        monkeypatch.setattr(packing, "CHUNK_BYTES", chunk)
+    tree = make(np.random.default_rng(len(request.param)))
     layout = Layout(tree)
-    assert layout.buffers == ((np.int32, 5 + 24 + 1), (np.uint8, 12 + 1 + 7))
+    assert layout.buffers == tuple((np.int32, n) for n in words)
+    return tree, layout
+
+
+def test_host_round_trip_is_the_identity_and_unpack_gives_views(layout_case):
+    """``bool`` leaves lie a byte each behind their buffer's words and
+    come back as ``bool`` VIEWS of the word buffer, the ``int32`` leaves
+    as views too: nothing is copied on the host in either direction."""
+    tree, layout = layout_case
     buffers = layout.pack(tree)
     assert [(b.dtype, b.size) for b in buffers] == list(layout.buffers)
     back = layout.unpack(buffers)
     assert_trees_equal(back, tree)
     for leaf in jax.tree.leaves(back):
-        assert any(np.shares_memory(leaf, b) for b in buffers)
+        assert sum(np.shares_memory(leaf, b) for b in buffers) == 1
         assert leaf.flags.c_contiguous
+    # A flag is a byte, 0 or 1, in its buffer's flag region, which is
+    # padded to a whole word and no further.
+    flags = [leaf for leaf in jax.tree.leaves(tree) if leaf.dtype == bool]
+    regions = [b.view(np.uint8)[4 * w:] for b, w in zip(buffers, layout.words)]
+    assert sum(int(r.sum()) for r in regions) == sum(
+        int(leaf.sum()) for leaf in flags)
+    assert all(r.max(initial=0) <= 1 for r in regions)
+    assert 0 <= sum(r.size for r in regions) - sum(
+        leaf.size for leaf in flags) < 4 * len(buffers)
     # Filled in place: what is written through a view is in the buffer.
     fresh = layout.alloc()
     assert not any(b.any() for b in fresh)
@@ -141,29 +206,38 @@ def test_host_round_trip_is_the_identity_and_unpack_gives_views():
         np.testing.assert_array_equal(got, want)
 
 
-def test_device_round_trip_matches_the_host_layout():
+def test_device_round_trip_matches_the_host_layout(layout_case):
     """pack under jit lays the leaves out where the host's unpack finds
-    them, and unpack under jit finds what the host's pack laid out."""
-    tree = _tree(np.random.default_rng(2))
-    layout = Layout(tree)
-    buffers = jax.device_get(
+    them, and unpack under jit finds what the host's pack laid out, bit
+    for bit: a flag's byte is the same byte on both sides."""
+    tree, layout = layout_case
+    buffers = layout.pack(tree)
+    on_device = jax.device_get(
         jax.jit(layout.pack)(jax.tree.map(jnp.asarray, tree)))
-    assert_trees_equal(layout.unpack(buffers), tree)
-    assert_trees_equal(jax.jit(layout.unpack)(layout.pack(tree)), tree)
+    for got, want in zip(on_device, buffers):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+    assert_trees_equal(layout.unpack(on_device), tree)
+    assert_trees_equal(jax.jit(layout.unpack)(buffers), tree)
 
 
 def test_a_buffer_is_closed_at_the_chunk_bound(monkeypatch):
-    """Whole leaves, in flatten order, a new buffer of the kind once the
-    next leaf would pass CHUNK_BYTES; a leaf larger than the bound has a
-    buffer to itself; both sides of the boundary agree on the pieces."""
+    """Whole leaves: the ``int32`` ones in flatten order, a new buffer
+    once the next would pass CHUNK_BYTES, as if the tree had no flags
+    (a leaf larger than the bound has a buffer to itself); each ``bool``
+    one in the first buffer with room left for it; both sides of the
+    boundary agree on the pieces."""
     monkeypatch.setattr(packing, "CHUNK_BYTES", 64)
     i32 = lambda n, v: np.full(n, v, np.int32)
     tree = [i32(10, 1), np.ones(40, bool), i32(6, 2), i32(1, 3),
             np.zeros(30, bool), i32(40, 4), i32(2, 5)]
     layout = Layout(tree)
-    assert layout.buffers == (
-        (np.int32, 16), (np.uint8, 40), (np.int32, 1), (np.uint8, 30),
-        (np.int32, 40), (np.int32, 2))
+    # 64 B of words | 4 B + 40 flags | 160 B | 8 B + 30 flags
+    assert layout.buffers == tuple((np.int32, n) for n in (
+        16, 1 + 10, 40, 2 + 8))
+    assert layout.words == (16, 1, 40, 2)
+    assert layout.words == tuple(n for _, n in Layout(
+        [leaf for leaf in tree if leaf.dtype == np.int32]).buffers)
     buffers = layout.pack(tree)
     assert_trees_equal(layout.unpack(buffers), tree)
     on_device = jax.jit(layout.pack)(jax.tree.map(jnp.asarray, tree))
@@ -172,7 +246,7 @@ def test_a_buffer_is_closed_at_the_chunk_bound(monkeypatch):
         np.testing.assert_array_equal(got, want)
     assert layout != Layout(tree[:-1])
     monkeypatch.undo()
-    assert len(Layout(tree).buffers) == 2
+    assert len(Layout(tree).buffers) == 1
 
 
 def test_a_leaf_that_is_neither_int32_nor_bool_is_refused():
@@ -284,19 +358,20 @@ def test_column_step_is_node_step_bit_for_bit(small_columns, columns_in,
                 durable_tail=tails[n] if durable else None)
             plain[n], p_out, p_info = node_step(
                 cfg, plain[n], *jax.tree.map(jnp.asarray, (inbox, host)))
-            pair = lay.columns.compact(inbox)
-            fits = bool((pair[0][:, 0] <= lay.columns.K).all())
+            held = lay.columns.compact(inbox)
+            fits = bool((lay.columns.view(held).n <= lay.columns.K).all())
+            # HostInbox whole (its planes packed, a row buffer that holds
+            # none): tests/test_plane_rows.py has the rows.  The columns
+            # ride behind the rows, in the one buffer that goes up.
+            up = lay.rows_in.whole(host)
             if columns_in and fits:
-                bufs = lay.host.pack(host) + pair
+                bufs = lay.host.pack(host) + (np.concatenate([up, held]),)
                 seen["columns_in"] += 1
             else:
-                bufs = lay.inputs.pack((host, inbox))
+                bufs = lay.inputs.pack((host, inbox)) + (up,)
                 seen["dense_in"] += 1
-            # HostInbox whole (its planes packed, a row pair that holds
-            # none): tests/test_plane_rows.py has the rows.
-            cols[n], carries[n], pair, dense = node_step_columns(
-                cfg, lay, columns_in and fits, cols[n], carries[n],
-                bufs + lay.rows_in.whole(host))
+            cols[n], carries[n], out, dense = node_step_columns(
+                cfg, lay, columns_in and fits, cols[n], carries[n], bufs)
             tag = f"tick {t} node {n}"
             assert_trees_equal(cols[n], plain[n], tag)
             s = plain[n]
@@ -312,13 +387,14 @@ def test_column_step_is_node_step_bit_for_bit(small_columns, columns_in,
             assert_trees_equal(lay.columns.unstack(dense), p_out, tag)
             p_out = jax.device_get(p_out)
             occ = _occupied(p_out)
-            pair = jax.device_get(pair)
-            np.testing.assert_array_equal(pair[0][:, 0], occ.sum(axis=1), tag)
-            if (pair[0][:, 0] <= lay.columns.K).all():
+            out = jax.device_get(out)
+            assert out.dtype == np.int32 and out.shape == (lay.columns.size,)
+            view = lay.columns.view(out)
+            np.testing.assert_array_equal(view.n, occ.sum(axis=1), tag)
+            if (view.n <= lay.columns.K).all():
                 seen["columns_out"] += 1
-                assert_trees_equal(lay.columns.expand(pair),
+                assert_trees_equal(lay.columns.expand(out),
                                    _on_columns(p_out, occ), tag)
-                view = lay.columns.view(pair)
                 for p in range(N):
                     np.testing.assert_array_equal(
                         view.lanes(p, view.row("ae_valid", p)),
@@ -362,9 +438,9 @@ def test_column_counts_per_row(small_columns, counts):
             getattr(tree, valid[rng.integers(len(valid))])[p, g] = True
             tree.aer_success[p, g] = rng.random() < 0.5     # a plain flag
     pair = lay.compact(tree)
-    for a, b in zip(pair, jax.jit(lay.compact)(
-            jax.tree.map(jnp.asarray, tree))):
-        np.testing.assert_array_equal(a, b)
+    assert pair.dtype == np.int32 and pair.shape == (lay.size,)
+    np.testing.assert_array_equal(
+        pair, jax.jit(lay.compact)(jax.tree.map(jnp.asarray, tree)))
     n, held, _, _ = lay._parts(pair)
     np.testing.assert_array_equal(n, counts)
     for p, at in enumerate(lanes):
@@ -374,12 +450,9 @@ def test_column_counts_per_row(small_columns, counts):
     if max(counts) <= lay.K:
         want = _on_columns(tree, _occupied(tree))
         assert_trees_equal(lay.expand(pair), want)
-        assert_trees_equal(jax.jit(lay.expand)(
-            tuple(map(jnp.asarray, pair))), want)
+        assert_trees_equal(jax.jit(lay.expand)(jnp.asarray(pair)), want)
         # host round trip: the identity, in both orders
-        again = lay.compact(lay.expand(pair))
-        for a, b in zip(pair, again):
-            np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(pair, lay.compact(lay.expand(pair)))
 
 
 def test_a_new_field_finds_its_place_in_the_columns(small_columns):
@@ -398,8 +471,9 @@ def test_a_new_field_finds_its_place_in_the_columns(small_columns):
     tree["zz_word"] = np.zeros((P, G, 2), np.int32)
     lay = ColumnLayout(tree)
     assert (lay.W, lay.F, len(lay.occupancy)) == (8, 3, 2) and lay != before
-    assert lay.buffers == ((np.dtype(np.int32), (P, 1 + 3 + 3 * 8)),
-                           (np.dtype(np.uint8), (P, 3 * 3)))
+    # count, lanes and words a row, then a byte a flag: 18 in 5 words
+    assert lay.size == P * (1 + 3 + 3 * 8) + 5 and lay.nbytes == 4 * lay.size
+    assert lay.alloc().shape == (lay.size,)
     tree["zz_valid"][1, 7] = tree["a_valid"][1, 2] = True
     tree["zz_word"][1, 7] = (5, 6)
     tree["a_wide"][1, 2] = np.arange(5)
@@ -441,8 +515,8 @@ def test_row_counts_round_trip(count):
                     carried=["info.start"])
     assert (lay.W, lay.F, lay.H) == (4, 2, 6)
     assert (lay.Lw, lay.Ew, lay.Lf, lay.Ef) == (2, 1, 1, 1)
-    assert lay.buffers == ((np.dtype(np.int32), 1 + 6 + K + 4 * K),
-                           (np.dtype(np.uint8), 2 * K))
+    # count, header, lanes and words, then a byte a flag
+    assert lay.size == 1 + 6 + K + 4 * K + 2 * K // 4
     # ``count`` lanes move: a level differs from what the other side
     # holds, or an event happened.
     at = np.sort(rng.choice(G, count, replace=False))
@@ -464,11 +538,11 @@ def test_row_counts_round_trip(count):
     moved = lay.moved(words, flags, prev_w, prev_f)
     np.testing.assert_array_equal(np.nonzero(moved)[0], at)
     pair = lay.compact(words, flags, header, moved)
+    assert pair.dtype == np.int32 and pair.shape == (lay.size,)
     on_device = jax.jit(lambda w, f, h, pw, pf: lay.compact(
         w, f, h, lay.moved(w, f, pw, pf)))(
             *map(jnp.asarray, (words, flags, header, prev_w, prev_f)))
-    for a, b in zip(pair, on_device):
-        np.testing.assert_array_equal(a, np.asarray(b))
+    np.testing.assert_array_equal(pair, np.asarray(on_device))
     view = lay.view(pair)
     assert view.n == count and view.head("sums").tolist() == [0, 1, 2, 3, 4]
     assert bool(view.head("veto")) is True
@@ -485,7 +559,7 @@ def test_row_counts_round_trip(count):
         base_w, base_f = prev_w.copy(), prev_f.copy()
         base_w[lay.Lw + lay.Ew:] = words[lay.Lw + lay.Ew:]  # carried: as is
         for got in (lay.expand(pair, base_w, base_f),
-                    jax.jit(lay.expand)(tuple(map(jnp.asarray, pair)),
+                    jax.jit(lay.expand)(jnp.asarray(pair),
                                         jnp.asarray(base_w),
                                         jnp.asarray(base_f))):
             np.testing.assert_array_equal(np.asarray(got[0]), words)
@@ -500,16 +574,24 @@ def test_row_counts_round_trip(count):
         RowLayout({"x": np.zeros(G, np.float32)}, G, K)
 
 
-@pytest.mark.parametrize("config, columns", [
-    ("coord-1g-3v", False), ("multiraft-1k-3v", False),
-    ("multiraft-10k-3v", False), ("multiraft-100k-3v", True),
+@pytest.mark.parametrize("config, columns, words, buffers", [
+    ("coord-1g-3v", False, 1, 1), ("multiraft-1k-3v", False, 1, 1),
+    ("multiraft-10k-3v", False, 2, 2), ("multiraft-10k-3v-hb2", False, 2, 2),
+    ("multiraft-100k-3v", True, 10, 11),
+    ("multiraft-100k-3v-hib", True, 10, 11),
 ])
-def test_shape_rule_on_the_benchmarks_configurations(config, columns):
+def test_shape_rule_on_the_benchmarks_configurations(config, columns, words,
+                                                     buffers):
     """Which program a node runs follows from its shape alone: the cells
-    whose dense planes take fewer than COLUMN_BUFFERS word buffers keep
-    node_step_packed (at 10,000 lanes, two buffers, columns cost the chip
-    more than they saved: PERF.md, PR 35), the 100,000-Region cell takes
-    columns."""
+    whose dense ``int32`` planes take fewer than COLUMN_BUFFERS buffers
+    keep node_step_packed (at 10,000 lanes, two buffers, columns cost the
+    chip more than they saved: PERF.md, PR 35), the 100,000-Region cells
+    take columns.  The count is of the buffers that hold words, which are
+    the ones the words had while the flags had buffers of their own
+    (``words``: 1, 2 and 10 then as now): that the flags now ride behind
+    them moves no shape across the rule, and takes a buffer a way off
+    every packed step (``buffers``: what the dense operand crosses in;
+    2, 3 and 12 before)."""
     import json
     import os
     from rafting_tpu.api import RaftConfig
@@ -522,6 +604,7 @@ def test_shape_rule_on_the_benchmarks_configurations(config, columns):
                      data_dir="unused", **raft).engine_config()
     lay = column_layouts(cfg, True)
     assert (lay is not None) == columns
-    words = sum(dt == packing.WORD
-                for dt, _ in step_layouts(cfg, True)[0].buffers)
+    inputs, _ = step_layouts(cfg, True)
+    assert sum(w > 0 for w in inputs.words) == words
     assert (words >= COLUMN_BUFFERS) == columns
+    assert len(inputs.buffers) == buffers

@@ -83,7 +83,7 @@ class RowHost:
         self.seen = dict(rows_in=0, whole_in=0, rows_out=0, whole_out=0)
 
     def upload(self, host: HostInbox) -> tuple:
-        """(HostInbox's planes or None, the row pair) for one step."""
+        """(HostInbox's planes or None, the row buffer) for one step."""
         rl = self.lay.rows_in
         said = (host.submit_n != 0) | host.snap_done | (host.compact_to != 0) \
             | (host.conf_voters != 0) | (host.xfer_target != NIL) \
@@ -97,8 +97,8 @@ class RowHost:
             self.sent = None if durable is None else durable.copy()
             return host, rl.whole(host)
         self.seen["rows_in"] += 1
-        pair = rl.alloc()
-        view = rl.view(pair)
+        buf = rl.alloc()
+        view = rl.view(buf)
         view.set_n(len(ids))
         view.ids[:len(ids)] = ids
         view.set_head("read_veto", host.read_veto)
@@ -107,14 +107,14 @@ class RowHost:
             view.field(name)[:len(ids)] = getattr(host, name)[ids]
         if durable is not None:
             self.sent[ids] = durable[ids]
-        return None, pair
+        return None, buf
 
     def fetch(self, rows, tag) -> Readback:
         """The mirror after one step's results, patched from the fetched
-        row pair or taken whole (``pack_readback``) when they do not fit;
-        the events are the step's own until :meth:`done`."""
+        row buffer or taken whole (``pack_readback``) when they do not
+        fit; the events are the step's own until :meth:`done`."""
         rl = self.lay.rows_out
-        view = rl.view(jax.device_get(rows))
+        view = rl.view(rows)
         n = view.n
         if n > rl.K or self.first:
             self.first = False
@@ -138,6 +138,45 @@ class RowHost:
             rl = self.lay.rows_out
             self.words[rl.Lw:, self.moved] = 0
             self.flags[rl.Lf:, self.moved] = False
+
+
+def one_up(lay, rows, inbox) -> tuple:
+    """What a step that fits uploads: ONE word buffer, HostInbox's row
+    buffer and behind it the inbox's columns, allocated once and filled
+    through the views the runtime fills (``alloc_regions``); the regions
+    read back what was written, count for count and field for field."""
+    up, (r, c) = packing.alloc_regions(lay.rows_in, lay.columns)
+    assert up.dtype == np.int32 and up.shape == (
+        lay.rows_in.size + lay.columns.size,)
+    assert lay.rows_in.view(r).n == 0 and (lay.columns.view(c).n == 0).all()
+    r[...] = rows
+    c[...] = lay.columns.compact(inbox)
+    held = _on_columns(inbox)
+    view = lay.columns.view(packing.regions(up, lay.rows_in, lay.columns)[1])
+    for name in inbox.__dataclass_fields__:
+        if getattr(inbox, name) is not None and (view.n <= lay.columns.K).all():
+            np.testing.assert_array_equal(view.dense(name),
+                                          getattr(held, name), name)
+    for a, b in zip(lay.rows_in._parts(up[:lay.rows_in.size]),
+                    lay.rows_in._parts(rows)):
+        np.testing.assert_array_equal(a, b)
+    return (up,)
+
+
+def one_down(lay, down, columns, p_out, tag) -> tuple:
+    """What a column step's fetch brings down: ONE word buffer, the
+    Readback's row buffer and behind it the outbox's columns as the step
+    returned them, which are the dense outbox compacted.  Returns the two
+    regions."""
+    down = jax.device_get(down)
+    assert down.dtype == np.int32 and down.shape == (
+        lay.rows_out.size + lay.columns.size,), tag
+    rows, cols = packing.regions(down, lay.rows_out, lay.columns)
+    assert np.shares_memory(rows, down) and np.shares_memory(cols, down)
+    np.testing.assert_array_equal(cols, jax.device_get(columns), tag)
+    np.testing.assert_array_equal(
+        cols, lay.columns.compact(jax.device_get(p_out)), tag)
+    return rows, cols
 
 
 def assert_mirror_is(back: Readback, want: Readback, tag: str):
@@ -221,16 +260,17 @@ def test_rows_in_and_out_are_the_dense_planes_bit_for_bit(
             plain[n], p_out, p_info = node_step(
                 cfg, plain[n], *jax.tree.map(jnp.asarray, (inbox, host)))
             h = hosts[n]
-            planes, pair = h.upload(host)
+            planes, up = h.upload(host)
             base = resident if planes is None else lay.host.pack(planes)
             if columns_in:
-                bufs = base + lay.columns.compact(inbox)
+                bufs = base + one_up(lay, up, inbox)
             else:
-                bufs = lay.inputs.pack((lay.host.unpack(base), inbox))
+                bufs = lay.inputs.pack((lay.host.unpack(base), inbox)) + (up,)
             last = h.carry
-            rows[n], h.carry, c_pair, dense = node_step_columns(
-                cfg, lay, columns_in, rows[n], last, bufs + pair)
-            r_pair = compact_readback(lay, h.carry, last)
+            rows[n], h.carry, c_out, dense = node_step_columns(
+                cfg, lay, columns_in, rows[n], last, bufs)
+            r_down, _ = one_down(lay, compact_readback(
+                lay, h.carry, last, c_out), c_out, p_out, tag)
             assert_trees_equal(rows[n], plain[n], tag)
             assert_trees_equal(lay.columns.unstack(dense), p_out, tag)
             if durable:         # what the commit clamp of phase 10 read
@@ -242,7 +282,7 @@ def test_rows_in_and_out_are_the_dense_planes_bit_for_bit(
                 voted_for=s.voted_for, role=s.role, leader_id=s.leader_id,
                 commit=s.commit, base=s.log.base,
                 base_term=s.log.base_term, heat=s.heat, windows=None)
-            back = h.fetch(r_pair, tag)
+            back = h.fetch(r_down, tag)
             assert_mirror_is(back._replace(windows=None), want, tag)
             h.done()
             outboxes.append(jax.device_get(p_out))
@@ -282,23 +322,23 @@ def test_one_lane_over_either_capacity_falls_back_by_count(small, lanes):
         nonlocal plain, rows
         plain, p_out, p_info = node_step(
             cfg, plain, *jax.tree.map(jnp.asarray, (inbox, host)))
-        planes, pair = h.upload(host)
+        planes, up = h.upload(host)
         base = resident if planes is None else lay.host.pack(planes)
         last = h.carry
-        rows, h.carry, c_pair, dense = node_step_columns(
-            cfg, lay, True, rows, last,
-            base + lay.columns.compact(inbox) + pair)
-        r_pair = compact_readback(lay, h.carry, last)
+        rows, h.carry, c_out, dense = node_step_columns(
+            cfg, lay, True, rows, last, base + one_up(lay, up, inbox))
+        r_down, _ = one_down(lay, compact_readback(
+            lay, h.carry, last, c_out), c_out, p_out, tag)
         assert_trees_equal(rows, plain, tag)
         s = plain
-        back = h.fetch(r_pair, tag)
+        back = h.fetch(r_down, tag)
         assert_mirror_is(back._replace(windows=None), Readback(
             info=p_info, outbox=None, term=s.term, voted_for=s.voted_for,
             role=s.role, leader_id=s.leader_id, commit=s.commit,
             base=s.log.base, base_term=s.log.base_term, heat=s.heat,
             windows=None), tag)
         h.done()
-        return int(jax.device_get(r_pair[0])[0]), p_info
+        return lay.rows_out.view(r_down).n, p_info
 
     for t in range(40):             # elect: every lane led and quiet
         step(empty, f"boot {t}")
@@ -456,3 +496,95 @@ def test_device_durable_tail_and_gauges_through_a_storm_a_failed_barrier_a_purge
                 assert m[name] > 0, (n.node_id, name)
     finally:
         c.close()
+
+
+# name -> (rows a step holds each way, columns a peer row holds, lanes
+# written in one step, whether the Readback and the outbox then overflow)
+CROSSINGS = {
+    "fits": (8, 8, 3, False, False),
+    "rows-over": (4, 8, 6, True, False),
+    "columns-over": (8, 3, 6, False, True),
+    "both-over": (4, 3, 6, True, True),
+}
+
+
+@pytest.mark.parametrize("case", list(CROSSINGS))
+def test_a_column_step_is_one_array_each_way_and_an_overflow_is_fetched_whole(
+        tmp_path, small, monkeypatch, case):
+    """One step of a node that leads six lanes or more accepts a write on
+    ``lanes`` of them at once.  Where rows and columns hold it, the step
+    uploads ONE array (``transfers`` 1 on ``raft.dispatch_upload``) and
+    fetches ONE (1 on ``raft.scan_fetch``); where the Readback moved more
+    lanes than its rows hold, where a row of the outbox holds more
+    columns than the buffer, and where both do, that part comes down
+    whole in the dense layout's buffers beside the one array, counted as
+    what really crossed; and after every step the mirrors are the
+    device's lanes and every write is acknowledged."""
+    from rafting_tpu.utils.profiling import StageSpans
+    k_rows, k_columns, lanes, rows_over, columns_over = CROSSINGS[case]
+    small(k_rows, k_rows, columns=k_columns)
+    cfg = EngineConfig(n_peers=3, pre_vote=True, **BASE)
+    lay = column_layouts(cfg, False)
+    assert lay is not None
+    c = LocalCluster(cfg, str(tmp_path), provider_factory=NullProvider,
+                     seed=5, pipeline=False)
+    try:
+        for _ in range(60):
+            c.tick()
+        node = max(c.nodes.values(), key=lambda n: int(
+            ((n.h_role == LEADER) & n.h_ready).sum()))
+        led = np.nonzero((node.h_role == LEADER) & node.h_ready)[0]
+        assert len(led) >= 6
+        notes = []
+        real = StageSpans.note
+
+        def spy(self, **stats):
+            if self is node._stages and "transfers" in stats:
+                notes.append((self._name, node.ticks, stats))
+            return real(self, **stats)
+
+        monkeypatch.setattr(StageSpans, "note", spy)
+        futs = []
+        for _ in range(3):          # in each phase of the heartbeat's three
+            futs += [node.submit_batch(int(g), [b"w"]) for g in led[:lanes]]
+            for _ in range(4):
+                c.tick()
+                assert_mirrors_and_gauges(c)
+        c.tick(10)
+        assert all(f.done() and f.exception() is None for f in futs)
+    finally:
+        monkeypatch.undo()
+        step_layouts.cache_clear()
+        column_layouts.cache_clear()
+        c.close()
+    size = lambda layout: sum(4 * n for _, n in layout.buffers)
+    up = {t: s for name, t, s in notes if name == "dispatch_upload"}
+    down = {t: s for name, t, s in notes if name == "scan_fetch"}
+    assert up.keys() == down.keys() and len(up) >= 22
+    for s in up.values():       # the rows, the columns behind them
+        planes = lay.inputs if s["dense"] else \
+            lay.host if s["planes_dense"] else None
+        assert s["transfers"] == 1 + (len(planes.buffers) if planes else 0)
+        assert s["bytes"] == lay.rows_in.nbytes \
+            + (0 if s["dense"] else lay.columns.nbytes) \
+            + (size(planes) if planes else 0)
+    for s in down.values():     # the rows and the columns, and what
+        assert s["transfers"] == 1 \
+            + len(lay.back.buffers) * s["planes_dense"] \
+            + len(lay.outbox.buffers) * s["dense"]   # either does not hold
+        assert s["bytes"] == lay.rows_out.nbytes + lay.columns.nbytes \
+            + size(lay.back) * s["planes_dense"] \
+            + size(lay.outbox) * s["dense"]
+    # The step that took the writes: it offered ``lanes`` rows ...
+    wrote = [t for t, s in up.items() if not s["planes_dense"]
+             and s["rows"] >= lanes] if lanes <= k_rows else \
+        [t for t, s in up.items() if s["planes_dense"]]
+    assert wrote, up
+    # ... and came down as the case says, each part by its own count.
+    assert any((down[t]["planes_dense"], down[t]["dense"])
+               == (int(rows_over), int(columns_over)) for t in wrote), \
+        [down[t] for t in wrote]
+    if case == "fits":
+        assert any(up[t]["transfers"] == down[t]["transfers"] == 1
+                   and down[t]["rows"] >= lanes and down[t]["columns"] > 0
+                   for t in wrote), [(up[t], down[t]) for t in wrote]

@@ -158,10 +158,11 @@ def test_read_and_maintain_spans_carry_what_the_tick_did(tmp_path):
 
 
 def test_a_tick_crosses_to_the_device_in_two_transfers_each_way(tmp_path):
-    """A served tick uploads its packed buffers and fetches the step's:
+    """A served tick uploads its packed buffer and fetches the step's:
     ``raft.dispatch_upload`` and ``raft.scan_fetch`` note ``transfers`` of
-    at most 2 on every tick of every node, and the counters
-    ``h2d_transfers`` / ``d2h_transfers`` advance by no more a tick."""
+    exactly 1 on every tick of every node (2 while the flags had a buffer
+    of their own, whence the name: two a tick in all now), and the
+    counters ``h2d_transfers`` / ``d2h_transfers`` advance by 1 a tick."""
     import jax
 
     cfg = EngineConfig(n_groups=16, n_peers=3)
@@ -181,7 +182,7 @@ def test_a_tick_crosses_to_the_device_in_two_transfers_each_way(tmp_path):
                 c.tick()
         for i, n in c.nodes.items():
             for k, was in zip(counters, before[i]):
-                assert 8 <= n.metrics[k] - was <= 2 * 8, (i, k)
+                assert n.metrics[k] - was == 8, (i, k)
     finally:
         c.close()
     noted = {"raft.dispatch_upload": [], "raft.scan_fetch": []}
@@ -190,7 +191,7 @@ def test_a_tick_crosses_to_the_device_in_two_transfers_each_way(tmp_path):
             noted[name].append(stats["transfers"])
     for name, transfers in noted.items():
         assert len(transfers) == 3 * 8, name
-        assert all(1 <= t <= 2 for t in transfers), (name, transfers)
+        assert transfers == [1] * (3 * 8), (name, transfers)
 
 
 def test_lease_hits_lanes_and_leaderless_ride_the_spans(tmp_path):

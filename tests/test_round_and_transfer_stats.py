@@ -224,26 +224,23 @@ def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch,
         column_layouts.cache_clear()
         lc.close()
 
-    def size(*layouts):
-        return sum(int(np.prod(shape)) * np.dtype(dt).itemsize
-                   for layout in layouts for dt, shape in layout.buffers)
+    def size(layout):
+        return sum(n * np.dtype(dt).itemsize for dt, n in layout.buffers)
 
     by_phase = {name: kw for name, kw in notes if "bytes" in kw}
     assert set(by_phase) == {"dispatch_upload", "scan_fetch"}
     up, down = by_phase["dispatch_upload"], by_phase["scan_fetch"]
     assert (lay is not None) == columns
     if columns:
-        # A column pair and a row pair each way, and the [G] planes'
-        # buffers beside them only where a span says they crossed whole
-        # (``planes_dense``: rows is then 0).
-        rows = lambda layout: sum(
-            n * np.dtype(dt).itemsize for dt, n in layout.buffers)
-        for span, planes, pair in ((up, lay.host, lay.rows_in),
+        # ONE buffer each way, the rows and behind them the columns, and
+        # the [G] planes' buffers beside it only where a span says they
+        # crossed whole (``planes_dense``: rows is then 0).
+        for span, planes, rows in ((up, lay.host, lay.rows_in),
                                    (down, lay.back, lay.rows_out)):
             whole = span["planes_dense"]
-            assert whole in (0, 1) and span["rows"] <= pair.K * (1 - whole)
-            assert span["transfers"] == 4 + len(planes.buffers) * whole
-            assert span["bytes"] == size(lay.columns) + rows(pair) \
+            assert whole in (0, 1) and span["rows"] <= rows.K * (1 - whole)
+            assert span["transfers"] == 1 + len(planes.buffers) * whole
+            assert span["bytes"] == lay.columns.nbytes + rows.nbytes \
                 + size(planes) * whole > 0
         assert up["dense"] == down["dense"] == 0
         if fetched is not None:

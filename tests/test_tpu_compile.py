@@ -153,8 +153,9 @@ def test_node_step_holds_no_gather_and_no_scatter(one_chip, packed, config,
         _, readback = step_layouts(cfg, True)
         assert (len(WINDOW_SUMS),) in [shape for *_, shape in readback.slots]
         _, out = lowered.out_info
+        # Two buffers each way (three while the flags had one of their own).
         assert tuple((np.dtype(o.dtype), o.shape[0]) for o in out) \
-            == readback.buffers and len(out) == 3
+            == readback.buffers and len(out) == 2
     compiled = lowered.compile()
     hlo = compiled.as_text()
     found = {op: len(re.findall(rf"\b{op}\(", hlo))
@@ -215,18 +216,19 @@ def test_column_step_compiles_at_100k_lanes_and_addresses_k_rows(
     assert (len(WINDOW_SUMS),) in [shape for *_, shape in lay.back.slots]
     assert len(lay.back.buffers) == 4
 
-    def bufs(layout):
-        return tuple(jax.ShapeDtypeStruct(
-            (n,) if isinstance(n, int) else n, dt, sharding=one_chip)
-            for dt, n in layout.buffers)
+    words = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32,
+                                           sharding=one_chip)
+    bufs = lambda layout: tuple(words(n) for _, n in layout.buffers)
 
     state = _on(one_chip, jax.eval_shape(lambda: init_state(cfg, 0, seed=0)))
     carry = _on(one_chip, jax.eval_shape(lambda: first_carry(lay)))
-    operand = bufs(lay.host) + bufs(lay.columns) if columns_in \
-        else bufs(lay.inputs)
-    compiled = node_step_columns.lower(
-        cfg, lay, columns_in, state, carry,
-        operand + bufs(lay.rows_in)).compile()
+    # One buffer beside the planes: the rows, and the columns behind them.
+    operand = bufs(lay.host) + (words(lay.rows_in.size + lay.columns.size),) \
+        if columns_in else bufs(lay.inputs) + (words(lay.rows_in.size),)
+    lowered = node_step_columns.lower(
+        cfg, lay, columns_in, state, carry, operand)
+    assert lowered.out_info[2].shape == (lay.columns.size,)
+    compiled = lowered.compile()
     mem = compiled.memory_analysis()
     per_node = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes)
@@ -246,7 +248,10 @@ def test_column_step_compiles_at_100k_lanes_and_addresses_k_rows(
     # ... and coming out, in the program of their own: one gather a kind
     # (the K_out rows that moved) and the one-block-a-row gather of the
     # search for them.
-    rows = compact_readback.lower(lay, carry, carry).compile()
+    # ... and ONE result: the rows and, behind them, the outbox's columns.
+    rows = compact_readback.lower(lay, carry, carry, words(lay.columns.size))
+    assert rows.out_info.shape == (lay.rows_out.size + lay.columns.size,)
+    rows = rows.compile()
     found = _index_rows(rows.as_text())
     print(f"compact_readback: {found}")
     assert found and {op for op, _ in found} == {"gather"}, found

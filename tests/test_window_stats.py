@@ -302,17 +302,19 @@ def _packed_step(cfg):
 def _column_step(cfg):
     lay = column_layouts(cfg, True)
     assert lay is not None
-    # HostInbox and the Readback cross whole here (a row pair that holds
-    # none; pack_readback), so one carry serves every node of the test.
+    # HostInbox and the Readback cross whole here (a row buffer that
+    # holds none; pack_readback), so one carry serves every node of the
+    # test.
     carry = [first_carry(lay)]
 
     def step(state, host, inbox):
-        pair = lay.columns.compact(inbox)
-        fits = bool((pair[0][:, 0] <= lay.columns.K).all())
-        bufs = lay.host.pack(host) + pair if fits \
-            else lay.inputs.pack((host, inbox))
+        held = lay.columns.compact(inbox)
+        fits = bool((lay.columns.view(held).n <= lay.columns.K).all())
+        rows = lay.rows_in.whole(host)
+        bufs = lay.host.pack(host) + (np.concatenate([rows, held]),) \
+            if fits else lay.inputs.pack((host, inbox)) + (rows,)
         state, carry[0], _, dense = node_step_columns(
-            cfg, lay, fits, state, carry[0], bufs + lay.rows_in.whole(host))
+            cfg, lay, fits, state, carry[0], bufs)
         back = lay.back.unpack(jax.device_get(pack_readback(lay, carry[0])))
         return state, back._replace(outbox=lay.columns.unstack(dense))
     return step

@@ -8,9 +8,13 @@
 
 ``device``: the served step program at the 100,000-Region cell's shape, wall
 clock around ``block_until_ready``, median of 30 calls in one process: the
-packed step, and the column step for each ``--rows K_in,K_out`` (on a tree
-without the row form: the column step as it is).  Every call carries empty
-messages and a handful of rows, as a step between two heartbeat rounds does.
+packed step, and the column step for each ``--rows K_in,K_out``.  Every call
+carries empty messages and a handful of rows, as a step between two heartbeat
+rounds does.
+Beside each median: the arrays the call takes from the host and returns to
+it (what a step uploads and fetches), and their bytes.  ``--config NAME``
+times another configuration's packed step (its column step too where its
+shape takes one).
 
 ``check``: three nodes at that shape stepped through the packed step and
 through the row form on this backend; every state, outbox, mirror and the
@@ -68,87 +72,91 @@ def median_ms(call, n=30):
     return statistics.median(times[3:]) * 1e3
 
 
+def crossing(up, down) -> str:
+    """The arrays a call takes from the host and returns to it."""
+    import jax
+    up, down = jax.tree.leaves(up), jax.tree.leaves(down)
+    return (f"{len(up)} array(s) up ({sum(a.nbytes for a in up)} B), "
+            f"{len(down)} down ({sum(a.nbytes for a in down)} B)")
+
+
 def device(rows):
     import jax
     from rafting_tpu.core import packing, step
     from rafting_tpu.core.types import NIL, init_state
 
     cfg = engine_config()
-    print("device", jax.devices()[0].device_kind, "lanes", cfg.n_groups,
-          flush=True)
+    print("device", jax.devices()[0].device_kind, "config", CONFIG, "lanes",
+          cfg.n_groups, flush=True)
     inputs, _ = step.step_layouts(cfg, True)
     state = [init_state(cfg, 0, seed=1)]
-    dense = jax.device_put(inputs.alloc())
+    dense = tuple(jax.device_put(inputs.alloc()))
 
     def packed():
         state[0], out = step.node_step_packed(cfg, inputs, state[0], dense)
         return out
 
     t0 = time.perf_counter()
-    jax.block_until_ready(packed())
+    out = jax.block_until_ready(packed())
     print(f"node_step_packed: first call {time.perf_counter() - t0:.1f} s, "
-          f"median {median_ms(packed):.3f} ms", flush=True)
-    has_rows = hasattr(step, "first_carry")
-    for k_in, k_out in (rows if has_rows else [(0, 0)]):
-        if has_rows:
-            packing.ROWS_IN, packing.ROWS_OUT = k_in, k_out
+          f"median {median_ms(packed):.3f} ms; {crossing(dense, out)}",
+          flush=True)
+    if step.column_layouts(cfg, True) is None:
+        return
+    for k_in, k_out in rows:
+        packing.ROWS_IN, packing.ROWS_OUT = k_in, k_out
         step.column_layouts.cache_clear()
         lay = step.column_layouts(cfg, True)
         state = [init_state(cfg, 0, seed=1)]
         host = lay.host.alloc()
         lay.host.unpack(host).xfer_target[...] = NIL
         resident = tuple(jax.device_put(host))
-        pair = jax.device_put(lay.columns.alloc())
-        if has_rows:
-            carry = [step.first_carry(lay)]
-            rp = lay.rows_in.alloc()
-            view = lay.rows_in.view(rp)
-            view.set_n(3)
-            view.ids[:3] = (5, 77, 4242)
-            view.field("xfer_target")[:3] = NIL
-            view.field("submit_n")[:3] = 1
-            view.set_head("clock", 0)
-            rp = jax.device_put(rp)
+        # One array up: the rows, and the (empty) columns behind them.
+        up, (rp, _) = packing.alloc_regions(lay.rows_in, lay.columns)
+        carry = [step.first_carry(lay)]
+        view = lay.rows_in.view(rp)
+        view.set_n(3)
+        view.ids[:3] = (5, 77, 4242)
+        view.field("xfer_target")[:3] = NIL
+        view.field("submit_n")[:3] = 1
+        view.set_head("clock", 0)
+        rp, up = (jax.device_put(rp),), (jax.device_put(up),)
 
-            def columns():
-                last = carry[0]
-                out = step.node_step_columns(
-                    cfg, lay, True, state[0], last, resident + pair + rp)
-                state[0], carry[0] = out[0], out[1]
-                return step.compact_readback(lay, carry[0], last), out[2]
-        else:
-            def columns():
-                out = step.node_step_columns(
-                    cfg, lay, True, state[0], resident + pair)
-                state[0] = out[0]
-                return out[1:3]
+        def columns():
+            last = carry[0]
+            out = step.node_step_columns(
+                cfg, lay, True, state[0], last, resident + up)
+            state[0], carry[0] = out[0], out[1]
+            return step.compact_readback(lay, carry[0], last, out[2])
+
         t0 = time.perf_counter()
-        jax.block_until_ready(columns())
+        out = jax.block_until_ready(columns())
         first = time.perf_counter() - t0
         print(f"node_step_columns rows in/out {k_in}/{k_out}: first call "
-              f"{first:.1f} s, median {median_ms(columns):.3f} ms",
-              flush=True)
-        if has_rows:
-            def back():
-                return columns(), step.pack_readback(lay, carry[0])
+              f"{first:.1f} s, median {median_ms(columns):.3f} ms; "
+              f"{crossing(up, out)}", flush=True)
 
-            jax.block_until_ready(back())
-            print(f"  ... + pack_readback: median {median_ms(back):.3f} ms",
-                  flush=True)
+        def back():
+            return columns(), step.pack_readback(lay, carry[0])
 
-            def dense_in():     # the other form of the operand
-                last = carry[0]
-                out = step.node_step_columns(
-                    cfg, lay, False, state[0], last, dense + rp)
-                state[0], carry[0] = out[0], out[1]
-                return (step.compact_readback(lay, carry[0], last),
-                        step.pack_outbox(lay, out[3]))
+        out = jax.block_until_ready(back())
+        print(f"  ... + pack_readback: median {median_ms(back):.3f} ms; "
+              f"{crossing(up, out)}", flush=True)
 
-            t0 = time.perf_counter()
-            jax.block_until_ready(dense_in())
-            first = time.perf_counter() - t0
-            print(f"  dense operand + pack_outbox: first call {first:.1f} s, "
-                  f"median {median_ms(dense_in, 10):.3f} ms", flush=True)
+        def dense_in():     # the other form of the operand
+            last = carry[0]
+            out = step.node_step_columns(
+                cfg, lay, False, state[0], last, dense + rp)
+            state[0], carry[0] = out[0], out[1]
+            return (step.compact_readback(lay, carry[0], last, out[2]),
+                    step.pack_outbox(lay, out[3]))
+
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(dense_in())
+        first = time.perf_counter() - t0
+        print(f"  dense operand + pack_outbox: first call {first:.1f} s, "
+              f"median {median_ms(dense_in, 10):.3f} ms; "
+              f"{crossing(dense + rp, out)}", flush=True)
 
 
 def on_valid(msgs):
@@ -184,7 +192,7 @@ def check(steps, whole_in=False):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from rafting_tpu.core import step
+    from rafting_tpu.core import packing, step
     from rafting_tpu.core.cluster import route
     from rafting_tpu.core.types import HostInbox, Messages, NIL, init_state
 
@@ -206,7 +214,8 @@ def check(steps, whole_in=False):
     resident = tuple(jax.device_put(zero))
     outboxes = [jax.device_get(Messages.empty(cfg))] * N
     tails = [np.zeros(G, np.int32)] * N
-    seen = dict(rows_in=0, whole_in=0, rows_out=0, whole_out=0, cols_in=0)
+    seen = dict(rows_in=0, whole_in=0, rows_out=0, whole_out=0, cols_in=0,
+                cols_out=0)
     for t in range(steps):
         inflight = jax.tree.map(lambda *a: np.stack(a), *outboxes)
         inboxes = jax.device_get(route(inflight, jnp.ones((N, N), bool)))
@@ -232,14 +241,14 @@ def check(steps, whole_in=False):
             if sent[n] is not None:
                 said |= tails[n] != sent[n]
             ids = np.nonzero(said)[0]
-            pair = lay.columns.compact(inbox)
-            fits = bool((pair[0][:, 0] <= lay.columns.K).all())
+            held = lay.columns.compact(inbox)
+            fits = bool((lay.columns.view(held).n <= lay.columns.K).all())
             seen["cols_in"] += fits
             if sent[n] is None or len(ids) > rin.K or whole_in:
                 seen["whole_in"] += 1
                 sent[n] = tails[n].copy()
                 rp = rin.whole(host)
-                up = jax.device_put(lay.host.pack(host))
+                up = tuple(jax.device_put(lay.host.pack(host)))
             else:
                 seen["rows_in"] += 1
                 rp = rin.alloc()
@@ -253,19 +262,22 @@ def check(steps, whole_in=False):
                 v.field("durable_tail")[:len(ids)] = tails[n][ids]
                 sent[n][ids] = tails[n][ids]
                 up = resident
-            if fits:
-                operand = up + jax.device_put(pair + rp)
+            if fits:        # one array: the rows, the columns behind them
+                operand = up + (jax.device_put(
+                    np.concatenate([rp, held])),)
             else:
                 dense = lay.inputs.alloc()
                 d_host, d_inbox = lay.inputs.unpack(dense)
                 jax.tree.map(np.copyto, d_inbox, inbox)
                 jax.tree.map(np.copyto, d_host, lay.host.unpack(
                     jax.device_get(up)))
-                operand = jax.device_put(dense + rp)
+                operand = tuple(jax.device_put(dense + (rp,)))
             last = carry[n]
-            rows[n], carry[n], c_pair, o_dense = step.node_step_columns(
+            rows[n], carry[n], c_out, o_dense = step.node_step_columns(
                 cfg, lay, fits, rows[n], last, operand)
-            r_pair = step.compact_readback(lay, carry[n], last)
+            down = jax.device_get(step.compact_readback(
+                lay, carry[n], last, c_out))
+            r_down, c_down = packing.regions(down, rout, lay.columns)
             tag = f"step {t} node {n}"
             bad = []
             for (path, a), b in zip(
@@ -290,7 +302,18 @@ def check(steps, whole_in=False):
             for a, b in zip(jax.tree.leaves(on_valid(got_out)),
                             jax.tree.leaves(on_valid(want.outbox))):
                 np.testing.assert_array_equal(a, b, tag + " outbox")
-            view = rout.view(jax.device_get(r_pair))
+            # The outbox's columns, as they came down behind the rows: the
+            # dense outbox on its valid lanes wherever every row fits.
+            np.testing.assert_array_equal(c_down, jax.device_get(c_out), tag)
+            cols = lay.columns.view(c_down)
+            if (cols.n <= lay.columns.K).all():
+                seen["cols_out"] += 1
+                held = want.outbox.replace(**{
+                    name: cols.dense(name) for name in cols.planes})
+                for a, b in zip(jax.tree.leaves(on_valid(held)),
+                                jax.tree.leaves(on_valid(want.outbox))):
+                    np.testing.assert_array_equal(a, b, tag + " columns")
+            view = rout.view(r_down)
             words, flags = mirror[n]
             if view.n > rout.K or first[n]:
                 first[n] = False
@@ -428,6 +451,8 @@ def main():
     sub = ap.add_subparsers(dest="cmd", required=True)
     d = sub.add_parser("device")
     d.add_argument("--rows", action="append", default=[])
+    d.add_argument("--config", default=CONFIG,
+                   help="benchmark/configs/<name>.json")
     c = sub.add_parser("check")
     c.add_argument("--steps", type=int, default=40)
     c.add_argument("--whole-in", type=int, default=0)
@@ -448,6 +473,7 @@ def main():
         CONFIG = a.config
         check(a.steps, bool(a.whole_in))
     elif a.cmd == "device":
+        CONFIG = a.config
         device([tuple(int(k) for k in s.split(","))
                 for s in a.rows] or [(512, 512)])
     else:
