@@ -125,25 +125,42 @@ def settles_now(now: float, due: Optional[float], cost: float) -> bool:
 
 
 def arrival_step_at(now: float, ended: float, took: float,
-                    due: Optional[float], cost: float) -> Optional[float]:
+                    due: Optional[float], cost: float,
+                    waited: float = 0.0) -> Optional[float]:
     """When a loop with work waiting at ``now`` may start a step for it
     between two timer ticks, or None when the work is to ride the
     timer's own tick at ``due``.  ``ended`` and ``took``: when the last
-    step ended and how long it ran; ``cost``: what a whole step has
-    lately taken (all in seconds, on the clock of ``now``).  Two rules,
-    both about what the loop observes of itself:
+    step ended and how long it ran; ``waited``: how much of ``took`` its
+    thread spent blocked on another agent, holding no interpreter;
+    ``cost``: what a whole step has lately taken (all in seconds, on the
+    clock of ``now``).  Two rules, both about what the loop observes of
+    itself:
 
-    * the gap: no step starts before the last step's own duration has
-      passed since it ended.  Arrivals inside the gap share the next
-      step, so the batching a dense load needs comes back by itself as
-      load rises, and one node's arrival steps never take more than
-      about half of an interpreter;
-    * room: ``SETTLE_MARGIN`` times ``cost`` fits between the start and
-      ``due``, so an arrival step settles (``settles_now``) and never
-      makes a timer tick late.  A node whose step fills its period gets
-      None every time and ticks by the timer alone, as it always did.
+    * the gap: no step starts before the time the last step HELD THE
+      INTERPRETER (``took - waited``) has passed since it ended.
+      Arrivals inside the gap share the next step, so the batching a
+      dense load needs comes back by itself as load rises (interpreter
+      time a step grows with the operations it carries), and one node's
+      arrival steps never take more than about half of an interpreter.
+      Blocked is what the loop observes at boundaries it has anyway: the
+      wait for the device (stage ``scan_device``: ``block_until_ready``),
+      the copy down (``scan_fetch``: ``device_get``) and the WAL's own
+      fsync seconds; all three run with the interpreter's lock released,
+      so charging them a second time behind the step bought no other
+      thread anything.  ``dispatch_enqueue`` is the host's own work on
+      the call of the step (CPU, not a wait: PERF.md, PR 36) and
+      ``dispatch_upload`` holds the lock for most of its length (PERF.md,
+      PR 43): both count as held.  A Python stage that waited for the
+      interpreter counts as held too, so three loops that crowd one
+      interpreter lengthen their own gaps.  ``waited`` 0 (a step that
+      failed, or ran no host phase) is the step's whole duration;
+    * room: ``SETTLE_MARGIN`` times ``cost`` (the WHOLE step, waits and
+      all) fits between the start and ``due``, so an arrival step
+      settles (``settles_now``) and never makes a timer tick late.  A
+      node whose step fills its period gets None every time and ticks by
+      the timer alone, as it always did.
     """
-    at = max(now, ended + took)
+    at = max(now, ended + max(0.0, took - waited))
     return at if settles_now(at, due, cost) else None
 
 
@@ -909,6 +926,15 @@ class RaftNode:
         # Seconds per whole step, the same memory: arrival_step_at()
         # weighs their median against the time left until _tick_due.
         self._step_costs: deque = deque(maxlen=HOST_COST_MEMORY)
+        # Seconds the tick() call under way spent in the WAL's fsyncs,
+        # as _host_phase adds them up: with the stages scan_device and
+        # scan_fetch what its thread spent blocked on another agent, which
+        # _run takes off the gap behind the step (arrival_step_at()).
+        self._fsync_waited = 0.0
+        # _await_step to the step it lets start: (held, gap, waited), for
+        # the step's raft.dispatch_intake span and the two metrics; None
+        # under a caller that steps the node itself.
+        self._gap_given: Optional[Tuple[bool, float, float]] = None
         # Heartbeat rounds in flight, oldest first: [the engine's clock of
         # the timer's step that sent the period's heartbeats, that step's
         # start, the peers whose acknowledgement of it is still out].
@@ -1577,11 +1603,17 @@ class RaftNode:
     def _run(self, interval: float) -> None:
         """The loop: a step when the period's timer fires, and a step when
         work waits in between (``_wake``) and ``arrival_step_at`` finds
-        the gap passed and room before the timer.  The period stays the
-        ENGINE's clock whatever the arrival rate: only the timer's step
-        advances it (``tick(arrival=False)``), and ``_next_start``,
-        ``tick_late_s`` and ``ticks_late`` are about timer ticks alone.
-        The first step is the timer's."""
+        the gap passed and room before the timer.  The gap behind a step
+        is the time it held the interpreter: its duration less what
+        ``tick()`` observed of its own waits (the stages ``scan_device``
+        and ``scan_fetch`` and the WAL's fsync seconds of that call,
+        whatever tick's host phase ran in it; not ``dispatch_upload`` or
+        ``dispatch_enqueue``, which are the host's own work), and the
+        whole duration of a step that failed or ran no host phase.  The
+        period stays the ENGINE's clock whatever the arrival rate: only
+        the timer's step advances it (``tick(arrival=False)``), and
+        ``_next_start``, ``tick_late_s`` and ``ticks_late`` are about
+        timer ticks alone.  The first step is the timer's."""
         st = self._stages
         while not self._stop.is_set():
             t0 = time.perf_counter()
@@ -1589,8 +1621,12 @@ class RaftNode:
             if not arrival:
                 self._note_tick_start(t0, interval,
                                       self._next_start(t0, interval))
+            waited = 0.0
             try:
                 self.tick(arrival=arrival)
+                if self._host_runs:
+                    waited = self._fsync_waited + st.total(
+                        "scan_device", "scan_fetch")
             except Exception:
                 log.exception("node %d tick failed", self.node_id)
                 st.leave()
@@ -1600,14 +1636,16 @@ class RaftNode:
             # From the loop's second step on, a stage that outlasts the
             # period is a stall (the first loads or compiles the program).
             st.period = interval
-            self._await_step(ended, ended - t0)
+            self._await_step(ended, ended - t0, waited)
             st.leave()
         st.period = None    # whoever steps the node next has no period
 
-    def _await_step(self, ended: float, took: float) -> None:
+    def _await_step(self, ended: float, took: float, waited: float) -> None:
         """Tick thread, between two steps: sleep until the timer's tick is
         due, or until work waits and ``arrival_step_at`` lets a step
-        start for it, whichever is first."""
+        start for it, whichever is first.  Leaves the step it lets start
+        ``_gap_given``: whether work stood waiting for the gap's end
+        (``held``), the gap, and what the last step's waits took off it."""
         due, wake, stop = self._tick_due, self._wake, self._stop
         # What a step has lately cost: the median of the last few, not
         # their maximum.  The one step that a pause stretched is paid for
@@ -1617,19 +1655,22 @@ class RaftNode:
         # close to the timer costs that tick a few milliseconds, no more.
         costs = sorted(self._step_costs)
         cost = costs[len(costs) // 2] if costs else 0.0
+        held = False
         while not stop.is_set():
             now = time.perf_counter()
             if now >= due:
-                return
+                break
             if not wake.is_set():
                 wake.wait(due - now)
                 continue
-            at = arrival_step_at(now, ended, took, due, cost)
+            at = arrival_step_at(now, ended, took, due, cost, waited)
             if at is not None and at <= now:
-                return
+                break
             # Inside the gap, or no room before the timer: the work waits
             # (and what arrives meanwhile shares its step).
+            held = at is not None
             stop.wait((due if at is None else at) - now)
+        self._gap_given = (held, max(0.0, took - waited), waited)
 
     def set_active(self, group: int, active: bool,
                    purge: bool = False) -> None:
@@ -1727,6 +1768,7 @@ class RaftNode:
         # own host phase (settled) or eager_send (overlapped).
         st.begin(self.ticks)
         self._host_runs = 0
+        self._fsync_waited = 0.0
         if self._lat is not None:
             self._lat.tick = self.ticks
         _tick_t0 = st.enter("dispatch_intake")
@@ -1735,6 +1777,17 @@ class RaftNode:
         m["ticks"] += 1
         if arrival:
             m["ticks_on_arrival"] += 1
+        given, self._gap_given = self._gap_given, None
+        if given is not None:
+            # The loop started this step: what _await_step gave it.  A
+            # timer step is never held by the gap (its work rode the
+            # timer for want of room, or there was none).
+            held, gap, waited = given
+            held = int(held and arrival)
+            st.note(held=held, gap_ms=1e3 * gap, waited_ms=1e3 * waited)
+            if arrival:
+                m["steps_held"] += held
+                m.observe("arrival_gap_s", gap)
         # Whatever was queued before this instant the intake below sees;
         # whatever is queued after it sets the event again.
         self._wake.clear()
@@ -3067,6 +3120,7 @@ class RaftNode:
                 # seconds (observe=False).
                 _t0 = st.enter("wal", observe=False)
                 prep, fsync_s, blob_fn = self._persist(ctx, ids)
+                self._fsync_waited += fsync_s
                 self._watch_io(fsync_s)
                 if self._lat_tick:
                     # The Python step stamped STAGED before its barrier

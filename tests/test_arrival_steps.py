@@ -14,6 +14,7 @@ import pytest
 
 from rafting_tpu.core.types import EngineConfig
 from rafting_tpu.machine.kv_machine import KVMachineProvider
+from rafting_tpu.runtime.node import arrival_step_at, settles_now
 from rafting_tpu.testkit.harness import LocalCluster
 
 
@@ -275,3 +276,229 @@ def test_every_step_says_on_its_span_who_started_it(tmp_path, monkeypatch):
             types.SimpleNamespace(xplane=path)) is None
     finally:
         lc.close()
+
+
+# ---------------------------------------------------------------- the gap
+
+# Made-up readings of a loop between two steps: (now, ended, took, due,
+# cost).  A 100,000-lane step and a small one; inside the gap, past it,
+# with room before the timer and without.
+READINGS = [(now, 10.0, took, due, cost)
+            for took, cost in ((0.0145, 0.0145), (0.008, 0.006),
+                               (0.19, 0.05))
+            for now in (10.0, 10.004, 10.02, 10.3)
+            for due in (10.03, 10.25, 11.0)]
+WAITS = (0.0, 0.001, 0.0067, 0.0145, 0.1, 1.0)
+
+
+def _parent(now, ended, took, due, cost):
+    """The rule as it stood: the gap is the last step's whole duration."""
+    at = max(now, ended + took)
+    return at if settles_now(at, due, cost) else None
+
+
+def _waited_0_is_the_parents_instant(r):
+    assert arrival_step_at(*r) == _parent(*r)
+    assert arrival_step_at(*r, 0.0) == _parent(*r)
+
+
+def _never_later_than_the_parent_never_before_now(r):
+    old = _parent(*r)
+    for w in WAITS:
+        at = arrival_step_at(*r, w)
+        if old is not None:
+            assert at is not None and r[0] <= at <= old
+
+
+def _monotone_in_waited(r):
+    ats = [arrival_step_at(*r, w) for w in WAITS]
+    for sooner, later in zip(ats[1:], ats):
+        # more of the step spent blocked: the same instant or an earlier
+        # one, and a step that was let start stays let start
+        assert later is None or (sooner is not None and sooner <= later)
+
+
+def _all_of_it_waited_starts_now(r):
+    now, _, took, due, cost = r
+    for w in (took, took + 0.5):
+        at = arrival_step_at(*r, w)
+        assert at == (now if settles_now(now, due, cost) else None)
+
+
+def _the_room_rule_is_untouched(r):
+    now, _, _, due, cost = r
+    for w in WAITS:
+        at = arrival_step_at(*r, w)
+        # No room at ``now`` is no room, however short the gap; and a step
+        # that is let start has room for the WHOLE step's cost, waits
+        # and all: no arrival step makes a timer tick late.
+        if not settles_now(now, due, cost):
+            assert at is None
+        if at is not None:
+            assert settles_now(at, due, cost)
+
+
+@pytest.mark.parametrize("holds", [
+    _waited_0_is_the_parents_instant,
+    _never_later_than_the_parent_never_before_now,
+    _monotone_in_waited,
+    _all_of_it_waited_starts_now,
+    _the_room_rule_is_untouched,
+], ids=lambda f: f.__name__.strip("_"))
+def test_the_gap_is_what_the_step_held_of_the_interpreter(holds):
+    """``arrival_step_at`` with one more observed quantity, ``waited``:
+    the seconds of the last step its thread spent blocked.  A pure
+    function of readings; no clock is read, no node is built."""
+    assert any(_parent(*r) is None for r in READINGS)
+    assert any(_parent(*r) not in (None, r[0]) for r in READINGS)
+    for r in READINGS:
+        holds(r)
+
+
+class _Standing(threading.Event):
+    """Work that always waits: an event nobody clears."""
+
+    def clear(self):
+        pass
+
+
+BLOCKED_S = 0.03
+
+
+@pytest.fixture(scope="module")
+def blocked_loop(tmp_path_factory):
+    """Real loops, traced, in which one node's step sleeps ``BLOCKED_S``
+    inside its ``scan_device`` stage (a device that takes that long) under
+    a standing ``_wake``.  Yields the trace, the counts around it and what
+    that node's loop did over thirty of its steps."""
+    import glob
+
+    import jax
+
+    tmp = tmp_path_factory.mktemp("blocked")
+    lc = _cluster(tmp / "data")
+    trace_dir = str(tmp / "trace")
+    try:
+        node = lc.nodes[lc.leader_of(1)]
+        fetch, st = node._fetch, node._stages
+
+        def slow_fetch(ctx):
+            st.enter("scan_device")
+            time.sleep(BLOCKED_S)
+            return fetch(ctx)
+
+        node._fetch = slow_fetch
+        node._wake = _Standing()
+        node._wake.set()
+        m = node.metrics
+
+        def busy_s():
+            return (m.histogram("tick_latency_s").total
+                    + m.histogram("tick_stage_tail_s").total)
+
+        c0 = _counts(lc)
+        held0 = {i: int(n.metrics["steps_held"])
+                 for i, n in lc.nodes.items()}
+        with jax.profiler.trace(trace_dir):
+            lc.tick(1)
+            lc.start_loops(2.0)
+            _after_timer_tick(node)
+            while node.ticks < c0[node.node_id][0] + 5:
+                time.sleep(0.001)
+            t0, b0, n0 = time.perf_counter(), busy_s(), node.ticks
+            while node.ticks < n0 + 30:
+                time.sleep(0.001)
+            t1, b1, n1 = time.perf_counter(), busy_s(), node.ticks
+            lc.stop_loops()
+        (path,) = glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True)
+        yield dict(lc=lc, node=node, path=path, c0=c0, c1=_counts(lc),
+                   held0=held0, wall_s=t1 - t0, busy_s=b1 - b0,
+                   steps=n1 - n0)
+    finally:
+        lc.close()
+
+
+def test_a_loop_that_waits_for_its_device_steps_more_than_half_the_time(
+        blocked_loop):
+    """Under the parent's rule a loop with work always waiting took a
+    step, waited the step's length, took the next: ``1 / (2 x took)``
+    steps a second, its steps half of the time.  The seconds the step
+    slept in ``scan_device`` held no interpreter and leave no gap behind
+    them, so the steps fill more than half of the time."""
+    b = blocked_loop
+    assert b["steps"] >= 30
+    took = b["busy_s"] / b["steps"]
+    assert took > BLOCKED_S
+    assert b["steps"] / b["wall_s"] > 1.0 / (2.0 * took), b
+
+
+def test_each_step_says_what_gap_it_was_given(blocked_loop):
+    """Every step a loop starts carries ``held``, ``gap_ms`` and
+    ``waited_ms`` on its ``raft.dispatch_intake`` span beside ``arrival``
+    (a step a caller takes by hand carries ``arrival`` alone); the
+    counter ``steps_held`` and the histogram ``arrival_gap_s`` are the
+    same over the arrival steps."""
+    from benchmark import spanstats
+
+    b = blocked_loop
+    spanstats.reduce_file.cache_clear()
+    stats = spanstats.reduce_file(b["path"])
+    arrivals = spanstats.rows(stats, "dispatch_intake", "arrival")
+    rows = spanstats.rows(stats, "dispatch_intake", "held")
+    for i, n in b["lc"].nodes.items():
+        steps, _, arrival, _ = (x - y for x, y in zip(b["c1"][i],
+                                                      b["c0"][i]))
+        assert len(arrivals[i]) == steps
+        # the manual tick and the loop's first step were given no gap
+        assert len(rows[i]) == steps - 2
+        assert all({"gap_ms", "waited_ms"} <= set(s) for s in rows[i])
+        on_arrival = [s for s in rows[i] if s["arrival"]]
+        assert len(on_arrival) == arrival
+        assert {s["held"] for s in rows[i]} <= {0.0, 1.0}
+        assert not any(s["held"] for s in rows[i] if not s["arrival"])
+        assert sum(s["held"] for s in on_arrival) == \
+            int(n.metrics["steps_held"]) - b["held0"][i]
+        gaps = n.metrics.histogram("arrival_gap_s")
+        assert gaps.n == arrival
+        assert 1e3 * gaps.total == pytest.approx(
+            sum(s["gap_ms"] for s in on_arrival))
+    slow = rows[b["node"].node_id]
+    # Work always waited, so the gap held every arrival step back, and
+    # the gap is the step less its sleep: shorter than the sleep was.
+    assert all(s["held"] for s in slow if s["arrival"])
+    assert all(s["waited_ms"] >= 1e3 * BLOCKED_S for s in slow)
+    assert sum(s["gap_ms"] for s in slow) < sum(s["waited_ms"] for s in slow)
+
+
+@pytest.mark.parametrize("metric,stat", [("step_held_share", "held"),
+                                         ("arrival_gap_ms", "gap_ms")])
+def test_the_gap_s_two_readers(blocked_loop, monkeypatch, metric, stat):
+    """``benchmark/layer_metrics/step_held_share.py`` and
+    ``arrival_gap_ms.py`` over a traced loop's spans: the mean of the
+    statistic over the arrival steps of all nodes; a trace whose spans
+    carry no such statistic, as the parent's, reads as nothing."""
+    import copy
+    import importlib
+    import types
+
+    from benchmark import spanstats
+
+    reader = importlib.import_module(f"benchmark.layer_metrics.{metric}")
+    r = types.SimpleNamespace(xplane=blocked_loop["path"])
+    spanstats.reduce_file.cache_clear()
+    stats = copy.deepcopy(spanstats.reduce_file(r.xplane))
+    on_arrival = [s for ticks in stats["dispatch_intake"].values()
+                  for s in ticks.values() if s.get("arrival") and stat in s]
+    assert len(on_arrival) == sum(
+        blocked_loop["c1"][i][2] - blocked_loop["c0"][i][2]
+        for i in blocked_loop["lc"].nodes)
+    want = sum(s[stat] for s in on_arrival) / len(on_arrival)
+    assert reader.read(r) == pytest.approx(want)
+    assert want > 0.5 if stat == "held" else 0.0 < want < 1e3 * BLOCKED_S
+    # The parent's spans: node, tick and arrival, nothing of the gap.
+    for ticks in stats["dispatch_intake"].values():
+        for s in ticks.values():
+            for k in ("held", "gap_ms", "waited_ms"):
+                s.pop(k, None)
+    monkeypatch.setattr(spanstats, "reduce_file", lambda p: stats)
+    assert reader.read(r) is None

@@ -372,6 +372,91 @@ def test_leader_isolate_hostage_when_check_quorum_off(tmp_path):
         cluster.close()
 
 
+# ------------------------------------ info ops that nobody observed --
+
+def _made_up_history(rng, kind, n):
+    """``n`` ops of up to three overlapping clients on one key, results
+    from a real register (``w``) or list (``a``) stepped at each op's
+    linearization point; then some mutations lose their reply (info: took
+    effect or never ran) and now and then a read is made stale."""
+    t, state, ops, open_until = 0, None, [], [0, 0, 0]
+    for i in range(n):
+        c = rng.randrange(3)
+        inv = max(t, open_until[c]) + 1
+        resp = inv + rng.choice((1, 1, 3, 6))
+        t, open_until[c] = inv, resp
+        if rng.random() < 0.5:
+            ops.append([inv + rng.random() * (resp - inv),
+                        _op(i, "r", "k", inv=inv, resp=resp, proc=f"c{c}")])
+        else:
+            ops.append([inv + rng.random() * (resp - inv),
+                        _op(i, kind, "k", f"v{i}", inv=inv, resp=resp,
+                            proc=f"c{c}")])
+    lost = set()
+    for at, o in sorted(ops, key=lambda p: p[0]):
+        if o.kind == "r":
+            o.result = state
+        elif rng.random() < 0.35:
+            o.status, o.resp_seq = "info", math.inf
+            if rng.random() < 0.5:
+                lost.add(o.id)          # never ran
+                continue
+        if o.kind == "w":
+            state = o.value
+        elif o.kind == "a":
+            state = (state or ()) + (o.value,)
+    reads = [o for _, o in ops if o.kind == "r"]
+    if reads and rng.random() < 0.4:
+        stale = rng.choice(reads)
+        stale.result = rng.choice([None, "v0", ("v0",), ("v1", "v0")])
+    return [o for _, o in ops]
+
+
+@pytest.mark.parametrize("kind", ["w", "a"], ids=["register", "list"])
+@pytest.mark.parametrize("seed", range(6))
+def test_dropping_unobserved_info_ops_changes_no_verdict(monkeypatch, kind,
+                                                         seed):
+    """``linz._observable`` drops the info writes and appends whose value
+    no ok read returned before the search; the search over every live op
+    (what the checker did before) gives the same verdict on made-up
+    histories of both kinds, legal and illegal."""
+    import random
+
+    rng = random.Random(1000 * seed + ord(kind))
+    histories = [_made_up_history(rng, kind, rng.randrange(4, 10))
+                 for _ in range(60)]
+    pruned = [linz.check_ops(h) for h in histories]
+    dropped = sum(
+        len(h) - len(linz._observable(h)) for h in histories)
+    monkeypatch.setattr(linz, "_observable", lambda live: live)
+    assert pruned == [linz.check_ops(h) for h in histories]
+    assert dropped > 0 and True in pruned and False in pruned
+
+
+def test_info_appends_nobody_read_cost_no_search():
+    """Eight appends of unknown outcome early in a list key's history of
+    ninety ops, none of them ever read: each could take effect at any later
+    point or never, which the search once tried one by one (a run of the
+    loop test below grew past 30 GB on such a history); dropped, the
+    verdict is the ok ops' own, at once.  An observed one still counts:
+    a read that holds it where it cannot be is flagged."""
+    ops, held = [], ()
+    for i in range(8):
+        ops.append(_op(i, "a", "l", f"lost{i}", status="info", inv=i))
+    for i in range(8, 90, 2):
+        held += (f"v{i}",)
+        ops.append(_op(i, "a", "l", f"v{i}", inv=10 * i, resp=10 * i + 1))
+        ops.append(_op(i + 1, "r", "l", result=list(held), inv=10 * i + 2,
+                       resp=10 * i + 3))
+    assert linz.check(ops).ok
+    seen = list(held) + ["lost3"]
+    ok = ops + [_op(90, "r", "l", result=seen, inv=1000, resp=1001)]
+    assert linz.check(ok).ok
+    early = ops[:10] + [_op(91, "r", "l", result=["lost3", "v8"], inv=85,
+                            resp=86)] + ops[10:]
+    assert not linz.check(early).ok
+
+
 # ------------------------------------------- loops that step on arrival --
 #
 # The same judgment with the nodes under their own loops

@@ -6,7 +6,11 @@ node's ``ticks``, ``ticks_settled`` and ``ticks_on_arrival`` counters read
 around the window (PR 29: a step is the timer's, or one the loop started for
 arriving work; the engine's clock ``state.now`` moves by the timer's alone,
 so ``engine_now`` equals ``timer_ticks`` on a node booted from an empty
-directory, give or take the step in flight when the two were read):
+directory, give or take the step in flight when the two were read), and
+beside them (PR 43) ``steps_held``, the arrival steps that started at the end
+of the gap ``arrival_step_at`` left behind the step before, with work
+already waiting, and ``arrival_gap_ms``, the mean gap an arrival step was
+given (histogram ``arrival_gap_s``):
 
     python3 tools/settled_probe.py --workload W --seed N --seconds S [--trace 1]
         [--tick-ms T] [--rate R] [--cpu-lanes L]
@@ -72,6 +76,7 @@ def main() -> None:
                               overrides=ov)
     for node, p in zip(got.nodes, got.program or ()):
         w, whole = p["counters"], node.metrics
+        gaps, gap_s = p["histograms"].get("arrival_gap_s", (0, 0.0))
         harness.say("settled", node=node.node_id,
                     window_ticks=w.get("ticks", 0),
                     window_settled=w.get("ticks_settled", 0),
@@ -80,6 +85,8 @@ def main() -> None:
                     window_on_arrival=w.get("ticks_on_arrival", 0),
                     arrival_share=round(w.get("ticks_on_arrival", 0)
                                         / max(1, w.get("ticks", 0)), 4),
+                    steps_held=w.get("steps_held", 0),
+                    arrival_gap_ms=round(1e3 * gap_s / max(1, gaps), 3),
                     process_ticks=whole["ticks"],
                     process_settled=whole["ticks_settled"],
                     process_on_arrival=whole["ticks_on_arrival"],
