@@ -16,3 +16,37 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def small(monkeypatch):
+    """``small(rows_in, rows_out, columns, chunk_bytes)``: shrink
+    ``core/packing.py``'s sizes (None leaves one as it ships) so that a
+    node of a few lanes takes the step a 100,000-lane node takes: buffers
+    closed every ``chunk_bytes`` make the shape rule engage the column and
+    row step (``core/step.py column_layouts``), ``columns`` a peer row and
+    ``rows_in`` / ``rows_out`` rows a step decide what overflows.  As it
+    is called, ``small()``, a 16-lane node's heartbeat rounds and
+    elections overflow and its single operations cross as rows and
+    columns.  The layout caches are emptied on both sides so that no
+    other test sees these layouts."""
+    from rafting_tpu.core import packing
+    from rafting_tpu.core.step import column_layouts, step_layouts
+
+    def clear():
+        step_layouts.cache_clear()
+        column_layouts.cache_clear()
+
+    def shrink(rows_in=6, rows_out=6, columns=3, chunk_bytes=512):
+        for name, value in (("ROWS_IN", rows_in), ("ROWS_OUT", rows_out),
+                            ("COLUMNS", columns),
+                            ("CHUNK_BYTES", chunk_bytes)):
+            if value is not None:
+                monkeypatch.setattr(packing, name, value)
+        clear()
+    clear()
+    yield shrink
+    monkeypatch.undo()
+    clear()

@@ -27,7 +27,7 @@ import rafting_tpu.runtime.node as node_mod
 from rafting_tpu.api import RaftConfig, RaftContainer
 from rafting_tpu.core import packing
 from rafting_tpu.core.step import (
-    _host_from_rows, column_layouts, pack_readback, step_layouts)
+    _host_from_rows, column_layouts, pack_readback)
 from rafting_tpu.core.types import EngineConfig, LEADER
 from rafting_tpu.testkit import linz
 from rafting_tpu.testkit.fixtures import NullProvider
@@ -51,19 +51,10 @@ COUNTERS = ("steps_columns_in", "column_overflows_in",
 
 
 @pytest.fixture
-def small_columns(monkeypatch):
-    """Layouts built while this holds close a buffer every 2 KB and take
-    K columns a row.  The layout caches are emptied on both sides so that
-    no other test sees them."""
-    step_layouts.cache_clear()
-    column_layouts.cache_clear()
-    monkeypatch.setattr(packing, "CHUNK_BYTES", 2048)
-    monkeypatch.setattr(packing, "COLUMNS", K)
-    monkeypatch.setattr(packing, "ROWS_IN", ROWS)
-    monkeypatch.setattr(packing, "ROWS_OUT", ROWS)
-    yield
-    step_layouts.cache_clear()
-    column_layouts.cache_clear()
+def small_columns(small):
+    """This file's sizes of ``small`` (tests/conftest.py): a buffer closed
+    every 2 KB, K columns a row, ROWS rows a step."""
+    small(ROWS, ROWS, columns=K, chunk_bytes=2048)
 
 
 @pytest.fixture

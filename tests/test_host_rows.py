@@ -19,8 +19,7 @@ import time
 import numpy as np
 import pytest
 
-from rafting_tpu.core import packing
-from rafting_tpu.core.step import column_layouts, step_layouts
+from rafting_tpu.core.step import column_layouts
 from rafting_tpu.core.types import EngineConfig, LEADER
 from rafting_tpu.machine.dispatch import ApplyDispatcher
 from rafting_tpu.runtime.node import RaftNode
@@ -45,22 +44,6 @@ COUNTERS = (
 PLANES = ("_durable_tail_m", "_wal_floor", "_stable_term_m",
           "_stable_voted_m", "_rel_min", "h_commit", "h_base", "h_term",
           "h_active", "_inflight_submit", "_inflight_read")
-
-
-@pytest.fixture
-def small(monkeypatch):
-    """Row buffers of ``ROWS`` rows and buffers so small that the 16-lane
-    shape takes the column step (tests/test_plane_rows.py ``small``)."""
-    monkeypatch.setattr(packing, "ROWS_IN", ROWS)
-    monkeypatch.setattr(packing, "ROWS_OUT", ROWS)
-    monkeypatch.setattr(packing, "COLUMNS", 3)
-    monkeypatch.setattr(packing, "CHUNK_BYTES", 512)
-    step_layouts.cache_clear()
-    column_layouts.cache_clear()
-    yield
-    monkeypatch.undo()
-    step_layouts.cache_clear()
-    column_layouts.cache_clear()
 
 
 def recount(node: RaftNode, ctx) -> None:
@@ -479,7 +462,7 @@ def one_array(t: Twins):
 
 
 SCENARIOS = {
-    "one_array": (one_array, dict(packing=dict(COLUMNS=16))),
+    "one_array": (one_array, dict(packing=dict(columns=16))),
     "lease_reads": (lease_reads, {}),
     "readindex_reads": (readindex_reads, dict(engine=dict(read_lease=False))),
     "halted_machine": (halted_machine, {}),
@@ -503,10 +486,7 @@ def test_selections_over_the_rows_are_the_selections_over_whole_planes(
         tmp_path, monkeypatch, small, scenario, pipeline):
     run, kw = SCENARIOS[scenario]
     kw = dict(kw)
-    for name, value in kw.pop("packing", {}).items():
-        monkeypatch.setattr(packing, name, value)
-        step_layouts.cache_clear()
-        column_layouts.cache_clear()
+    small(ROWS, ROWS, **{"columns": 3, **kw.pop("packing", {})})
     t = Twins(tmp_path, monkeypatch, pipeline, **kw)
     try:
         run(t)

@@ -465,7 +465,14 @@ def test_info_appends_nobody_read_cost_no_search():
 # clock advanced by the timer's steps alone (HostInbox.clock).  Most steps
 # of this run are arrival steps; the leader of the loaded group is cut off
 # half way, so an election, a step-down and the read plane's aborts all
-# happen between and across them.
+# happen between and across them.  The period is twenty of the cluster's
+# own steps as it measures them here and now (never under 50 ms): a loop
+# starts an arrival step only where twice a step fits before its timer
+# (runtime/node.py arrival_step_at), so on a host where a step is slow (a
+# suite's other workers) a fixed period left no room, the work rode the
+# timer and the share of arrival steps, which follows the wall clock, read
+# under a half (0.375 on a quarter of a core at 50 ms; 0.73-0.83 there at
+# twenty steps).
 
 @pytest.mark.parametrize("lease", [True, False], ids=["lease", "strict"])
 def test_linearizable_with_loops_stepping_on_arrival(tmp_path, lease):
@@ -488,11 +495,15 @@ def test_linearizable_with_loops_stepping_on_arrival(tmp_path, lease):
     try:
         for g in range(cfg.n_groups):
             cluster.wait_leader(g)
+        rounds = 5
+        t0 = _time.perf_counter()
+        cluster.tick(rounds)
+        step = (_time.perf_counter() - t0) / (rounds * len(cluster.nodes))
+        period = max(0.05, 20 * step)
         now0 = {i: int(n.state.now) for i, n in cluster.nodes.items()}
         timer0 = {i: n.timer_ticks for i, n in cluster.nodes.items()}
         history = History()
         load = KVWorkload(cluster, history, group=1, clients=3, seed=17)
-        period = 0.05
         cluster.start_loops(period)
         load.start()
         _time.sleep(30 * period)

@@ -41,7 +41,10 @@ SMALL_CHUNK = 3072          # bytes: a few [P, G] planes a buffer at 16 lanes
 def small_chunks(monkeypatch):
     """Layouts built while this holds close a buffer every 3 KB.  The
     layout cache is emptied on both sides so that no other test sees
-    them."""
+    them.  Not ``small`` (tests/conftest.py): that one makes a shape take
+    the column step, this one keeps it PACKED over several buffers (3 KB
+    is two word buffers at 16 lanes, under the four the shape rule asks
+    for)."""
     step_layouts.cache_clear()
     column_layouts.cache_clear()
     monkeypatch.setattr(packing, "CHUNK_BYTES", SMALL_CHUNK)

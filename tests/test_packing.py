@@ -286,19 +286,12 @@ def test_next_dispatch_leaves_a_pending_ticks_arrays_alone(tmp_path):
 
 
 @pytest.fixture
-def small_columns(monkeypatch):
-    """``set(K)``: column buffers of K columns a row, and buffers so small
-    that the 8-lane shape's planes do not fit one (the shape rule then
-    engages columns)."""
-    def set_k(k):
-        monkeypatch.setattr(packing, "COLUMNS", k)
-        monkeypatch.setattr(packing, "CHUNK_BYTES", 256)
-        step_layouts.cache_clear()
-        column_layouts.cache_clear()
-    yield set_k
-    monkeypatch.undo()
-    step_layouts.cache_clear()
-    column_layouts.cache_clear()
+def small_columns(small):
+    """``set(K)``: ``small`` (tests/conftest.py) at this file's sizes:
+    column buffers of K columns a row, the rows as they ship (nothing of
+    8 lanes overflows them), and buffers so small that the 8-lane shape's
+    planes do not fit one."""
+    return lambda k: small(None, None, columns=k, chunk_bytes=256)
 
 
 def _occupied(msgs):

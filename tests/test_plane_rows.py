@@ -27,24 +27,6 @@ BASE = dict(n_groups=16, log_slots=16, batch=4, max_submit=4,
             election_ticks=8, heartbeat_ticks=3, rpc_timeout_ticks=6)
 
 
-@pytest.fixture
-def small(monkeypatch):
-    """``set(k_in, k_out, columns)``: row buffers of so many rows, column
-    buffers of ``columns`` a peer row, and buffers so small that the
-    16-lane shape takes the column step."""
-    def set_k(k_in, k_out, columns=16):
-        monkeypatch.setattr(packing, "ROWS_IN", k_in)
-        monkeypatch.setattr(packing, "ROWS_OUT", k_out)
-        monkeypatch.setattr(packing, "COLUMNS", columns)
-        monkeypatch.setattr(packing, "CHUNK_BYTES", 512)
-        step_layouts.cache_clear()
-        column_layouts.cache_clear()
-    yield set_k
-    monkeypatch.undo()
-    step_layouts.cache_clear()
-    column_layouts.cache_clear()
-
-
 def assert_trees_equal(a, b, tag=""):
     la, ta = jax.tree.flatten(a)
     lb, tb = jax.tree.flatten(b)
@@ -232,7 +214,7 @@ def test_rows_in_and_out_are_the_dense_planes_bit_for_bit(
     the Readback as rows, the messages as columns or densely: state,
     outbox, every mirror the host patches and the device's durable plane
     agree after every one of 60 steps, whichever way each part crossed."""
-    small(k_in, k_out)
+    small(k_in, k_out, columns=16)
     cfg = EngineConfig(n_peers=3, **BASE)
     N, G = cfg.n_peers, cfg.n_groups
     lay = column_layouts(cfg, durable)
@@ -306,7 +288,7 @@ def test_one_lane_over_either_capacity_falls_back_by_count(small, lanes):
     five goes up and comes down as rows, the step with six says so in its
     counts (the TRUE ones) and crosses whole, and both are the dense
     step."""
-    small(5, 5)
+    small(5, 5, columns=16)
     cfg = EngineConfig(n_peers=1, **BASE)
     G = cfg.n_groups
     lay = column_layouts(cfg, False)

@@ -187,7 +187,7 @@ def test_hb_acknowledged_matches_the_period_and_the_peer(tmp_path):
 
 @pytest.mark.parametrize("columns", [False, True], ids=["packed", "columns"])
 def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch,
-                                                       columns):
+                                                       small, columns):
     """Every ``st.note`` of a step, caught where it is written: the upload
     and the fetch say how many buffers crossed and how many bytes, and
     those are the layouts' own sizes; on a shape whose messages cross as
@@ -196,10 +196,7 @@ def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch,
     way, beside the [G] planes' buffers only where those crossed whole, and
     ``columns`` and ``rows`` are the counts that crossed."""
     if columns:
-        monkeypatch.setattr(packing, "CHUNK_BYTES", 512)
-        monkeypatch.setattr(packing, "COLUMNS", _cfg().n_groups)
-    step_layouts.cache_clear()
-    column_layouts.cache_clear()
+        small(None, None, columns=_cfg().n_groups)
     lc = LocalCluster(_cfg(), str(tmp_path), seed=5)
     try:
         lc.tick(3)

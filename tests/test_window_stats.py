@@ -22,7 +22,6 @@ import numpy as np
 import pytest
 
 from rafting_tpu.api.anomaly import NotReadyError
-from rafting_tpu.core import packing
 from rafting_tpu.core.cluster import route
 from rafting_tpu.core.step import (
     WINDOW_SUMS, column_layouts, first_carry, node_step_columns,
@@ -274,21 +273,6 @@ def np_window_sums(cfg, old, new):
             int((pair & cooling).sum()), int((pair & timed_out).sum())]
 
 
-@pytest.fixture
-def small_buffers(monkeypatch):
-    """Buffers so small that the 8-lane shape's planes do not fit one: the
-    shape rule then engages the column step (tests/test_column_serving.py,
-    tests/test_packing.py)."""
-    monkeypatch.setattr(packing, "COLUMNS", 8)
-    monkeypatch.setattr(packing, "CHUNK_BYTES", 256)
-    step_layouts.cache_clear()
-    column_layouts.cache_clear()
-    yield
-    monkeypatch.undo()
-    step_layouts.cache_clear()
-    column_layouts.cache_clear()
-
-
 def _packed_step(cfg):
     inputs, readback = step_layouts(cfg, True)
 
@@ -321,14 +305,14 @@ def _column_step(cfg):
 
 
 @pytest.mark.parametrize("form", ["packed", "columns"])
-def test_window_sums_are_a_recomputation_from_the_states(request, form):
+def test_window_sums_are_a_recomputation_from_the_states(small, form):
     """A cluster stepped 80 periods through the served program over links
     cut at random, so that windows fill, time out and cool down: after
     every step the readback's five sums equal a numpy recomputation from
     the states fetched on either side of it, and each of the five was
     non-zero somewhere."""
-    if form == "columns":
-        request.getfixturevalue("small_buffers")
+    if form == "columns":       # 8 lanes: columns that hold every lane
+        small(None, None, columns=8, chunk_bytes=256)
     cfg = EngineConfig(**SUMS)
     N, G = cfg.n_peers, cfg.n_groups
     step = (_packed_step if form == "packed" else _column_step)(cfg)
