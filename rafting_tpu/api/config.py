@@ -67,6 +67,16 @@ class RaftConfig:
     # (core/step.py "hibernation"; runtime/node.py "the node-level beat").
     # Off as shipped here: every group ticks, as it always did.
     hibernate_regions: bool = False
+    # How a linearizable read confirms the leader (core/step.py phase 6b;
+    # EngineConfig.read_lease).  True: by a lease, etcd raft's
+    # ReadOnlyLeaseBased: a read rides the heartbeat acknowledgements the
+    # leader already holds, which is sound while timers and pauses stay
+    # within the bounds the engine's veto watches.  False: by a ReadIndex
+    # round of its own, etcd raft's ReadOnlySafe (etcd's default): a read
+    # is answered only after a majority has acknowledged an AppendEntries
+    # that left the leader in or after the step that stamped it, with no
+    # assumption on any clock.  On as shipped here, as it always was.
+    read_lease: bool = True
     # engine shapes
     n_groups: int = 16
     log_slots: int = 64
@@ -162,6 +172,7 @@ class RaftConfig:
             avail_crit=self.avail_critical_point,
             recovery_ticks=self.recovery_cool_down_ticks,
             hibernate=self.hibernate_regions,
+            read_lease=self.read_lease,
         )
 
     def maintain(self):
@@ -195,7 +206,7 @@ def load_xml_config(path: str) -> RaftConfig:
           </cluster>
           <timing tick="100" heartbeat="1" election="3" broadcast="0.5"
                   pre-vote="true" tick-stagger="false"
-                  hibernate-regions="false"/>
+                  hibernate-regions="false" read-lease="true"/>
           <engine groups="16" log-slots="64" batch="8" max-submit="8"/>
           <snapshot state-change-threshold="64" dirty-log-tolerance="16"
                     snap-min-interval="20" compact-min-interval="10"
@@ -232,6 +243,7 @@ def load_xml_config(path: str) -> RaftConfig:
         tick_stagger=attr("timing", "tick-stagger", False, boolean),
         hibernate_regions=attr("timing", "hibernate-regions", False,
                                boolean),
+        read_lease=attr("timing", "read-lease", True, boolean),
         n_groups=attr("engine", "groups", 16, int),
         log_slots=attr("engine", "log-slots", 64, int),
         batch=attr("engine", "batch", 8, int),

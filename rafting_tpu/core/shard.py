@@ -62,9 +62,10 @@ def state_pspecs(trace: bool = False, heat: bool = False,
         heard=_NODE_GROUP, since=_NODE_GROUP) if qc else None
     kw["lease"] = LeaseGuard(
         vote_hold=_NODE_GROUP, carry_bar=_NODE_GROUP) if lease else None
-    # Hibernation (cfg.hibernate) is a served deployment's: the sharded
-    # harness runs without it.
-    kw["hib"] = None
+    # Hibernation (cfg.hibernate) and strict ReadIndex's counter
+    # (cfg.read_lease off) are a served deployment's: the sharded harness
+    # runs without them.
+    kw["hib"] = kw["read_seq"] = None
     return RaftState(**kw)
 
 
@@ -72,6 +73,7 @@ def messages_pspecs() -> Messages:
     """Specs for stacked [N, P, G, ...] message planes (axis 2 = group)."""
     kw = {f.name: _NODE_PEER_GROUP for f in dataclasses.fields(Messages)}
     kw["ae_sleep"] = kw["aer_asleep"] = None
+    kw["ae_seq"] = kw["aer_seq"] = None
     return Messages(**kw)
 
 

@@ -337,6 +337,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     #     heartbeat fails the term check, is not the selected AE, and so
     #     wakes instead.
     # (e) the field off: every line below is under `if hiber`.
+    strict = not cfg.read_lease      # 6b: stamps ordered by steps
     hiber = cfg.hibernate
     if hiber:
         asleep0, busy_at, slept = s.hib.asleep, s.hib.busy_at, s.hib.slept
@@ -607,6 +608,12 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # same-term reply proves we processed the leader's AE — the read
     # plane's barrier evidence (the occupancy-echo idiom again).
     out_aer_tick = jnp.where(ae_v, inbox.ae_tick, 0)
+    if strict:
+        # The stamp counter's echo (6b), to a request at OUR term alone:
+        # the counter starts over with every leadership, and the reply to
+        # an older term's request carries our newer term, which its
+        # sender may have been elected at since.
+        out_aer_seq = jnp.where(ae_t_ok, inbox.ae_seq, 0)
     if hiber:
         # The sleep heartbeat this follower agrees to: the selected AE,
         # accepted, empty, flagged, and both logs and commits level.
@@ -785,10 +792,12 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     #   the newer term strictly earlier, which must intersect our
     #   same-term evidence majority — a node cannot return to an older
     #   term.  No clock-drift assumption anywhere.
-    # * strict ReadIndex: store the ECHOED send tick, so release requires
-    #   acks to heartbeats SENT at/after the stamp (the textbook
-    #   dedicated confirmation round) — sound under arbitrary transport
-    #   delay, one round trip slower.
+    # * strict ReadIndex (etcd's ReadOnlySafe): store the ECHOED stamp
+    #   counter, so release requires acks to AppendEntries that LEFT in or
+    #   after the step of the stamp (the textbook dedicated confirmation
+    #   round) — sound under arbitrary transport delay, with no assumption
+    #   on any clock, one round trip slower.  "Ticks and steps" below has
+    #   the counter and its proof.
     #
     # host.read_veto (host runtime detected a wall-clock tick gap) drops
     # stored AND same-tick evidence: a paused host's inbox may hold acks
@@ -812,11 +821,28 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     #   never releases (evid < stamp).
     # * strict: an AE sent in an earlier step of this `now` echoes
     #   aer_tick == now although it left before a read offered in a
-    #   later step of it: the echo would confirm nothing.  So strict
-    #   mode stamps reads only in the step that advances the clock
-    #   (phase 8b stamp_open): that step is the first at its `now`, every
-    #   AE carrying the stamp's value leaves in it or after it, and an
-    #   offer made in a clock-0 step stays offered until the timer's.
+    #   later step of it: that echo confirms nothing, so strict mode does
+    #   not order by ticks at all.  It orders by STEPS: read_seq [G] moves
+    #   by one in every step that stamps a batch (8b), the batch's stamp
+    #   is the moved value, every AE that leaves in that step or a later
+    #   one carries the counter as that step left it (phase 9 ae_seq >=
+    #   the stamp) and every AE that left before it carries less.  A
+    #   follower echoes the word of a request at its own term (phase 4),
+    #   read_evid keeps the highest echo per peer, and the release rule is
+    #   the one it always was: evidence >= stamp from a majority.  So a
+    #   batch stamped in ANY step, the timer's or one that work started,
+    #   is released only by acknowledgements of AEs that left in or after
+    #   its step, whatever is duplicated, reordered or delayed.
+    #   The counter lives within one continuous leadership at one term,
+    #   beside the FIFO (8b keep_reads zeroes both and the evidence): a
+    #   term has one leader and a node that loses the role cannot win it
+    #   back at the same term, so the values a term's requests carry come
+    #   from ONE run of the counter, and the term checks on both sides
+    #   (phase 4: the echo; phase 6 aer_r: the reply) keep every other
+    #   run's echoes out.  A follower that restarts echoes what it is
+    #   sent and keeps nothing.  Nothing here reads a clock: the host's
+    #   read_veto (which drops evidence) and read_fresh_ticks have
+    #   nothing to guard in this mode.
     #
     # The carried lease (cfg.lease_carry_ticks = heartbeat_ticks - 1 > 0).
     # With a heartbeat every h ticks a lane hears acknowledgements in one
@@ -888,7 +914,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         evid_val = jnp.broadcast_to(now, (G, P))
     else:
         evid_hit = aer_r & ~self_hot
-        evid_val = jnp.maximum(read_evid, inbox.aer_tick.T)
+        evid_val = jnp.maximum(read_evid, inbox.aer_seq.T)
     if hiber:
         # (b): nothing is stored in a step the lane entered asleep.  The
         # members' word is latched here: an acknowledgement that says
@@ -1092,18 +1118,27 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # — a fresh leader's commit index may lag entries committed by its
     # predecessors until its own-term entry commits, Raft §5.4.2; serving
     # before that could miss them).
-    # Strict ReadIndex stamps only where the clock advances (6b, "ticks
-    # and steps"); the lease stamps in any step.
-    stamp_open = True if cfg.read_lease else host.clock > 0
+    # Either mode stamps in any step (6b, "ticks and steps"): the lease
+    # with the tick, strict ReadIndex with its own counter, which this
+    # step moves if it stamps.  (The literal is what is left of "strict
+    # stamps at the timer alone": with it the lease's program stays, equation
+    # for equation, the one tests/test_read_index.py pins.)
+    stamp_open = True
     n_read = jnp.where(keep_reads & (commit >= own_from) & (rq_len < K)
                        & stamp_open,
                        jnp.maximum(host.read_n, 0), 0)
     read_acc = n_read > 0
+    if strict:
+        read_seq = jnp.where(keep_reads, s.read_seq, 0) \
+            + read_acc.astype(I32)
+        stamp = read_seq[:, None]
+    else:
+        stamp = now
     slot_in = (jnp.arange(K, dtype=I32)[None, :]
                == jnp.remainder(rq_head + rq_len, K)[:, None]) \
         & read_acc[:, None]                                      # [G, K]
     rq_idx = jnp.where(slot_in, commit[:, None], rq_idx)
-    rq_stamp = jnp.where(slot_in, now, rq_stamp)
+    rq_stamp = jnp.where(slot_in, stamp, rq_stamp)
     rq_n = jnp.where(slot_in, n_read[:, None], rq_n)
     rq_len = rq_len + read_acc.astype(I32)
     read_index_out = jnp.where(read_acc, commit, 0)
@@ -1111,7 +1146,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     # tick carries receipt == now == the fresh batch's stamp, so a
     # heartbeat-ack burst releases a same-tick read with zero extra round
     # trips — the lease fast path IS the general rule at its freshness
-    # limit.  Strict mode can only release on a later tick's echo.  A
+    # limit.  Strict mode can only release on a later step's echo.  A
     # carried lease (6b) lets a receipt reach `carry` ticks further,
     # unless a transfer has barred it (case c).
     if carry:
@@ -1273,6 +1308,10 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     out_ae_occ = hb_occupy.T
     # Send tick, echoed back as aer_tick (read-barrier evidence, 6b).
     out_ae_tick = jnp.broadcast_to(now, (P, G)).astype(I32)
+    if strict:
+        # The stamp counter as this step leaves it, echoed back as
+        # aer_seq (6b).
+        out_ae_seq = jnp.broadcast_to(read_seq[None, :], (P, G))
     if hiber:
         out_ae_sleep = (send_hb & quiet[:, None]).T
     # Snapshot offer for laggards (reference Leader.java:168-190); occupies
@@ -1575,6 +1614,7 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         qc=qc,
         lease=guard,
         hib=hib,
+        read_seq=read_seq if strict else None,
     )
     outbox = Messages(
         ae_valid=out_ae_valid, ae_term=out_ae_term,
@@ -1599,6 +1639,8 @@ def node_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         tn_valid=out_tn_valid, tn_term=out_tn_term,
         ae_sleep=out_ae_sleep if hiber else None,
         aer_asleep=out_aer_asleep if hiber else None,
+        ae_seq=out_ae_seq if strict else None,
+        aer_seq=out_aer_seq if strict else None,
     )
     info = StepInfo(
         submit_start=sub_start, submit_acc=n_acc, dirty=dirty,

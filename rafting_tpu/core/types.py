@@ -157,11 +157,21 @@ class EngineConfig:
                                   #     heartbeat-ack quorum in this tick's
                                   #     inbox releases a same-tick read — zero
                                   #     extra round trips).  False = strict
-                                  #     ReadIndex: evidence is the ECHOED send
-                                  #     tick, so a read only releases on acks
-                                  #     to heartbeats SENT at/after its stamp
-                                  #     (a dedicated post-stamp confirmation
-                                  #     round; delay-proof, ~1 RTT slower).
+                                  #     ReadIndex (etcd's ReadOnlySafe):
+                                  #     evidence is the ECHO of a per-lane
+                                  #     counter that moves in every step that
+                                  #     stamps a batch (RaftState.read_seq,
+                                  #     carried as ae_seq / aer_seq), so a
+                                  #     read only releases on acks to
+                                  #     AppendEntries that LEFT in or after
+                                  #     the step that stamped it (a dedicated
+                                  #     post-stamp confirmation round; no
+                                  #     assumption on clocks or delays, one
+                                  #     round trip slower).  The counter and
+                                  #     the two message words exist only here:
+                                  #     with the lease they are None and the
+                                  #     step, its pytrees and the wire are
+                                  #     what they were.
     read_fresh_ticks: int = 3     # lease evidence freshness: an ack older
                                   #     than this many own-clock ticks past
                                   #     its echoed send tick is not lease
@@ -603,13 +613,16 @@ class RaftState:
     read_evid: jax.Array      # [G, P] int32 — barrier evidence per peer:
                               #   with cfg.read_lease, the own-clock RECEIPT
                               #   tick of the last fresh same-term AE ack;
-                              #   without, the ECHOED send tick (aer_tick) —
-                              #   acks to heartbeats sent at/after a stamp.
+                              #   without, the highest ECHOED stamp counter
+                              #   (aer_seq) — acks to AppendEntries that left
+                              #   in or after the step of a stamp.
                               #   0 = none this leadership.  A receipt
                               #   releases batches stamped in its tick and
                               #   in the cfg.lease_carry_ticks after it.
     rq_idx: jax.Array         # [G, K] int32 — pending batch read indices
-    rq_stamp: jax.Array       # [G, K] int32 — pending batch stamp ticks
+    rq_stamp: jax.Array       # [G, K] int32 — pending batch stamps: the
+                              #   tick with cfg.read_lease, else read_seq
+                              #   as the stamping step left it
     rq_n: jax.Array           # [G, K] int32 — reads per pending batch
     rq_head: jax.Array        # [G] int32 — FIFO ring head slot
     rq_len: jax.Array         # [G] int32 — pending batch count (<= K)
@@ -636,6 +649,13 @@ class RaftState:
 
     # Hibernation's lanes (cfg.hibernate).  Same None-subtree contract.
     hib: Any = None           # Optional[Hibernate]
+
+    # Strict ReadIndex's stamp counter (cfg.read_lease False; None with the
+    # lease, same None-subtree contract): it moves by one in every step
+    # that stamps a batch, every AppendEntries that leaves in or after that
+    # step carries it (ae_seq), and it lives within one continuous
+    # leadership at one term, as the read FIFO does: 0 on any other lane.
+    read_seq: Any = None      # Optional[[G] int32]
 
 
 @struct.dataclass
@@ -746,6 +766,7 @@ def crash_restart(cfg: EngineConfig, s: "RaftState") -> "RaftState":
         qc=qc,
         lease=lease,
         hib=hib,
+        read_seq=None if s.read_seq is None else z(G),
         rng=rng,
         role=z(G),
         leader_id=jnp.full((G,), NIL, I32),
@@ -816,11 +837,11 @@ class Messages:
                              #   followers adopt configs apply-on-append
                              #   exactly as they adopt terms
     ae_tick: jax.Array       # [P, G] int32 — sender's own clock at send,
-                             #   echoed back as aer_tick: the read plane's
-                             #   barrier-evidence anchor (strict ReadIndex
-                             #   compares the echo against the read stamp;
-                             #   the lease path uses it as a freshness bound
-                             #   on duplicate-delivery chains)
+                             #   echoed back as aer_tick: the lease's
+                             #   freshness bound on duplicate-delivery
+                             #   chains (strict ReadIndex orders by ae_seq,
+                             #   below), hibernation's and the runtime's
+                             #   heartbeat-round anchor
 
     # AppendEntries response (reference RaftResponse + match bookkeeping)
     aer_valid: jax.Array     # [P, G] bool
@@ -887,6 +908,18 @@ class Messages:
                              #   acknowledgement of its whole log
     aer_asleep: Any = None   # Optional[[P, G] bool] — the replier fell (or
                              #   stays) asleep on that heartbeat
+    # Strict ReadIndex (cfg.read_lease False; None with the lease, same
+    # contract): the order of stamps and AppendEntries by STEPS.
+    ae_seq: Any = None       # Optional[[P, G] int32] — the sender's
+                             #   RaftState.read_seq as this step left it:
+                             #   every batch stamped up to and in the step
+                             #   this AppendEntries leaves in has a stamp
+                             #   <= it
+    aer_seq: Any = None      # Optional[[P, G] int32] — echo of ae_seq by a
+                             #   replier AT THE REQUEST'S TERM (0 otherwise:
+                             #   the counter starts over with every
+                             #   leadership, so the echo of an older term's
+                             #   request must never reach a newer one)
 
     @classmethod
     def empty(cls, cfg: EngineConfig) -> "Messages":
@@ -912,6 +945,8 @@ class Messages:
             tn_valid=f(P, G), tn_term=z(P, G),
             ae_sleep=f(P, G) if cfg.hibernate else None,
             aer_asleep=f(P, G) if cfg.hibernate else None,
+            ae_seq=None if cfg.read_lease else z(P, G),
+            aer_seq=None if cfg.read_lease else z(P, G),
         )
 
 
@@ -965,10 +1000,11 @@ class HostInbox:
     # none of them can expire an election, RPC, CheckQuorum or transfer
     # deadline, none sends a cadence heartbeat (``now >= hb_due`` stays
     # false; a read's barrier heartbeat and data AppendEntries leave),
-    # and everything stamped in them (``sent_at``, ``ok_at``, ``rq_stamp``,
-    # ``ae_tick``) carries the period's value.  Whoever steps with no
-    # loop advances the clock every step (``empty()`` gives 1).  A node's
-    # first step is a timer step: stamps are >= 1, evidence 0 means none.
+    # and everything stamped in them (``sent_at``, ``ok_at``, ``ae_tick``,
+    # the lease's ``rq_stamp``) carries the period's value.  Whoever steps
+    # with no loop advances the clock every step (``empty()`` gives 1).  A
+    # node's first step is a timer step: stamps are >= 1, evidence 0 means
+    # none.
     clock: jax.Array           # scalar int32 — 0 or 1
     # Durable-tail feedback (the pipelined runtime's safety lane): the
     # highest log index per group the host has FSYNCED.  When present, the
@@ -1216,4 +1252,5 @@ def init_state(cfg: EngineConfig, node_id: int, seed: int = 0,
         qc=(QuorumContact.empty(G, P) if cfg.check_quorum else None),
         lease=(LeaseGuard.empty(G) if cfg.lease_carry_ticks else None),
         hib=(Hibernate.empty(G, P) if cfg.hibernate else None),
+        read_seq=(None if cfg.read_lease else z(G)),
     )

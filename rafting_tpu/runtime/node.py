@@ -319,14 +319,17 @@ class _ReadOffer:
     (StepInfo.read_carried: the lease outlived the period it was
     acknowledged in, core/step.py phase 6b); ``woke``: that step woke the
     lane from hibernation, so the barrier is the heartbeat that wakes its
-    followers."""
+    followers; ``t_stamp``: when the host phase of the stamping step saw
+    the stamp (``time.perf_counter``), from which a batch that a later
+    step's acknowledgements release measures its round."""
 
-    __slots__ = ("parts", "n", "lease", "carried", "woke")
+    __slots__ = ("parts", "n", "lease", "carried", "woke", "t_stamp")
 
     def __init__(self, parts: List[_ReadBatch]):
         self.parts = parts
         self.n = sum(len(b.payloads) for b in parts)
         self.lease = self.carried = self.woke = False
+        self.t_stamp = 0.0
 
 
 class _TickCtx:
@@ -1048,6 +1051,12 @@ class RaftNode:
         # heartbeats that a batch left pending asked for.
         self.metrics["read_lease_carried"] += 0
         self.metrics["read_kicks"] += 0
+        # Batches that a LATER step's acknowledgements released (a
+        # ReadIndex round, not the lease in the stamping step), and
+        # batches stamped in a step that work started between two timer
+        # ticks.
+        self.metrics["read_rounds"] += 0
+        self.metrics["read_stamps_on_arrival"] += 0
         # Hibernation: lanes that fell asleep and woke, the wakes by what
         # caused them (a request of this host's, a message, the peer-lost
         # signal), and the empty frames of the node-level beat.
@@ -2455,6 +2464,12 @@ class RaftNode:
     def _hold_read_veto(self, arrival: bool) -> bool:
         """Tick thread, at a step's intake: ``HostInbox.read_veto``."""
         cfg = self.cfg
+        if not cfg.read_lease:
+            # Strict ReadIndex anchors nothing at a receipt and reads no
+            # clock (core/step.py phase 6b): a pause stretches nothing,
+            # and evidence dropped would only cost a pending read a
+            # second round.  Never raised.
+            return False
         # Wall-clock pause detection (HostInbox.read_veto contract): a gap
         # beyond read_fresh_ticks tick intervals invalidates stored lease
         # evidence AND whatever acks queued in the inbox across the pause.
@@ -3186,7 +3201,8 @@ class RaftNode:
 
                 # -- 6b. read plane: stamped/released bookkeeping + serving --
                 st.note(lanes=self._harvest_reads(ctx.info, ids,
-                                                  ctx.woke_ids)
+                                                  ctx.woke_ids,
+                                                  arrival=not ctx.timer)
                         + self._serve_reads(applied, ids))
                 self._note_scanned()
                 st.enter("maintain")
@@ -4038,7 +4054,8 @@ class RaftNode:
 
     def _harvest_reads(self, info: StepInfo,
                        ids: Optional[np.ndarray] = None,
-                       woke_ids: Optional[np.ndarray] = None) -> int:
+                       woke_ids: Optional[np.ndarray] = None,
+                       arrival: bool = False) -> int:
         """Tick thread: mirror the device read FIFO's transitions reported
         in StepInfo — offers the device STAMPED move to pending with
         their ReadIndex; pending offers whose barrier RELEASED move to
@@ -4072,6 +4089,10 @@ class RaftNode:
         # pays the barrier that wakes its followers.
         woke = set(woke_ids.tolist()) if stamped and woke_ids is not None \
             else ()
+        # One reading of the wall's monotonic clock for a step that stamps
+        # or releases, none for one that does neither.
+        t_now = time.perf_counter() if stamped or released else 0.0
+        rounds, round_s = 0, 0.0
         with self._read_lock:
             for g in stamped:
                 b = self._reads_offered.pop(g, None)
@@ -4085,6 +4106,7 @@ class RaftNode:
                 b.lease = bool(read_lease[g])
                 b.carried = bool(read_carried[g])
                 b.woke = g in woke
+                b.t_stamp = t_now
                 self._reads_pending.setdefault(g, deque()).append(
                     (int(read_idx[g]), b))
                 m = self.metrics
@@ -4097,12 +4119,24 @@ class RaftNode:
                 for _ in range(int(read_rel[g])):
                     assert q, (f"g={g}: device released a read batch the "
                                "host FIFO does not hold")
-                    rel.append(q.popleft())
+                    idx, b = q.popleft()
+                    rel.append((idx, b))
+                    if not b.lease:
+                        # Stamped in an earlier step: a round of
+                        # acknowledgements came between.
+                        rounds += 1
+                        round_s += t_now - b.t_stamp
                 # Columnar serve gate: remember the smallest ReadIndex
                 # still waiting so _serve_reads visits only groups whose
                 # apply frontier actually reached one.
                 if rel[0][0] < self._rel_min[g]:
                     self._rel_min[g] = rel[0][0]
+        if stamped or released:
+            on_arrival = len(stamped) if arrival else 0
+            self.metrics["read_stamps_on_arrival"] += on_arrival
+            self.metrics["read_rounds"] += rounds
+            self._stages.note(stamps=len(stamped), arrival_stamps=on_arrival,
+                              rounds=rounds, round_ms=1e3 * round_s)
         for g in aborted:
             self._reject_reads(g)
         return len(stamped) + len(released) + len(aborted)

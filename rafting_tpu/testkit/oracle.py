@@ -170,6 +170,10 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     rq_head = s["rq_head"].copy()
     rq_len = s["rq_len"].copy()
     K = cfg.read_slots
+    # Strict ReadIndex's stamp counter (kernel 6b, "ticks and steps").
+    strict = not cfg.read_lease
+    if strict:
+        read_seq = s["read_seq"].copy()
 
     # Flight recorder (cfg.trace_depth): the scalar mirror of the kernel's
     # ring writes — same canonical event order, same ring semantics.
@@ -268,6 +272,9 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         out["ae_sleep"] = zb(P, G)
         out["aer_asleep"] = zb(P, G)
         info["asleep"] = zb(G)
+    if strict:
+        out["ae_seq"] = zi(P, G)
+        out["aer_seq"] = zi(P, G)
 
     for g in range(G):
         log = _Log(ring[g], cring[g], int(base[g]), int(base_term[g]),
@@ -469,6 +476,9 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
                 # echoes it on success AND failure — any same-term reply
                 # proves the AE was processed).
                 out["aer_tick"][p, g] = ib["ae_tick"][p, g]
+                if strict and ae_ok[p]:
+                    # The stamp counter's echo, to a request at our term.
+                    out["aer_seq"][p, g] = ib["ae_seq"][p, g]
         if hiber:
             # The sleep heartbeat this follower agrees to.
             sleep_ok = (ae_any and acc and bool(ib["ae_sleep"][ae_peer, g])
@@ -638,7 +648,8 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
                         # pre-votes as its followers do.
                         elect_dl[g] = now + cfg.election_ticks
             else:
-                read_evid[g, p] = max(int(read_evid[g, p]), echoed)
+                read_evid[g, p] = max(int(read_evid[g, p]),
+                                      int(ib["aer_seq"][p, g]))
         if h["read_veto"]:
             # Host detected a wall-clock tick gap: stored AND same-tick
             # lease evidence is untrustworthy (kernel applies the same
@@ -769,17 +780,20 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
             rq_head[g] = 0
             rq_len[g] = 0
             read_evid[g, :] = 0
+            if strict:
+                read_seq[g] = 0
         n_read = 0
-        # Strict ReadIndex stamps only in the step that advances the
-        # clock (kernel 6b, "ticks and steps").
         if (keep_reads and commit[g] >= own_from_a[g]
-                and int(rq_len[g]) < K
-                and (cfg.read_lease or int(h["clock"]) > 0)):
+                and int(rq_len[g]) < K):
             n_read = max(0, int(h["read_n"][g]))
         if n_read > 0:
+            # The lease stamps with the tick, strict ReadIndex with its
+            # counter, which a step that stamps moves.
+            if strict:
+                read_seq[g] += 1
             slot = (int(rq_head[g]) + int(rq_len[g])) % K
             rq_idx[g, slot] = commit[g]
-            rq_stamp[g, slot] = now
+            rq_stamp[g, slot] = read_seq[g] if strict else now
             rq_n[g, slot] = n_read
             rq_len[g] += 1
             info["read_index"][g] = commit[g]
@@ -922,6 +936,8 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
                     out["ae_commit"][p, g] = commit[g]
                     out["ae_n"][p, g] = n_send
                     out["ae_tick"][p, g] = now
+                    if strict:
+                        out["ae_seq"][p, g] = read_seq[g]
                     if hiber:
                         out["ae_sleep"][p, g] = send_hb and quiet
                     for k in range(B):
@@ -1160,4 +1176,6 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
     if hiber:
         new_state.update({"hib.asleep": asleep_a, "hib.busy_at": busy_at,
                           "hib.slept": slept})
+    if strict:
+        new_state["read_seq"] = read_seq
     return new_state, out, info

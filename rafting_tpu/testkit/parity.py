@@ -37,7 +37,10 @@ MSG_GROUPS = {
 }
 
 
-HIBERNATE_FLAGS = {"ae_valid": ["ae_sleep"], "aer_valid": ["aer_asleep"]}
+# Fields of a mode that is on (hibernation; strict ReadIndex) ride their
+# kind's lane.
+OPTIONAL_FIELDS = {"ae_valid": ["ae_sleep", "ae_seq"],
+                   "aer_valid": ["aer_asleep", "aer_seq"]}
 
 
 def assert_messages_equal(kernel_out: Messages, oracle_out: dict, tag: str):
@@ -47,8 +50,7 @@ def assert_messages_equal(kernel_out: Messages, oracle_out: dict, tag: str):
         np.testing.assert_array_equal(
             kv, ov, err_msg=f"{tag}: {vfield} mismatch")
         mask = kv
-        # The flags of a feature that is on ride their kind's lane.
-        deps = deps + [f for f in HIBERNATE_FLAGS.get(vfield, ()) if f in k]
+        deps = deps + [f for f in OPTIONAL_FIELDS.get(vfield, ()) if f in k]
         for f in deps:
             a, b = k[f], oracle_out[f]
             m = mask[..., None] if a.ndim == 3 else mask
@@ -124,7 +126,7 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
     partition = None
     stats = {"partitions": 0, "crashes": 0, "stalls": 0, "arrival_steps": 0,
              "lease_reads": 0, "lease_carried": 0, "asleep_steps": 0,
-             "wakes": 0}
+             "wakes": 0, "stamps_on_arrival": 0, "reads_released": 0}
 
     for t in range(n_ticks):
         quiet = any(a <= t < b for a, b in calm)
@@ -245,6 +247,10 @@ def run_parity(seed: int, n_ticks: int, cfg: EngineConfig,
             stats["lease_reads"] += int(np.asarray(k_info.read_lease).sum())
             stats["lease_carried"] += int(
                 np.asarray(k_info.read_carried).sum())
+            if int(host.clock) == 0:
+                stats["stamps_on_arrival"] += int(
+                    (np.asarray(k_info.read_acc) > 0).sum())
+            stats["reads_released"] += int(np.asarray(k_info.read_rel).sum())
             if cfg.hibernate:
                 now_asleep = np.asarray(k_info.asleep)
                 stats["asleep_steps"] += int(now_asleep.sum())

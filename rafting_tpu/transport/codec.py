@@ -157,12 +157,17 @@ KIND_FIELDS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # TimeoutNow (§3.10 leadership transfer).
     "tn": ("tn_valid", ("tn_term",)),
 }
-# Flags of an engine feature that is off are not fields of the cluster's
-# Messages (core/types.py: None leaves), and the wire is then what it
-# always was.  Where the feature is on (every member of a cluster runs one
-# configuration) they close their kind's section, after the fields above.
+# Flags and words of an engine mode that is off are not fields of the
+# cluster's Messages (core/types.py: None leaves), and the wire is then
+# what it always was.  Where the mode is on they close their kind's
+# section, after the fields above, in this order: hibernation's flag, then
+# strict ReadIndex's word (cfg.read_lease off).  Every member of a cluster
+# runs ONE configuration; a node whose optional fields differ from ours (a
+# strict node and a lease node) is refused at the handshake: HELLO carries
+# ``schema_tag(template)``, which covers the optional fields that are on
+# and equals SCHEMA_TAG where none is.
 OPTIONAL_FIELDS: Dict[str, Tuple[str, ...]] = {
-    "ae": ("ae_sleep",), "aer": ("aer_asleep",)}
+    "ae": ("ae_sleep", "ae_seq"), "aer": ("aer_asleep", "aer_seq")}
 
 
 def kind_fields(kind: str, have) -> Tuple[str, Tuple[str, ...]]:
@@ -230,22 +235,25 @@ def peek_frame(buf) -> Optional[Tuple[int, bytes, int]]:
     return ftype, body, _HDR.size + blen
 
 
-def _schema_tag() -> int:
-    """CRC of the per-kind field tables: two peers agree on the MSGS wire
-    layout iff their tags match.  Carried in HELLO so a field-list change
-    (e.g. aer_empty / is_probe) rejects a mixed-version peer with ONE
-    clear log line instead of presenting as endless opaque connection
-    drops when the misaligned columns fail the body bounds checks."""
+def schema_tag(have=()) -> int:
+    """CRC of the per-kind field tables, with the optional fields that
+    ``have`` (a template) holds: two peers agree on the MSGS wire layout
+    iff their tags match.  Carried in HELLO so a field-list change (e.g.
+    aer_empty / is_probe) or a cluster wired from two configurations
+    rejects the peer with ONE clear log line instead of presenting as
+    endless opaque connection drops when the misaligned columns fail the
+    body bounds checks."""
     desc = ";".join(f"{k}:{v}:{','.join(d)}"
-                    for k, (v, d) in KIND_FIELDS.items())
+                    for k in KIND_FIELDS for v, d in [kind_fields(k, have)])
     return zlib.crc32(desc.encode())
 
 
-SCHEMA_TAG = _schema_tag()
+SCHEMA_TAG = schema_tag()
 
 
-def pack_hello(node_id: int, G: int, P: int, B: int) -> bytes:
-    return frame(HELLO, struct.pack("<IIIII", node_id, G, P, B, SCHEMA_TAG))
+def pack_hello(node_id: int, G: int, P: int, B: int,
+               tag: int = SCHEMA_TAG) -> bytes:
+    return frame(HELLO, struct.pack("<IIIII", node_id, G, P, B, tag))
 
 
 def unpack_hello(body: bytes) -> Tuple[int, int, int, int, int]:

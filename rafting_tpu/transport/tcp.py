@@ -232,8 +232,12 @@ class TcpTransport:
         self.result_encoder = result_encoder
         self.read_handler = read_handler
         self.conf_node = conf_node
+        # Covers the optional fields this cluster's Messages hold
+        # (hibernation, strict ReadIndex): a peer of another configuration
+        # is refused at the handshake.
+        self._tag = codec.schema_tag(template)
         self._hello = codec.pack_hello(node_id, cfg.n_groups, cfg.n_peers,
-                                       cfg.batch)
+                                       cfg.batch, self._tag)
         self._senders: Dict[int, PeerSender] = {}
         # Frames read per source node, of whatever type, once its channel
         # has said who it is: what the runtime's node-level beat watches
@@ -390,11 +394,11 @@ class TcpTransport:
                                          self.cfg.batch):
                             log.error("shape mismatch from node %d", nid)
                             return
-                        if tag != codec.SCHEMA_TAG:
+                        if tag != self._tag:
                             log.error("wire-schema mismatch from node %d "
                                       "(tag %#x != ours %#x) — peer runs a "
-                                      "different build", nid, tag,
-                                      codec.SCHEMA_TAG)
+                                      "different build or configuration",
+                                      nid, tag, self._tag)
                             return
                         src = nid
                     elif ftype == codec.MSGS:

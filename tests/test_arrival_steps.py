@@ -65,23 +65,25 @@ def _after_timer_tick(node, timeout=60.0):
 
 
 @pytest.mark.parametrize("lease", [True, False], ids=["lease", "strict"])
-def test_write_and_lease_read_resolve_before_the_next_timer_tick(
-        tmp_path, lease):
-    """At a period of seconds, a write is acknowledged and a lease read
-    served while the leader's timer count stands still: steps that the
-    arrivals started did the work, on the leader and on the followers
-    that acknowledged.  A strict read is stamped by the timer's step
-    (core/step.py 6b) and so takes the timer; its write does not."""
+def test_write_and_read_resolve_before_the_next_timer_tick(tmp_path, lease):
+    """At a period of seconds, a write is acknowledged and a linearizable
+    read served while the leader's timer count stands still: steps that
+    the arrivals started did the work, on the leader and on the followers
+    that acknowledged.  That holds for the lease and for strict ReadIndex
+    alike: a strict read is stamped in the step it arrives in, its
+    barrier heartbeat leaves in that step and the followers' arrival
+    steps acknowledge it (core/step.py 6b); no lease ever releases it."""
     lc = _cluster(tmp_path, read_lease=lease)
     try:
         lc.start_loops(2.0)
         lead = lc.nodes[lc.leader_of(1)]
+        m0 = {k: int(lead.metrics[k]) for k in (
+            "read_rounds", "read_stamps_on_arrival", "read_lease_hits")}
         for attempt in range(4):
             timer0 = _after_timer_tick(lead)
             before = _counts(lc)
             lead.submit(1, _kv("set", "k", attempt)).result(60)
-            if lease:
-                assert lead.read(1, _kv("get", "k")).result(60) == attempt
+            assert lead.read(1, _kv("get", "k")).result(60) == attempt
             if lead.timer_ticks == timer0:
                 break
         else:
@@ -90,10 +92,13 @@ def test_write_and_lease_read_resolve_before_the_next_timer_tick(
         assert after[lead.node_id][2] > before[lead.node_id][2]
         assert sum(after[i][2] > before[i][2] for i in after
                    if i != lead.node_id) >= 1
+        lc.stop_loops()
+        moved = {k: int(lead.metrics[k]) - v for k, v in m0.items()}
+        assert moved["read_stamps_on_arrival"] >= 1
         if not lease:
-            timer0 = lead.timer_ticks
-            assert lead.read(1, _kv("get", "k")).result(60) == attempt
-            assert lead.timer_ticks > timer0
+            # Every strict read paid a round of its own.
+            assert moved["read_lease_hits"] == 0
+            assert moved["read_rounds"] == attempt + 1
     finally:
         lc.close()
 

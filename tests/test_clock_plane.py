@@ -6,8 +6,9 @@ one tick of the protocol's clock, delivered in pieces; these tests hold
 the engine to what that means:
 
 * I1  no run of clock-0 steps moves ``now`` or expires a timer;
-* I2  strict ReadIndex never releases a read on the echo of an
-      AppendEntries that left before the read arrived; the lease releases
+* I2  strict ReadIndex stamps a read in the step it arrives in and never
+      releases it on the echo of an AppendEntries that left before that
+      step (tests/test_read_index.py has the rest); the lease releases
       on evidence of the same ``now`` and never on evidence of an earlier;
 * I3  a clock-0 step that brings nothing emits nothing;
 * kernel and scalar oracle agree over random mixes of the two kinds of
@@ -227,12 +228,13 @@ def _followers(lead):
     return [n for n in range(3) if n != lead]
 
 
-def test_strict_read_waits_for_the_clock_and_a_later_echo():
+def test_strict_read_is_stamped_where_it_arrives_and_waits_for_a_later_echo():
     """read_lease off.  A heartbeat leaves in the timer's step at ``now``
-    N; a read is offered in a later step of the same N.  The echo of
-    that heartbeat carries N and must not release the read: strict mode
-    does not even stamp it until the clock advances, and then only an
-    echo of a heartbeat sent at or after the stamp releases it."""
+    N; a read is offered in a later step of the same N.  It is stamped in
+    that step (strict ReadIndex orders stamps and AppendEntries by steps,
+    not by ticks).  The echo of the earlier heartbeat carries N, the very
+    tick of the stamp, and must not release the read: only the echo of an
+    AppendEntries that left in or after the stamping step does."""
     cfg = small_cfg(read_lease=False, heartbeat_ticks=1)
     t = Trio(cfg, seed=4)
     lead = t.settle()
@@ -243,36 +245,42 @@ def test_strict_read_waits_for_the_clock_and_a_later_echo():
     # The timer's step at N: the cadence heartbeat leaves.
     t.step(lead, t.inbox_of(lead))
     N = t.now(lead)
+    seq0 = int(np.asarray(t.states[lead].read_seq)[0])
     assert np.asarray(t.out[lead].ae_valid)[fol, 0].all()
     assert (np.asarray(t.out[lead].ae_tick)[fol, 0] == N).all()
+    assert (np.asarray(t.out[lead].ae_seq)[fol, 0] == seq0).all()
     # The followers answer it (their own arrival steps).
     for f in fol:
         t.step(f, t.inbox_of(f, senders=[lead]), arrival=True)
         assert int(np.asarray(t.out[f].aer_tick)[lead, 0]) == N
+        assert int(np.asarray(t.out[f].aer_seq)[lead, 0]) == seq0
+    old_echoes = t.inbox_of(lead, senders=fol)
     # The read arrives AFTER that heartbeat left, in a step of the same N
-    # that brings nothing else: not stamped.
+    # that brings nothing else: stamped there, and its own barrier
+    # heartbeat leaves, at the same tick and one stamp later.
     info = t.step(lead, arrival=True, read_n=offer)
     assert t.now(lead) == N
-    assert int(np.asarray(info.read_acc)[0]) == 0
-    # The echoes arrive, the read still offered: still not stamped, so
-    # nothing to release.
-    info = t.step(lead, t.inbox_of(lead, senders=fol), arrival=True,
-                  read_n=offer)
-    assert int(np.asarray(info.read_acc)[0]) == 0
-    assert int(np.asarray(info.read_rel)[0]) == 0
-    # The clock advances: stamped at N + 1; the evidence in hand is the
-    # echo of N and releases nothing; a barrier heartbeat leaves at N + 1.
-    info = t.step(lead, read_n=offer)
-    assert t.now(lead) == N + 1
     assert int(np.asarray(info.read_acc)[0]) == 1
     assert int(np.asarray(info.read_rel)[0]) == 0
-    assert (np.asarray(t.out[lead].ae_tick)[fol, 0] == N + 1).all()
-    # Its echo does.
+    assert bool(np.asarray(info.read_kick)[0])
+    assert (np.asarray(t.out[lead].ae_tick)[fol, 0] == N).all()
+    assert (np.asarray(t.out[lead].ae_seq)[fol, 0] == seq0 + 1).all()
+    kicks = {f: t.inbox_of(f, senders=[lead]) for f in fol}
+    # The echoes of the EARLIER heartbeat arrive: they carry the stamp's
+    # own tick and release nothing, in this step or the timer's next.
+    info = t.step(lead, old_echoes, arrival=True)
+    assert int(np.asarray(info.read_rel)[0]) == 0
+    info = t.step(lead, old_echoes)
+    assert t.now(lead) == N + 1
+    assert int(np.asarray(info.read_rel)[0]) == 0
+    # The barrier heartbeat's echo does.
     for f in fol:
-        t.step(f, t.inbox_of(f, senders=[lead]), arrival=True)
+        t.step(f, kicks[f], arrival=True)
+        assert int(np.asarray(t.out[f].aer_seq)[lead, 0]) == seq0 + 1
     info = t.step(lead, t.inbox_of(lead, senders=fol), arrival=True)
     assert int(np.asarray(info.read_rel)[0]) == 1
     assert int(np.asarray(info.read_served)[0]) == 1
+    assert not bool(np.asarray(info.read_lease)[0])
 
 
 def test_lease_read_takes_evidence_of_its_own_now_and_no_older():

@@ -108,7 +108,8 @@ class Rounds:
         its fields (to deliver later: ``inject``)."""
         kept = {}
         for f in Messages.__dataclass_fields__:
-            if f.split("_", 1)[0] == kind:
+            if f.split("_", 1)[0] == kind \
+                    and getattr(self.inflight, f) is not None:
                 a = np.array(getattr(self.inflight, f))
                 kept[f] = a[src, dst, g].copy()
                 if f.endswith("_valid"):

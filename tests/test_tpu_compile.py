@@ -11,6 +11,7 @@ conftest.py), every compile happens in the test's own process, and all
 cases stay in this one file.
 """
 
+import dataclasses
 import json
 import os
 import re
@@ -58,6 +59,17 @@ def _on(sharding, tree):
     return jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
         tree)
+
+
+def _cell_engine(config):
+    """The engine as a benchmark configuration's file builds it."""
+    from rafting_tpu.api import RaftConfig
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           config + ".json")) as f:
+        raft = json.load(f)["raft_config"]
+    uris = [f"raft://127.0.0.1:{7001 + i}" for i in range(3)]
+    return RaftConfig(local=uris[0], peers=tuple(uris[1:]),
+                      data_dir="unused", **raft).engine_config()
 
 
 def _lower_step(sharding, cfg, packed):
@@ -134,13 +146,7 @@ def test_node_step_holds_no_gather_and_no_scatter(one_chip, packed, config,
     the AppendEntries build alone would be 61 MB).  The same on TiKV's own
     heartbeat, where the lease is carried (core/step.py phase 6b: a second
     pass of the read barrier and two guard lanes)."""
-    from rafting_tpu.api import RaftConfig
-    with open(os.path.join(REPO, "benchmark", "configs",
-                           config + ".json")) as f:
-        raft = json.load(f)["raft_config"]
-    uris = [f"raft://127.0.0.1:{7001 + i}" for i in range(3)]
-    cfg = RaftConfig(local=uris[0], peers=tuple(uris[1:]),
-                     data_dir="unused", **raft).engine_config()
+    cfg = _cell_engine(config)
     assert (cfg.n_groups, cfg.n_peers, cfg.log_slots, cfg.batch,
             cfg.max_submit, cfg.read_slots) == (10_000, 3, 64, 8, 8, 4)
     assert cfg.lease_carry_ticks == carry
@@ -164,6 +170,30 @@ def test_node_step_holds_no_gather_and_no_scatter(one_chip, packed, config,
     print(f"{found} temp_size_in_bytes={temp}")
     assert found == {"gather": 0, "scatter": 0}
     assert temp < 32 * 1024 ** 2, temp
+
+
+@pytest.mark.parametrize("config, strict", [("coord-1g-3v", False),
+                                            ("coord-1g-3v-ri", True)])
+def test_the_strict_step_compiles_beside_the_lease_one(one_chip, config,
+                                                       strict):
+    """node_step_packed as the two coordination-service cells build it,
+    through the chip's compiler: with ``read_lease`` off (PR 45's cell:
+    the stamp counter, a word on an AppendEntries and one on its reply)
+    the step stays one program with no gather and no scatter, takes and
+    returns one buffer, and its operand is longer by those two [P, G]
+    words and nothing else."""
+    cfg = _cell_engine(config)
+    assert (cfg.n_groups, cfg.n_peers, cfg.read_lease) == (16, 3, not strict)
+    inputs, readback = step_layouts(cfg, True)
+    assert len(inputs.buffers) == len(readback.buffers) == 1
+    lease_cfg = dataclasses.replace(cfg, read_lease=True)
+    extra = 2 * cfg.n_peers * cfg.n_groups if strict else 0
+    assert sum(inputs.words) \
+        == sum(step_layouts(lease_cfg, True)[0].words) + extra
+    compiled = _lower_step(one_chip, cfg, packed=True).compile()
+    hlo = compiled.as_text()
+    assert {op: len(re.findall(rf"\b{op}\(", hlo))
+            for op in ("gather", "scatter")} == {"gather": 0, "scatter": 0}
 
 
 def _index_rows(hlo):
@@ -198,13 +228,7 @@ def test_column_step_compiles_at_100k_lanes_and_addresses_k_rows(
     lanes.  The same with hibernation compiled in (PR 41's cell: three
     more lanes of state, a flag each way on the wire, one more level and
     one more row field), which adds no gather and no scatter."""
-    from rafting_tpu.api import RaftConfig
-    with open(os.path.join(REPO, "benchmark", "configs",
-                           config + ".json")) as f:
-        raft = json.load(f)["raft_config"]
-    uris = [f"raft://127.0.0.1:{7001 + i}" for i in range(3)]
-    cfg = RaftConfig(local=uris[0], peers=tuple(uris[1:]),
-                     data_dir="unused", **raft).engine_config()
+    cfg = _cell_engine(config)
     assert (cfg.n_groups, cfg.n_peers) == (100_000, 3)
     assert cfg.hibernate == config.endswith("-hib")
     lay = column_layouts(cfg, True)
