@@ -16,7 +16,7 @@ Phases (any failure raises and the exit code is non-zero):
                give the inline path's states; the scalar oracle must agree
                with the kernel tick by tick.
 3. served    — three RaftContainers over localhost TCP, 100,000 group lanes
-               each, WAL fsync on, FileMachine applies, whatever pipeline /
+               each, WAL fsync on, FileMachine applies, whatever
                host tier / WAL engine the program selects here: writes and
                linearizable reads through RaftStub, every acknowledged
                write in all three replicas' machines, one node destroyed,
@@ -447,7 +447,6 @@ def phase_served(chip, n_lanes: int, n_groups: int, n_clients: int,
             node = containers[0].node
             store = node.store.wal
             say("served.selected", lanes=n_lanes, tick_ms=tick_ms,
-                pipeline=bool(node.pipeline),
                 host_tier=("native" if node.store.can_stage_native
                            else "python"),
                 host_workers=node.host_workers,

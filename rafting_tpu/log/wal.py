@@ -15,7 +15,7 @@ anything that depends on a staged record, until :meth:`sync` returns.
 One ``sync`` is the fsync barrier covering every record staged before it;
 the node runtime releases RPC replies and completes client futures only
 behind that barrier (persist-before-reply, amortized over all groups),
-and the pipelined runtime additionally feeds the post-barrier durable
+and additionally feeds the post-barrier durable
 tail back into the device scan so an un-fsynced range can never be
 self-acked into a commit quorum (core/types.py HostInbox.durable_tail).
 

@@ -66,7 +66,7 @@ from ..snapshot.policy import MaintainAgreement
 from ..transport import InboxAccumulator, messages_template
 from ..transport.inbox import fill_columns, scatter_dense
 from ..transport.codec import (
-    BEAT, EAGER_KINDS, KIND_FIELDS, assemble_slice, frame, pack_beat,
+    BEAT, KIND_FIELDS, assemble_slice, frame, pack_beat,
     pack_hops, pack_kind_section,
 )
 from ..api.anomaly import (
@@ -93,35 +93,15 @@ log = logging.getLogger(__name__)
 # consumers only read it.
 _NOOP_LENS = np.zeros(1, np.uint32)
 
-# The stages of one host phase (_host_phase), as StageSpans names them.
-HOST_STAGES = ("wal", "fsync", "send", "apply", "reads", "maintain")
-# A fetched tick settles in its own period when its host phase fits this
-# many times into what is left of the period: once for the phase itself,
-# once more for the tail, the flush and a phase slower than any seen.
+# A loop starts a step for arriving work only when a whole step fits this
+# many times into what is left of the period: once for the step itself,
+# once more for its tail and for a step slower than any lately seen.
 SETTLE_MARGIN = 2.0
-# The host phase is taken to cost what the costliest of this many recent
-# ones did: one slow phase is remembered for that many ticks and then
-# forgotten whatever its size, and a cost that hovers at the limit reads
-# as its upper envelope, so the order does not flip tick by tick.
-HOST_COST_MEMORY = 8
 
 # Heartbeat rounds a node follows at once (``hb_round_s``).  A started loop
 # has one open, the period's; a caller that steps its nodes in lock step
 # advances the clock every step and hears of a round two steps later.
 HB_ROUNDS_OPEN = 4
-
-
-def settles_now(now: float, due: Optional[float], cost: float) -> bool:
-    """Whether a pipelined tick, just fetched at ``now``, runs its own
-    host phase in this period (True) or stashes it for the next tick to
-    run under that tick's scan (False).  ``due`` is when the loop is to
-    start its next tick, ``cost`` what a host phase has lately taken
-    (seconds, the clock of ``now``).  A caller that ticks with no loop
-    observes no deadline (``due`` None) and keeps the order the node was
-    constructed with: pipelined stays overlapped."""
-    if due is None:
-        return False
-    return now + SETTLE_MARGIN * cost <= due
 
 
 def arrival_step_at(now: float, ended: float, took: float,
@@ -153,15 +133,20 @@ def arrival_step_at(now: float, ended: float, took: float,
       PR 43): both count as held.  A Python stage that waited for the
       interpreter counts as held too, so three loops that crowd one
       interpreter lengthen their own gaps.  ``waited`` 0 (a step that
-      failed, or ran no host phase) is the step's whole duration;
+      failed) is the step's whole duration;
     * room: ``SETTLE_MARGIN`` times ``cost`` (the WHOLE step, waits and
-      all) fits between the start and ``due``, so an arrival step
-      settles (``settles_now``) and never makes a timer tick late.  A
-      node whose step fills its period gets None every time and ticks by
-      the timer alone, as it always did.
+      all) fits between the start and ``due``, so a step of the usual
+      length ends before the timer's tick is due.  A step that started
+      runs to its end whatever it then takes: one that outlasts the
+      period makes the timer's tick late (``tick_late_s``,
+      ``ticks_late``).  A node whose step fills its period gets None
+      every time and ticks by the timer alone, as does a caller with no
+      loop and so no deadline (``due`` None).
     """
     at = max(now, ended + max(0.0, took - waited))
-    return at if settles_now(at, due, cost) else None
+    if due is None or at + SETTLE_MARGIN * cost > due:
+        return None
+    return at
 
 
 class BatchSubmit:
@@ -333,14 +318,13 @@ class _ReadOffer:
 
 
 class _TickCtx:
-    """One tick in flight through the durable pipeline.
+    """One tick in flight, from ``_dispatch`` to the end of its host phase.
 
     Created by ``_dispatch`` holding the step's packed result buffers on
     the device (the scan may still be executing); ``_fetch`` pulls them and
     sets the host planes, numpy views into the fetched buffers; the host
-    phase (``_host_phase``) consumes those.  Carrying the per-tick
-    inputs (inbox arrays, staged payload runs, offered counts) here is
-    what lets the NEXT scan dispatch before this tick's host work runs."""
+    phase (``_host_phase``) consumes those, with the per-tick inputs
+    (inbox arrays, staged payload runs, offered counts) carried here."""
 
     __slots__ = (
         # dispatch-time host inputs
@@ -358,12 +342,6 @@ class _TickCtx:
         # -> host views of the fetched buffers (fetch)
         "info", "outbox", "term", "voted", "role", "leader", "commit",
         "base", "base_term",
-        # Eager-send bookkeeping (overlapped ticks): per-peer AE columns
-        # whose payloads were not staged at fetch time — the host phase
-        # packs exactly these after the barrier.  None = nothing left
-        # eagerly (serial and settled ticks: every kind packs post-fsync,
-        # the classic send).
-        "deferred_ae",
         # Hibernation (cfg.hibernate): the lanes the step's peer-lost
         # signal named and those it asked something beside a write or a
         # read (dispatch); the lanes it woke (fetch).
@@ -418,6 +396,10 @@ def _at(plane: np.ndarray, lanes: Optional[np.ndarray]) -> np.ndarray:
 
 
 class RaftNode:
+    # Read by benchmark/harness.py's ``[selected]`` line and by nothing
+    # else: there is one tick order (tick()), and it feeds durable_tail.
+    pipeline = True
+
     def __init__(self, cfg: EngineConfig, node_id: int, data_dir: str,
                  provider: MachineProvider,
                  transport_factory: Callable,
@@ -429,7 +411,6 @@ class RaftNode:
                  busy_threshold: int = 1_000,
                  store=None,
                  serializer=None,
-                 pipeline: Optional[bool] = None,
                  wal_shards: Optional[int] = None,
                  host_workers: Optional[int] = None,
                  latency_slo_s: Optional[float] = None):
@@ -445,17 +426,6 @@ class RaftNode:
         ``serializer``: CmdSerializer for command/result encoding across
         the leader-forward relay (api/serial.py; reference CmdSerializer,
         support/serial/CmdSerializer.java:11-24) — default JSON.
-        ``pipeline``: build the double-buffered durable pipeline (see
-        ``tick``).  Default: ON exactly when the engine runs on an
-        accelerator backend.
-        A pipelined node under its own loop (``start``) then chooses the
-        order per tick from the loop's deadline: a fetched tick whose
-        host phase fits in what is left of the period runs it there (the
-        serial order, nothing deferred), and only a tick with no such
-        room is overlapped with the next scan, one tick later for every
-        message and acknowledgement it carries (``settles_now``).  A
-        caller that drives ``tick()`` itself has no deadline and gets the
-        overlapped order on every tick.
         ``wal_shards``: stripe count for the default WAL store (ignored
         when ``store`` is passed) — default from env RAFT_WAL_SHARDS,
         else 4.
@@ -473,9 +443,6 @@ class RaftNode:
         self.data_dir = data_dir
         self.serializer = serializer or JsonSerializer()
         os.makedirs(data_dir, exist_ok=True)
-        if pipeline is None:
-            pipeline = jax.default_backend() != "cpu"
-        self.pipeline = bool(pipeline)
         if wal_shards is None:
             wal_shards = int(os.environ.get("RAFT_WAL_SHARDS", "4"))
 
@@ -921,14 +888,10 @@ class RaftNode:
         self._stages = StageSpans(self.metrics, node_id)
         self._tick_due: Optional[float] = None   # _run: next start due
         self._tick_stagger = False               # start(): share a host
-        # Seconds per host phase over the last HOST_COST_MEMORY ticks that
-        # ran one (the stage spans' own sum): what settles_now() weighs
-        # against the time left until _tick_due.
-        self._host_costs: deque = deque(maxlen=HOST_COST_MEMORY)
-        self._host_runs = 0                      # host phases this tick
-        # Seconds per whole step, the same memory: arrival_step_at()
-        # weighs their median against the time left until _tick_due.
-        self._step_costs: deque = deque(maxlen=HOST_COST_MEMORY)
+        # Seconds per whole step of the loop, the last eight: one slow
+        # step is forgotten after as many.  arrival_step_at() weighs their
+        # median against the time left until _tick_due.
+        self._step_costs: deque = deque(maxlen=8)
         # Seconds the tick() call under way spent in the WAL's fsyncs,
         # as _host_phase adds them up: with the stages scan_device and
         # scan_fetch what its thread spent blocked on another agent, which
@@ -946,14 +909,6 @@ class RaftNode:
         #                           step's drain closed (tick() ends it)
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
-        # Double-buffered pipeline state: the fetched-but-not-yet-host-
-        # processed tick (see tick()).  Owned by the tick thread.
-        self._pending: Optional[_TickCtx] = None
-        # Per-group offer counts riding the in-flight/pending tick, so the
-        # next dispatch never offers the same queued entry twice (the
-        # device accepting both would outrun the host queues).
-        self._inflight_submit = np.zeros(G, np.int32)
-        self._inflight_read = np.zeros(G, np.int32)
         # The [G] side of a shape that takes the column step (core/step.py
         # column_layouts; all of it unused on any other): what the device
         # keeps from step to step (RowCarry), the host's copy of the
@@ -998,27 +953,18 @@ class RaftNode:
         # (_where and the sums beside it), folded into the stage's span
         # and the counter host_lanes_scanned by _note_scanned.
         self._scanned = 0
-        # Per-peer outbox sections accumulated across a tick's packing
-        # sites (the host phase's deferred/non-eager sections + the eager
-        # AE pack) and flushed as ONE frame per peer at end of tick — the
-        # accumulator drains one slice per source per tick, so two frames
-        # would back up.  Tick thread only.
+        # Per-peer outbox sections a tick's host phase packed, flushed as
+        # ONE frame per peer (_flush_sends) — the accumulator drains one
+        # slice per source per tick, so two frames would back up.  Tick
+        # thread only.
         self._held_sections: Dict[int, List[bytes]] = {}
-        self.metrics.gauge("pipeline_enabled", int(self.pipeline))
         self.metrics.gauge("wal_shards",
                            getattr(getattr(self.store, "wal", None),
                                    "n_shards", 1))
         self.metrics.gauge("host_workers", self.host_workers)
         self.metrics.gauge("native_host", int(self._native_wal))
-        # Eager leader sends (overlapped ticks): AE frames released right
-        # after fetch, ahead of the tick's own fsync (safe — commit only
-        # counts fsynced self-matches via HostInbox.durable_tail).
-        self.metrics["eager_sends"] += 0
-        # Ticks started, and those of them whose own host phase ran in
-        # their own period (serial ticks, and pipelined ticks that
-        # settles_now() found room for).
+        # Ticks started.
         self.metrics["ticks"] += 0
-        self.metrics["ticks_settled"] += 0
         # Those of them a loop started for arriving work, between two
         # timer ticks (HostInbox.clock 0).
         self.metrics["ticks_on_arrival"] += 0
@@ -1158,19 +1104,6 @@ class RaftNode:
         self._wake.set()
         if self._thread is not None:
             self._thread.join(timeout=10)
-        # Settle the pipeline: the pending tick's host work (WAL staging,
-        # fsync, sends, applies) runs here on the closing thread —
-        # single-writer ownership transfers exactly like the GC settle
-        # below — so nothing the device computed is lost on a graceful
-        # close and the durable tail matches the device tail on restart.
-        pending, self._pending = self._pending, None
-        if pending is not None:
-            try:
-                self._host_phase(pending)
-            except Exception:
-                log.exception("node %d: pipeline drain failed on close",
-                              self.node_id)
-            self._stages.leave()
         if self._lat is not None:
             # Final harvest: retired-but-unmerged spans land in the
             # histograms before the registry goes quiet (spans still in
@@ -1615,10 +1548,10 @@ class RaftNode:
         the gap passed and room before the timer.  The gap behind a step
         is the time it held the interpreter: its duration less what
         ``tick()`` observed of its own waits (the stages ``scan_device``
-        and ``scan_fetch`` and the WAL's fsync seconds of that call,
-        whatever tick's host phase ran in it; not ``dispatch_upload`` or
-        ``dispatch_enqueue``, which are the host's own work), and the
-        whole duration of a step that failed or ran no host phase.  The
+        and ``scan_fetch`` and the WAL's fsync seconds of that call; not
+        ``dispatch_upload`` or ``dispatch_enqueue``, which are the host's
+        own work), and the whole duration of a step that failed (a
+        ``tick()`` that returns has run its host phase).  The
         period stays the ENGINE's clock whatever the arrival rate: only
         the timer's step advances it (``tick(arrival=False)``), and
         ``_next_start``, ``tick_late_s`` and ``ticks_late`` are about
@@ -1633,9 +1566,8 @@ class RaftNode:
             waited = 0.0
             try:
                 self.tick(arrival=arrival)
-                if self._host_runs:
-                    waited = self._fsync_waited + st.total(
-                        "scan_device", "scan_fetch")
+                waited = self._fsync_waited + st.total(
+                    "scan_device", "scan_fetch")
             except Exception:
                 log.exception("node %d tick failed", self.node_id)
                 st.leave()
@@ -1728,55 +1660,29 @@ class RaftNode:
         of their steps is a period of the engine's clock, as it always
         was.
 
-        Serial mode (``pipeline=False``): the classic strictly ordered
-        tick — scan, wait, persist+fsync, send, apply, maintain — nothing
-        overlaps.
-
-        Pipelined mode (the durable pipeline): this tick's fused scan is
-        DISPATCHED first (JAX async dispatch — no blocking transfer), the
-        host phase of a tick still pending from before (WAL staging, the
-        fsync barrier, outbox release, applies, read serving,
-        maintenance) runs while the device computes, and then this
-        tick's results are fetched.  What follows is chosen per tick from
-        what the loop observes (``settles_now``):
-
-        * SETTLED — the time left until the loop is due to start its next
-          tick holds this tick's host phase with room to spare: the phase
-          runs now, in serial order (fetch, persist+fsync, one flush,
-          apply, reads), nothing stays pending, and the next tick finds
-          nothing to overlap.  No fetched tick then sleeps through the
-          rest of a period waiting for the next tick to process it.
-        * OVERLAPPED — there is no such room (a node whose host work
-          fills its period), or no deadline at all (a caller that drives
-          ``tick()`` itself: ``LocalCluster``, ``chip_smoke.py``, the
-          lock-step tests): the tick is stashed in ``_pending``, its
-          AppendEntries leave eagerly, and its host phase runs under the
-          NEXT tick's scan — the double-buffered order the pipeline was
-          built for.
-
-        The tick that goes from overlapping to settling runs two host
-        phases, the pending tick's and then its own, each behind its own
-        barrier, and flushes once.  Safety holds in either order because
-        (a) a tick's acknowledgements, AE-responses, votes, served reads
-        and futures are released only inside its own host phase, strictly
-        after its fsync barrier — ack-after-fsync, exactly as serial —
-        and (b) the scan's commit quorum counts our own match only up to
-        the FSYNCED durable tail fed through ``HostInbox.durable_tail``,
-        so a scan racing the previous tick's fsync can never self-ack an
-        un-fsynced range into a commit.  Pipeline barriers (lifecycle
-        changes, snapshot installs) drain the pending tick first; both
-        are rare.
+        One order, the only one: the step is DISPATCHED (``_dispatch``:
+        intake, one upload, JAX async dispatch), its results are FETCHED
+        (``_fetch``: wait, one copy down, mirrors) and its HOST PHASE runs
+        at once (``_host_phase``: WAL staging, THE fsync barrier, stamps,
+        one flush of one frame a peer, applies, read serving, maintain),
+        then the tail; nothing of a tick outlives ``tick()``.  Every
+        acknowledgement, AE-response, vote, AppendEntries, served read and
+        future of the tick leaves behind that one barrier, by order alone.
+        Beside the order, the scan's commit quorum counts this node's own
+        match only up to the FSYNCED tail it is fed every step
+        (``HostInbox.durable_tail``): after a barrier that FAILED the host's
+        staged mirror is ahead of the disk, and what the clamp is then fed
+        is the confirmed tail (``_acked_tail``), so no un-fsynced range is
+        ever self-acknowledged into a commit, and a failed barrier changes
+        what the one program is fed, never which program runs.
         """
         st = self._stages
         # Every instant of tick() belongs to one named phase (the stage
         # histograms and raft.<name> profiler spans): dispatch_intake,
         # dispatch_upload, dispatch_enqueue (_dispatch); wal, fsync, send,
         # apply, reads, maintain (_host_phase); scan_device, scan_fetch,
-        # mirrors (_fetch); eager_send (overlapped ticks); tail.  _run adds
-        # wait.  Pipelined order: dispatch, pending host phase, fetch, then
-        # own host phase (settled) or eager_send (overlapped).
+        # mirrors (_fetch); tail.  _run adds wait.
         st.begin(self.ticks)
-        self._host_runs = 0
         self._fsync_waited = 0.0
         if self._lat is not None:
             self._lat.tick = self.ticks
@@ -1801,54 +1707,11 @@ class RaftNode:
         # whatever is queued after it sets the event again.
         self._wake.clear()
         ctx = self._dispatch(arrival, _tick_t0)
-        if self.pipeline:
-            prev, self._pending = self._pending, None
-            try:
-                if prev is not None:
-                    self._host_phase(prev, defer_send=True)
-            finally:
-                # The dispatched tick must never be dropped: even if
-                # the pending host phase failed (the loop in _run
-                # keeps ticking through exceptions), fetch and stash
-                # it so its appends are persisted next tick —
-                # otherwise the device state advances past entries
-                # whose payloads the WAL never saw.
-                self._fetch(ctx)
-                self._pending = ctx
-            settled = settles_now(
-                time.perf_counter(), self._tick_due,
-                max(self._host_costs, default=0.0))
-        else:
-            self._fetch(ctx)
-            settled = True
-        if settled:
-            # The serial order from here, for a serial node and for a
-            # pipelined one with room in this period.  Everything of
-            # this tick is packed behind its own barrier (deferred_ae
-            # stays None: no eager pack), and the host phase's one
-            # flush carries whatever a pending tick's phase above held
-            # back with it — one slice per peer per tick, as the peers'
-            # inbox accumulators drain them.
-            self._pending = None
-            self._host_phase(ctx)
-            m["ticks_settled"] += 1
-        else:
-            # Eager leader sends: THIS tick's AE/heartbeat frames
-            # leave now, ahead of this tick's own fsync (which
-            # runs next tick).  Safe because commit counts our
-            # self-match only up to the fsynced durable tail
-            # (HostInbox.durable_tail); AE-responses, votes and
-            # client futures stay strictly behind the fsync in
-            # the deferred host phase.
-            st.enter("eager_send")
-            self._eager_send(ctx)
-            self._flush_sends()
+        self._fetch(ctx)
+        self._host_phase(ctx)
         # tick_latency_s ends here, where it always has; the tail below
         # (admission, txn, span harvest, health) is the stage after it.
         m.observe("tick_latency_s", st.enter("tail") - _tick_t0)
-        if self._host_runs:
-            self._host_costs.append(
-                st.total(*HOST_STAGES) / self._host_runs)
         m.observe("tick_stage_dispatch_s", st.total(
             "dispatch_intake", "dispatch_upload", "dispatch_enqueue"))
         m.observe("tick_stage_scan_wait_s",
@@ -2042,14 +1905,6 @@ class RaftNode:
             changes, self._lifecycle = self._lifecycle, []
         with self._snap_lock:
             fetched, self._snap_fetched = self._snap_fetched, []
-        if (changes or fetched) and self._pending is not None:
-            # Pipeline barrier: purges and snapshot installs move the WAL
-            # floor / wipe lanes, which is only sound once every device-
-            # computed append is persisted (the serial invariant).  Both
-            # are rare catch-up/admin events; one overlap window is lost.
-            prev, self._pending = self._pending, None
-            self._host_phase(prev)
-            self._stages.enter("dispatch_intake")
         if changes or fetched:
             # Lanes open, close or are wiped, and an installed snapshot
             # moves a durable tail, a floor and the apply frontier: the
@@ -2095,19 +1950,14 @@ class RaftNode:
         # -- 1. host inbox ---------------------------------------------------
         # A shape that takes the column step (core/step.py column_layouts)
         # keeps no fresh [G] planes: its intake is _dispatch_rows'.
-        lay = column_layouts(
-            cfg, self.pipeline or self._acked_tail is not None)
+        lay = column_layouts(cfg, True)
         if lay is not None:
             return self._dispatch_rows(lay, arrival, started, fetched)
         with self._submit_lock:
             # One vector op over the entry-count mirror — the dict walk
-            # was O(groups-with-queues) per tick.  Offers already riding
-            # the pending (un-persisted) tick are subtracted: the device
-            # must never be offered the same queued entry twice, or the
-            # two accepts would outrun the host queues.
+            # was O(groups-with-queues) per tick.
             submit_n = np.minimum(
-                np.maximum(self._queued_n - self._inflight_submit, 0),
-                cfg.max_submit).astype(np.int32)
+                self._queued_n, cfg.max_submit).astype(np.int32)
         read_n = np.zeros(G, np.int32)
         for g, n in self._offer_reads():
             read_n[g] = n
@@ -2134,21 +1984,15 @@ class RaftNode:
                 conf_learners[g] = ent[1]
             for g, ent in self._xfer_pending.items():
                 xfer_target[g] = ent[0]
-        # Durability feedback (pipelined mode): the fsynced tail per
-        # group — every completed host phase ends with its fsync barrier,
-        # so the mirror is durable by construction at dispatch time.  The
-        # scan clamps its own commit-quorum match to it (core/step.py
-        # phase 10), making ack-after-fsync a kernel invariant rather
-        # than a host-ordering convention.
-        durable = None
-        if self.pipeline or self._acked_tail is not None:
-            # Serial mode normally needs no clamp (the barrier strictly
-            # precedes the next dispatch) — but after a FAILED barrier
-            # the staged mirror is ahead of disk, so the confirmed-tail
-            # clamp (_acked_tail) is fed in serial mode too.
-            src = self._durable_tail_m if self._acked_tail is None \
-                else self._acked_tail
-            durable = np.minimum(src, I32_SAFE_MAX).astype(np.int32)
+        # Durability feedback: the fsynced tail per group.  The scan
+        # clamps its own commit-quorum match to it (core/step.py phase
+        # 10).  Every completed host phase ends with its fsync barrier, so
+        # the mirror is durable by construction at dispatch time; after a
+        # FAILED barrier the staged mirror is ahead of the disk, and the
+        # confirmed tail (_acked_tail) is fed instead.
+        src = self._durable_tail_m if self._acked_tail is None \
+            else self._acked_tail
+        durable = np.minimum(src, I32_SAFE_MAX).astype(np.int32)
         compact_to = self._compact_grant.astype(np.int32)
         self._compact_grant = np.zeros(G, np.int64)
         wake, wake_ids, asked = None, self._wake_ids, None
@@ -2167,9 +2011,8 @@ class RaftNode:
         # host planes and the drained slices' messages as zeroed dense
         # planes that are views of the packed buffers, filled where they
         # cross to the device from.  ctx.arrays reads them (DenseView)
-        # until this tick's host phase is done with it (in an overlapped
-        # tick: through the next dispatch, which fills its own).
-        inputs, readback = step_layouts(cfg, durable is not None)
+        # until this tick's host phase is done with it.
+        inputs, readback = step_layouts(cfg, True)
         batches, staged_payloads = self.acc.pop()
         buffers = inputs.alloc()
         host, inbox = inputs.unpack(buffers)
@@ -2212,10 +2055,7 @@ class RaftNode:
         ctx.staged_payloads, ctx.arrays = staged_payloads, arrays
         ctx.packed, ctx.readback = packed, readback
         ctx.columns = ctx.out_dense = ctx.rows = None
-        ctx.deferred_ae = None
         ctx.wake_ids, ctx.asked_ids, ctx.woke_ids = wake_ids, asked, None
-        self._inflight_submit = self._inflight_submit + submit_n
-        self._inflight_read = self._inflight_read + read_n
         return ctx
 
     def _dispatch_rows(self, lay, arrival: bool, started: float,
@@ -2234,15 +2074,9 @@ class RaftNode:
         rl = lay.rows_in
         # -- 1. host inbox, as (lanes, values) --------------------------------
         with self._submit_lock:
-            # Offers already riding the pending (un-persisted) tick are
-            # subtracted: the device must never be offered the same
-            # queued entry twice (see _dispatch).
             sub_ids = np.flatnonzero(self._queued_n)
             sub_n = np.minimum(
-                self._queued_n[sub_ids] - self._inflight_submit[sub_ids],
-                cfg.max_submit).astype(np.int32)
-        keep = sub_n > 0
-        sub_ids, sub_n = sub_ids[keep], sub_n[keep]
+                self._queued_n[sub_ids], cfg.max_submit).astype(np.int32)
         offers = self._offer_reads()
         read_ids = np.fromiter((g for g, _ in offers), np.int64, len(offers))
         read_n = np.fromiter((n for _, n in offers), np.int32, len(offers))
@@ -2260,17 +2094,11 @@ class RaftNode:
         # plane moved since it was last told (_dur_sent is what the
         # device holds, lane for lane: the commit clamp of phase 10
         # reads it).
-        durable = None
+        durable = self._durable_tail_m if self._acked_tail is None \
+            else self._acked_tail
         dur_ids = sub_ids[:0]
-        if "durable_tail" in rl.at:
-            durable = self._durable_tail_m if self._acked_tail is None \
-                else self._acked_tail
-            if self._dur_sent is not None:
-                dur_ids = np.flatnonzero(durable != self._dur_sent)
-        else:
-            # A serial node between failed barriers feeds no tail and the
-            # device keeps none: the next one it is fed goes up whole.
-            self._dur_sent = None
+        if self._dur_sent is not None:
+            dur_ids = np.flatnonzero(durable != self._dur_sent)
         fields = [
             ("submit_n", sub_ids, sub_n), ("read_n", read_ids, read_n),
             ("compact_to", grant_ids, grants)]
@@ -2300,7 +2128,7 @@ class RaftNode:
         # The count decides (a storm moves every lane's durable tail: no
         # sort of 100,000 lanes to find that out).
         said = [dur_ids] + [at for _, at, _ in fields]
-        whole = (durable is not None and self._dur_sent is None) \
+        whole = self._dur_sent is None \
             or max(len(at) for at in said) > rl.K
         if not whole:
             ids = np.unique(np.concatenate(said))
@@ -2345,11 +2173,9 @@ class RaftNode:
             view.set_n(-1)
             for name, at, vals in fields:
                 getattr(host, name)[at] = vals
-            if durable is not None:
-                np.copyto(host.durable_tail,
-                          np.minimum(durable, I32_SAFE_MAX),
-                          casting="unsafe")
-                self._dur_sent = durable.copy()
+            np.copyto(host.durable_tail, np.minimum(durable, I32_SAFE_MAX),
+                      casting="unsafe")
+            self._dur_sent = durable.copy()
         else:
             n = ids.size
             view.set_n(n)
@@ -2357,11 +2183,10 @@ class RaftNode:
             view.field("xfer_target")[:n] = NIL
             for name, at, vals in fields:
                 view.field(name)[np.searchsorted(ids, at)] = vals
-            if durable is not None:
-                # Every row says its lane's level, moved or not.
-                view.field("durable_tail")[:n] = np.minimum(
-                    durable[ids], I32_SAFE_MAX)
-                self._dur_sent[dur_ids] = durable[dur_ids]
+            # Every row says its lane's level, moved or not.
+            view.field("durable_tail")[:n] = np.minimum(
+                durable[ids], I32_SAFE_MAX)
+            self._dur_sent[dur_ids] = durable[dur_ids]
         buffers += (up,)
 
         # -- 2b. upload ------------------------------------------------------
@@ -2387,8 +2212,6 @@ class RaftNode:
         st.enter("dispatch_enqueue")
         if self._carry is None:
             self._carry = first_carry(lay)
-        elif durable is None and self._carry.durable is not None:
-            self._carry = self._carry._replace(durable=None)
         last = self._carry
         self.state, self._carry, out, out_dense = node_step_columns(
             cfg, lay, columns_in is not None, self.state, last, packed)
@@ -2405,14 +2228,11 @@ class RaftNode:
         ctx.wake_ids, ctx.asked_ids, ctx.woke_ids = wake_ids, asked, None
         ctx.submit_n[sub_ids] = sub_n
         ctx.read_n[read_ids] = read_n
-        self._inflight_submit[sub_ids] += sub_n
-        self._inflight_read[read_ids] += read_n
         ctx.timer = not arrival
         ctx.started = started
         ctx.staged_payloads, ctx.arrays = staged_payloads, arrays
         ctx.packed, ctx.readback = (back,), lay.back
         ctx.columns, ctx.out_dense = lay, out_dense
-        ctx.deferred_ae = None
         return ctx
 
     def _resident_zero(self, lay) -> tuple:
@@ -2435,17 +2255,13 @@ class RaftNode:
         # that linearizable: only batches WAITING at this instant are
         # merged, so every merged read was invoked before the step that
         # stamps its ReadIndex is even dispatched; a read that arrives
-        # while an offer exists (offered, or riding the pending tick)
-        # waits for the next slot and never joins a stamped offer.  An
-        # unstamped offer (no free device slot / not leader yet) simply
-        # stays offered, as it is, and is re-offered next tick.  An offer
-        # riding the pending tick is masked out until that tick's harvest
-        # (an offer must reach the device exactly once per stamp attempt).
-        offers = []
+        # while an offer exists waits for the next slot and never joins a
+        # stamped offer.  An unstamped offer (no free device slot / not
+        # leader yet) simply stays offered, as it is, and is re-offered
+        # next tick.
         with self._read_lock:
             for g, q in self._reads_waiting.items():
-                if q and g not in self._reads_offered \
-                        and not self._inflight_read[g]:
+                if q and g not in self._reads_offered:
                     offer = _ReadOffer(list(q))
                     q.clear()
                     self._read_queued_n[g] -= offer.n
@@ -2456,10 +2272,7 @@ class RaftNode:
                             # offered was the wait for it
                             # (lat_read_queue_s).
                             b.sink.span.mark(OFFERED)
-            for g, offer in self._reads_offered.items():
-                if not self._inflight_read[g]:
-                    offers.append((g, offer.n))
-        return offers
+            return [(g, offer.n) for g, offer in self._reads_offered.items()]
 
     def _hold_read_veto(self, arrival: bool) -> bool:
         """Tick thread, at a step's intake: ``HostInbox.read_veto``."""
@@ -2602,11 +2415,9 @@ class RaftNode:
     # --------------------------------------------------------- tick: fetch
 
     def _fetch(self, ctx: _TickCtx) -> None:
-        """Pull the dispatched scan's results to the host (the pipeline's
-        only blocking point) and refresh the per-tick mirrors.  After an
-        overlapped tick this runs AFTER that tick's host phase, so the
-        wait here is whatever device time the host work did not cover;
-        after a settled tick it is the whole step."""
+        """Pull the dispatched scan's results to the host (the tick's one
+        wait for the device: the whole step) and refresh the per-tick
+        mirrors."""
         st = self._stages
         # The wait is split where the work happens: scan_device is the
         # device's remaining work on this tick's step, scan_fetch the
@@ -3085,20 +2896,13 @@ class RaftNode:
 
     # ---------------------------------------------------- tick: host phase
 
-    def _host_phase(self, ctx: _TickCtx, defer_send: bool = False) -> None:
-        """One fetched tick's host work: WAL staging, THE fsync barrier,
-        outbox release, applies + future completion, read serving,
-        maintenance.  Everything that acknowledges the tick runs here,
-        strictly after its barrier — the phase of an overlapped tick
-        runs under the next tick's device scan, that of a settled tick
-        right behind its own fetch.
-
-        ``defer_send``: pack the outbox but HOLD the per-peer sections in
-        ``_held_sections`` instead of flushing frames — a pipelined
-        tick() flushes exactly once per wall tick, after the eager AE
-        pack (overlapped) or inside its own host phase (settled), so
-        each peer receives ONE combined slice per tick (the inbox
-        accumulator drains one slice per source per tick).
+    def _host_phase(self, ctx: _TickCtx) -> None:
+        """One fetched tick's host work, right behind its fetch: WAL
+        staging, THE fsync barrier, outbox release (one flush: each peer
+        receives ONE slice per tick, as its inbox accumulator drains
+        them), applies + future completion, read serving, maintenance.
+        Everything that acknowledges the tick, and everything it sends,
+        runs here, strictly after its barrier.
 
         Only the persist step varies (``_persist``: the native WAL
         engine's one call, or the Python engine's stage and barrier);
@@ -3121,7 +2925,6 @@ class RaftNode:
         ids = self._host_lanes(ctx)
         pre_tail = (ids, self._durable_tail_m.copy() if ids is None
                     else self._durable_tail_m[ids])
-        self._host_runs += 1
         G = self.cfg.n_groups
         st = self._stages
         m = self.metrics
@@ -3161,11 +2964,10 @@ class RaftNode:
 
                 # -- 5. release outbox (only ever after the barrier) ---------
                 held, sent = self._stash_outbox_sections(
-                    ctx.outbox, deferred=ctx.deferred_ae, blob_fn=blob_fn)
+                    ctx.outbox, blob_fn=blob_fn)
                 for p, secs in held.items():
                     self._held_sections.setdefault(p, []).extend(secs)
-                if not defer_send:
-                    self._flush_sends()
+                self._flush_sends()
                 st.note(lanes=sent)
                 st.enter("apply")
                 if self._lat_tick:
@@ -3229,16 +3031,9 @@ class RaftNode:
             except (WalNoSpace, WalSyncError) as e:
                 self._storage_fault(e, *pre_tail)
         finally:
-            # This tick's offers are settled even on failure: leaking the
-            # inflight counts would mask those groups from every future
-            # dispatch (queued commands never re-offered, futures hung).
-            # A mid-persist failure can instead re-offer an entry the
-            # device already accepted — a client-retry-style duplicate,
-            # strictly better than permanent starvation.
-            if ctx.rows is None:
-                self._inflight_submit = self._inflight_submit - ctx.submit_n
-                self._inflight_read = self._inflight_read - ctx.read_n
-            else:
+            # A column step's planes are cleared even on failure: the next
+            # step must find them zero.
+            if ctx.rows is not None:
                 self._rows_done(ctx)
 
     def _host_lanes(self, ctx: _TickCtx) -> Optional[np.ndarray]:
@@ -3297,13 +3092,11 @@ class RaftNode:
         self._stages.note(scanned=n)
 
     def _rows_done(self, ctx: _TickCtx) -> None:
-        """A column step's host phase is over: settle its offers lane by
-        lane and clear what the step wrote into the node's persistent
-        planes at the lanes it wrote (its offers; the events of the rows
-        that came down), so that the next step finds zero planes."""
+        """A column step's host phase is over: clear what the step wrote
+        into the node's persistent planes at the lanes it wrote (its
+        offers; the events of the rows that came down), so that the next
+        step finds zero planes."""
         step = ctx.rows
-        self._inflight_submit[step.sub_ids] -= step.sub_n
-        self._inflight_read[step.read_ids] -= step.read_n
         ctx.submit_n[step.sub_ids] = 0
         ctx.read_n[step.read_ids] = 0
         self._up_planes.append(step.up)
@@ -3712,14 +3505,7 @@ class RaftNode:
                 # the HEAD while the queue is still FIFO (pre-engage
                 # transient) and at the TAIL once LIFO kicks in, so
                 # check both ends.  Only untouched batches (taken == 0)
-                # are expirable; never entries the device accepted —
-                # nor, in an overlapped tick, entries it may yet accept: the
-                # tick dispatched after this one already carries offers
-                # against this queue, and the device accepts by COUNT,
-                # so the queue must keep at least that many entries.
-                if adm_expire is not None:
-                    riding = self._inflight_submit - submit_n
-                    queued = self._queued_n
+                # are expirable; never entries the device accepted.
                 for g, q in self._submissions.items():
                     if not q:
                         continue
@@ -3728,12 +3514,10 @@ class RaftNode:
                         adm_oldest = t0
                     if adm_expire is not None:
                         while q and q[0].taken == 0 \
-                                and adm_now - q[0].t_enq > adm_expire \
-                                and queued[g] - len(q[0].run) >= riding[g]:
+                                and adm_now - q[0].t_enq > adm_expire:
                             self._expire_batch(g, q.popleft(), expired)
                         while q and q[-1].taken == 0 \
-                                and adm_now - q[-1].t_enq > adm_expire \
-                                and queued[g] - len(q[-1].run) >= riding[g]:
+                                and adm_now - q[-1].t_enq > adm_expire:
                             self._expire_batch(g, q.pop(), expired)
             if adm_oldest is not None:
                 self._adm_delay = adm_now - adm_oldest
@@ -4593,18 +4377,13 @@ class RaftNode:
     # ------------------------------------------------------------------ send
 
     def _stash_outbox_sections(self, h_out,
-                               deferred: Optional[Dict[int, np.ndarray]]
-                               = None,
                                blob_fn: Optional[Callable] = None
                                ) -> Tuple[Dict[int, List[bytes]], int]:
         """Pack one tick's outbox into per-peer kind
         sections and return {peer: [sections]} and the message columns
         (lane by kind by peer) packed — the caller folds into
         ``_held_sections``; ``_flush_sends`` assembles each peer's
-        sections into ONE MSGS frame.  ``deferred`` replaces the
-        valid-column scan for the eager kinds: only the AE columns the
-        eager pack dropped (payloads not yet staged) are packed here —
-        the rest of the AE traffic already left right after fetch."""
+        sections into ONE MSGS frame."""
         P = self.cfg.n_peers
         # Quarantine silence: no frame for a poisoned stripe's groups
         # ever leaves (their staged ranges may not be durable here — a
@@ -4622,22 +4401,13 @@ class RaftNode:
             healthy = None if mask is None else h_out.over(p, mask)
             secs: List[bytes] = []
             for kind in KIND_FIELDS:
-                if deferred is not None and kind in EAGER_KINDS:
-                    cols = deferred.get(p)
-                    if cols is None or not len(cols):
-                        continue
-                    if mask is not None:
-                        cols = cols[mask[cols]]
-                        if not len(cols):
-                            continue
-                else:
-                    valid = h_out.row(KIND_FIELDS[kind][0], p)
-                    if healthy is not None:
-                        valid = valid & healthy
-                    cols = h_out.lanes(p, valid)
-                    if not len(cols):
-                        continue
-                sec, n_cols, _dropped = pack_kind_section(
+                valid = h_out.row(KIND_FIELDS[kind][0], p)
+                if healthy is not None:
+                    valid = valid & healthy
+                cols = h_out.lanes(p, valid)
+                if not len(cols):
+                    continue
+                sec, n_cols = pack_kind_section(
                     kind, fields, win, runs, cols=cols,
                     payload_blob_fn=blob_fn)
                 if n_cols:
@@ -4647,57 +4417,10 @@ class RaftNode:
                 held[p] = secs
         return held, packed
 
-    def _eager_send(self, ctx: _TickCtx) -> None:
-        """Overlapped ticks: pack THIS tick's AE sections right after
-        fetch, ahead of the tick's own fsync (which runs inside next
-        tick's host phase).  Safe for AE only: the commit rule counts
-        our own match at min(log.last, durable_tail) (core/step.py), so
-        an un-fsynced local range can never self-ack into a commit —
-        while AE-responses, votes and client futures stay strictly
-        behind the fsync.  Columns whose payloads are not yet in the
-        store cache (entries accepted this very tick — they stage in the
-        deferred host phase) are recorded in ``ctx.deferred_ae`` and
-        packed there instead."""
-        if self._healthy_groups is not None:
-            # Quarantine active: route ALL AE through the deferred host
-            # phase, whose packing masks the poisoned stripes' groups
-            # (eager frames must never carry their un-durable ranges).
-            ctx.deferred_ae = None
-            return
-        P = self.cfg.n_peers
-        out = ctx.outbox
-        win = self.store.payloads_window
-        runs = getattr(self.store, "payload_runs", None)
-        deferred: Dict[int, np.ndarray] = {}
-        n_eager = 0
-        for p in range(P):
-            if p == self.node_id:
-                continue
-            fields = out.fields(p)
-            for kind in EAGER_KINDS:
-                cols = out.lanes(p, out.row(KIND_FIELDS[kind][0], p))
-                if not len(cols):
-                    continue
-                sec, n_cols, dropped = pack_kind_section(
-                    kind, fields, win, runs, cols=cols)
-                if n_cols:
-                    self._held_sections.setdefault(p, []).append(sec)
-                    n_eager += n_cols
-                if len(dropped):
-                    deferred[p] = dropped
-        ctx.deferred_ae = deferred
-        if n_eager:
-            self.metrics["eager_sends"] += n_eager
-
     def _flush_sends(self) -> None:
         """Assemble every peer's held sections into ONE MSGS frame and
-        release it.  The single per-tick flush point: in an overlapped
-        tick a peer's frame combines the previous tick's post-fsync
-        sections with this tick's eager AE sections; in the tick that
-        goes from overlapping to settling, the pending tick's post-fsync
-        sections with this tick's own (the newer last either way — for a
-        lane duplicated across sections, unpack's scatter is last-wins,
-        so the newer message stands).
+        release it.  The single per-tick flush point, behind the tick's
+        fsync barrier.
 
         Hop-tracing sideband: pending HOPS requests/echoes piggyback on
         the same send_slice blob (FrameReader parses concatenated
@@ -5113,10 +4836,10 @@ class RaftNode:
         incarnation's pending, nor cancel its in-flight marker."""
         # A file of its own for every download: a lane leaves
         # _snap_inflight when its download is done, the device goes on
-        # asking until the snapshot is INSTALLED (the next dispatch), and a
-        # pipelined node's pending host phase, run as that dispatch's
-        # barrier, then starts a second download beside the tick thread
-        # that is archiving the first.  Under one name the second
+        # asking until the snapshot is INSTALLED (the next dispatch), and
+        # the host phase of a tick whose dispatch the download's end just
+        # missed then starts a second download, which runs beside the tick
+        # thread that is archiving the first.  Under one name the second
         # truncated what the first install was reading (an empty snapshot
         # installed at the right index: tests/test_host_rows.py's
         # snapshot_install twins, one run in ten under load).

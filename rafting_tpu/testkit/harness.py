@@ -92,7 +92,6 @@ class LocalCluster:
                  store_factory: Optional[Callable[[int], object]] = None,
                  serializer_factory: Optional[Callable[[], object]] = None,
                  transport: str = "loopback",
-                 pipeline: Optional[bool] = None,
                  wal_shards: Optional[int] = None,
                  host_workers: Optional[int] = None):
         """``provider_factory(node_id)`` returns a MachineProvider; defaults
@@ -110,14 +109,13 @@ class LocalCluster:
         reader-thread / accumulator plane is exercised under the same
         manual-tick control (the reference's system test runs real TCP,
         test/resources/raft1.xml:3-7).
-        ``pipeline`` / ``wal_shards`` / ``host_workers``: forwarded to
+        ``wal_shards`` / ``host_workers``: forwarded to
         every RaftNode (see RaftNode.__init__; None = the node's
         defaults)."""
         self.cfg = cfg
         self.root = root
         self.seed = seed
         self.transport = transport
-        self.pipeline = pipeline
         self.wal_shards = wal_shards
         self.host_workers = host_workers
         self.net = LoopbackNetwork(cfg.n_peers)
@@ -174,7 +172,6 @@ class LocalCluster:
             store=(self.store_factory(i) if self.store_factory else None),
             serializer=(self.serializer_factory()
                         if self.serializer_factory else None),
-            pipeline=self.pipeline,
             wal_shards=self.wal_shards,
             host_workers=self.host_workers)
         node.transport.start()

@@ -1018,8 +1018,12 @@ def oracle_step(cfg: EngineConfig, state: RaftState, inbox: Messages,
         # ---- 10. commit advance -------------------------------------------
         # (reference Leadership.majorIndices:116-130 + the own-term rule,
         # Leader.tryCommit:256-261.)
+        # The own-match durability gate (HostInbox.durable_tail, fed by
+        # every runtime node): the self column counts only the fsynced
+        # prefix.  None (the fused-scan paths) keeps self = log.last.
         full = match_idx[g].copy()
-        full[me] = log.last
+        full[me] = log.last if "durable_tail" not in h \
+            else min(log.last, int(h["durable_tail"][g]))
 
         def _stat(mask: int) -> int:
             # ops/quorum.masked_order_stat, scalar: non-members sort as

@@ -438,44 +438,30 @@ def unpack_snap_hdr(body: bytes) -> Tuple[int, int, int, bool, int]:
 
 
 
-# Kinds a leader may release BEFORE its tick's fsync in pipelined mode:
-# AppendEntries (incl. heartbeats) only.  Safe because the commit rule
-# counts a leader's own match at min(log.last, durable_tail) (core/step.py
-# HostInbox.durable_tail clamp) — an un-fsynced local range can never be
-# counted toward a majority.  Everything vote- or ack-bearing (rv/rvr, aer,
-# is/isr, tn) reflects state that must be durable before it is announced
-# and stays strictly behind the fsync barrier.
-EAGER_KINDS = ("ae",)
-
-_EMPTY_COLS = np.zeros(0, np.uint32)
-
-
 def pack_kind_section(kind: str, fields: Dict[str, np.ndarray],
                       payload_window_fn: Optional[Callable[[int, int, int],
                                                            list]] = None,
                       payload_runs_fn: Optional[Callable] = None,
                       cols: Optional[np.ndarray] = None,
                       payload_blob_fn: Optional[Callable] = None
-                      ) -> Tuple[bytes, int, np.ndarray]:
+                      ) -> Tuple[bytes, int]:
     """Pack ONE kind's wire section (the ``<BI>`` kind header + columns +
     field planes [+ ae payload blob]) for the given column ids.
 
     ``cols`` defaults to every valid column; a packer of a subset (the
-    deferred AE columns, the healthy groups under a quarantine) passes
-    its own, and the per-peer sections concatenate via
-    :func:`assemble_slice` (``unpack_slice`` accumulates repeated
-    kinds).  Returns ``(section, n_cols, dropped)``:
-    ``dropped`` lists the ``ae`` columns whose payloads were unavailable —
-    an eager (pre-persist) packer defers them to the host phase, where the
-    entries are staged; the serial pack path treats a drop as network loss
-    (the engine's resend/timeout recovers).  Other kinds never drop.
+    healthy groups under a quarantine) passes its own, and the per-peer
+    sections concatenate via :func:`assemble_slice` (``unpack_slice``
+    accumulates repeated kinds).  Returns ``(section, n_cols)``.  An
+    ``ae`` column whose payloads are unavailable is dropped, which is
+    network loss to the engine (its resend/timeout recovers); other kinds
+    never drop.
 
     ``payload_blob_fn(cols, starts, ns) -> Optional[(ok_mask, blob)]``:
     the native host tier's bulk blob builder — when it returns a result,
     the whole per-column Python resolution loop is skipped and ``blob``
     (byte-identical layout: kept columns' u32 length words, then their
     payloads) lands in the section directly; columns with ``ok`` False
-    are dropped/deferred exactly like a Python-path payload miss.  A
+    are dropped exactly like a Python-path payload miss.  A
     ``None`` return falls back to the Python loop.
     """
     vfield, dfields = kind_fields(kind, fields)
@@ -483,7 +469,6 @@ def pack_kind_section(kind: str, fields: Dict[str, np.ndarray],
         cols = np.nonzero(fields[vfield])[0].astype(np.uint32)
     else:
         cols = np.asarray(cols, np.uint32)
-    dropped = _EMPTY_COLS
     blob_section = b""
     if kind == "ae" and len(cols):
         # Resolve payloads for indices prev_idx+1 .. prev_idx+n per
@@ -499,7 +484,6 @@ def pack_kind_section(kind: str, fields: Dict[str, np.ndarray],
                 cols, prevs.astype(np.int64) + 1, ns.astype(np.uint32))
             if res is not None:
                 ok, blob_section = res
-                dropped = cols[~ok]
                 cols = cols[ok]
                 n_cols = len(cols)
                 parts = [struct.pack("<BI", KIND_IDS[kind], n_cols)]
@@ -509,13 +493,12 @@ def pack_kind_section(kind: str, fields: Dict[str, np.ndarray],
                         parts.append(
                             np.ascontiguousarray(fields[f][cols]).tobytes())
                     parts.append(blob_section)
-                return b"".join(parts), n_cols, dropped
-        keep, drop, pieces, len_parts = [], [], [], []
+                return b"".join(parts), n_cols
+        keep, pieces, len_parts = [], [], []
         for g, prev, n in zip(cols.tolist(), prevs.tolist(), ns.tolist()):
             if n and payload_runs_fn is not None:
                 run = payload_runs_fn(int(g), prev + 1, n)
                 if run is None:
-                    drop.append(g)
                     continue
                 keep.append(g)
                 pieces.extend(run[0])
@@ -525,14 +508,12 @@ def pack_kind_section(kind: str, fields: Dict[str, np.ndarray],
                    if n and payload_window_fn is not None else
                    [None] * n if n else [])
             if any(p is None for p in win):
-                drop.append(g)
                 continue
             keep.append(g)
             pieces.extend(win)
             len_parts.append(np.fromiter(map(len, win), np.uint32,
                                          len(win)))
         cols = np.asarray(keep, np.uint32)
-        dropped = np.asarray(drop, np.uint32)
         lens = (np.concatenate(len_parts) if len_parts
                 else np.zeros(0, np.uint32))
         blob_section = lens.tobytes() + b"".join(pieces)
@@ -543,16 +524,16 @@ def pack_kind_section(kind: str, fields: Dict[str, np.ndarray],
         for f in dfields:
             parts.append(np.ascontiguousarray(fields[f][cols]).tobytes())
         parts.append(blob_section)
-    return b"".join(parts), n_cols, dropped
+    return b"".join(parts), n_cols
 
 
 def assemble_slice(src: int, sections: List[bytes]) -> bytes:
     """Concatenate independently packed kind sections into ONE MSGS frame.
 
     One frame per (src, peer) per tick is a delivery invariant: the inbox
-    accumulator drains one slice per source per tick, so per-stripe or
-    eager/deferred sections must merge here rather than travel as separate
-    frames (which would add a tick of latency each and grow the backlog).
+    accumulator drains one slice per source per tick, so a peer's sections
+    must merge here rather than travel as separate frames (which would add
+    a tick of latency each and grow the backlog).
     Sections may repeat a kind — ``unpack_slice`` concatenates them, and
     the dense scatter is last-wins in section order for any duplicated
     (kind, group) lane."""
@@ -594,7 +575,7 @@ def pack_slice(src: int, fields: Dict[str, np.ndarray],
     sections: List[bytes] = []
     n_total = 0
     for kind in KIND_FIELDS:
-        sec, n_cols, _dropped = pack_kind_section(
+        sec, n_cols = pack_kind_section(
             kind, fields, payload_window_fn, payload_runs_fn)
         sections.append(sec)
         n_total += n_cols
@@ -619,9 +600,8 @@ def unpack_slice(body: bytes, template: Dict[str, Tuple[np.dtype, tuple]],
     materialize).  ``n_groups`` bounds-checks column ids so a corrupt or
     shape-mismatched frame can't scatter out of range.
 
-    A kind may appear in SEVERAL sections (the eager/deferred AE split
-    contributes one each per frame —
-    :func:`assemble_slice`): their columns CONCATENATE in section order,
+    A kind may appear in SEVERAL sections (:func:`assemble_slice`):
+    their columns CONCATENATE in section order,
     so the consumer's dense scatter is last-wins for a duplicated
     (kind, group) lane, and a later section's payload run replaces an
     earlier one for the same group.

@@ -50,3 +50,18 @@ def small(monkeypatch):
     yield shrink
     monkeypatch.undo()
     clear()
+
+
+@pytest.fixture
+def take_shape(small):
+    """``take_shape(cfg, shape)`` for a test with an axis ``["packed",
+    "columns"]``: ``columns`` makes ``cfg``'s nodes take the column and row
+    step (``small()``), ``packed`` leaves them the packed one; either way
+    the shape rule is asked whether it agrees."""
+    from rafting_tpu.core.step import column_layouts
+
+    def take(cfg, shape):
+        if shape == "columns":
+            small()
+        assert (column_layouts(cfg, True) is not None) == (shape == "columns")
+    return take

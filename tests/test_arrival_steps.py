@@ -14,7 +14,7 @@ import pytest
 
 from rafting_tpu.core.types import EngineConfig
 from rafting_tpu.machine.kv_machine import KVMachineProvider
-from rafting_tpu.runtime.node import arrival_step_at, settles_now
+from rafting_tpu.runtime.node import SETTLE_MARGIN, arrival_step_at
 from rafting_tpu.testkit.harness import LocalCluster
 
 
@@ -296,10 +296,16 @@ READINGS = [(now, 10.0, took, due, cost)
 WAITS = (0.0, 0.001, 0.0067, 0.0145, 0.1, 1.0)
 
 
+def _room(at, due, cost):
+    """The room rule by itself: a whole step fits SETTLE_MARGIN times
+    between ``at`` and the timer."""
+    return at + SETTLE_MARGIN * cost <= due
+
+
 def _parent(now, ended, took, due, cost):
     """The rule as it stood: the gap is the last step's whole duration."""
     at = max(now, ended + took)
-    return at if settles_now(at, due, cost) else None
+    return at if _room(at, due, cost) else None
 
 
 def _waited_0_is_the_parents_instant(r):
@@ -327,7 +333,7 @@ def _all_of_it_waited_starts_now(r):
     now, _, took, due, cost = r
     for w in (took, took + 0.5):
         at = arrival_step_at(*r, w)
-        assert at == (now if settles_now(now, due, cost) else None)
+        assert at == (now if _room(now, due, cost) else None)
 
 
 def _the_room_rule_is_untouched(r):
@@ -337,10 +343,10 @@ def _the_room_rule_is_untouched(r):
         # No room at ``now`` is no room, however short the gap; and a step
         # that is let start has room for the WHOLE step's cost, waits
         # and all: no arrival step makes a timer tick late.
-        if not settles_now(now, due, cost):
+        if not _room(now, due, cost):
             assert at is None
         if at is not None:
-            assert settles_now(at, due, cost)
+            assert _room(at, due, cost)
 
 
 @pytest.mark.parametrize("holds", [

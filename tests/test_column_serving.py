@@ -122,8 +122,7 @@ def assert_counters_match_spans(node, notes, maybe=()):
     assert sum(s["columns"] for s in down) > 0
     assert sum(s["rows"] for s in up) > 0
     assert sum(s["rows"] for s in down) > 0
-    lay = column_layouts(node.cfg, node.pipeline
-                         or node._acked_tail is not None)
+    lay = column_layouts(node.cfg, True)
     # Up: ONE buffer, the rows and behind them the messages' columns, or
     # the rows alone beside the dense operand (whose buffers hold
     # HostInbox's planes too); HostInbox's planes beside the one buffer
@@ -157,8 +156,8 @@ def oracle_checked_columns(monkeypatch):
     """Cross-check every runtime node_step_columns call against the scalar
     oracle, whichever way the messages went in: the oracle steps what the
     upload buffers stand for, and state, dense outbox and StepInfo must be
-    the oracle's (oracle FIRST: the step donates its state).  Serial mode
-    only: the oracle has no durable_tail lane."""
+    the oracle's (oracle FIRST: the step donates its state), the
+    ``durable_tail`` clamp every node feeds included."""
     real = node_mod.node_step_columns
     calls = {True: 0, False: 0}
 
@@ -172,7 +171,10 @@ def oracle_checked_columns(monkeypatch):
             host, inbox = lay.inputs.unpack(bufs)
         # The HostInbox the rows stand for, over the planes they ride
         # beside (the resident zero planes, or the planes whole).
-        host, _ = _host_from_rows(lay.rows_in, host, rows, None)
+        host, _ = _host_from_rows(
+            lay.rows_in, *jax.tree.map(jnp.asarray, (host, rows)),
+            carry.durable)
+        assert host.durable_tail is not None
         host, inbox = jax.tree.map(jnp.asarray, (host, inbox))
         o_state, o_out, o_info = oracle_step(cfg, state, inbox, host)
         out = real(cfg, lay, columns_in, state, carry, buffers)
@@ -189,14 +191,20 @@ def oracle_checked_columns(monkeypatch):
     return calls
 
 
+# Reads by the lease, and by strict ReadIndex (read_lease off: the step
+# and the layouts gain read_seq / ae_seq / aer_seq, PR 45).
+READS = [pytest.param(True, id="lease"), pytest.param(False, id="strict")]
+
+
+@pytest.mark.parametrize("lease", READS)
 def test_column_steps_match_the_oracle_through_election_rounds_and_traffic(
-        tmp_path, small_columns, oracle_checked_columns, noted):
+        tmp_path, small_columns, oracle_checked_columns, noted, lease):
     cfg = EngineConfig(n_groups=16, n_peers=3, log_slots=16, batch=4,
                        max_submit=4, election_ticks=8, heartbeat_ticks=4,
-                       rpc_timeout_ticks=6, pre_vote=True)
-    assert column_layouts(cfg, False) is not None
+                       rpc_timeout_ticks=6, pre_vote=True, read_lease=lease)
+    assert column_layouts(cfg, True) is not None
     c = LocalCluster(cfg, str(tmp_path), provider_factory=NullProvider,
-                     seed=5, pipeline=False)
+                     seed=5)
     try:
         for g in range(cfg.n_groups):       # the election: 16 lanes at once
             assert c.wait_leader(g, max_rounds=200) is not None
@@ -216,10 +224,9 @@ def test_column_steps_match_the_oracle_through_election_rounds_and_traffic(
         assert sum(int(n.h_commit.astype(np.int64).sum())
                    for n in c.nodes.values()) > 0
         for i, n in c.nodes.items():
-            # HostInbox overflows its rows on the node that leads more
-            # than ROWS lanes (a serial node uploads no durable tail).
-            assert_counters_match_spans(n, noted[i],
-                                        maybe=("row_overflows_in",))
+            # HostInbox overflows its rows on every node: the first step
+            # uploads the durable tail whole.
+            assert_counters_match_spans(n, noted[i])
         assert sum(n.metrics["row_overflows_in"]
                    for n in c.nodes.values()) > 0
     finally:
@@ -229,14 +236,14 @@ def test_column_steps_match_the_oracle_through_election_rounds_and_traffic(
 # ------------------------------------------------ (b) served, linearizable ----
 
 
-@pytest.fixture
-def served(tmp_path, small_columns):
+@pytest.fixture(params=READS)
+def served(request, tmp_path, small_columns):
     ports = free_ports(3)
     uris = [f"raft://127.0.0.1:{p}" for p in ports]
     cs = [RaftContainer(RaftConfig(
         local=u, peers=tuple(p for p in uris if p != u), n_groups=16,
         log_slots=32, batch=4, max_submit=4, tick_ms=50, seed=3,
-        data_dir=str(tmp_path / f"node{i}"),
+        read_lease=request.param, data_dir=str(tmp_path / f"node{i}"),
         election_mul=scaled_election_mul(10)), kv_factory()).create()
         for i, u in enumerate(uris)]
     yield cs

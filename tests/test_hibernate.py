@@ -777,7 +777,7 @@ def test_asleep_woken_and_woke_ride_the_spans_that_are_there(tmp_path):
         "raft." + k for k in (
             "dispatch_intake", "dispatch_upload", "dispatch_enqueue", "wal",
             "fsync", "send", "apply", "reads", "maintain", "scan_device",
-            "scan_fetch", "mirrors", "eager_send", "tail", "wait")}
+            "scan_fetch", "mirrors", "tail", "wait")}
     mine = lambda name: [s for n, s in spans
                          if n == name and s["node"] == lead]
     mirrors = mine("raft.mirrors")

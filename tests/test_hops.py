@@ -200,7 +200,7 @@ def test_cluster_hop_reconciliation_serial(tmp_path, monkeypatch):
     keeps pack and flush in the same host phase, so the only slack is
     the intra-tick t_pack→SENT sliver (the WAL stage+fsync)."""
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
-    c = LocalCluster(CFG, str(tmp_path), pipeline=False)
+    c = LocalCluster(CFG, str(tmp_path))
     try:
         c.wait_leader(0)
         for i in range(6):
@@ -250,7 +250,7 @@ def test_hop_blind_receiver_ignores_hops_frames(tmp_path, monkeypatch):
     is strictly additive)."""
     monkeypatch.setenv("RAFT_HOP_TRACE", "0")
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
-    c = LocalCluster(CFG, str(tmp_path), pipeline=False)
+    c = LocalCluster(CFG, str(tmp_path))
     try:
         c.wait_leader(0)
         for n in c.nodes.values():

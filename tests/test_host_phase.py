@@ -2,9 +2,8 @@
 — the native WAL engine's one stage-and-fsync call (log/native/wal.cpp
 wal_stage_and_sync / wal_pack_ae) at thread widths 1, 2 and 4, and the
 Python engine's stage and barrier: tick-for-tick scalar-oracle parity
-under partition + crash + stall nemesis, the eager-send crash window
-(acks/futures must never precede the tick's own fsync even though leader
-AE frames release before it), the crash-in-the-stage-window durability
+under partition + crash + stall nemesis, one step program through a
+failed barrier, the crash-in-the-stage-window durability
 contract, byte-identical WAL segments between the two engines (recovery
 interchangeable in BOTH directions, torn tails included), outcome
 convergence, and which step a node takes: the store decides, a
@@ -17,6 +16,7 @@ what it feeds the device (WAL staging, submission arenas, inbox routing)
 diverges at the exact offending tick — the host phase sits between two
 oracle-checked device steps."""
 
+import errno
 import os
 import shutil
 
@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 
 import rafting_tpu.runtime.node as node_mod
-from rafting_tpu.core.step import step_layouts
+from rafting_tpu.core.step import column_layouts, step_layouts
 from rafting_tpu.core.types import EngineConfig, LEADER, conf_voters_of
 from rafting_tpu.log import wal as wal_mod
 from rafting_tpu.log.store import LogStore, restore_raft_state
@@ -72,8 +72,8 @@ def oracle_checked_step(monkeypatch):
     """Cross-check every runtime node_step_packed call against the scalar
     oracle: the oracle steps what the tick's upload buffers hold, and the
     packed step's readback must unpack to the oracle's outputs (oracle
-    FIRST: the step donates its state buffers).  Serial pipeline mode
-    only — the oracle has no durable_tail lane."""
+    FIRST: the step donates its state buffers), the ``durable_tail`` clamp
+    every node feeds included."""
     real = node_mod.node_step_packed
     calls = {"n": 0}
 
@@ -82,7 +82,8 @@ def oracle_checked_step(monkeypatch):
             jnp.asarray, inputs.unpack(jax.device_get(buffers)))
         o_state, o_out, o_info = oracle_step(cfg, state, inbox, host)
         k_state, packed = real(cfg, inputs, state, buffers)
-        _, readback = step_layouts(cfg, host.durable_tail is not None)
+        assert host.durable_tail is not None
+        _, readback = step_layouts(cfg, True)
         back = readback.unpack(jax.device_get(packed))
         tag = f"oracle-checked step #{calls['n']}"
         assert_state_equal(k_state, o_state, tag)
@@ -118,7 +119,7 @@ def test_oracle_parity_under_nemesis(tmp_path, engine, workers, lease,
         nemesis.clock_stalls(3, 36, rate=0.03, seed=23),
     )
     c = make_cluster(cfg, str(tmp_path), engine, workers,
-                     provider_factory=NullProvider, seed=5, pipeline=False)
+                     provider_factory=NullProvider, seed=5)
     try:
         assert all(n.host_workers == workers for n in c.nodes.values())
 
@@ -153,64 +154,56 @@ def test_oracle_parity_under_nemesis(tmp_path, engine, workers, lease,
 # ------------------------------------------------------- crash windows ----
 
 
-@pytest.mark.parametrize("engine", [engine_param("python"),
-                                    engine_param("native")])
-def test_eager_window_crash_completes_nothing(tmp_path, engine):
-    """Kill a pipelined leader inside the eager-send window — AE/heartbeat
-    frames for tick N already left the node, tick N+1 may be dispatched,
-    but tick N's fsync has NOT run.  No submit future may have completed
-    for the un-fsynced range, and WAL recovery from the crash image
-    restores the pre-accept durable tail (commit safety holds because the
-    device clamps self-match to durable_tail, so an eagerly
-    announced-but-lost suffix is merely resent, never counted).  The same
-    whether the fsync is the Python barrier's or the native call's."""
+@pytest.mark.parametrize("shape", ["packed", "columns"])
+def test_a_failed_barrier_keeps_the_one_program(tmp_path, monkeypatch,
+                                                take_shape, shape):
+    """A node compiles ONE step program and keeps it through a failed
+    barrier and its recovery: every step is fed ``durable_tail`` (the
+    fsynced mirror; the confirmed tail ``_acked_tail`` while a barrier
+    stands failed), so the clamp changes what the program is fed, never
+    which program runs.  (A node that fed a tail only while clamped
+    compiled a second program in the middle of a storage fault.)"""
     cfg = EngineConfig(n_groups=4, n_peers=3, log_slots=32, batch=4,
                        max_submit=4, election_ticks=10, heartbeat_ticks=3,
                        rpc_timeout_ticks=8)
-    c = make_cluster(cfg, str(tmp_path), engine, 2, shards=2, pipeline=True)
+    take_shape(cfg, shape)
+    lay = column_layouts(cfg, True)
+    name = "node_step_columns" if lay is not None else "node_step_packed"
+    step = getattr(node_mod, name)
+    keys = set()
+
+    def spy(cfg_, layout, *rest):
+        # The step's static arguments beside cfg: (layout,) of the packed
+        # step, (layout, columns_in) of the column step.
+        keys.add((layout,) + (rest[:1] if lay is not None else ()))
+        return step(cfg_, layout, *rest)
+
+    monkeypatch.setattr(node_mod, name, spy)
+    c = LocalCluster(cfg, str(tmp_path), provider_factory=NullProvider,
+                     seed=5, wal_shards=2)
     try:
         lead = c.wait_leader(0)
-        c.tick(5)
         node = c.nodes[lead]
-        assert node.metrics["eager_sends"] > 0, \
-            "eager-send window never opened — test is vacuous"
-        tail_before = int(node._durable_tail_m[0])
-
-        fut = node.submit_batch(0, [b"eager-%d" % k for k in range(3)])
-        # One lockstep round: the scan accepts the batch and the leader's
-        # eager sender already released this tick's AE frames, but the
-        # batch's host phase (staging + fsync) runs only NEXT tick.
-        c.tick(1)
-        pend = node._pending
-        assert pend is not None
-        acc = int(np.asarray(pend.info.submit_acc)[0])
-        assert acc == 3, f"device should have accepted the batch, got {acc}"
-        start = int(np.asarray(pend.info.submit_start)[0])
-
-        assert not fut.done(), \
-            "submit future completed before the range was fsynced"
-        assert int(node._durable_tail_m[0]) == tail_before
-
-        img = str(tmp_path / "crash-img")
-        shutil.copytree(os.path.join(node.data_dir, "wal"), img)
-        store = LogStore(img)
-        try:
-            assert store.tail(0) == tail_before < start
-            state = restore_raft_state(cfg, lead, store)
-            assert int(np.asarray(state.log.last)[0]) == tail_before
-            for idx in range(start, start + acc):
-                assert store.payload(0, idx) is None
-        finally:
-            store.close()
-
-        # The surviving node drains normally: the future completes only
-        # AFTER its own host phase's fsync.
-        for _ in range(30):
-            c.tick(1)
-            if fut.done():
+        c.tick_until(lambda: node.is_ready(0), what="leader ready")
+        fut = node.submit(0, b"warm")
+        c.tick_until(fut.done, what="warm write")
+        c.tick(10)
+        compiled, before = step._cache_size(), set(keys)
+        node.store.set_fault("write", value=errno.ENOSPC, shard=0)
+        fut = node.submit(0, b"kept-through-enospc")
+        clamped = 0
+        for _ in range(200):
+            c.tick()
+            clamped += node._acked_tail is not None
+            if fut.done() and node._acked_tail is None:
                 break
-        assert fut.done() and len(fut.result(timeout=1)) == 3
-        assert int(node._durable_tail_m[0]) >= start + acc - 1
+        assert clamped and fut.done() and fut.exception() is None
+        c.tick(5)
+        want = lay if lay is not None else step_layouts(cfg, True)[0]
+        assert {k[0] for k in keys} == {want}, "a second layout was stepped"
+        # No compile but for a static variant of the column step first met
+        # after the fault (columns in / dense in).
+        assert step._cache_size() - compiled == len(keys - before)
     finally:
         c.close()
 
@@ -377,16 +370,17 @@ def test_torn_tail_cross_backend_parity(tmp_path):
 
 
 @needs_native
+@pytest.mark.parametrize("shape", ["packed", "columns"])
 @pytest.mark.parametrize("workers", [2, 4])
-def test_native_python_convergence(tmp_path, workers):
+def test_native_python_convergence(tmp_path, take_shape, workers, shape):
     """The native step at width W and the Python step drive the same
     workload to the same applied outcome — the engine and its threads
-    repartition WORK, never effects."""
+    repartition WORK, never effects — whichever step the shape takes."""
+    take_shape(CFG, shape)
     results = {}
     for engine in ("native", "python"):
         c = make_cluster(CFG, str(tmp_path / engine), engine, workers,
-                         provider_factory=NullProvider, seed=3,
-                         pipeline=True)
+                         provider_factory=NullProvider, seed=3)
         try:
             lead = c.wait_leader(0)
             c.tick_until(lambda: c.nodes[lead].is_ready(0),

@@ -25,7 +25,8 @@ from rafting_tpu.machine.dispatch import ApplyDispatcher
 from rafting_tpu.runtime.node import RaftNode
 from rafting_tpu.snapshot.policy import MaintainAgreement
 from rafting_tpu.testkit.fixtures import NullProvider
-from rafting_tpu.testkit.harness import LocalCluster
+from rafting_tpu.log.wal import native_available
+from rafting_tpu.testkit.harness import LocalCluster, wal_store_factory
 
 BASE = dict(n_groups=16, n_peers=3, log_slots=16, batch=4, max_submit=4,
             election_ticks=8, heartbeat_ticks=3, rpc_timeout_ticks=6,
@@ -43,7 +44,7 @@ COUNTERS = (
     "enospc_backpressure")
 PLANES = ("_durable_tail_m", "_wal_floor", "_stable_term_m",
           "_stable_voted_m", "_rel_min", "h_commit", "h_base", "h_term",
-          "h_active", "_inflight_submit", "_inflight_read")
+          "h_active")
 
 
 def recount(node: RaftNode, ctx) -> None:
@@ -82,14 +83,14 @@ class Twins:
     """The cluster as it ships (``rows``) and one whose host phases all
     look at every lane (``whole``), stepped through the same operations."""
 
-    def __init__(self, tmp_path, monkeypatch, pipeline, **cfg):
+    def __init__(self, tmp_path, monkeypatch, wal, **cfg):
         self.cfg = EngineConfig(**{**BASE, **cfg.pop("engine", {})})
         # The two planes that act on the wall clock (health evacuation on
         # a slow fsync, admission shedding on queue delay) would tell the
         # twins apart by chance.
         monkeypatch.setenv("RAFT_HEALTH", "0")
         monkeypatch.setenv("RAFT_ADMISSION", "0")
-        assert column_layouts(self.cfg, bool(pipeline)) is not None
+        assert column_layouts(self.cfg, True) is not None
         roots = {k: str(tmp_path / k) for k in ("rows", "whole")}
         self.seen = {k: {} for k in roots}      # (kind, node) -> [selections]
         self.with_ids = self.phases = 0
@@ -131,9 +132,9 @@ class Twins:
                                            disp.backlog.tolist()))
             return n
 
-        def phase(node, ctx, defer_send=False):
+        def phase(node, ctx):
             by_dispatcher[id(node.dispatcher)] = node
-            real_phase(node, ctx, defer_send)
+            real_phase(node, ctx)
             if node._host_sets_ok:
                 recount(node, ctx)
 
@@ -141,10 +142,12 @@ class Twins:
         monkeypatch.setattr(RaftNode, "_where", where)
         monkeypatch.setattr(RaftNode, "_host_phase", phase)
         monkeypatch.setattr(ApplyDispatcher, "advance", advance)
-        kw = dict(provider_factory=NullProvider, seed=5, pipeline=pipeline,
-                  **cfg)
-        self.rows = LocalCluster(self.cfg, roots["rows"], **kw)
-        self.whole = LocalCluster(self.cfg, roots["whole"], **kw)
+        kw = dict(provider_factory=NullProvider, seed=5, **cfg)
+        self.rows, self.whole = (
+            LocalCluster(self.cfg, roots[k], store_factory=wal_store_factory(
+                roots[k], wal), **kw) for k in ("rows", "whole"))
+        assert all(n.store.can_stage_native == (wal == "native")
+                   for c in self.both for n in c.nodes.values())
         self.roots = roots
 
     @property
@@ -479,15 +482,18 @@ SCENARIOS = {
 }
 
 
-@pytest.mark.parametrize("pipeline", [True, False],
-                         ids=["pipelined", "serial"])
+@pytest.mark.parametrize("wal", ["native", "python"])
 @pytest.mark.parametrize("scenario", list(SCENARIOS))
 def test_selections_over_the_rows_are_the_selections_over_whole_planes(
-        tmp_path, monkeypatch, small, scenario, pipeline):
+        tmp_path, monkeypatch, small, scenario, wal):
+    """Under either persist step (the native engine's one call walks the
+    rows' spans, the Python stage and barrier the same)."""
+    if wal == "native" and not native_available():
+        pytest.skip("no native WAL toolchain")
     run, kw = SCENARIOS[scenario]
     kw = dict(kw)
     small(ROWS, ROWS, **{"columns": 3, **kw.pop("packing", {})})
-    t = Twins(tmp_path, monkeypatch, pipeline, **kw)
+    t = Twins(tmp_path, monkeypatch, wal, **kw)
     try:
         run(t)
         t.tick(10)

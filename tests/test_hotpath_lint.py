@@ -22,7 +22,7 @@ from rafting_tpu.runtime.node import RaftNode
 # boot recovery.  Banned substrings mean "visits every group".
 HOT_METHODS = (
     "_persist_prepare", "_persist_stage", "_sweep_rejections",
-    "_stash_outbox_sections", "_eager_send", "_flush_sends",
+    "_stash_outbox_sections", "_flush_sends",
     "_harvest_reads", "_serve_reads",
     "_host_phase", "_persist", "_persist_stage_native", "_build_spans",
     "_recover_machines",
@@ -64,7 +64,42 @@ def test_one_host_phase_and_no_switch_to_fork_it():
                 assert hit is None, (
                     f"{os.path.join(root, f)} names {hit.group(0)}: the "
                     f"engine is the store's (LogStore(force_python=...)), "
-                    f"the order the node's own (pipeline=, settles_now)")
+                    f"and there is one tick order")
+
+
+def test_one_tick_order_and_nothing_to_choose_another():
+    """A tick is ``_dispatch``, ``_fetch``, ``_host_phase(ctx)``, and
+    nothing of it outlives ``tick()``: no argument builds a node or a
+    test cluster with another order, the node has no second send path and
+    keeps no tick for later, and no source file of the package names a
+    piece of the overlapped order that went (the names are spelled in
+    parts here so that a grep for them finds the program alone)."""
+    from rafting_tpu.testkit.harness import LocalCluster
+
+    for cls in (RaftNode, LocalCluster):
+        assert "pipe" + "line" not in inspect.signature(cls.__init__).parameters
+    assert list(inspect.signature(RaftNode._host_phase).parameters) == [
+        "self", "ctx"]
+    assert not hasattr(RaftNode, "_eager" + "_send")
+    assert not re.search(r"self\._pend" + r"ing\b",
+                         inspect.getsource(RaftNode))
+    tick = inspect.getsource(RaftNode.tick)
+    order = [tick.index(call) for call in (
+        "self._dispatch(", "self._fetch(ctx)", "self._host_phase(ctx)")]
+    assert order == sorted(order)
+    gone = re.compile("|".join((
+        "deferred" + "_ae", "defer" + "_send", "EAGER" + "_KINDS",
+        "settles" + "_now", "_host" + "_costs", "ticks" + "_settled",
+        "eager" + "_sends", "pipeline" + "_enabled")))
+    pkg = os.path.dirname(os.path.dirname(node_mod.__file__))
+    for root, _dirs, files in os.walk(pkg):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(root, f)) as fh:
+                    hit = gone.search(fh.read())
+                assert hit is None, (
+                    f"{os.path.join(root, f)} names {hit.group(0)}: a "
+                    f"piece of the overlapped tick order, which is gone")
 
 
 def test_a_tick_crosses_the_device_boundary_packed():
@@ -95,16 +130,15 @@ def test_a_tick_crosses_the_device_boundary_packed():
 
 def test_send_plane_uses_section_packing():
     """Frames are built per-kind via pack_kind_section + assemble_slice
-    (the stash/eager/deferred split needs per-section control); a revived
-    whole-frame pack_slice call would re-couple eager and deferred
-    sections and break the durability-decoupled send plane."""
+    (the quarantine mask needs per-section control of the columns); a
+    revived whole-frame pack_slice call would pack a poisoned stripe's
+    lanes with the rest."""
     src = inspect.getsource(node_mod)
     assert "pack_slice(" not in src, (
         "runtime/node.py calls pack_slice — pack per-kind sections with "
         "pack_kind_section and frame them with assemble_slice")
-    for name in ("_stash_outbox_sections", "_eager_send"):
-        assert "pack_kind_section" in \
-            inspect.getsource(getattr(RaftNode, name)), name
+    assert "pack_kind_section" in \
+        inspect.getsource(RaftNode._stash_outbox_sections)
 
 
 def test_stub_history_gate_is_single_is_none_test():

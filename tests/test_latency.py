@@ -1,5 +1,5 @@
 """Per-entry latency tracing plane (ISSUE 13): seeded sampler
-determinism, span completeness through the pipelined commit path,
+determinism, span completeness through the commit path,
 crash-in-the-fsync-window outcome-unknown semantics (a crashed span
 never fabricates a latency), the /latency endpoint + exposition
 round-trip, native wal_stats() parity with Python-side timings, and the
@@ -101,20 +101,21 @@ def test_disabled_plane_holds_no_tracer(tmp_path, monkeypatch):
 @pytest.mark.parametrize("engine", [
     "python", pytest.param("native", marks=pytest.mark.skipif(
         not wal_mod.native_available(), reason="native WAL engine unavailable"))])
-@pytest.mark.parametrize("pipeline", [False, True],
-                         ids=["serial", "pipelined"])
+@pytest.mark.parametrize("shape", ["packed", "columns"])
 def test_span_completeness_and_reconciliation(tmp_path, monkeypatch,
-                                              pipeline, engine):
+                                              take_shape, shape, engine):
     """Rate-1 sampling through a live cluster: every acked submit yields
     an outcome-ok span with every write-phase stamp in protocol order,
     and the phase-pair histograms telescope — the sum of per-phase means
     equals the end-to-end mean (the /latency vs /metrics reconciliation
     the acceptance criteria call for).  Under either persist step: the
     Python one stamps ``staged`` before its barrier and ``fsynced``
-    behind it, the native one both at its one call's return."""
+    behind it, the native one both at its one call's return; and
+    whichever step the shape takes."""
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
+    take_shape(CFG, shape)
     c = LocalCluster(
-        CFG, str(tmp_path), pipeline=pipeline,
+        CFG, str(tmp_path),
         store_factory=wal_store_factory(str(tmp_path), engine))
     try:
         assert all(n.store.can_stage_native == (engine == "native")

@@ -117,9 +117,8 @@ def test_windowed_rates_cover_absolute_set_counters():
 def test_host_tier_metrics_on_exposition(tmp_path):
     """The host phase's observability — the host_workers gauge (the
     native WAL engine's effective thread width; 1 under the Python
-    engine), the native_host gauge and the eager_sends counter (rendered
-    with the _total suffix, zero from boot via its counter init) — all
-    appear on /metrics and the page passes the strict validator."""
+    engine) and the native_host gauge — appear on /metrics and the page
+    passes the strict validator."""
     from rafting_tpu.core.types import EngineConfig
     from rafting_tpu.log import native_available
     from rafting_tpu.testkit.harness import LocalCluster
@@ -137,42 +136,39 @@ def test_host_tier_metrics_on_exposition(tmp_path):
         validate_exposition(text)
         assert f"raft_host_workers {2 if native else 1}" in text
         assert f"raft_native_host {int(native)}" in text
-        assert "raft_eager_sends_total" in text
     finally:
         c.close()
 
 
-@pytest.mark.parametrize("pipeline,due,share", [
-    (False, None, 1.0), (True, None, 0.0), (True, float("inf"), 1.0),
-], ids=["serial", "overlapped", "settled"])
-def test_ticks_settled_on_exposition(tmp_path, pipeline, due, share):
-    """ISSUE 25 satellite: how often the tick loop settles a tick in its
-    own period is on /metrics from boot — `ticks_settled` beside `ticks`,
-    both counters — and reads 1 for a serial node, 0 for a pipelined node
-    with no deadline, 1 for a pipelined node whose deadline has room."""
+def test_ticks_on_exposition(tmp_path):
+    """How many steps a node took, and how many of them were started for
+    arriving work, is on /metrics from boot: ``ticks`` and
+    ``ticks_on_arrival``, both counters.  Nothing on the page speaks of a
+    second tick order."""
     from rafting_tpu.core.types import EngineConfig
     from rafting_tpu.testkit.harness import LocalCluster
 
     cfg = EngineConfig(n_groups=4, n_peers=3, log_slots=16, batch=4,
                        max_submit=4, election_ticks=6, heartbeat_ticks=2,
                        rpc_timeout_ticks=5)
-    c = LocalCluster(cfg, str(tmp_path), pipeline=pipeline)
+    c = LocalCluster(cfg, str(tmp_path))
     try:
         node = c.nodes[0]
         boot = node.metrics.render_prometheus()
         validate_exposition(boot)
         assert "raft_ticks_total 0" in boot
-        assert "raft_ticks_settled_total 0" in boot
-        for n in c.nodes.values():
-            n._tick_due = due
+        assert "raft_ticks_on_arrival_total 0" in boot
         c.tick(10)
+        for _ in range(3):
+            node.tick(arrival=True)
         text = node.metrics.render_prometheus()
         validate_exposition(text)
-        assert "raft_ticks_total 10" in text
-        assert f"raft_ticks_settled_total {int(10 * share)}" in text
-        assert node.metrics["ticks"] == node.ticks == 10
-        doc = node.metrics.to_dict()["counters"]
-        assert doc["ticks_settled"] == share * doc["ticks"]
+        assert "raft_ticks_total 13" in text
+        assert "raft_ticks_on_arrival_total 3" in text
+        assert node.metrics["ticks"] == node.ticks == 13
+        assert node.timer_ticks == 10
+        for gone in ("settled", "eager", "pipeline"):
+            assert gone not in text
     finally:
         c.close()
 
@@ -227,7 +223,7 @@ def test_heat_and_hop_metrics_on_exposition(tmp_path, monkeypatch):
     cfg = EngineConfig(n_groups=4, n_peers=3, log_slots=32, batch=4,
                        max_submit=4, election_ticks=6, heartbeat_ticks=2,
                        rpc_timeout_ticks=5, heat=True)
-    c = LocalCluster(cfg, str(tmp_path), pipeline=False)
+    c = LocalCluster(cfg, str(tmp_path))
     try:
         c.wait_leader(0)
         for i in range(4):
@@ -266,7 +262,7 @@ def test_hop_metric_cardinality_bounded(tmp_path, monkeypatch):
     cfg = EngineConfig(n_groups=4, n_peers=3, log_slots=32, batch=4,
                        max_submit=4, election_ticks=6, heartbeat_ticks=2,
                        rpc_timeout_ticks=5)
-    c = LocalCluster(cfg, str(tmp_path), pipeline=False)
+    c = LocalCluster(cfg, str(tmp_path))
     try:
         c.wait_leader(0)
         for i in range(6):

@@ -58,11 +58,17 @@ def _words(layout):
     return sum(w > 0 for w in layout.words)
 
 
+# Reads by the lease, and by strict ReadIndex (read_lease off: the step
+# and the layouts gain read_seq / ae_seq / aer_seq, PR 45).
+READS = [pytest.param(True, id="lease"), pytest.param(False, id="strict")]
+
+
+@pytest.mark.parametrize("lease", READS)
 def test_step_over_many_buffers_is_the_one_buffer_step(small_chunks,
-                                                       monkeypatch):
+                                                       monkeypatch, lease):
     cfg = EngineConfig(n_groups=16, n_peers=3, log_slots=16, batch=4,
                        max_submit=4, election_ticks=5, heartbeat_ticks=1,
-                       rpc_timeout_ticks=4)
+                       rpc_timeout_ticks=4, read_lease=lease)
     N, G = cfg.n_peers, cfg.n_groups
     many_in, many_back = step_layouts(cfg, True)
     assert _words(many_in) >= 2 and _words(many_back) >= 2
@@ -115,14 +121,14 @@ def test_step_over_many_buffers_is_the_one_buffer_step(small_chunks,
     assert accepted > 0, "no leader accepted a write: the run proved little"
 
 
-@pytest.fixture
-def served(tmp_path, small_chunks):
+@pytest.fixture(params=READS)
+def served(request, tmp_path, small_chunks):
     ports = free_ports(3)
     uris = [f"raft://127.0.0.1:{p}" for p in ports]
     cs = [RaftContainer(RaftConfig(
         local=u, peers=tuple(p for p in uris if p != u), n_groups=16,
         log_slots=32, batch=4, max_submit=4, tick_ms=20, seed=3,
-        data_dir=str(tmp_path / f"node{i}"),
+        read_lease=request.param, data_dir=str(tmp_path / f"node{i}"),
         election_mul=scaled_election_mul(10)), kv_factory()).create()
         for i, u in enumerate(uris)]
     yield cs

@@ -156,7 +156,7 @@ def test_heatmap_and_hops_endpoints(tmp_path, monkeypatch):
     decaying registry document, /hops the hop tracer's, and /latency
     carries the hops subdocument when tracing is live."""
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
-    c = LocalCluster(CFG_HEAT, str(tmp_path), pipeline=False)
+    c = LocalCluster(CFG_HEAT, str(tmp_path))
     try:
         c.wait_leader(0)
         for i in range(4):

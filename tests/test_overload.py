@@ -387,7 +387,7 @@ def test_paced_tick_loop_does_not_read_its_own_period_as_a_queue(tmp_path):
     wait looked like a standing queue and an idle cluster shed its only
     client."""
     import time
-    c = LocalCluster(CFG, str(tmp_path), seed=9, pipeline=True)
+    c = LocalCluster(CFG, str(tmp_path), seed=9)
     try:
         lead = c.wait_leader(0)
         node = c.nodes[lead]

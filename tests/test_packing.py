@@ -254,28 +254,34 @@ def test_a_leaf_that_is_neither_int32_nor_bool_is_refused():
         Layout({"x": np.zeros(3, np.float32)})
 
 
-# ----------------------------------- (c) a pending tick keeps its buffers ----
+# ------------------------------ (c) every tick has buffers of its own ----
 
 
-def test_next_dispatch_leaves_a_pending_ticks_arrays_alone(tmp_path):
-    """Overlapped order (tick() driven with no deadline): the tick that
-    is pending while the next one dispatches still reads its own inbox
-    planes, which the next dispatch neither reuses nor overwrites."""
+def test_next_dispatch_leaves_the_last_ticks_arrays_alone(tmp_path):
+    """Every dispatch fills upload buffers of its own: the inbox planes a
+    tick's host phase read are neither reused nor overwritten by the next
+    dispatch (an upload may alias host memory, and the step that reads it
+    runs asynchronously)."""
     cfg = EngineConfig(n_peers=3, **BASE)
-    c = LocalCluster(cfg, str(tmp_path), seed=1, pipeline=True)
+    c = LocalCluster(cfg, str(tmp_path), seed=1)
     try:
         c.wait_leader(0)
         node = c.nodes[0]
-        held = []       # (the pending tick's planes, their copies)
-        for _ in range(6):
-            c.tick()
-            arrays = node._pending.arrays.planes     # a DenseView's
+        held = []       # (a tick's planes as its host phase began, copies)
+        host_phase = node._host_phase
+
+        def keep(ctx):
+            arrays = ctx.arrays.planes               # a DenseView's
             if held:
                 before, copies = held[-1]
                 for name, plane in before.items():
                     np.testing.assert_array_equal(plane, copies[name], name)
                     assert not np.shares_memory(plane, arrays[name])
             held.append((arrays, {k: v.copy() for k, v in arrays.items()}))
+            return host_phase(ctx)
+        node._host_phase = keep
+        c.tick(6)
+        assert len(held) == 6
         assert sum(bool(a["ae_valid"].any() or a["aer_valid"].any())
                    for a, _ in held) >= 4, "no traffic reached the node"
     finally:

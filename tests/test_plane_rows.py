@@ -21,7 +21,8 @@ from rafting_tpu.core.step import (
 from rafting_tpu.core.types import (
     EngineConfig, HostInbox, I32_SAFE_MAX, LEADER, Messages, NIL, init_state)
 from rafting_tpu.testkit.fixtures import NullProvider
-from rafting_tpu.testkit.harness import LocalCluster
+from rafting_tpu.log.wal import native_available
+from rafting_tpu.testkit.harness import LocalCluster, wal_store_factory
 
 BASE = dict(n_groups=16, log_slots=16, batch=4, max_submit=4,
             election_ticks=8, heartbeat_ticks=3, rpc_timeout_ticks=6)
@@ -352,14 +353,14 @@ def checked_rows(monkeypatch):
     def checked(cfg, lay, columns_in, state, carry, buffers):
         node = nodes[int(state.node_id)]
         out = real(cfg, lay, columns_in, state, carry, buffers)
-        if "durable_tail" in lay.rows_in.at:
-            src = node._durable_tail_m if node._acked_tail is None \
-                else node._acked_tail
-            np.testing.assert_array_equal(
-                np.asarray(out[1].durable),
-                np.minimum(src, I32_SAFE_MAX).astype(np.int32),
-                f"node {node.node_id} tick {node.ticks}")
-            calls.append(node._acked_tail is not None)
+        assert "durable_tail" in lay.rows_in.at     # every step is fed one
+        src = node._durable_tail_m if node._acked_tail is None \
+            else node._acked_tail
+        np.testing.assert_array_equal(
+            np.asarray(out[1].durable),
+            np.minimum(src, I32_SAFE_MAX).astype(np.int32),
+            f"node {node.node_id} tick {node.ticks}")
+        calls.append(node._acked_tail is not None)
         return out
 
     monkeypatch.setattr(node_mod, "node_step_columns", checked)
@@ -404,24 +405,26 @@ def _lose_a_barrier(c, payload: bytes) -> int:
     return lead
 
 
-@pytest.mark.parametrize("pipeline", [True, False],
-                         ids=["pipelined", "serial"])
+@pytest.mark.parametrize("engine", ["native", "python"])
 def test_device_durable_tail_and_gauges_through_a_storm_a_failed_barrier_a_purge_and_a_reopen(
-        tmp_path, small, checked_rows, pipeline):
+        tmp_path, small, checked_rows, engine):
     """A cluster whose every lane elects at once (a storm: both row forms
     overflow), serves writes as rows, loses a barrier to ENOSPC (the host
-    then feeds ``_acked_tail``: a serial node feeds a tail only then, and
-    loses a second barrier later, after steps that fed none), purges a
-    lane, reopens it, and restarts a node: in every step that is fed a
-    tail the device's ``durable_tail`` is the host's plane, after every
-    tick the mirrors are the device's lanes and the running gauges a full
-    recount, and both row forms and both fallbacks were taken."""
+    then feeds ``_acked_tail``) and a second one later, after steps that
+    were fed the fsynced mirror again, purges a lane, reopens it, and
+    restarts a node: in every step the device's ``durable_tail`` is the
+    host's plane, after every tick the mirrors are the device's lanes and
+    the running gauges a full recount, and both row forms and both
+    fallbacks were taken.  Under either persist step."""
+    if engine == "native" and not native_available():
+        pytest.skip("no native WAL toolchain")
     small(4, 4, columns=3)
     nodes, calls = checked_rows
     cfg = EngineConfig(n_peers=3, pre_vote=True, **BASE)
-    assert column_layouts(cfg, pipeline) is not None
+    assert column_layouts(cfg, True) is not None
     c = LocalCluster(cfg, str(tmp_path), provider_factory=NullProvider,
-                     seed=5, pipeline=pipeline)
+                     seed=5,
+                     store_factory=wal_store_factory(str(tmp_path), engine))
     try:
         nodes.update(c.nodes)
         for _ in range(60):                     # the storm
@@ -441,14 +444,15 @@ def test_device_durable_tail_and_gauges_through_a_storm_a_failed_barrier_a_purge
                     futs.append(n.submit_batch(g, [b"burst"]))
             c.tick()
             assert_mirrors_and_gauges(c)
+        assert all(n.store.can_stage_native == (engine == "native")
+                   for n in c.nodes.values())
         lead = _lose_a_barrier(c, b"kept-through-enospc")
-        assert any(calls) and not (pipeline and calls[-1])
-        if not pipeline:
-            fed = len(calls)
-            c.tick(5)                           # steps that feed no tail
-            assert len(calls) == fed
-            lead = _lose_a_barrier(c, b"kept-again")
-            assert len(calls) > fed
+        assert any(calls) and not calls[-1]
+        clamped = sum(calls)
+        c.tick(5)                       # steps fed the fsynced mirror
+        assert sum(calls) == clamped
+        lead = _lose_a_barrier(c, b"kept-again")
+        assert sum(calls) > clamped and not calls[-1]
         # A purge (the lane's durable tail and mirrors drop to zero under
         # the rows) and a reopen.
         victim = c.nodes[(lead + 1) % 3]
@@ -506,10 +510,10 @@ def test_a_column_step_is_one_array_each_way_and_an_overflow_is_fetched_whole(
     k_rows, k_columns, lanes, rows_over, columns_over = CROSSINGS[case]
     small(k_rows, k_rows, columns=k_columns)
     cfg = EngineConfig(n_peers=3, pre_vote=True, **BASE)
-    lay = column_layouts(cfg, False)
+    lay = column_layouts(cfg, True)
     assert lay is not None
     c = LocalCluster(cfg, str(tmp_path), provider_factory=NullProvider,
-                     seed=5, pipeline=False)
+                     seed=5)
     try:
         for _ in range(60):
             c.tick()

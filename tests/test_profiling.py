@@ -19,7 +19,7 @@ from rafting_tpu.utils.profiling import StageSpans
 # The top-level stages: every instant of tick() is in exactly one of them
 # (dispatch = intake + upload + enqueue, scan_wait = device + fetch).
 TOP_STAGES = ("dispatch", "wal", "fsync", "send", "apply", "reads",
-              "maintain", "scan_wait", "mirrors", "eager_send", "tail")
+              "maintain", "scan_wait", "mirrors", "tail")
 
 
 def _raft_spans(trace_dir):
@@ -43,18 +43,19 @@ def _stage_total(node) -> float:
 @pytest.mark.parametrize("engine", [
     "python", pytest.param("native", marks=pytest.mark.skipif(
         not native_available(), reason="native WAL engine unavailable"))])
-@pytest.mark.parametrize("pipeline", [False, True],
-                         ids=["serial", "pipelined"])
-def test_stages_cover_the_tick(tmp_path, pipeline, engine):
+@pytest.mark.parametrize("shape", ["packed", "columns"])
+def test_stages_cover_the_tick(tmp_path, take_shape, shape, engine):
     """Over 50 manual ticks of a 16-lane node the stage totals sum to at
     least 95% of the time spent inside tick(), on the ticking thread's
     own clock, and the composite stages are the sums of their parts —
-    under either persist step: the Python one enters ``wal`` and
-    ``fsync``, the native one ``wal`` alone, and both leave one sample
-    a host phase in each of the two histograms."""
+    whichever step the shape takes and under either persist step: the
+    Python one enters ``wal`` and ``fsync``, the native one ``wal``
+    alone, and both leave one sample a host phase in each of the two
+    histograms."""
     cfg = EngineConfig(n_groups=16, n_peers=3)
+    take_shape(cfg, shape)
     c = LocalCluster(
-        cfg, str(tmp_path), seed=1, pipeline=pipeline,
+        cfg, str(tmp_path), seed=1,
         store_factory=wal_store_factory(str(tmp_path), engine))
     try:
         c.wait_leader(0)
@@ -81,7 +82,6 @@ def test_stages_cover_the_tick(tmp_path, pipeline, engine):
         # tick_latency_s still ends where the tail begins.
         assert h["tick_latency_s"].total + h["tick_stage_tail_s"].total \
             == pytest.approx(_stage_total(node), rel=1e-6)
-        assert ("tick_stage_eager_send_s" in h) == pipeline
         assert h["tick_stage_wal_s"].n == h["tick_stage_fsync_s"].n \
             == h["tick_stage_send_s"].n
         assert ("fsync" in node._stages.spent) == (engine == "python")
@@ -96,7 +96,7 @@ def test_any_profiler_session_holds_the_stage_spans(tmp_path):
     import jax
 
     cfg = EngineConfig(n_groups=16, n_peers=3)
-    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1, pipeline=True)
+    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1)
     trace_dir = str(tmp_path / "trace")
     try:
         c.wait_leader(0)
@@ -109,7 +109,7 @@ def test_any_profiler_session_holds_the_stage_spans(tmp_path):
     for name, stats in _raft_spans(trace_dir):
         seen.setdefault(name, set()).add((stats["node"], stats["tick"]))
     for name in ("dispatch_intake", "dispatch_upload", "dispatch_enqueue",
-                 "scan_device", "scan_fetch", "mirrors", "eager_send",
+                 "scan_device", "scan_fetch", "mirrors",
                  "tail", "reads", "maintain"):
         ids = seen["raft." + name]
         assert {n for n, _ in ids} == {0, 1, 2}, name
@@ -166,7 +166,7 @@ def test_a_tick_crosses_to_the_device_in_two_transfers_each_way(tmp_path):
     import jax
 
     cfg = EngineConfig(n_groups=16, n_peers=3)
-    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1, pipeline=True)
+    c = LocalCluster(cfg, str(tmp_path / "data"), seed=1)
     trace_dir = str(tmp_path / "trace")
     counters = ("h2d_transfers", "d2h_transfers")
     try:
@@ -438,34 +438,6 @@ def test_no_profiler_session_allocates_no_annotation(monkeypatch):
     assert st.spent["wal"] == pytest.approx(end - b)
     assert st.leave() >= end and len(st.spent) == 2     # nothing open
     assert st.period is None and "stage_stalls" not in m._counters
-
-
-def test_host_cost_is_the_stage_spans_own_sum(tmp_path):
-    """What the loop weighs against its deadline (runtime/node.py
-    settles_now) is read from the stage spans, per host phase: the six
-    host stages of a tick over the host phases it ran, the costliest of
-    the last HOST_COST_MEMORY ticks."""
-    from rafting_tpu.runtime.node import HOST_COST_MEMORY, HOST_STAGES
-    c = LocalCluster(EngineConfig(n_groups=4, n_peers=3), str(tmp_path),
-                     seed=1, pipeline=True)
-    try:
-        node = c.nodes[0]
-        node.tick()
-        assert not node._host_costs           # fetched, no host phase yet
-        node.tick()
-        assert len(node._host_costs) == node._host_runs == 1
-        assert node._host_costs[-1] == pytest.approx(
-            node._stages.total(*HOST_STAGES))
-        node._tick_due = float("inf")         # room: this tick runs two
-        node.tick()
-        assert node._host_runs == 2
-        assert node._host_costs[-1] == pytest.approx(
-            node._stages.total(*HOST_STAGES) / 2)
-        for _ in range(HOST_COST_MEMORY + 3):
-            node.tick()
-        assert len(node._host_costs) == HOST_COST_MEMORY
-    finally:
-        c.close()
 
 
 def test_tick_starts_lie_on_a_staggered_grid(tmp_path):

@@ -229,12 +229,13 @@ def test_waiting_single_reads_ride_one_barrier(kv_cluster, n):
 
 def test_read_behind_an_offer_in_flight_waits_for_the_next_slot(tmp_path):
     """The invariant of the merge: only batches WAITING when the slot is
-    won share its stamp.  Under the overlapped order an offer rides the
-    pending tick for a round; a read that arrives meanwhile joins no
-    stamped offer and gets a barrier of its own, one slot later."""
+    won share its stamp.  A strict ReadIndex offer (no lease) is stamped
+    in one step and waits a round of acknowledgements for its release; a
+    read that arrives meanwhile joins no stamped offer and gets a barrier
+    of its own, one slot later."""
     root = str(tmp_path)
     lc = LocalCluster(
-        _cfg(), root, pipeline=True,
+        _cfg(read_lease=False), root,
         provider_factory=lambda i: KVMachineProvider(
             os.path.join(root, f"kv{i}")))
     try:
@@ -242,7 +243,7 @@ def test_read_behind_an_offer_in_flight_waits_for_the_next_slot(tmp_path):
         _write(lc, node, "a", 1)
         barriers = node.metrics["read_barriers"]
         first = node.read(0, _kv("get", "a"))
-        lc.tick()               # dispatched: the offer rides this tick
+        lc.tick()               # offered and stamped: the round is out
         assert 0 in node._reads_offered or node._reads_pending.get(0)
         late = node.read(0, _kv("get", "a"))
         # It waits: it is in no offer, stamped or not.

@@ -210,11 +210,13 @@ def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch,
             return real(self, **stats)
 
         monkeypatch.setattr(StageSpans, "note", spy)
+        outboxes, host_phase = [], node._host_phase
+        node._host_phase = lambda ctx: (outboxes.append(ctx.outbox),
+                                        host_phase(ctx))[1]
         node.tick()
-        durable = node.pipeline or node._acked_tail is not None
-        inputs, readback = step_layouts(node.cfg, durable)
-        lay = column_layouts(node.cfg, durable)
-        fetched = node._pending.outbox if node._pending else None
+        (fetched,) = outboxes
+        inputs, readback = step_layouts(node.cfg, True)
+        lay = column_layouts(node.cfg, True)
     finally:
         monkeypatch.undo()
         step_layouts.cache_clear()
@@ -240,8 +242,7 @@ def test_transfer_spans_carry_the_packed_layouts_bytes(tmp_path, monkeypatch,
             assert span["bytes"] == lay.columns.nbytes + rows.nbytes \
                 + size(planes) * whole > 0
         assert up["dense"] == down["dense"] == 0
-        if fetched is not None:
-            assert down["columns"] == fetched.columns
+        assert down["columns"] == fetched.columns
     else:
         assert up["transfers"] == len(inputs.buffers)
         assert down["transfers"] == len(readback.buffers)

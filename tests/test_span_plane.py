@@ -8,7 +8,7 @@ over the same spans, none on a wall-clock gate."""
 
 import pytest
 
-from rafting_tpu.core.types import EngineConfig
+from rafting_tpu.core.types import EngineConfig, LEADER
 from rafting_tpu.testkit.harness import LocalCluster
 from rafting_tpu.utils.latency import (
     ACKED, COMMITTED, OFFERED, SENT, SERVED, SUBMITTED,
@@ -35,12 +35,12 @@ def _settle(c, futs, rounds=60):
     c.tick(2)       # the retired rings are harvested at the tick's tail
 
 
-@pytest.mark.parametrize("pipeline", [False, True],
-                         ids=["serial", "pipelined"])
+@pytest.mark.parametrize("shape", ["packed", "columns"])
 def test_read_spans_split_queue_from_confirm(tmp_path, monkeypatch,
-                                             pipeline):
+                                             take_shape, shape):
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
-    c = LocalCluster(CFG, str(tmp_path), seed=3, pipeline=pipeline)
+    take_shape(CFG, shape)
+    c = LocalCluster(CFG, str(tmp_path), seed=3)
     try:
         lead = c.wait_leader(0)
         c.submit_via_leader(0, b"w")
@@ -78,7 +78,7 @@ def test_write_groups_telescope_to_e2e(tmp_path, monkeypatch):
     spans, are the mean of lat_e2e_s (to 1%), every stamp in order with
     its tick number."""
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
-    c = LocalCluster(CFG, str(tmp_path), seed=3, pipeline=True)
+    c = LocalCluster(CFG, str(tmp_path), seed=3)
     try:
         lead = c.wait_leader(0)
         node = c.nodes[lead]
@@ -102,18 +102,23 @@ def test_write_groups_telescope_to_e2e(tmp_path, monkeypatch):
         c.close()
 
 
-def test_skipped_round_leaves_backlog_and_two_rounds_per_commit(
+def test_skipped_round_leaves_backlog_and_one_more_round_per_commit(
         tmp_path, monkeypatch):
-    """Three nodes ticked by hand.  One node misses one round (its peers
-    tick, it does not): from then on its inbox holds one slice per source
-    beyond the one it pops, and the writes it leads need two more of its
-    ticks from sent to committed, for the next 20 rounds and more."""
+    """Three nodes ticked by hand, each leading lanes of its own (so each
+    sends the others a slice every tick: a node that only answers sends
+    nothing unasked, and its queue drains by itself).  One node misses
+    one round (its peers tick, it does not): from then on its inbox holds
+    one slice per source beyond the one it pops, and the writes it leads
+    need one more of its ticks from sent to committed (every
+    acknowledgement waits a tick in the queue), for the next 20 rounds
+    and more."""
     monkeypatch.setenv("RAFT_LAT_SAMPLE", "1")
-    c = LocalCluster(CFG, str(tmp_path), seed=3, pipeline=True)
+    c = LocalCluster(CFG, str(tmp_path), seed=1)
     try:
         lead = c.wait_leader(0)
         node = c.nodes[lead]
         c.tick(10)
+        assert all((n.h_role == LEADER).any() for n in c.nodes.values())
 
         def commit_ticks(n):
             node._lat.recent.clear()
@@ -136,7 +141,7 @@ def test_skipped_round_leaves_backlog_and_two_rounds_per_commit(
                 other.tick()                    # the round `lead` misses
         assert backlog_over(20) == 1.0
         after = commit_ticks(3)
-        assert after == [before[0] + 2] * 3
+        assert after == [before[0] + 1] * 3
         assert backlog_over(5) == 1.0           # it never comes back
         g = node.metrics._gauges
         assert {g[f"inbox_backlog_src{p}"] for p in range(3) if p != lead} \
